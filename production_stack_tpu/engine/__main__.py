@@ -13,6 +13,7 @@ import os
 
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.server import EngineServer
+from production_stack_tpu.utils.compile_cache import configure_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -65,7 +66,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-device-stop", dest="device_stop",
                    action="store_false",
                    help="fixed-trip fused scan; overshoot discarded on "
-                        "the host (chip-window A/B control)")
+                        "the host (A/B control)")
     p.add_argument("--adaptive-decode-k", action="store_true",
                    default=True,
                    help="size each fused round from pow2 buckets up to "
@@ -85,8 +86,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--async-decode", action="store_true", default=False,
                    help="double-buffered decode: dispatch round N+1 on "
                         "round N's on-device tokens before fetching it "
-                        "(measured slower than the default synchronous "
-                        "path with --prefetch-decode at K=8; see PERF.md)")
+                        "(delays prefill admission; not measured on an "
+                        "attached chip)")
     p.add_argument("--no-async-decode", dest="async_decode",
                    action="store_false")
     p.add_argument("--prefetch-decode", action="store_true", default=True,
@@ -300,14 +301,28 @@ def config_from_args(args: argparse.Namespace) -> EngineConfig:
     )
 
 
+def require_accelerator() -> None:
+    """Refuse the CPU backend unless `JAX_PLATFORMS` names it.
+
+    With `JAX_PLATFORMS` unset jax drops to the CPU when libtpu fails to
+    initialise, and a 3B model would "serve" from host memory. The CPU
+    is a supported backend only where it was asked for (tests, local
+    servers: `JAX_PLATFORMS=cpu`)."""
+    import jax
+
+    asked = (jax.config.jax_platforms or "").lower().split(",")
+    if jax.default_backend() == "cpu" and "cpu" not in asked:
+        raise SystemExit(
+            "no accelerator: jax initialised the CPU backend although "
+            f"JAX_PLATFORMS={jax.config.jax_platforms!r} does not name "
+            "it. Fix the TPU runtime, or set JAX_PLATFORMS=cpu to serve "
+            "from the CPU on purpose."
+        )
+
+
 def main(argv: list[str] | None = None) -> None:
     args = build_parser().parse_args(argv)
-    # chip-session hygiene: refuse to start a second process that would
-    # dial the real TPU (a second dial hangs in backend init and can
-    # wedge a remote-attached chip); SIGTERM is the sanctioned stop
-    from production_stack_tpu.utils import chip_guard
-
-    _chip_lock = chip_guard.engage()  # noqa: F841 — held for process life
+    configure_compile_cache()
     if args.kv_instance_id == "default-instance":
         # by convention the instance id is host:port so kvaware routing can
         # map controller matches back to endpoint urls (routing_logic.py);
@@ -322,6 +337,7 @@ def main(argv: list[str] | None = None) -> None:
             except OSError:
                 host = "127.0.0.1"
         args.kv_instance_id = f"{host}:{args.port}"
+    follower = False
     if args.multihost:
         # must run before anything touches a device (jax.distributed)
         from production_stack_tpu.parallel import multihost
@@ -331,18 +347,20 @@ def main(argv: list[str] | None = None) -> None:
             num_processes=args.num_processes,
             process_id=args.process_id,
         )
-        if multihost.process_index() != 0:
-            # follower host: no HTTP server, replay host 0's device steps
-            from production_stack_tpu.engine.model_runner import ModelRunner
-            from production_stack_tpu.engine.multihost_engine import (
-                follower_loop,
-                validate_multihost_config,
-            )
+        follower = multihost.process_index() != 0
+    require_accelerator()
+    if follower:
+        # follower host: no HTTP server, replay host 0's device steps
+        from production_stack_tpu.engine.model_runner import ModelRunner
+        from production_stack_tpu.engine.multihost_engine import (
+            follower_loop,
+            validate_multihost_config,
+        )
 
-            cfg = config_from_args(args)
-            validate_multihost_config(cfg)
-            follower_loop(ModelRunner(cfg))
-            return
+        cfg = config_from_args(args)
+        validate_multihost_config(cfg)
+        follower_loop(ModelRunner(cfg))
+        return
     server = EngineServer(config_from_args(args))
     server.run(host=args.host, port=args.port)
 

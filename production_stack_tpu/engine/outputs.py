@@ -89,7 +89,7 @@ class EngineStatsSnapshot:
     # compile-count observability: program-variant builds (jit cache
     # misses on the runner's step builders) since boot, total and per
     # builder kind — tpu:compile_events_total in /metrics and the
-    # bench `compiles` detail slot. The chip-window cold-start tax
+    # bench `compiles` detail slot. The cold-start compile cost
     # (and the single-kernel variant-space shrink) read directly off
     # this instead of being inferred from compile logs.
     compile_events_total: int = 0

@@ -1204,9 +1204,13 @@ class EngineServer:
         return web.json_response({"status": "healthy"})
 
     async def handle_version(self, request: web.Request) -> web.Response:
-        return web.json_response(
-            {"version": production_stack_tpu.__version__}
-        )
+        """Package version plus what the engine runs on: platform,
+        device_kind, device_count, attention_impl, ragged_kernel and
+        per-device bytes_in_use."""
+        return web.json_response({
+            "version": production_stack_tpu.__version__,
+            **self.engine.engine.runner.device_report(),
+        })
 
     async def handle_metrics(self, request: web.Request) -> web.Response:
         self.metrics.update_from_snapshot(self.engine.stats())

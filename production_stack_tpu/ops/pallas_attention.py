@@ -38,17 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from production_stack_tpu.parallel.compat import shard_map
-
-# jax-generation compat (same contract as parallel/compat.py), module-
-# local so the shared pltpu module is never mutated: jax 0.4.x spells
-# the HBM memory space `ANY` and the Mosaic params `TPUCompilerParams`;
-# the newer public names are HBM / CompilerParams.
-_HBM = getattr(pltpu, "HBM", None) or pltpu.ANY
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or (
-    pltpu.TPUCompilerParams
-)
-
 MASK_VALUE = -1e30
 
 # Query-tile rows of the unified ragged kernel's row blocks. 8 is the
@@ -538,8 +527,8 @@ def ragged_paged_attention(
                 (tq, nq, d), lambda i, *_: (i, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(memory_space=_HBM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=pl.BlockSpec(
             (tq, nq, d), lambda i, *_: (i, 0, 0),
@@ -564,7 +553,7 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, nq, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 2**20,
         ),
@@ -604,7 +593,7 @@ def ragged_paged_attention_tp(
         block_size=block_size, scale=scale, interpret=interpret,
         window=window,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -665,8 +654,8 @@ def paged_prefill_attention(
                 (tq, nq, d), lambda i, *_: (i, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(memory_space=_HBM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=pl.BlockSpec(
             (tq, nq, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
@@ -692,7 +681,7 @@ def paged_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nq, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # large f32 q/accumulator tiles exceed the default 16 MiB
             # scoped-vmem stack; v5e has 128 MiB — allow half of it
@@ -731,7 +720,7 @@ def paged_prefill_attention_tp(
         block_size=block_size, scale=scale, interpret=interpret,
         window=window,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -793,7 +782,7 @@ def paged_decode_attention_tp(
         block_size=block_size, scale=scale, interpret=interpret,
         window=window,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -839,8 +828,8 @@ def paged_decode_attention(
                 (1, nq, d), lambda i, *_: (i, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(memory_space=_HBM),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=pl.BlockSpec(
             (1, nq, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
@@ -863,7 +852,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nq, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # large f32 q/accumulator tiles exceed the default 16 MiB
             # scoped-vmem stack; v5e has 128 MiB — allow half of it

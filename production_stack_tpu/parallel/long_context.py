@@ -57,7 +57,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.models import llama
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.parallel.compat import shard_map
 from production_stack_tpu.parallel.ring_attention import (
     ring_attention_local,
 )
@@ -93,7 +92,7 @@ def _forward(cfg: ModelConfig, params: dict, token_ids: jax.Array,
     has_tp = "tp" in mesh.axis_names and mesh.shape["tp"] > 1
     spec4 = (P(None, SP_AXIS, "tp", None) if has_tp
              else P(None, SP_AXIS, None, None))
-    ring = shard_map(
+    ring = jax.shard_map(
         functools.partial(
             ring_attention_local, axis_name=SP_AXIS, causal=True,
             scale=llama.attention_scale(cfg), window=cfg.sliding_window,
@@ -242,7 +241,7 @@ class LongContextPrefiller:
         has_tp = "tp" in mesh.axis_names and mesh.shape["tp"] > 1
         spec4 = (P(None, SP_AXIS, "tp", None) if has_tp
                  else P(None, SP_AXIS, None, None))
-        ring = shard_map(
+        ring = jax.shard_map(
             functools.partial(
                 ring_attention_local, axis_name=SP_AXIS, causal=True,
                 scale=llama.attention_scale(cfg), window=self.window,

@@ -43,8 +43,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.parallel import compat
-from production_stack_tpu.parallel.compat import shard_map
 from production_stack_tpu.ops.attention import context_attention_prefill
 from production_stack_tpu.ops.layers import (
     apply_rope,
@@ -177,7 +175,7 @@ def _pp_prefill(cfg, S, M, mesh, params, tokens, *, chunk):
     cache_spec = P(PP_AXIS, None, None, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(layer_specs, P(None, None), P(None)),
         out_specs=(P(None, None, None), cache_spec, cache_spec),
@@ -198,7 +196,7 @@ def _pp_prefill(cfg, S, M, mesh, params, tokens, *, chunk):
         # makes them device-varying (stage-dependent), so pre-cast their
         # varying-manual-axes type or the fori_loop carry types mismatch
         def varying(x):
-            return compat.pvary(x, (PP_AXIS,))
+            return jax.lax.pcast(x, (PP_AXIS,), to="varying")
 
         kc0 = varying(jnp.zeros((L_loc, nkv, slots, d), dtype))
         vc0 = varying(jnp.zeros((L_loc, nkv, slots, d), dtype))

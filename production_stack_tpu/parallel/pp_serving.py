@@ -46,7 +46,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from production_stack_tpu.models import llama
-from production_stack_tpu.parallel.compat import shard_map
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.layers import rms_norm, rope_cos_sin
 
@@ -107,7 +106,7 @@ def forward_pp(
     layer_specs = jax.tree.map(lambda _: P(PP_AXIS), params["layers"])
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         # partial-manual: pp is manual here, tp (if present) stays
         # GSPMD-auto inside, so the Megatron shardings keep working

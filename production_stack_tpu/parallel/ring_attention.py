@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from production_stack_tpu.parallel.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 SP_AXIS = "sp"
@@ -165,7 +164,7 @@ def ring_attention(
     exactly as the engine's chunked prefill already does).
     """
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention_local, axis_name=axis_name, causal=causal,
             scale=scale,

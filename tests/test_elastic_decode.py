@@ -6,11 +6,10 @@ KV writes to the trash slot, penalty/DFA state frozen) and the host
 applies exactly the per-lane valid counts instead of discarding
 overshoot after the fetch.
 
-Role: the round-5 chip windows measured K=32 wasting 28% of sampled
-slots on overshoot and K=16 blowing p50 TTFT to 9-14 s on long
-uninterruptible rounds (PERF.md); device stops remove the waste,
-adaptive K removes the admission starvation, and this suite pins the
-parity bar every prior perf PR met."""
+Role: a fixed-trip K round samples slots past a lane's stop and keeps
+waiting prompts out for K uninterruptible steps; device stops remove
+the waste, adaptive K removes the admission starvation, and this suite
+pins the parity bar every prior perf PR met."""
 
 from __future__ import annotations
 

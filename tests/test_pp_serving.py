@@ -42,14 +42,6 @@ def test_pp2_matches_single_device():
 def test_pp2_tp2_matches_single_device():
     """pp x tp composition: layer axis manual over pp, Megatron tp left
     to GSPMD inside the partial-manual shard_map."""
-    import jax
-
-    if not (hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")):
-        # jax 0.4.x SPMD can't partition the partial-manual pp region
-        # when tp stays auto inside it ("PartitionId instruction is not
-        # supported" at dispatch) — an XLA/jax-generation limit, not an
-        # engine bug; pp-only and tp-only compositions are covered above
-        pytest.skip("pp x tp partial-manual needs the vma-era jax SPMD")
     sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
     ref = [o.token_ids for o in make_engine().generate(PROMPTS, sp)]
     eng = make_engine(pp=2, tp=2)

@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from production_stack_tpu.parallel.compat import shard_map
 
 from production_stack_tpu.parallel.ring_attention import (
     attention_reference,
@@ -78,7 +77,7 @@ def test_ring_plus_tensor_parallel():
     want = attention_reference(q, k, v, causal=True)
 
     spec = P(None, "sp", "tp", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention_local, axis_name="sp"),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )
@@ -101,7 +100,7 @@ def test_ring_long_context_memory_shape():
     mesh = _mesh(sp)
     spec = P(None, "sp", None, None)
     q, k, v = _rand(b=1, s=s, h=2, hk=2, d=8)
-    shard_map(probe, mesh=mesh, in_specs=(spec, spec, spec),
+    jax.shard_map(probe, mesh=mesh, in_specs=(spec, spec, spec),
               out_specs=spec)(q, k, v)
     assert captured["kv_local"][1] == s // sp
 

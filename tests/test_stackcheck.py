@@ -558,13 +558,13 @@ def test_multiline_justification_is_folded():
 
         async def go():
             # stackcheck: disable=blocking-async — calibrated warmup
-            # stall measured against the chip tunnel
+            # stall measured against the device link
             time.sleep(1)
     """)
     (f,) = analyze_source(src)
     assert f.suppressed
     assert f.justification == (
-        "calibrated warmup stall measured against the chip tunnel"
+        "calibrated warmup stall measured against the device link"
     )
 
 

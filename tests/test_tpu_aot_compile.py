@@ -1,0 +1,157 @@
+"""Compile-only TPU v5e: real XLA:TPU + Mosaic without a chip.
+
+libtpu can describe a v5e topology on a machine that has none, and
+`jax.jit(f).lower(*ShapeDtypeStructs placed on its devices).compile()`
+then runs the same compilers a chip run would. Interpret-mode parity
+tests cannot see a Mosaic lowering error; these can — the pre-flight for
+any kernel or step-program change before chip time is spent on it.
+
+Shapes are llama-3.2-3b's (24 q / 8 kv heads, head_dim 128, block 32),
+the model `chip_smoke.py` serves on one chip.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from production_stack_tpu.ops import pallas_attention as pa
+
+BS = 32
+NQ, NKV, D = 24, 8, 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one device of a compile-only v5e:2x2 topology."""
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"compile-only TPU topology unavailable: {e!r}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _cache(sh, d=D):
+    # (L, nkv, slots, d): stays in HBM, so its size does not matter
+    return _spec(sh, (2, NKV, 64 * BS, d), jnp.bfloat16)
+
+
+def _compile_decode(sh, *, nq=NQ, d=D, lanes=16, pages=32):
+    fn = functools.partial(
+        pa.paged_decode_attention, block_size=BS, scale=d**-0.5
+    )
+    return jax.jit(fn).lower(
+        _spec(sh, (lanes, nq, d), jnp.bfloat16), _cache(sh, d),
+        _cache(sh, d), _spec(sh, (), jnp.int32),
+        _spec(sh, (lanes, pages), jnp.int32),
+        _spec(sh, (lanes,), jnp.int32),
+    ).compile()
+
+
+def _compile_ragged(sh, *, nq=NQ, d=D, rows=128, lanes=16, pages=32):
+    fn = functools.partial(
+        pa.ragged_paged_attention, block_size=BS, scale=d**-0.5
+    )
+    blocks = rows // pa.RAGGED_TQ
+    return jax.jit(fn).lower(
+        _spec(sh, (rows, nq, d), jnp.bfloat16), _cache(sh, d),
+        _cache(sh, d), _spec(sh, (), jnp.int32),
+        _spec(sh, (lanes, pages), jnp.int32),
+        _spec(sh, (blocks + 1,), jnp.int32),
+        _spec(sh, (blocks + lanes, 4), jnp.int32),
+    ).compile()
+
+
+def test_decode_kernel_compiles(v5e):
+    _compile_decode(v5e)
+
+
+def test_ragged_kernel_compiles(v5e):
+    _compile_ragged(v5e)
+
+
+@pytest.mark.slow
+def test_prefill_kernel_compiles(v5e):
+    fn = functools.partial(
+        pa.paged_prefill_attention, block_size=BS, scale=D**-0.5
+    )
+    jax.jit(fn).lower(
+        _spec(v5e, (512, NQ, D), jnp.bfloat16), _cache(v5e), _cache(v5e),
+        _spec(v5e, (), jnp.int32), _spec(v5e, (32,), jnp.int32),
+        _spec(v5e, (), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("compile_kernel", [_compile_decode, _compile_ragged])
+def test_head_dim_64_is_refused_by_mosaic(v5e, compile_kernel):
+    """The hardware rule behind ModelRunner's head_dim % 128 check: a
+    page slice of a 64-wide cache is a partial (8, 128) tile."""
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\)"):
+        compile_kernel(v5e, nq=32, d=64)
+
+
+def test_block_tables_are_bounded_by_smem(v5e):
+    """Scalar-prefetch SMEM is 1 MiB: lanes x ctx-bucket pages x 4 B
+    must stay under it, which bounds --max-num-seqs x context."""
+    _compile_decode(v5e, lanes=16, pages=4096)  # 256 KiB: fits
+    with pytest.raises(Exception, match="memory space smem"):
+        _compile_decode(v5e, lanes=64, pages=4096)  # 1 MiB: refused
+
+
+@pytest.mark.slow
+def test_decode_multi_step_compiles_at_full_width_and_depth(
+    v5e, monkeypatch
+):
+    """One whole fused-K decode program of llama-3.2-3b, as the engine
+    builds it for the chip: Mosaic kernels (not interpret), pinned cache
+    layout, donated caches. No full-cache copy may appear in its temps."""
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.model_runner import ModelRunner
+    from production_stack_tpu.models import config as mcfg
+    from production_stack_tpu.models import llama
+
+    mc = dataclasses.replace(
+        mcfg.get_model_config("llama-3.2-3b"), name="llama-3.2-3b-aot"
+    )
+    monkeypatch.setitem(mcfg._PRESETS, mc.name, mc)
+    abstract = jax.eval_shape(
+        lambda: llama.init_params(mc, jax.random.key(0), jnp.bfloat16)
+    )
+    runner = ModelRunner(
+        EngineConfig(
+            model=mc.name, tokenizer="byte", max_model_len=8192,
+            max_num_seqs=16, num_scheduler_steps=8, num_kv_blocks=64,
+            attention_impl="pallas",
+        ),
+        params=abstract,
+    )
+    # the step builders read the backend at trace time (interpret mode,
+    # layout pin): trace them as the chip would
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, c_pad, k = 16, 1024, 8
+    step = runner._make_decode_multi_step(b, c_pad, k)
+    _, packed_len = runner._decode_pack_layout(b, c_pad, False)
+
+    def on_chip(x):
+        return _spec(v5e, x.shape, x.dtype)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        jax.tree.map(on_chip, abstract), on_chip(runner.k_cache),
+        on_chip(runner.v_cache), _spec(v5e, (packed_len,), jnp.int32),
+    ).compile()
+    cache_bytes = runner.k_cache.size * runner.k_cache.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
