@@ -1233,16 +1233,17 @@ def run_config(sched_steps: int, prefill_seqs: int, async_decode: bool,
             # buffer (no serial upload); misses staged but invalidated
             "staged_hits": engine._staged_hits_total,
             "staged_misses": engine._staged_misses_total,
-            # pipelined-prefill attribution: where prefill wall time
-            # went (prep / h2d / dispatch / fetch) + staging and
-            # cold-prompt chaining effectiveness
+            # the round's phase attribution (tracing/phases.py): where
+            # the step thread's wall time went (schedule / pack / h2d /
+            # dispatch / fetch / apply) + staging and cold-prompt
+            # chaining effectiveness
             "prefill_phase_s": {
                 k: round(v, 3)
-                for k, v in engine.runner.prefill_phase_s.items()
+                for k, v in engine.phases.seconds().items()
             },
             # per-phase sample counts: phase_s / phase_n = mean wall
-            # time per dispatch for that phase
-            "prefill_phase_n": dict(engine.runner.prefill_phase_n),
+            # time per observation of that phase
+            "prefill_phase_n": engine.phases.counts(),
             "prefill_staged_hits": engine._pf_staged_hits_total,
             "prefill_staged_misses": engine._pf_staged_misses_total,
             "prefill_chained_chunks": engine._pf_chained_chunks_total,

@@ -22,6 +22,7 @@ from production_stack_tpu.engine.outputs import (
     RequestOutput,
 )
 from production_stack_tpu.engine.sampling_params import SamplingParams
+from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
 
 logger = init_logger(__name__)
@@ -41,6 +42,15 @@ class AsyncLLMEngine:
         # reads/pops on hot paths (suppressed with rationale in place)
         self._streams: dict[str, asyncio.Queue] = {}  # guarded by: self._lock
         self._lock = threading.Lock()
+        # The step thread holds `_lock` for the whole of engine.step(),
+        # which ends in the fetch of the round in flight; `generate`,
+        # `abort` and `stats` take it ON THE EVENT LOOP, so each such
+        # acquire can stop every SSE stream of the replica for up to a
+        # round. These spans count that wait by site (seconds, count):
+        # tpu:event_loop_lock_wait_seconds (all sites) and tpu:admit_
+        # lock_wait_seconds (`generate` alone, once per request), and
+        # `server.<site>` in a profiler trace.
+        self.lock_waits = phases.PhaseTimer(phases.LOCK_WAITS, "server.")
         self._wake = threading.Event()
         self._stopped = False
         self._thread = threading.Thread(
@@ -94,9 +104,11 @@ class AsyncLLMEngine:
                 # needed — this note is the audit trail)
                 time.sleep(0.5)
             if outputs and self._loop is not None:
-                self._loop.call_soon_threadsafe(self._deliver, outputs)
+                with self.engine.phases.span("deliver"):
+                    self._loop.call_soon_threadsafe(self._deliver, outputs)
             if not busy:
-                self._wake.wait(timeout=0.02)
+                with self.engine.phases.span("idle"):
+                    self._wake.wait(timeout=0.02)
                 self._wake.clear()
 
     def _fail_inflight(self) -> list[RequestOutput]:
@@ -150,7 +162,9 @@ class AsyncLLMEngine:
         q: asyncio.Queue[RequestOutput] = asyncio.Queue()
         finished = False
         try:
-            with self._lock:
+            with self.lock_waits.span("admit_lock_wait") as wait, \
+                    self._lock:
+                wait.stop()  # the wait is over: the lock is held
                 self._streams[request_id] = q
                 self.engine.add_request(
                     request_id,
@@ -176,11 +190,14 @@ class AsyncLLMEngine:
             # behind the step thread's full engine.step
             self._streams.pop(request_id, None)
             if not finished:
-                with self._lock:
+                with self.lock_waits.span("abort_lock_wait") as wait, \
+                        self._lock:
+                    wait.stop()
                     self.engine.abort_request(request_id)
 
     async def abort(self, request_id: str) -> bool:
-        with self._lock:
+        with self.lock_waits.span("abort_lock_wait") as wait, self._lock:
+            wait.stop()
             return self.engine.abort_request(request_id)
 
     def has_request(self, request_id: str) -> bool:
@@ -191,8 +208,11 @@ class AsyncLLMEngine:
 
     # -- introspection -----------------------------------------------------
     def stats(self) -> EngineStatsSnapshot:
-        with self._lock:
-            return self.engine.stats()
+        with self.lock_waits.span("stats_lock_wait") as wait, self._lock:
+            wait.stop()
+            snap = self.engine.stats()
+        snap.loop_lock_waits = self.lock_waits.pairs()
+        return snap
 
     def drain_kv_observations(self) -> tuple[list[float], list[float]]:
         """KV export/restore histogram observations since the last

@@ -34,6 +34,7 @@ from production_stack_tpu.engine.scheduler import (
 )
 from production_stack_tpu.engine.sequence import Sequence, SequenceStatus
 from production_stack_tpu.engine.tokenizer import get_tokenizer
+from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
 
 logger = init_logger(__name__)
@@ -126,6 +127,24 @@ class LLMEngine:
             self.timeline = NULL_RECORDER
         self._tl_enabled = self.timeline.enabled
         self.scheduler.timeline = self.timeline
+        # -- the round seen from inside (tracing/phases.py) ---------------
+        # one timer for the whole round: the runner feeds pack / h2d /
+        # dispatch, the engine schedule / fetch / apply (and the server's
+        # step loop idle / deliver). `_round` numbers the dispatched
+        # rounds; the `engine.step` annotation of a profiler trace
+        # carries it as `round` and the request timeline's prefill_chunk
+        # / first_token / decode_round events as `engine_round` (a
+        # decode_round's own `round` counts that REQUEST's rounds), so a
+        # request in /debug/requests joins a round in a trace by that
+        # number and not by clock
+        self.phases = self.runner.phases
+        self._round = 0
+        # the round whose tokens the events being recorded belong to:
+        # the round just dispatched, or (async decode) the in-flight one
+        # being resolved a step later
+        self._event_round = 0
+        self._step_ann = None     # live only inside a profiler session
+        self._step_tagged = False
         # async decode pipeline (double-buffered dispatch): the in-flight
         # decode round whose sampled tokens are still ON DEVICE
         self._pending_decode: dict | None = None
@@ -1376,15 +1395,19 @@ class LLMEngine:
         bookkeeping to the synchronous path)."""
         pend = self._pending_decode
         self._pending_decode = None
-        # stackcheck: disable=device-sync-transitive — THE sanctioned
-        # fetch seam of async dispatch: the one device fetch for the
-        # in-flight round, taken after the next round was dispatched
-        toks = np.asarray(pend["toks"])  # (k, b) — the only device fetch
-        lps = pend.get("lps")
-        if lps is not None:
-            # stackcheck: disable=device-sync-transitive — logprob
-            # arrays ride the same sanctioned in-flight-round fetch
-            lps = tuple(np.asarray(a) for a in lps)
+        # the events recorded below belong to the round being resolved,
+        # not to the one dispatched a moment ago
+        self._event_round = pend["round"]
+        with self.phases.span("fetch"):
+            # stackcheck: disable=device-sync-transitive — THE sanctioned
+            # fetch seam of async dispatch: the one device fetch for the
+            # in-flight round, taken after the next round was dispatched
+            toks = np.asarray(pend["toks"])  # (k, b) — the only fetch
+            lps = pend.get("lps")
+            if lps is not None:
+                # stackcheck: disable=device-sync-transitive — logprob
+                # arrays ride the same sanctioned in-flight-round fetch
+                lps = tuple(np.asarray(a) for a in lps)
         seqs = pend["seqs"]
         self._apply_multi_tokens(seqs, toks, pend["k"], lps=lps)
         # requests aborted mid-flight already emitted their final output
@@ -1411,40 +1434,41 @@ class LLMEngine:
         no KV/state writes, never sampled) and are skipped without
         touching the overshoot counter — the host takes exactly the
         generated tokens."""
-        nb = len(seqs)
-        # one numpy->python conversion per lane, not one per k*b slot
-        vcounts = valid[:nb].tolist() if valid is not None else None
-        if vcounts and max(vcounts) < k:
-            # every lane froze before the trip count: the device round
-            # exited early instead of paying the all-finished tail
-            self._decode_early_exit_rounds_total += 1
-        for i in range(k):
-            for j, seq in enumerate(seqs):
-                if vcounts is not None and i >= vcounts[j]:
-                    continue  # device-frozen rows: pad, never sampled
-                if seq.finished:
-                    # host-side stop (stop strings, guided completion,
-                    # or the fixed-trip --no-device-stop control): this
-                    # slot WAS sampled on device and is now discarded —
-                    # the waste class device stops exist to eliminate
-                    self._decode_overshoot_tokens_total += 1
-                    continue
-                seq.num_computed_tokens = seq.num_tokens
-                entry = None
-                n = seq.sampling_params.logprobs
-                if lps is not None and n is not None:
-                    chosen, tv, ti = lps
-                    entry = {
-                        "token_id": int(toks[i, j]),
-                        "logprob": float(chosen[i, j]),
-                        "top_logprobs": [
-                            {"token_id": int(ti[i, j, m]),
-                             "logprob": float(tv[i, j, m])}
-                            for m in range(n)
-                        ],
-                    }
-                self._append_token(seq, int(toks[i, j]), entry)
-        self._note_decode_round(seqs, k, extra_attrs=round_attrs)
+        with self.phases.span("apply"):
+            nb = len(seqs)
+            # one numpy->python conversion per lane, not one per k*b slot
+            vcounts = valid[:nb].tolist() if valid is not None else None
+            if vcounts and max(vcounts) < k:
+                # every lane froze before the trip count: the device round
+                # exited early instead of paying the all-finished tail
+                self._decode_early_exit_rounds_total += 1
+            for i in range(k):
+                for j, seq in enumerate(seqs):
+                    if vcounts is not None and i >= vcounts[j]:
+                        continue  # device-frozen rows: pad, never sampled
+                    if seq.finished:
+                        # host-side stop (stop strings, guided completion,
+                        # or the fixed-trip --no-device-stop control): this
+                        # slot WAS sampled on device and is now discarded —
+                        # the waste class device stops exist to eliminate
+                        self._decode_overshoot_tokens_total += 1
+                        continue
+                    seq.num_computed_tokens = seq.num_tokens
+                    entry = None
+                    n = seq.sampling_params.logprobs
+                    if lps is not None and n is not None:
+                        chosen, tv, ti = lps
+                        entry = {
+                            "token_id": int(toks[i, j]),
+                            "logprob": float(chosen[i, j]),
+                            "top_logprobs": [
+                                {"token_id": int(ti[i, j, m]),
+                                 "logprob": float(tv[i, j, m])}
+                                for m in range(n)
+                            ],
+                        }
+                    self._append_token(seq, int(toks[i, j]), entry)
+            self._note_decode_round(seqs, k, extra_attrs=round_attrs)
 
     def _note_decode_round(
         self, seqs: list[Sequence], k: int,
@@ -1467,6 +1491,7 @@ class LLMEngine:
             attrs = {
                 "k_chosen": k, "lanes_done": lanes_done,
                 "prefill_lanes": 0, "decode_lanes": len(seqs),
+                "engine_round": self._event_round,
             }
             if extra_attrs:
                 attrs.update(extra_attrs)
@@ -1480,6 +1505,13 @@ class LLMEngine:
     # stackcheck: hot-path — may only enqueue (flush = device-snapshot
     # enqueue; the d2h runs on the offload worker)
     def step(self) -> list[RequestOutput]:
+        # one `engine.step` per call in a profiler trace, the phases its
+        # leaves; outside a profiler session no annotation is made
+        ann = None
+        if phases.profiling():
+            ann = self._step_ann = phases.TraceAnnotation("engine.step")
+            self._step_tagged = False
+            ann.__enter__()
         try:
             return self._step_impl()
         finally:
@@ -1489,6 +1521,25 @@ class LLMEngine:
             # freed blocks from staying pinned forever
             if self._kv_export_pending:
                 self._flush_kv_exports()
+            if ann is not None:
+                self._step_ann = None
+                ann.__exit__(None, None, None)
+
+    def _begin_round(self, kind: str, k: int, lanes: int, rows: int) -> int:
+        """Number the round about to be dispatched and, inside a
+        profiler session, tag this step's `engine.step` with it: `kind`
+        (decode / ragged / prefill / verify), fused steps `k`, live
+        decode `lanes` and prefill `rows`. A step that dispatches more
+        than one round (chained prefill chunks) is tagged with its
+        first; every round still takes a number."""
+        self._round += 1
+        self._event_round = self._round
+        ann = self._step_ann
+        if ann is not None and not self._step_tagged:
+            self._step_tagged = True
+            ann.set_metadata(round=self._round, kind=kind, k=k,
+                             lanes=lanes, rows=rows)
+        return self._round
 
     # stackcheck: hot-path — the async-decode round trip: dispatch the
     # next round BEFORE fetching the in-flight one; the only sanctioned
@@ -1499,17 +1550,21 @@ class LLMEngine:
         # fetching the in-flight round (the fetch overlaps the new
         # round's execution)
         if self._pending_decode is not None:
-            if self._can_chain():
+            with self.phases.span("schedule"):
+                can_chain = self._can_chain()
+            if can_chain:
                 pend = self._pending_decode
                 seqs: list[Sequence] = pend["seqs"]
                 k = pend["k"]
                 want_lp = pend.get("lps") is not None
-                temps, top_ps, top_ks, min_ps, keys, _ = (
-                    self._sampling_arrays(seqs)
-                )
-                keys[:, 1] += k  # k sampled-but-unapplied tokens per lane
-                positions = [s.num_tokens - 1 + k for s in seqs]
-                ctx_lens = [s.num_tokens + k for s in seqs]
+                with self.phases.span("pack"):
+                    temps, top_ps, top_ks, min_ps, keys, _ = (
+                        self._sampling_arrays(seqs)
+                    )
+                    keys[:, 1] += k  # k sampled-but-unapplied per lane
+                    positions = [s.num_tokens - 1 + k for s in seqs]
+                    ctx_lens = [s.num_tokens + k for s in seqs]
+                rnd = self._begin_round("decode", k, len(seqs), 0)
                 ys = self.runner.decode_multi(
                     pend["toks"][-1], positions,
                     [s.block_table for s in seqs], ctx_lens, k,
@@ -1522,31 +1577,40 @@ class LLMEngine:
                 )
                 outputs = self._resolve_pending()
                 self._pending_decode = {"seqs": seqs, "toks": toks_next,
-                                        "k": k, "lps": lps_next}
+                                        "k": k, "lps": lps_next,
+                                        "round": rnd}
                 self.last_step_kind = "decode"
                 return outputs
             # pipeline flush: apply the in-flight tokens before any
             # scheduling decision reads sequence state
+            flushed_round = self._pending_decode["round"]
             flushed = self._resolve_pending()
-            return flushed + self._step_scheduled()
+            outputs = flushed + self._step_scheduled()
+            if self._step_ann is not None and not self._step_tagged:
+                # nothing was dispatched behind the flush
+                self._step_ann.set_metadata(
+                    round=flushed_round, kind="flush")
+            return outputs
         return self._step_scheduled()
 
     def _step_scheduled(self) -> list[RequestOutput]:
-        if self._kv_restores:
-            # start h2d uploads for restores whose tier fetch landed
-            # while their requests sit in the waiting queue (the upload
-            # then overlaps this step's compute)
-            self._poll_kv_restores()
-        # long-prefill lane: advance ring chunks / KV landing BEFORE
-        # scheduling, so a job whose chain just finished landing is
-        # decode-ready in THIS round's plan (its first token rides the
-        # same step). One enqueue per job per step — never a device
-        # fetch — so the decode/ragged rounds below keep their cadence.
-        long_stepped: list[Sequence] = []
-        long_progress = True
-        if self.long_prefill is not None and self.long_prefill.active:
-            long_stepped, long_progress = self._advance_long_prefills()
-        sched_out = self.scheduler.schedule()
+        with self.phases.span("schedule"):
+            if self._kv_restores:
+                # start h2d uploads for restores whose tier fetch landed
+                # while their requests sit in the waiting queue (the
+                # upload then overlaps this step's compute)
+                self._poll_kv_restores()
+            # long-prefill lane: advance ring chunks / KV landing BEFORE
+            # scheduling, so a job whose chain just finished landing is
+            # decode-ready in THIS round's plan (its first token rides
+            # the same step). One enqueue per job per step — never a
+            # device fetch — so the decode/ragged rounds below keep
+            # their cadence.
+            long_stepped: list[Sequence] = []
+            long_progress = True
+            if self.long_prefill is not None and self.long_prefill.active:
+                long_stepped, long_progress = self._advance_long_prefills()
+            sched_out = self.scheduler.schedule()
         if sched_out.preempted or sched_out.prefills or sched_out.aborted:
             # any table free/reassignment or lane-set change invalidates
             # the staged prefetch (the epoch in the fingerprint already
@@ -1709,78 +1773,81 @@ class LLMEngine:
         otherwise. Returns the stepped sequences (empty when the round
         went async — resolution happens on a later step)."""
         stepped: list[Sequence] = []
-        tokens = [s.all_token_ids[-1] for s in seqs]
-        positions = [s.num_tokens - 1 for s in seqs]
-        tables = [s.block_table for s in seqs]
-        ctx_lens = [s.num_tokens for s in seqs]
-        # guided lanes ride the fused multi-step scan via on-device
-        # TokenDFA tables (structured.TokenDFA — outlines-style
-        # FSM-index compilation); only constraints too large to
-        # compile under budget fall back to the host-masked
-        # single-step path below
-        guided_tables = None
-        needs_guided = any(self._is_guided(s) for s in seqs)
-        if needs_guided and k_steps > 1:
-            # leave the fused path when any guided lane is close to
-            # its token budget: the final steps need budget-aware
-            # completion steering (_steer_allowed), which only the
-            # host-masked path evaluates. Parity with K=1 holds —
-            # unsteered steps mask identically on both paths.
-            near_budget = any(
-                self._is_guided(s)
-                and (s.sampling_params.max_tokens
-                     - len(s.generated_token_ids))
-                <= k_steps + self.GUIDED_STEER_BOUND
-                for s in seqs
-            )
-            if not near_budget:
-                guided_tables = self._device_guided_tables(seqs)
+        with self.phases.span("pack"):
+            tokens = [s.all_token_ids[-1] for s in seqs]
+            positions = [s.num_tokens - 1 for s in seqs]
+            tables = [s.block_table for s in seqs]
+            ctx_lens = [s.num_tokens for s in seqs]
+            # guided lanes ride the fused multi-step scan via on-device
+            # TokenDFA tables (structured.TokenDFA — outlines-style
+            # FSM-index compilation); only constraints too large to
+            # compile under budget fall back to the host-masked
+            # single-step path below
+            guided_tables = None
+            needs_guided = any(self._is_guided(s) for s in seqs)
+            if needs_guided and k_steps > 1:
+                # leave the fused path when any guided lane is close to
+                # its token budget: the final steps need budget-aware
+                # completion steering (_steer_allowed), which only the
+                # host-masked path evaluates. Parity with K=1 holds —
+                # unsteered steps mask identically on both paths.
+                near_budget = any(
+                    self._is_guided(s)
+                    and (s.sampling_params.max_tokens
+                         - len(s.generated_token_ids))
+                    <= k_steps + self.GUIDED_STEER_BOUND
+                    for s in seqs
+                )
+                if not near_budget:
+                    guided_tables = self._device_guided_tables(seqs)
         if k_steps > 1 and (not needs_guided
                             or guided_tables is not None):
-            temps, top_ps, top_ks, min_ps, keys, needs_pen = (
-                self._sampling_arrays(seqs)
-            )
-            # token-count state rides on device through the scan; only
-            # the compact generated-id lists cross the bus
-            penalties = self._penalty_args(seqs) if needs_pen else None
-            want_lp = any(
-                s.sampling_params.logprobs is not None for s in seqs
-            )
-            bias = self._bias_arrays(seqs)
-            will_async = (
-                self._async_decode and penalties is None
-                and guided_tables is None and bias is None
-            )
-            # device-side stop masks: not on async-chained rounds —
-            # the chain commits round N+1 before round N's valid
-            # counts are known, so a mid-round freeze would leave
-            # the chained dispatch running on a pad token
-            stop = (
-                self._stop_arrays(seqs)
-                if self._device_stop and not will_async else None
-            )
-            staged_kw = {}
-            st = self._staged_decode
-            self._staged_decode = None
-            if st is not None:
-                if (penalties is None and bias is None
-                        and guided_tables is None
-                        and st["fp"] == self._stage_fingerprint(
-                            seqs, k_steps)):
-                    # the prediction held: dispatch chained on the
-                    # previous round's on-device tokens with the
-                    # pre-uploaded packed buffer — zero serial h2d
-                    staged_kw = {"staged": st["handle"]}
-                    tokens = st["chain_tokens"]
-                    self._staged_hits_total += 1
-                else:
-                    self._staged_misses_total += 1
+            with self.phases.span("pack"):
+                temps, top_ps, top_ks, min_ps, keys, needs_pen = (
+                    self._sampling_arrays(seqs)
+                )
+                # token-count state rides on device through the scan; only
+                # the compact generated-id lists cross the bus
+                penalties = self._penalty_args(seqs) if needs_pen else None
+                want_lp = any(
+                    s.sampling_params.logprobs is not None for s in seqs
+                )
+                bias = self._bias_arrays(seqs)
+                will_async = (
+                    self._async_decode and penalties is None
+                    and guided_tables is None and bias is None
+                )
+                # device-side stop masks: not on async-chained rounds —
+                # the chain commits round N+1 before round N's valid
+                # counts are known, so a mid-round freeze would leave
+                # the chained dispatch running on a pad token
+                stop = (
+                    self._stop_arrays(seqs)
+                    if self._device_stop and not will_async else None
+                )
+                staged_kw = {}
+                st = self._staged_decode
+                self._staged_decode = None
+                if st is not None:
+                    if (penalties is None and bias is None
+                            and guided_tables is None
+                            and st["fp"] == self._stage_fingerprint(
+                                seqs, k_steps)):
+                        # the prediction held: dispatch chained on the
+                        # previous round's on-device tokens with the
+                        # pre-uploaded packed buffer — zero serial h2d
+                        staged_kw = {"staged": st["handle"]}
+                        tokens = st["chain_tokens"]
+                        self._staged_hits_total += 1
+                    else:
+                        self._staged_misses_total += 1
             # fused on-device decode+sample loop: K tokens per
             # dispatch, ONE device->host fetch
             # stop rides a conditional kwarg: the multihost runner
             # wrapper replays host token lists and knows no stop
             # masks (and _device_stop is already off there)
             stop_kw = {"stop": stop} if stop is not None else {}
+            rnd = self._begin_round("decode", k_steps, len(seqs), 0)
             ys = self.runner.decode_multi(
                 tokens, positions, tables, ctx_lens, k_steps,
                 temps, top_ps, top_ks, keys, min_ps=min_ps,
@@ -1807,7 +1874,7 @@ class LLMEngine:
                 # following round before fetching this one
                 self._pending_decode = {
                     "seqs": seqs, "toks": toks_dev, "k": k_steps,
-                    "lps": lps_dev,
+                    "lps": lps_dev, "round": rnd,
                 }
                 return stepped
             if (self._prefetch_decode and penalties is None
@@ -1851,56 +1918,60 @@ class LLMEngine:
                     "chain_tokens": toks_dev[-1],
                 }
             # materialize the round's results in one place so the d2h
-            # cost lands in the fetch phase meter like other fetches
-            tf = time.perf_counter()
-            # stackcheck: disable=device-sync-transitive — the ONE
-            # metered multi-token fetch for this decode round
-            toks_np = np.asarray(toks_dev)
-            lps_np = (
-                # stackcheck: disable=device-sync-transitive — logprob
-                # arrays exist only when lanes requested them; they
-                # ride this round's metered fetch with the tokens
-                tuple(np.asarray(a) for a in lps_dev)
-                if lps_dev else None
-            )
-            valid_np = (
-                # stackcheck: disable=device-sync-transitive —
-                # validity mask rides the same metered fetch as the
-                # tokens it gates
-                np.asarray(valid_dev)
-                if valid_dev is not None else None
-            )
-            self.runner._phase_add("fetch", time.perf_counter() - tf)
+            # cost lands in the fetch phase like other fetches
+            with self.phases.span("fetch"):
+                # stackcheck: disable=device-sync-transitive — the ONE
+                # metered multi-token fetch for this decode round
+                toks_np = np.asarray(toks_dev)
+                lps_np = (
+                    # stackcheck: disable=device-sync-transitive —
+                    # logprob arrays exist only when lanes requested
+                    # them; they ride this round's metered fetch with
+                    # the tokens
+                    tuple(np.asarray(a) for a in lps_dev)
+                    if lps_dev else None
+                )
+                valid_np = (
+                    # stackcheck: disable=device-sync-transitive —
+                    # validity mask rides the same metered fetch as the
+                    # tokens it gates
+                    np.asarray(valid_dev)
+                    if valid_dev is not None else None
+                )
             self._apply_multi_tokens(
                 seqs, toks_np, k_steps, lps=lps_np, valid=valid_np,
             )
             stepped.extend(seqs)
         else:
+            self._begin_round("decode", 1, len(seqs), 0)
             logits = self.runner.decode(
                 tokens, positions, tables, ctx_lens,
                 lora_slots=[self._lora_slot(s) for s in seqs],
             )
-            sampled, used_logits = self._sample(
-                seqs, logits[: len(seqs)], return_logits=True
-            )
-            # stackcheck: disable=device-sync-transitive — the ONE
-            # intended per-round materialization of the sampled-from
-            # logits; logprob entries below index into it row by row
-            used_logits = np.asarray(used_logits)
-            for i, (seq, token) in enumerate(zip(seqs, sampled)):
-                seq.num_computed_tokens = seq.num_tokens
-                entry = None
-                if seq.sampling_params.logprobs is not None:
-                    entry = self._host_logprob_entry(
-                        used_logits[i], int(token),
-                        seq.sampling_params.logprobs,
-                    )
-                self._append_token(seq, int(token), entry)
-                stepped.append(seq)
-            # adaptive K can size a round down to 1 (single token
-            # left / admission pressure): those rounds belong in the
-            # tpu:decode_k histogram too
-            self._note_decode_round(seqs, 1)
+            # host sampling reads the logits: the round's fetch
+            with self.phases.span("fetch"):
+                sampled, used_logits = self._sample(
+                    seqs, logits[: len(seqs)], return_logits=True
+                )
+                # stackcheck: disable=device-sync-transitive — the ONE
+                # intended per-round materialization of the sampled-from
+                # logits; logprob entries below index into it row by row
+                used_logits = np.asarray(used_logits)
+            with self.phases.span("apply"):
+                for i, (seq, token) in enumerate(zip(seqs, sampled)):
+                    seq.num_computed_tokens = seq.num_tokens
+                    entry = None
+                    if seq.sampling_params.logprobs is not None:
+                        entry = self._host_logprob_entry(
+                            used_logits[i], int(token),
+                            seq.sampling_params.logprobs,
+                        )
+                    self._append_token(seq, int(token), entry)
+                    stepped.append(seq)
+                # adaptive K can size a round down to 1 (single token
+                # left / admission pressure): those rounds belong in the
+                # tpu:decode_k histogram too
+                self._note_decode_round(seqs, 1)
         return stepped
 
     # -- unified ragged prefill+decode rounds -------------------------------
@@ -2014,52 +2085,53 @@ class LLMEngine:
         for w in works:
             if w.seq.metrics.first_scheduled_time is None:
                 w.seq.metrics.first_scheduled_time = now
-        phase_snap = (
-            self.runner.phase_snapshot() if self._tl_enabled else None
-        )
-        seqs_w = [w.seq for w in works]
-        pf_sampling = self._sampling_arrays(seqs_w)[:5]
-        pf_chunks = [
-            w.seq.prompt_token_ids[
-                w.chunk_start : w.chunk_start + w.chunk_len
+        phase_snap = self.phases.seconds() if self._tl_enabled else None
+        with self.phases.span("pack"):
+            seqs_w = [w.seq for w in works]
+            pf_sampling = self._sampling_arrays(seqs_w)[:5]
+            pf_chunks = [
+                w.seq.prompt_token_ids[
+                    w.chunk_start : w.chunk_start + w.chunk_len
+                ]
+                for w in works
             ]
-            for w in works
-        ]
-        pf_budgets = [
-            w.seq.num_prompt_tokens - (w.chunk_start + w.chunk_len)
-            for w in works
-        ]
-        temps, top_ps, top_ks, min_ps, keys, needs_pen = (
-            self._sampling_arrays(seqs)
-        )
-        penalties = self._penalty_args(seqs) if needs_pen else None
-        want_lp = any(
-            s.sampling_params.logprobs is not None for s in seqs
-        )
-        bias = self._bias_arrays(seqs)
-        stop = self._stop_arrays(seqs) if self._device_stop else None
-        tokens = [s.all_token_ids[-1] for s in seqs]
-        staged_kw = {}
-        st = self._staged_ragged
-        self._staged_ragged = None
-        if st is not None:
-            if (penalties is None and bias is None
-                    and guided_tables is None
-                    and st["fp"] == self._ragged_fingerprint(
-                        works, seqs, k_steps)):
-                # the prediction held: chain the decode lanes on the
-                # previous round's on-device tokens with the
-                # pre-uploaded lane-typed buffer — zero serial h2d
-                staged_kw = {"staged": st["handle"]}
-                tokens = st["chain_tokens"]
-                self._ragged_staged_hits_total += 1
-            else:
-                # lane-mix / state drift since the stage (and the
-                # runner additionally validates the staged buffer's
-                # total layout length): a counted staging miss — the
-                # dispatch rebuilds + uploads serially, never errors
-                self._ragged_staged_misses_total += 1
+            pf_budgets = [
+                w.seq.num_prompt_tokens - (w.chunk_start + w.chunk_len)
+                for w in works
+            ]
+            temps, top_ps, top_ks, min_ps, keys, needs_pen = (
+                self._sampling_arrays(seqs)
+            )
+            penalties = self._penalty_args(seqs) if needs_pen else None
+            want_lp = any(
+                s.sampling_params.logprobs is not None for s in seqs
+            )
+            bias = self._bias_arrays(seqs)
+            stop = self._stop_arrays(seqs) if self._device_stop else None
+            tokens = [s.all_token_ids[-1] for s in seqs]
+            staged_kw = {}
+            st = self._staged_ragged
+            self._staged_ragged = None
+            if st is not None:
+                if (penalties is None and bias is None
+                        and guided_tables is None
+                        and st["fp"] == self._ragged_fingerprint(
+                            works, seqs, k_steps)):
+                    # the prediction held: chain the decode lanes on the
+                    # previous round's on-device tokens with the
+                    # pre-uploaded lane-typed buffer — zero serial h2d
+                    staged_kw = {"staged": st["handle"]}
+                    tokens = st["chain_tokens"]
+                    self._ragged_staged_hits_total += 1
+                else:
+                    # lane-mix / state drift since the stage (and the
+                    # runner additionally validates the staged buffer's
+                    # total layout length): a counted staging miss — the
+                    # dispatch rebuilds + uploads serially, never errors
+                    self._ragged_staged_misses_total += 1
         stop_kw = {"stop": stop} if stop is not None else {}
+        self._begin_round(
+            "ragged", k_steps, len(seqs), sum(len(c) for c in pf_chunks))
         pf_sampled_dev, pf_logits_dev, ys = self.runner.ragged_dispatch(
             pf_chunks,
             [w.chunk_start for w in works],
@@ -2102,7 +2174,7 @@ class LLMEngine:
             w.seq.num_computed_tokens += w.chunk_len
             self._prompt_tokens_total += w.chunk_len
         if self._tl_enabled:
-            phases = self.runner.phase_delta(phase_snap)
+            group_phases = self.phases.delta(phase_snap)
             for w in works:
                 self.timeline.event(
                     w.seq.request_id, "prefill_chunk",
@@ -2113,11 +2185,13 @@ class LLMEngine:
                         "staged_hit": len(staged_kw) > 0,
                         "chained": False,
                         "group_size": len(works),
+                        "engine_round": self._event_round,
                         "ragged": True,
                         "prefill_lanes": len(works),
                         "decode_lanes": len(seqs),
                         **(
-                            {"group_phase_s": phases} if phases else {}
+                            {"group_phase_s": group_phases}
+                            if group_phases else {}
                         ),
                     },
                 )
@@ -2125,52 +2199,53 @@ class LLMEngine:
             (i, w) for i, w in enumerate(works) if w.is_last_chunk
         ]
         if finals:
-            tf = time.perf_counter()
-            # stackcheck: disable=device-sync-transitive — the ONE
-            # metered prefill-token fetch for this ragged round
-            toks_np = np.asarray(pf_sampled_dev)
-            self.runner._phase_add("fetch", time.perf_counter() - tf)
-            for i, w in finals:
-                tok = int(toks_np[i])
-                if tok < 0:
-                    # the device pins ONLY non-real lanes to the idle
-                    # sentinel; a real lane yielding it means the lane
-                    # packing drifted — fail this round loudly rather
-                    # than emitting a corrupt stream
-                    raise RuntimeError(
-                        f"ragged dispatch returned the idle-lane "
-                        f"sentinel for real prefill lane {i} "
-                        f"({w.seq.request_id})"
-                    )
-                entry = None
-                n = w.seq.sampling_params.logprobs
-                if n is not None:
-                    entry = self._host_logprob_entry(
-                        # stackcheck: disable=device-sync-transitive —
-                        # logprob rows materialize only for lanes that
-                        # requested them; this is their fetch point
-                        np.asarray(pf_logits_dev[i]), tok, n
-                    )
-                self._append_token(w.seq, tok, entry)
-                stepped.append(w.seq)
+            with self.phases.span("fetch"):
+                # stackcheck: disable=device-sync-transitive — the ONE
+                # metered prefill-token fetch for this ragged round
+                pf_toks_np = np.asarray(pf_sampled_dev)
+            with self.phases.span("apply"):
+                for i, w in finals:
+                    tok = int(pf_toks_np[i])
+                    if tok < 0:
+                        # the device pins ONLY non-real lanes to the
+                        # idle sentinel; a real lane yielding it means
+                        # the lane packing drifted — fail this round
+                        # loudly rather than emitting a corrupt stream
+                        raise RuntimeError(
+                            f"ragged dispatch returned the idle-lane "
+                            f"sentinel for real prefill lane {i} "
+                            f"({w.seq.request_id})"
+                        )
+                    entry = None
+                    n = w.seq.sampling_params.logprobs
+                    if n is not None:
+                        entry = self._host_logprob_entry(
+                            # stackcheck: disable=device-sync-transitive
+                            # — logprob rows materialize only for lanes
+                            # that requested them; their fetch point
+                            np.asarray(pf_logits_dev[i]), tok, n
+                        )
+                    self._append_token(w.seq, tok, entry)
+                    stepped.append(w.seq)
         # materialize the decode-lane results in one place so the d2h
-        # cost lands in the fetch phase meter like every other fetch
-        tf = time.perf_counter()
-        # stackcheck: disable=device-sync-transitive — the ONE metered
-        # multi-token fetch for this ragged round's decode lanes
-        toks_np = np.asarray(toks_dev)
-        lps_np = (
-            # stackcheck: disable=device-sync-transitive — logprob
-            # arrays exist only when lanes requested them; they ride
-            # this round's metered fetch with the tokens
-            tuple(np.asarray(a) for a in lps_dev) if lps_dev else None
-        )
-        valid_np = (
-            # stackcheck: disable=device-sync-transitive — validity
-            # mask rides the same metered fetch as the tokens it gates
-            np.asarray(valid_dev) if valid_dev is not None else None
-        )
-        self.runner._phase_add("fetch", time.perf_counter() - tf)
+        # cost lands in the fetch phase like every other fetch
+        with self.phases.span("fetch"):
+            # stackcheck: disable=device-sync-transitive — the ONE
+            # metered multi-token fetch for this ragged round's decode
+            # lanes
+            toks_np = np.asarray(toks_dev)
+            lps_np = (
+                # stackcheck: disable=device-sync-transitive — logprob
+                # arrays exist only when lanes requested them; they ride
+                # this round's metered fetch with the tokens
+                tuple(np.asarray(a) for a in lps_dev) if lps_dev else None
+            )
+            valid_np = (
+                # stackcheck: disable=device-sync-transitive — validity
+                # mask rides the same metered fetch as the tokens it
+                # gates
+                np.asarray(valid_dev) if valid_dev is not None else None
+            )
         self._apply_multi_tokens(
             seqs, toks_np, k_steps,
             lps=lps_np,
@@ -2476,9 +2551,9 @@ class LLMEngine:
             if w.seq.metrics.first_scheduled_time is None:
                 w.seq.metrics.first_scheduled_time = now
         staged_hit = False
-        phase_snap = (
-            self.runner.phase_snapshot() if self._tl_enabled else None
-        )
+        phase_snap = self.phases.seconds() if self._tl_enabled else None
+        self._begin_round(
+            "prefill", 0, 0, sum(w.chunk_len for w in works))
         staged_kw = {}
         if staged is not None:
             if staged["fp"] == self._prefill_fingerprint(works):
@@ -2523,18 +2598,16 @@ class LLMEngine:
                 sampling=(t1, p1, k1, m1, keys1),
                 prompt_lp_targets=[int(x) for x in tgts],
             )
-            tf = time.perf_counter()
-            # stackcheck: disable=device-sync-transitive — the metered
-            # guided/bias lane fetch: token + prompt-logprob triplet
-            tok_of[i] = int(np.asarray(token_dev))
-            chosen, tv, ti = (
-                # stackcheck: disable=device-sync-transitive — same
-                # metered fetch, prompt-logprob arrays for this lane
-                np.asarray(chosen), np.asarray(tv), np.asarray(ti)
-            )
-            self.runner._phase_add(
-                "fetch", time.perf_counter() - tf
-            )
+            with self.phases.span("fetch"):
+                # stackcheck: disable=device-sync-transitive — the
+                # metered guided/bias lane fetch: token + prompt-logprob
+                # triplet
+                tok_of[i] = int(np.asarray(token_dev))
+                chosen, tv, ti = (
+                    # stackcheck: disable=device-sync-transitive — same
+                    # metered fetch, prompt-logprob arrays for this lane
+                    np.asarray(chosen), np.asarray(tv), np.asarray(ti)
+                )
             last_logits[i] = logits
             self._accumulate_prompt_lps(
                 seq, w.chunk_start, tgts, chosen, tv, ti,
@@ -2589,13 +2662,11 @@ class LLMEngine:
                     last_logits[i] = logits[j]
             # ONE fetch for the whole std group's sampled tokens
             if any(w.is_last_chunk for w in sworks):
-                tf = time.perf_counter()
-                # stackcheck: disable=device-sync-transitive — the ONE
-                # metered fetch for the std prefill group (see above)
-                toks_np = np.asarray(tokens_dev)
-                self.runner._phase_add(
-                    "fetch", time.perf_counter() - tf
-                )
+                with self.phases.span("fetch"):
+                    # stackcheck: disable=device-sync-transitive — the
+                    # ONE metered fetch for the std prefill group (see
+                    # above)
+                    toks_np = np.asarray(tokens_dev)
                 for j, (i, _) in enumerate(std_works):
                     tok_of[i] = int(toks_np[j])
         for i, w in enumerate(works):
@@ -2606,7 +2677,7 @@ class LLMEngine:
             # per-phase wall time (delta over the runner's tpu:prefill_*
             # counters — the group shares one dispatch, so the phases
             # are group-level, tagged with the group size)
-            phases = self.runner.phase_delta(phase_snap)
+            group_phases = self.phases.delta(phase_snap)
             for w in works:
                 self.timeline.event(
                     w.seq.request_id, "prefill_chunk",
@@ -2617,13 +2688,15 @@ class LLMEngine:
                         "staged_hit": staged_hit,
                         "chained": chained,
                         "group_size": len(works),
+                        "engine_round": self._event_round,
                         # lane-mix attribution (unified-round contract:
                         # every prefill event says what rode with it —
                         # the split path rides alone)
                         "prefill_lanes": len(works),
                         "decode_lanes": 0,
                         **(
-                            {"group_phase_s": phases} if phases else {}
+                            {"group_phase_s": group_phases}
+                            if group_phases else {}
                         ),
                     },
                 )
@@ -2631,52 +2704,53 @@ class LLMEngine:
             (i, w) for i, w in enumerate(works) if w.is_last_chunk
         ]
         if finals:
-            # first tokens were sampled ON DEVICE inside the prefill
-            # program — the host fetches (s_pad,) int32 instead of
-            # (s_pad, vocab) f32 logits. Only a post-preemption
-            # sequence with active penalties (its generated history
-            # is folded into the prompt, so penalty counts are
-            # non-empty at the "first" token) needs the logits
-            # (_needs_host_first_sample — shared with the ragged
-            # round's fusability gate).
-            pen = [(i, w) for i, w in finals
-                   if self._needs_host_first_sample(w.seq)]
-            clean = [(i, w) for i, w in finals
-                     if not self._needs_host_first_sample(w.seq)]
-            if clean:
-                for i, w in clean:
-                    entry = None
-                    n = w.seq.sampling_params.logprobs
-                    if n is not None:
-                        entry = self._host_logprob_entry(
-                            # stackcheck: disable=device-sync-transitive
-                            # — logprob rows materialize only for lanes
-                            # that requested them; their fetch point
-                            np.asarray(last_logits[i]),
-                            tok_of[i], n,
-                        )
-                    self._append_token(w.seq, tok_of[i], entry)
-                    stepped.append(w.seq)
-            if pen:
-                fl = jnp.stack([last_logits[i] for i, _ in pen])
-                sampled, used_logits = self._sample(
-                    [w.seq for _, w in pen], fl, return_logits=True
-                )
-                # stackcheck: disable=device-sync-transitive — the ONE
-                # intended materialization of penalized-lane logits;
-                # logprob entries below index into it row by row
-                used_logits = np.asarray(used_logits)
-                for j, ((i, w), token) in enumerate(
-                    zip(pen, sampled)
-                ):
-                    entry = None
-                    n = w.seq.sampling_params.logprobs
-                    if n is not None:
-                        entry = self._host_logprob_entry(
-                            used_logits[j], int(token), n
-                        )
-                    self._append_token(w.seq, int(token), entry)
-                    stepped.append(w.seq)
+            with self.phases.span("apply"):
+                # first tokens were sampled ON DEVICE inside the prefill
+                # program — the host fetches (s_pad,) int32 instead of
+                # (s_pad, vocab) f32 logits. Only a post-preemption
+                # sequence with active penalties (its generated history
+                # is folded into the prompt, so penalty counts are
+                # non-empty at the "first" token) needs the logits
+                # (_needs_host_first_sample — shared with the ragged
+                # round's fusability gate).
+                pen = [(i, w) for i, w in finals
+                       if self._needs_host_first_sample(w.seq)]
+                clean = [(i, w) for i, w in finals
+                         if not self._needs_host_first_sample(w.seq)]
+                if clean:
+                    for i, w in clean:
+                        entry = None
+                        n = w.seq.sampling_params.logprobs
+                        if n is not None:
+                            entry = self._host_logprob_entry(
+                                # stackcheck: disable=device-sync-transitive
+                                # — logprob rows materialize only for lanes
+                                # that requested them; their fetch point
+                                np.asarray(last_logits[i]),
+                                tok_of[i], n,
+                            )
+                        self._append_token(w.seq, tok_of[i], entry)
+                        stepped.append(w.seq)
+                if pen:
+                    fl = jnp.stack([last_logits[i] for i, _ in pen])
+                    sampled, used_logits = self._sample(
+                        [w.seq for _, w in pen], fl, return_logits=True
+                    )
+                    # stackcheck: disable=device-sync-transitive — the ONE
+                    # intended materialization of penalized-lane logits;
+                    # logprob entries below index into it row by row
+                    used_logits = np.asarray(used_logits)
+                    for j, ((i, w), token) in enumerate(
+                        zip(pen, sampled)
+                    ):
+                        entry = None
+                        n = w.seq.sampling_params.logprobs
+                        if n is not None:
+                            entry = self._host_logprob_entry(
+                                used_logits[j], int(token), n
+                            )
+                        self._append_token(w.seq, int(token), entry)
+                        stepped.append(w.seq)
         return stepped
 
     # -- speculative decoding (prompt-lookup n-gram drafts) ----------------
@@ -2775,6 +2849,8 @@ class LLMEngine:
         starts = np.asarray(
             [len(s.generated_token_ids) for s in seqs], np.int64
         )
+        self._begin_round(
+            "verify", 1, len(seqs), sum(len(c) for c in chunks))
         sampled = self.runner.verify_batch(
             chunks,
             start_positions=[s.num_tokens - 1 for s in seqs],
@@ -2806,7 +2882,8 @@ class LLMEngine:
                 self._append_token(seq, int(t))
             if self._tl_enabled and not seq.finished:
                 self.timeline.decode_round(
-                    seq.request_id, len(new_tokens)
+                    seq.request_id, len(new_tokens),
+                    attrs={"engine_round": self._event_round},
                 )
             stepped.append(seq)
         self.last_step_kind = "decode"
@@ -2815,24 +2892,25 @@ class LLMEngine:
     def _finalize_stepped(
         self, stepped: list[Sequence]
     ) -> list[RequestOutput]:
-        outputs: list[RequestOutput] = []
-        for seq in stepped:
-            self._register_full_blocks(seq)
-            out = self._make_output(seq)
-            outputs.append(out)
-            if seq.finished:
-                seq.metrics.finished_time = time.time()
-                self._finished_total += 1
-                self.scheduler.free_finished(seq)
-                self._seqs.pop(seq.request_id, None)
-                self.timeline.finish(
-                    seq.request_id, seq.finish_reason,
-                    {
-                        "generated_tokens": len(seq.generated_token_ids),
-                        "preemptions": seq.metrics.num_preemptions,
-                    } if self._tl_enabled else None,
-                )
-        return outputs
+        with self.phases.span("apply"):
+            outputs: list[RequestOutput] = []
+            for seq in stepped:
+                self._register_full_blocks(seq)
+                out = self._make_output(seq)
+                outputs.append(out)
+                if seq.finished:
+                    seq.metrics.finished_time = time.time()
+                    self._finished_total += 1
+                    self.scheduler.free_finished(seq)
+                    self._seqs.pop(seq.request_id, None)
+                    self.timeline.finish(
+                        seq.request_id, seq.finish_reason,
+                        {
+                            "generated_tokens": len(seq.generated_token_ids),
+                            "preemptions": seq.metrics.num_preemptions,
+                        } if self._tl_enabled else None,
+                    )
+            return outputs
 
     # -- internals ---------------------------------------------------------
     def _sampling_arrays(
@@ -3392,7 +3470,7 @@ class LLMEngine:
                     {"ttft_s": round(
                         seq.metrics.first_token_time
                         - seq.metrics.arrival_time, 6,
-                    )},
+                    ), "engine_round": self._event_round},
                 )
         seq.append_token(int(token))
         self._generation_tokens_total += 1
@@ -3628,18 +3706,10 @@ class LLMEngine:
             requests_finished_total=self._finished_total,
             spec_draft_tokens_total=self._spec_drafts_total,
             spec_accepted_tokens_total=self._spec_accepted_total,
-            prefill_prep_seconds_total=(
-                self.runner.prefill_phase_s["prep"]
-            ),
-            prefill_h2d_seconds_total=(
-                self.runner.prefill_phase_s["h2d"]
-            ),
-            prefill_dispatch_seconds_total=(
-                self.runner.prefill_phase_s["dispatch"]
-            ),
-            prefill_fetch_seconds_total=(
-                self.runner.prefill_phase_s["fetch"]
-            ),
+            engine_phases=self.phases.pairs(),
+            attn_context_tokens=tuple(self.runner.attn_context_tokens),
+            program_stages=phases.program_stage_pairs(),
+            program_cache_hits_total=phases.PROGRAM_CACHE_HITS[0],
             prefill_staged_hits_total=self._pf_staged_hits_total,
             prefill_staged_misses_total=self._pf_staged_misses_total,
             prefill_chained_chunks_total=self._pf_chained_chunks_total,

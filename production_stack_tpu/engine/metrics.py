@@ -19,11 +19,36 @@ from prometheus_client import (
     Histogram,
 )
 
+from prometheus_client.core import SummaryMetricFamily
+
 from production_stack_tpu.engine.outputs import EngineStatsSnapshot
+from production_stack_tpu.tracing import ENGINE_PHASES
 
 _LATENCY_BUCKETS = (
     0.01, 0.025, 0.05, 0.1, 0.2, 0.4, 0.8, 1.5, 3.0, 6.0, 12.0, 30.0, 60.0,
 )
+
+
+class _PairTotals:
+    """Running (sum, count) totals exposed as `<name>_sum` and
+    `<name>_count`: a Summary that is SET from totals kept where the
+    work happens, not observed sample by sample."""
+
+    def __init__(self, model_name: str, docs: dict[str, str]):
+        self.model_name = model_name
+        self.docs = docs
+        self.values: dict[str, tuple] = {n: (0.0, 0) for n in docs}
+
+    def set(self, name: str, pair) -> None:
+        self.values[name] = (pair[0], pair[1])
+
+    def collect(self):
+        for name, doc in self.docs.items():
+            total, count = self.values[name]
+            fam = SummaryMetricFamily(name, doc, labels=["model_name"])
+            fam.add_metric([self.model_name], count_value=count,
+                           sum_value=total)
+            yield fam
 
 
 class EngineMetrics:
@@ -84,26 +109,60 @@ class EngineMetrics:
             "vllm:spec_decode_num_accepted_tokens",
             "Speculative draft tokens accepted", label, registry=reg,
         )
-        # pipelined-prefill attribution (tpu-native): wall seconds per
-        # phase of the prefill dispatch path + staging effectiveness,
-        # so a dashboard can see WHERE prefill time goes (prep / h2d /
-        # dispatch / fetch) and whether the h2d overlap is landing
-        self.prefill_prep_s = Counter(
-            "tpu:prefill_prep_seconds", "Prefill host-prep wall time",
+        # the round seen from inside (tracing/phases.py): every sample
+        # below is a (seconds, count) pair exposed as `<name>_sum` /
+        # `<name>_count`, so a mean over a window is delta-sum over
+        # delta-count as for the histograms. What a dashboard or the
+        # benchmark must tell apart has a NAME of its own, not a label
+        # value (readers sum a sample over its label sets).
+        self.pairs = _PairTotals(model_name, {
+            **{
+                f"tpu:engine_phase_{p}_seconds":
+                    f"Wall time of the engine round's `{p}` phase "
+                    f"(tracing/phases.py; `engine.{p}` in a profiler "
+                    "trace)"
+                for p in ENGINE_PHASES
+            },
+            "tpu:event_loop_lock_wait_seconds":
+                "Time the server's event-loop thread spent acquiring "
+                "the engine lock (admission, abort, stats): every SSE "
+                "stream of the replica stands still meanwhile",
+            "tpu:admit_lock_wait_seconds":
+                "Per request: event-loop wait for the engine lock "
+                "before add_request (not in tpu:request_queue_seconds, "
+                "which starts after it)",
+            "tpu:attn_context_tokens":
+                "Context tokens the attention calls of a dispatched "
+                "round had to read once (sum) per round (count): each "
+                "decode lane's context at each fused step, each "
+                "prefill chunk's end context",
+            **{
+                f"tpu:program_{st}_seconds": doc
+                for st, doc in (
+                    ("trace", "jaxpr tracing of programs (jax.monitoring)"),
+                    ("lower", "lowering of programs to MLIR"),
+                    ("compile", "backend compile of programs, or their "
+                     "retrieval from the persistent cache"),
+                )
+            },
+        })
+        reg.register(self.pairs)
+        self.program_cache_hits = Counter(
+            "tpu:program_cache_hits",
+            "Programs served by jax's persistent compilation cache",
             label, registry=reg,
         )
-        self.prefill_h2d_s = Counter(
-            "tpu:prefill_h2d_seconds",
-            "Prefill host->device upload wall time", label, registry=reg,
+        # HTTP handler entry -> first content chunk of a streamed
+        # completion/chat written, on the server's clock: parse,
+        # template, tokenize, lock wait, queue, prefill, the hop back to
+        # the loop, detokenize, write (vllm:time_to_first_token_seconds
+        # starts inside the engine lock and ends on the step thread)
+        self.server_ttft = Histogram(
+            "tpu:server_ttft_seconds",
+            "Streamed request: handler entry -> first content chunk "
+            "written", label, buckets=_LATENCY_BUCKETS, registry=reg,
         )
-        self.prefill_dispatch_s = Counter(
-            "tpu:prefill_dispatch_seconds",
-            "Prefill dispatch-enqueue wall time", label, registry=reg,
-        )
-        self.prefill_fetch_s = Counter(
-            "tpu:prefill_fetch_seconds",
-            "Prefill device->host fetch wall time", label, registry=reg,
-        )
+        self.server_ttft.labels(model_name)  # exported from boot
         self.prefill_staged_hits = Counter(
             "tpu:prefill_staged_hits",
             "Prefill dispatches served from a pre-uploaded staged "
@@ -399,18 +458,21 @@ class EngineMetrics:
             max(0, s.spec_accepted_tokens_total
                 - prev.spec_accepted_tokens_total)
         )
-        self.prefill_prep_s.labels(m).inc(max(
-            0.0, s.prefill_prep_seconds_total
-            - prev.prefill_prep_seconds_total))
-        self.prefill_h2d_s.labels(m).inc(max(
-            0.0, s.prefill_h2d_seconds_total
-            - prev.prefill_h2d_seconds_total))
-        self.prefill_dispatch_s.labels(m).inc(max(
-            0.0, s.prefill_dispatch_seconds_total
-            - prev.prefill_dispatch_seconds_total))
-        self.prefill_fetch_s.labels(m).inc(max(
-            0.0, s.prefill_fetch_seconds_total
-            - prev.prefill_fetch_seconds_total))
+        for name, pair in s.engine_phases.items():
+            self.pairs.set(f"tpu:engine_phase_{name}_seconds", pair)
+        if s.loop_lock_waits:
+            waits = s.loop_lock_waits
+            self.pairs.set("tpu:event_loop_lock_wait_seconds", (
+                sum(p[0] for p in waits.values()),
+                sum(p[1] for p in waits.values())))
+            self.pairs.set(
+                "tpu:admit_lock_wait_seconds", waits["admit_lock_wait"])
+        self.pairs.set("tpu:attn_context_tokens", s.attn_context_tokens)
+        for stage, pair in s.program_stages.items():
+            self.pairs.set(f"tpu:program_{stage}_seconds", pair)
+        self.program_cache_hits.labels(m).inc(max(
+            0, s.program_cache_hits_total
+            - prev.program_cache_hits_total))
         self.prefill_staged_hits.labels(m).inc(max(
             0, s.prefill_staged_hits_total
             - prev.prefill_staged_hits_total))

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import functools
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +33,7 @@ from production_stack_tpu.models import llama
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as xla_attn
 from production_stack_tpu.parallel import sharding as sharding_rules
+from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
 
 logger = init_logger(__name__)
@@ -63,6 +63,24 @@ RAGGED_TQ = 8
 
 def _ceil_tq(n: int) -> int:
     return -(-n // RAGGED_TQ) * RAGGED_TQ
+
+
+# The kinds of program the runner builds, one list for three uses: the
+# name a builder's function is jitted under (`jit_<kind>` on a profiler
+# trace's `XLA Modules` line and in the HLO module's name, instead of
+# twelve programs all called `jit_step`), the `kind` label of
+# tpu:compile_events_total, and the `kind` of an `engine.build` span.
+PROGRAM_KINDS = (
+    "decode", "decode_multi", "ragged", "ragged_rows", "prefill",
+    "prefill_rows", "prefill_batch", "verify", "embed", "kv_import",
+)
+
+
+def jit_program(kind: str, fn, **jit_kw):
+    """`jax.jit(fn)` under the name of its kind."""
+    assert kind in PROGRAM_KINDS, kind
+    fn.__name__ = fn.__qualname__ = kind
+    return jax.jit(fn, **jit_kw)
 
 
 class ModelRunner:
@@ -260,17 +278,21 @@ class ModelRunner:
         self.prefill_pipeline = (
             bool(config.prefill_pipeline) and self.mesh is None
         )
-        # per-phase prefill wall time (seconds) + dispatch counts, fed
-        # to /metrics and the bench attribution slots: prep = host array
-        # build, h2d = upload enqueue (staged uploads overlap compute
-        # but still count — they are real link work), dispatch = jitted
-        # call enqueue, fetch = device->host reads (engine-side)
-        self.prefill_phase_s = {
-            "prep": 0.0, "h2d": 0.0, "dispatch": 0.0, "fetch": 0.0,
-        }
-        self.prefill_phase_n = {
-            "prep": 0, "h2d": 0, "dispatch": 0, "fetch": 0,
-        }
+        # the round's phase spans (tracing/phases.py): wall seconds and
+        # counts per phase, fed to /metrics (tpu:engine_phase_*), the
+        # request timeline and the bench attribution slots, and written
+        # into the profiler's trace while one is taken. The runner times
+        # pack = host array build, h2d = upload enqueue (staged uploads
+        # overlap compute but still count — they are real link work) and
+        # dispatch = the jitted call's enqueue, once per step program
+        # dispatched; the engine times schedule, fetch and apply on the
+        # same timer
+        self.phases = phases.PhaseTimer(phases.ENGINE_PHASES, "engine.")
+        # context tokens the attention calls of the dispatched rounds
+        # had to read once, and the rounds counted (tpu:attn_context_
+        # tokens): host integers the dispatch already holds
+        self.attn_context_tokens = [0, 0]
+        phases.install_program_listeners()
 
         # jit caches keyed by bucket tuple
         self._prefill_fns: dict[tuple[int, int], object] = {}
@@ -313,10 +335,16 @@ class ModelRunner:
             ],
         }
 
-    def _note_compile(self, kind: str) -> None:
-        """Count one program-variant build (jit cache miss)."""
+    def _note_compile(self, kind: str, key=None):
+        """Count one program-variant build (jit cache miss). Returns the
+        `engine.build` annotation (kind + cache key) to hold around the
+        program's first call, where jax traces, lowers and compiles it:
+        a child of that call's `engine.dispatch` in a profiler trace,
+        and nothing outside a profiler session."""
+        assert kind in PROGRAM_KINDS, kind
         self.compile_events[kind] = self.compile_events.get(kind, 0) + 1
         self.compile_events_total += 1
+        return phases.annotation("engine.build", kind=kind, key=repr(key))
 
     # -- sizing -----------------------------------------------------------
     def _resolve_num_blocks(self) -> int:
@@ -555,24 +583,26 @@ class ModelRunner:
         return tokens, positions_dev, write_slots, gather_slots, t_pad, c_pad
 
     # -- pipelined prefill: fused h2d buffer --------------------------------
-    def _phase_add(self, name: str, dt: float) -> None:
-        self.prefill_phase_s[name] += dt
-        self.prefill_phase_n[name] += 1
-
-    # -- per-dispatch phase attribution (request timelines) -----------------
-    # A snapshot/delta pair around one dispatch attributes its prep/h2d/
-    # dispatch/fetch wall time to the requests it served (the engine's
-    # prefill_chunk timeline events). Pure host dict copies: no device
-    # handle is touched, so the marked hot paths stay sync-free.
-    def phase_snapshot(self) -> dict[str, float]:
-        return dict(self.prefill_phase_s)
-
-    def phase_delta(self, snapshot: dict[str, float]) -> dict[str, float]:
-        return {
-            k: round(v - snapshot.get(k, 0.0), 6)
-            for k, v in self.prefill_phase_s.items()
-            if v - snapshot.get(k, 0.0) > 0.0
-        }
+    def _note_attn_context(
+        self, decode_lens=(), steps: int = 0, prefill_lens=(),
+    ) -> None:
+        """Count one dispatched round's attention reads: each decode
+        lane's context at each of its `steps` fused steps (context + i
+        at step i) and each prefill chunk's END context once, both cut
+        to the sliding window where the model has one. A lane that a
+        device stop freezes mid-round is counted to the round's end."""
+        k, n = steps, len(decode_lens)
+        w = self.model_config.sliding_window
+        if w is None:
+            tokens = (k * sum(decode_lens) + n * (k * (k - 1) // 2)
+                      + sum(prefill_lens))
+        else:
+            tokens = sum(
+                min(c + i, w) for c in decode_lens for i in range(k)
+            ) + sum(min(c, w) for c in prefill_lens)
+        cell = self.attn_context_tokens
+        cell[0] += tokens
+        cell[1] += 1
 
     @staticmethod
     def _layout_of(fields: list[tuple[str, tuple[int, ...]]]):
@@ -892,7 +922,8 @@ class ModelRunner:
     def _build_prefill_rows(self, r_pad: int, pc_pad: int):
         """Jitted ragged-rows packed prefill (kernel-mode variant of
         _build_prefill_batch; program key (r_pad, pc_pad))."""
-        return jax.jit(
+        return jit_program(
+            "prefill_rows",
             self._make_prefill_rows_step(r_pad, pc_pad),
             donate_argnums=(1, 2), **self._step_jit_kwargs(2),
         )
@@ -1003,15 +1034,13 @@ class ModelRunner:
         of sitting serially before the next one (prefill mirror of
         stage_decode_multi). Returns a handle for prefill(staged=...);
         the caller (engine) validates its fingerprint before use."""
-        t0 = time.perf_counter()
-        t_pad, c_pad, packed = self._fill_prefill_pack(
-            token_ids, start_pos, block_table, total_len,
-            sampling=sampling,
-        )
-        t1 = time.perf_counter()
-        self._phase_add("prep", t1 - t0)
-        handle = (("single", t_pad, c_pad), jax.device_put(packed))
-        self._phase_add("h2d", time.perf_counter() - t1)
+        with self.phases.span("pack"):
+            t_pad, c_pad, packed = self._fill_prefill_pack(
+                token_ids, start_pos, block_table, total_len,
+                sampling=sampling,
+            )
+        with self.phases.span("h2d"):
+            handle = (("single", t_pad, c_pad), jax.device_put(packed))
         return handle
 
     # stackcheck: hot-path
@@ -1024,23 +1053,21 @@ class ModelRunner:
         sampling=None,
     ) -> tuple:
         """Packed-group variant of stage_prefill."""
-        t0 = time.perf_counter()
-        if self.ragged_kernel and self.prefill_pipeline:
-            r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
-                chunks, start_positions, block_tables, total_lens,
-                sampling=sampling,
-            )
-            key = ("rows", r_pad, pc_pad)
-        else:
-            s_pad, t_pad, c_pad, packed = self._fill_packed_prefill_pack(
-                chunks, start_positions, block_tables, total_lens,
-                sampling=sampling,
-            )
-            key = ("packed", s_pad, t_pad, c_pad)
-        t1 = time.perf_counter()
-        self._phase_add("prep", t1 - t0)
-        handle = (key, jax.device_put(packed))
-        self._phase_add("h2d", time.perf_counter() - t1)
+        with self.phases.span("pack"):
+            if self.ragged_kernel and self.prefill_pipeline:
+                r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
+                    chunks, start_positions, block_tables, total_lens,
+                    sampling=sampling,
+                )
+                key = ("rows", r_pad, pc_pad)
+            else:
+                s_pad, t_pad, c_pad, packed = self._fill_packed_prefill_pack(
+                    chunks, start_positions, block_tables, total_lens,
+                    sampling=sampling,
+                )
+                key = ("packed", s_pad, t_pad, c_pad)
+        with self.phases.span("h2d"):
+            handle = (key, jax.device_put(packed))
         return handle
 
     def _build_prefill(self, t_pad: int, c_pad: int,
@@ -1101,7 +1128,8 @@ class ModelRunner:
 
         jit_kw = self._step_jit_kwargs(2 if not want_prompt_lp else 5)
         if not self.prefill_pipeline:
-            return jax.jit(step, donate_argnums=(1, 2), **jit_kw)
+            return jit_program(
+                "prefill", step, donate_argnums=(1, 2), **jit_kw)
 
         # pipelined variant: ONE fused i32 operand instead of ~8 small
         # h2d transfers (layout shared with the host build,
@@ -1141,7 +1169,8 @@ class ModelRunner:
                 **plp_kw,
             )
 
-        return jax.jit(packed_step, donate_argnums=(1, 2), **jit_kw)
+        return jit_program(
+            "prefill", packed_step, donate_argnums=(1, 2), **jit_kw)
 
     def _build_verify_batch(self, s_pad: int, t_pad: int, c_pad: int):
         """Batched speculative verification: s_pad lanes' draft chunks
@@ -1181,8 +1210,8 @@ class ModelRunner:
                                     min_p=min_ps)
             return sampled, kc, vc
 
-        return jax.jit(step, donate_argnums=(1, 2),
-                       **self._step_jit_kwargs(1))
+        return jit_program("verify", step, donate_argnums=(1, 2),
+                           **self._step_jit_kwargs(1))
 
     def verify_batch(
         self,
@@ -1227,35 +1256,40 @@ class ModelRunner:
         ).astype(np.uint32)
 
         key = (s_pad, t_pad, c_pad)
+        build = phases.NO_SPAN
         if key not in self._verify_batch_fns:
             logger.info(
                 "compiling batched verify step s=%d t=%d ctx=%d",
                 s_pad, t_pad, c_pad,
             )
-            self._note_compile("verify")
+            build = self._note_compile("verify", key)
             self._verify_batch_fns[key] = self._build_verify_batch(
                 s_pad, t_pad, c_pad
             )
         fn = self._verify_batch_fns[key]
         lora_kw = self._packed_lora_kwargs(lora_slots, n, s_pad, t_pad)
-        sampled, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            jnp.asarray(tokens.reshape(-1)),
-            jnp.asarray(positions_dev.reshape(-1)),
-            jnp.asarray(write_slots.reshape(-1)),
-            jnp.asarray(tables),
-            jnp.asarray(q_starts),
-            jnp.asarray(tl_full),
-            jnp.asarray(temps.reshape(-1)),
-            jnp.asarray(top_ps.reshape(-1)),
-            jnp.asarray(top_ks.reshape(-1)),
-            jnp.asarray(min_ps_g.reshape(-1)),
-            jnp.asarray(keys.reshape(-1, 2)),
-            **lora_kw,
-        )
-        return np.asarray(sampled).reshape(s_pad, t_pad)[:n]
+        self._note_attn_context(prefill_lens=total_lens)
+        with self.phases.span("dispatch"), build:
+            sampled, self.k_cache, self.v_cache = fn(
+                self.params,
+                self.k_cache,
+                self.v_cache,
+                jnp.asarray(tokens.reshape(-1)),
+                jnp.asarray(positions_dev.reshape(-1)),
+                jnp.asarray(write_slots.reshape(-1)),
+                jnp.asarray(tables),
+                jnp.asarray(q_starts),
+                jnp.asarray(tl_full),
+                jnp.asarray(temps.reshape(-1)),
+                jnp.asarray(top_ps.reshape(-1)),
+                jnp.asarray(top_ks.reshape(-1)),
+                jnp.asarray(min_ps_g.reshape(-1)),
+                jnp.asarray(keys.reshape(-1, 2)),
+                **lora_kw,
+            )
+        with self.phases.span("fetch"):
+            out = np.asarray(sampled).reshape(s_pad, t_pad)[:n]
+        return out
 
     def _packed_host_prep(
         self,
@@ -1492,11 +1526,13 @@ class ModelRunner:
         the fused-buffer variant under the prefill pipeline)."""
         jit_kw = self._step_jit_kwargs(2)
         if not self.prefill_pipeline:
-            return jax.jit(
+            return jit_program(
+                "prefill_batch",
                 self._make_prefill_batch_step(s_pad, t_pad),
                 donate_argnums=(1, 2), **jit_kw,
             )
-        return jax.jit(
+        return jit_program(
+            "prefill_batch",
             self._make_prefill_batch_packed(s_pad, t_pad, c_pad),
             donate_argnums=(1, 2), **jit_kw,
         )
@@ -1578,7 +1614,8 @@ class ModelRunner:
             )
             return logits, kc, vc
 
-        return jax.jit(step, donate_argnums=(1, 2), **self._step_jit_kwargs())
+        return jit_program("decode", step, donate_argnums=(1, 2),
+                           **self._step_jit_kwargs())
 
     def _decode_pack_layout(self, b: int, c_pad: int, chained: bool,
                             guided: bool = False,
@@ -2042,7 +2079,8 @@ class ModelRunner:
                             bias_cap: int = 0,
                             stop_cap: int | None = None):
         """Jitted fused-K decode program (see _make_decode_multi_step)."""
-        return jax.jit(
+        return jit_program(
+            "decode_multi",
             self._make_decode_multi_step(
                 b, c_pad, k_steps, use_penalties=use_penalties,
                 want_logprobs=want_logprobs, chained=chained,
@@ -2162,83 +2200,77 @@ class ModelRunner:
                     and staged[0] == ("single", t_pad, c_pad)):
                 packed_dev = staged[1]  # upload already overlapped
             if packed_dev is None:
-                t0 = time.perf_counter()
-                t_pad, c_pad, packed = self._fill_prefill_pack(
-                    token_ids, start_pos, block_table, total_len,
-                    sampling=sampling,
-                    prompt_lp_targets=prompt_lp_targets,
+                with self.phases.span("pack"):
+                    t_pad, c_pad, packed = self._fill_prefill_pack(
+                        token_ids, start_pos, block_table, total_len,
+                        sampling=sampling,
+                        prompt_lp_targets=prompt_lp_targets,
+                    )
+                with self.phases.span("h2d"):
+                    packed_dev = jnp.asarray(packed)
+            fn, build = self._prefill_fn(t_pad, c_pad, want_plp)
+            self._note_attn_context(prefill_lens=(total_len,))
+            with self.phases.span("dispatch"), build:
+                ys = fn(
+                    self.params, self.k_cache, self.v_cache, packed_dev,
+                    **lora_kw,
                 )
-                t1 = time.perf_counter()
-                self._phase_add("prep", t1 - t0)
-                packed_dev = jnp.asarray(packed)
-                self._phase_add("h2d", time.perf_counter() - t1)
-            key = (t_pad, c_pad, "plp") if want_plp else (t_pad, c_pad)
-            if key not in self._prefill_fns:
-                logger.info("compiling prefill step t=%d ctx=%d plp=%s",
-                            t_pad, c_pad, want_plp)
-                self._note_compile("prefill")
-                self._prefill_fns[key] = self._build_prefill(
-                    t_pad, c_pad, want_prompt_lp=want_plp
-                )
-            t2 = time.perf_counter()
-            ys = self._prefill_fns[key](
-                self.params, self.k_cache, self.v_cache, packed_dev,
-                **lora_kw,
-            )
-            self._phase_add("dispatch", time.perf_counter() - t2)
             self.k_cache, self.v_cache = ys[-2], ys[-1]
             return ys[:-2]
         t = len(token_ids)
-        t0 = time.perf_counter()
-        (tokens, positions_dev, write_slots, gather_slots,
-         t_pad, c_pad) = self._prefill_host_prep(
-            token_ids, block_table, start_pos, total_len
-        )
+        with self.phases.span("pack"):
+            (tokens, positions_dev, write_slots, gather_slots,
+             t_pad, c_pad) = self._prefill_host_prep(
+                token_ids, block_table, start_pos, total_len
+            )
+            temps, top_ps, top_ks, min_ps, keys = self._sampling_args(
+                1, sampling
+            )
+            plp_kw = {}
+            if want_plp:
+                tg = np.full((t_pad,), -1, np.int32)
+                tg[: len(prompt_lp_targets)] = prompt_lp_targets
+                plp_kw = {"targets": jnp.asarray(tg)}
+        with self.phases.span("h2d"):
+            args = (
+                jnp.asarray(tokens),
+                jnp.asarray(positions_dev),
+                jnp.asarray(write_slots),
+                jnp.asarray(gather_slots),
+                jnp.int32(total_len),
+                jnp.int32(t - 1),
+                jnp.asarray(temps),
+                jnp.asarray(top_ps),
+                jnp.asarray(top_ks),
+                jnp.asarray(min_ps),
+                jnp.asarray(keys),
+            )
+        fn, build = self._prefill_fn(t_pad, c_pad, want_plp)
+        self._note_attn_context(prefill_lens=(total_len,))
+        with self.phases.span("dispatch"), build:
+            ys = fn(
+                self.params,
+                self.k_cache,
+                self.v_cache,
+                *args,
+                **plp_kw,
+                **lora_kw,
+            )
+        self.k_cache, self.v_cache = ys[-2], ys[-1]
+        return ys[:-2]
+
+    def _prefill_fn(self, t_pad: int, c_pad: int, want_plp: bool):
+        """(program, build annotation) of a single-sequence prefill."""
         key = (t_pad, c_pad, "plp") if want_plp else (t_pad, c_pad)
+        build = phases.NO_SPAN
         if key not in self._prefill_fns:
             logger.info("compiling prefill step t=%d ctx=%d plp=%s",
                         t_pad, c_pad, want_plp)
-            self._note_compile("prefill")
+            build = self._note_compile("prefill", key)
             self._prefill_fns[key] = self._build_prefill(
                 t_pad, c_pad, want_prompt_lp=want_plp
             )
-        fn = self._prefill_fns[key]
-        temps, top_ps, top_ks, min_ps, keys = self._sampling_args(
-            1, sampling
-        )
-        plp_kw = {}
-        if want_plp:
-            tg = np.full((t_pad,), -1, np.int32)
-            tg[: len(prompt_lp_targets)] = prompt_lp_targets
-            plp_kw = {"targets": jnp.asarray(tg)}
-        t1 = time.perf_counter()
-        self._phase_add("prep", t1 - t0)
-        args = (
-            jnp.asarray(tokens),
-            jnp.asarray(positions_dev),
-            jnp.asarray(write_slots),
-            jnp.asarray(gather_slots),
-            jnp.int32(total_len),
-            jnp.int32(t - 1),
-            jnp.asarray(temps),
-            jnp.asarray(top_ps),
-            jnp.asarray(top_ks),
-            jnp.asarray(min_ps),
-            jnp.asarray(keys),
-        )
-        t2 = time.perf_counter()
-        self._phase_add("h2d", t2 - t1)
-        ys = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            *args,
-            **plp_kw,
-            **lora_kw,
-        )
-        self._phase_add("dispatch", time.perf_counter() - t2)
-        self.k_cache, self.v_cache = ys[-2], ys[-1]
-        return ys[:-2]
+        return self._prefill_fns[key], build
 
     def prefill_batch(
         self,
@@ -2268,34 +2300,33 @@ class ModelRunner:
                     and staged[0] == ("rows", r_pad, pc_pad)):
                 packed_dev = staged[1]  # upload already overlapped
             if packed_dev is None:
-                t0 = time.perf_counter()
-                r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
-                    chunks, start_positions, block_tables, total_lens,
-                    sampling=sampling,
-                )
-                t1 = time.perf_counter()
-                self._phase_add("prep", t1 - t0)
-                packed_dev = jnp.asarray(packed)
-                self._phase_add("h2d", time.perf_counter() - t1)
+                with self.phases.span("pack"):
+                    r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
+                        chunks, start_positions, block_tables, total_lens,
+                        sampling=sampling,
+                    )
+                with self.phases.span("h2d"):
+                    packed_dev = jnp.asarray(packed)
             key = ("rows", r_pad, pc_pad)
+            build = phases.NO_SPAN
             if key not in self._prefill_batch_fns:
                 logger.info(
                     "compiling ragged-rows prefill step rows=%d ctx=%d",
                     r_pad, pc_pad,
                 )
-                self._note_compile("prefill_rows")
+                build = self._note_compile("prefill_rows", key)
                 self._prefill_batch_fns[key] = self._build_prefill_rows(
                     r_pad, pc_pad
                 )
             lora_kw = self._rows_lora_kwargs(lora_slots, chunks, r_pad)
-            t2 = time.perf_counter()
-            sampled, logits, self.k_cache, self.v_cache = (
-                self._prefill_batch_fns[key](
-                    self.params, self.k_cache, self.v_cache,
-                    packed_dev, **lora_kw,
+            self._note_attn_context(prefill_lens=total_lens)
+            with self.phases.span("dispatch"), build:
+                sampled, logits, self.k_cache, self.v_cache = (
+                    self._prefill_batch_fns[key](
+                        self.params, self.k_cache, self.v_cache,
+                        packed_dev, **lora_kw,
+                    )
                 )
-            )
-            self._phase_add("dispatch", time.perf_counter() - t2)
             return sampled, logits
         if self.prefill_pipeline:
             s_pad = next_pow2(max(n, 1))
@@ -2306,92 +2337,81 @@ class ModelRunner:
                     and staged[0] == ("packed", s_pad, t_pad, c_pad)):
                 packed_dev = staged[1]  # upload already overlapped
             if packed_dev is None:
-                t0 = time.perf_counter()
-                s_pad, t_pad, c_pad, packed = (
-                    self._fill_packed_prefill_pack(
-                        chunks, start_positions, block_tables,
-                        total_lens, sampling=sampling,
+                with self.phases.span("pack"):
+                    s_pad, t_pad, c_pad, packed = (
+                        self._fill_packed_prefill_pack(
+                            chunks, start_positions, block_tables,
+                            total_lens, sampling=sampling,
+                        )
                     )
-                )
-                t1 = time.perf_counter()
-                self._phase_add("prep", t1 - t0)
-                packed_dev = jnp.asarray(packed)
-                self._phase_add("h2d", time.perf_counter() - t1)
-            key = (s_pad, t_pad, c_pad)
-            if key not in self._prefill_batch_fns:
-                logger.info(
-                    "compiling packed prefill step s=%d t=%d ctx=%d",
-                    s_pad, t_pad, c_pad,
-                )
-                self._note_compile("prefill_batch")
-                self._prefill_batch_fns[key] = self._build_prefill_batch(
-                    s_pad, t_pad, c_pad
-                )
+                with self.phases.span("h2d"):
+                    packed_dev = jnp.asarray(packed)
+            fn, build = self._prefill_batch_fn(s_pad, t_pad, c_pad)
             lora_kw = self._packed_lora_kwargs(
                 lora_slots, n, s_pad, t_pad
             )
-            t2 = time.perf_counter()
-            sampled, logits, self.k_cache, self.v_cache = (
-                self._prefill_batch_fns[key](
+            self._note_attn_context(prefill_lens=total_lens)
+            with self.phases.span("dispatch"), build:
+                sampled, logits, self.k_cache, self.v_cache = fn(
                     self.params, self.k_cache, self.v_cache,
                     packed_dev, **lora_kw,
                 )
-            )
-            self._phase_add("dispatch", time.perf_counter() - t2)
             return sampled, logits
-        t0 = time.perf_counter()
-        (s_pad, t_pad, c_pad, tokens, positions_dev, write_slots,
-         q_starts, tl_full, tables) = self._packed_host_prep(
-            chunks, start_positions, block_tables, total_lens
-        )
-        last_rows = np.zeros((s_pad,), dtype=np.int32)
-        for s, ids in enumerate(chunks):
-            last_rows[s] = s * t_pad + (len(ids) - 1)
-        for s in range(n, s_pad):
-            last_rows[s] = s * t_pad
+        with self.phases.span("pack"):
+            (s_pad, t_pad, c_pad, tokens, positions_dev, write_slots,
+             q_starts, tl_full, tables) = self._packed_host_prep(
+                chunks, start_positions, block_tables, total_lens
+            )
+            last_rows = np.zeros((s_pad,), dtype=np.int32)
+            for s, ids in enumerate(chunks):
+                last_rows[s] = s * t_pad + (len(ids) - 1)
+            for s in range(n, s_pad):
+                last_rows[s] = s * t_pad
+            lora_kw = self._packed_lora_kwargs(lora_slots, n, s_pad, t_pad)
+            temps, top_ps, top_ks, min_ps, keys = self._sampling_args(
+                s_pad, sampling
+            )
+        with self.phases.span("h2d"):
+            args = (
+                jnp.asarray(tokens.reshape(-1)),
+                jnp.asarray(positions_dev.reshape(-1)),
+                jnp.asarray(write_slots.reshape(-1)),
+                jnp.asarray(tables),
+                jnp.asarray(q_starts),
+                jnp.asarray(tl_full),
+                jnp.asarray(last_rows),
+                jnp.asarray(temps),
+                jnp.asarray(top_ps),
+                jnp.asarray(top_ks),
+                jnp.asarray(min_ps),
+                jnp.asarray(keys),
+            )
+        fn, build = self._prefill_batch_fn(s_pad, t_pad, c_pad)
+        self._note_attn_context(prefill_lens=total_lens)
+        with self.phases.span("dispatch"), build:
+            sampled, logits, self.k_cache, self.v_cache = fn(
+                self.params,
+                self.k_cache,
+                self.v_cache,
+                *args,
+                **lora_kw,
+            )
+        return sampled, logits
 
+    def _prefill_batch_fn(self, s_pad: int, t_pad: int, c_pad: int):
+        """(program, build annotation) of a packed-group prefill."""
         key = (s_pad, t_pad, c_pad)
+        build = phases.NO_SPAN
         if key not in self._prefill_batch_fns:
             logger.info(
                 "compiling packed prefill step s=%d t=%d ctx=%d",
                 s_pad, t_pad, c_pad,
             )
-            self._note_compile("prefill_batch")
+            build = self._note_compile("prefill_batch", key)
             self._prefill_batch_fns[key] = self._build_prefill_batch(
                 s_pad, t_pad, c_pad
             )
-        fn = self._prefill_batch_fns[key]
-        lora_kw = self._packed_lora_kwargs(lora_slots, n, s_pad, t_pad)
-        temps, top_ps, top_ks, min_ps, keys = self._sampling_args(
-            s_pad, sampling
-        )
-        t1 = time.perf_counter()
-        self._phase_add("prep", t1 - t0)
-        args = (
-            jnp.asarray(tokens.reshape(-1)),
-            jnp.asarray(positions_dev.reshape(-1)),
-            jnp.asarray(write_slots.reshape(-1)),
-            jnp.asarray(tables),
-            jnp.asarray(q_starts),
-            jnp.asarray(tl_full),
-            jnp.asarray(last_rows),
-            jnp.asarray(temps),
-            jnp.asarray(top_ps),
-            jnp.asarray(top_ks),
-            jnp.asarray(min_ps),
-            jnp.asarray(keys),
-        )
-        t2 = time.perf_counter()
-        self._phase_add("h2d", t2 - t1)
-        sampled, logits, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            *args,
-            **lora_kw,
-        )
-        self._phase_add("dispatch", time.perf_counter() - t2)
-        return sampled, logits
+        return self._prefill_batch_fns[key], build
 
     def precompile_prefill(
         self,
@@ -2614,41 +2634,43 @@ class ModelRunner:
         b = self.config.max_num_seqs
         c_pad = self._ctx_bucket(max(context_lens))
 
-        tokens = np.zeros((b,), dtype=np.int32)
-        tokens[:b_actual] = token_ids
-        pos = np.zeros((b,), dtype=np.int32)
-        pos[:b_actual] = positions
-        ctx = np.ones((b,), dtype=np.int32)
-        ctx[:b_actual] = context_lens
+        with self.phases.span("pack"):
+            tokens = np.zeros((b,), dtype=np.int32)
+            tokens[:b_actual] = token_ids
+            pos = np.zeros((b,), dtype=np.int32)
+            pos[:b_actual] = positions
+            ctx = np.ones((b,), dtype=np.int32)
+            ctx[:b_actual] = context_lens
 
-        write_slots = np.zeros((b,), dtype=np.int32)
-        for i in range(b_actual):
-            write_slots[i] = self._slots_for_positions(
-                block_tables[i], np.asarray([positions[i]])
-            )[0]
-        if self.attention_impl == "pallas":
-            # pallas path takes padded block tables (pages), not per-token
-            # gather slots
-            n_pages = c_pad // self.block_size
-            tables = np.stack(
-                [
-                    self._padded_block_table(
-                        block_tables[i] if i < b_actual else [], n_pages
-                    )
-                    for i in range(b)
-                ]
-            )
-        else:
-            tables = np.zeros((b, c_pad), dtype=np.int32)
+            write_slots = np.zeros((b,), dtype=np.int32)
             for i in range(b_actual):
-                tables[i] = self._gather_slots_for_table(
-                    block_tables[i], c_pad
+                write_slots[i] = self._slots_for_positions(
+                    block_tables[i], np.asarray([positions[i]])
+                )[0]
+            if self.attention_impl == "pallas":
+                # pallas path takes padded block tables (pages), not per-token
+                # gather slots
+                n_pages = c_pad // self.block_size
+                tables = np.stack(
+                    [
+                        self._padded_block_table(
+                            block_tables[i] if i < b_actual else [], n_pages
+                        )
+                        for i in range(b)
+                    ]
                 )
+            else:
+                tables = np.zeros((b, c_pad), dtype=np.int32)
+                for i in range(b_actual):
+                    tables[i] = self._gather_slots_for_table(
+                        block_tables[i], c_pad
+                    )
 
         key = (b, c_pad)
+        build = phases.NO_SPAN
         if key not in self._decode_fns:
             logger.info("compiling decode step b=%d ctx=%d", b, c_pad)
-            self._note_compile("decode")
+            build = self._note_compile("decode", key)
             self._decode_fns[key] = self._build_decode(b, c_pad)
         fn = self._decode_fns[key]
         lora_kw = {}
@@ -2660,17 +2682,19 @@ class ModelRunner:
                 "lora": self.lora_manager.buffers,
                 "lora_slots": jnp.asarray(slots),
             }
-        logits, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            jnp.asarray(tokens),
-            jnp.asarray(pos),
-            jnp.asarray(write_slots),
-            jnp.asarray(tables),
-            jnp.asarray(ctx),
-            **lora_kw,
-        )
+        with self.phases.span("h2d"):
+            args = (
+                jnp.asarray(tokens),
+                jnp.asarray(pos),
+                jnp.asarray(write_slots),
+                jnp.asarray(tables),
+                jnp.asarray(ctx),
+            )
+        self._note_attn_context(context_lens, 1)
+        with self.phases.span("dispatch"), build:
+            logits, self.k_cache, self.v_cache = fn(
+                self.params, self.k_cache, self.v_cache, *args, **lora_kw,
+            )
         return logits
 
     def _fill_decode_pack(
@@ -2795,12 +2819,17 @@ class ModelRunner:
         and validates the prediction before dispatching on it; a stale
         stage (ctx-bucket mismatch) is ignored by decode_multi.
         Returns (c_pad, device_array) for decode_multi(staged=...)."""
-        c_pad = self._ctx_bucket(max(context_lens) + max(0, steps - 1))
-        packed = self._fill_decode_pack(
-            c_pad, True, None, positions, block_tables, context_lens,
-            temps, top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
-        )
-        return (c_pad, jax.device_put(packed))
+        with self.phases.span("pack"):
+            c_pad = self._ctx_bucket(
+                max(context_lens) + max(0, steps - 1)
+            )
+            packed = self._fill_decode_pack(
+                c_pad, True, None, positions, block_tables, context_lens,
+                temps, top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
+            )
+        with self.phases.span("h2d"):
+            handle = (c_pad, jax.device_put(packed))
+        return handle
 
     def _decode_pen_kwargs(
         self, penalties: tuple | None, b: int, c_pad: int, b_actual: int
@@ -2974,11 +3003,14 @@ class ModelRunner:
             if int(staged[1].shape[0]) == want_total:
                 packed_dev = staged[1]
         if packed_dev is None:
-            packed_dev = jnp.asarray(self._fill_decode_pack(
-                c_pad, chained, token_ids, positions, block_tables,
-                context_lens, temps, top_ps, top_ks, keys,
-                min_ps=min_ps, guided_lanes=guided_lanes, stop=stop,
-            ))
+            with self.phases.span("pack"):
+                packed = self._fill_decode_pack(
+                    c_pad, chained, token_ids, positions, block_tables,
+                    context_lens, temps, top_ps, top_ks, keys,
+                    min_ps=min_ps, guided_lanes=guided_lanes, stop=stop,
+                )
+            with self.phases.span("h2d"):
+                packed_dev = jnp.asarray(packed)
 
         pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, b_actual)
         guided_kw, guided_shapes = self._decode_guided_kwargs(guided)
@@ -2988,6 +3020,7 @@ class ModelRunner:
         cache_key = (b, c_pad, steps, penalties is not None,
                      want_logprobs, chained, guided_shapes, bias_cap,
                      stop_cap)
+        build = phases.NO_SPAN
         if cache_key not in self._decode_multi_fns:
             logger.info(
                 "compiling multi-step decode b=%d ctx=%d k=%d pen=%s "
@@ -2995,7 +3028,7 @@ class ModelRunner:
                 b, c_pad, steps, penalties is not None, want_logprobs,
                 chained, guided_shapes, bias_cap, stop_cap,
             )
-            self._note_compile("decode_multi")
+            build = self._note_compile("decode_multi", cache_key)
             self._decode_multi_fns[cache_key] = self._build_decode_multi(
                 b, c_pad, steps, use_penalties=penalties is not None,
                 want_logprobs=want_logprobs, chained=chained,
@@ -3013,17 +3046,19 @@ class ModelRunner:
                 "lora_slots": jnp.asarray(slots),
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        ys, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            packed_dev,
-            **chained_kw,
-            **guided_kw,
-            **pen_kw,
-            **bias_kw,
-            **lora_kw,
-        )
+        self._note_attn_context(context_lens, steps)
+        with self.phases.span("dispatch"), build:
+            ys, self.k_cache, self.v_cache = fn(
+                self.params,
+                self.k_cache,
+                self.v_cache,
+                packed_dev,
+                **chained_kw,
+                **guided_kw,
+                **pen_kw,
+                **bias_kw,
+                **lora_kw,
+            )
         return ys
 
     # -- unified ragged prefill+decode dispatch ----------------------------
@@ -3176,7 +3211,7 @@ class ModelRunner:
             )
             return pf_sampled, pf_logits, ys, kc, vc
 
-        return jax.jit(step, donate_argnums=(1, 2))
+        return jit_program("ragged", step, donate_argnums=(1, 2))
 
     # -- single-kernel ragged-rows round -----------------------------------
     def _ragged_rows_pack_sizes(
@@ -3384,7 +3419,7 @@ class ModelRunner:
             )
             return pf_sampled, pf_logits, ys, kc, vc
 
-        return jax.jit(step, donate_argnums=(1, 2))
+        return jit_program("ragged_rows", step, donate_argnums=(1, 2))
 
     # stackcheck: hot-path — speculative h2d prefetch of the NEXT ragged
     # round's packed buffer: the upload overlaps the in-flight round's
@@ -3408,32 +3443,30 @@ class ModelRunner:
         ragged_dispatch(staged=...); the caller validates its
         fingerprint — and the dispatch validates the total layout
         length — before use."""
-        t0 = time.perf_counter()
-        c_pad = self._ctx_bucket(
-            max(context_lens) + max(0, steps - 1)
-        )
-        if self.ragged_kernel:
-            r_pad, pc_pad, packed = self._fill_ragged_rows_pack(
-                pf_chunks, pf_start_positions, pf_block_tables,
-                pf_total_lens, pf_sampling, c_pad, True, None,
-                positions, block_tables, context_lens, steps, temps,
-                top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
-                pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+        with self.phases.span("pack"):
+            c_pad = self._ctx_bucket(
+                max(context_lens) + max(0, steps - 1)
             )
-            key = ("rows", r_pad, pc_pad, c_pad)
-        else:
-            s_pad, t_pad, pc_pad, packed = self._fill_ragged_pack(
-                pf_chunks, pf_start_positions, pf_block_tables,
-                pf_total_lens, pf_sampling, c_pad, True, None,
-                positions, block_tables, context_lens, steps, temps,
-                top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
-                pf_budgets=pf_budgets, dec_budgets=dec_budgets,
-            )
-            key = ("ragged", s_pad, t_pad, pc_pad, c_pad)
-        t1 = time.perf_counter()
-        self._phase_add("prep", t1 - t0)
-        handle = (key, jax.device_put(packed))
-        self._phase_add("h2d", time.perf_counter() - t1)
+            if self.ragged_kernel:
+                r_pad, pc_pad, packed = self._fill_ragged_rows_pack(
+                    pf_chunks, pf_start_positions, pf_block_tables,
+                    pf_total_lens, pf_sampling, c_pad, True, None,
+                    positions, block_tables, context_lens, steps, temps,
+                    top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
+                    pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                )
+                key = ("rows", r_pad, pc_pad, c_pad)
+            else:
+                s_pad, t_pad, pc_pad, packed = self._fill_ragged_pack(
+                    pf_chunks, pf_start_positions, pf_block_tables,
+                    pf_total_lens, pf_sampling, c_pad, True, None,
+                    positions, block_tables, context_lens, steps, temps,
+                    top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
+                    pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                )
+                key = ("ragged", s_pad, t_pad, pc_pad, c_pad)
+        with self.phases.span("h2d"):
+            handle = (key, jax.device_put(packed))
         return handle
 
     # stackcheck: hot-path — ONE dispatch serves the whole lane-typed
@@ -3515,19 +3548,17 @@ class ModelRunner:
             if int(staged[1].shape[0]) == want_total:
                 packed_dev = staged[1]
         if packed_dev is None:
-            t0 = time.perf_counter()
-            _s, _t, _pc, packed = self._fill_ragged_pack(
-                pf_chunks, pf_start_positions, pf_block_tables,
-                pf_total_lens, pf_sampling, c_pad, chained, token_ids,
-                positions, block_tables, context_lens, steps, temps,
-                top_ps, top_ks, keys, min_ps=min_ps,
-                guided_lanes=guided_lanes, stop=stop,
-                pf_budgets=pf_budgets, dec_budgets=dec_budgets,
-            )
-            t1 = time.perf_counter()
-            self._phase_add("prep", t1 - t0)
-            packed_dev = jnp.asarray(packed)
-            self._phase_add("h2d", time.perf_counter() - t1)
+            with self.phases.span("pack"):
+                _s, _t, _pc, packed = self._fill_ragged_pack(
+                    pf_chunks, pf_start_positions, pf_block_tables,
+                    pf_total_lens, pf_sampling, c_pad, chained, token_ids,
+                    positions, block_tables, context_lens, steps, temps,
+                    top_ps, top_ks, keys, min_ps=min_ps,
+                    guided_lanes=guided_lanes, stop=stop,
+                    pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                )
+            with self.phases.span("h2d"):
+                packed_dev = jnp.asarray(packed)
 
         pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, b_actual)
         guided_kw, guided_shapes = self._decode_guided_kwargs(guided)
@@ -3537,6 +3568,7 @@ class ModelRunner:
         cache_key = (s_pad, t_pad, pc_pad, b, c_pad, steps,
                      penalties is not None, want_logprobs, chained,
                      guided_shapes, bias_cap, stop_cap)
+        build = phases.NO_SPAN
         if cache_key not in self._ragged_fns:
             logger.info(
                 "compiling ragged round s=%d t=%d pctx=%d b=%d ctx=%d "
@@ -3545,7 +3577,7 @@ class ModelRunner:
                 penalties is not None, want_logprobs, chained,
                 guided_shapes, bias_cap, stop_cap,
             )
-            self._note_compile("ragged")
+            build = self._note_compile("ragged", cache_key)
             self._ragged_fns[cache_key] = self._build_ragged(
                 s_pad, t_pad, pc_pad, b, c_pad, steps,
                 use_penalties=penalties is not None,
@@ -3568,19 +3600,19 @@ class ModelRunner:
                 "pf_lora_slots": pf_kw["lora_slots"],
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        t2 = time.perf_counter()
-        pf_sampled, pf_logits, ys, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            packed_dev,
-            **chained_kw,
-            **guided_kw,
-            **pen_kw,
-            **bias_kw,
-            **lora_kw,
-        )
-        self._phase_add("dispatch", time.perf_counter() - t2)
+        self._note_attn_context(context_lens, steps, pf_total_lens)
+        with self.phases.span("dispatch"), build:
+            pf_sampled, pf_logits, ys, self.k_cache, self.v_cache = fn(
+                self.params,
+                self.k_cache,
+                self.v_cache,
+                packed_dev,
+                **chained_kw,
+                **guided_kw,
+                **pen_kw,
+                **bias_kw,
+                **lora_kw,
+            )
         return pf_sampled, pf_logits, ys
 
     # stackcheck: hot-path — the single-kernel lane-typed round: ONE
@@ -3624,19 +3656,17 @@ class ModelRunner:
             if int(staged[1].shape[0]) == want_total:
                 packed_dev = staged[1]
         if packed_dev is None:
-            t0 = time.perf_counter()
-            _r, _pc, packed = self._fill_ragged_rows_pack(
-                pf_chunks, pf_start_positions, pf_block_tables,
-                pf_total_lens, pf_sampling, c_pad, chained, token_ids,
-                positions, block_tables, context_lens, steps, temps,
-                top_ps, top_ks, keys, min_ps=min_ps,
-                guided_lanes=guided_lanes, stop=stop,
-                pf_budgets=pf_budgets, dec_budgets=dec_budgets,
-            )
-            t1 = time.perf_counter()
-            self._phase_add("prep", t1 - t0)
-            packed_dev = jnp.asarray(packed)
-            self._phase_add("h2d", time.perf_counter() - t1)
+            with self.phases.span("pack"):
+                _r, _pc, packed = self._fill_ragged_rows_pack(
+                    pf_chunks, pf_start_positions, pf_block_tables,
+                    pf_total_lens, pf_sampling, c_pad, chained, token_ids,
+                    positions, block_tables, context_lens, steps, temps,
+                    top_ps, top_ks, keys, min_ps=min_ps,
+                    guided_lanes=guided_lanes, stop=stop,
+                    pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                )
+            with self.phases.span("h2d"):
+                packed_dev = jnp.asarray(packed)
 
         pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, b_actual)
         guided_kw, guided_shapes = self._decode_guided_kwargs(guided)
@@ -3646,6 +3676,7 @@ class ModelRunner:
         cache_key = ("rows", r_pad, pc_pad, b, c_pad, steps,
                      penalties is not None, want_logprobs, chained,
                      guided_shapes, bias_cap, stop_cap)
+        build = phases.NO_SPAN
         if cache_key not in self._ragged_fns:
             logger.info(
                 "compiling ragged-rows round rows=%d pctx=%d b=%d "
@@ -3655,7 +3686,7 @@ class ModelRunner:
                 want_logprobs, chained, guided_shapes, bias_cap,
                 stop_cap,
             )
-            self._note_compile("ragged_rows")
+            build = self._note_compile("ragged_rows", cache_key)
             self._ragged_fns[cache_key] = self._build_ragged_rows(
                 r_pad, pc_pad, b, c_pad, steps,
                 use_penalties=penalties is not None,
@@ -3680,19 +3711,19 @@ class ModelRunner:
                 "pf_lora_slots": jnp.asarray(pf_rows),
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        t2 = time.perf_counter()
-        pf_sampled, pf_logits, ys, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.k_cache,
-            self.v_cache,
-            packed_dev,
-            **chained_kw,
-            **guided_kw,
-            **pen_kw,
-            **bias_kw,
-            **lora_kw,
-        )
-        self._phase_add("dispatch", time.perf_counter() - t2)
+        self._note_attn_context(context_lens, steps, pf_total_lens)
+        with self.phases.span("dispatch"), build:
+            pf_sampled, pf_logits, ys, self.k_cache, self.v_cache = fn(
+                self.params,
+                self.k_cache,
+                self.v_cache,
+                packed_dev,
+                **chained_kw,
+                **guided_kw,
+                **pen_kw,
+                **bias_kw,
+                **lora_kw,
+            )
         return pf_sampled, pf_logits, ys
 
     def precompile_ragged(
@@ -3829,7 +3860,8 @@ class ModelRunner:
             keep = (positions < valid_len)[:, None].astype(jnp.float32)
             return jnp.sum(h * keep, axis=0), kc, vc
 
-        return jax.jit(step, donate_argnums=(1, 2), **self._step_jit_kwargs())
+        return jit_program("embed", step, donate_argnums=(1, 2),
+                           **self._step_jit_kwargs())
 
     def embed(self, token_ids: list[int], lora_slot: int = 0) -> np.ndarray:
         """Mean-pooled + L2-normalised final hidden state -> (hidden,) f32
@@ -3868,16 +3900,18 @@ class ModelRunner:
             positions = np.full((t_pad,), c_pad, np.int32)
             positions[: len(ids)] = np.arange(start, start + len(ids))
             key = (t_pad, c_pad)
+            build = phases.NO_SPAN
             if key not in self._embed_fns:
                 logger.info("compiling embed step t=%d ctx=%d", t_pad,
                             c_pad)
-                self._note_compile("embed")
+                build = self._note_compile("embed", key)
                 self._embed_fns[key] = self._build_embed(t_pad, c_pad)
-            part, kc, vc = self._embed_fns[key](
-                self.params, kc, vc, jnp.asarray(toks),
-                jnp.asarray(positions),
-                jnp.int32(start + len(ids)), jnp.int32(t), **lora_kw,
-            )
+            with build:
+                part, kc, vc = self._embed_fns[key](
+                    self.params, kc, vc, jnp.asarray(toks),
+                    jnp.asarray(positions),
+                    jnp.int32(start + len(ids)), jnp.int32(t), **lora_kw,
+                )
             pooled_sum += np.asarray(part, np.float64)
         pooled = pooled_sum / max(t, 1)
         norm = float(np.linalg.norm(pooled))
@@ -3946,8 +3980,8 @@ class ModelRunner:
             vc = vc.at[:, :, idx].set(flat[1])
             return kc, vc
 
-        return jax.jit(step, donate_argnums=(0, 1),
-                       **self._step_jit_kwargs(0))
+        return jit_program("kv_import", step, donate_argnums=(0, 1),
+                           **self._step_jit_kwargs(0))
 
     def _import_args(
         self, block_ids: list[int], src_cols: list[int], n_pad: int
@@ -4003,14 +4037,16 @@ class ModelRunner:
         bids, cols = self._import_args(block_ids, src_cols, n_pad)
         key = (n_pad, n_pad)
         fn = self._import_fns.get(key)
+        build = phases.NO_SPAN
         if fn is None:
             logger.info("compiling kv import n_src=%d n_dst=%d", *key)
-            self._note_compile("kv_import")
+            build = self._note_compile("kv_import", key)
             fn = self._import_fns[key] = self._build_import(*key)
-        self.k_cache, self.v_cache = fn(
-            self.k_cache, self.v_cache, jnp.asarray(bids),
-            jnp.asarray(cols), staged,
-        )
+        with build:
+            self.k_cache, self.v_cache = fn(
+                self.k_cache, self.v_cache, jnp.asarray(bids),
+                jnp.asarray(cols), staged,
+            )
 
     def precompile_kv_import(self, max_blocks: int) -> int:
         """Warm the donated import scatter's (n, n) pow2 diagonal up to

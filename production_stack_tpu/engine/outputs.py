@@ -45,15 +45,25 @@ class EngineStatsSnapshot:
     # speculative decoding acceptance (vllm:spec_decode_* role)
     spec_draft_tokens_total: int = 0
     spec_accepted_tokens_total: int = 0
-    # pipelined-prefill attribution: wall seconds per phase of the
-    # prefill dispatch path (prep = host array build, h2d = upload,
-    # dispatch = jitted-call enqueue, fetch = device->host token reads)
-    # plus staging effectiveness — tpu:prefill_* in /metrics and the
-    # bench.py prefill_phase_s detail slot
-    prefill_prep_seconds_total: float = 0.0
-    prefill_h2d_seconds_total: float = 0.0
-    prefill_dispatch_seconds_total: float = 0.0
-    prefill_fetch_seconds_total: float = 0.0
+    # the round seen from inside (tracing/phases.py), each a (seconds,
+    # count) pair: the step thread's phases (schedule, pack, h2d,
+    # dispatch, fetch, apply, idle, deliver) — tpu:engine_phase_*_seconds
+    # in /metrics, the bench.py phase detail slot — and the event-loop
+    # thread's waits for the engine lock by site (filled in by
+    # AsyncLLMEngine.stats) — tpu:event_loop_lock_wait_seconds,
+    # tpu:admit_lock_wait_seconds
+    engine_phases: dict = field(default_factory=dict)
+    loop_lock_waits: dict = field(default_factory=dict)
+    # (tokens, rounds): context tokens the attention calls of the
+    # dispatched rounds had to read once — tpu:attn_context_tokens
+    attn_context_tokens: tuple = (0, 0)
+    # the stages of building a program, from jax's monitoring events of
+    # this process: trace / lower / compile -> (seconds, count), and the
+    # persistent compile cache's hits — tpu:program_*_seconds,
+    # tpu:program_cache_hits
+    program_stages: dict = field(default_factory=dict)
+    program_cache_hits_total: int = 0
+    # prefill staging effectiveness — tpu:prefill_staged_* in /metrics
     prefill_staged_hits_total: int = 0
     prefill_staged_misses_total: int = 0
     prefill_chained_chunks_total: int = 0

@@ -224,6 +224,15 @@ class EngineServer:
                 status=400,
             )
 
+    def _observe_first_chunk(self, request: web.Request) -> None:
+        """tpu:server_ttft_seconds, once per streamed request: handler
+        entry -> its first content chunk written."""
+        t_enter = request.pop("t_enter", None)
+        if t_enter is not None:
+            self.metrics.server_ttft.labels(self.model_name).observe(
+                time.perf_counter() - t_enter
+            )
+
     def _observe_finish(self, out, arrival: float) -> None:
         m = out.metrics
         ttft = (
@@ -278,6 +287,7 @@ class EngineServer:
 
     # -- completions -------------------------------------------------------
     async def handle_completions(self, request: web.Request) -> web.StreamResponse:
+        request["t_enter"] = time.perf_counter()
         body, err = await self._json_body(request)
         if err is not None:
             return err
@@ -402,6 +412,7 @@ class EngineServer:
 
     # -- chat --------------------------------------------------------------
     async def handle_chat(self, request: web.Request) -> web.StreamResponse:
+        request["t_enter"] = time.perf_counter()
         body, err = await self._json_body(request)
         if err is not None:
             return err
@@ -854,6 +865,7 @@ class EngineServer:
                         lp_pos.get(idx, 0),
                     )
                     await send(chunk)
+                    self._observe_first_chunk(request)
                 elif kind == "finish":
                     remaining -= 1
                     if payload is not None:
@@ -928,6 +940,7 @@ class EngineServer:
                         out.new_logprobs, 0, lp_pos,
                     )
                     await send(chunk)
+                    self._observe_first_chunk(request)
             if final is not None:
                 self._observe_finish(final, arrival)
                 if chat:

@@ -241,9 +241,12 @@ def forward(
         if use_lora
         else (params["layers"], jnp.arange(cfg.num_layers))
     )
-    (h, k_cache, v_cache), _ = jax.lax.scan(
-        layer, (h, k_cache, v_cache), xs
-    )
+    # named scopes put `layers/...` and `lm_head/...` into the operation
+    # names a profiler trace shows (metadata only: the program is the same)
+    with jax.named_scope("layers"):
+        (h, k_cache, v_cache), _ = jax.lax.scan(
+            layer, (h, k_cache, v_cache), xs
+        )
 
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
                  cfg.norm_weight_offset)
@@ -255,9 +258,10 @@ def forward(
         if cfg.tie_word_embeddings
         else params["lm_head"]
     )
-    logits = jnp.dot(
-        h_sel, lm_head, preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("lm_head"):
+        logits = jnp.dot(
+            h_sel, lm_head, preferred_element_type=jnp.float32
+        )
     return logits, k_cache, v_cache
 
 

@@ -550,6 +550,7 @@ def ragged_paged_attention(
     )
     return pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, nq, d), q.dtype),
         interpret=interpret,
@@ -678,6 +679,7 @@ def paged_prefill_attention(
     )
     return pl.pallas_call(
         kernel,
+        name="paged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nq, d), q.dtype),
         interpret=interpret,
@@ -849,6 +851,7 @@ def paged_decode_attention(
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nq, d), q.dtype),
         interpret=interpret,

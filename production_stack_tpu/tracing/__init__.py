@@ -8,6 +8,10 @@
   preempt/resume → finish) feeding `/debug/requests` and the
   `engine_request` span.
 
+- ``phases``: the engine round's phase spans, on the profiler's clock
+  and in `/metrics` (imports jax: engine only, not re-exported here;
+  the phase NAMES below are jax-free for `engine/metrics.py`).
+
 See ``production_stack_tpu/tracing/README.md`` for the end-to-end flow
 and how to read a timeline when triaging a TTFT regression.
 """
@@ -40,8 +44,16 @@ from production_stack_tpu.tracing.timeline import (
     debug_requests_payload,
 )
 
+# The step thread's phases, in the order of a round; `idle` and
+# `deliver` belong to AsyncLLMEngine._step_loop, outside `engine.step`.
+ENGINE_PHASES = (
+    "schedule", "pack", "h2d", "dispatch", "fetch", "apply", "idle",
+    "deliver",
+)
+
 __all__ = [
     "DECODE_EVENT_EVERY",
+    "ENGINE_PHASES",
     "EXPORTERS",
     "NULL_RECORDER",
     "OTLP_FLUSH_INTERVAL_S",

@@ -106,6 +106,8 @@ def _queryable_names(families: dict[str, str]) -> set[str]:
         elif kind == "histogram":
             out.update((f"{name}_bucket", f"{name}_count",
                         f"{name}_sum"))
+        elif kind == "summary":
+            out.update((f"{name}_count", f"{name}_sum"))
         else:  # gauge / unknown
             out.add(name)
     return out
