@@ -132,15 +132,24 @@ class EngineConfig:
     # (ops/pallas_attention.ragged_paged_attention) whose grid
     # iterates a flattened query-row space with per-lane metadata in
     # scalar-prefetch SMEM: decode lanes contribute one row, prefill
-    # lanes their chunk's q-tiles, so ANY lane mix is one kernel
+    # lanes their chunk's 8-row q-tiles, so ANY lane mix is one kernel
     # launch with no cross-lane padding, and the packed-prefill /
     # ragged-round program variants key on padded ROW-count buckets
     # instead of the (group, chunk) lane-mix grid (fewer compiles =
-    # smaller cold-start tax). Tokens + logical KV are bit-identical
-    # to the composed per-lane kernels (tests/test_pallas_attention
-    # .py, tests/test_ragged_dispatch.py). Only effective with
-    # attention_impl=pallas; False (--no-ragged-kernel) keeps the
-    # composed per-lane kernels as the bench attribution control.
+    # smaller cold-start tax). Each segment walks its lane's context a
+    # KV BLOCK of N pages at a time (N picked at trace time from the
+    # per-chip kv heads, head_dim, cache dtype and page size: ~256 KiB
+    # of K a block, 128-512 keys on the lane axis), blocks aligned to
+    # absolute page indices so that the keys summed together never
+    # depend on the asker; a one-row segment (every decode lane) walks
+    # with its own g query rows a kv head, a prefill tile with all 8
+    # rows fused. The composed kernels call the same walk, so tokens
+    # are identical and logical KV equal to float32 rounding between
+    # the two modes' programs (tests/test_ragged_dispatch.py), and the
+    # kernels bit-identical per row (tests/test_pallas_attention.py).
+    # Only effective with attention_impl=pallas; False
+    # (--no-ragged-kernel) keeps the composed per-lane kernels as the
+    # bench attribution control.
     ragged_kernel: bool = True
     # compile every steady-state serving program shape at startup
     # (full-chunk + resume-tail prefill, packed groups, fused-K decode,

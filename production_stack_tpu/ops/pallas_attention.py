@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: paged GQA decode attention over the HBM KV cache.
+"""Pallas TPU kernels: paged GQA attention over the HBM KV cache.
 
 This is the hot op of the serving engine (the capability the reference
 stack gets from vLLM's PagedAttention CUDA kernels; our TPU-first design
@@ -12,16 +12,40 @@ replaces the gather-based XLA path in ops/attention.py on TPU):
   matching operand positions — Mosaic rejects the slot-major layout's
   mismatched-batch matmul outright ("batch dims must be equal" on v5e)
   and slot-major per-head slices break (nkv, d) tiling.
-- The cache stays in HBM (`memory_space=ANY`); the kernel DMAs one page
-  at a time into VMEM, double-buffered so the next page streams in
-  while the current one is on the MXU. The gathered (batch, ctx, ...)
-  context copy the XLA path materialises is never built — decode reads
-  each KV byte exactly once.
+- The cache stays in HBM (`memory_space=ANY`). ONE page walk (`_walk`)
+  serves all three kernels. It advances by a KV BLOCK of N pages: the N
+  page DMAs of a block land side by side in one (nkv, N*bs, d) VMEM
+  buffer, a ring of `_KV_RING` such buffers keeps the following blocks
+  in flight while the current one is computed, and one iteration does
+  one QK^T, one masked online-softmax update and one PV over the whole
+  block — N*bs keys on the lane axis (a multiple of 128 wherever the
+  page size allows), so the vregs and the MXU's tiles are full. N comes
+  from `_kv_block_pages`: what the kernel sees at trace time (kv heads
+  — per chip under tensor parallelism —, head_dim, cache dtype, page
+  size) against a VMEM budget. The gathered (batch, ctx, ...) context
+  copy the XLA path materialises is never built.
+- KV blocks sit at ABSOLUTE multiples of N pages: a walk that starts
+  inside a block (sliding window) or ends inside one (every context's
+  tail) masks what lies outside and points the copies of pages past
+  the walk's last page at that last page, so no table entry beyond a
+  lane's pages is ever used as an address. Which keys are summed
+  together therefore never depends on who asks, and a wholly masked
+  block is an exact no-op of the online softmax: that is what makes
+  the ragged kernel bit-identical per row to the composed ones.
+- Two tile heights on the sublane axis, one algorithm: a segment that
+  owns ONE query row (every decode lane) walks its context with
+  (nkv, g, d) queries — g rows a kv head, padded to the 8-row sublane
+  tile — while a segment with more rows (a prefill chunk's tile) fuses
+  them as (nkv, TQ*g, d). The ragged kernel picks per segment from the
+  segment's own `n_rows`.
+- Operands enter the MXU as stored: q, K and V in the cache dtype with
+  float32 accumulation, the softmax scale on the float32 scores. The
+  running max, sum, p and the accumulator are float32; for a bf16 cache
+  p goes through the PV product as three exact bf16 pieces stacked on
+  the row axis (`_pv`), so V is loaded once and p is never rounded.
 - The block table rides in scalar-prefetch SMEM (PrefetchScalarGridSpec)
   so page addresses are known before the body runs — this is the "dense
   tiling, not gather-heavy layout" recipe for TPU paged attention.
-- Online softmax (running max / sum / accumulator in f32) over pages,
-  one grid program per sequence.
 - The layer index is a scalar argument indexing the full cache, so jit
   never slices (= copies) a per-layer cache to feed the kernel.
 
@@ -40,11 +64,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 MASK_VALUE = -1e30
 
-# Query-tile rows of the unified ragged kernel's row blocks. 8 is the
-# f32 sublane minimum: decode lanes contribute ONE query row each, so a
-# bigger tile only grows the masked-row waste of decode-heavy mixes,
-# while prefill chunks (pow2 buckets >= 8) tile it exactly.
+# Query-tile rows of the unified ragged kernel's row blocks: the unit
+# the runner packs prefill chunks in (pow2 buckets >= 8 tile it
+# exactly). Decode lanes share a block, one row each, and are walked
+# one row at a time (see _attend), so the tile costs them nothing.
 RAGGED_TQ = 8
+
+# KV blocks resident in VMEM per kernel: one under the MXU, the others
+# streaming in behind it.
+_KV_RING = 3
 
 # Launch accounting: the model runner's `_attn` dispatch seam counts
 # every kernel CALL it stages while a program traces (counting inside
@@ -70,6 +98,214 @@ def _note_trace(kind: str) -> None:
     _LAUNCHES[kind] += 1
 
 
+def _kv_block_pages(nkv: int, d: int, itemsize: int, block_size: int) -> int:
+    """Pages per KV block: the most keys, in multiples of the 128-lane
+    vreg width from 128 to 512, whose ring of K and V buffers fits a
+    2 MiB VMEM budget — a K block of about 256 KiB whatever the head
+    count. It is the block's BYTES that pay for a walk step (the page
+    DMAs' issue, one wait, a loop turn, a softmax update), while every
+    key past a lane's last costs MXU time for nothing (an idle lane
+    ships ctx = 1 and still pays one block). Measured on a v5e
+    (PERF.md, Findings PR 25): 8 kv heads are fastest at 128 keys, 4 at
+    256, 2 (a tensor-parallel shard) at 512."""
+    budget = 2 * 2**20
+    per_key = 2 * _KV_RING * nkv * d * itemsize
+    keys = min(512, max(128, budget // per_key // 128 * 128))
+    return max(1, keys // block_size)
+
+
+def _pv(p, v):
+    """(nkv, rows, keys) float32 x (nkv, keys, d) -> (nkv, rows, d)
+    float32, with V entering the MXU as stored. For a bf16 V, p is split
+    into three bf16 pieces that sum to it exactly (3 x 8 significand
+    bits cover float32's 24) and the pieces ride the row axis of ONE
+    product: V is loaded into the MXU once, nothing is rounded."""
+    dims = (((2,), (1,)), ((0,), (0,)))
+    if v.dtype == p.dtype:
+        return jax.lax.dot_general(
+            p, v, dims, preferred_element_type=jnp.float32
+        )
+    rows = p.shape[1]
+    pieces = []
+    for _ in range(3):
+        piece = p.astype(v.dtype).astype(jnp.float32)
+        pieces.append(piece)
+        p = p - piece
+    o = jax.lax.dot_general(
+        jnp.concatenate(pieces, axis=1).astype(v.dtype), v, dims,
+        preferred_element_type=jnp.float32,
+    )
+    return o[:, :rows] + o[:, rows:2 * rows] + o[:, 2 * rows:]
+
+
+def _walk(
+    q,                  # (nkv, rows, d), cache dtype
+    q_pos,              # int32, broadcastable to (1, rows, 1): the
+                        # absolute position of each fused row's query
+    lo,                 # first key position any row attends
+    hi,                 # one past the last key position any row attends
+    page_of,            # logical page index -> physical page id (SMEM)
+    layer,
+    kv,                 # the program's cache refs and scratch:
+                        # k_cache, v_cache (L, nkv, slots, d) HBM;
+                        # k_buf, v_buf (_KV_RING, nkv, N*bs, d) VMEM;
+                        # DMA sems (_KV_RING, 2)
+    static,             # block_size, num_pages, scale, window
+):
+    """THE page walk: causal (and windowed) attention of `rows` fused
+    query rows over keys [lo, hi) of one sequence's pages, online
+    softmax over KV blocks of N pages at absolute multiples of N.
+    Returns the normalised (nkv, rows, d) float32 output. hi <= lo
+    walks nothing and starts no copy."""
+    k_cache_ref, v_cache_ref, k_buf, v_buf, sem = kv
+    ring, nkv, c, d = k_buf.shape
+    rows = q.shape[1]
+    bs, scale, window = static["block_size"], static["scale"], static["window"]
+    n = c // bs
+    b_lo = jax.lax.div(lo, c)
+    b_hi = jax.lax.div(hi + c - 1, c)
+    last_page = jax.lax.div(hi - 1, bs)
+    halves = ((k_cache_ref, k_buf), (v_cache_ref, v_buf))
+
+    def start(b):
+        # one strided DMA per page and cache: all heads' rows of the
+        # page's slot range (a tile-aligned slice of the head-major
+        # cache) into the page's place in the block buffer
+        @pl.when(b < b_hi)
+        def _():
+            slot = jax.lax.rem(b, ring)
+
+            def page(p, _):
+                row0 = page_of(jnp.minimum(b * n + p, last_page)) * bs
+                dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+                for which, (cache_ref, buf) in enumerate(halves):
+                    pltpu.make_async_copy(
+                        cache_ref.at[layer, :, pl.ds(row0, bs)],
+                        buf.at[slot, :, dst],
+                        sem.at[slot, which],
+                    ).start()
+                return 0
+
+            jax.lax.fori_loop(0, n, page, 0)
+
+    for ahead in range(ring - 1):
+        start(b_lo + ahead)
+
+    # the causal limit, cut to the walk's end: keys past `hi` inside the
+    # last block hold a repeated page
+    q_hi = jnp.minimum(q_pos, hi - 1)
+    key_of = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c), 2)
+
+    def body(b, carry):
+        m, l, acc = carry
+        start(b + ring - 1)
+        slot = jax.lax.rem(b, ring)
+        for which, (_, buf) in enumerate(halves):
+            # ONE wait per cache for the block's N page copies: they
+            # signal one semaphore, and a wait takes a copy's size only
+            pltpu.make_async_copy(
+                buf.at[slot], buf.at[slot], sem.at[slot, which]
+            ).wait()
+
+        # (nkv, rows, d) x (nkv, c, d) -> (nkv, rows, c), batched over
+        # kv heads
+        s = jax.lax.dot_general(
+            q, k_buf[slot].astype(q.dtype),
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        k_pos = b * c + key_of
+        valid = k_pos <= q_hi
+        if window is not None:
+            valid &= k_pos > q_pos - window
+        s = jnp.where(valid, s, MASK_VALUE)
+
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        return m_new, l_new, acc * corr + _pv(p, v_buf[slot])
+
+    m0 = jnp.full((nkv, rows, 1), MASK_VALUE, jnp.float32)
+    l0 = jnp.zeros((nkv, rows, 1), jnp.float32)
+    acc0 = jnp.zeros((nkv, rows, d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(b_lo, b_hi, body, (m0, l0, acc0))
+    return acc / jnp.maximum(l, 1e-30)
+
+
+def _attend(
+    q_ref,              # (TQ, nq, d) VMEM — the program's query tile
+    out_ref,            # (TQ, nq, d) VMEM
+    row0,               # first tile row the segment owns
+    n_rows,             # rows it owns; `one_row` promises n_rows == 1
+    qpos0,              # absolute position of row0's query
+    page_of,
+    layer,
+    kv,
+    static,
+    *,
+    one_row: bool,
+):
+    """One segment — `n_rows` contiguous positions of one sequence, in
+    tile rows [row0, row0 + n_rows) — attends its context and stores its
+    rows. Row r of the segment sees keys up to qpos0 + r (and down to
+    its window). Two tile heights: a one-row segment takes its row out
+    of the tile and walks with (nkv, g, d) queries, g padded to the
+    8-row sublane tile; any other fuses the whole tile as
+    (nkv, TQ*g, d), row t*g + j being head j of tile row t, and rows
+    outside the segment compute garbage the masked store never
+    writes."""
+    tq, nq, d = q_ref.shape
+    k_buf = kv[2]
+    nkv = k_buf.shape[1]
+    g = nq // nkv
+    # q and K meet in the MXU in their common dtype: as stored when the
+    # cache has the model's dtype (the reshapes want 32-bit rows)
+    q_dtype = jnp.promote_types(q_ref.dtype, k_buf.dtype)
+    window = static["window"]
+    hi = jnp.minimum(
+        qpos0 + n_rows, static["num_pages"] * static["block_size"]
+    )
+    lo = jnp.int32(0)
+    if window is not None:
+        # the EARLIEST row needs keys down to qpos0 - window + 1;
+        # earlier KV blocks never stream in
+        lo = jnp.clip(qpos0 - window + 1, 0, hi)
+
+    if one_row:
+        q = q_ref[row0].astype(jnp.float32).reshape(nkv, g, d)
+        if g % 8:
+            pad = jnp.zeros((nkv, 8 - g % 8, d), jnp.float32)
+            q = jnp.concatenate([q, pad], axis=1)
+        out = _walk(
+            q.astype(q_dtype), qpos0, lo, hi, page_of, layer, kv, static
+        )
+        out_ref[row0] = out[:, :g].reshape(nq, d).astype(out_ref.dtype)
+        return
+
+    q = (
+        q_ref[...].astype(jnp.float32)
+        .reshape(tq, nkv, g, d)
+        .transpose(1, 0, 2, 3)
+        .reshape(nkv, tq * g, d)
+    )
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (1, tq * g, 1), 1) // g
+    out = _walk(
+        q.astype(q_dtype), qpos0 + (row_of - row0), lo, hi, page_of,
+        layer, kv, static,
+    )
+    out = (
+        out.reshape(nkv, tq, g, d)
+        .transpose(1, 0, 2, 3)
+        .reshape(tq, nq, d)
+    )
+    # row-masked merge: segments of one block write disjoint row
+    # ranges sequentially (read-modify-write within the program)
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, (tq, 1, 1), 0)
+    keep = (row_ids >= row0) & (row_ids < row0 + n_rows)
+    out_ref[...] = jnp.where(keep, out.astype(out_ref.dtype), out_ref[...])
+
+
 def _decode_kernel(
     # scalar prefetch
     layer_ref,          # (1,) int32
@@ -82,101 +318,22 @@ def _decode_kernel(
     # outputs
     out_ref,            # (1, nq, d) VMEM
     # scratch
-    k_buf,              # (2, nkv, bs, d) VMEM
+    k_buf,              # (_KV_RING, nkv, N*bs, d) VMEM
     v_buf,
-    sem,                # DMA sems (2, 2)
-    *,
-    block_size: int,
-    num_pages: int,
-    scale: float,
-    window: int | None = None,
+    sem,                # DMA sems (_KV_RING, 2)
+    **static,           # block_size, num_pages, scale, window
 ):
+    """One grid program per sequence: its one query row at position
+    ctx_len - 1 over its own pages (the sliding window, HF semantics:
+    keys j > q_pos - window, starts the walk at the window's first KV
+    block)."""
     i = pl.program_id(0)
-    layer = layer_ref[0]
-    ctx_len = context_lens_ref[i]
-    nq, d = q_ref.shape[1], q_ref.shape[2]
-    nkv = k_buf.shape[1]
-    g = nq // nkv
-    bs = block_size
-
-    # number of pages this sequence actually uses
-    n_used = jnp.minimum(
-        (ctx_len + bs - 1) // bs, jnp.int32(num_pages)
+    _attend(
+        q_ref, out_ref, 0, 1, context_lens_ref[i] - 1,
+        lambda j: block_tables_ref[i, j], layer_ref[0],
+        (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), static,
+        one_row=True,
     )
-    # sliding window (HF semantics: keys j > q_pos - window, q_pos =
-    # ctx_len-1): pages wholly below the window are never even DMA'd —
-    # the page walk starts at the window's first page
-    if window is None:
-        n_start = jnp.int32(0)
-    else:
-        n_start = jnp.maximum(ctx_len - window, 0) // bs
-
-    # one strided DMA per page: all heads' rows for the page's slot
-    # range (the head-major cache makes this a tile-aligned slice)
-    def page_dma(slot, page_idx, buf, cache_ref, which):
-        row0 = block_tables_ref[i, page_idx] * bs
-        return pltpu.make_async_copy(
-            cache_ref.at[layer, :, pl.ds(row0, bs)],
-            buf.at[slot],
-            sem.at[slot, which],
-        )
-
-    @pl.when(n_used > n_start)
-    def _():
-        s0 = jax.lax.rem(n_start, 2)
-        page_dma(s0, n_start, k_buf, k_cache_ref, 0).start()
-        page_dma(s0, n_start, v_buf, v_cache_ref, 1).start()
-
-    q = q_ref[0].astype(jnp.float32).reshape(nkv, g, d) * scale
-
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
-
-        @pl.when(j + 1 < n_used)
-        def _():
-            page_dma(nxt, j + 1, k_buf, k_cache_ref, 0).start()
-            page_dma(nxt, j + 1, v_buf, v_cache_ref, 1).start()
-
-        page_dma(slot, j, k_buf, k_cache_ref, 0).wait()
-        page_dma(slot, j, v_buf, v_cache_ref, 1).wait()
-
-        k = k_buf[slot].astype(jnp.float32)  # (nkv, bs, d)
-        v = v_buf[slot].astype(jnp.float32)
-        # (nkv, g, d) x (nkv, bs, d) -> (nkv, g, bs), batched over kv heads
-        s = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
-        valid = pos < ctx_len
-        if window is not None:
-            # mask within the boundary page of the window
-            valid &= pos > ctx_len - 1 - window
-        s = jnp.where(valid, s, MASK_VALUE)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)  # (nkv, g, bs)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # (nkv, g, bs) x (nkv, bs, d) -> (nkv, g, d)
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_new = acc * corr + pv
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((nkv, g, 1), MASK_VALUE, jnp.float32)
-    l0 = jnp.zeros((nkv, g, 1), jnp.float32)
-    acc0 = jnp.zeros((nkv, g, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(n_start, n_used, body, (m0, l0, acc0))
-
-    out = acc / jnp.maximum(l, 1e-30)
-    out_ref[0] = out.reshape(nq, d).astype(out_ref.dtype)
 
 
 def _prefill_kernel(
@@ -185,19 +342,15 @@ def _prefill_kernel(
     block_table_ref,    # (P,) int32 — this sequence's pages
     # array inputs
     q_ref,              # (Tq, nq, d) VMEM — this program's query tile
-    k_cache_ref,        # (L, nkv, slots, d) ANY/HBM — head-major
+    k_cache_ref,
     v_cache_ref,
     # outputs
     out_ref,            # (Tq, nq, d) VMEM
     # scratch
-    k_buf,              # (2, nkv, bs, d) VMEM
+    k_buf,
     v_buf,
-    sem,                # DMA sems (2, 2)
-    *,
-    block_size: int,
-    num_pages: int,
-    scale: float,
-    window: int | None = None,
+    sem,
+    **static,
 ):
     """Ragged chunked-prefill attention for ONE sequence over the paged
     HBM cache (SURVEY §7 hard-part #1, prefill half).
@@ -207,108 +360,17 @@ def _prefill_kernel(
     padded tail rows simply read garbage that the runner discards, exactly
     like the XLA path's padded rows). Causality is per-element:
     key_pos <= q_pos, evaluated against the online softmax, so one pass
-    over the context pages serves every query row — the per-layer
-    (ctx, nkv, d) gathered copy the XLA path materialises is never built
-    and each KV byte streams from HBM exactly once per chunk.
+    over the context pages serves every query row of a tile — the
+    per-layer (ctx, nkv, d) gathered copy the XLA path materialises is
+    never built, and later tiles see (and stream) more pages.
     """
-    i = pl.program_id(0)
-    layer = meta_ref[0]
-    q_start = meta_ref[1]
-    tq, nq, d = q_ref.shape
-    nkv = k_buf.shape[1]
-    g = nq // nkv
-    bs = block_size
-
-    tile_base = q_start + i * tq
-    # pages holding positions [0, tile_base + tq): later tiles see more
-    n_used = jnp.minimum(
-        (tile_base + tq + bs - 1) // bs, jnp.int32(num_pages)
+    tq = q_ref.shape[0]
+    _attend(
+        q_ref, out_ref, 0, tq, meta_ref[1] + pl.program_id(0) * tq,
+        lambda j: block_table_ref[j], meta_ref[0],
+        (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), static,
+        one_row=False,
     )
-    # sliding window: the tile's EARLIEST row needs keys down to
-    # tile_base - window + 1; pages wholly below that never stream in.
-    # n_start < n_used always (a tile's own page is inside its window).
-    if window is None:
-        n_start = jnp.int32(0)
-    else:
-        n_start = jnp.maximum(tile_base - window + 1, 0) // bs
-
-    def page_dma(slot, page_idx, buf, cache_ref, which):
-        row0 = block_table_ref[page_idx] * bs
-        return pltpu.make_async_copy(
-            cache_ref.at[layer, :, pl.ds(row0, bs)],
-            buf.at[slot],
-            sem.at[slot, which],
-        )
-
-    s0 = jax.lax.rem(n_start, 2)
-    page_dma(s0, n_start, k_buf, k_cache_ref, 0).start()
-    page_dma(s0, n_start, v_buf, v_cache_ref, 1).start()
-
-    # (Tq, nq, d) -> (nkv, Tq*g, d): batch kv heads on the MXU; row r of
-    # the fused axis belongs to query row r // g
-    q = q_ref[...].astype(jnp.float32)
-    q = (
-        q.reshape(tq, nkv, g, d)
-        .transpose(1, 0, 2, 3)
-        .reshape(nkv, tq * g, d)
-        * scale
-    )
-    q_pos = tile_base + (
-        jax.lax.broadcasted_iota(jnp.int32, (1, tq * g, 1), 1) // g
-    )
-
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-        nxt = jax.lax.rem(j + 1, 2)
-
-        @pl.when(j + 1 < n_used)
-        def _():
-            page_dma(nxt, j + 1, k_buf, k_cache_ref, 0).start()
-            page_dma(nxt, j + 1, v_buf, v_cache_ref, 1).start()
-
-        page_dma(slot, j, k_buf, k_cache_ref, 0).wait()
-        page_dma(slot, j, v_buf, v_cache_ref, 1).wait()
-
-        k = k_buf[slot].astype(jnp.float32)  # (nkv, bs, d)
-        v = v_buf[slot].astype(jnp.float32)
-        # (nkv, Tq*g, d) x (nkv, bs, d) -> (nkv, Tq*g, bs)
-        s = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        k_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, bs), 2
-        )
-        valid = k_pos <= q_pos
-        if window is not None:
-            valid &= k_pos > q_pos - window
-        s = jnp.where(valid, s, MASK_VALUE)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc * corr + pv
-
-    m0 = jnp.full((nkv, tq * g, 1), MASK_VALUE, jnp.float32)
-    l0 = jnp.zeros((nkv, tq * g, 1), jnp.float32)
-    acc0 = jnp.zeros((nkv, tq * g, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(n_start, n_used, body, (m0, l0, acc0))
-
-    out = acc / jnp.maximum(l, 1e-30)
-    out = (
-        out.reshape(nkv, tq, g, d)
-        .transpose(1, 0, 2, 3)
-        .reshape(tq, nq, d)
-    )
-    out_ref[...] = out.astype(out_ref.dtype)
 
 
 def _ragged_kernel(
@@ -321,20 +383,15 @@ def _ragged_kernel(
     block_tables_ref,   # (S, P) int32 — per-LANE page tables
     # array inputs
     q_ref,              # (TQ, nq, d) VMEM — this block's query rows
-    k_cache_ref,        # (L, nkv, slots, d) ANY/HBM — head-major
+    k_cache_ref,
     v_cache_ref,
     # outputs
     out_ref,            # (TQ, nq, d) VMEM
     # scratch
-    k_buf,              # (2, nkv, bs, d) VMEM
+    k_buf,
     v_buf,
-    sem,                # DMA sems (2, 2)
-    *,
-    block_size: int,
-    num_pages: int,
-    scale: float,
-    window: int | None = None,
-    tq: int = RAGGED_TQ,
+    sem,
+    **static,
 ):
     """Unified ragged paged attention: ONE grid over the flattened
     query-row space of an arbitrary lane mix (the "Ragged Paged
@@ -348,138 +405,73 @@ def _ragged_kernel(
     block), so per-block SEGMENT metadata rides the scalar-prefetch
     SMEM path as a CSR list: each segment names its lane's page-table
     row, its row range within the block, and the absolute position of
-    its first query row. The kernel walks each segment's own pages
-    (double-buffered HBM->VMEM DMA, online softmax — the same per-row
-    math as the composed _prefill_kernel/_decode_kernel, so outputs
-    are bit-identical per row) and row-masks its store, which makes
-    decode the degenerate n_rows=1 / q_pos=ctx-1 case of the causal
-    prefill body: one kernel, any lane mix, one launch.
+    its first query row. Each segment walks its own pages through the
+    same `_attend` as the composed kernels — a one-row segment (decode,
+    q_pos = ctx-1) at the decode kernel's tile height, any other at the
+    prefill kernel's — so outputs are bit-identical per row: one
+    kernel, any lane mix, one launch. n_rows == 0 (idle slot) walks
+    nothing and stores nothing.
     """
     i = pl.program_id(0)
-    layer = meta_ref[0]
-    nq, d = q_ref.shape[1], q_ref.shape[2]
-    nkv = k_buf.shape[1]
-    g = nq // nkv
-    bs = block_size
-    s_lo = blk_seg_ref[i]
-    s_hi = blk_seg_ref[i + 1]
-
-    # (TQ, nq, d) -> (nkv, TQ*g, d): batch kv heads on the MXU; fused
-    # row r belongs to query row r // g (same packing as the composed
-    # prefill kernel, so per-row arithmetic is identical)
-    q = q_ref[...].astype(jnp.float32)
-    q = (
-        q.reshape(tq, nkv, g, d)
-        .transpose(1, 0, 2, 3)
-        .reshape(nkv, tq * g, d)
-        * scale
-    )
-    row_of = (
-        jax.lax.broadcasted_iota(jnp.int32, (1, tq * g, 1), 1) // g
-    )  # row index 0..tq-1 of each fused row
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (tq, 1, 1), 0)
+    kv = (k_cache_ref, v_cache_ref, k_buf, v_buf, sem)
 
     def seg_body(s, _):
         lane = seg_meta_ref[s, 0]
-        row0 = seg_meta_ref[s, 1]
         n_rows = seg_meta_ref[s, 2]
-        qpos0 = seg_meta_ref[s, 3]
-        # pages holding positions [0, qpos0 + n_rows): the segment's
-        # LAST owned row attends up to its own position. n_rows == 0
-        # (idle slot) walks nothing and stores nothing.
-        n_used = jnp.minimum(
-            (qpos0 + n_rows + bs - 1) // bs, jnp.int32(num_pages)
+        attend = functools.partial(
+            _attend, q_ref, out_ref, seg_meta_ref[s, 1], n_rows,
+            seg_meta_ref[s, 3], lambda j: block_tables_ref[lane, j],
+            meta_ref[0], kv, static,
         )
-        # sliding window: the segment's EARLIEST row needs keys down
-        # to qpos0 - window + 1; earlier pages never stream in
-        if window is None:
-            n_start = jnp.int32(0)
-        else:
-            n_start = jnp.maximum(qpos0 - window + 1, 0) // bs
-        n_start = jnp.minimum(n_start, n_used)
-
-        def page_dma(slot, page_idx, buf, cache_ref, which):
-            r0 = block_tables_ref[lane, page_idx] * bs
-            return pltpu.make_async_copy(
-                cache_ref.at[layer, :, pl.ds(r0, bs)],
-                buf.at[slot],
-                sem.at[slot, which],
-            )
-
-        @pl.when(n_used > n_start)
-        def _():
-            s0 = jax.lax.rem(n_start, 2)
-            page_dma(s0, n_start, k_buf, k_cache_ref, 0).start()
-            page_dma(s0, n_start, v_buf, v_cache_ref, 1).start()
-
-        # per-row absolute query positions for THIS segment's causal
-        # mask; rows outside [row0, row0+n_rows) compute garbage that
-        # the masked store below never writes
-        q_pos = qpos0 + (row_of - row0)
-
-        def body(j, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(j, 2)
-            nxt = jax.lax.rem(j + 1, 2)
-
-            @pl.when(j + 1 < n_used)
-            def _():
-                page_dma(nxt, j + 1, k_buf, k_cache_ref, 0).start()
-                page_dma(nxt, j + 1, v_buf, v_cache_ref, 1).start()
-
-            page_dma(slot, j, k_buf, k_cache_ref, 0).wait()
-            page_dma(slot, j, v_buf, v_cache_ref, 1).wait()
-
-            k = k_buf[slot].astype(jnp.float32)  # (nkv, bs, d)
-            v = v_buf[slot].astype(jnp.float32)
-            s_dots = jax.lax.dot_general(
-                q, k,
-                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )  # (nkv, TQ*g, bs)
-            k_pos = j * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, bs), 2
-            )
-            valid = k_pos <= q_pos
-            if window is not None:
-                valid &= k_pos > q_pos - window
-            s_dots = jnp.where(valid, s_dots, MASK_VALUE)
-
-            m_new = jnp.maximum(
-                m, jnp.max(s_dots, axis=-1, keepdims=True)
-            )
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(s_dots - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, v,
-                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, acc * corr + pv
-
-        m0 = jnp.full((nkv, tq * g, 1), MASK_VALUE, jnp.float32)
-        l0 = jnp.zeros((nkv, tq * g, 1), jnp.float32)
-        acc0 = jnp.zeros((nkv, tq * g, d), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(
-            n_start, n_used, body, (m0, l0, acc0)
-        )
-
-        out = acc / jnp.maximum(l, 1e-30)
-        out = (
-            out.reshape(nkv, tq, g, d)
-            .transpose(1, 0, 2, 3)
-            .reshape(tq, nq, d)
-        )
-        # row-masked merge: segments of one block write disjoint row
-        # ranges sequentially (read-modify-write within the program)
-        keep = (row_ids >= row0) & (row_ids < row0 + n_rows)
-        out_ref[...] = jnp.where(
-            keep, out.astype(out_ref.dtype), out_ref[...]
-        )
+        pl.when(n_rows == 1)(functools.partial(attend, one_row=True))
+        pl.when(n_rows > 1)(functools.partial(attend, one_row=False))
         return 0
 
-    jax.lax.fori_loop(s_lo, s_hi, seg_body, 0)
+    jax.lax.fori_loop(blk_seg_ref[i], blk_seg_ref[i + 1], seg_body, 0)
+
+
+def _paged_call(
+    kernel, name, tq, scalars, q, k_cache, v_cache, *,
+    num_pages, block_size, scale, window, interpret,
+):
+    """The pallas_call the three kernels share: a grid over `tq`-row
+    tiles of q, the caches left in HBM, the scalars prefetched to SMEM,
+    a ring of KV-block buffers as scratch."""
+    r, nq, d = q.shape
+    nkv = k_cache.shape[1]
+    keys = block_size * _kv_block_pages(
+        nkv, d, k_cache.dtype.itemsize, block_size
+    )
+    tile = pl.BlockSpec(
+        (tq, nq, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
+    )
+    cache = pl.BlockSpec(memory_space=pltpu.HBM)
+    return pl.pallas_call(
+        functools.partial(
+            kernel, block_size=block_size, num_pages=num_pages,
+            scale=scale, window=window,
+        ),
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(r // tq,),
+            in_specs=[tile, cache, cache],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((_KV_RING, nkv, keys, d), k_cache.dtype),
+                pltpu.VMEM((_KV_RING, nkv, keys, d), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((_KV_RING, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # large f32 q/accumulator tiles exceed the default 16 MiB
+            # scoped-vmem stack; v5e has 128 MiB — allow half of it
+            vmem_limit_bytes=64 * 2**20,
+        ),
+    )(*(jnp.asarray(s, jnp.int32) for s in scalars), q, k_cache, v_cache)
 
 
 @functools.partial(
@@ -510,111 +502,25 @@ def ragged_paged_attention(
     metadata — see _ragged_kernel. Returns (R, nq, d) in q.dtype; rows
     covered by no segment are undefined (callers discard them, the
     same contract as the composed kernels' padded rows)."""
-    r, nq, d = q.shape
-    nkv = k_cache.shape[1]
-    num_pages = block_tables.shape[1]
+    r = q.shape[0]
     n_blocks = blk_seg.shape[0] - 1
     tq = r // n_blocks
     assert tq * n_blocks == r, (
         f"ragged row space {r} must tile into {n_blocks} blocks"
     )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec(
-                (tq, nq, d), lambda i, *_: (i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-        ],
-        out_specs=pl.BlockSpec(
-            (tq, nq, d), lambda i, *_: (i, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, nkv, block_size, d), k_cache.dtype),
-            pltpu.VMEM((2, nkv, block_size, d), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _ragged_kernel,
-        block_size=block_size,
-        num_pages=num_pages,
-        scale=scale,
-        window=window,
-        tq=tq,
-    )
-    return pl.pallas_call(
-        kernel,
-        name="ragged_paged_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, nq, d), q.dtype),
+    return _paged_call(
+        _ragged_kernel, "ragged_paged_attention", tq,
+        (jnp.reshape(layer, 1), blk_seg, seg_meta, block_tables),
+        q, k_cache, v_cache, num_pages=block_tables.shape[1],
+        block_size=block_size, scale=scale, window=window,
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 * 2**20,
-        ),
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        blk_seg.astype(jnp.int32),
-        seg_meta.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        q,
-        k_cache,
-        v_cache,
     )
-
-
-def ragged_paged_attention_tp(
-    q: jax.Array,             # (R, nq, d) — heads sharded over tp
-    k_cache: jax.Array,       # (L, nkv, num_slots, d) — kv heads sharded
-    v_cache: jax.Array,
-    layer: jax.Array,
-    block_tables: jax.Array,  # (S, P) replicated
-    blk_seg: jax.Array,       # (G+1,) replicated
-    seg_meta: jax.Array,      # (SC, 4) replicated
-    *,
-    mesh: jax.sharding.Mesh,
-    block_size: int,
-    scale: float,
-    interpret: bool = False,
-    window: int | None = None,
-) -> jax.Array:
-    """Tensor-parallel ragged paged attention via shard_map (same
-    head-congruence argument as paged_decode_attention_tp: GQA groups
-    are chip-local, so the kernel body needs no collectives)."""
-    tp = _resolve_tp_axis(mesh)
-    P = jax.sharding.PartitionSpec
-    body = functools.partial(
-        ragged_paged_attention,
-        block_size=block_size, scale=scale, interpret=interpret,
-        window=window,
-    )
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(None, tp, None),
-            P(None, tp, None, None),
-            P(None, tp, None, None),
-            P(),
-            P(None, None),
-            P(None),
-            P(None, None),
-        ),
-        out_specs=P(None, tp, None),
-        check_vma=False,
-    )(q, k_cache, v_cache, layer, block_tables, blk_seg, seg_meta)
 
 
 def _prefill_q_tile(t: int, nq: int, d: int) -> int:
     """Largest pow2 query tile whose f32 q + accumulator fit a ~4 MiB VMEM
-    budget each (v5e VMEM is 128 MiB but leave room for double-buffered KV
-    pages, the output tile, and Mosaic's own spills). One tile per chunk
+    budget each (v5e VMEM is 128 MiB but leave room for the ring of KV
+    blocks, the output tile, and Mosaic's own spills). One tile per chunk
     (the common case) means the context streams from HBM exactly once."""
     budget = 4 * 2**20
     per_row = nq * d * 4
@@ -643,161 +549,15 @@ def paged_prefill_attention(
 ) -> jax.Array:
     """Chunked-prefill paged attention for one sequence. -> (t, nq, d)."""
     t, nq, d = q.shape
-    nkv = k_cache.shape[1]
-    num_pages = block_table.shape[0]
-    tq = _prefill_q_tile(t, nq, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(t // tq,),
-        in_specs=[
-            pl.BlockSpec(
-                (tq, nq, d), lambda i, *_: (i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-        ],
-        out_specs=pl.BlockSpec(
-            (tq, nq, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, nkv, block_size, d), k_cache.dtype),
-            pltpu.VMEM((2, nkv, block_size, d), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _prefill_kernel,
-        block_size=block_size,
-        num_pages=num_pages,
-        scale=scale,
-        window=window,
-    )
-    meta = jnp.stack(
-        [jnp.asarray(layer, jnp.int32), jnp.asarray(q_start, jnp.int32)]
-    )
-    return pl.pallas_call(
-        kernel,
-        name="paged_prefill_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, nq, d), q.dtype),
+    return _paged_call(
+        _prefill_kernel, "paged_prefill_attention",
+        _prefill_q_tile(t, nq, d),
+        (jnp.stack([jnp.asarray(layer, jnp.int32),
+                    jnp.asarray(q_start, jnp.int32)]), block_table),
+        q, k_cache, v_cache, num_pages=block_table.shape[0],
+        block_size=block_size, scale=scale, window=window,
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            # large f32 q/accumulator tiles exceed the default 16 MiB
-            # scoped-vmem stack; v5e has 128 MiB — allow half of it
-            vmem_limit_bytes=64 * 2**20,
-        ),
-    )(
-        meta,
-        block_table.astype(jnp.int32),
-        q,
-        k_cache,
-        v_cache,
     )
-
-
-def paged_prefill_attention_tp(
-    q: jax.Array,            # (t, nq, d) — heads sharded over tp
-    k_cache: jax.Array,      # (L, nkv, num_slots, d) — head-major — kv heads sharded
-    v_cache: jax.Array,
-    layer: jax.Array,
-    block_table: jax.Array,  # (P,) replicated
-    q_start: jax.Array,      # scalar replicated
-    *,
-    mesh: jax.sharding.Mesh,
-    block_size: int,
-    scale: float,
-    interpret: bool = False,
-    window: int | None = None,
-) -> jax.Array:
-    """Tensor-parallel chunked-prefill paged attention via shard_map (same
-    head-congruence argument as paged_decode_attention_tp: GQA groups are
-    chip-local, so the kernel body needs no collectives)."""
-    tp = _resolve_tp_axis(mesh)
-    P = jax.sharding.PartitionSpec
-    body = functools.partial(
-        paged_prefill_attention,
-        block_size=block_size, scale=scale, interpret=interpret,
-        window=window,
-    )
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(None, tp, None),
-            P(None, tp, None, None),
-            P(None, tp, None, None),
-            P(),
-            P(None),
-            P(),
-        ),
-        out_specs=P(None, tp, None),
-        check_vma=False,
-    )(q, k_cache, v_cache, layer, block_table, q_start)
-
-
-def _resolve_tp_axis(mesh: jax.sharding.Mesh) -> str:
-    """Resolve the tensor-parallel axis by name: on the multihost (dp, tp)
-    mesh, axis_names[0] would be the DP axis and silently reshard the
-    cache; only a single-axis mesh may fall back to its sole axis."""
-    if "tp" in mesh.axis_names:
-        return "tp"
-    if len(mesh.axis_names) == 1:
-        return mesh.axis_names[0]
-    raise ValueError(
-        f"mesh {mesh.axis_names} has no 'tp' axis; paged attention "
-        "needs the kv-head-sharded tensor-parallel axis"
-    )
-
-
-def paged_decode_attention_tp(
-    q: jax.Array,             # (b, nq, d) — heads sharded over tp
-    k_cache: jax.Array,       # (L, nkv, num_slots, d) — kv heads sharded
-    v_cache: jax.Array,
-    layer: jax.Array,
-    block_tables: jax.Array,  # (b, P) replicated
-    context_lens: jax.Array,  # (b,) replicated
-    *,
-    mesh: jax.sharding.Mesh,
-    block_size: int,
-    scale: float,
-    interpret: bool = False,
-    window: int | None = None,
-) -> jax.Array:
-    """Tensor-parallel paged decode attention via shard_map.
-
-    The KV cache is sharded over the kv-head axis and q heads are split
-    congruently (parallel/sharding.py), so each chip's GQA groups are fully
-    local: the kernel body needs zero cross-chip communication — the psum
-    stays where GSPMD already puts it, after the wo row-parallel projection.
-    shard_map hands each chip its (b, nq/tp, d) query slice and
-    (L, nkv/tp, slots, d) cache shard; block tables and context lens ride
-    replicated. check_vma=False because pallas_call does not participate in
-    varying-axes inference.
-    """
-    tp = _resolve_tp_axis(mesh)
-    P = jax.sharding.PartitionSpec
-    body = functools.partial(
-        paged_decode_attention,
-        block_size=block_size, scale=scale, interpret=interpret,
-        window=window,
-    )
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(None, tp, None),
-            P(None, tp, None, None),
-            P(None, tp, None, None),
-            P(),
-            P(None, None),
-            P(None),
-        ),
-        out_specs=P(None, tp, None),
-        check_vma=False,
-    )(q, k_cache, v_cache, layer, block_tables, context_lens)
 
 
 @functools.partial(
@@ -818,54 +578,89 @@ def paged_decode_attention(
     window: int | None = None,
 ) -> jax.Array:
     """One decode step of paged attention. Returns (b, nq, d) in q.dtype."""
-    b, nq, d = q.shape
-    nkv = k_cache.shape[1]
-    num_pages = block_tables.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, nq, d), lambda i, *_: (i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, nq, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, nkv, block_size, d), k_cache.dtype),
-            pltpu.VMEM((2, nkv, block_size, d), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel,
-        block_size=block_size,
-        num_pages=num_pages,
-        scale=scale,
-        window=window,
-    )
-    return pl.pallas_call(
-        kernel,
-        name="paged_decode_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nq, d), q.dtype),
+    return _paged_call(
+        _decode_kernel, "paged_decode_attention", 1,
+        (jnp.reshape(layer, 1), block_tables, context_lens),
+        q, k_cache, v_cache, num_pages=block_tables.shape[1],
+        block_size=block_size, scale=scale, window=window,
         interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            # large f32 q/accumulator tiles exceed the default 16 MiB
-            # scoped-vmem stack; v5e has 128 MiB — allow half of it
-            vmem_limit_bytes=64 * 2**20,
-        ),
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        block_tables.astype(jnp.int32),
-        context_lens.astype(jnp.int32),
-        q,
-        k_cache,
-        v_cache,
+    )
+
+
+def _resolve_tp_axis(mesh: jax.sharding.Mesh) -> str:
+    """Resolve the tensor-parallel axis by name: on the multihost (dp, tp)
+    mesh, axis_names[0] would be the DP axis and silently reshard the
+    cache; only a single-axis mesh may fall back to its sole axis."""
+    if "tp" in mesh.axis_names:
+        return "tp"
+    if len(mesh.axis_names) == 1:
+        return mesh.axis_names[0]
+    raise ValueError(
+        f"mesh {mesh.axis_names} has no 'tp' axis; paged attention "
+        "needs the kv-head-sharded tensor-parallel axis"
+    )
+
+
+def _over_heads(kernel_fn, mesh, q, k_cache, v_cache, *replicated, **static):
+    """Tensor-parallel paged attention via shard_map.
+
+    The KV cache is sharded over the kv-head axis and q heads are split
+    congruently (parallel/sharding.py), so each chip's GQA groups are fully
+    local: the kernel body needs zero cross-chip communication — the psum
+    stays where GSPMD already puts it, after the wo row-parallel projection.
+    shard_map hands each chip its (rows, nq/tp, d) query slice and
+    (L, nkv/tp, slots, d) cache shard (the KV block is sized from that
+    per-chip head count); the layer index, tables, lengths and segment
+    metadata ride replicated. check_vma=False because pallas_call does
+    not participate in varying-axes inference.
+    """
+    tp = _resolve_tp_axis(mesh)
+    P = jax.sharding.PartitionSpec
+    cache = P(None, tp, None, None)
+    return jax.shard_map(
+        functools.partial(kernel_fn, **static),
+        mesh=mesh,
+        in_specs=(P(None, tp, None), cache, cache)
+        + tuple(P(*[None] * jnp.ndim(x)) for x in replicated),
+        out_specs=P(None, tp, None),
+        check_vma=False,
+    )(q, k_cache, v_cache, *replicated)
+
+
+def ragged_paged_attention_tp(
+    q, k_cache, v_cache, layer, block_tables, blk_seg, seg_meta, *,
+    mesh: jax.sharding.Mesh, block_size: int, scale: float,
+    interpret: bool = False, window: int | None = None,
+) -> jax.Array:
+    """ragged_paged_attention with heads sharded over the mesh's tp axis."""
+    return _over_heads(
+        ragged_paged_attention, mesh, q, k_cache, v_cache, layer,
+        block_tables, blk_seg, seg_meta, block_size=block_size,
+        scale=scale, interpret=interpret, window=window,
+    )
+
+
+def paged_prefill_attention_tp(
+    q, k_cache, v_cache, layer, block_table, q_start, *,
+    mesh: jax.sharding.Mesh, block_size: int, scale: float,
+    interpret: bool = False, window: int | None = None,
+) -> jax.Array:
+    """paged_prefill_attention with heads sharded over the tp axis."""
+    return _over_heads(
+        paged_prefill_attention, mesh, q, k_cache, v_cache, layer,
+        block_table, q_start, block_size=block_size, scale=scale,
+        interpret=interpret, window=window,
+    )
+
+
+def paged_decode_attention_tp(
+    q, k_cache, v_cache, layer, block_tables, context_lens, *,
+    mesh: jax.sharding.Mesh, block_size: int, scale: float,
+    interpret: bool = False, window: int | None = None,
+) -> jax.Array:
+    """paged_decode_attention with heads sharded over the tp axis."""
+    return _over_heads(
+        paged_decode_attention, mesh, q, k_cache, v_cache, layer,
+        block_tables, context_lens, block_size=block_size, scale=scale,
+        interpret=interpret, window=window,
     )

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from production_stack_tpu.ops import attention as xla_attn
+from production_stack_tpu.ops import pallas_attention as pa
 from production_stack_tpu.ops.pallas_attention import paged_decode_attention
 
 
@@ -35,12 +36,12 @@ def make_case(seed, b=4, layers=2, pages_per_seq=4, bs=8, nkv=2, g=2, d=128,
 
 
 def reference(q, k_cache, v_cache, layer, block_tables, context_lens, bs,
-              scale):
+              scale, window=None):
     slots = xla_attn.block_table_slots(block_tables, bs)  # (b, P*bs)
     k_ctx = k_cache[layer][:, slots].transpose(1, 2, 0, 3)  # (b,c,nkv,d)
     v_ctx = v_cache[layer][:, slots].transpose(1, 2, 0, 3)
     return xla_attn.context_attention_decode(
-        q, k_ctx, v_ctx, context_lens, scale
+        q, k_ctx, v_ctx, context_lens, scale, window=window
     )
 
 
@@ -660,3 +661,217 @@ def test_engine_multistep_pallas_path():
     assert eng_p.runner.attention_impl == "pallas"
     out_p = [o.token_ids for o in eng_p.generate(prompts, sp)]
     assert out_p == out_x
+
+
+# ---- the walk's edges ------------------------------------------------------
+# One page walk serves the three kernels: KV blocks of N pages at
+# absolute multiples of N, a ring of block buffers, a one-row and a
+# fused tile height. These cases sit on its edges. Each compares the
+# composed kernel with the XLA reference at the file's tolerances AND
+# the ragged kernel with the composed one bit for bit.
+
+_KERNELS = (
+    pa.paged_decode_attention, pa.paged_prefill_attention,
+    pa.ragged_paged_attention,
+)
+
+
+@pytest.fixture
+def kv_block_pages(monkeypatch):
+    """force(n) pins the walk's KV block to n pages (None: the size
+    `_kv_block_pages` picks for the case's shapes). The kernels are
+    jitted on shapes, not on N, so their caches are dropped around a
+    forced size."""
+    def force(n):
+        if n is not None:
+            monkeypatch.setattr(pa, "_kv_block_pages", lambda *_: n)
+        return n
+
+    for f in _KERNELS:
+        f.clear_cache()
+    yield force
+    for f in _KERNELS:
+        f.clear_cache()
+
+
+def _natural_pages(nkv, d, dtype, bs):
+    return pa._kv_block_pages(nkv, d, jnp.dtype(dtype).itemsize, bs)
+
+
+def _lanes_case(seed, ctx, pages, bs=8, nkv=2, g=2, d=128,
+                dtype=jnp.float32):
+    """Decode lanes with GIVEN context lengths over disjoint shuffled
+    pages; tables `pages` wide, entries past a lane's last page 0."""
+    rng = np.random.RandomState(seed)
+    b = len(ctx)
+    need = [-(-c // bs) for c in ctx]
+    num_blocks = 1 + sum(need)
+    shape = (2, nkv, num_blocks * bs, d)
+    kc = jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+    vc = jnp.asarray(rng.randn(*shape).astype(np.float32), dtype)
+    q = jnp.asarray(rng.randn(b, nkv * g, d).astype(np.float32), dtype)
+    order = rng.permutation(np.arange(1, num_blocks))
+    tables = np.zeros((b, pages), np.int32)
+    at = 0
+    for i, n in enumerate(need):
+        tables[i, :n] = order[at:at + n]
+        at += n
+    return q, kc, vc, jnp.asarray(tables), jnp.asarray(ctx, jnp.int32)
+
+
+def _decode_three_ways(q, kc, vc, bt, ctx, bs, window=None, layer=1,
+                       tol=2e-5):
+    """composed decode ~ XLA reference; ragged decode rows == composed."""
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    out_c = paged_decode_attention(
+        q, kc, vc, jnp.int32(layer), bt, ctx,
+        block_size=bs, scale=scale, interpret=True, window=window,
+    )
+    out_r = reference(q, kc, vc, layer, bt, ctx, bs, scale, window=window)
+    np.testing.assert_allclose(
+        np.asarray(out_c, np.float32), np.asarray(out_r, np.float32),
+        rtol=tol, atol=tol,
+    )
+    r_pad, blk_seg, seg = _dec_rows_meta(np.asarray(ctx))
+    qp = jnp.pad(q, ((0, r_pad - q.shape[0]), (0, 0), (0, 0)))
+    out_k = _ragged(qp, kc, vc, layer, bt, blk_seg, seg, bs=bs,
+                    window=window)[: q.shape[0]]
+    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_c))
+
+
+@pytest.mark.parametrize("window", [None, "inside"])
+@pytest.mark.parametrize("n_pages", [2, 3, None])
+def test_walk_block_boundaries(kv_block_pages, n_pages, window):
+    """Contexts of one key, shorter than one KV block, ending exactly
+    on a block boundary and one key past it (first and second block),
+    in a table whose width is no multiple of N; `inside` is a sliding
+    window that starts in the middle of a KV block."""
+    bs = 8
+    n = kv_block_pages(n_pages) or _natural_pages(2, 128, jnp.float32, bs)
+    c = n * bs
+    ctx = [1, c - 3, c, c + 1, 2 * c, 2 * c + 1, 9]
+    pages = -(-(2 * c + 1) // bs)
+    assert pages % n, "the table must not tile into KV blocks"
+    w = None if window is None else c // 2 + 3
+    _decode_three_ways(*_lanes_case(11, ctx, pages), bs, window=w)
+
+
+@pytest.mark.parametrize("nkv,g", [(8, 3), (8, 4), (4, 7)])
+def test_walk_gqa_groups(kv_block_pages, nkv, g):
+    """The one-row tile pads g = 3, 4, 7 query rows a kv head to the
+    sublane tile: llama-3.2-3b's, mistral-7b's and qwen2-7b's groups,
+    at the KV block their head counts pick."""
+    bs = 8
+    c = bs * _natural_pages(nkv, 128, jnp.float32, bs)
+    ctx = [5, c, c + 1, c + bs + 2]
+    _decode_three_ways(
+        *_lanes_case(12, ctx, -(-(2 * c) // bs) + 1, nkv=nkv, g=g), bs
+    )
+
+
+@pytest.mark.parametrize("n_pages", [2, None])
+def test_walk_bfloat16_cache(kv_block_pages, n_pages):
+    """bf16 q, K and V enter the products as stored and p crosses PV
+    as three exact bf16 pieces: same bits from both kernels, the
+    reference to bf16's tolerance."""
+    bs = 16
+    n = kv_block_pages(n_pages) or _natural_pages(2, 128, jnp.bfloat16, bs)
+    c = n * bs
+    ctx = [3, c, c + 1, 2 * c - 1]
+    case = _lanes_case(13, ctx, 2 * n + 1, bs=bs, dtype=jnp.bfloat16)
+    _decode_three_ways(*case, bs, tol=2e-2)
+
+
+def test_pv_pieces_are_exact():
+    """_pv against a float64 product of the float32 p with the bf16 V:
+    nothing of p is lost (a bf16-rounded p would err by ~4e-3)."""
+    rng = np.random.RandomState(0)
+    p = jnp.asarray(rng.rand(2, 8, 64).astype(np.float32))
+    v = jnp.asarray(rng.randn(2, 64, 128).astype(np.float32), jnp.bfloat16)
+    want = np.einsum(
+        "hrk,hkd->hrd", np.asarray(p, np.float64),
+        np.asarray(v.astype(jnp.float32), np.float64),
+    )
+    np.testing.assert_allclose(np.asarray(pa._pv(p, v)), want, rtol=2e-6,
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("n_pages", [2, None])
+def test_walk_idle_slot_between_live_lanes(kv_block_pages, n_pages):
+    """An idle slot (n_rows == 0) BETWEEN two live one-row segments of
+    one grid block starts no copy and stores nothing: the live rows
+    keep the bits of the composed decode kernel."""
+    bs = 8
+    n = kv_block_pages(n_pages) or _natural_pages(2, 128, jnp.float32, bs)
+    c = n * bs
+    ctx = [c + 2, 7, 2 * c]
+    q, kc, vc, bt, ctx_a = _lanes_case(14, ctx, 2 * n + 1)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    ref = paged_decode_attention(
+        q, kc, vc, jnp.int32(0), bt, ctx_a,
+        block_size=bs, scale=scale, interpret=True,
+    )
+    # lane 1 idles: its row keeps the zeros it came with
+    seg = jnp.asarray(
+        [[0, 0, 1, ctx[0] - 1], [1, 1, 0, 0], [2, 2, 1, ctx[2] - 1]],
+        jnp.int32,
+    )
+    qp = jnp.pad(q, ((0, 5), (0, 0), (0, 0)))
+    out = _ragged(qp, kc, vc, 0, bt, jnp.asarray([0, 3], jnp.int32), seg)
+    np.testing.assert_array_equal(
+        np.asarray(out)[[0, 2]], np.asarray(ref)[[0, 2]]
+    )
+
+
+@pytest.mark.parametrize("window", [None, 11])
+@pytest.mark.parametrize("n_pages", [2, None])
+def test_walk_decode_rows_beside_prefill_tail(kv_block_pages, n_pages,
+                                              window):
+    """ONE grid block holds a prefill chunk's 3-row tail (fused tile
+    height) and four decode rows (one-row height): each region keeps
+    the bits of its composed kernel, and the XLA reference holds."""
+    bs, nkv, g, d = 8, 2, 2, 128
+    n = kv_block_pages(n_pages) or _natural_pages(nkv, d, jnp.float32, bs)
+    c = n * bs
+    tail = 3
+    q_start = c + 5  # the tail sits just past a KV-block boundary
+    ctx = [q_start + tail, 1, c, c + 1, 2 * c + 3]
+    pages = 2 * n + 1
+    q, kc, vc, bt, ctx_a = _lanes_case(15, ctx, pages, nkv=nkv, g=g, d=d)
+    scale = 1.0 / np.sqrt(d)
+    rng = np.random.RandomState(16)
+    q_pf = jnp.asarray(rng.randn(8, nkv * g, d).astype(np.float32))
+    ref_pf = pa.paged_prefill_attention(
+        q_pf, kc, vc, jnp.int32(1), bt[0], jnp.int32(q_start),
+        block_size=bs, scale=scale, interpret=True, window=window,
+    )
+    ref_dec = paged_decode_attention(
+        q[1:], kc, vc, jnp.int32(1), bt[1:], ctx_a[1:],
+        block_size=bs, scale=scale, interpret=True, window=window,
+    )
+    seg = np.asarray(
+        [[0, 0, tail, q_start]]
+        + [[i, tail + i - 1, 1, ctx[i] - 1] for i in range(1, 5)],
+        np.int32,
+    )
+    q_blk = jnp.concatenate([q_pf[:tail], q[1:], jnp.zeros_like(q[:1])])
+    out = _ragged(
+        q_blk, kc, vc, 1, bt, jnp.asarray([0, 5], jnp.int32),
+        jnp.asarray(seg), window=window,
+    )
+    np.testing.assert_array_equal(
+        np.asarray(out[:tail]), np.asarray(ref_pf[:tail])
+    )
+    np.testing.assert_array_equal(
+        np.asarray(out[tail:tail + 4]), np.asarray(ref_dec)
+    )
+    slots = xla_attn.block_table_slots(bt[0], bs)
+    out_r = xla_attn.context_attention_prefill(
+        q_pf[:tail], kc[1][:, slots].transpose(1, 0, 2),
+        vc[1][:, slots].transpose(1, 0, 2),
+        jnp.arange(q_start, q_start + tail), jnp.int32(q_start + tail),
+        scale, window=window,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out[:tail]), np.asarray(out_r), rtol=2e-5, atol=2e-5
+    )

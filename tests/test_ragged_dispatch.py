@@ -1,7 +1,7 @@
 """Unified ragged prefill+decode dispatch: ONE lane-typed engine round
 (prefill-chunk lanes + fused decode lanes in a single device program)
-must be BIT-IDENTICAL to the split alternating path
-(`--no-ragged-dispatch`) — tokens AND logical KV — across the mixed
+must give the split alternating path's (`--no-ragged-dispatch`) tokens,
+exactly, and its logical KV (see _assert_kv_close) across the mixed
 matrix: cold multi-chunk prefills riding beside decoding lanes, device
 stops firing mid-round, min_tokens gates, penalties, guided lanes,
 LoRA slots, and staged-prefetch hits.
@@ -85,10 +85,27 @@ def _cached_kv_by_hash(engine):
     }
 
 
+def _assert_kv_close(c_a, c_b):
+    """Logical KV of two engines that ran DIFFERENT programs over the
+    same tokens: same cached hashes, K and V equal to float32 rounding.
+    Not bit equality: the two engines batch a token's row with
+    different neighbours (a mixed round's [prefill | decode] rows, a
+    row-count bucket against a (group, chunk) grid), and XLA's CPU
+    matmuls block a different row count differently — layer 0's K/V
+    (no matmul over mixed rows behind them yet) are bit-equal, deeper
+    layers differ by ~1e-6 (PERF.md, Findings PR 25). Kernel against
+    kernel stays bit-exact in tests/test_pallas_attention.py."""
+    assert set(c_a) == set(c_b) and c_a, "cached hash sets differ"
+    for h in c_a:
+        for x, y in zip(c_a[h], c_b[h]):
+            np.testing.assert_array_equal(x[0], y[0])  # layer 0
+            np.testing.assert_allclose(x, y, rtol=2e-5, atol=2e-5)
+
+
 def _assert_parity(arrivals, sps, k=4, engine_kw=None, check_kv=True):
     """Run the staggered workload under ragged and split engines;
-    assert token streams (and logical KV) bit-identical. Returns the
-    ragged engine for counter assertions."""
+    assert token streams identical and logical KV equal to rounding.
+    Returns the ragged engine for counter assertions."""
     kw = engine_kw or {}
     e_r = _engine(True, k=k, **kw)
     out_r = _run_staggered(e_r, arrivals, sps)
@@ -98,11 +115,7 @@ def _assert_parity(arrivals, sps, k=4, engine_kw=None, check_kv=True):
         r: t for r, (t, _) in out_s.items()
     }
     if check_kv:
-        c_r, c_s = _cached_kv_by_hash(e_r), _cached_kv_by_hash(e_s)
-        assert set(c_r) == set(c_s) and c_r, "cached hash sets differ"
-        for h in c_r:
-            np.testing.assert_array_equal(c_r[h][0], c_s[h][0])
-            np.testing.assert_array_equal(c_r[h][1], c_s[h][1])
+        _assert_kv_close(_cached_kv_by_hash(e_r), _cached_kv_by_hash(e_s))
     return e_r, out_r, out_s
 
 
@@ -110,8 +123,8 @@ def _assert_parity(arrivals, sps, k=4, engine_kw=None, check_kv=True):
 def test_cold_multichunk_prefill_beside_decode_parity():
     """A 4-chunk cold prompt arrives while another lane decodes: its
     chunks ride as prefill lanes of the SAME rounds the decode lane
-    keeps stepping in — tokens and logical KV bit-identical to the
-    alternating split path."""
+    keeps stepping in — the alternating split path's tokens and
+    logical KV."""
     sp = SamplingParams(max_tokens=16, temperature=0.0, ignore_eos=True)
     e_r, _, _ = _assert_parity(
         [(0, "a", SHORT), (2, "b", LONG)], sp,
@@ -488,8 +501,8 @@ def test_ragged_engine_gates():
 
 def test_single_kernel_mixed_round_parity():
     """Kernel-mode ragged engine vs kernel-mode split engine (both
-    attention_impl=pallas, interpret on CPU): tokens + logical KV
-    bit-identical through mixed rounds with device stops."""
+    attention_impl=pallas, interpret on CPU): identical tokens, equal
+    logical KV through mixed rounds with device stops."""
     sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
     e_r, _, _ = _assert_parity(
         [(0, "a", SHORT), (2, "b", LONG), (3, "c", MED)], sp,
@@ -528,7 +541,7 @@ def test_single_kernel_exotic_sampling_parity():
 
 def test_single_kernel_vs_composed_kernels_parity():
     """Kernel-mode vs composed-kernel (--no-ragged-kernel) ragged
-    engines: same staggered mixed workload, bit-identical tokens AND
+    engines: same staggered mixed workload, identical tokens and equal
     logical KV — the A/B the bench @norpakernel control measures."""
     sp = SamplingParams(max_tokens=10, temperature=0.0, ignore_eos=True)
     arrivals = [(0, "a", SHORT), (2, "b", LONG)]
@@ -540,11 +553,7 @@ def test_single_kernel_vs_composed_kernels_parity():
     assert {r: t for r, (t, _) in out_k.items()} == {
         r: t for r, (t, _) in out_c.items()
     }
-    c_k, c_c = _cached_kv_by_hash(e_k), _cached_kv_by_hash(e_c)
-    assert set(c_k) == set(c_c) and c_k
-    for h in c_k:
-        np.testing.assert_array_equal(c_k[h][0], c_c[h][0])
-        np.testing.assert_array_equal(c_k[h][1], c_c[h][1])
+    _assert_kv_close(_cached_kv_by_hash(e_k), _cached_kv_by_hash(e_c))
 
 
 def _mixed_dispatch(runner, n_pf, chunk_len, k=4, total_len=16):

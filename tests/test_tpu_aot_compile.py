@@ -45,9 +45,9 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _cache(sh, d=D):
+def _cache(sh, d=D, nkv=NKV):
     # (L, nkv, slots, d): stays in HBM, so its size does not matter
-    return _spec(sh, (2, NKV, 64 * BS, d), jnp.bfloat16)
+    return _spec(sh, (2, nkv, 64 * BS, d), jnp.bfloat16)
 
 
 def _compile_decode(sh, *, nq=NQ, d=D, lanes=16, pages=32):
@@ -62,14 +62,16 @@ def _compile_decode(sh, *, nq=NQ, d=D, lanes=16, pages=32):
     ).compile()
 
 
-def _compile_ragged(sh, *, nq=NQ, d=D, rows=128, lanes=16, pages=32):
+def _compile_ragged(sh, *, nq=NQ, nkv=NKV, d=D, rows=128, lanes=16,
+                    pages=32, window=None):
     fn = functools.partial(
-        pa.ragged_paged_attention, block_size=BS, scale=d**-0.5
+        pa.ragged_paged_attention, block_size=BS, scale=d**-0.5,
+        window=window,
     )
     blocks = rows // pa.RAGGED_TQ
     return jax.jit(fn).lower(
-        _spec(sh, (rows, nq, d), jnp.bfloat16), _cache(sh, d),
-        _cache(sh, d), _spec(sh, (), jnp.int32),
+        _spec(sh, (rows, nq, d), jnp.bfloat16), _cache(sh, d, nkv),
+        _cache(sh, d, nkv), _spec(sh, (), jnp.int32),
         _spec(sh, (lanes, pages), jnp.int32),
         _spec(sh, (blocks + 1,), jnp.int32),
         _spec(sh, (blocks + lanes, 4), jnp.int32),
@@ -82,6 +84,19 @@ def test_decode_kernel_compiles(v5e):
 
 def test_ragged_kernel_compiles(v5e):
     _compile_ragged(v5e)
+
+
+@pytest.mark.parametrize("nq,nkv,window", [
+    (32, 8, None),   # mistral-7b: group 4, a KV block of 128 keys
+    (28, 4, None),   # qwen2-7b: group 7, 256 keys
+    (8, 2, None),    # mistral-7b under tp=4, per chip: 512 keys
+    (32, 8, 4096),   # a sliding window starts its walk inside a block
+])
+def test_ragged_kernel_compiles_at_benchmark_widths(v5e, nq, nkv, window):
+    """The one-row and the fused tile height of the walk, at the head
+    counts the benchmark's configurations give it and at the KV block
+    `_kv_block_pages` picks for each."""
+    _compile_ragged(v5e, nq=nq, nkv=nkv, window=window)
 
 
 @pytest.mark.slow
