@@ -8,11 +8,18 @@ command in four things a depth-cut model with seeded weights needs and
 the CLI cannot give:
 
 1. the model configuration comes from the benchmark's data file, through
-   the program's own `from_hf_config`, registered as a preset;
-2. the weights are made on the device from `--seed` in ONE jitted call
-   (layer by layer inside it, bf16, in the engine's own tensor-parallel
-   layout where there is a mesh), with non-zero q/k/v biases where the
-   architecture has them;
+   the program's own `from_hf_config`, registered as a preset. What of
+   that file is written to the `config.json` is the family's to say
+   (`hf_config`), less the keys every configuration has
+   (`manifest.COMMON_KEYS`); whether the file and the resulting
+   `ModelConfig` agree is the family's `check`, and the tiny widths of a
+   rehearsal its `rehearsal_config`;
+2. the weights are made on the device from `--seed` in ONE jitted call.
+   Common, here: the `rbg` key from the seed, the one `jax.jit` with the
+   engine's own tensor-parallel layout where there is a mesh, the wait
+   for the arrays. The family's (`init_params`): the parameter tree,
+   layer by layer, in the serving type, with every term non-zero that a
+   dropped term should show in the reference check;
 3. the tokenizer stand-in renders every token id as one reversible
    character (`ReversibleByteTokenizer`): with random weights the plain
    byte tokenizer renders nearly every id as "", so a stream would carry
@@ -28,15 +35,18 @@ the CLI cannot give:
    program was built all the same (`run.py`).
 
 A control thread (plain HTTP on a localhost port) serves the parent:
-start/stop a `jax.profiler` trace and reduce it, evaluate the plain
-reference on the engine's own parameter arrays, report device memory.
-Only the process that holds the chip can do those.
+start/stop a `jax.profiler` trace and reduce it, evaluate the family's
+plain reference on the engine's own parameter arrays, report device
+memory. Only the process that holds the chip can do those.
+
+Nothing here names a matrix of a parameter tree or branches on a field
+of an architecture: the configuration's file names its family, and the
+family is a file (`manifest.py` says what it gives).
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import shutil
@@ -51,15 +61,16 @@ REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, REPO)
 sys.path.insert(0, HERE)
 
-# keys of a configuration file that are the benchmark's, not the
-# published config.json's
-OWN_KEYS = ("qkv_bias", "source", "reduced", "assumed", "deployment",
-            "chips", "replicas", "engine_args", "router_args")
+import manifest  # noqa: E402
+
 ID_BASE = 0x10000   # first code point past the surrogates and the BMP
 
 
-def hf_config_of(config: dict) -> dict:
-    return {k: v for k, v in config.items() if k not in OWN_KEYS}
+def hf_config_of(config: dict, family) -> dict:
+    """The `config.json` the program reads: what the family makes of the
+    configuration's file, less the benchmark's own keys."""
+    return {k: v for k, v in family.hf_config(config).items()
+            if k not in manifest.COMMON_KEYS}
 
 
 def make_tokenizer():
@@ -88,11 +99,13 @@ def make_tokenizer():
     return ReversibleByteTokenizer()
 
 
-def model_config(config: dict, name: str, rehearse: bool, tp: int = 1):
-    """The ModelConfig, by the program's own `from_hf_config`."""
+def model_config(config: dict, family, name: str, rehearse: bool,
+                 tp: int = 1):
+    """The ModelConfig, by the program's own `from_hf_config`, checked
+    by the family against the configuration's file."""
     from production_stack_tpu.models import config as mcfg
 
-    hf = hf_config_of(config)
+    hf = hf_config_of(config, family)
     tmp = tempfile.mkdtemp(prefix="chipbench-cfg-")
     try:
         with open(os.path.join(tmp, "config.json"), "w") as f:
@@ -100,62 +113,22 @@ def model_config(config: dict, name: str, rehearse: bool, tp: int = 1):
         mc = mcfg.from_hf_config(tmp, name=name)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
+    family.check(config, mc)
     if rehearse:
-        # a rehearsal checks control flow on the CPU, not speed: the
-        # tiny debug widths, keeping what selects code paths
-        mc = dataclasses.replace(
-            mcfg.TINY_DEBUG, name=name, qkv_bias=mc.qkv_bias,
-            num_kv_heads=max(mcfg.TINY_DEBUG.num_kv_heads, tp),
-            rms_norm_eps=mc.rms_norm_eps, rope_theta=mc.rope_theta,
-            tie_word_embeddings=mc.tie_word_embeddings,
-            max_model_len=mc.max_model_len,
-        )
+        mc = family.rehearsal_config(mc, tp)
     return mcfg._register(mc)
 
 
-def make_params(mc, seed: int, dtype, mesh):
+def make_params(family, mc, seed: int, dtype, mesh):
     """All weights from the seed, on the device, under one jit."""
     import jax
-    import jax.numpy as jnp
 
-    h, i, v = mc.hidden_size, mc.intermediate_size, mc.vocab_size
     # the hardware generator: threefry would spend most of set-up here
     key = jax.random.fold_in(
         jax.random.key(seed % (1 << 31), impl="rbg"), seed >> 31)
 
-    def w(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32)
-                * fan_in ** -0.5).astype(dtype)
-
-    shapes = {
-        "wq": ((h, mc.q_size), h), "wk": ((h, mc.kv_size), h),
-        "wv": ((h, mc.kv_size), h), "wo": ((mc.q_size, h), mc.q_size),
-        "w_gate": ((h, i), h), "w_up": ((h, i), h), "w_down": ((i, h), i),
-    }
-    if mc.qkv_bias:
-        # non-zero, so that a dropped bias shows in the reference check
-        shapes |= {"bq": ((mc.q_size,), 4), "bk": ((mc.kv_size,), 4),
-                   "bv": ((mc.kv_size,), 4)}
-
-    def one_layer(k):
-        ks = jax.random.split(k, len(shapes))
-        lp = {n: w(ks[j], s, f) for j, (n, (s, f)) in
-              enumerate(sorted(shapes.items()))}
-        lp["attn_norm"] = jnp.ones((h,), dtype)
-        lp["mlp_norm"] = jnp.ones((h,), dtype)
-        return lp
-
     def init(k):
-        k_embed, k_head, k_layers = jax.random.split(k, 3)
-        params = {
-            "embed": w(k_embed, (v, h), h),
-            "layers": jax.lax.map(
-                one_layer, jax.random.split(k_layers, mc.num_layers)),
-            "final_norm": jnp.ones((h,), dtype),
-        }
-        if not mc.tie_word_embeddings:
-            params["lm_head"] = w(k_head, (h, v), h)
-        return params
+        return family.init_params(mc, k, dtype)
 
     kw = {}
     if mesh is not None:
@@ -224,8 +197,9 @@ def warm_programs(engine, context_floor: int, rehearse: bool) -> int:
 class Control:
     """What the parent may ask of the process that holds the chip."""
 
-    def __init__(self, mc, params, out_dir: str):
-        self.mc, self.params, self.out_dir = mc, params, out_dir
+    def __init__(self, family, mc, params, out_dir: str):
+        self.family, self.mc, self.params = family, mc, params
+        self.out_dir = out_dir
         self.trace_dir: str | None = None
 
     def memory(self) -> dict:
@@ -273,7 +247,8 @@ class Control:
 
         t0 = time.monotonic()
         lps = reference.teacher_forced_logprobs(
-            self.mc, self.params, body["prompt_ids"], body["generated_ids"])
+            self.family, self.mc, self.params, body["prompt_ids"],
+            body["generated_ids"])
         return {"logprobs": lps, "seconds": time.monotonic() - t0}
 
 
@@ -321,6 +296,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config-file", required=True)
     ap.add_argument("--config-name", required=True)
+    ap.add_argument("--family-file", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--port", type=int, required=True)
     ap.add_argument("--control-port", type=int, required=True)
@@ -359,13 +335,9 @@ def main() -> None:
         raise SystemExit(
             f"backend {jax.default_backend()!r} with rehearse={a.rehearse}: "
             "a rehearsal runs on the CPU and nothing else does")
-    mc = model_config(config, a.config_name, a.rehearse,
+    family = manifest.load_family(a.family_file)
+    mc = model_config(config, family, a.config_name, a.rehearse,
                       args.tensor_parallel_size)
-    if mc.qkv_bias != bool(config.get("qkv_bias", False)):
-        raise SystemExit(
-            f"{a.config_file} says qkv_bias={config.get('qkv_bias')!r}, the "
-            f"program's ModelConfig says {mc.qkv_bias}: opcount.py would "
-            "count other weights than are served")
     ecfg = config_from_args(args)
     mesh = None
     if ecfg.tensor_parallel_size > 1 or ecfg.pipeline_parallel_size > 1:
@@ -374,7 +346,7 @@ def main() -> None:
         mesh = sharding.make_serving_mesh(
             ecfg.tensor_parallel_size, ecfg.pipeline_parallel_size)
     t0 = time.monotonic()
-    params = make_params(mc, a.seed, jnp.dtype(ecfg.dtype), mesh)
+    params = make_params(family, mc, a.seed, jnp.dtype(ecfg.dtype), mesh)
     t_params = time.monotonic() - t0
     server = EngineServer(ecfg, params=params)
     engine = server.engine.engine
@@ -390,8 +362,8 @@ def main() -> None:
         "num_kv_blocks": engine.runner.num_blocks,
     }), flush=True)
     os.makedirs(a.out_dir, exist_ok=True)
-    httpd = serve_control(Control(mc, engine.runner.params, a.out_dir),
-                          a.control_port)
+    httpd = serve_control(
+        Control(family, mc, engine.runner.params, a.out_dir), a.control_port)
     try:
         server.run(host="127.0.0.1", port=a.port)
     finally:

@@ -4,9 +4,11 @@ the kernels took.
 Numerator: the context tokens the dispatched rounds' attention calls had
 to read once (the program's counter: each decode lane's context at each
 fused step, each prefill chunk's end context), times the KV bytes of a
-token (opcount.py), over the cell's chips, over the chip's peak memory
-bandwidth (peaks.json): seconds of pure KV streaming, as a share of the
-measured window. Denominator: the summed device time of the attention
+token (the cell's family, `ctx["family"]`: one constant a
+configuration, so a family whose layers read different numbers of
+tokens brings a reader of its own), over the cell's chips, over the
+chip's peak memory bandwidth (peaks.json): seconds of pure KV
+streaming, as a share of the measured window. Denominator: the summed device time of the attention
 kernels in the trace, as a share of the traced span. Each is a rate over
 its own steady span, so the 5 s trace and the whole window may differ in
 length. In percent.
@@ -38,7 +40,7 @@ def read(spec, ctx):
     if not kernel_s:
         return None
     tokens = sum(after[n] - before.get(n, 0.0) for n in spec["samples"])
-    nbytes = tokens * ctx["opcount"].kv_bytes_per_token(ctx["config"])
+    nbytes = tokens * ctx["family"].kv_bytes_per_token(ctx["config"])
     least_s = nbytes / ctx["chips"] / ctx["peak"]["hbm_bytes_per_s"]
     return ((least_s / ctx["window_s"])
             / (kernel_s / trace["window_s"]) * 100.0)

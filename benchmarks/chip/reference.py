@@ -1,29 +1,28 @@
-"""The plain reference: a Llama-class decoder's forward pass in float32.
+"""The plain reference, as far as it is common to every architecture.
 
-A copy of `tests/reference_model.py::dense_forward` (the original stays
-where the program's own tests use it), made float32 throughout under
-`jax.default_matmul_precision("highest")`: no kernel, no cache, no
-batching, a dense causal mask over the whole sequence. It follows the
-published architectures (Mistral, Qwen2: RMSNorm, rotary embedding on
-half-split head dims, grouped-query attention, SwiGLU, untied lm_head,
-and q/k/v biases where the configuration has them). One departure from a
-textbook loop: the layers are walked by `lax.scan` over the stacked
-weights and each layer's bf16 weights are upcast inside the step, so
-only one layer's float32 copy lives beside the serving cache, and the
-lm_head is applied to the asked rows only, in vocabulary slices.
+The forward pass itself is the family's (`forward_logprobs` of the file
+under `families/` that the cell's configuration names: float32, no
+kernel, no cache, no batching, nothing of the program imported). Here
+is what every family shares: the ids and the rows asked for, the jit
+under `jax.default_matmul_precision("highest")`, the comparison that
+decides `correct`, and its tolerances with their reasons.
 
 `teacher_forced_logprobs` is what `correct` is decided on: for a prompt
-and the tokens the served path generated after it, the reference's
-log-probability of each generated token given everything before it.
+and the tokens the served path generated after it, the family's
+reference log-probability of each generated token given everything
+before it.
 
 TOLERANCE. The served path computes in bfloat16 (8 bits of precision,
 relative rounding 2**-8 = 0.4%) with float32 accumulation, so its logits
 over 14-16 layers of random weights carry an absolute error of a few
-hundredths against float32. Measured on the chip at published widths
-(PERF.md, Findings, PR 23; 27 runs, 2 prompts of 8 tokens each), the
-served chosen-token log-probabilities differ from this reference by at
-most 0.033 at any position (mistral-7b-l16; 0.023 on qwen2-7b-l14), and
-by at most 0.012 in the mean over a prompt's 8 positions.
+hundredths against float32. The measurements below are the dense
+family's, the only one served so far; a family that is added reads its
+own before it relies on these limits. Measured on the chip at published
+widths (PERF.md, Findings, PR 23; 27 runs, 2 prompts of 8 tokens each),
+the served chosen-token log-probabilities differ from the dense
+reference by at most 0.033 at any position (mistral-7b-l16; 0.023 on
+qwen2-7b-l14), and by at most 0.012 in the mean over a prompt's 8
+positions.
 LOGPROB_ATOL = 0.1 is three times the worst position seen and far below
 what a wrong computation gives: a dropped q/k/v bias, a wrong GQA
 grouping, a wrong rope theta or a missing layer changes the chosen
@@ -41,83 +40,21 @@ from __future__ import annotations
 
 LOGPROB_ATOL = 0.1
 MEAN_ATOL = 0.03
-VOCAB_SLICES = 8
 
 
-def _forward_logprobs(cfg, params, token_ids, rows):
-    """log-softmax over the vocabulary at `rows` of a full forward pass
-    over `token_ids` (t,). Everything float32, precision highest."""
-    import jax
-    import jax.numpy as jnp
-
-    f32 = jnp.float32
-    t = token_ids.shape[0]
-    nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    eps = cfg.rms_norm_eps
-    pos = jnp.arange(t, dtype=f32)
-    half = d // 2
-    inv = 1.0 / (cfg.rope_theta ** (jnp.arange(half, dtype=f32) * 2.0 / d))
-    freqs = pos[:, None] * inv[None, :]
-    cos, sin = jnp.cos(freqs)[:, None, :], jnp.sin(freqs)[:, None, :]
-    mask = jnp.tril(jnp.ones((t, t), bool))
-
-    def rms(x, w):
-        n = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-        return n * (w.astype(f32) + cfg.norm_weight_offset)
-
-    def rope(x):
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-    def layer(h, lp):
-        lp = jax.tree.map(lambda a: a.astype(f32), lp)
-        x = rms(h, lp["attn_norm"])
-        q, k, v = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
-        if cfg.qkv_bias:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = rope(q.reshape(t, nq, d))
-        k = rope(k.reshape(t, nkv, d))
-        v = v.reshape(t, nkv, d)
-        qg = q.reshape(t, nkv, nq // nkv, d)
-        s = jnp.einsum("tkgd,skd->tkgs", qg, k) * (d ** -0.5)
-        s = jnp.where(mask[:, None, None, :], s, -1e30)
-        o = jnp.einsum("tkgs,skd->tkgd", jax.nn.softmax(s, -1), v)
-        h = h + o.reshape(t, nq * d) @ lp["wo"]
-        x = rms(h, lp["mlp_norm"])
-        h = h + (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])
-                 ) @ lp["w_down"]
-        return h, None
-
-    h = params["embed"][token_ids].astype(f32) * cfg.embed_scale
-    h, _ = jax.lax.scan(layer, h, params["layers"])
-    h = rms(h, params["final_norm"])[rows]
-    lm = (params["embed"].T if cfg.tie_word_embeddings
-          else params["lm_head"])
-    v = lm.shape[1]
-    step = -(-v // VOCAB_SLICES)
-    logits = jnp.concatenate([
-        h @ lm[:, i:i + step].astype(f32) for i in range(0, v, step)
-    ], -1)
-    return jax.nn.log_softmax(logits, -1)
-
-
-def teacher_forced_logprobs(cfg, params, prompt_ids, generated_ids):
-    """The reference's log-probability of each generated token, given
-    the prompt and the generated tokens before it. Plain floats."""
+def teacher_forced_logprobs(family, cfg, params, prompt_ids,
+                            generated_ids):
+    """The family's reference log-probability of each generated token,
+    given the prompt and the generated tokens before it. Plain floats."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    if cfg.is_moe or cfg.sliding_window or cfg.hidden_act != "silu":
-        raise NotImplementedError(
-            "the reference covers dense SwiGLU decoders with full "
-            "attention; add the mechanism here with its configuration")
     ids = np.asarray(list(prompt_ids) + list(generated_ids), np.int32)
     n_p, n_g = len(prompt_ids), len(generated_ids)
     rows = np.arange(n_p - 1, n_p - 1 + n_g, dtype=np.int32)
     with jax.default_matmul_precision("highest"):
-        lp = jax.jit(_forward_logprobs, static_argnums=0)(
+        lp = jax.jit(family.forward_logprobs, static_argnums=0)(
             cfg, params, jnp.asarray(ids), jnp.asarray(rows))
     lp = np.asarray(lp)
     return [float(lp[i, g]) for i, g in enumerate(generated_ids)]

@@ -50,7 +50,6 @@ sys.path.insert(0, HERE)
 
 import loadgen  # noqa: E402
 import manifest  # noqa: E402
-import opcount  # noqa: E402
 
 ENGINE_READY_S = 1100
 ROUTER_READY_S = 60
@@ -165,6 +164,7 @@ def start_children(cell, args, out_dir: str, children: list) -> dict:
         argv = [os.path.join(HERE, "engine_child.py"),
                 "--config-file", cell.config_file,
                 "--config-name", cell.config_name,
+                "--family-file", cell.family_file,
                 "--seed", str(args.seed), "--port", str(port),
                 "--control-port", str(control), "--out-dir", out_dir,
                 "--context-floor-tokens",
@@ -360,6 +360,9 @@ def end_to_end_values(cell, driver, t0: float, t1: float) -> dict:
 
 
 def run_cell(args, cell, out_dir: str, children: list) -> dict | None:
+    # the cell's family, for its counts (readers divide by its bytes);
+    # a family file that is not there stops the run before any child
+    family = manifest.load_family(cell.family_file)
     sys_ = start_children(cell, args, out_dir, children)
     engine = sys_["engines"][0]
     with open(engine["child"].log_path, errors="replace") as f:
@@ -425,7 +428,7 @@ def run_cell(args, cell, out_dir: str, children: list) -> dict | None:
             "router_after": scr["router_after"],
             "records": e2e["window"], "trace": reduced,
             "config": cell.config, "chips": cell.chips, "peak": peak_kind,
-            "opcount": opcount, "loadgen": loadgen,
+            "family": family, "loadgen": loadgen,
             "window_s": t1 - t0,
         }
         metrics = manifest.read_layer_metrics(cell, ctx)

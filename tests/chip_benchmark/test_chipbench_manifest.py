@@ -134,23 +134,99 @@ def test_every_cell_reports_setup_another_metric_and_a_layer_metric(cell):
     assert set(loaded.peaks) == {"TPU v5 lite"}
 
 
-@pytest.mark.parametrize("entry", M["configs"], ids=lambda c: c["name"])
-def test_configuration_file_holds_the_published_keys(entry):
-    engine_child = _load("engine_child")
+def _configuration(entry):
     with open(os.path.join(ROOT, entry["file"])) as f:
         cfg = json.load(f)
+    family = manifest.load_family(
+        os.path.join(BENCH, "families", cfg["family"] + ".py"))
+    return cfg, family
+
+
+@pytest.mark.parametrize("entry", M["configs"], ids=lambda c: c["name"])
+def test_configuration_file_is_what_the_manifest_says(entry):
+    """What the contract asks of EVERY configuration, whatever its
+    architecture."""
+    cfg, _ = _configuration(entry)
     assert cfg["source"] == entry["source"]
     assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
     assert not any(WIDTH.search(k) for k in entry["reduced"])
-    hf = engine_child.hf_config_of(cfg)
-    assert "engine_args" not in hf and hf["hidden_size"] in (4096, 3584)
-    mc = engine_child.model_config(cfg, "test-" + entry["name"], False)
-    assert mc.head_dim == 128 and mc.sliding_window is None
-    assert mc.num_layers == hf["num_hidden_layers"]
-    assert mc.qkv_bias == cfg["qkv_bias"] and "qkv_bias" not in hf
-    assert mc.rms_norm_eps == hf["rms_norm_eps"]
-    from production_stack_tpu.models import config as mcfg
-    mcfg._PRESETS.pop(mc.name)
+    assert set(manifest.COMMON_KEYS) <= set(cfg)
+
+
+@pytest.mark.parametrize("entry", M["configs"], ids=lambda c: c["name"])
+def test_configurations_family_is_a_file_that_gives_the_whole_contract(
+        entry):
+    cfg, family = _configuration(entry)
+    assert NAME.match(cfg["family"])
+    for name in manifest.FAMILY_API:
+        assert callable(getattr(family, name))
+    # the cell finds the same file by the same name
+    cell = next(w["name"] for w in M["workloads"]
+                if w["config"] == entry["name"])
+    assert os.path.samefile(manifest.load_cell(cell).family_file,
+                            family.__file__)
+    for count in manifest.FAMILY_COUNTS:
+        assert getattr(family, count)(cfg) > 0
+
+
+@pytest.mark.parametrize("entry", M["configs"], ids=lambda c: c["name"])
+def test_configuration_reaches_the_program_and_passes_its_familys_check(
+        entry):
+    engine_child = _load("engine_child")
+    cfg, family = _configuration(entry)
+    hf = engine_child.hf_config_of(cfg, family)
+    assert not set(hf) & set(manifest.COMMON_KEYS)
+    # model_config runs the family's check
+    mc = engine_child.model_config(cfg, family, "test-" + entry["name"],
+                                   False)
+    try:
+        assert mc.num_layers == cfg["num_hidden_layers"]
+        small = family.rehearsal_config(mc, 1)
+        assert small.name == mc.name
+        assert small.hidden_size < mc.hidden_size
+    finally:
+        from production_stack_tpu.models import config as mcfg
+        mcfg._PRESETS.pop(mc.name)
+
+
+# what each configuration was chosen for, asked of the configuration it
+# belongs to and of no other
+DENSE_CONFIGS = {
+    "mistral-7b-l16": {"hidden_size": 4096, "qkv_bias": False,
+                       "num_kv_heads": 8, "rms_norm_eps": 1e-5},
+    "qwen2-7b-l14": {"hidden_size": 3584, "qkv_bias": True,
+                     "num_kv_heads": 4, "rms_norm_eps": 1e-6},
+}
+
+
+def test_the_cases_by_name_are_dense_configurations_of_the_benchmark():
+    # one way only: a later dense configuration comes as data files and
+    # needs no case here (`dense.check` holds it to its ModelConfig)
+    dense = {c["name"] for c in M["configs"]
+             if _configuration(c)[0]["family"] == "dense"}
+    assert set(DENSE_CONFIGS) <= dense
+
+
+@pytest.mark.parametrize("name", sorted(DENSE_CONFIGS))
+def test_dense_configuration_holds_the_published_keys(name):
+    engine_child = _load("engine_child")
+    want = DENSE_CONFIGS[name]
+    entry = next(c for c in M["configs"] if c["name"] == name)
+    cfg, family = _configuration(entry)
+    assert cfg["family"] == "dense" and family.OWN_KEYS == ("qkv_bias",)
+    hf = engine_child.hf_config_of(cfg, family)
+    assert "engine_args" not in hf and "qkv_bias" not in hf
+    assert hf["hidden_size"] == want["hidden_size"]
+    mc = engine_child.model_config(cfg, family, "test-dense-" + name, False)
+    try:
+        assert mc.head_dim == 128 and mc.sliding_window is None
+        assert mc.qkv_bias == cfg["qkv_bias"] == want["qkv_bias"]
+        assert mc.num_kv_heads == want["num_kv_heads"]
+        assert mc.rms_norm_eps == hf["rms_norm_eps"] == want["rms_norm_eps"]
+        assert not mc.is_moe and mc.hidden_act == "silu"
+    finally:
+        from production_stack_tpu.models import config as mcfg
+        mcfg._PRESETS.pop(mc.name)
 
 
 def test_tokenizer_stand_in_is_one_reversible_character_per_id():
@@ -231,6 +307,7 @@ def test_a_later_pr_adds_a_cell_with_new_files_and_entries_only(tmp_path):
 
     cell = manifest.load_cell("qwen2-7b-l7.short-only", root=str(root))
     assert cell.config["num_hidden_layers"] == 7 and cell.chips == 1
+    assert cell.family_file == str(bench / "families" / "dense.py")
     assert cell.traffic["rate_rps"] == 12.5
     assert cell.traffic["prompt_tokens"] == {"dist": "fixed", "n": 64}
     assert [x["name"] for x in cell.end_to_end] == [

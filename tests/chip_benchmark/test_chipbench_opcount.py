@@ -1,5 +1,6 @@
-"""opcount.py against the figures the configurations were chosen by,
-and the plain reference against the program's own dense reference."""
+"""The dense family's counts against the figures the configurations were
+chosen by, and its plain reference, through `reference.py`, against the
+program's own dense reference."""
 
 import importlib.util
 import json
@@ -21,7 +22,7 @@ def _load(name, sub=""):
     return mod
 
 
-opcount = _load("opcount")
+opcount = _load("dense", "families")     # the dense family's counts
 
 
 def hf(name, **over):
@@ -75,7 +76,7 @@ def test_weight_stream_share_is_bytes_over_bandwidth_over_step_time():
     with open(os.path.join(BENCH, "peaks.json")) as f:
         peak = json.load(f)["TPU v5 lite"]
     least_ms = opcount.layer_stack_bytes(c) / 819e9 * 1e3
-    ctx = {"config": c, "chips": 1, "peak": peak, "opcount": opcount,
+    ctx = {"config": c, "chips": 1, "peak": peak, "family": opcount,
            "read": lambda name: 2 * least_ms}
     assert reader.read({"step_metric": "x"}, ctx) == pytest.approx(50.0)
     ctx["read"] = lambda name: None
@@ -126,6 +127,8 @@ def test_reference_agrees_with_the_programs_dense_reference_with_biases():
     from production_stack_tpu.models import llama
 
     reference = _load("reference")
+    dense = _load("manifest").load_family(
+        os.path.join(BENCH, "families", "dense.py"))
     mc = dataclasses.replace(mcfg.TINY_DEBUG, name="t", qkv_bias=True,
                              tie_word_embeddings=False, rms_norm_eps=1e-6)
     params = llama.init_params(mc, jax.random.key(0), jnp.float32)
@@ -134,7 +137,7 @@ def test_reference_agrees_with_the_programs_dense_reference_with_biases():
         params["layers"][b] = 0.5 * jax.random.normal(
             k[i], params["layers"][b].shape, jnp.float32)
     prompt, gen = list(range(5, 45)), [7, 300, 12, 99]
-    got = reference.teacher_forced_logprobs(mc, params, prompt, gen)
+    got = reference.teacher_forced_logprobs(dense, mc, params, prompt, gen)
     logits = dense_forward(mc, params, prompt + gen)
     want = np.asarray(jax.nn.log_softmax(logits, -1))
     for i, g in enumerate(gen):
@@ -144,5 +147,5 @@ def test_reference_agrees_with_the_programs_dense_reference_with_biases():
         **params["layers"],
         "bq": jnp.zeros_like(params["layers"]["bq"]),
         "bk": jnp.zeros_like(params["layers"]["bk"])})
-    off = reference.teacher_forced_logprobs(mc, zeroed, prompt, gen)
+    off = reference.teacher_forced_logprobs(dense, mc, zeroed, prompt, gen)
     assert max(abs(a - b) for a, b in zip(off, got)) > 1e-3

@@ -30,7 +30,7 @@ def spec_of(metric):
 
 
 tr = _load("trace_reduce")
-opcount = _load("opcount")
+opcount = _load("dense", "families")     # the dense family's counts
 gap_share = _load("trace_gap_share", READERS).read
 module_ms = _load("trace_module_ms", READERS).read
 kv_share = _load("attn_kv_stream_share", READERS).read
@@ -110,7 +110,7 @@ def test_kv_stream_share_against_numbers_worked_by_hand():
         "engine_before": {"tpu:attn_context_tokens_sum": 1.0e6},
         "engine_after": {"tpu:attn_context_tokens_sum": 1.0e6 + 3.125e8},
         "window_s": 50.0, "chips": 1, "config": config,
-        "opcount": opcount, "peak": {"hbm_bytes_per_s": 819.2e9},
+        "family": opcount, "peak": {"hbm_bytes_per_s": 819.2e9},
     }
     # 3.125e8 tokens x 65,536 B = 2.048e13 B; / 819.2e9 B/s = 25 s of
     # streaming in a 50 s window = 0.5; the kernels ran 2.5 s of a 5 s
