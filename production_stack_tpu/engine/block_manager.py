@@ -13,6 +13,14 @@ hit (same design as vLLM's prefix caching / LMCache's local backend).
 
 Block 0 is reserved as the null/trash block: padded batch lanes write their
 garbage K/V there, so it is never handed to a sequence.
+
+A model whose window-attention layers keep their own KV arrays (a second
+CACHE GROUP, models/layer_groups.py) is served by `WindowedBlockManager`:
+the pool above stays THE pool — its ids fill every sequence's table, its
+hash chain decides prefix hits — and the window group's smaller pool hangs
+on it through one block map (primary id -> window-group id, 0 = not
+resident). A sequence holds a window-group block only while some position
+in it can still fall inside a later query's window.
 """
 
 from __future__ import annotations
@@ -328,6 +336,17 @@ class BlockManager:
                 else:
                     self.free_blocks.append(bid)
 
+    def prepare_chunk(self, block_table: list[int], start: int,
+                      end: int) -> None:
+        """Before a dispatch computes positions [start, end) of the
+        table's sequence. One pool, nothing to do: the table already
+        covers them (WindowedBlockManager overrides)."""
+
+    def release_behind(self, block_table: list[int], next_pos: int) -> None:
+        """The sequence's next query is at `next_pos` and none will come
+        before it. One pool keeps every block (WindowedBlockManager
+        overrides)."""
+
     def free(self, block_table: list[int]) -> None:
         """Release a sequence's references; cached blocks become evictable."""
         # table-identity epoch: freed block ids may be handed to another
@@ -354,3 +373,204 @@ class BlockManager:
         if freed_cached and self.on_freed_cached is not None:
             # one batched d2h export per freed sequence (see kv/offload.py)
             self.on_freed_cached(freed_cached)
+
+
+class WindowTable(list):
+    """A sequence's block table (primary ids, as every program ships
+    them) that also says which of its blocks the sequence holds in the
+    window group: those at indices [lo, hi). Below `lo` they were
+    released (or, after a prefix hit, never taken); from `hi` on they
+    are not allocated there yet."""
+    __slots__ = ("lo", "hi")
+
+    def __init__(self, ids=(), lo: int = 0, hi: int = 0):
+        super().__init__(ids)
+        self.lo, self.hi = lo, hi
+
+
+class WindowedBlockManager(BlockManager):
+    """BlockManager plus the window cache group's pool.
+
+    The per-sequence table of the window group is the primary table
+    mapped through `block_map` — on the device too (the runner uploads
+    the map when `map_version` moved), so no program ships a second
+    table. A window-group block ("twin") belongs to ONE primary block
+    for as long as it is resident:
+
+    - allocated when a dispatch is about to write the primary block's
+      positions (`prepare_chunk`, `ensure_capacity`), referenced by the
+      sequence;
+    - referenced again by every sequence that adopts the primary block
+      on a prefix hit AND still needs it (the last `window` positions
+      before the hit's end);
+    - released by a sequence once every position in the block lies
+      behind `next query - window` (`release_behind`), or with the
+      sequence (`free`);
+    - at reference count 0: freed at once if the primary block is not
+      hash-registered, else kept evictable (LRU) so that a later prefix
+      hit can end there; dropped when the primary block is evicted.
+
+    A prefix hit of n blocks is granted only where the window group
+    still has the blocks covering the last `window` positions before
+    n * block_size; otherwise it is cut back to the longest n for which
+    that holds (`match_prefix`).
+
+    The pool is sized by the runner for every lane's window and chunk at
+    once plus as much again for cached prefixes (`ModelRunner.
+    _window_blocks_needed`), so the lanes the scheduler admits (at most
+    max_num_seqs) can always be served after evicting cached twins:
+    running out is a bug, and raises."""
+
+    def __init__(self, num_blocks: int, block_size: int,
+                 enable_prefix_caching: bool, *, window: int,
+                 num_window_blocks: int):
+        super().__init__(num_blocks, block_size, enable_prefix_caching)
+        import numpy as np
+
+        if num_window_blocks < 2:
+            raise ValueError("the window group needs at least 2 blocks")
+        self.window = window
+        self.num_window_blocks = num_window_blocks
+        # primary block id -> window-group block id (0 = not resident);
+        # the array the runner uploads
+        self.block_map = np.zeros((num_blocks,), np.int32)
+        self.map_version = 0
+        self._wfree: list[int] = list(range(num_window_blocks - 1, 0, -1))
+        self._wref = [0] * num_window_blocks
+        self._wowner = [0] * num_window_blocks
+        # twin id -> None, LRU: reference count 0, primary hash-registered
+        self._wevictable: OrderedDict[int, None] = OrderedDict()
+        self.window_blocks_released = 0
+
+    # -- the window pool ----------------------------------------------------
+    @property
+    def window_blocks_in_use(self) -> int:
+        """Twins some sequence references."""
+        return (self.num_window_blocks - 1 - len(self._wfree)
+                - len(self._wevictable))
+
+    def _walloc(self, bid: int) -> None:
+        assert not self.block_map[bid], f"block {bid} already has a twin"
+        if self._wfree:
+            w = self._wfree.pop()
+        elif self._wevictable:
+            w, _ = self._wevictable.popitem(last=False)
+            self.block_map[self._wowner[w]] = 0
+        else:
+            raise RuntimeError(
+                "out of window-group KV blocks: the pool is sized for "
+                "every lane at once, so this is a bookkeeping bug"
+            )
+        self.block_map[bid] = w
+        self._wowner[w] = bid
+        self._wref[w] = 1
+        self.map_version += 1
+
+    def _wtake(self, bid: int) -> None:
+        w = int(self.block_map[bid])
+        assert w, f"block {bid} has no twin to take"
+        if self._wref[w] == 0:
+            del self._wevictable[w]
+        self._wref[w] += 1
+
+    def _wrelease(self, bid: int) -> None:
+        w = int(self.block_map[bid])
+        assert w and self._wref[w] > 0, f"twin of block {bid} not held"
+        self._wref[w] -= 1
+        if self._wref[w]:
+            return
+        if self.blocks[bid].block_hash is not None:
+            self._wevictable[w] = None
+        else:
+            self._drop_twin(bid)
+
+    def _drop_twin(self, bid: int) -> None:
+        w = int(self.block_map[bid])
+        if not w:
+            return
+        assert self._wref[w] == 0, f"twin of block {bid} still referenced"
+        self._wevictable.pop(w, None)
+        self._wfree.append(w)
+        self.block_map[bid] = 0
+        self.map_version += 1
+
+    def _pop_free_block(self) -> int:
+        bid = super()._pop_free_block()
+        # a primary block that starts a new life (evicted from the
+        # cache, or freed unregistered) takes no twin along
+        self._drop_twin(bid)
+        return bid
+
+    def _tail_start(self, n_blocks: int) -> int:
+        """First block a query at position n_blocks * block_size still
+        attends in the window group."""
+        first_key = n_blocks * self.block_size - self.window + 1
+        return max(0, first_key // self.block_size)
+
+    # -- sequence-level API -------------------------------------------------
+    def match_prefix(self, token_ids: list[int],
+                     seed: int = 0) -> tuple[list[int], int]:
+        matched, _ = super().match_prefix(token_ids, seed)
+        # allocate_prompt computes at least one token: check the window
+        # at the boundary it will really start from
+        n = min(len(matched), (len(token_ids) - 1) // self.block_size)
+        while n > 0 and not all(
+            self.block_map[b] for b in matched[self._tail_start(n):n]
+        ):
+            n -= 1
+        return matched[:n], n * self.block_size
+
+    def allocate_prompt(self, token_ids, seed: int = 0,
+                        reuse_cache: bool = True):
+        alloc = super().allocate_prompt(token_ids, seed, reuse_cache)
+        if alloc is None:
+            return None
+        table, cached = alloc
+        n = cached // self.block_size
+        table = WindowTable(table, lo=self._tail_start(n), hi=n)
+        for bid in table[table.lo:table.hi]:
+            self._wtake(bid)
+        return table, cached
+
+    def _extend(self, table: WindowTable, upto: int) -> None:
+        """Twins for the table's blocks [hi, upto)."""
+        for i in range(table.hi, min(upto, len(table))):
+            bid = table[i]
+            if self.block_map[bid]:
+                self._wtake(bid)  # resident already: share it
+            else:
+                self._walloc(bid)
+            table.hi = i + 1
+
+    def ensure_capacity(self, num_tokens: int, block_table) -> bool:
+        if not super().ensure_capacity(num_tokens, block_table):
+            return False
+        self._extend(block_table, len(block_table))
+        return True
+
+    def prepare_chunk(self, block_table, start: int, end: int) -> None:
+        self.release_behind(block_table, start)
+        self._extend(block_table, -(-end // self.block_size))
+
+    def release_behind(self, block_table, next_pos: int) -> None:
+        if not isinstance(block_table, WindowTable):
+            return  # already freed (a finished lane still in a batch)
+        # block i lies wholly behind the window of every later query
+        # when its last position (i+1)*bs - 1 <= next_pos - window
+        new_lo = min(
+            max(0, (next_pos - self.window + 1) // self.block_size),
+            len(block_table),
+        )
+        for i in range(block_table.lo, min(new_lo, block_table.hi)):
+            self._wrelease(block_table[i])
+            self.window_blocks_released += 1
+        if new_lo > block_table.lo:
+            block_table.lo = new_lo
+            block_table.hi = max(block_table.hi, new_lo)
+
+    def free(self, block_table) -> None:
+        if isinstance(block_table, WindowTable):
+            for bid in block_table[block_table.lo:block_table.hi]:
+                self._wrelease(bid)
+            block_table.lo = block_table.hi = len(block_table)
+        super().free(block_table)

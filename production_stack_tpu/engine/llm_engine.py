@@ -14,7 +14,10 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from production_stack_tpu.engine.block_manager import BlockManager
+from production_stack_tpu.engine.block_manager import (
+    BlockManager,
+    WindowedBlockManager,
+)
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.model_runner import ModelRunner
 from production_stack_tpu.engine.outputs import (
@@ -64,11 +67,26 @@ class LLMEngine:
                 # host 0 only: followers never construct an LLMEngine,
                 # they run multihost_engine.follower_loop on a bare runner
                 wrap_engine_for_multihost(self)
-        self.block_manager = BlockManager(
-            num_blocks=self.runner.num_blocks,
-            block_size=config.block_size,
-            enable_prefix_caching=config.enable_prefix_caching,
-        )
+        if self.runner.num_window_blocks:
+            # a model with a windowed cache group: the second pool hangs
+            # on the first through one block map, which the runner
+            # uploads when it moved (block_manager.WindowedBlockManager)
+            from production_stack_tpu.models import layer_groups
+
+            mc = self.runner.model_config
+            self.block_manager = WindowedBlockManager(
+                self.runner.num_blocks, config.block_size,
+                config.enable_prefix_caching,
+                window=mc.attn_kinds[layer_groups.mapped_kind(mc)].window,
+                num_window_blocks=self.runner.num_window_blocks,
+            )
+            self.runner.block_map_source = self.block_manager
+        else:
+            self.block_manager = BlockManager(
+                num_blocks=self.runner.num_blocks,
+                block_size=config.block_size,
+                enable_prefix_caching=config.enable_prefix_caching,
+            )
         self.scheduler = Scheduler(
             SchedulerConfig(
                 max_num_seqs=config.max_num_seqs,
@@ -139,6 +157,10 @@ class LLMEngine:
         # number and not by clock
         self.phases = self.runner.phases
         self._round = 0
+        # (window-group blocks in use, running sequences) summed over
+        # the dispatched rounds: their ratio is the blocks a sequence
+        # holds there, the proof in a run that the release works
+        self._window_blocks_per_seq = [0, 0]
         # the round whose tokens the events being recorded belong to:
         # the round just dispatched, or (async decode) the in-flight one
         # being resolved a step later
@@ -1534,6 +1556,10 @@ class LLMEngine:
         first; every round still takes a number."""
         self._round += 1
         self._event_round = self._round
+        if self.runner.num_window_blocks:
+            held = self._window_blocks_per_seq
+            held[0] += self.block_manager.window_blocks_in_use
+            held[1] += self.scheduler.num_running
         ann = self._step_ann
         if ann is not None and not self._step_tagged:
             self._step_tagged = True
@@ -2027,6 +2053,7 @@ class LLMEngine:
         fusable, else split execution of the SAME plan (both halves
         still run this engine step, so the no-interleave-wait
         scheduling contract holds either way)."""
+        self._prepare_chunks(works)
         seqs = dwork.seqs
         k_steps = dwork.k
         # decode-half gates mirror _run_decode_round's fused path; the
@@ -2533,6 +2560,25 @@ class LLMEngine:
         }
         self.scheduler.staged_prefill_ready = True
 
+    def _prepare_chunks(self, works: list[PrefillWork]) -> None:
+        """Every prefill chunk passes here right before its dispatch,
+        scheduled, chained or split alike: a block manager with a second
+        cache group releases what the chunk's sequence left behind its
+        window and makes room for the chunk's positions there (a no-op
+        with one pool)."""
+        if not self.runner.num_window_blocks:
+            return
+        with phases.annotation("engine.kv_release", chunks=len(works)):
+            for w in works:
+                # blocks that chunks chained earlier in this step
+                # filled: content-addressed BEFORE their twins are let
+                # go, so that the twins stay for a later prefix hit
+                self._register_full_blocks(w.seq)
+                self.block_manager.prepare_chunk(
+                    w.seq.block_table, w.chunk_start,
+                    w.chunk_start + w.chunk_len,
+                )
+
     def _run_prefill_works(
         self, works: list[PrefillWork], staged: dict | None = None,
         chained: bool = False,
@@ -2545,6 +2591,7 @@ class LLMEngine:
         fingerprint matches this exact group. `chained` marks groups
         dispatched by cold-prompt chaining (no host round-trip since the
         previous group) for the timeline."""
+        self._prepare_chunks(works)
         stepped: list[Sequence] = []
         now = time.time()
         for w in works:
@@ -3692,6 +3739,34 @@ class LLMEngine:
         return [self.embed_one(t, lora_name)[0] for t in texts]
 
     # -- stats for /metrics -------------------------------------------------
+    def _layer_group_stats(self) -> dict:
+        """The snapshot fields of a model of layer groups (none for a
+        model of alike layers). All host memory: nothing here waits
+        for the device. The caller holds the step lock."""
+        mc = self.runner.model_config
+        if not mc.layer_groups:
+            return {}
+        names = ["window" if ak.window else "full" for ak in mc.attn_kinds]
+        bm = self.block_manager
+        in_use = {"full": round(bm.usage * (bm.num_blocks - 1))}
+        out = {}
+        if self.runner.num_window_blocks:
+            in_use["window"] = bm.window_blocks_in_use
+            out = {
+                "kv_window_blocks_released_total":
+                    bm.window_blocks_released,
+                "kv_window_blocks_per_seq":
+                    tuple(self._window_blocks_per_seq),
+            }
+        return {
+            "attn_context_by_kind": {
+                n: c[0] for n, c in zip(
+                    names, self.runner.attn_context_by_kind)},
+            "moe_stats": self.runner.moe_stats(),
+            "kv_blocks_in_use": in_use,
+            **out,
+        }
+
     def stats(self) -> EngineStatsSnapshot:
         _remote = self.offload.remote if self.offload is not None else None
         return EngineStatsSnapshot(
@@ -3708,6 +3783,7 @@ class LLMEngine:
             spec_accepted_tokens_total=self._spec_accepted_total,
             engine_phases=self.phases.pairs(),
             attn_context_tokens=tuple(self.runner.attn_context_tokens),
+            **self._layer_group_stats(),
             program_stages=phases.program_stage_pairs(),
             program_cache_hits_total=phases.PROGRAM_CACHE_HITS[0],
             prefill_staged_hits_total=self._pf_staged_hits_total,

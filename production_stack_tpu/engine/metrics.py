@@ -136,6 +136,12 @@ class EngineMetrics:
                 "round had to read once (sum) per round (count): each "
                 "decode lane's context at each fused step, each "
                 "prefill chunk's end context",
+            "tpu:kv_window_blocks_per_seq":
+                "A model with a windowed cache group: window-group "
+                "blocks some sequence holds (sum) and running "
+                "sequences (count), both summed over the dispatched "
+                "rounds; their ratio stays near (window + chunk) / "
+                "block_size however long the contexts grow",
             **{
                 f"tpu:program_{st}_seconds": doc
                 for st, doc in (
@@ -147,6 +153,39 @@ class EngineMetrics:
             },
         })
         reg.register(self.pairs)
+        # a model of layer groups (models/layer_groups.py); zero for
+        # a model of alike layers. Names, not label values, tell the
+        # kinds apart (readers sum a sample over its label sets)
+        self.attn_context_kind = {
+            kind: Counter(
+                f"tpu:attn_context_tokens_{kind}",
+                f"Context tokens a `{kind}` attention layer of a "
+                "layer-group model read (the window kind cut to its "
+                "window): tpu:attn_context_tokens, per kind and layer",
+                label, registry=reg)
+            for kind in ("full", "window")
+        }
+        self.moe_rows = {
+            name: Counter(f"tpu:moe_{name}", doc, label, registry=reg)
+            for name, doc in (
+                ("routed_rows", "Routed expert layers: (row, expert) "
+                 "pairs routed, over all layers and fused steps"),
+                ("local_rows", "Routed expert layers: pairs whose "
+                 "expert is held by this engine (its expert-parallel "
+                 "rank's slice); over tpu:moe_routed_rows about "
+                 "1 / ep_size"),
+                ("active_experts", "Routed expert layers: local experts "
+                 "with at least one row, summed over layers and steps"),
+            )
+        }
+        self.kv_blocks_in_use = Gauge(
+            "tpu:kv_blocks_in_use",
+            "KV blocks some sequence references, per cache group",
+            ["model_name", "group"], registry=reg)
+        self.kv_window_released = Counter(
+            "tpu:kv_window_blocks_released",
+            "Window-group KV blocks a sequence let go because every "
+            "position in them lay behind its window", label, registry=reg)
         self.program_cache_hits = Counter(
             "tpu:program_cache_hits",
             "Programs served by jax's persistent compilation cache",
@@ -468,6 +507,20 @@ class EngineMetrics:
             self.pairs.set(
                 "tpu:admit_lock_wait_seconds", waits["admit_lock_wait"])
         self.pairs.set("tpu:attn_context_tokens", s.attn_context_tokens)
+        for kind, tokens in s.attn_context_by_kind.items():
+            self.attn_context_kind[kind].labels(m).inc(max(
+                0, tokens - prev.attn_context_by_kind.get(kind, 0)))
+        for name, now, was in zip(
+                ("routed_rows", "local_rows", "active_experts"),
+                s.moe_stats, prev.moe_stats):
+            self.moe_rows[name].labels(m).inc(max(0, now - was))
+        for group, blocks in s.kv_blocks_in_use.items():
+            self.kv_blocks_in_use.labels(m, group).set(blocks)
+        self.kv_window_released.labels(m).inc(max(
+            0, s.kv_window_blocks_released_total
+            - prev.kv_window_blocks_released_total))
+        self.pairs.set("tpu:kv_window_blocks_per_seq",
+                       s.kv_window_blocks_per_seq)
         for stage, pair in s.program_stages.items():
             self.pairs.set(f"tpu:program_{stage}_seconds", pair)
         self.program_cache_hits.labels(m).inc(max(

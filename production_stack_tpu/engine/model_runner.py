@@ -21,6 +21,7 @@ The attention inner op is chosen at construction: the XLA gather path
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu.engine.config import EngineConfig
-from production_stack_tpu.models import llama
+from production_stack_tpu.models import layer_groups, llama
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as xla_attn
 from production_stack_tpu.parallel import sharding as sharding_rules
@@ -97,6 +98,8 @@ class ModelRunner:
         self.max_model_len = config.resolved_max_model_len()
 
         mc = self.model_config
+        if mc.layer_groups:
+            self._refuse_for_layer_groups(config, mc)
         if mc.is_moe and mc.moe_capacity_factor > 0:
             # serving steps pad decode lanes / prefill buckets, and the
             # GShard capacity path has no per-row validity inside
@@ -141,6 +144,10 @@ class ModelRunner:
             self._forward = functools.partial(
                 pp_serving.forward_pp, mesh=self.mesh
             )
+        elif mc.layer_groups:
+            self._forward = functools.partial(
+                layer_groups.forward, block_size=config.block_size
+            )
         else:
             self._forward = llama.forward
 
@@ -158,7 +165,8 @@ class ModelRunner:
                 "tp=%d, pp=%d)",
                 mc.name, mc.num_params() / 1e9, config.dtype, tp, pp,
             )
-            init_fn = lambda key: llama.init_params(mc, key, self.dtype)
+            init = (layer_groups if mc.layer_groups else llama).init_params
+            init_fn = lambda key: init(mc, key, self.dtype)
             if self.mesh is not None:
                 # init directly into the TP layout: no transient replicated
                 # copy of the full weights on any single chip
@@ -173,27 +181,38 @@ class ModelRunner:
             params = sharding_rules.shard_params(params, self.mesh, mc)
         self.params = params
 
-        self.num_blocks = self._resolve_num_blocks()
         self.block_size = config.block_size
-        num_slots = self.num_blocks * self.block_size
-        # head-major (L, nkv, slots, d): the layout the Pallas kernels
-        # and the MXU want (see ops/pallas_attention.py docstring)
-        cache_shape = (
-            mc.num_layers, mc.num_kv_heads, num_slots, mc.head_dim
-        )
-        logger.info(
-            "allocating KV cache: %d blocks x %d slots (%.2f GiB)",
-            self.num_blocks, self.block_size,
-            2 * math.prod(cache_shape) * self.cache_dtype.itemsize / 2**30,
-        )
-        zeros = lambda: jnp.zeros(cache_shape, self.cache_dtype)
-        if self.mesh is not None:
-            zeros = jax.jit(
-                zeros,
-                out_shardings=sharding_rules.cache_sharding(self.mesh),
+        # the block manager of a model with a windowed cache group
+        # (WindowedBlockManager; the engine sets it): where the k_cache
+        # property reads the block map when it changed
+        self.block_map_source = None
+        self._map_version = None
+        if mc.layer_groups:
+            self._allocate_cache_groups()
+        else:
+            self.num_blocks = self._resolve_num_blocks()
+            self.num_window_blocks = 0
+            num_slots = self.num_blocks * self.block_size
+            # head-major (L, nkv, slots, d): the layout the Pallas
+            # kernels and the MXU want (see ops/pallas_attention.py
+            # docstring)
+            cache_shape = (
+                mc.num_layers, mc.num_kv_heads, num_slots, mc.head_dim
             )
-        self.k_cache = zeros()
-        self.v_cache = zeros()
+            logger.info(
+                "allocating KV cache: %d blocks x %d slots (%.2f GiB)",
+                self.num_blocks, self.block_size,
+                2 * math.prod(cache_shape) * self.cache_dtype.itemsize
+                / 2**30,
+            )
+            zeros = lambda: jnp.zeros(cache_shape, self.cache_dtype)
+            if self.mesh is not None:
+                zeros = jax.jit(
+                    zeros,
+                    out_shardings=sharding_rules.cache_sharding(self.mesh),
+                )
+            self.k_cache = zeros()
+            self.v_cache = zeros()
 
         self._scale = mc.head_dim**-0.5
         # attention impl: pallas paged kernel on TPU; under TP the kernel
@@ -209,7 +228,7 @@ class ModelRunner:
                 f"attention_impl must be auto|xla|pallas, got {impl!r}"
             )
         on_tpu = jax.default_backend() == "tpu"
-        if impl == "pallas" and on_tpu and mc.head_dim % 128:
+        if impl == "pallas" and on_tpu and self._unaligned_heads(mc):
             # Mosaic requires DMA slices aligned to the (8, 128) lane
             # tiling: a head_dim below 128 (e.g. Llama-3.2-1B's 64) pads
             # the cache's lane dim and every page slice becomes a partial
@@ -292,6 +311,10 @@ class ModelRunner:
         # had to read once, and the rounds counted (tpu:attn_context_
         # tokens): host integers the dispatch already holds
         self.attn_context_tokens = [0, 0]
+        # the same per attention kind of a layer-group model, tokens a
+        # LAYER of that kind read (tpu:attn_context_tokens_<kind>)
+        self._kind_windows = [ak.window for ak in mc.attn_kinds]
+        self.attn_context_by_kind = [[0] for _ in mc.attn_kinds]
         phases.install_program_listeners()
 
         # jit caches keyed by bucket tuple
@@ -317,6 +340,181 @@ class ModelRunner:
         self.compile_events_total = 0
 
         self.max_ctx_bucket = self._ctx_bucket(self.max_model_len)
+
+    @staticmethod
+    def _unaligned_heads(mc: ModelConfig) -> bool:
+        """True where the paged kernels cannot take the model's head
+        widths: a page slice must be whole (8, 128) tiles. A layer-group
+        model stores K padded to the next 128 lanes (`_k_store_dim`), so
+        only its V width has to be aligned."""
+        if mc.layer_groups:
+            return bool(mc.v_dim % 128)
+        return bool(mc.head_dim % 128)
+
+    def _k_store_dim(self) -> int:
+        """Lanes a K row takes in the cache. On the chip's kernel path
+        a q/k head width that is no multiple of the 128-lane tile is
+        stored with zero lanes up to the next one (192 -> 256): HBM
+        tiles the minor dim in 128s whatever the logical width, so the
+        bytes are the same as stored, and a page slice stays whole
+        tiles for the DMA (measured: PERF.md, Findings PR 28). q is
+        padded to match where the kernel is called (`_attn`)."""
+        mc, cfg = self.model_config, self.config
+        on_kernel_path = (
+            jax.default_backend() == "tpu"
+            and cfg.attention_impl in ("auto", "pallas")
+            and not self._unaligned_heads(mc)
+        )
+        if on_kernel_path and mc.head_dim % 128:
+            return -(-mc.head_dim // 128) * 128
+        return mc.head_dim
+
+    @staticmethod
+    def _refuse_for_layer_groups(config: EngineConfig, mc) -> None:
+        """A model of layer groups runs on one device through the
+        chunked-prefill and fused-decode programs. What has no code
+        path for it is refused here, by name, rather than run wrongly."""
+        refused = {
+            "--enable-lora": config.enable_lora,
+            "--tensor-parallel-size > 1": config.tensor_parallel_size > 1,
+            "--pipeline-parallel-size > 1":
+                config.pipeline_parallel_size > 1,
+            "multihost serving": bool(config.multihost),
+            "--num-speculative-tokens": config.num_speculative_tokens > 0,
+            "--long-prefill-threshold (the ring prefill lane)":
+                config.long_prefill_threshold is not None,
+            "KV offload tiers (--cpu-offload-gb, --disk-offload-dir, "
+            "--remote-cache-url)": bool(
+                config.cpu_offload_bytes or config.disk_offload_dir
+                or config.remote_cache_url),
+            "PD transfer (--kv-role / --kv-transfer-listen / --kv-peer)":
+                bool(config.kv_role) or any(
+                    (config.kv_transfer_config or {}).values()),
+        }
+        on = [name for name, flag in refused.items() if flag]
+        if on:
+            raise ValueError(
+                f"model {mc.name} is a stack of layer groups "
+                "(models/layer_groups.py: a KV cache per attention "
+                "kind), which does not serve with: " + "; ".join(on)
+            )
+
+    def _window_blocks_needed(self) -> int:
+        """Blocks of the windowed cache group: for every lane the
+        window behind its next query and the chunk (or fused decode
+        steps) ahead of it, and as much again as cached prefixes may
+        pin (the window before each lane's first computed token)."""
+        cfg = self.config
+        kind = layer_groups.mapped_kind(self.model_config)
+        if kind is None:
+            return 0
+        bs = cfg.block_size
+        win = -(-self.model_config.attn_kinds[kind].window // bs)
+        ahead = -(-max(cfg.max_prefill_chunk,
+                       2 * cfg.num_scheduler_steps) // bs)
+        return max(1, cfg.max_num_seqs) * (2 * win + ahead + 2) + 1
+
+    def _allocate_cache_groups(self) -> None:
+        """One K and one V array per attention kind, (L_kind, nkv_kind,
+        slots, d): kind 0's pool takes every token and is sized from
+        free HBM after the windowed kind's pool, which is sized by what
+        the lanes can hold at once (`_window_blocks_needed`)."""
+        mc, bs = self.model_config, self.block_size
+        item = self.cache_dtype.itemsize
+        dk, dv = self._k_store_dim(), mc.v_dim
+        mapped = layer_groups.mapped_kind(mc)
+        layers = [mc.layer_kinds.count(i) for i in range(len(mc.attn_kinds))]
+
+        def block_bytes(kind):
+            return (layers[kind] * mc.attn_kinds[kind].num_kv_heads
+                    * (dk + dv) * bs * item)
+
+        self.num_window_blocks = self._window_blocks_needed()
+        window_bytes = (
+            self.num_window_blocks * block_bytes(mapped)
+            if mapped is not None else 0
+        )
+        self.num_blocks = self._resolve_num_blocks(
+            bytes_per_block=sum(
+                block_bytes(i) for i in range(len(mc.attn_kinds))
+                if i != mapped),
+            reserve=window_bytes,
+        )
+        kg, vg = [], []
+        for i, ak in enumerate(mc.attn_kinds):
+            n = self.num_window_blocks if i == mapped else self.num_blocks
+            kg.append(jnp.zeros(
+                (layers[i], ak.num_kv_heads, n * bs, dk), self.cache_dtype))
+            vg.append(jnp.zeros(
+                (layers[i], ak.num_kv_heads, n * bs, dv), self.cache_dtype))
+        logger.info(
+            "allocating KV cache groups: %s (K stored at %d lanes, V at "
+            "%d; %.2f GiB)",
+            ", ".join(
+                f"kind {i}: {layers[i]} layers x {ak.num_kv_heads} kv "
+                f"heads x {a.shape[2] // bs} blocks"
+                for i, (ak, a) in enumerate(zip(mc.attn_kinds, kg))),
+            dk, dv,
+            sum(a.nbytes for a in kg + vg) / 2**30,
+        )
+        self._k_cache = {
+            "g": tuple(kg),
+            "map": jnp.zeros((self.num_blocks,), jnp.int32),
+        }
+        self.v_cache = {"g": tuple(vg)}
+        # the routed layers' counters: each program's own (`"stats"` in
+        # the K side it returns), on their way to the host, and summed
+        self._stats_pending: collections.deque = collections.deque()
+        self._stats_total = [0] * layer_groups.N_STATS
+
+    @property
+    def k_cache(self):
+        """The K side of the cache as the step programs take it. For a
+        model with a windowed cache group it carries the block map,
+        uploaded here when the block manager changed it since the last
+        dispatch (one small h2d, started before the program that needs
+        it and ordered before it on the device's stream)."""
+        src = self.block_map_source
+        if src is not None and src.map_version != self._map_version:
+            self._map_version = src.map_version
+            self._k_cache = {
+                **self._k_cache,
+                # a copy: the transfer may still read the host buffer
+                # while the scheduler plans the next round
+                "map": jnp.asarray(src.block_map.copy()),
+            }
+        return self._k_cache
+
+    @k_cache.setter
+    def k_cache(self, value) -> None:
+        """What a step program returned. A layer-group program's K side
+        carries that program's routed-layer counters (`_enter_caches`
+        starts them at zero): they are taken off here, before the next
+        dispatch donates the rest, and start their way to the host
+        beside the round's tokens."""
+        if isinstance(value, dict) and "stats" in value:
+            value = dict(value)
+            stats = value.pop("stats")
+            stats.copy_to_host_async()
+            self._stats_pending.append(stats)
+            self._drain_stats()
+        self._k_cache = value
+
+    def _drain_stats(self) -> None:
+        """Add the counters of every program that has finished to the
+        host's totals (Python ints: nothing wraps); never waits for one
+        that has not."""
+        pending = self._stats_pending
+        while pending and pending[0].is_ready():
+            for i, x in enumerate(np.asarray(pending.popleft())):
+                self._stats_total[i] += int(x)
+
+    def moe_stats(self) -> tuple[int, ...]:
+        """The routed layers' counters (layer_groups.N_STATS) of every
+        finished program since start-up. Reads host memory: a scrape
+        waits for no round. The caller holds the engine's step lock."""
+        self._drain_stats()
+        return tuple(self._stats_total)
 
     def device_report(self) -> dict:
         """What this runner runs on, as jax reports it (served by
@@ -347,18 +545,24 @@ class ModelRunner:
         return phases.annotation("engine.build", kind=kind, key=repr(key))
 
     # -- sizing -----------------------------------------------------------
-    def _resolve_num_blocks(self) -> int:
+    def _resolve_num_blocks(self, bytes_per_block: int | None = None,
+                            reserve: int = 0) -> int:
+        """Blocks of the (primary) pool: `--num-kv-blocks`, or what the
+        free HBM holds at `bytes_per_block` (a model of alike layers:
+        K and V of every layer) after `reserve` bytes for another
+        cache group."""
         cfg, mc = self.config, self.model_config
         if cfg.num_kv_blocks is not None:
             return cfg.num_kv_blocks
-        bytes_per_block = (
-            2
-            * mc.num_layers
-            * cfg.block_size
-            * mc.num_kv_heads
-            * mc.head_dim
-            * self.cache_dtype.itemsize
-        )
+        if bytes_per_block is None:
+            bytes_per_block = (
+                2
+                * mc.num_layers
+                * cfg.block_size
+                * mc.num_kv_heads
+                * mc.head_dim
+                * self.cache_dtype.itemsize
+            )
         tp = self.mesh.size if self.mesh is not None else 1
         # per-chip view: weights and KV blocks are both split ~1/tp.
         param_bytes = mc.num_params() * self.dtype.itemsize // tp
@@ -380,7 +584,7 @@ class ModelRunner:
                 "memory_stats(); cannot size the KV cache from HBM — "
                 "pass --num-kv-blocks"
             )
-        budget = int(limit * cfg.hbm_utilization) - reserved
+        budget = int(limit * cfg.hbm_utilization) - reserved - reserve
         num = max(2, budget // (bytes_per_block // tp))
         # cap: no point holding more than max_model_len * max_num_seqs * 2
         cap = (
@@ -390,50 +594,65 @@ class ModelRunner:
         )
         return int(min(num, max(cap, 2)))
 
-    def _pallas_smoke_test(self, mc: ModelConfig) -> None:
+    def _smoke_caches(self, mc: ModelConfig):
+        """(k, v, spec) of a four-block cache for each kernel variant
+        serving will compile: the model's one, or one per layer kind
+        (its kv heads, window and sink; K at its stored width)."""
         bs = self.block_size
-        d, nkv = mc.head_dim, mc.num_kv_heads
+        kinds = (
+            [(ak.num_kv_heads, layer_groups.AttnSpec(
+                window=ak.window,
+                sink=(jnp.zeros((mc.num_heads,), jnp.float32)
+                      if ak.sink else None),
+                block_map=(jnp.zeros((4,), jnp.int32)
+                           if ak.window else None),
+            )) for ak in mc.attn_kinds]
+            if mc.layer_groups else [(mc.num_kv_heads, None)]
+        )
+        for nkv, spec in kinds:
+            kc = jnp.zeros(
+                (1, nkv, 4 * bs, self._k_store_dim()), self.cache_dtype)
+            vc = jnp.zeros((1, nkv, 4 * bs, mc.v_dim), self.cache_dtype)
+            if self.mesh is not None:
+                # exercise the exact shard_map paths serving will take
+                cs = sharding_rules.cache_sharding(self.mesh)
+                kc, vc = jax.device_put(kc, cs), jax.device_put(vc, cs)
+            yield kc, vc, spec
+
+    def _pallas_smoke_test(self, mc: ModelConfig) -> None:
+        d = mc.head_dim
         # probe the exact kernel variants serving will compile — the
         # windowed page walk included (traced loop start + guarded
         # DMA); `_attn` routes through the shard_map TP wrappers under
         # a mesh, exactly as the step builders do
-        kc = jnp.zeros((1, nkv, 4 * bs, d), self.cache_dtype)
         q = jnp.zeros((1, mc.num_heads, d), self.dtype)
         tables = jnp.zeros((1, 2), jnp.int32)
         lens = jnp.ones((1,), jnp.int32)
         qp = jnp.zeros((8, mc.num_heads, d), self.dtype)
         table1 = jnp.zeros((2,), jnp.int32)
-        if self.mesh is not None:
-            # exercise the exact shard_map paths serving will take
-            kc = jax.device_put(
-                kc, sharding_rules.cache_sharding(self.mesh)
-            )
-        out = self._attn("decode", q, jnp.int32(0), kc, kc, tables,
-                         lens)
-        out2 = self._attn("prefill", qp, jnp.int32(0), kc, kc, table1,
-                          jnp.int32(0))
-        jax.block_until_ready((out, out2))
+        for kc, vc, spec in self._smoke_caches(mc):
+            out = self._attn("decode", q, jnp.int32(0), kc, vc, tables,
+                             lens, spec=spec)
+            out2 = self._attn("prefill", qp, jnp.int32(0), kc, vc,
+                              table1, jnp.int32(0), spec=spec)
+            jax.block_until_ready((out, out2))
 
     def _ragged_smoke_test(self, mc: ModelConfig) -> None:
         """Compile the unified ragged kernel in the grid shape serving
         dispatches: one prefill q-tile beside one decode row."""
-        bs = self.block_size
-        d, nkv = mc.head_dim, mc.num_kv_heads
-        kc = jnp.zeros((1, nkv, 4 * bs, d), self.cache_dtype)
-        if self.mesh is not None:
-            kc = jax.device_put(
-                kc, sharding_rules.cache_sharding(self.mesh)
-            )
         blk_seg = jnp.asarray([0, 1, 2], jnp.int32)
         seg_meta = jnp.asarray(
             [[0, 0, RAGGED_TQ, 0], [1, 0, 1, 0]], jnp.int32
         )
-        qr = jnp.zeros((2 * RAGGED_TQ, mc.num_heads, d), self.dtype)
-        out = self._attn(
-            "ragged", qr, jnp.int32(0), kc, kc,
-            jnp.zeros((2, 2), jnp.int32), blk_seg, seg_meta,
-        )
-        jax.block_until_ready(out)
+        qr = jnp.zeros(
+            (2 * RAGGED_TQ, mc.num_heads, mc.head_dim), self.dtype)
+        for kc, vc, spec in self._smoke_caches(mc):
+            out = self._attn(
+                "ragged", qr, jnp.int32(0), kc, vc,
+                jnp.zeros((2, 2), jnp.int32), blk_seg, seg_meta,
+                spec=spec,
+            )
+            jax.block_until_ready(out)
 
     def _step_jit_kwargs(self, n_host_outs: int = 1) -> dict:
         """Extra jit options for the prefill/decode step builders.
@@ -461,14 +680,21 @@ class ModelRunner:
             next_pow2(self.config.max_prefill_chunk),
         )
 
-    def _pin_cache_layout(self, kc, vc):
-        """Pin the KV caches to the row-major physical layout the Pallas
-        custom calls constrain their operands to.
+    def _enter_caches(self, kc, vc):
+        """What every step program does to the caches it was handed. A
+        layer-group cache gets the program's routed-layer counters, from
+        zero, where a step composed of steps has not yet given it them
+        (`k_cache`'s setter takes them off what the program returns).
+        The caches are then pinned to the row-major physical
+        layout the Pallas custom calls constrain their operands to.
 
-        Without this, XLA may pick a different layout for the scan body's
+        Without the pin, XLA may pick a different layout for the scan body's
         scatter (observed on v5e: {3,1,2,0} vs the kernel's {3,2,1,0})
         and insert a FULL-CACHE layout-conversion copy per step — 2 x
         3.8 GiB per step for the 3B model, which OOMed HBM outright."""
+        if isinstance(kc, dict) and "stats" not in kc:
+            kc = {**kc, "stats": jnp.zeros(
+                (layer_groups.N_STATS,), jnp.int32)}
         if self.attention_impl != "pallas" or (
             jax.default_backend() != "tpu"
         ):
@@ -476,15 +702,21 @@ class ModelRunner:
         from jax.experimental.layout import Layout, with_layout_constraint
 
         fmt = Layout((0, 1, 2, 3))
-        return (with_layout_constraint(kc, fmt),
-                with_layout_constraint(vc, fmt))
+
+        def pin(c):
+            if isinstance(c, dict):  # cache groups: the arrays under "g"
+                return {**c, "g": tuple(
+                    with_layout_constraint(a, fmt) for a in c["g"])}
+            return with_layout_constraint(c, fmt)
+
+        return pin(kc), pin(vc)
 
     # -- jitted step builders ---------------------------------------------
     # stackcheck: hot-path — the ONE dispatch seam every pallas
     # attention call goes through (trace-time only: closed over by the
     # jitted step builders); collapses the former per-site
     # `mesh is not None -> *_tp else *` call ladders
-    def _attn(self, kind: str, q, layer, kc, vc, *args):
+    def _attn(self, kind: str, q, layer, kc, vc, *args, spec=None):
         """Route one attention call to the pallas kernel for `kind`
         ("prefill" | "decode" | "ragged"), picking the shard_map TP
         variant under a mesh and filling the static block-size/scale/
@@ -517,9 +749,41 @@ class ModelRunner:
             interpret=jax.default_backend() != "tpu",
             window=self.model_config.sliding_window,
         )
+        if spec is not None:
+            # a layer kind of a layer-group model (layer_groups.AttnSpec):
+            # its own window and sink, and, for the windowed cache group,
+            # the lanes' tables (args[0] for every kernel) mapped into
+            # that group's pool
+            kw["window"] = spec.window
+            if spec.sink is not None:
+                kw["sink"] = spec.sink
+            if spec.block_map is not None:
+                args = (spec.block_map[args[0]], *args[1:])
+        if kc.shape[-1] > q.shape[-1]:
+            # K stored wider than d_k (`_k_store_dim`): zero lanes on
+            # both sides of the product
+            q = jnp.pad(
+                q, ((0, 0), (0, 0), (0, kc.shape[-1] - q.shape[-1])))
         if self.mesh is not None:
             return fns[1](q, kc, vc, layer, *args, mesh=self.mesh, **kw)
         return fns[0](q, kc, vc, layer, *args, **kw)
+
+    def _xla_ctx(self, kc, vc, l, slots, spec):
+        """The XLA path's gathered context of one layer, and the window
+        and sink its attention call takes: the model's one window, or
+        what the layer kind's `spec` says (the windowed cache group's
+        slots are the primary pool's mapped block by block)."""
+        window, sink = self.model_config.sliding_window, None
+        if spec is not None:
+            window, sink = spec.window, spec.sink
+            if spec.block_map is not None:
+                bs = self.block_size
+                slots = spec.block_map[slots // bs] * bs + slots % bs
+        # head-major cache + traced `l`: [l, :, slots] has two advanced
+        # indices split by a slice, so numpy hoists them to the front —
+        # the result is ALREADY (..., c, nkv, d)
+        return kc[l, :, slots], vc[l, :, slots], {
+            "window": window, "sink": sink}
 
     def _prefill_attn_closure(self):
         """The per-layer attention callback shared by the prefill and
@@ -534,24 +798,20 @@ class ModelRunner:
         scale = self._scale
         if self.attention_impl == "pallas":
 
-            def attn(q, l, kc, vc, gather_slots, q_positions, total_len):
+            def attn(q, l, kc, vc, gather_slots, q_positions, total_len,
+                     spec=None):
                 return self._attn(
                     "prefill", q, l, kc, vc, gather_slots,
-                    q_positions[0],
+                    q_positions[0], spec=spec,
                 )
         else:
 
-            window = self.model_config.sliding_window
-
-            def attn(q, l, kc, vc, gather_slots, q_positions, total_len):
-                # head-major cache + traced `l`: [l, :, slots] has two
-                # advanced indices split by a slice, so numpy hoists them
-                # to the front — the result is ALREADY (c, nkv, d)
-                k_ctx = kc[l, :, gather_slots]
-                v_ctx = vc[l, :, gather_slots]
+            def attn(q, l, kc, vc, gather_slots, q_positions, total_len,
+                     spec=None):
+                k_ctx, v_ctx, kw = self._xla_ctx(
+                    kc, vc, l, gather_slots, spec)
                 return xla_attn.context_attention_prefill(
-                    q, k_ctx, v_ctx, q_positions, total_len, scale,
-                    window=window,
+                    q, k_ctx, v_ctx, q_positions, total_len, scale, **kw
                 )
 
         return attn
@@ -603,6 +863,13 @@ class ModelRunner:
         cell = self.attn_context_tokens
         cell[0] += tokens
         cell[1] += 1
+        for window, by_kind in zip(self._kind_windows,
+                                   self.attn_context_by_kind):
+            # per layer of that kind: the window kind cut to its window
+            cut = window or (1 << 62)
+            by_kind[0] += sum(
+                min(c + i, cut) for c in decode_lens for i in range(k)
+            ) + sum(min(c, cut) for c in prefill_lens)
 
     @staticmethod
     def _layout_of(fields: list[tuple[str, tuple[int, ...]]]):
@@ -889,7 +1156,7 @@ class ModelRunner:
             }
 
         def step(params, kc, vc, packed, lora=None, lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             pf = unpack(packed)
             seg_meta = self._rows_pf_seg_meta(
                 r_pad, pf["lane_row0"], pf["lane_rows"], pf["q_starts"]
@@ -898,10 +1165,10 @@ class ModelRunner:
                 r_pad // RAGGED_TQ + 1, dtype=jnp.int32
             )
 
-            def attn_fn(q, l, kcc, vcc):
+            def attn_fn(q, l, kcc, vcc, spec=None):
                 return self._attn(
                     "ragged", q, l, kcc, vcc, pf["tables"], blk_seg,
-                    seg_meta,
+                    seg_meta, spec=spec,
                 )
 
             logits, kc, vc = self._forward(
@@ -1084,7 +1351,7 @@ class ModelRunner:
                  gather_slots, total_len, last_row, temps, top_ps,
                  top_ks, min_ps, keys, targets=None,
                  lora=None, lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             attn_fn = functools.partial(
                 attn,
                 gather_slots=gather_slots,
@@ -1093,7 +1360,8 @@ class ModelRunner:
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
-                lambda q, l, k, v: attn_fn(q, l, k, v),
+                lambda q, l, k, v, spec=None: attn_fn(
+                    q, l, k, v, spec=spec),
                 # prompt-logprobs needs every row's distribution; the
                 # normal path materializes only the LAST row (the first
                 # generated token's) to keep the program output small
@@ -1192,7 +1460,7 @@ class ModelRunner:
         def step(params, kc, vc, tokens, positions, write_slots, tables,
                  q_starts, total_lens, temps, top_ps, top_ks, min_ps,
                  keys, lora=None, lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             attn_fn = functools.partial(
                 attn,
                 tables=tables,
@@ -1202,7 +1470,8 @@ class ModelRunner:
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
-                lambda q, l, k, v: attn_fn(q, l, k, v),
+                lambda q, l, k, v, spec=None: attn_fn(
+                    q, l, k, v, spec=spec),
                 logits_rows=jnp.arange(s_pad * t_pad),
                 lora=lora, lora_slots=lora_slots,
             )
@@ -1385,7 +1654,7 @@ class ModelRunner:
             off_in = (np.arange(n_blk, dtype=np.int32) * tq) % t_pad
 
             def attn(q, l, kc, vc, tables, q_starts, positions2d,
-                     total_lens):
+                     total_lens, spec=None):
                 blk_seg = jnp.arange(n_blk + 1, dtype=jnp.int32)
                 seg_meta = jnp.stack([
                     jnp.asarray(lane_of),
@@ -1394,41 +1663,43 @@ class ModelRunner:
                     q_starts[lane_of] + jnp.asarray(off_in),
                 ], axis=1)
                 return self._attn(
-                    "ragged", q, l, kc, vc, tables, blk_seg, seg_meta
+                    "ragged", q, l, kc, vc, tables, blk_seg, seg_meta,
+                    spec=spec,
                 )
         elif self.attention_impl == "pallas":
 
             # tables: (s_pad, P) per-sequence padded block tables;
             # q_starts: (s_pad,) absolute position of each chunk's row 0
             def attn(q, l, kc, vc, tables, q_starts, positions2d,
-                     total_lens):
+                     total_lens, spec=None):
                 qs = q.reshape(s_pad, t_pad, mc.num_heads, mc.head_dim)
+                if spec is not None and spec.block_map is not None:
+                    # mapped once for all lanes; the per-lane calls
+                    # below take the mapped rows as they are
+                    tables = spec.block_map[tables]
+                    spec = spec._replace(block_map=None)
                 outs = []
                 for s in range(s_pad):
                     outs.append(self._attn(
                         "prefill", qs[s], l, kc, vc, tables[s],
-                        q_starts[s],
+                        q_starts[s], spec=spec,
                     ))
                 return jnp.concatenate(outs, axis=0)
         else:
 
             # tables: (s_pad, c_pad) per-sequence gather slots
             def attn(q, l, kc, vc, tables, q_starts, positions2d,
-                     total_lens):
-                # advanced-index hoisting (see prefill): (s, c, nkv, d)
-                k_ctx = kc[l, :, tables]
-                v_ctx = vc[l, :, tables]
+                     total_lens, spec=None):
+                # (s, c, nkv, d)
+                k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
                 qs = q.reshape(s_pad, t_pad, mc.num_heads, mc.head_dim)
                 out = jax.vmap(
                     functools.partial(
-                        xla_attn.context_attention_prefill,
-                        window=self.model_config.sliding_window,
+                        xla_attn.context_attention_prefill, **kw
                     ),
                     in_axes=(0, 0, 0, 0, 0, None),
                 )(qs, k_ctx, v_ctx, positions2d, total_lens, scale)
-                return out.reshape(
-                    s_pad * t_pad, mc.num_heads, mc.head_dim
-                )
+                return out.reshape(s_pad * t_pad, mc.num_heads, -1)
 
         return attn
 
@@ -1459,7 +1730,7 @@ class ModelRunner:
         def step(params, kc, vc, tokens, positions, write_slots, tables,
                  q_starts, total_lens, last_rows, temps, top_ps, top_ks,
                  min_ps, keys, lora=None, lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             attn_fn = functools.partial(
                 attn,
                 tables=tables,
@@ -1469,7 +1740,8 @@ class ModelRunner:
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
-                lambda q, l, k, v: attn_fn(q, l, k, v),
+                lambda q, l, k, v, spec=None: attn_fn(
+                    q, l, k, v, spec=spec),
                 logits_rows=last_rows,
                 lora=lora, lora_slots=lora_slots,
             )
@@ -1551,7 +1823,7 @@ class ModelRunner:
         if self.attention_impl == "pallas" and self.ragged_kernel:
             tq = RAGGED_TQ
 
-            def attn(q, l, kc, vc, tables, context_lens):
+            def attn(q, l, kc, vc, tables, context_lens, spec=None):
                 b = q.shape[0]
                 r_pad = _ceil_tq(b)
                 n_blk = r_pad // tq
@@ -1569,29 +1841,29 @@ class ModelRunner:
                     context_lens - 1,
                 ], axis=1)
                 out = self._attn(
-                    "ragged", qp, l, kc, vc, tables, blk_seg, seg_meta
+                    "ragged", qp, l, kc, vc, tables, blk_seg, seg_meta,
+                    spec=spec,
                 )
                 return out[:b]
         elif self.attention_impl == "pallas":
 
-            def attn(q, l, kc, vc, tables, context_lens):
+            def attn(q, l, kc, vc, tables, context_lens, spec=None):
                 # q: (b, nq, d); kc/vc: full (L, nkv, slots, d) — the
                 # kernel DMAs pages straight from HBM, no gathered
                 # copy. Under TP the kernel is shard_mapped: each chip
                 # runs it on its local kv-head shard (GQA groups are
                 # chip-local)
                 return self._attn(
-                    "decode", q, l, kc, vc, tables, context_lens
+                    "decode", q, l, kc, vc, tables, context_lens,
+                    spec=spec,
                 )
         else:
 
-            def attn(q, l, kc, vc, tables, context_lens):
-                # advanced-index hoisting (see prefill): (b, c, nkv, d)
-                k_ctx = kc[l, :, tables]
-                v_ctx = vc[l, :, tables]
+            def attn(q, l, kc, vc, tables, context_lens, spec=None):
+                # (b, c, nkv, d)
+                k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
                 return xla_attn.context_attention_decode(
-                    q, k_ctx, v_ctx, context_lens, scale,
-                    window=self.model_config.sliding_window,
+                    q, k_ctx, v_ctx, context_lens, scale, **kw
                 )
 
         return attn
@@ -1602,13 +1874,14 @@ class ModelRunner:
 
         def step(params, kc, vc, tokens, positions, write_slots,
                  tables, context_lens, lora=None, lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             attn_fn = functools.partial(
                 attn, tables=tables, context_lens=context_lens
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
-                lambda q, l, k, v: attn_fn(q, l, k, v),
+                lambda q, l, k, v, spec=None: attn_fn(
+                    q, l, k, v, spec=spec),
                 logits_rows=jnp.arange(b),
                 lora=lora, lora_slots=lora_slots,
             )
@@ -1719,7 +1992,7 @@ class ModelRunner:
                  gen_ids=None, presence=None, frequency=None,
                  repetition=None, lb_ids=None, lb_vals=None,
                  lora=None, lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             consts, carry0 = core["unpack"](
                 packed, chained_tokens=chained_tokens,
                 g_token_class=g_token_class, g_class_mask=g_class_mask,
@@ -1877,7 +2150,8 @@ class ModelRunner:
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
-                lambda q, l, k, v: attn_fn(q, l, k, v),
+                lambda q, l, k, v, spec=None: attn_fn(
+                    q, l, k, v, spec=spec),
                 logits_rows=lane,
                 lora=lora, lora_slots=lora_slots,
             )
@@ -3326,7 +3600,7 @@ class ModelRunner:
                  frequency=None, repetition=None, lb_ids=None,
                  lb_vals=None, lora=None, lora_slots=None,
                  pf_lora_slots=None):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             lane_types = packed[:s_cap + b]
             pf_packed = packed[meta_n:meta_n + pf_n]
             dec_packed = packed[meta_n + pf_n:]
@@ -3384,11 +3658,11 @@ class ModelRunner:
                 ),
             ])
 
-            def attn_fn(q, l, kcc, vcc):
+            def attn_fn(q, l, kcc, vcc, spec=None):
                 qp = jnp.pad(q, ((0, b_pad - b), (0, 0), (0, 0)))
                 out = self._attn(
                     "ragged", qp, l, kcc, vcc, tables_cat, blk_seg,
-                    seg_meta,
+                    seg_meta, spec=spec,
                 )
                 return out[:r_pad + b]
 
@@ -3864,6 +4138,14 @@ class ModelRunner:
                            **self._step_jit_kwargs())
 
     def embed(self, token_ids: list[int], lora_slot: int = 0) -> np.ndarray:
+        if self.model_config.layer_groups:
+            raise NotImplementedError(
+                "embeddings are not served for a model of layer groups "
+                "(the embed program runs over a scratch cache of one kind)"
+            )
+        return self._embed(token_ids, lora_slot)
+
+    def _embed(self, token_ids: list[int], lora_slot: int = 0) -> np.ndarray:
         """Mean-pooled + L2-normalised final hidden state -> (hidden,) f32
         (decoder-as-embedder, e5-mistral pattern). Inputs above
         max_model_len are rejected, never silently truncated."""
@@ -3967,7 +4249,7 @@ class ModelRunner:
         bs = self.block_size
 
         def step(kc, vc, bids, cols, staged):
-            kc, vc = self._pin_cache_layout(kc, vc)
+            kc, vc = self._enter_caches(kc, vc)
             # staged: (2, L, n_src_pad, nkv, bs, d) wire layout
             sel = staged[:, :, cols]  # (2, L, n_dst_pad, nkv, bs, d)
             hm = jnp.swapaxes(sel, 2, 3)  # head-major

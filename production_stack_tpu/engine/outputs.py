@@ -57,6 +57,25 @@ class EngineStatsSnapshot:
     # (tokens, rounds): context tokens the attention calls of the
     # dispatched rounds had to read once — tpu:attn_context_tokens
     attn_context_tokens: tuple = (0, 0)
+    # -- a model of layer groups (models/layer_groups.py); all zero or
+    # empty for a model of alike layers ------------------------------
+    # context tokens a LAYER of each attention kind read, by the kind's
+    # name ("full", or "window" for the kind with one):
+    # tpu:attn_context_tokens_<kind>
+    attn_context_by_kind: dict = field(default_factory=dict)
+    # routed expert layers, summed on the device over layers and fused
+    # steps: pairs routed, pairs whose expert is held here, local
+    # experts with at least one row — tpu:moe_routed_rows,
+    # tpu:moe_local_rows, tpu:moe_active_experts
+    moe_stats: tuple = (0, 0, 0)
+    # blocks some sequence references, per cache group —
+    # tpu:kv_blocks_in_use{group}; window-group blocks let go behind a
+    # window — tpu:kv_window_blocks_released; and (window-group blocks
+    # in use, running sequences) summed over the dispatched rounds —
+    # tpu:kv_window_blocks_per_seq
+    kv_blocks_in_use: dict = field(default_factory=dict)
+    kv_window_blocks_released_total: int = 0
+    kv_window_blocks_per_seq: tuple = (0, 0)
     # the stages of building a program, from jax's monitoring events of
     # this process: trace / lower / compile -> (seconds, count), and the
     # persistent compile cache's hits — tpu:program_*_seconds,

@@ -529,6 +529,11 @@ class Scheduler:
                     break
             else:
                 decode_seqs.append(seq)
+                # a second cache group lets go of what lies behind the
+                # window of this lane's next query (no-op with one pool)
+                self.block_manager.release_behind(
+                    seq.block_table, seq.num_computed_tokens
+                )
         return decode_seqs
 
     # stackcheck: hot-path — pure host planning of the lane-typed round

@@ -1,8 +1,11 @@
-"""Model architecture configs for the Llama-class decoder family.
+"""Model architecture configs for the decoder families the engine serves.
 
 One config dataclass covers Llama 2/3, Mistral, Qwen2 (qkv bias), Mixtral
 (MoE), Phi-3 (fused qkv/gate_up), Gemma (GeGLU + zero-centered norms +
-scaled embeddings), and TinyLlama variants — the family the reference stack's tutorials deploy (Llama-3.1-8B in
+scaled embeddings), TinyLlama variants, and stacks of LAYER GROUPS
+(`attn_kinds` / `layer_kinds`: window and full attention layers with
+their own kv heads, rope theta and caches, a routed expert layer that is
+told which experts it holds; models/layer_groups.py) — the family the reference stack's tutorials deploy (Llama-3.1-8B in
 reference: tutorials/08-benchmark-multi-round-qa-multi-gpu.md, opt-125m-sized
 configs for CI-scale tests).
 
@@ -21,6 +24,18 @@ from dataclasses import dataclass
 from production_stack_tpu.utils import init_logger
 
 logger = init_logger(__name__)
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer in a stack of layer groups. Each kind
+    has its own KV cache arrays (a cache group, engine/model_runner.py)."""
+    num_kv_heads: int
+    rope_theta: float
+    window: int | None = None  # keys j with q_pos - window < j <= q_pos
+    sink: bool = False         # a learned per-q-head logit in the
+                               # softmax denominator (nothing added to
+                               # the numerator)
 
 
 @dataclass(frozen=True)
@@ -44,8 +59,10 @@ class ModelConfig:
     embed_scale: float = 1.0  # Gemma scales embeddings by sqrt(hidden)
     # sliding-window attention (Phi-3-mini, Mistral-v0.1): each token
     # attends to at most this many predecessors; None = full context.
-    # Served on the XLA attention path (the paged kernels are
-    # full-context); parity-tested against transformers beyond the window
+    # Both attention paths mask it; the paged kernels' page walk
+    # (ops/pallas_attention._walk) starts at the window's first KV
+    # block, so earlier pages never stream in. ONE window for every
+    # layer; a stack that mixes windows says so in `attn_kinds`
     sliding_window: int | None = None
     # MoE (Mixtral family): 0 experts = dense MLP. capacity_factor 0
     # selects the exact all-experts einsum path; > 0 the GShard
@@ -53,10 +70,92 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 2
     moe_capacity_factor: float = 0.0
+    # -- layer groups (models/layer_groups.py). Empty `attn_kinds` = a
+    # stack of alike layers described by the scalar fields above
+    # (models/llama.py). Otherwise layer i is of kind
+    # attn_kinds[layer_kinds[i]]; `num_kv_heads`, `rope_theta` and
+    # `sliding_window` above are then kind 0's and nothing reads them.
+    attn_kinds: tuple[AttnKind, ...] = ()
+    layer_kinds: tuple[int, ...] = ()
+    # q/k head width is `head_dim`; v (and the attention output) may be
+    # narrower, and rotary may cover only the leading dims of q and k
+    v_head_dim: int | None = None   # None = head_dim
+    rotary_dim: int | None = None   # None = head_dim
+    v_scale: float = 1.0            # multiplies V before the cache
+    # routed experts (ops/moe.routed_experts): `router_experts` is the
+    # router's width — every expert of the deployment — of which this
+    # engine holds the contiguous slice of expert-parallel rank
+    # `ep_rank` of `ep_size`: the ONE place that says which experts
+    # are here. 0 = no routed layer. The first `dense_layers` layers
+    # keep a dense MLP of width `intermediate_size`; expert width is
+    # `moe_intermediate_size`
+    router_experts: int = 0
+    router_scoring: str = "softmax"  # or "sigmoid"
+    router_bias: bool = False        # selection by score + learned bias
+    router_renorm: bool = True       # weights / their sum over chosen
+    moe_intermediate_size: int = 0
+    dense_layers: int = 0
+    ep_rank: int = 0
+    ep_size: int = 1
+
+    def __post_init__(self):
+        if self.attn_kinds:
+            if len(self.layer_kinds) != self.num_layers or not all(
+                0 <= k < len(self.attn_kinds) for k in self.layer_kinds
+            ):
+                raise ValueError(
+                    f"model {self.name}: layer_kinds must give one of "
+                    f"{len(self.attn_kinds)} attn_kinds for each of "
+                    f"{self.num_layers} layers, got {self.layer_kinds}"
+                )
+        if self.router_experts and (
+            self.router_experts % self.ep_size
+            or not 0 <= self.ep_rank < self.ep_size
+        ):
+            raise ValueError(
+                f"model {self.name}: {self.router_experts} routed experts "
+                f"do not divide over ep_size={self.ep_size} "
+                f"(ep_rank={self.ep_rank})"
+            )
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def layer_groups(self) -> bool:
+        """True for a stack described by `attn_kinds` (served by
+        models/layer_groups.py, one cache group per kind)."""
+        return bool(self.attn_kinds)
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        return self.rotary_dim or self.head_dim
+
+    @property
+    def local_experts(self) -> int:
+        """Routed experts held here: ranks hold contiguous slices."""
+        return self.router_experts // self.ep_size
+
+    def segments(self) -> tuple[tuple[int, bool, int, int], ...]:
+        """The stack as runs of alike layers: (kind, routed, count,
+        index of the run's first layer WITHIN its kind's cache group).
+        A program traces one layer per run, so its size grows with the
+        runs, not the layers."""
+        runs: list[list] = []
+        seen = [0] * len(self.attn_kinds)
+        for i, kind in enumerate(self.layer_kinds):
+            routed = bool(self.router_experts) and i >= self.dense_layers
+            if runs and runs[-1][0] == kind and runs[-1][1] == routed:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, routed, 1, seen[kind]])
+            seen[kind] += 1
+        return tuple(tuple(r) for r in runs)
 
     @property
     def q_size(self) -> int:
@@ -69,6 +168,28 @@ class ModelConfig:
     def num_params(self) -> int:
         """Approximate parameter count (for memory budgeting)."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        if self.layer_groups:
+            # every weight HELD here: the local experts only
+            total = v * h * (1 if self.tie_word_embeddings else 2) + h
+            for li, kind in enumerate(self.layer_kinds):
+                ak = self.attn_kinds[kind]
+                total += (
+                    h * self.q_size
+                    + h * ak.num_kv_heads * (self.head_dim + self.v_dim)
+                    + self.num_heads * self.v_dim * h
+                    + 2 * h
+                    + (self.num_heads if ak.sink else 0)
+                )
+                if self.router_experts and li >= self.dense_layers:
+                    total += (
+                        h * self.router_experts
+                        + (self.router_experts if self.router_bias else 0)
+                        + self.local_experts * 3 * h
+                        * self.moe_intermediate_size
+                    )
+                else:
+                    total += 3 * h * i
+            return total
         mlp = 3 * h * i * max(1, self.num_experts)
         if self.is_moe:
             mlp += h * self.num_experts  # router
@@ -139,6 +260,43 @@ TINY_MOE_DEBUG = _register(
         num_kv_heads=4,  # ep tests shard experts one-per-chip at tp=4
         num_experts=4,
         num_experts_per_tok=2,
+    )
+)
+
+# a stack of layer groups at tiny widths that keep every code path of
+# models/layer_groups.py: full and window layers with different kv
+# heads, q/k heads wider than v heads, rotary on a third of the dims,
+# a sink on the window kind, V scaled, a leading dense layer, then 16
+# sigmoid-routed experts (selection bias, renormalised) of which rank 0
+# of 4 holds 4; window 8, so contexts of 40+ run far past it
+TINY_GROUPS_DEBUG = _register(
+    ModelConfig(
+        name="pst-tiny-groups-debug",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=24,
+        max_model_len=256,
+        rope_theta=1e7,
+        attn_kinds=(
+            AttnKind(num_kv_heads=1, rope_theta=1e7),
+            AttnKind(num_kv_heads=2, rope_theta=1e4, window=8, sink=True),
+        ),
+        layer_kinds=(0, 1, 1, 0),
+        v_head_dim=16,
+        rotary_dim=8,
+        v_scale=0.707,
+        router_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        router_bias=True,
+        moe_intermediate_size=32,
+        dense_layers=1,
+        ep_rank=0,
+        ep_size=4,
     )
 )
 
@@ -263,6 +421,9 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace `config.json` on local disk."""
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
+    if hf.get("model_type") == "mimo_v2":
+        return _from_mimo_v2(hf, name or os.path.basename(
+            os.path.normpath(path)))
     arch = (hf.get("architectures") or ["?"])[0]
     if arch not in (
         "LlamaForCausalLM",
@@ -284,10 +445,11 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
     if not hf.get("use_sliding_window", True):
         window = None
     # HF Qwen2 slides only layers >= max_window_layers; the shipped
-    # default (== num_hidden_layers) means NO layer slides. Mixed
-    # per-layer windows aren't representable here: all-full when no
-    # layer slides, else keep the window for every layer (the majority
-    # behavior) and say so.
+    # default (== num_hidden_layers) means NO layer slides. A stack of
+    # mixed windows is a layer-group config (`attn_kinds`), which the
+    # checkpoint loader (models/weights.py) does not map yet: all-full
+    # when no layer slides, else keep the window for every layer (the
+    # majority behavior) and say so.
     mwl = hf.get("max_window_layers")
     if window and mwl is not None:
         if mwl >= hf["num_hidden_layers"]:
@@ -295,8 +457,10 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
         elif mwl > 0:
             logger.warning(
                 "max_window_layers=%d < num_hidden_layers=%d: applying "
-                "sliding_window=%d to ALL layers (per-layer windows "
-                "unsupported); first %d layers will differ from HF",
+                "sliding_window=%d to ALL layers (mixed windows are a "
+                "layer-group config, attn_kinds, which the checkpoint "
+                "loader does not map); first %d layers will differ "
+                "from HF",
                 mwl, hf["num_hidden_layers"], window, mwl,
             )
     if window and window >= max_len:
@@ -326,6 +490,101 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
         sliding_window=int(window) if window else None,
         num_experts=hf.get("num_local_experts", 0),
         num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+    )
+
+
+def _from_mimo_v2(hf: dict, name: str) -> ModelConfig:
+    """`model_type: mimo_v2` (MiMo-V2-Flash / V2.5 text decoder): full
+    and window attention layers by `hybrid_layer_pattern` (0 full, 1
+    window), each kind with its own kv heads and rope theta, a learned
+    sink where the config says so; qk and v head dims apart, rotary on
+    the leading `partial_rotary_factor` of the qk dims, V scaled;
+    leading dense layers by `moe_layer_freq`, then sigmoid-scored
+    routed experts chosen by score + bias (`noaux_tc`).
+
+    `n_routed_experts` is the ROUTER's width. `ep_size` / `ep_rank`
+    (not published keys: a deployment's) say which contiguous slice of
+    the experts this engine holds; absent = all of them."""
+    L = hf["num_hidden_layers"]
+    pattern = list(hf["hybrid_layer_pattern"])
+    freq = list(hf.get("moe_layer_freq") or [0] * L)
+    dense_layers = freq.index(1) if 1 in freq else L
+    if (len(pattern) != L or len(freq) != L
+            or any(f != 1 for f in freq[dense_layers:])):
+        raise ValueError(
+            f"{name}: hybrid_layer_pattern and moe_layer_freq must have "
+            f"num_hidden_layers={L} entries, dense layers leading"
+        )
+    for key, want in (("n_shared_experts", (None, 0)),
+                      ("n_group", (None, 1)), ("topk_group", (None, 1)),
+                      ("routed_scaling_factor", (None, 1, 1.0)),
+                      ("attention_bias", (None, False)),
+                      ("hidden_act", (None, "silu"))):
+        if hf.get(key) not in want:
+            raise ValueError(
+                f"{name}: {key}={hf.get(key)!r} is not served (shared "
+                "experts, group-limited routing, a routed scaling "
+                "factor and attention biases have no code path yet)"
+            )
+    if (hf.get("swa_head_dim", hf["head_dim"]) != hf["head_dim"]
+            or hf.get("swa_v_head_dim", hf["v_head_dim"])
+            != hf["v_head_dim"]
+            or hf.get("swa_num_attention_heads",
+                      hf["num_attention_heads"])
+            != hf["num_attention_heads"]):
+        raise ValueError(
+            f"{name}: window layers with other q heads or head dims "
+            "than the full layers are not served"
+        )
+    window = hf.get("sliding_window") or hf.get("sliding_window_size")
+    kinds = (
+        AttnKind(
+            num_kv_heads=hf["num_key_value_heads"],
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            sink=bool(hf.get("add_full_attention_sink_bias", False)),
+        ),
+        AttnKind(
+            num_kv_heads=hf.get("swa_num_key_value_heads",
+                                hf["num_key_value_heads"]),
+            rope_theta=float(hf.get("swa_rope_theta",
+                                    hf.get("rope_theta", 10000.0))),
+            window=int(window),
+            sink=bool(hf.get("add_swa_attention_sink_bias", False)),
+        ),
+    )
+    head_dim = hf["head_dim"]
+    # the rotary dims are the leading int(head_dim * factor), as the
+    # published modelling code takes them (192 * 0.334 -> 64)
+    rotary = int(head_dim * hf.get("partial_rotary_factor", 1.0))
+    routed = dense_layers < L
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=kinds[0].num_kv_heads,
+        head_dim=head_dim,
+        max_model_len=hf.get("max_position_embeddings", 8192),
+        rope_theta=kinds[0].rope_theta,
+        rms_norm_eps=hf.get("layernorm_epsilon",
+                            hf.get("rms_norm_eps", 1e-5)),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attn_kinds=kinds,
+        layer_kinds=tuple(int(p) for p in pattern),
+        v_head_dim=hf["v_head_dim"],
+        rotary_dim=rotary - rotary % 2,
+        v_scale=float(hf.get("attention_value_scale") or 1.0),
+        router_experts=hf["n_routed_experts"] if routed else 0,
+        num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+        router_scoring=hf.get("scoring_func", "softmax"),
+        router_bias=hf.get("topk_method") == "noaux_tc",
+        router_renorm=bool(hf.get("norm_topk_prob", True)),
+        moe_intermediate_size=hf.get("moe_intermediate_size", 0),
+        dense_layers=dense_layers,
+        ep_rank=int(hf.get("ep_rank", 0)),
+        ep_size=int(hf.get("ep_size", 1)),
     )
 
 

@@ -1,0 +1,313 @@
+"""A decoder whose layers come in GROUPS, over one paged KV cache per kind.
+
+`models/llama.py` serves a stack of alike layers under one `lax.scan`.
+This module serves the stacks `ModelConfig.attn_kinds` describes: full
+and window attention layers side by side, each kind with its own kv
+heads, rope theta, window and (where the config says so) a learned sink
+in the softmax; q/k heads wider than v heads, rotary on the leading dims
+of a head only, V scaled before the cache; leading dense layers and then
+a routed expert layer over the experts this engine holds
+(`ops/moe.routed_experts`, told its slice by `cfg.ep_rank/ep_size`).
+Nothing here branches on a model's name: the fields select the code.
+
+Why a module beside llama.py and not llama.py grown: the layer body
+differs in every line that touches a shape (two head widths, a cache per
+kind, a write slot per cache group, a spec handed to the attention
+callback, expert statistics in the carry) while LoRA, the pipeline
+phase loop and the tensor-parallel sharding rules hang on llama.py's
+`decoder_layer` as it is. Growing it would put a branch on every one of
+those lines for every dense model; the parts that ARE shared (norms,
+rope, SwiGLU, the cache-write idiom, the attention callback) are the
+functions both import.
+
+Design:
+- params["segments"] holds one stacked tree per RUN of alike layers
+  (`cfg.segments()`: layer 0, the five window layers, the full layer of
+  a one-period cut), walked by one `lax.scan` each, so a program traces
+  one layer per run and its size does not grow with the depth of a run.
+- the KV cache is a pytree: k_cache = {"g": one (L_kind, nkv, slots,
+  d_k) array per kind, "map": int32 block map, "stats": int32
+  counters}, v_cache = {"g": (..., d_v) arrays}. Kind 0's block table
+  is THE table every program ships. A windowed kind's cache is a
+  smaller pool of its own; its table is kind 0's mapped through "map"
+  (primary block id -> this pool's block id, 0 = the null block: not
+  resident), on the device, so no program ships a second table or a
+  second set of write slots (engine/block_manager.WindowedBlockManager
+  keeps the map; the runner uploads it when it changed).
+- "stats" accumulates the routed layers' counters on the device through
+  every layer and fused step of ONE program, which starts it at zero
+  (`ModelRunner._enter_caches`); the runner takes it off what the
+  program returns and copies it out beside the round's tokens.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.ops.layers import (
+    apply_rope,
+    rms_norm,
+    rope_cos_sin,
+    swiglu,
+)
+from production_stack_tpu.ops.moe import routed_experts
+
+# kc["stats"]: pairs routed, pairs whose expert is held here, local
+# experts with at least one row — summed over routed layers and steps
+N_STATS = 3
+
+
+class AttnSpec(NamedTuple):
+    """What an attention call of one layer kind needs beyond q and the
+    caches; the runner's attention callbacks take it as `spec`."""
+    window: int | None
+    sink: jax.Array | None       # (nq,) float32 logits
+    block_map: jax.Array | None  # primary block id -> this group's
+
+
+def mapped_kind(cfg: ModelConfig) -> int | None:
+    """The windowed kind (its cache group is the mapped pool), after
+    checking what this module and the block manager can hold: a full
+    kind first (its table is every sequence's table), and at most one
+    windowed kind."""
+    windowed = [i for i, k in enumerate(cfg.attn_kinds) if k.window]
+    if cfg.attn_kinds[0].window or len(windowed) > 1:
+        raise ValueError(
+            f"model {cfg.name}: layer groups need kind 0 to attend the "
+            "full context and at most one windowed kind, got windows "
+            f"{[k.window for k in cfg.attn_kinds]}"
+        )
+    return windowed[0] if windowed else None
+
+
+def init_params(
+    cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16
+) -> dict:
+    """Random-init parameters; sinks and the router's selection bias
+    non-zero, so that dropping either shows against the reference."""
+    h, v = cfg.hidden_size, cfg.vocab_size
+    nq, dk, dv = cfg.num_heads, cfg.head_dim, cfg.v_dim
+    keys = iter(jax.random.split(key, 16 * len(cfg.segments()) + 4))
+
+    def w(shape, fan_in):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * fan_in ** -0.5).astype(dtype)
+
+    segments = []
+    for kind, routed, c, _ in cfg.segments():
+        ak = cfg.attn_kinds[kind]
+        nkv = ak.num_kv_heads
+        lp = {
+            "attn_norm": jnp.ones((c, h), dtype),
+            "mlp_norm": jnp.ones((c, h), dtype),
+            "wq": w((c, h, nq * dk), h),
+            "wk": w((c, h, nkv * dk), h),
+            "wv": w((c, h, nkv * dv), h),
+            "wo": w((c, nq * dv, h), nq * dv),
+        }
+        if ak.sink:
+            lp["sink"] = jax.random.normal(
+                next(keys), (c, nq), jnp.float32)
+        if cfg.qkv_bias:
+            lp["bq"] = w((c, nq * dk), 4)
+            lp["bk"] = w((c, nkv * dk), 4)
+            lp["bv"] = w((c, nkv * dv), 4)
+        if routed:
+            e, f = cfg.local_experts, cfg.moe_intermediate_size
+            lp["router"] = w((c, h, cfg.router_experts), h)
+            if cfg.router_bias:
+                lp["router_bias"] = 0.1 * jax.random.normal(
+                    next(keys), (c, cfg.router_experts), jnp.float32)
+            lp["w_gate"] = w((c, e, h, f), h)
+            lp["w_up"] = w((c, e, h, f), h)
+            lp["w_down"] = w((c, e, f, h), f)
+        else:
+            i = cfg.intermediate_size
+            lp["w_gate"] = w((c, h, i), h)
+            lp["w_up"] = w((c, h, i), h)
+            lp["w_down"] = w((c, i, h), i)
+        segments.append(lp)
+    params = {
+        "embed": w((v, h), h),
+        "segments": segments,
+        "final_norm": jnp.ones((h,), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((h, v), h)
+    return params
+
+
+# a routed run's expert weights: kept out of the scan's per-layer slices
+# and handed to the expert layer as whole stacks with the layer's index
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
+           write_slots, real, attn_fn, block_map, dtype, experts=None,
+           stack_index=None):
+    """One layer of `kind` over n rows: llama.decoder_layer's shape
+    (K/V written at `write_slots` BEFORE attn_fn runs) with this
+    family's widths. `kc`/`vc` are the kind's own cache arrays and `l`
+    indexes them. `real` (n,) bool: the rows that are tokens; the rest
+    (padding, idle lanes, lanes a device stop froze) write the null
+    block's slot 0."""
+    ak = cfg.attn_kinds[kind]
+    n = h.shape[0]
+    nq, nkv = cfg.num_heads, ak.num_kv_heads
+    dk, dv = cfg.head_dim, cfg.v_dim
+
+    def proj(x, name, bias):
+        out = jnp.dot(x, lp[name], preferred_element_type=jnp.float32)
+        return out + lp[bias].astype(jnp.float32) if cfg.qkv_bias else out
+
+    x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps,
+                 cfg.norm_weight_offset)
+    q = proj(x, "wq", "bq").astype(dtype).reshape(n, nq, dk)
+    k = proj(x, "wk", "bk").astype(dtype).reshape(n, nkv, dk)
+    v = proj(x, "wv", "bv")
+    if cfg.v_scale != 1.0:
+        v = v * cfg.v_scale
+    v = v.astype(dtype).reshape(n, nkv, dv)
+    q, k = apply_rope(q, k, cos, sin)
+
+    # per-head plane scatters (see llama.decoder_layer for why). The
+    # cache may store K wider than d_k (zero lanes up to the kernel's
+    # 128-lane tile, model_runner): the pad is written with the row
+    kh = k.astype(kc.dtype).swapaxes(0, 1)  # (nkv, n, d_k)
+    if kc.shape[-1] > dk:
+        kh = jnp.pad(kh, ((0, 0), (0, 0), (0, kc.shape[-1] - dk)))
+    vh = v.astype(vc.dtype).swapaxes(0, 1)
+    for head in range(nkv):
+        kc = kc.at[l, head, write_slots].set(kh[head])
+        vc = vc.at[l, head, write_slots].set(vh[head])
+
+    spec = AttnSpec(
+        window=ak.window,
+        sink=lp["sink"] if ak.sink else None,
+        block_map=block_map if ak.window else None,
+    )
+    attn_out = attn_fn(q, l, kc, vc, spec)  # (n, nq, d_v)
+    # the paged kernels store a segment's rows into their output tile
+    # and leave the tile's other rows as the tile held them: now and
+    # then not a number (on the chip: tokens 0 and NaN log-probabilities
+    # after rounds with padded rows, PR 28). In a stack of alike layers
+    # such a row stays its own. Here it would reach real rows: through
+    # the routed layer's row matrices (0 x NaN), and through the null
+    # block it writes, which a windowed lane reads, masked, for the
+    # pages it let go
+    attn_out = jnp.where(real[:, None, None], attn_out, 0)
+    h = h + jnp.dot(
+        attn_out.reshape(n, nq * dv).astype(dtype), lp["wo"],
+        preferred_element_type=jnp.float32,
+    ).astype(dtype)
+
+    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps,
+                 cfg.norm_weight_offset)
+    if routed:
+        # rows that are no tokens keep no pair
+        y, st = routed_experts(
+            x, lp["router"], lp.get("router_bias"),
+            *(experts[name] for name in EXPERT_STACKS),
+            stack_index=stack_index,
+            top_k=cfg.num_experts_per_tok,
+            first_expert=cfg.ep_rank * cfg.local_experts,
+            scoring=cfg.router_scoring, renorm=cfg.router_renorm,
+            valid=real,
+        )
+        h = h + y.astype(dtype)
+        stats = stats + st
+    else:
+        h = h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                       act=cfg.hidden_act)
+    return h, kc, vc, stats
+
+
+def forward(
+    cfg: ModelConfig,
+    params: dict,
+    token_ids: jax.Array,   # (n,) int32
+    positions: jax.Array,   # (n,) int32
+    k_cache: dict,          # {"g": per-kind arrays, "map", "stats"}
+    v_cache: dict,          # {"g": per-kind arrays}
+    write_slots: jax.Array,  # (n,) int32 slots in KIND 0's pool
+    attn_fn,                # attn_fn(q, l, kc, vc, spec) -> (n, nq, d_v)
+    logits_rows: jax.Array,
+    lora: dict | None = None,
+    lora_slots: jax.Array | None = None,
+    return_hidden: bool = False,
+    *,
+    block_size: int,
+):
+    """llama.forward's contract over a cache group per kind; returns
+    (logits[r, V] fp32, k_cache, v_cache)."""
+    assert lora is None, "LoRA is refused at start-up for layer groups"
+    dtype = params["embed"].dtype
+    block_map = k_cache["map"]
+    kg, vg = list(k_cache["g"]), list(v_cache["g"])
+    stats = k_cache["stats"]
+    # a token's row never writes slot 0 (the null block's)
+    real = write_slots > 0
+    # a windowed kind's slot for a row: the row's primary block mapped
+    # into that pool, same offset in the block
+    mapped_slots = None
+    if any(ak.window for ak in cfg.attn_kinds):
+        mapped_slots = (
+            block_map[write_slots // block_size] * block_size
+            + write_slots % block_size
+        )
+    rope = [rope_cos_sin(positions, cfg.rope_dim, ak.rope_theta)
+            for ak in cfg.attn_kinds]
+
+    h = params["embed"][token_ids].astype(dtype)
+    if cfg.embed_scale != 1.0:
+        h = (h.astype(jnp.float32) * cfg.embed_scale).astype(dtype)
+
+    with jax.named_scope("layers"):
+        for lp_stack, (kind, routed, count, l0) in zip(
+            params["segments"], cfg.segments()
+        ):
+            windowed = cfg.attn_kinds[kind].window is not None
+            cos, sin = rope[kind]
+
+            experts = None
+            if routed:
+                experts = {n: lp_stack[n] for n in EXPERT_STACKS}
+                lp_stack = {n: a for n, a in lp_stack.items()
+                            if n not in EXPERT_STACKS}
+
+            def body(carry, xs, kind=kind, routed=routed, cos=cos,
+                     sin=sin, windowed=windowed, experts=experts):
+                h, kc, vc, st = carry
+                lp, l, c = xs
+                h, kc, vc, st = _layer(
+                    cfg, kind, routed, h, kc, vc, st, lp, l,
+                    cos=cos, sin=sin,
+                    write_slots=mapped_slots if windowed else write_slots,
+                    real=real, attn_fn=attn_fn, block_map=block_map, dtype=dtype,
+                    experts=experts, stack_index=c,
+                )
+                return (h, kc, vc, st), None
+
+            (h, kg[kind], vg[kind], stats), _ = jax.lax.scan(
+                body, (h, kg[kind], vg[kind], stats),
+                (lp_stack, l0 + jnp.arange(count), jnp.arange(count)),
+            )
+
+    k_cache = {"g": tuple(kg), "map": block_map, "stats": stats}
+    v_cache = {"g": tuple(vg)}
+    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
+                 cfg.norm_weight_offset)
+    h_sel = h[logits_rows]
+    if return_hidden:
+        return h_sel.astype(jnp.float32), k_cache, v_cache
+    lm_head = (
+        params["embed"].T if cfg.tie_word_embeddings
+        else params["lm_head"]
+    )
+    with jax.named_scope("lm_head"):
+        logits = jnp.dot(h_sel, lm_head, preferred_element_type=jnp.float32)
+    return logits, k_cache, v_cache

@@ -64,6 +64,22 @@ def _gqa_output(p: jax.Array, v: jax.Array) -> jax.Array:
     return out.reshape(*lead, nkv * g, d)
 
 
+def _softmax(scores: jax.Array, sink: jax.Array | None) -> jax.Array:
+    """Softmax over the last axis of (..., nkv, g, c) scores. `sink`
+    ((nq,) float32): one learned logit per q head that joins the
+    denominator and adds nothing to the numerator — an extra column
+    that is dropped after the normalisation."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    col = jnp.broadcast_to(
+        sink.astype(jnp.float32).reshape(scores.shape[-3:-1])[..., None],
+        (*scores.shape[:-1], 1),
+    )
+    return jax.nn.softmax(
+        jnp.concatenate([scores, col], axis=-1), axis=-1
+    )[..., :-1]
+
+
 def context_attention_decode(
     q: jax.Array,  # (batch, num_q_heads, head_dim)
     k_ctx: jax.Array,  # (batch, padded_ctx, num_kv_heads, head_dim)
@@ -71,8 +87,10 @@ def context_attention_decode(
     context_lens: jax.Array,  # (batch,) valid positions incl. the new token
     scale: float,
     window: int | None = None,  # sliding-window size; None = full context
+    sink: jax.Array | None = None,  # (nq,) logits, see _softmax
 ) -> jax.Array:
-    """One decode step over gathered per-sequence context. -> (b, nq, d).
+    """One decode step over gathered per-sequence context. -> (b, nq,
+    d_v): K and V may have different head widths.
 
     With `window`, the query (at position context_len-1) attends only
     its last `window` predecessors incl. itself (HF sliding-window
@@ -84,7 +102,7 @@ def context_attention_decode(
     if window is not None:
         valid = valid & (key_pos > context_lens[:, None] - 1 - window)
     scores = jnp.where(valid[:, None, None, :], scores, MASK_VALUE)
-    p = jax.nn.softmax(scores, axis=-1)
+    p = _softmax(scores, sink)
     return _gqa_output(p, v_ctx).astype(q.dtype)
 
 
@@ -96,9 +114,10 @@ def context_attention_prefill(
     total_len: jax.Array,  # scalar: valid context positions (prefix + chunk)
     scale: float,
     window: int | None = None,  # sliding-window size; None = full context
+    sink: jax.Array | None = None,  # (nq,) logits, see _softmax
 ) -> jax.Array:
     """Chunked-prefill attention for one sequence; causal over absolute
-    positions (context rows ARE absolute positions). -> (t, nq, d).
+    positions (context rows ARE absolute positions). -> (t, nq, d_v).
 
     With `window`, each query attends only its last `window` positions
     incl. itself (keys j with q_pos - window < j <= q_pos)."""
@@ -113,5 +132,5 @@ def context_attention_prefill(
             key_pos[None, :] > q_positions[:, None] - window
         )
     scores = jnp.where(mask[:, None, None, :], scores, MASK_VALUE)
-    p = jax.nn.softmax(scores, axis=-1)
+    p = _softmax(scores, sink)
     return _gqa_output(p, v_ctx).astype(q.dtype)

@@ -57,14 +57,22 @@ def apply_rope(
     """Apply rotary embeddings.
 
     q: (N, num_heads, head_dim), k: (N, num_kv_heads, head_dim),
-    cos/sin: (N, head_dim).
+    cos/sin: (N, rotary_dim). With rotary_dim < head_dim (partial
+    rotary) the leading rotary_dim dims of each head are rotated,
+    half-split among themselves, and the rest pass unrotated.
     """
     cos = cos[:, None, :].astype(jnp.float32)
     sin = sin[:, None, :].astype(jnp.float32)
+    r = cos.shape[-1]
 
     def rot(x):
         xf = x.astype(jnp.float32)
-        return (xf * cos + _rotate_half(xf) * sin).astype(x.dtype)
+        if r == x.shape[-1]:
+            return (xf * cos + _rotate_half(xf) * sin).astype(x.dtype)
+        xr = xf[..., :r]
+        return jnp.concatenate(
+            [xr * cos + _rotate_half(xr) * sin, xf[..., r:]], axis=-1
+        ).astype(x.dtype)
 
     return rot(q), rot(k)
 

@@ -48,6 +48,15 @@ replaces the gather-based XLA path in ops/attention.py on TPU):
   tiling, not gather-heavy layout" recipe for TPU paged attention.
 - The layer index is a scalar argument indexing the full cache, so jit
   never slices (= copies) a per-layer cache to feed the kernel.
+- K and V may have different head widths (d_k from the K cache, d_v
+  from the V cache; q has K's width, the output V's), and a layer may
+  bring a SINK: one learned logit per q head that joins the softmax
+  denominator and adds nothing to the numerator — in the online softmax
+  that is the start state (m, l, acc) = (sink, 1, 0) instead of
+  (-1e30, 0, 0). Both come from what the call is given; a model with
+  one head width and no sink lowers to what it did without them.
+- A sliding window (`window`) is masked per key and the walk starts at
+  the window's first KV block, so the pages behind it never stream in.
 
 Numerics match ops/attention.py (f32 softmax, same masking); parity is
 enforced by tests/test_pallas_attention.py in interpret mode on CPU.
@@ -98,7 +107,8 @@ def _note_trace(kind: str) -> None:
     _LAUNCHES[kind] += 1
 
 
-def _kv_block_pages(nkv: int, d: int, itemsize: int, block_size: int) -> int:
+def _kv_block_pages(nkv: int, d: int, itemsize: int, block_size: int,
+                    d_v: int | None = None) -> int:
     """Pages per KV block: the most keys, in multiples of the 128-lane
     vreg width from 128 to 512, whose ring of K and V buffers fits a
     2 MiB VMEM budget — a K block of about 256 KiB whatever the head
@@ -107,9 +117,10 @@ def _kv_block_pages(nkv: int, d: int, itemsize: int, block_size: int) -> int:
     key past a lane's last costs MXU time for nothing (an idle lane
     ships ctx = 1 and still pays one block). Measured on a v5e
     (PERF.md, Findings PR 25): 8 kv heads are fastest at 128 keys, 4 at
-    256, 2 (a tensor-parallel shard) at 512."""
+    256, 2 (a tensor-parallel shard) at 512. `d` is K's head width and
+    `d_v` V's where it differs."""
     budget = 2 * 2**20
-    per_key = 2 * _KV_RING * nkv * d * itemsize
+    per_key = _KV_RING * nkv * (d + (d_v or d)) * itemsize
     keys = min(512, max(128, budget // per_key // 128 * 128))
     return max(1, keys // block_size)
 
@@ -151,14 +162,16 @@ def _walk(
                         # k_buf, v_buf (_KV_RING, nkv, N*bs, d) VMEM;
                         # DMA sems (_KV_RING, 2)
     static,             # block_size, num_pages, scale, window
+    sink=None,          # (nkv, rows, 1) float32 logits, or None
 ):
     """THE page walk: causal (and windowed) attention of `rows` fused
     query rows over keys [lo, hi) of one sequence's pages, online
     softmax over KV blocks of N pages at absolute multiples of N.
-    Returns the normalised (nkv, rows, d) float32 output. hi <= lo
-    walks nothing and starts no copy."""
+    Returns the normalised (nkv, rows, d_v) float32 output. hi <= lo
+    walks nothing and starts no copy (and gives zeros)."""
     k_cache_ref, v_cache_ref, k_buf, v_buf, sem = kv
-    ring, nkv, c, d = k_buf.shape
+    ring, nkv, c, _ = k_buf.shape
+    d = v_buf.shape[-1]
     rows = q.shape[1]
     bs, scale, window = static["block_size"], static["scale"], static["window"]
     n = c // bs
@@ -226,16 +239,22 @@ def _walk(
         l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
         return m_new, l_new, acc * corr + _pv(p, v_buf[slot])
 
-    m0 = jnp.full((nkv, rows, 1), MASK_VALUE, jnp.float32)
-    l0 = jnp.zeros((nkv, rows, 1), jnp.float32)
+    if sink is None:
+        m0 = jnp.full((nkv, rows, 1), MASK_VALUE, jnp.float32)
+        l0 = jnp.zeros((nkv, rows, 1), jnp.float32)
+    else:
+        # the sink is a key whose logit is given and whose value is 0
+        m0 = sink
+        l0 = jnp.ones((nkv, rows, 1), jnp.float32)
     acc0 = jnp.zeros((nkv, rows, d), jnp.float32)
     _, l, acc = jax.lax.fori_loop(b_lo, b_hi, body, (m0, l0, acc0))
     return acc / jnp.maximum(l, 1e-30)
 
 
 def _attend(
-    q_ref,              # (TQ, nq, d) VMEM — the program's query tile
-    out_ref,            # (TQ, nq, d) VMEM
+    q_ref,              # (TQ, nq, d_k) VMEM — the program's query tile
+    sink_ref,           # (nkv, g, 1) float32 VMEM, or None
+    out_ref,            # (TQ, nq, d_v) VMEM
     row0,               # first tile row the segment owns
     n_rows,             # rows it owns; `one_row` promises n_rows == 1
     qpos0,              # absolute position of row0's query
@@ -258,6 +277,7 @@ def _attend(
     tq, nq, d = q_ref.shape
     k_buf = kv[2]
     nkv = k_buf.shape[1]
+    d_v = kv[3].shape[-1]
     g = nq // nkv
     # q and K meet in the MXU in their common dtype: as stored when the
     # cache has the model's dtype (the reshapes want 32-bit rows)
@@ -272,15 +292,19 @@ def _attend(
         # earlier KV blocks never stream in
         lo = jnp.clip(qpos0 - window + 1, 0, hi)
 
+    sink = None if sink_ref is None else sink_ref[...]
     if one_row:
         q = q_ref[row0].astype(jnp.float32).reshape(nkv, g, d)
         if g % 8:
             pad = jnp.zeros((nkv, 8 - g % 8, d), jnp.float32)
             q = jnp.concatenate([q, pad], axis=1)
+            if sink is not None:
+                sink = jnp.concatenate([sink, pad[:, :, :1]], axis=1)
         out = _walk(
-            q.astype(q_dtype), qpos0, lo, hi, page_of, layer, kv, static
+            q.astype(q_dtype), qpos0, lo, hi, page_of, layer, kv, static,
+            sink,
         )
-        out_ref[row0] = out[:, :g].reshape(nq, d).astype(out_ref.dtype)
+        out_ref[row0] = out[:, :g].reshape(nq, d_v).astype(out_ref.dtype)
         return
 
     q = (
@@ -290,14 +314,17 @@ def _attend(
         .reshape(nkv, tq * g, d)
     )
     row_of = jax.lax.broadcasted_iota(jnp.int32, (1, tq * g, 1), 1) // g
+    if sink is not None:
+        # fused row t*g + j is head j of tile row t
+        sink = jnp.concatenate([sink] * tq, axis=1)
     out = _walk(
         q.astype(q_dtype), qpos0 + (row_of - row0), lo, hi, page_of,
-        layer, kv, static,
+        layer, kv, static, sink,
     )
     out = (
-        out.reshape(nkv, tq, g, d)
+        out.reshape(nkv, tq, g, d_v)
         .transpose(1, 0, 2, 3)
-        .reshape(tq, nq, d)
+        .reshape(tq, nq, d_v)
     )
     # row-masked merge: segments of one block write disjoint row
     # ranges sequentially (read-modify-write within the program)
@@ -312,15 +339,13 @@ def _decode_kernel(
     block_tables_ref,   # (b, P) int32
     context_lens_ref,   # (b,) int32
     # array inputs
-    q_ref,              # (1, nq, d) VMEM — this program's query
-    k_cache_ref,        # (L, nkv, slots, d) ANY/HBM — head-major
-    v_cache_ref,
-    # outputs
-    out_ref,            # (1, nq, d) VMEM
-    # scratch
-    k_buf,              # (_KV_RING, nkv, N*bs, d) VMEM
-    v_buf,
-    sem,                # DMA sems (_KV_RING, 2)
+    q_ref,              # (1, nq, d_k) VMEM — this program's query
+    k_cache_ref,        # (L, nkv, slots, d_k) ANY/HBM — head-major
+    v_cache_ref,        # (L, nkv, slots, d_v)
+    *rest,              # [sink_ref (nkv, g, 1) VMEM,] then
+                        # out_ref (1, nq, d_v) VMEM, and the scratch:
+                        # k_buf (_KV_RING, nkv, N*bs, d_k), v_buf VMEM,
+                        # DMA sems (_KV_RING, 2)
     **static,           # block_size, num_pages, scale, window
 ):
     """One grid program per sequence: its one query row at position
@@ -328,8 +353,10 @@ def _decode_kernel(
     keys j > q_pos - window, starts the walk at the window's first KV
     block)."""
     i = pl.program_id(0)
+    *sink_ref, out_ref, k_buf, v_buf, sem = rest
     _attend(
-        q_ref, out_ref, 0, 1, context_lens_ref[i] - 1,
+        q_ref, *(sink_ref or [None]), out_ref, 0, 1,
+        context_lens_ref[i] - 1,
         lambda j: block_tables_ref[i, j], layer_ref[0],
         (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), static,
         one_row=True,
@@ -341,15 +368,10 @@ def _prefill_kernel(
     meta_ref,           # (2,) int32: [layer, q_start]
     block_table_ref,    # (P,) int32 — this sequence's pages
     # array inputs
-    q_ref,              # (Tq, nq, d) VMEM — this program's query tile
+    q_ref,              # (Tq, nq, d_k) VMEM — this program's query tile
     k_cache_ref,
     v_cache_ref,
-    # outputs
-    out_ref,            # (Tq, nq, d) VMEM
-    # scratch
-    k_buf,
-    v_buf,
-    sem,
+    *rest,              # [sink_ref,] out_ref (Tq, nq, d_v), scratch
     **static,
 ):
     """Ragged chunked-prefill attention for ONE sequence over the paged
@@ -365,8 +387,10 @@ def _prefill_kernel(
     never built, and later tiles see (and stream) more pages.
     """
     tq = q_ref.shape[0]
+    *sink_ref, out_ref, k_buf, v_buf, sem = rest
     _attend(
-        q_ref, out_ref, 0, tq, meta_ref[1] + pl.program_id(0) * tq,
+        q_ref, *(sink_ref or [None]), out_ref, 0, tq,
+        meta_ref[1] + pl.program_id(0) * tq,
         lambda j: block_table_ref[j], meta_ref[0],
         (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), static,
         one_row=False,
@@ -382,15 +406,10 @@ def _ragged_kernel(
                         # [lane, row0_in_block, n_rows, q_pos_of_row0]
     block_tables_ref,   # (S, P) int32 — per-LANE page tables
     # array inputs
-    q_ref,              # (TQ, nq, d) VMEM — this block's query rows
+    q_ref,              # (TQ, nq, d_k) VMEM — this block's query rows
     k_cache_ref,
     v_cache_ref,
-    # outputs
-    out_ref,            # (TQ, nq, d) VMEM
-    # scratch
-    k_buf,
-    v_buf,
-    sem,
+    *rest,              # [sink_ref,] out_ref (TQ, nq, d_v), scratch
     **static,
 ):
     """Unified ragged paged attention: ONE grid over the flattened
@@ -413,13 +432,15 @@ def _ragged_kernel(
     nothing and stores nothing.
     """
     i = pl.program_id(0)
+    *sink_ref, out_ref, k_buf, v_buf, sem = rest
     kv = (k_cache_ref, v_cache_ref, k_buf, v_buf, sem)
 
     def seg_body(s, _):
         lane = seg_meta_ref[s, 0]
         n_rows = seg_meta_ref[s, 2]
         attend = functools.partial(
-            _attend, q_ref, out_ref, seg_meta_ref[s, 1], n_rows,
+            _attend, q_ref, *(sink_ref or [None]), out_ref,
+            seg_meta_ref[s, 1], n_rows,
             seg_meta_ref[s, 3], lambda j: block_tables_ref[lane, j],
             meta_ref[0], kv, static,
         )
@@ -432,20 +453,35 @@ def _ragged_kernel(
 
 def _paged_call(
     kernel, name, tq, scalars, q, k_cache, v_cache, *,
-    num_pages, block_size, scale, window, interpret,
+    num_pages, block_size, scale, window, interpret, sink=None,
 ):
     """The pallas_call the three kernels share: a grid over `tq`-row
     tiles of q, the caches left in HBM, the scalars prefetched to SMEM,
-    a ring of KV-block buffers as scratch."""
+    a ring of KV-block buffers as scratch. `sink` ((nq,) logits) rides
+    as one more VMEM input where the layer has one."""
     r, nq, d = q.shape
     nkv = k_cache.shape[1]
+    d_v = v_cache.shape[-1]
+    assert k_cache.shape[-1] == d, (k_cache.shape, q.shape)
     keys = block_size * _kv_block_pages(
-        nkv, d, k_cache.dtype.itemsize, block_size
+        nkv, d, k_cache.dtype.itemsize, block_size, d_v
     )
-    tile = pl.BlockSpec(
-        (tq, nq, d), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM
-    )
+
+    def tile(width):
+        return pl.BlockSpec(
+            (tq, nq, width), lambda i, *_: (i, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+
     cache = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs, inputs = [tile(d), cache, cache], [q, k_cache, v_cache]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec(
+            (nkv, nq // nkv, 1), lambda i, *_: (0, 0, 0),
+            memory_space=pltpu.VMEM,
+        ))
+        inputs.append(
+            sink.astype(jnp.float32).reshape(nkv, nq // nkv, 1))
     return pl.pallas_call(
         functools.partial(
             kernel, block_size=block_size, num_pages=num_pages,
@@ -455,15 +491,15 @@ def _paged_call(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(r // tq,),
-            in_specs=[tile, cache, cache],
-            out_specs=tile,
+            in_specs=in_specs,
+            out_specs=tile(d_v),
             scratch_shapes=[
                 pltpu.VMEM((_KV_RING, nkv, keys, d), k_cache.dtype),
-                pltpu.VMEM((_KV_RING, nkv, keys, d), v_cache.dtype),
+                pltpu.VMEM((_KV_RING, nkv, keys, d_v), v_cache.dtype),
                 pltpu.SemaphoreType.DMA((_KV_RING, 2)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, nq, d_v), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -471,7 +507,7 @@ def _paged_call(
             # scoped-vmem stack; v5e has 128 MiB — allow half of it
             vmem_limit_bytes=64 * 2**20,
         ),
-    )(*(jnp.asarray(s, jnp.int32) for s in scalars), q, k_cache, v_cache)
+    )(*(jnp.asarray(s, jnp.int32) for s in scalars), *inputs)
 
 
 @functools.partial(
@@ -488,6 +524,7 @@ def ragged_paged_attention(
                               # G = R // RAGGED_TQ
     seg_meta: jax.Array,      # (SC, 4) int32 — [lane, row0, n_rows,
                               # q_pos0] per segment
+    sink: jax.Array | None = None,  # (nq,) float32 logits
     *,
     block_size: int,
     scale: float,
@@ -513,7 +550,7 @@ def ragged_paged_attention(
         (jnp.reshape(layer, 1), blk_seg, seg_meta, block_tables),
         q, k_cache, v_cache, num_pages=block_tables.shape[1],
         block_size=block_size, scale=scale, window=window,
-        interpret=interpret,
+        interpret=interpret, sink=sink,
     )
 
 
@@ -541,6 +578,7 @@ def paged_prefill_attention(
     layer: jax.Array,        # scalar int32
     block_table: jax.Array,  # (P,) int32 — pages of THIS sequence
     q_start: jax.Array,      # scalar int32 — absolute position of q row 0
+    sink: jax.Array | None = None,  # (nq,) float32 logits
     *,
     block_size: int,
     scale: float,
@@ -556,7 +594,7 @@ def paged_prefill_attention(
                     jnp.asarray(q_start, jnp.int32)]), block_table),
         q, k_cache, v_cache, num_pages=block_table.shape[0],
         block_size=block_size, scale=scale, window=window,
-        interpret=interpret,
+        interpret=interpret, sink=sink,
     )
 
 
@@ -571,6 +609,7 @@ def paged_decode_attention(
     layer: jax.Array,         # scalar int32
     block_tables: jax.Array,  # (b, P) int32 — page ids per sequence
     context_lens: jax.Array,  # (b,) int32
+    sink: jax.Array | None = None,  # (nq,) float32 logits
     *,
     block_size: int,
     scale: float,
@@ -583,7 +622,7 @@ def paged_decode_attention(
         (jnp.reshape(layer, 1), block_tables, context_lens),
         q, k_cache, v_cache, num_pages=block_tables.shape[1],
         block_size=block_size, scale=scale, window=window,
-        interpret=interpret,
+        interpret=interpret, sink=sink,
     )
 
 

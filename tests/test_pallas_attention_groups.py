@@ -1,0 +1,133 @@
+"""The ONE page walk with what a layer-group model asks of it (interpret
+mode on the CPU) against ops/attention.py: K heads of width 192 beside V
+heads of width 128, a learned sink per q head as the online softmax's
+start state, the window, both tile heights (a one-row segment and a
+fused tile), and the ragged kernel bit-identical per row to the composed
+ones. The dense models' shapes are tests/test_pallas_attention.py's."""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from production_stack_tpu.ops import attention as xla_attn
+from production_stack_tpu.ops import pallas_attention as pa
+
+BS, DK, DV = 16, 192, 128
+SCALE = DK ** -0.5
+
+
+def case(seed, *, lanes=3, pages=20, nkv=2, g=4, dk=DK, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    blocks = 1 + lanes * pages
+    kc = rng.randn(2, nkv, blocks * BS, dk).astype(np.float32)
+    vc = rng.randn(2, nkv, blocks * BS, DV).astype(np.float32)
+    tables = rng.permutation(np.arange(1, blocks)).reshape(lanes, pages)
+    sink = rng.randn(nkv * g).astype(np.float32)
+    return (jnp.asarray(kc, dtype), jnp.asarray(vc, dtype),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(sink), rng)
+
+
+def gathered(kc, vc, table):
+    slots = xla_attn.block_table_slots(table, BS)
+    return kc[1][:, slots].swapaxes(0, 1), vc[1][:, slots].swapaxes(0, 1)
+
+
+@pytest.mark.parametrize("g", [4, 8])  # 4 pads the one-row tile to 8 rows
+@pytest.mark.parametrize("window, with_sink", [
+    (None, False), (None, True), (40, True), (40, False)])
+def test_decode_rows_dk192_dv128(g, window, with_sink):
+    kc, vc, tables, sink, rng = case(g, g=g)
+    nq = 2 * g
+    lens = jnp.asarray([1, 37, 20 * BS], jnp.int32)
+    q = jnp.asarray(rng.randn(3, nq, DK), jnp.float32)
+    s = sink if with_sink else None
+    out = pa.paged_decode_attention(
+        q, kc, vc, jnp.int32(1), tables, lens, s, block_size=BS,
+        scale=SCALE, interpret=True, window=window)
+    assert out.shape == (3, nq, DV)
+    for i in range(3):
+        k_ctx, v_ctx = gathered(kc, vc, tables[i])
+        ref = xla_attn.context_attention_decode(
+            q[i:i + 1], k_ctx[None], v_ctx[None], lens[i:i + 1], SCALE,
+            window=window, sink=s)
+        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(ref[0]),
+                                   rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_prefill_tile_with_sink(window):
+    kc, vc, tables, sink, rng = case(7)
+    q = jnp.asarray(rng.randn(16, 8, DK), jnp.float32)
+    start = 150
+    out = pa.paged_prefill_attention(
+        q, kc, vc, jnp.int32(1), tables[0], jnp.int32(start), sink,
+        block_size=BS, scale=SCALE, interpret=True, window=window)
+    k_ctx, v_ctx = gathered(kc, vc, tables[0])
+    ref = xla_attn.context_attention_prefill(
+        q, k_ctx, v_ctx, start + jnp.arange(16), jnp.int32(start + 16),
+        SCALE, window=window, sink=sink)
+    assert out.shape == (16, 8, DV)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_ragged_rows_are_bit_identical_to_the_composed_kernels():
+    """A prefill chunk of two tiles beside two decode rows in one
+    launch: each row equals, bit for bit, what the composed kernel of
+    its kind gives (sink and window on)."""
+    kc, vc, tables, sink, rng = case(9)
+    tq, window = pa.RAGGED_TQ, 40
+    q = jnp.asarray(rng.randn(3 * tq, 8, DK), jnp.float32)
+    start, lens = 90, jnp.asarray([61, 300], jnp.int32)
+    blk_seg = jnp.asarray([0, 1, 2, 4], jnp.int32)
+    seg_meta = jnp.asarray([
+        [0, 0, tq, start], [0, 0, tq, start + tq],
+        [1, 0, 1, 60], [2, 1, 1, 299]], jnp.int32)
+    kw = dict(block_size=BS, scale=SCALE, interpret=True, window=window)
+    out = pa.ragged_paged_attention(
+        q, kc, vc, jnp.int32(1), tables, blk_seg, seg_meta, sink, **kw)
+    pre = pa.paged_prefill_attention(
+        q[:2 * tq], kc, vc, jnp.int32(1), tables[0], jnp.int32(start),
+        sink, **kw)
+    dec = pa.paged_decode_attention(
+        q[2 * tq:2 * tq + 2], kc, vc, jnp.int32(1), tables[1:], lens,
+        sink, **kw)
+    np.testing.assert_array_equal(np.asarray(out[:2 * tq]),
+                                  np.asarray(pre))
+    np.testing.assert_array_equal(np.asarray(out[2 * tq:2 * tq + 2]),
+                                  np.asarray(dec))
+
+
+def test_k_stored_at_256_lanes_gives_the_same_rows():
+    """What the runner does on the chip: K rows stored with zero lanes
+    up to the next 128 (192 -> 256) and q padded to match."""
+    kc, vc, tables, sink, rng = case(11, dtype=jnp.bfloat16)
+    lens = jnp.asarray([130, 37, 20 * BS], jnp.int32)
+    q = jnp.asarray(rng.randn(3, 8, DK), jnp.bfloat16)
+    kw = dict(block_size=BS, scale=SCALE, interpret=True, window=40)
+    out = pa.paged_decode_attention(
+        q, kc, vc, jnp.int32(1), tables, lens, sink, **kw)
+    pad = ((0, 0), (0, 0), (0, 0), (0, 64))
+    wide = pa.paged_decode_attention(
+        jnp.pad(q, pad[1:]), jnp.pad(kc, pad), vc, jnp.int32(1), tables,
+        lens, sink, **kw)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(wide, np.float32))
+    k_ctx, v_ctx = gathered(kc, vc, tables[0])
+    ref = xla_attn.context_attention_decode(
+        q[:1], k_ctx[None], v_ctx[None], lens[:1], SCALE, window=40,
+        sink=sink)
+    np.testing.assert_allclose(
+        np.asarray(out[0], np.float32), np.asarray(ref[0], np.float32),
+        rtol=2e-2, atol=2e-2)
+
+
+def test_dense_shapes_size_their_kv_block_as_before():
+    # one head width: the budget is what PR 25 measured it at
+    for nkv, want in ((8, 128), (4, 256), (2, 512)):
+        assert pa._kv_block_pages(nkv, 128, 2, 32) * 32 == want
+    # K at 256 lanes beside V at 128, bf16: 128 keys for 8 and 4 kv heads
+    assert pa._kv_block_pages(8, 256, 2, 32, 128) * 32 == 128
+    assert pa._kv_block_pages(4, 256, 2, 32, 128) * 32 == 128
