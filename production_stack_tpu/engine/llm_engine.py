@@ -3783,6 +3783,7 @@ class LLMEngine:
             spec_accepted_tokens_total=self._spec_accepted_total,
             engine_phases=self.phases.pairs(),
             attn_context_tokens=tuple(self.runner.attn_context_tokens),
+            decode_lane_steps=tuple(self.runner.decode_lane_steps),
             **self._layer_group_stats(),
             program_stages=phases.program_stage_pairs(),
             program_cache_hits_total=phases.PROGRAM_CACHE_HITS[0],

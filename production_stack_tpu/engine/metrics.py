@@ -388,6 +388,22 @@ class EngineMetrics:
             "tpu:decode_rounds", "Decode rounds dispatched",
             label, registry=reg,
         )
+        self.decode_lane_steps = Counter(
+            "tpu:decode_lane_steps",
+            "Lanes x fused steps of every dispatched round's decode "
+            "rows (the program runs max_num_seqs lanes whatever the "
+            "live batch)",
+            label, registry=reg,
+        )
+        self.decode_idle_lane_steps = Counter(
+            "tpu:decode_idle_lane_steps",
+            "Of tpu:decode_lane_steps, those the host packed as "
+            "zero-row segments of the attention walk (lanes holding "
+            "no sequence: no KV block read). Lanes a device stop "
+            "freezes mid-round skip their walk too and are NOT "
+            "counted here",
+            label, registry=reg,
+        )
         self.decode_overshoot = Counter(
             "tpu:decode_overshoot_tokens",
             "Sampled decode slots discarded by the host past a stop "
@@ -558,6 +574,10 @@ class EngineMetrics:
             - prev.long_prefill_overflow_seconds_total))
         self.decode_rounds.labels(m).inc(max(
             0, s.decode_rounds_total - prev.decode_rounds_total))
+        for counter, now, was in zip(
+                (self.decode_lane_steps, self.decode_idle_lane_steps),
+                s.decode_lane_steps, prev.decode_lane_steps):
+            counter.labels(m).inc(max(0, now - was))
         self.decode_overshoot.labels(m).inc(max(
             0, s.decode_overshoot_tokens_total
             - prev.decode_overshoot_tokens_total))

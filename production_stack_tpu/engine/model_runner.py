@@ -10,8 +10,11 @@ TPU without per-step recompilation):
   chunk padded to a power-of-two length bucket and the context padded to
   a whole-block bucket; single-sequence prefill keeps its own buckets;
 - decode runs a fixed number of lanes (max_num_seqs) with the context padded
-  to the max bucket needed this step; idle lanes point at the null block and
-  their writes land in the reserved trash slot 0;
+  to the max bucket needed this step; a lane that holds no sequence ships
+  context length 0, which the step programs read as "no row this step": the
+  paged kernels walk nothing for it and give it a zero row, and its writes
+  land in the reserved trash slot 0 of the null block (a lane a device stop
+  froze mid-round is handed to attention the same way);
 - KV caches are donated into every step, so XLA performs scatter updates
   in place in HBM (no cache copies).
 
@@ -311,6 +314,11 @@ class ModelRunner:
         # had to read once, and the rounds counted (tpu:attn_context_
         # tokens): host integers the dispatch already holds
         self.attn_context_tokens = [0, 0]
+        # the decode rows of the dispatched rounds: lanes x fused steps,
+        # and those of them the pack shipped with context 0, zero-row
+        # segments of the attention walk (tpu:decode_lane_steps,
+        # tpu:decode_idle_lane_steps)
+        self.decode_lane_steps = [0, 0]
         # the same per attention kind of a layer-group model, tokens a
         # LAYER of that kind read (tpu:attn_context_tokens_<kind>)
         self._kind_windows = [ak.window for ak in mc.attn_kinds]
@@ -850,8 +858,14 @@ class ModelRunner:
         lane's context at each of its `steps` fused steps (context + i
         at step i) and each prefill chunk's END context once, both cut
         to the sliding window where the model has one. A lane that a
-        device stop freezes mid-round is counted to the round's end."""
+        device stop freezes mid-round is counted to the round's end.
+        And the decode rows' lane-steps, with those of lanes that hold
+        no sequence (a frozen lane is no idle one here: the host packed
+        it live)."""
         k, n = steps, len(decode_lens)
+        lanes = self.config.max_num_seqs
+        self.decode_lane_steps[0] += k * lanes
+        self.decode_lane_steps[1] += k * (lanes - n)
         w = self.model_config.sliding_window
         if w is None:
             tokens = (k * sum(decode_lens) + n * (k * (k - 1) // 2)
@@ -1828,8 +1842,11 @@ class ModelRunner:
                 r_pad = _ceil_tq(b)
                 n_blk = r_pad // tq
                 qp = jnp.pad(q, ((0, r_pad - b), (0, 0), (0, 0)))
-                # one single-row segment per lane; blocks hold up to
-                # TQ lanes (CSR offsets clip at the live lane count)
+                # one segment per lane — one row where the lane holds
+                # a token this step, none where it does not (context
+                # length 0: the kernel walks nothing and zeroes the
+                # row); blocks hold up to TQ lanes (CSR offsets clip
+                # at the lane count)
                 blk_seg = jnp.minimum(
                     jnp.arange(n_blk + 1, dtype=jnp.int32) * tq, b
                 )
@@ -1837,7 +1854,7 @@ class ModelRunner:
                 seg_meta = jnp.stack([
                     lanes,
                     lanes % tq,
-                    jnp.ones((b,), jnp.int32),
+                    (context_lens > 0).astype(jnp.int32),
                     context_lens - 1,
                 ], axis=1)
                 out = self._attn(
@@ -2139,8 +2156,12 @@ class ModelRunner:
             )
             if use_stop:
                 # frozen lanes write the trash slot: a done lane's
-                # overshoot KV must never land past its real end
+                # overshoot KV must never land past its real end; and
+                # attention gets them as it gets a lane that holds no
+                # sequence, context 0: nothing to walk for the rest of
+                # the round (the carry keeps the lane's real context)
                 write_slots = jnp.where(done, 0, write_slots)
+                ctx = jnp.where(done, 0, ctx)
             return tokens, positions, write_slots, ctx
 
         def fwd(params, kc, vc, carry, consts, lora, lora_slots):
@@ -2913,7 +2934,7 @@ class ModelRunner:
             tokens[:b_actual] = token_ids
             pos = np.zeros((b,), dtype=np.int32)
             pos[:b_actual] = positions
-            ctx = np.ones((b,), dtype=np.int32)
+            ctx = np.zeros((b,), dtype=np.int32)  # 0: no row this step
             ctx[:b_actual] = context_lens
 
             write_slots = np.zeros((b,), dtype=np.int32)
@@ -3014,7 +3035,9 @@ class ModelRunner:
         pos = np.zeros((b,), dtype=np.int32)
         pos[:b_actual] = positions
         put("positions", pos)
-        ctx = np.ones((b,), dtype=np.int32)
+        # a lane that holds no sequence ships context 0: the step
+        # programs make it a zero-row segment of the attention walk
+        ctx = np.zeros((b,), dtype=np.int32)
         ctx[:b_actual] = context_lens
         put("ctx", ctx)
 
@@ -3638,7 +3661,8 @@ class ModelRunner:
             # block map: prefill blocks carry one chunk segment each;
             # decode lanes are single-row segments sharing the tail
             # blocks (q_pos = ctx-1 makes decode the degenerate causal
-            # case of the one kernel body)
+            # case of the one kernel body), zero-row ones where the
+            # lane holds no token this step (context 0)
             pf_seg = self._rows_pf_seg_meta(
                 r_pad, pf["lane_row0"], pf["lane_rows"], pf["q_starts"]
             )
@@ -3646,7 +3670,7 @@ class ModelRunner:
             dec_seg = jnp.stack([
                 s_cap + dlanes,
                 dlanes % tq,
-                jnp.ones((b,), jnp.int32),
+                (d_ctx > 0).astype(jnp.int32),
                 d_ctx - 1,
             ], axis=1)
             seg_meta = jnp.concatenate([pf_seg, dec_seg], axis=0)

@@ -57,6 +57,10 @@ class EngineStatsSnapshot:
     # (tokens, rounds): context tokens the attention calls of the
     # dispatched rounds had to read once — tpu:attn_context_tokens
     attn_context_tokens: tuple = (0, 0)
+    # (lanes x fused steps of the dispatched rounds' decode rows, those
+    # the host packed as zero-row segments: lanes holding no sequence)
+    # — tpu:decode_lane_steps, tpu:decode_idle_lane_steps
+    decode_lane_steps: tuple = (0, 0)
     # -- a model of layer groups (models/layer_groups.py); all zero or
     # empty for a model of alike layers ------------------------------
     # context tokens a LAYER of each attention kind read, by the kind's

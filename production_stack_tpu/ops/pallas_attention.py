@@ -114,8 +114,9 @@ def _kv_block_pages(nkv: int, d: int, itemsize: int, block_size: int,
     2 MiB VMEM budget — a K block of about 256 KiB whatever the head
     count. It is the block's BYTES that pay for a walk step (the page
     DMAs' issue, one wait, a loop turn, a softmax update), while every
-    key past a lane's last costs MXU time for nothing (an idle lane
-    ships ctx = 1 and still pays one block). Measured on a v5e
+    key past a lane's last costs MXU time for nothing (a lane that
+    holds no sequence is a zero-row segment and walks no block at
+    all). Measured on a v5e
     (PERF.md, Findings PR 25): 8 kv heads are fastest at 128 keys, 4 at
     256, 2 (a tensor-parallel shard) at 512. `d` is K's head width and
     `d_v` V's where it differs."""
@@ -428,8 +429,13 @@ def _ragged_kernel(
     same `_attend` as the composed kernels — a one-row segment (decode,
     q_pos = ctx-1) at the decode kernel's tile height, any other at the
     prefill kernel's — so outputs are bit-identical per row: one
-    kernel, any lane mix, one launch. n_rows == 0 (idle slot) walks
-    nothing and stores nothing.
+    kernel, any lane mix, one launch. A segment costs what its keys
+    cost: n_rows == 0 (a decode lane that holds no sequence this step,
+    a row block no prefill lane covers) walks nothing, starts no copy
+    and stores ZEROS into tile row `row0`, which it still names: the
+    row is then a number whatever VMEM held (it goes on through the
+    layer and is written to the null block, which a windowed lane
+    reads masked: 0 x NaN is NaN).
     """
     i = pl.program_id(0)
     *sink_ref, out_ref, k_buf, v_buf, sem = rest
@@ -446,6 +452,12 @@ def _ragged_kernel(
         )
         pl.when(n_rows == 1)(functools.partial(attend, one_row=True))
         pl.when(n_rows > 1)(functools.partial(attend, one_row=False))
+
+        @pl.when(n_rows == 0)
+        def _():
+            out_ref[seg_meta_ref[s, 1]] = jnp.zeros(
+                out_ref.shape[1:], out_ref.dtype)
+
         return 0
 
     jax.lax.fori_loop(blk_seg_ref[i], blk_seg_ref[i + 1], seg_body, 0)
@@ -536,9 +548,10 @@ def ragged_paged_attention(
     The caller packs every lane's query rows back-to-back on the row
     axis (prefill chunks RAGGED_TQ-aligned; decode lanes one row each,
     sharing row blocks) and describes the layout with the CSR segment
-    metadata — see _ragged_kernel. Returns (R, nq, d) in q.dtype; rows
-    covered by no segment are undefined (callers discard them, the
-    same contract as the composed kernels' padded rows)."""
+    metadata — see _ragged_kernel. Returns (R, nq, d) in q.dtype; a
+    zero-row segment's `row0` holds zeros, rows covered by no segment
+    are undefined (callers discard them, the same contract as the
+    composed kernels' padded rows)."""
     r = q.shape[0]
     n_blocks = blk_seg.shape[0] - 1
     tq = r // n_blocks

@@ -14,10 +14,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from prometheus_client import CollectorRegistry, generate_latest
 
 from production_stack_tpu.engine import model_runner
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.llm_engine import LLMEngine
+from production_stack_tpu.engine.metrics import EngineMetrics
 from production_stack_tpu.engine.sampling_params import SamplingParams
 from production_stack_tpu.ops import pallas_attention
 from production_stack_tpu.tracing import ENGINE_PHASES, phases
@@ -272,6 +274,34 @@ def test_attn_context_tokens_equals_the_hand_count_and_phases_count():
     assert set(snap.engine_phases) == set(ENGINE_PHASES)
     assert set(snap.program_stages) == {"trace", "lower", "compile"}
     assert all(n > 0 and s > 0 for s, n in snap.program_stages.values())
+
+
+def test_decode_lane_steps_count_the_lanes_that_hold_no_sequence():
+    """tpu:decode_lane_steps / tpu:decode_idle_lane_steps: lanes x fused
+    steps of every dispatched round's decode rows, and those of lanes
+    the pack shipped with context 0 (zero-row segments of the walk);
+    a prefill round adds nothing."""
+    e = LLMEngine(cfg())
+    lanes = e.config.max_num_seqs
+    assert lanes > 2
+    e.add_request("a", prompt_token_ids=prompt(10),
+                  sampling_params=greedy(9))
+    e.add_request("b", prompt_token_ids=prompt(6, seed=5),
+                  sampling_params=greedy(9))
+    e.step()
+    assert e.last_step_kind == "prefill"
+    assert e.runner.decode_lane_steps == [0, 0]
+    e.step()                    # two live lanes, K=4
+    assert e.last_step_kind == "decode"
+    assert e.runner.decode_lane_steps == [4 * lanes, 4 * (lanes - 2)]
+    assert e.stats().decode_lane_steps == (4 * lanes, 4 * (lanes - 2))
+    reg = CollectorRegistry()
+    metrics = EngineMetrics("m", registry=reg)
+    metrics.update_from_snapshot(e.stats())
+    text = generate_latest(reg).decode()
+    for name, value in (("decode_lane_steps", 4.0 * lanes),
+                        ("decode_idle_lane_steps", 4.0 * (lanes - 2))):
+        assert f'tpu:{name}_total{{model_name="m"}} {value}' in text, name
 
 
 def test_sliding_window_bounds_the_attention_context_count():

@@ -799,8 +799,8 @@ def test_pv_pieces_are_exact():
 @pytest.mark.parametrize("n_pages", [2, None])
 def test_walk_idle_slot_between_live_lanes(kv_block_pages, n_pages):
     """An idle slot (n_rows == 0) BETWEEN two live one-row segments of
-    one grid block starts no copy and stores nothing: the live rows
-    keep the bits of the composed decode kernel."""
+    one grid block starts no copy and stores zeros into the row it
+    names: the live rows keep the bits of the composed decode kernel."""
     bs = 8
     n = kv_block_pages(n_pages) or _natural_pages(2, 128, jnp.float32, bs)
     c = n * bs
@@ -811,7 +811,6 @@ def test_walk_idle_slot_between_live_lanes(kv_block_pages, n_pages):
         q, kc, vc, jnp.int32(0), bt, ctx_a,
         block_size=bs, scale=scale, interpret=True,
     )
-    # lane 1 idles: its row keeps the zeros it came with
     seg = jnp.asarray(
         [[0, 0, 1, ctx[0] - 1], [1, 1, 0, 0], [2, 2, 1, ctx[2] - 1]],
         jnp.int32,
@@ -821,6 +820,53 @@ def test_walk_idle_slot_between_live_lanes(kv_block_pages, n_pages):
     np.testing.assert_array_equal(
         np.asarray(out)[[0, 2]], np.asarray(ref)[[0, 2]]
     )
+    assert not np.asarray(out)[1].any()
+
+
+@pytest.mark.parametrize("window", [None, "inside"])
+@pytest.mark.parametrize("n_pages", [2, None])
+def test_zero_row_decode_segments_among_live_ones(kv_block_pages, n_pages,
+                                                  window):
+    """What the runner ships since PR 29: a lane that holds no sequence
+    is a ZERO-row segment (context 0) where it was a one-row segment
+    over one key of the null page (context 1). Thirteen lanes over two
+    grid blocks, idle ones between live ones and at the end, contexts
+    of one, two and three KV blocks side by side. The live rows keep
+    their bits
+    — against the all-ones packing and against the composed decode
+    kernel — and the idle rows are exactly zero although the output
+    tile held NaN before the kernel ran: interpret mode hands the
+    kernel an output full of NaN, which the rows no segment names
+    still show."""
+    bs = 8
+    n = kv_block_pages(n_pages) or _natural_pages(2, 128, jnp.float32, bs)
+    c = n * bs
+    ctx = [2 * c + 3, 0, c, 5, 0, 0, 3 * c - 1, c + 1,
+           1, 2 * c, 0, c - 1, 0]
+    live = np.asarray(ctx) > 0
+    w = None if window is None else c // 2 + 3
+    q, kc, vc, bt, _ = _lanes_case(
+        17, [max(x, 1) for x in ctx], -(-3 * c // bs) + 1)
+    bt = jnp.where(jnp.asarray(live)[:, None], bt, 0)  # idle: null page
+    r_pad, blk_seg, ones = _dec_rows_meta(np.maximum(ctx, 1))
+    zero_rows = ones.at[:, 2].set(jnp.asarray(live, jnp.int32))
+    zero_rows = zero_rows.at[:, 3].set(jnp.asarray(ctx, jnp.int32) - 1)
+    qp = jnp.pad(q, ((0, r_pad - len(ctx)), (0, 0), (0, 0)))
+    out_ones = np.asarray(
+        _ragged(qp, kc, vc, 1, bt, blk_seg, ones, window=w))
+    out = np.asarray(
+        _ragged(qp, kc, vc, 1, bt, blk_seg, zero_rows, window=w))
+    b = len(ctx)
+    np.testing.assert_array_equal(out[:b][live], out_ones[:b][live])
+    assert np.isnan(out[b:]).all(), "the tile was not poisoned"
+    assert not out[:b][~live].any()
+    # the composed decode kernel skips a context-0 lane the same way
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    composed = np.asarray(paged_decode_attention(
+        q, kc, vc, jnp.int32(1), bt, jnp.asarray(ctx, jnp.int32),
+        block_size=bs, scale=scale, interpret=True, window=w,
+    ))
+    np.testing.assert_array_equal(out[:b], composed)
 
 
 @pytest.mark.parametrize("window", [None, 11])

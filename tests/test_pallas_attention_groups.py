@@ -100,6 +100,47 @@ def test_ragged_rows_are_bit_identical_to_the_composed_kernels():
                                   np.asarray(dec))
 
 
+@pytest.mark.parametrize("window", [None, 40])
+def test_zero_row_segments_at_k256_v128_with_a_sink(window):
+    """A layer-group model's decode rows as the runner ships them since
+    PR 29: K stored at 256 lanes (192 + zeros) beside V at 128, a sink,
+    the window; lanes that hold no sequence are zero-row segments among
+    live ones. Live rows keep the bits of the all-ones packing (idle
+    lanes as one-row segments over one key of the null page), idle rows
+    are exactly zero where the output tile held NaN (interpret mode
+    fills it with NaN: the rows no segment names show it), and a sink
+    does not leak into a zero row (a walk of no keys with a sink would
+    give 0 / 1, the kernel does not even start it)."""
+    kc, vc, tables, sink, rng = case(13, lanes=6, dtype=jnp.bfloat16)
+    pad = ((0, 0), (0, 0), (0, 0), (0, 64))
+    kc = jnp.pad(kc, pad)
+    ctx = np.asarray([130, 0, 37, 0, 20 * BS, 0], np.int32)
+    live = ctx > 0
+    tables = jnp.where(jnp.asarray(live)[:, None], tables, 0)
+    q = jnp.pad(jnp.asarray(rng.randn(8, 8, DK), jnp.bfloat16), pad[1:])
+    lanes = np.arange(6, dtype=np.int32)
+    blk_seg = jnp.asarray([0, 6], jnp.int32)
+
+    def run(n_rows, lens):
+        seg = np.stack([lanes, lanes, n_rows, lens - 1], axis=1)
+        return np.asarray(pa.ragged_paged_attention(
+            q, kc, vc, jnp.int32(1), tables, blk_seg, jnp.asarray(seg),
+            sink, block_size=BS, scale=SCALE, interpret=True,
+            window=window).astype(jnp.float32))
+
+    ones = run(np.ones(6, np.int32), np.maximum(ctx, 1))
+    out = run(live.astype(np.int32), ctx)
+    assert out.shape == (8, 8, DV)
+    np.testing.assert_array_equal(out[:6][live], ones[:6][live])
+    assert not out[:6][~live].any()
+    assert np.isnan(out[6:]).all(), "the tile was not poisoned"
+    dec = np.asarray(pa.paged_decode_attention(
+        q[:6], kc, vc, jnp.int32(1), tables, jnp.asarray(ctx), sink,
+        block_size=BS, scale=SCALE, interpret=True,
+        window=window).astype(jnp.float32))
+    np.testing.assert_array_equal(out[:6], dec)
+
+
 def test_k_stored_at_256_lanes_gives_the_same_rows():
     """What the runner does on the chip: K rows stored with zero lanes
     up to the next 128 (192 -> 256) and q padded to match."""

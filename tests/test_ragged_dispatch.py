@@ -743,3 +743,103 @@ def test_stochastic_parity_in_mixed_rounds():
         [(0, "a", SHORT), (2, "b", LONG), (3, "c", MED)], sp,
         check_kv=False,
     )
+
+
+# -- (g) lanes that hold no sequence are zero-row segments (PR 29) -----------
+# The decode pack ships a lane without a sequence with context 0 and the
+# step programs make it a ZERO-row segment of the attention walk (no KV
+# block read, a zero row out), where it was a one-row segment over one
+# key of the null page (context 1). Nothing a live lane computes may
+# move, under any sampling feature, dispatch mode or stop mode, and no
+# program may be added or re-keyed.
+
+def _ship_idle_lanes_as_before(monkeypatch):
+    """The parent's packing: idle lanes with context 1 (the programs
+    then give them a one-row segment, as every lane had)."""
+    from production_stack_tpu.engine.model_runner import ModelRunner
+
+    fill = ModelRunner._fill_decode_pack
+
+    def fill_ones(self, c_pad, chained, token_ids, positions, *a, **kw):
+        packed = fill(self, c_pad, chained, token_ids, positions, *a, **kw)
+        b = self.config.max_num_seqs
+        layout, _ = self._decode_pack_layout(b, c_pad, chained)
+        at, _ = layout["ctx"]  # the fields before it are the same ones
+        assert not packed[at + len(positions): at + b].any()
+        packed[at + len(positions): at + b] = 1
+        return packed
+
+    monkeypatch.setattr(ModelRunner, "_fill_decode_pack", fill_ones)
+
+
+@pytest.mark.parametrize("device_stop, mode", [
+    (True, "staged"), (False, "staged"), (True, "plain"),
+    (False, "chained"),
+])
+def test_five_live_of_32_lanes_as_zero_row_segments(
+        monkeypatch, device_stop, mode):
+    """5 sequences on a 32-lane kernel-mode engine, through fused decode
+    rounds and lane-typed rounds (a cold 4-chunk prompt joins decoding
+    lanes), with penalties, log-probabilities, a stop id that freezes a
+    lane mid-round and budgets that end inside a round: tokens and
+    log-probabilities equal the parent's packing, and so do the kernel
+    launches traced and the keys of the programs built."""
+    import jax
+    from production_stack_tpu.ops import pallas_attention as pa
+
+    learn = SamplingParams(max_tokens=10, temperature=0.0,
+                           ignore_eos=True)
+    stream = _engine(False, k=1).generate([MED], learn)[0].token_ids
+    sps = {
+        "a": SamplingParams(max_tokens=13, temperature=0.7, seed=3,
+                            repetition_penalty=1.3, ignore_eos=True),
+        "b": SamplingParams(max_tokens=9, temperature=0.0, logprobs=2,
+                            ignore_eos=True),
+        "c": SamplingParams(max_tokens=12, temperature=0.0,
+                            ignore_eos=True,
+                            stop_token_ids=[stream[5]]),
+        "d": SamplingParams(max_tokens=11, temperature=0.0,
+                            ignore_eos=True),
+        "e": SamplingParams(max_tokens=7, temperature=0.9, top_p=0.8,
+                            seed=11, ignore_eos=True),
+    }
+    arrivals = [(0, "a", SHORT), (0, "c", MED), (0, "d", [9, 8, 7]),
+                (2, "b", LONG), (3, "e", MED[::-1])]
+    kw = dict(
+        attention_impl="pallas", max_num_seqs=32, num_kv_blocks=256,
+        device_stop=device_stop, prefetch_decode=mode == "staged",
+        async_decode=mode == "chained",
+    )
+
+    def run():
+        jax.clear_caches()  # the kernels' own jits: count every trace
+        pa.reset_launch_counts()
+        e = _engine(True, **kw)
+        out = _run_staggered(e, arrivals, sps)
+        r = e.runner
+        keys = {name: sorted(map(repr, getattr(r, name))) for name in (
+            "_decode_multi_fns", "_ragged_fns", "_prefill_batch_fns",
+            "_prefill_fns", "_decode_fns")}
+        return e, out, keys, pa.launch_counts(), dict(r.compile_events)
+
+    e, out, keys, launches, builds = run()
+    assert e.runner.ragged_kernel
+    lane_steps, idle = e.runner.decode_lane_steps
+    assert lane_steps > 0 and lane_steps % 32 == 0
+    # never more than 5 of the 32 lanes held a sequence
+    assert idle * 32 >= lane_steps * 27
+    assert e.stats().decode_lane_steps == (lane_steps, idle)
+    if mode == "staged":
+        assert e._ragged_rounds_total > 0
+    _ship_idle_lanes_as_before(monkeypatch)
+    e_p, out_p, keys_p, launches_p, builds_p = run()
+    assert out.keys() == out_p.keys() == sps.keys()
+    for rid in sps:
+        assert out[rid][0] == out_p[rid][0], rid
+    lp, lp_p = out["b"][1], out_p["b"][1]
+    assert len(lp) == len(lp_p) == 9
+    assert lp == lp_p  # same bits: the live rows' arithmetic is the same
+    assert out["c"][0][-1] == stream[5] and len(out["c"][0]) < 12
+    assert keys == keys_p and any(keys.values())
+    assert launches == launches_p
+    assert builds == builds_p

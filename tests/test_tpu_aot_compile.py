@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,9 @@ def _cache(sh, d=D, nkv=NKV):
     return _spec(sh, (2, nkv, 64 * BS, d), jnp.bfloat16)
 
 
+SMEM_BYTES = 2**20  # scalar prefetch: test_block_tables_are_bounded_by_smem
+
+
 def _compile_decode(sh, *, nq=NQ, d=D, lanes=16, pages=32):
     fn = functools.partial(
         pa.paged_decode_attention, block_size=BS, scale=d**-0.5
@@ -63,19 +67,28 @@ def _compile_decode(sh, *, nq=NQ, d=D, lanes=16, pages=32):
 
 
 def _compile_ragged(sh, *, nq=NQ, nkv=NKV, d=D, rows=128, lanes=16,
-                    pages=32, window=None):
+                    pages=32, window=None, d_v=None, sink=False):
     fn = functools.partial(
         pa.ragged_paged_attention, block_size=BS, scale=d**-0.5,
         window=window,
     )
     blocks = rows // pa.RAGGED_TQ
-    return jax.jit(fn).lower(
-        _spec(sh, (rows, nq, d), jnp.bfloat16), _cache(sh, d, nkv),
-        _cache(sh, d, nkv), _spec(sh, (), jnp.int32),
+    scalars = [
+        _spec(sh, (), jnp.int32),
         _spec(sh, (lanes, pages), jnp.int32),
         _spec(sh, (blocks + 1,), jnp.int32),
         _spec(sh, (blocks + lanes, 4), jnp.int32),
-    ).compile()
+    ]
+    args = [
+        _spec(sh, (rows, nq, d), jnp.bfloat16), _cache(sh, d, nkv),
+        _cache(sh, d_v or d, nkv), *scalars,
+    ]
+    if sink:
+        args.append(_spec(sh, (nq,), jnp.float32))
+    jax.jit(fn).lower(*args).compile()
+    # what rides scalar-prefetch SMEM: the layer, the tables, the CSR
+    # offsets and the segment list
+    return sum(4 * math.prod(a.shape) for a in scalars)
 
 
 def test_decode_kernel_compiles(v5e):
@@ -97,6 +110,33 @@ def test_ragged_kernel_compiles_at_benchmark_widths(v5e, nq, nkv, window):
     counts the benchmark's configurations give it and at the KV block
     `_kv_block_pages` picks for each."""
     _compile_ragged(v5e, nq=nq, nkv=nkv, window=window)
+
+
+@pytest.mark.parametrize("cell, shape", [
+    # a step program's decode rows (lanes rows) and a lane-typed round's
+    # [prefill rows | decode rows] at the widest prefill bucket, lanes x
+    # the cell's one context bucket of 32-token pages
+    ("mistral-7b-l16.chat-sys2k + .batch-fewshot2k",
+     dict(nq=32, nkv=8, lanes=32, pages=128)),
+    ("qwen2-7b-l14.chat-sys2k", dict(nq=28, nkv=4, lanes=32, pages=128)),
+    ("mimo-v2.5-ep16-l7.batch-doc8k, window layers",
+     dict(nq=64, nkv=8, lanes=64, pages=512, d=256, d_v=128, sink=True,
+          window=128)),
+    ("mimo-v2.5-ep16-l7.batch-doc8k, full layers",
+     dict(nq=64, nkv=4, lanes=64, pages=512, d=256, d_v=128, sink=True)),
+])
+@pytest.mark.parametrize("prefill_rows", [0, 512])
+def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
+    """The kernel as PR 29 left it (a zero-row segment walks nothing
+    and stores a zero row) compiles for a v5e at every cell's decode
+    shape — 32 lanes x 4,096 at 8 and 4 kv heads; 64 lanes x 16,384
+    with K stored at 256 lanes beside V at 128, a sink, the window of
+    128 — and what it prefetches to SMEM stays under the 1 MiB bound."""
+    lanes = shape["lanes"]
+    shape = {**shape, "rows": prefill_rows + lanes,
+             "lanes": lanes + (8 if prefill_rows else 0)}
+    smem = _compile_ragged(v5e, **shape)
+    assert smem < SMEM_BYTES, (cell, smem)
 
 
 @pytest.mark.slow
