@@ -66,7 +66,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-device-stop", dest="device_stop",
                    action="store_false",
                    help="fixed-trip fused scan; overshoot discarded on "
-                        "the host (A/B control)")
+                        "the host (the tests' reference)")
     p.add_argument("--adaptive-decode-k", action="store_true",
                    default=True,
                    help="size each fused round from pow2 buckets up to "
@@ -76,20 +76,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-adaptive-decode-k", dest="adaptive_decode_k",
                    action="store_false",
                    help="every round dispatches the full "
-                        "--num-scheduler-steps (fixed-K control)")
+                        "--num-scheduler-steps (fixed K)")
     p.add_argument("--num-speculative-tokens", type=int, default=0,
                    help="ngram prompt-lookup speculative decoding: "
                         "draft up to this many tokens and verify them "
                         "in one forward (greedy batch-1 decode; 0=off)")
     p.add_argument("--ngram-prompt-lookup-max", type=int, default=3)
     p.add_argument("--ngram-prompt-lookup-min", type=int, default=1)
-    p.add_argument("--async-decode", action="store_true", default=False,
-                   help="double-buffered decode: dispatch round N+1 on "
-                        "round N's on-device tokens before fetching it "
-                        "(delays prefill admission; not measured on an "
-                        "attached chip)")
-    p.add_argument("--no-async-decode", dest="async_decode",
-                   action="store_false")
     p.add_argument("--prefetch-decode", action="store_true", default=True,
                    help="speculative h2d prefetch: upload the next fused "
                         "round's inputs while the current one executes")
@@ -103,8 +96,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "without host round-trips")
     p.add_argument("--no-prefill-pipeline", dest="prefill_pipeline",
                    action="store_false",
-                   help="serial per-array prefill uploads (the "
-                        "pre-pipeline path; bench attribution control)")
+                   help="serial per-array prefill uploads (what "
+                        "multihost staging takes; the tests' reference)")
     p.add_argument("--ragged-dispatch", action="store_true",
                    default=True,
                    help="unified ragged prefill+decode rounds: when "
@@ -113,20 +106,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "program — no prefill/decode interleave wait")
     p.add_argument("--no-ragged-dispatch", dest="ragged_dispatch",
                    action="store_false",
-                   help="split alternating prefill/decode rounds (the "
-                        "pre-ragged path; bench attribution control)")
-    p.add_argument("--ragged-kernel", action="store_true",
-                   default=True,
-                   help="single-kernel ragged paged attention: ONE "
-                        "batched-grid Pallas kernel serves any lane "
-                        "mix (decode rows + prefill q-tiles share the "
-                        "grid), shrinking the precompile variant "
-                        "space to row-count buckets (pallas impl only)")
-    p.add_argument("--no-ragged-kernel", dest="ragged_kernel",
-                   action="store_false",
-                   help="compose per-lane prefill/decode kernels (the "
-                        "pre-unified kernels; bench attribution "
-                        "control)")
+                   help="split alternating prefill/decode rounds (what "
+                        "multihost and meshed engines always run; the "
+                        "tests' reference)")
     p.add_argument("--precompile-serving", action="store_true",
                    default=False,
                    help="compile every steady-state prefill/decode "
@@ -214,9 +196,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="pre-PR-4 synchronous KV tier traffic: d2h "
                         "export inside scheduling and blocking tier "
                         "reads + whole-cache-copy import on the step "
-                        "loop (bench attribution control; the default "
-                        "is the zero-stall async export/staged-restore "
-                        "path)")
+                        "loop (what multihost engines always run; "
+                        "the default is the zero-stall async "
+                        "export/staged-restore path)")
     p.add_argument("--kv-restore-wait-s", type=float, default=2.0,
                    help="staged-restore admission budget: max seconds a "
                         "waiting request may hold its admission slot "
@@ -259,12 +241,10 @@ def config_from_args(args: argparse.Namespace) -> EngineConfig:
         num_scheduler_steps=args.num_scheduler_steps,
         device_stop=args.device_stop,
         adaptive_decode_k=args.adaptive_decode_k,
-        async_decode=args.async_decode,
         precompile_serving=args.precompile_serving,
         prefetch_decode=args.prefetch_decode,
         prefill_pipeline=args.prefill_pipeline,
         ragged_dispatch=args.ragged_dispatch,
-        ragged_kernel=args.ragged_kernel,
         num_speculative_tokens=args.num_speculative_tokens,
         ngram_prompt_lookup_max=args.ngram_prompt_lookup_max,
         ngram_prompt_lookup_min=args.ngram_prompt_lookup_min,

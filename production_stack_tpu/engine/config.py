@@ -58,9 +58,10 @@ class EngineConfig:
     # so the host applies exactly the generated tokens instead of
     # discarding overshoot after the fetch; a round whose lanes all
     # finish exits early (lax.while_loop). False (--no-device-stop)
-    # keeps the fixed-trip scan as the A/B control (either side's cost
-    # on an attached chip: not measured). Host-side stop STRINGS still
-    # resolve on the host (text matching cannot run on device).
+    # keeps the fixed-trip scan, the reference the bit-identity tests
+    # compare against (either side's cost on an attached chip: not
+    # measured). Host-side stop STRINGS still resolve on the host (text
+    # matching cannot run on device).
     # Multihost engines ignore this (the broadcast wire ships host
     # token lists, not stop matrices).
     device_stop: bool = True
@@ -73,27 +74,13 @@ class EngineConfig:
     # answers stop dispatching full-K programs (the K=32 waste mode).
     # False (--no-adaptive-decode-k) keeps the fixed-K behavior.
     adaptive_decode_k: bool = True
-    # double-buffered decode (vLLM --async-scheduling role): dispatch
-    # decode round N+1 chained on round N's ON-DEVICE sampled tokens
-    # before fetching round N, so the device never idles on the
-    # host<->device RTT. Requires num_scheduler_steps > 1; rounds with
-    # logit penalties, lane-set changes, or lanes within K tokens of
-    # finishing fall back to the synchronous path (outputs stay
-    # bit-identical). Ignored under multihost (followers replay host
-    # token lists). Default OFF: chained rounds delay prefill
-    # admission, and async taking precedence would make
-    # prefetch_decode below dead code — h2d prefetch gets the overlap
-    # benefit at synchronous admission instead. Either side's cost on
-    # an attached chip: not measured.
-    async_decode: bool = False
     # speculative h2d prefetch: while a fused decode round executes,
     # upload the NEXT round's packed host inputs (positions/ctx/keys
     # advanced by K on the same lanes) and dispatch it chained on the
     # on-device sampled tokens when the prediction holds. Removes the
     # serial host->device transfer (cost on an attached chip: not
     # measured) from the steady-state round critical path with fully
-    # synchronous admission (unlike async_decode, at most ONE round is
-    # in flight).
+    # synchronous admission (at most ONE round is in flight).
     # Requires num_scheduler_steps > 1; single-device; off multihost.
     prefetch_decode: bool = True
     # pipelined prefill: (1) every prefill dispatch ships ONE packed i32
@@ -107,8 +94,8 @@ class EngineConfig:
     # a staged-and-ready chunk is admitted as zero-cost by the
     # scheduler's decode interleave. Outputs are bit-identical to the
     # serial path (tests/test_prefill_pipeline.py). False = the
-    # pre-pipeline per-array upload path (--no-prefill-pipeline, the
-    # bench attribution control).
+    # per-array upload path (--no-prefill-pipeline): what a multihost
+    # engine's staging takes, and the tests' reference.
     prefill_pipeline: bool = True
     # unified ragged prefill+decode dispatch (Ragged Paged Attention
     # role, PAPERS.md): when a round has BOTH mid-prefill runners and
@@ -121,36 +108,10 @@ class EngineConfig:
     # prompt's chunk runs in the very next round, and the decode half
     # keeps the device stop masks + staged h2d prefetch. Tokens are
     # bit-identical to the split path (tests/test_ragged_dispatch.py).
-    # False (--no-ragged-dispatch) keeps the split alternating rounds
-    # as the bench attribution control; multihost engines, async-
-    # chained decode, and meshed (tp/pp) engines always split.
+    # False (--no-ragged-dispatch) keeps the split alternating rounds:
+    # the path multihost and meshed (tp/pp) engines always take, and
+    # the tests' reference.
     ragged_dispatch: bool = True
-    # single-kernel ragged paged attention (the device half of the
-    # Ragged Paged Attention design): route every Pallas attention
-    # call — decode rounds, packed prefill groups, and the mixed
-    # lane-typed rounds above — through ONE batched-grid kernel
-    # (ops/pallas_attention.ragged_paged_attention) whose grid
-    # iterates a flattened query-row space with per-lane metadata in
-    # scalar-prefetch SMEM: decode lanes contribute one row, prefill
-    # lanes their chunk's 8-row q-tiles, so ANY lane mix is one kernel
-    # launch with no cross-lane padding, and the packed-prefill /
-    # ragged-round program variants key on padded ROW-count buckets
-    # instead of the (group, chunk) lane-mix grid (fewer compiles =
-    # smaller cold-start tax). Each segment walks its lane's context a
-    # KV BLOCK of N pages at a time (N picked at trace time from the
-    # per-chip kv heads, head_dim, cache dtype and page size: ~256 KiB
-    # of K a block, 128-512 keys on the lane axis), blocks aligned to
-    # absolute page indices so that the keys summed together never
-    # depend on the asker; a one-row segment (every decode lane) walks
-    # with its own g query rows a kv head, a prefill tile with all 8
-    # rows fused. The composed kernels call the same walk, so tokens
-    # are identical and logical KV equal to float32 rounding between
-    # the two modes' programs (tests/test_ragged_dispatch.py), and the
-    # kernels bit-identical per row (tests/test_pallas_attention.py).
-    # Only effective with attention_impl=pallas; False
-    # (--no-ragged-kernel) keeps the composed per-lane kernels as the
-    # bench attribution control.
-    ragged_kernel: bool = True
     # compile every steady-state serving program shape at startup
     # (full-chunk + resume-tail prefill, packed groups, fused-K decode,
     # per ctx bucket) so no XLA compile lands inside a live request's
@@ -251,7 +212,7 @@ class EngineConfig:
     # preempt/resume -> finish, served by /debug/requests and exported
     # as `engine_request` spans. Recording is append-only host work off
     # the device-dispatch path; False makes every hook a single boolean
-    # check (the bench `@trace` A/B measures the difference, PERF.md).
+    # check.
     request_timeline: bool = True
     # finished timelines kept for /debug/requests (bounded ring)
     timeline_ring_size: int = 256
@@ -271,10 +232,10 @@ class EngineConfig:
     # start while the request WAITS; admission lands once the restore
     # does, in-place donated cache update). True restores the pre-PR-4
     # synchronous path — device-sync export inside scheduling, blocking
-    # tier reads + whole-cache-copy import on the step loop — as the
-    # bench attribution control (--sync-kv-offload / @synckv). Multihost
-    # engines always take the synchronous path (the broadcast wire ships
-    # host arrays, not device buffers).
+    # tier reads + whole-cache-copy import on the step loop
+    # (--sync-kv-offload): the path multihost engines always take (the
+    # broadcast wire ships host arrays, not device buffers) and the
+    # tests' reference.
     sync_kv_offload: bool = False
     # staged-restore admission budget: how long an admission slot may be
     # held back while the request's tier fetch + h2d staging are in

@@ -44,6 +44,10 @@ logger = init_logger(__name__)
 
 
 class LLMEngine:
+    # read by benchmarks/chip/engine_child.warm_programs; goes when the
+    # harness gets a public precompile (ROADMAP D3)
+    _async_decode = False
+
     def __init__(self, config: EngineConfig, params: dict | None = None):
         self.config = config
         if config.multihost:
@@ -161,27 +165,13 @@ class LLMEngine:
         # the dispatched rounds: their ratio is the blocks a sequence
         # holds there, the proof in a run that the release works
         self._window_blocks_per_seq = [0, 0]
-        # the round whose tokens the events being recorded belong to:
-        # the round just dispatched, or (async decode) the in-flight one
-        # being resolved a step later
-        self._event_round = 0
         self._step_ann = None     # live only inside a profiler session
         self._step_tagged = False
-        # async decode pipeline (double-buffered dispatch): the in-flight
-        # decode round whose sampled tokens are still ON DEVICE
-        self._pending_decode: dict | None = None
-        self._async_decode = (
-            config.async_decode
-            and config.num_scheduler_steps > 1
-            and not config.multihost
-        )
         # device-side stop masks (elastic fused decode): EOS / stop-id /
         # remaining-budget checks ride INSIDE the fused scan, a finished
         # lane freezes mid-round and the dispatch returns per-lane valid
         # counts. Multihost is out (the broadcast wire ships host token
-        # lists, not stop matrices); async-chained rounds fall back per
-        # dispatch (the chain commits the NEXT round before the valid
-        # counts are known — see the will_async gate in the decode path)
+        # lists, not stop matrices)
         self._device_stop = (
             config.device_stop
             and config.num_scheduler_steps > 1
@@ -200,8 +190,8 @@ class LLMEngine:
         # still executing, then dispatch it chained on the on-device
         # tokens — the ~116 ms serial h2d leaves the round's critical
         # path while admission behavior stays fully synchronous (one
-        # round in flight, unlike async_decode). Multihost is out: the
-        # broadcast wire ships host token lists, not device arrays.
+        # round in flight). Multihost is out: the broadcast wire ships
+        # host token lists, not device arrays.
         self._prefetch_decode = (
             config.prefetch_decode
             and config.num_scheduler_steps > 1
@@ -229,15 +219,13 @@ class LLMEngine:
         # ONE lane-typed device program (model_runner.ragged_dispatch);
         # the scheduler plans them (plan_ragged_round) instead of
         # alternating behind the interleave. Multihost is out (the
-        # broadcast wire ships host argument lists), async-chained
-        # decode is out (the chain commits round N+1 before round N's
-        # lane mix is known), and meshed engines are out (the fused
-        # buffer is a committed single-device transfer — same rule as
-        # the prefill pipeline / decode prefetch staging).
+        # broadcast wire ships host argument lists), and meshed engines
+        # are out (the fused buffer is a committed single-device
+        # transfer — same rule as the prefill pipeline / decode
+        # prefetch staging).
         self._ragged_dispatch = (
             config.ragged_dispatch
             and not config.multihost
-            and not self._async_decode
             and self.runner.mesh is None
         )
         self.scheduler.config.ragged_dispatch = self._ragged_dispatch
@@ -344,9 +332,9 @@ class LLMEngine:
         # d2h snapshot enqueued after the step's dispatch, tier IO on
         # the offload worker) + staged restore (tier fetch + h2d start
         # while the request WAITS; admission lands once the restore
-        # does). sync_kv_offload keeps the pre-PR-4 synchronous path as
-        # the bench attribution control; multihost always takes it (the
-        # broadcast wire ships host arrays, not device buffers).
+        # does). sync_kv_offload keeps the pre-PR-4 synchronous path:
+        # multihost always takes it (the broadcast wire ships host
+        # arrays, not device buffers), and the tests compare against it.
         self._kv_async = (
             self.offload is not None
             and not config.sync_kv_offload
@@ -884,7 +872,7 @@ class LLMEngine:
 
     def _restore_sync(self, seq: Sequence) -> None:
         """Pre-PR-4 synchronous restore: blocking tier reads on the
-        scheduler thread (--sync-kv-offload attribution control and
+        scheduler thread (--sync-kv-offload and
         multihost engines)."""
         bm = self.block_manager
         if not bm.enable_prefix_caching:
@@ -923,7 +911,7 @@ class LLMEngine:
         """SYNC-MODE chain-source pull: one batched blocking round-trip
         from the PD peer (then the shared cache server) for whatever
         the local tiers could not supply. Only reachable from
-        _restore_sync (--sync-kv-offload attribution control and
+        _restore_sync (--sync-kv-offload and
         multihost engines) — the zero-stall async path routes chain
         pulls through the staged restore's pending-READ map instead
         (request_chain_reads), so no socket ever runs on the scheduler
@@ -1290,56 +1278,12 @@ class LLMEngine:
         return any(k.startswith(pref) for k in list(self._seqs))
 
     def has_unfinished(self) -> bool:
-        # an in-flight async decode round counts as unfinished work even
-        # when every owning request was aborted — the step loop must keep
-        # stepping so the round gets flushed and its device arrays freed
-        return (
-            self.scheduler.has_unfinished()
-            or self._pending_decode is not None
-        )
+        return self.scheduler.has_unfinished()
 
-    # -- async decode pipeline --------------------------------------------
-    def _can_chain(self) -> bool:
-        """True when the in-flight decode round can be followed by
-        another dispatch on the SAME lanes before its tokens land:
-        no admission/prefill work waiting, every pending lane alive and
-        KV lookahead growable without preemption.
-
-        Host-side stop conditions (EOS / stop tokens / stop strings) do
-        NOT refuse the chain: the next round is dispatched speculatively
-        and a lane that turns out to have stopped discards its overshoot
-        tokens in _apply_multi_tokens, wasting at most ONE round (<=K
-        tokens) per finished stream — once the stop is observed at
-        resolve time, `any(s.finished)` flushes the pipeline before
-        another round is chained (vLLM --async-scheduling semantics).
-        Only the bounds the host CAN predict — max_tokens and
-        max_model_len — refuse the chain outright, since their final
-        rounds would be guaranteed waste."""
-        pend = self._pending_decode
-        if pend is None:
-            return False
-        if self.scheduler.waiting:
-            return False  # admission (and prefill priority) need schedule()
-        seqs: list[Sequence] = pend["seqs"]
-        k = pend["k"]
-        if any(s.finished for s in seqs):  # stopped/aborted mid-flight
-            return False
-        if any(self._is_guided(s) for s in seqs):
-            # the chained dispatch carries no DFA tables; guided lanes
-            # resolve each round so their device states re-initialize
-            return False
-        if any(s.sampling_params.logit_bias for s in seqs):
-            return False  # chained dispatch carries no bias arrays
-        if set(id(s) for s in self.scheduler.running) != set(
-            id(s) for s in seqs
-        ):
-            return False  # lane set changed (new prefill-done seq, ...)
-        return self._reserve_next_round(seqs, k)
-
+    # -- the staged next round (h2d prefetch) ------------------------------
     def _reserve_next_round(self, seqs: list[Sequence], k: int) -> bool:
-        """Shared bounds + block reservation for dispatching a SECOND
-        fused round before the first one's tokens are applied (async
-        chaining AND h2d-prefetch staging): every lane at least 2K
+        """Bounds + block reservation for staging a SECOND fused round
+        before the first one's tokens are applied: every lane at least 2K
         tokens from its max_tokens/max_model_len bounds, and tables
         grown to cover both rounds. All-or-nothing growth: allocate
         only after EVERY lane passed its checks, so a late refusal
@@ -1373,7 +1317,7 @@ class LLMEngine:
         speculatively staged (h2d prefetch): single device, no waiting
         admission work, no guided lanes, every lane at least 2K tokens
         from its bounds, and block tables growable to cover this round
-        plus the staged one (same all-or-nothing rule as _can_chain)."""
+        plus the staged one (all-or-nothing, _reserve_next_round)."""
         if self.runner.mesh is not None:
             return False  # the staged put is a committed single-device
             # transfer; under a mesh jit would have to reshard it
@@ -1389,7 +1333,9 @@ class LLMEngine:
             # NOT a ragged lane — its ring runs outside the round, so
             # pure-decode staging stays live under it
         if any(self._is_guided(s) for s in seqs):
-            return False  # per-round DFA state re-init (see _can_chain)
+            # the chained dispatch carries no DFA tables; guided lanes
+            # resolve each round so their device states re-initialize
+            return False
         return self._reserve_next_round(seqs, k)
 
     def _stage_fingerprint(
@@ -1412,33 +1358,6 @@ class LLMEngine:
             k,
         )
 
-    def _resolve_pending(self) -> list[RequestOutput]:
-        """Fetch the in-flight round's tokens and apply them (identical
-        bookkeeping to the synchronous path)."""
-        pend = self._pending_decode
-        self._pending_decode = None
-        # the events recorded below belong to the round being resolved,
-        # not to the one dispatched a moment ago
-        self._event_round = pend["round"]
-        with self.phases.span("fetch"):
-            # stackcheck: disable=device-sync-transitive — THE sanctioned
-            # fetch seam of async dispatch: the one device fetch for the
-            # in-flight round, taken after the next round was dispatched
-            toks = np.asarray(pend["toks"])  # (k, b) — the only fetch
-            lps = pend.get("lps")
-            if lps is not None:
-                # stackcheck: disable=device-sync-transitive — logprob
-                # arrays ride the same sanctioned in-flight-round fetch
-                lps = tuple(np.asarray(a) for a in lps)
-        seqs = pend["seqs"]
-        self._apply_multi_tokens(seqs, toks, pend["k"], lps=lps)
-        # requests aborted mid-flight already emitted their final output
-        # via abort_request; re-finalizing them would double-count
-        # requests_finished_total and emit a spurious finished output
-        return self._finalize_stepped(
-            [s for s in seqs if s.request_id in self._seqs]
-        )
-
     # stackcheck: not-hot — host-side token bookkeeping over numpy
     # arrays every caller already fetched at its metered fetch point
     def _apply_multi_tokens(
@@ -1447,8 +1366,7 @@ class LLMEngine:
         valid: np.ndarray | None = None,
         round_attrs: dict | None = None,
     ) -> None:
-        """Apply a fused-K round's (k, b) sampled tokens — the ONE copy
-        of the bookkeeping both the sync and async paths share.
+        """Apply a fused-K round's (k, b) sampled tokens.
         `lps` = (chosen (k,b), top_vals (k,b,CAP), top_ids (k,b,CAP))
         host arrays when any lane requested logprobs. `valid` = the
         device-stop per-lane valid counts ((b,) int32, full-lane
@@ -1513,7 +1431,7 @@ class LLMEngine:
             attrs = {
                 "k_chosen": k, "lanes_done": lanes_done,
                 "prefill_lanes": 0, "decode_lanes": len(seqs),
-                "engine_round": self._event_round,
+                "engine_round": self._round,
             }
             if extra_attrs:
                 attrs.update(extra_attrs)
@@ -1547,7 +1465,7 @@ class LLMEngine:
                 self._step_ann = None
                 ann.__exit__(None, None, None)
 
-    def _begin_round(self, kind: str, k: int, lanes: int, rows: int) -> int:
+    def _begin_round(self, kind: str, k: int, lanes: int, rows: int) -> None:
         """Number the round about to be dispatched and, inside a
         profiler session, tag this step's `engine.step` with it: `kind`
         (decode / ragged / prefill / verify), fused steps `k`, live
@@ -1555,7 +1473,6 @@ class LLMEngine:
         than one round (chained prefill chunks) is tagged with its
         first; every round still takes a number."""
         self._round += 1
-        self._event_round = self._round
         if self.runner.num_window_blocks:
             held = self._window_blocks_per_seq
             held[0] += self.block_manager.window_blocks_in_use
@@ -1565,61 +1482,11 @@ class LLMEngine:
             self._step_tagged = True
             ann.set_metadata(round=self._round, kind=kind, k=k,
                              lanes=lanes, rows=rows)
-        return self._round
 
-    # stackcheck: hot-path — the async-decode round trip: dispatch the
-    # next round BEFORE fetching the in-flight one; the only sanctioned
-    # fetch lives in _resolve_pending
+    # stackcheck: hot-path — a round is dispatched and fetched inside
+    # the step that scheduled it; the fetches are the metered
+    # `phases.span("fetch")` seams of the round runners below
     def _step_impl(self) -> list[RequestOutput]:
-        # async decode fast path: keep the device busy by dispatching the
-        # next round on the in-flight round's on-device tokens, THEN
-        # fetching the in-flight round (the fetch overlaps the new
-        # round's execution)
-        if self._pending_decode is not None:
-            with self.phases.span("schedule"):
-                can_chain = self._can_chain()
-            if can_chain:
-                pend = self._pending_decode
-                seqs: list[Sequence] = pend["seqs"]
-                k = pend["k"]
-                want_lp = pend.get("lps") is not None
-                with self.phases.span("pack"):
-                    temps, top_ps, top_ks, min_ps, keys, _ = (
-                        self._sampling_arrays(seqs)
-                    )
-                    keys[:, 1] += k  # k sampled-but-unapplied per lane
-                    positions = [s.num_tokens - 1 + k for s in seqs]
-                    ctx_lens = [s.num_tokens + k for s in seqs]
-                rnd = self._begin_round("decode", k, len(seqs), 0)
-                ys = self.runner.decode_multi(
-                    pend["toks"][-1], positions,
-                    [s.block_table for s in seqs], ctx_lens, k,
-                    temps, top_ps, top_ks, keys, min_ps=min_ps,
-                    lora_slots=[self._lora_slot(s) for s in seqs],
-                    want_logprobs=want_lp,
-                )
-                toks_next, lps_next = (
-                    (ys[0], ys[1:]) if want_lp else (ys, None)
-                )
-                outputs = self._resolve_pending()
-                self._pending_decode = {"seqs": seqs, "toks": toks_next,
-                                        "k": k, "lps": lps_next,
-                                        "round": rnd}
-                self.last_step_kind = "decode"
-                return outputs
-            # pipeline flush: apply the in-flight tokens before any
-            # scheduling decision reads sequence state
-            flushed_round = self._pending_decode["round"]
-            flushed = self._resolve_pending()
-            outputs = flushed + self._step_scheduled()
-            if self._step_ann is not None and not self._step_tagged:
-                # nothing was dispatched behind the flush
-                self._step_ann.set_metadata(
-                    round=flushed_round, kind="flush")
-            return outputs
-        return self._step_scheduled()
-
-    def _step_scheduled(self) -> list[RequestOutput]:
         with self.phases.span("schedule"):
             if self._kv_restores:
                 # start h2d uploads for restores whose tier fetch landed
@@ -1796,8 +1663,7 @@ class LLMEngine:
         decode step, shared by the split path and the ragged round's
         split-execution fallback): the fused K-step on-device path when
         the batch supports it, the host-sampled single-step path
-        otherwise. Returns the stepped sequences (empty when the round
-        went async — resolution happens on a later step)."""
+        otherwise. Returns the stepped sequences."""
         stepped: list[Sequence] = []
         with self.phases.span("pack"):
             tokens = [s.all_token_ids[-1] for s in seqs]
@@ -1839,17 +1705,8 @@ class LLMEngine:
                     s.sampling_params.logprobs is not None for s in seqs
                 )
                 bias = self._bias_arrays(seqs)
-                will_async = (
-                    self._async_decode and penalties is None
-                    and guided_tables is None and bias is None
-                )
-                # device-side stop masks: not on async-chained rounds —
-                # the chain commits round N+1 before round N's valid
-                # counts are known, so a mid-round freeze would leave
-                # the chained dispatch running on a pad token
                 stop = (
-                    self._stop_arrays(seqs)
-                    if self._device_stop and not will_async else None
+                    self._stop_arrays(seqs) if self._device_stop else None
                 )
                 staged_kw = {}
                 st = self._staged_decode
@@ -1873,7 +1730,7 @@ class LLMEngine:
             # wrapper replays host token lists and knows no stop
             # masks (and _device_stop is already off there)
             stop_kw = {"stop": stop} if stop is not None else {}
-            rnd = self._begin_round("decode", k_steps, len(seqs), 0)
+            self._begin_round("decode", k_steps, len(seqs), 0)
             ys = self.runner.decode_multi(
                 tokens, positions, tables, ctx_lens, k_steps,
                 temps, top_ps, top_ks, keys, min_ps=min_ps,
@@ -1894,15 +1751,6 @@ class LLMEngine:
                 toks_dev, lps_dev = (
                     (ys[0], ys[1:]) if want_lp else (ys, None)
                 )
-            if will_async:
-                # start the double-buffered pipeline: leave the
-                # tokens on device; the NEXT step dispatches the
-                # following round before fetching this one
-                self._pending_decode = {
-                    "seqs": seqs, "toks": toks_dev, "k": k_steps,
-                    "lps": lps_dev, "round": rnd,
-                }
-                return stepped
             if (self._prefetch_decode and penalties is None
                     and guided_tables is None and bias is None
                     and self._can_stage(seqs, k_steps)):
@@ -2212,7 +2060,7 @@ class LLMEngine:
                         "staged_hit": len(staged_kw) > 0,
                         "chained": False,
                         "group_size": len(works),
-                        "engine_round": self._event_round,
+                        "engine_round": self._round,
                         "ragged": True,
                         "prefill_lanes": len(works),
                         "decode_lanes": len(seqs),
@@ -2408,7 +2256,7 @@ class LLMEngine:
 
     def _note_ragged_round(self, n_pf: int, n_dec: int) -> None:
         """Fused lane-typed round accounting: tpu:ragged_rounds, the
-        lane-mix histogram feed, and the bench detail slot's totals."""
+        lane-mix histogram feed, and the lane totals."""
         self._ragged_rounds_total += 1
         self._ragged_prefill_lanes_total += n_pf
         self._ragged_decode_lanes_total += n_dec
@@ -2735,7 +2583,7 @@ class LLMEngine:
                         "staged_hit": staged_hit,
                         "chained": chained,
                         "group_size": len(works),
-                        "engine_round": self._event_round,
+                        "engine_round": self._round,
                         # lane-mix attribution (unified-round contract:
                         # every prefill event says what rode with it —
                         # the split path rides alone)
@@ -2930,7 +2778,7 @@ class LLMEngine:
             if self._tl_enabled and not seq.finished:
                 self.timeline.decode_round(
                     seq.request_id, len(new_tokens),
-                    attrs={"engine_round": self._event_round},
+                    attrs={"engine_round": self._round},
                 )
             stepped.append(seq)
         self.last_step_kind = "decode"
@@ -3517,7 +3365,7 @@ class LLMEngine:
                     {"ttft_s": round(
                         seq.metrics.first_token_time
                         - seq.metrics.arrival_time, 6,
-                    ), "engine_round": self._event_round},
+                    ), "engine_round": self._round},
                 )
         seq.append_token(int(token))
         self._generation_tokens_total += 1
@@ -3912,8 +3760,9 @@ class LLMEngine:
         FULL grid of prefill programs (every pow2 chunk bucket — final
         tail chunks land anywhere below max_prefill_chunk — x every
         reachable ctx bucket x every pow2 packed-group size), the
-        fused-K decode program per ctx bucket (+ the chained async
-        variant), and, with spec decode on, the packed verify programs.
+        fused-K decode program per ctx bucket (+ the chained variant
+        a staged round dispatches), and, with spec decode on, the
+        packed verify programs.
         Servers call this at startup (--precompile-serving) so no XLA
         compile lands inside a live request's TTFT/ITL. Returns the
         number of trash dispatches executed.
@@ -3987,8 +3836,7 @@ class LLMEngine:
         for kk, chained, stop in decode_precompile_variants(
             cfg.num_scheduler_steps,
             self.scheduler.config.adaptive_decode_k,
-            overlap=self._async_decode or self._prefetch_decode,
-            async_chained=self._async_decode,
+            overlap=self._prefetch_decode,
             device_stop=self._device_stop,
         ):
             n += rnr.precompile_decode(

@@ -89,7 +89,7 @@ class LongPrefillManager:
         self._cv = threading.Condition()
         self._worker: threading.Thread | None = None
         self._closed = False
-        # lifetime accounting (tpu:long_prefill_* / bench slot)
+        # lifetime accounting (tpu:long_prefill_*)
         self.requests_total = 0
         self.chunks_total = 0
         self.fallbacks_total = 0

@@ -251,15 +251,15 @@ class ModelRunner:
             )
             impl = "xla"
         self.attention_impl = impl
-        # single-kernel ragged paged attention: route EVERY pallas
-        # attention call — decode rounds, packed prefill groups, mixed
-        # lane-typed rounds — through the one batched-grid
-        # ragged_paged_attention kernel (ops/pallas_attention.py), so
-        # any lane mix is one launch and the packed-prefill/ragged
-        # program variants key on padded ROW-count buckets instead of
-        # the (s_pad, t_pad) lane-mix grid. --no-ragged-kernel keeps
-        # the composed per-lane kernels as the A/B control.
-        self.ragged_kernel = bool(config.ragged_kernel) and impl == "pallas"
+        # wherever Pallas runs, EVERY batched attention call — decode
+        # rounds, packed prefill groups, mixed lane-typed rounds — is
+        # the one batched-grid ragged_paged_attention kernel
+        # (ops/pallas_attention.py): any lane mix is one launch, and
+        # on one device the packed-prefill/ragged program variants key
+        # on padded ROW-count buckets instead of the (s_pad, t_pad)
+        # lane-mix grid. A derived fact, kept as an attribute for its
+        # readers (/version, chip_smoke.py, the benchmark's warm-up)
+        self.ragged_kernel = impl == "pallas"
         if impl == "pallas" and on_tpu:
             # compile the exact kernel variants serving will use —
             # sliding-window page walk and shard_map wrappers included —
@@ -267,8 +267,7 @@ class ModelRunner:
             # with the compiler's message instead of the first request,
             # and never selects another attention path
             self._pallas_smoke_test(mc)
-            if self.ragged_kernel:
-                self._ragged_smoke_test(mc)
+            self._ragged_smoke_test(mc)
         logger.info(
             "device: platform=%s device_kind=%s count=%d "
             "attention_impl=%s ragged_kernel=%s",
@@ -301,9 +300,9 @@ class ModelRunner:
             bool(config.prefill_pipeline) and self.mesh is None
         )
         # the round's phase spans (tracing/phases.py): wall seconds and
-        # counts per phase, fed to /metrics (tpu:engine_phase_*), the
-        # request timeline and the bench attribution slots, and written
-        # into the profiler's trace while one is taken. The runner times
+        # counts per phase, fed to /metrics (tpu:engine_phase_*) and
+        # the request timeline, and written into the profiler's trace
+        # while one is taken. The runner times
         # pack = host array build, h2d = upload enqueue (staged uploads
         # overlap compute but still count — they are real link work) and
         # dispatch = the jitted call's enqueue, once per step program
@@ -342,8 +341,8 @@ class ModelRunner:
         # compile-count observability: every program-variant build (a
         # jit-cache miss on one of the builders above) is counted per
         # kind — the cold-start compile cost and the ragged-kernel
-        # variant-space shrink become measurable (tpu:compile_events_
-        # total, bench `compiles` slot) instead of inferred from logs
+        # variant-space shrink become measurable
+        # (tpu:compile_events_total) instead of inferred from logs
         self.compile_events: dict[str, int] = {}
         self.compile_events_total = 0
 
@@ -633,17 +632,12 @@ class ModelRunner:
         # windowed page walk included (traced loop start + guarded
         # DMA); `_attn` routes through the shard_map TP wrappers under
         # a mesh, exactly as the step builders do
-        q = jnp.zeros((1, mc.num_heads, d), self.dtype)
-        tables = jnp.zeros((1, 2), jnp.int32)
-        lens = jnp.ones((1,), jnp.int32)
         qp = jnp.zeros((8, mc.num_heads, d), self.dtype)
         table1 = jnp.zeros((2,), jnp.int32)
         for kc, vc, spec in self._smoke_caches(mc):
-            out = self._attn("decode", q, jnp.int32(0), kc, vc, tables,
-                             lens, spec=spec)
-            out2 = self._attn("prefill", qp, jnp.int32(0), kc, vc,
-                              table1, jnp.int32(0), spec=spec)
-            jax.block_until_ready((out, out2))
+            out = self._attn("prefill", qp, jnp.int32(0), kc, vc,
+                             table1, jnp.int32(0), spec=spec)
+            jax.block_until_ready(out)
 
     def _ragged_smoke_test(self, mc: ModelConfig) -> None:
         """Compile the unified ragged kernel in the grid shape serving
@@ -726,21 +720,17 @@ class ModelRunner:
     # `mesh is not None -> *_tp else *` call ladders
     def _attn(self, kind: str, q, layer, kc, vc, *args, spec=None):
         """Route one attention call to the pallas kernel for `kind`
-        ("prefill" | "decode" | "ragged"), picking the shard_map TP
-        variant under a mesh and filling the static block-size/scale/
-        interpret/window arguments from the runner's config. All
-        kernel call sites dispatch through here, so a new kernel (the
-        unified ragged one) lands at one seam instead of eight."""
+        ("prefill": a lone chunk | "ragged": every batched call),
+        picking the shard_map TP variant under a mesh and filling the
+        static block-size/scale/interpret/window arguments from the
+        runner's config. All kernel call sites dispatch through
+        here."""
         from production_stack_tpu.ops import pallas_attention
 
         fns = {
             "prefill": (
                 pallas_attention.paged_prefill_attention,
                 pallas_attention.paged_prefill_attention_tp,
-            ),
-            "decode": (
-                pallas_attention.paged_decode_attention,
-                pallas_attention.paged_decode_attention_tp,
             ),
             "ragged": (
                 pallas_attention.ragged_paged_attention,
@@ -1656,12 +1646,11 @@ class ModelRunner:
         mc = self.model_config
         scale = self._scale
 
-        if self.attention_impl == "pallas" and self.ragged_kernel:
+        if self.attention_impl == "pallas":
             # ONE ragged-kernel launch over the whole packed token
             # axis: every block of t_pad (pow2 >= RAGGED_TQ) belongs
             # to exactly one lane, so per-block segment metadata is a
-            # static lane map + the traced q_starts — the s_pad
-            # unrolled per-lane kernel ladder collapses to one grid
+            # static lane map + the traced q_starts
             tq = RAGGED_TQ
             n_blk = (s_pad * t_pad) // tq
             lane_of = np.arange(n_blk, dtype=np.int32) * tq // t_pad
@@ -1680,25 +1669,6 @@ class ModelRunner:
                     "ragged", q, l, kc, vc, tables, blk_seg, seg_meta,
                     spec=spec,
                 )
-        elif self.attention_impl == "pallas":
-
-            # tables: (s_pad, P) per-sequence padded block tables;
-            # q_starts: (s_pad,) absolute position of each chunk's row 0
-            def attn(q, l, kc, vc, tables, q_starts, positions2d,
-                     total_lens, spec=None):
-                qs = q.reshape(s_pad, t_pad, mc.num_heads, mc.head_dim)
-                if spec is not None and spec.block_map is not None:
-                    # mapped once for all lanes; the per-lane calls
-                    # below take the mapped rows as they are
-                    tables = spec.block_map[tables]
-                    spec = spec._replace(block_map=None)
-                outs = []
-                for s in range(s_pad):
-                    outs.append(self._attn(
-                        "prefill", qs[s], l, kc, vc, tables[s],
-                        q_starts[s], spec=spec,
-                    ))
-                return jnp.concatenate(outs, axis=0)
         else:
 
             # tables: (s_pad, c_pad) per-sequence gather slots
@@ -1825,16 +1795,14 @@ class ModelRunner:
 
     def _decode_attn_closure(self):
         """The decode-shaped attention callback shared by the
-        single-step, fused-K, and ragged-round builders: the unified
-        ragged kernel in all-decode-row configuration (decode lanes
-        are single-row segments of the one grid — the SAME program the
-        mixed rounds launch), the composed per-sequence-grid decode
-        kernel (--no-ragged-kernel A/B control), or the XLA gather
-        path. `tables` = padded per-sequence block tables (b, pages)
-        on the pallas paths, per-position gather slots (b, c_pad) on
-        the XLA path."""
+        single-step, fused-K, and ragged-round builders: the ragged
+        kernel in all-decode-row configuration (decode lanes are
+        single-row segments of the one grid — the SAME program the
+        mixed rounds launch), or the XLA gather path. `tables` =
+        padded per-sequence block tables (b, pages) on the pallas
+        path, per-position gather slots (b, c_pad) on the XLA path."""
         scale = self._scale
-        if self.attention_impl == "pallas" and self.ragged_kernel:
+        if self.attention_impl == "pallas":
             tq = RAGGED_TQ
 
             def attn(q, l, kc, vc, tables, context_lens, spec=None):
@@ -1862,18 +1830,6 @@ class ModelRunner:
                     spec=spec,
                 )
                 return out[:b]
-        elif self.attention_impl == "pallas":
-
-            def attn(q, l, kc, vc, tables, context_lens, spec=None):
-                # q: (b, nq, d); kc/vc: full (L, nkv, slots, d) — the
-                # kernel DMAs pages straight from HBM, no gathered
-                # copy. Under TP the kernel is shard_mapped: each chip
-                # runs it on its local kv-head shard (GQA groups are
-                # chip-local)
-                return self._attn(
-                    "decode", q, l, kc, vc, tables, context_lens,
-                    spec=spec,
-                )
         else:
 
             def attn(q, l, kc, vc, tables, context_lens, spec=None):
@@ -2720,7 +2676,7 @@ class ModelRunner:
         path; `groups`: (group_size, chunk_len, total_len) for the packed
         path. Returns the number of dispatches executed. A compile that
         lands inside a live request costs seconds and lands straight
-        in that request's TTFT/ITL, so servers and benches call this at
+        in that request's TTFT/ITL, so servers call this at
         startup
         for every bucket the configured workload shape can reach —
         including the resume-tail chunk (a fully prefix-cached prompt
@@ -2786,10 +2742,10 @@ class ModelRunner:
         ITL measurement. Greedy sampling arrays select the same program
         as any temperature (sampling params are runtime operands).
 
-        `chained=True` additionally compiles the async-pipeline variant
-        (device-array token input — a DISTINCT program cache key): the
-        chained dispatch crosses the same ctx buckets mid-pipeline, so
-        async serving needs both programs warm.
+        `chained=True` additionally compiles the variant a staged
+        round dispatches (device-array token input — a DISTINCT program
+        cache key): it crosses the same ctx buckets, so an engine that
+        stages its next round needs both programs warm.
 
         `stop=True` compiles the device-stop (elastic) program variant
         instead of the fixed-trip scan, at stop-id cap 0 — the cap only
@@ -2813,7 +2769,7 @@ class ModelRunner:
             npages = c_pad // bs
             # same 2x-plus-slack rule as precompile_prefill: the low
             # half of the pool may already hold live/cached K/V (warmup
-            # runs before precompile in bench/server startup), and the
+            # runs before precompile in server startup), and the
             # trash table must never reach down into it
             if nb < 2 * npages + 64:
                 logger.warning(
@@ -3255,9 +3211,8 @@ class ModelRunner:
         semantics, bit-identical to the host single-step path).
 
         `token_ids` may be a full-lane (b,) DEVICE array instead of a
-        host list: the async-decode pipeline chains round N+1 directly on
-        round N's on-device sampled tokens, so no host fetch sits between
-        dispatches.
+        host list: the staged round of the h2d prefetch chains round
+        N+1 directly on round N's on-device sampled tokens.
 
         `guided`: optional (cache_token, init_states (b,), lane_map (b,),
         token_class (M, V), class_mask (S, C), class_trans (S, C)) —
@@ -3589,8 +3544,7 @@ class ModelRunner:
         configuration). Prefill and decode lanes belong to different
         sequences with disjoint block tables, and the decode half's
         post-sample math is _decode_round_core's verbatim, so tokens
-        and logical KV are bit-identical to both the composed-kernel
-        ragged round and the split path."""
+        and logical KV are bit-identical to the split path."""
         from production_stack_tpu.engine.sampler import (
             RAGGED_IDLE_TOKEN,
             sample_tokens,

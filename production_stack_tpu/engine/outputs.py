@@ -48,9 +48,9 @@ class EngineStatsSnapshot:
     # the round seen from inside (tracing/phases.py), each a (seconds,
     # count) pair: the step thread's phases (schedule, pack, h2d,
     # dispatch, fetch, apply, idle, deliver) — tpu:engine_phase_*_seconds
-    # in /metrics, the bench.py phase detail slot — and the event-loop
-    # thread's waits for the engine lock by site (filled in by
-    # AsyncLLMEngine.stats) — tpu:event_loop_lock_wait_seconds,
+    # in /metrics — and the event-loop thread's waits for the engine
+    # lock by site (filled in by AsyncLLMEngine.stats) —
+    # tpu:event_loop_lock_wait_seconds,
     # tpu:admit_lock_wait_seconds
     engine_phases: dict = field(default_factory=dict)
     loop_lock_waits: dict = field(default_factory=dict)
@@ -96,8 +96,7 @@ class EngineStatsSnapshot:
     # the per-phase TTFT attribution — ring compute, device->host KV
     # materialization, paged-cache landing, and tier-export overflow
     # seconds that ran while long jobs were in flight —
-    # tpu:prefill_ring/d2h/land/overflow_* in /metrics and the bench
-    # `long_prefill` detail slot
+    # tpu:prefill_ring/d2h/land/overflow_* in /metrics
     long_prefill_requests_total: int = 0
     long_prefill_chunks_total: int = 0
     long_prefill_fallbacks_total: int = 0
@@ -108,21 +107,21 @@ class EngineStatsSnapshot:
     # elastic fused decode: rounds dispatched, sampled-then-discarded
     # overshoot tokens (~0 with device stops, except host-resolved stop
     # strings), and whole-round device early exits — tpu:decode_* in
-    # /metrics and the bench `elastic_decode` detail slot
+    # /metrics
     decode_rounds_total: int = 0
     decode_overshoot_tokens_total: int = 0
     decode_early_exit_rounds_total: int = 0
     # unified ragged dispatch: fused lane-typed rounds, rounds a mixed
     # plan ran split (exotic lanes), and per-side lane totals —
-    # tpu:ragged_* in /metrics and the bench `ragged_dispatch` slot
+    # tpu:ragged_* in /metrics
     ragged_rounds_total: int = 0
     ragged_split_rounds_total: int = 0
     ragged_prefill_lanes_total: int = 0
     ragged_decode_lanes_total: int = 0
     # compile-count observability: program-variant builds (jit cache
     # misses on the runner's step builders) since boot, total and per
-    # builder kind — tpu:compile_events_total in /metrics and the
-    # bench `compiles` detail slot. The cold-start compile cost
+    # builder kind — tpu:compile_events_total in /metrics. The
+    # cold-start compile cost
     # (and the single-kernel variant-space shrink) read directly off
     # this instead of being inferred from compile logs.
     compile_events_total: int = 0
@@ -131,8 +130,7 @@ class EngineStatsSnapshot:
     # zero-stall KV tiering attribution: deferred-export batches (wall
     # seconds measured ON THE OFFLOAD WORKER — overlapped activity, not
     # step-loop stalls) and staged restores (enqueue -> landed), plus
-    # per-tier hit/miss/byte counters — tpu:kv_* in /metrics and the
-    # bench `kv_offload` detail slot
+    # per-tier hit/miss/byte counters — tpu:kv_* in /metrics
     kv_export_seconds_total: float = 0.0
     kv_export_blocks_total: int = 0
     kv_export_bytes_total: int = 0
@@ -148,7 +146,7 @@ class EngineStatsSnapshot:
     # disaggregated-prefill peer pulls (PeerTier): blocks served by /
     # missing from the PD peer, bytes pulled over the transfer link,
     # and failed pulls (dead peer, corrupt frame) — tpu:kv_peer_* in
-    # /metrics and the bench `pd_transfer` detail slot
+    # /metrics
     kv_peer_hits_total: int = 0
     kv_peer_misses_total: int = 0
     kv_peer_read_bytes_total: int = 0
@@ -156,8 +154,7 @@ class EngineStatsSnapshot:
     # shared cache server (RemoteTier): blocks served by / missing from
     # the cluster-wide cache, bytes over the wire in each direction,
     # write-behind put_batch frames shipped, and failed flushes/pulls
-    # (dead server) — tpu:kv_remote_* in /metrics and the bench
-    # `kv_remote` detail slot
+    # (dead server) — tpu:kv_remote_* in /metrics
     kv_remote_hits_total: int = 0
     kv_remote_misses_total: int = 0
     kv_remote_read_bytes_total: int = 0

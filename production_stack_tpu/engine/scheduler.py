@@ -34,7 +34,7 @@ the very next round instead of queueing behind the interleave streak,
 and the admission-K clamp no longer applies to in-round prefill work
 (pick_decode_k's ragged branch). The streak counter, staged-bypass
 accounting, and clamp stay in place for the split path
-(`--no-ragged-dispatch`, multihost, async-chained rounds).
+(`--no-ragged-dispatch`, multihost, meshed engines).
 """
 
 from __future__ import annotations
@@ -163,10 +163,9 @@ def decode_k_buckets(cap: int, adaptive: bool) -> list[int]:
     """The fused-decode K program variants a serving config can
     dispatch: just the cap with adaptive K off, plus every pow2 below
     it with adaptive K on (pick_decode_k rounds remaining budgets UP
-    to the next pow2, so these are exactly the reachable Ks). The ONE
-    copy shared by LLMEngine.precompile_serving and bench.py's warmup
-    so the warmed variant set can never drift from the scheduler's
-    rounding."""
+    to the next pow2, so these are exactly the reachable Ks). Kept
+    beside pick_decode_k so the set LLMEngine.precompile_serving warms
+    can never drift from the scheduler's rounding."""
     cap = max(1, cap)
     ks = {cap}
     if adaptive and cap > 1:
@@ -178,24 +177,16 @@ def decode_k_buckets(cap: int, adaptive: bool) -> list[int]:
 
 
 def decode_precompile_variants(
-    cap: int, adaptive: bool, *,
-    overlap: bool, async_chained: bool, device_stop: bool,
+    cap: int, adaptive: bool, *, overlap: bool, device_stop: bool,
 ) -> list[tuple[int, bool, bool]]:
     """(k, chained, stop) decode program variants a serving config
-    dispatches — the ONE copy of the variant-selection policy shared by
-    LLMEngine.precompile_serving and bench.py's warmup, so neither can
-    silently warm a different set than the runtime selects (a missed
-    variant = a mid-request XLA compile). `overlap` = async decode OR
-    h2d prefetch (both dispatch the chained program); `async_chained`
-    rounds never carry stop masks (the chain commits round N+1 before
-    round N's valid counts exist), so async engines warm fixed-trip
-    programs instead."""
+    dispatches — the variant-selection policy LLMEngine.precompile_serving
+    warms by, kept beside the scheduler's rounding so it cannot silently
+    warm a different set than the runtime selects (a missed variant = a
+    mid-request XLA compile). `overlap` = the h2d prefetch, whose staged
+    round dispatches the chained program."""
     return [
-        (
-            k,
-            overlap and k > 1,
-            device_stop and not async_chained and k > 1,
-        )
+        (k, overlap and k > 1, device_stop and k > 1)
         for k in decode_k_buckets(cap, adaptive)
     ]
 

@@ -545,7 +545,7 @@ class KVCacheServer:
 class InProcessCacheServer:
     """A KVCacheServer on its own daemon thread's event loop — the ONE
     start-on-a-thread/stop-via-call_soon_threadsafe harness shared by
-    the bench `@remotekv` mode, the smoke harness, and the test suite
+    the smoke harness and the test suite
     (blocking clients in those contexts need the server's loop off
     their thread; production runs the module as its own process)."""
 

@@ -89,7 +89,7 @@ class CacheClient:
     """Blocking, connection-POOLED cache-server client.
 
     Only ever driven from worker/executor threads (the offload worker,
-    the sync-mode attribution control, tests) — never the scheduler
+    the synchronous path of --sync-kv-offload, tests) — never the scheduler
     thread. The pool exists so a long `put_batch` upload does not
     serialize a concurrent `stats`/`lookup` probe behind it: each call
     borrows a connection, creating up to `pool_size` on demand."""
@@ -387,7 +387,7 @@ class RemoteTier(KVTier):
             if stale:
                 self.flush()
 
-    # -- read side (offload worker / sync attribution control) -------------
+    # -- read side (offload worker / the --sync-kv-offload path) -----------
     def get(self, h: int) -> np.ndarray | None:
         with self._lock:
             arr = self._buf.get(h)

@@ -87,11 +87,10 @@ _KV_RING = 3
 # every kernel CALL it stages while a program traces (counting inside
 # the jitted bodies would under-count — jax's trace cache dedupes
 # identical inner-jit calls, but each call still launches at
-# runtime). A composed mixed round stages the prefill kernel once PER
-# LANE inside the layer scan; the unified kernel stages ONCE per
-# forward regardless of the lane mix — tests/test_ragged_dispatch.py
-# pins the one-launch contract on exactly this counter.
-_LAUNCHES = {"decode": 0, "prefill": 0, "ragged": 0}
+# runtime). The ragged kernel stages ONCE per forward regardless of
+# the lane mix — tests/test_ragged_dispatch.py pins the one-launch
+# contract on exactly this counter.
+_LAUNCHES = {"prefill": 0, "ragged": 0}
 
 
 def launch_counts() -> dict:
@@ -629,7 +628,12 @@ def paged_decode_attention(
     interpret: bool = False,
     window: int | None = None,
 ) -> jax.Array:
-    """One decode step of paged attention. Returns (b, nq, d) in q.dtype."""
+    """One decode step of paged attention, a grid program a sequence.
+    Returns (b, nq, d) in q.dtype. The runner does not call it: its
+    decode rows are one-row segments of `ragged_paged_attention`. Its
+    only use is as the reference that tests/test_pallas_attention.py
+    and tests/test_pallas_attention_groups.py hold those rows to, bit
+    for bit."""
     return _paged_call(
         _decode_kernel, "paged_decode_attention", 1,
         (jnp.reshape(layer, 1), block_tables, context_lens),
@@ -701,18 +705,5 @@ def paged_prefill_attention_tp(
     return _over_heads(
         paged_prefill_attention, mesh, q, k_cache, v_cache, layer,
         block_table, q_start, block_size=block_size, scale=scale,
-        interpret=interpret, window=window,
-    )
-
-
-def paged_decode_attention_tp(
-    q, k_cache, v_cache, layer, block_tables, context_lens, *,
-    mesh: jax.sharding.Mesh, block_size: int, scale: float,
-    interpret: bool = False, window: int | None = None,
-) -> jax.Array:
-    """paged_decode_attention with heads sharded over the tp axis."""
-    return _over_heads(
-        paged_decode_attention, mesh, q, k_cache, v_cache, layer,
-        block_tables, context_lens, block_size=block_size, scale=scale,
         interpret=interpret, window=window,
     )

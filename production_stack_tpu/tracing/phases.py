@@ -4,8 +4,8 @@ One helper for every boundary of the round. ``with timer.span("pack"):``
 
 * adds the elapsed ``time.perf_counter()`` seconds and one observation
   to the timer's (seconds, count) pair of that phase — the source of
-  the ``tpu:engine_phase_*_seconds`` samples, of the request timeline's
-  ``group_phase_s`` attribute and of bench.py's detail slot;
+  the ``tpu:engine_phase_*_seconds`` samples and of the request
+  timeline's ``group_phase_s`` attribute;
 * while a ``jax.profiler`` session is active, also writes the span as
   ``engine.pack`` into the profiler's OWN trace (``TraceAnnotation``),
   on the same clock as the device planes, so that the reducer of a

@@ -5,7 +5,7 @@ on every start: `JAX_COMPILATION_CACHE_DIR` when the operator sets it
 (jax reads that variable itself; nothing is set here), else
 `<checkout>/.jax_cache` — never a temp name, a pid or a time. Called by
 the chip-owning entry points (`python -m production_stack_tpu.engine`,
-`bench.py`) before their first compile.
+`benchmarks/chip/engine_child.py`) before their first compile.
 """
 
 from __future__ import annotations

@@ -195,125 +195,3 @@ def test_recycle_holds_concurrency(tmp_path):
     # finished sessions are NOT retained: their chat history would
     # otherwise accumulate for the whole run
     assert len(b.sessions) == 2
-
-
-def test_sweep_label_modifiers_parse():
-    """bench.py sweep labels: @-suffixes override per-config workload
-    env so one chip session can walk the reference's QPS/user serving
-    curve (run.sh sweeps QPS)."""
-    bench = _load_bench()
-
-    cfgs = bench._parse_sweep_labels(
-        "k8-sync-packed@qps4@u32@r1,k12-async-nopack@chunk1024,"
-        "k8-sync-packed@nopfx"
-    )
-    label, k, ps, ad, ov = cfgs[0]
-    assert (label, k, ad) == ("k8-sync-packed@qps4@u32@r1", 8, False)
-    assert ps > 1  # packed
-    assert ov == {"PST_BENCH_QPS": "4.0", "PST_BENCH_USERS": "32",
-                  "PST_BENCH_ROUNDS": "1"}
-    _, k2, ps2, ad2, ov2 = cfgs[1]
-    assert (k2, ps2, ad2) == (12, 1, True)
-    assert ov2 == {"PST_BENCH_PREFILL_CHUNK": "1024"}
-    assert cfgs[2][4] == {"PST_BENCH_PREFETCH": "0"}
-
-    # @trace: the tracing-overhead A/B config (PERF.md zero-cost claim)
-    (tcfg,) = bench._parse_sweep_labels("k8-sync-packed@trace")
-    assert tcfg[4] == {"PST_BENCH_TRACE": "1"}
-
-    import pytest
-    with pytest.raises(ValueError, match="modifier"):
-        bench._parse_sweep_labels("k8-sync-packed@bogus7")
-    with pytest.raises(ValueError, match="bad sweep config"):
-        bench._parse_sweep_labels("k8-asynch-packed")
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod_wd", os.path.join(os.path.dirname(__file__), "..",
-                                     "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-def test_elastic_sweep_modifiers_parse():
-    """@elastic / @noelastic drive the elastic-fused-decode A/B
-    (device stops + adaptive K vs the fixed-trip fixed-K control)."""
-    bench = _load_bench()
-    (on,) = bench._parse_sweep_labels("k16-sync-packed@elastic")
-    assert on[4] == {"PST_BENCH_ELASTIC": "1"}
-    (off,) = bench._parse_sweep_labels("k16-sync-packed@noelastic")
-    assert off[4] == {"PST_BENCH_ELASTIC": "0"}
-
-
-def test_ragged_sweep_modifiers_parse():
-    """@ragged / @noragged drive the unified-ragged-dispatch A/B
-    (lane-typed mixed rounds vs the split alternating control —
-    BENCH_SWEEP_ragged.json, PERF.md chip-queue item 6)."""
-    bench = _load_bench()
-    (on,) = bench._parse_sweep_labels("k8-sync-packed@ragged")
-    assert on[4] == {"PST_BENCH_RAGGED": "1"}
-    (off,) = bench._parse_sweep_labels("k8-sync-packed@noragged")
-    assert off[4] == {"PST_BENCH_RAGGED": "0"}
-
-
-def test_sweep_continues_past_watchdog_config(tmp_path, monkeypatch):
-    """A config whose child hits the 1200 s run watchdog is recorded in
-    the sweep JSON as {"ok": false, "watchdog": true} and the sweep
-    CONTINUES to the remaining configs instead of aborting the run."""
-    bench = _load_bench()
-    rows = {
-        "k16-sync-packed": {
-            "metric": "bench-aborted: watchdog (run_config"
-                      "[k16-sync-packed])",
-            "value": 0.0, "unit": "gen_tokens/s/chip",
-            "vs_baseline": 0.0, "watchdog": True,
-            "error": "k16 exceeded 1200s — chip wedged?",
-        },
-        "k8-sync-packed": {
-            "metric": "stub measurement", "value": 42.0,
-            "unit": "gen_tokens/s/chip", "vs_baseline": 0.1,
-        },
-    }
-    calls = []
-
-    def fake_run_one(label, env, timeout):
-        calls.append(label)
-        # the stub stands in for the per-config subprocess: the wedged
-        # config's child emitted its watchdog row and exited
-        return dict(rows[label]), False
-
-    monkeypatch.setattr(bench, "_run_one_config", fake_run_one)
-    out = tmp_path / "sweep.json"
-    monkeypatch.setenv("PST_BENCH_SWEEP_CONFIGS",
-                       "k16-sync-packed,k8-sync-packed")
-    monkeypatch.setenv("PST_BENCH_SWEEP_OUT", str(out))
-    bench._run_sweep()
-
-    data = json.loads(out.read_text())
-    assert [r.get("ok") for r in data["results"]] == [False, True]
-    assert data["results"][0]["watchdog"] is True
-    assert calls == ["k16-sync-packed", "k8-sync-packed"]
-
-
-def test_child_watchdog_row_carries_marker(capsys):
-    """The in-child run watchdog emits the explicit watchdog marker the
-    sweep parent keys on (and exits via os._exit, stubbed here)."""
-    bench = _load_bench()
-    import os as _os
-
-    exited = {}
-    orig_exit = _os._exit
-    _os._exit = lambda code: exited.setdefault("code", code)
-    try:
-        t = bench._arm_watchdog(3600.0, "run_config[stub]")
-        t.cancel()
-        # fire the timer body directly instead of waiting an hour
-        t.function()
-    finally:
-        _os._exit = orig_exit
-    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert row["watchdog"] is True and row["value"] == 0.0
-    assert exited["code"] == 2

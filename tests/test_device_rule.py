@@ -103,7 +103,7 @@ def _refused(*a, **kw):
 
 
 def test_kernel_compile_error_fails_construction(as_if_on_tpu, monkeypatch):
-    monkeypatch.setattr(pallas_attention, "paged_decode_attention", _refused)
+    monkeypatch.setattr(pallas_attention, "paged_prefill_attention", _refused)
     with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
         ModelRunner(_config(D128_CFG.name))
 

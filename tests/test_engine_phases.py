@@ -187,10 +187,11 @@ def test_every_builder_jits_under_a_kind_and_counts_it_under_the_same():
     assert not bare
 
 
-@pytest.mark.parametrize("ragged_kernel,pipeline", [
-    (True, True), (False, True), (False, False)])
+@pytest.mark.parametrize("attention_impl,pipeline", [
+    ("pallas", True), ("xla", True), ("xla", False)])
 def test_programs_lower_under_the_name_of_their_kind(
-        ragged_kernel, pipeline):
+        attention_impl, pipeline):
+    # the rows programs, the lane-mix programs, the per-array uploads
     lowered = []
 
     def listen(event, duration, fun_name=None, **_):
@@ -199,7 +200,7 @@ def test_programs_lower_under_the_name_of_their_kind(
 
     jax.monitoring.register_event_duration_secs_listener(listen)
     try:
-        e = LLMEngine(cfg(ragged_kernel=ragged_kernel,
+        e = LLMEngine(cfg(attention_impl=attention_impl,
                           prefill_pipeline=pipeline))
         e.add_request("a", prompt_token_ids=prompt(21),
                       sampling_params=greedy(12))

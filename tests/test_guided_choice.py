@@ -46,7 +46,7 @@ def test_sampled_guided_still_lands_on_a_choice():
 def test_guided_under_multistep_config():
     """K>1 engines must route guided lanes through the single-step
     masked path."""
-    eng = make_engine(num_scheduler_steps=4, async_decode=True)
+    eng = make_engine(num_scheduler_steps=4)
     sp = SamplingParams(max_tokens=32, temperature=0.0,
                         guided_choice=["alpha", "beta"])
     out = eng.generate(["pick"], sp)[0]

@@ -73,7 +73,7 @@ def test_sampled_output_parses_against_schema():
 def test_guided_json_under_multistep_config():
     """K>1 engines route guided lanes through the single-step masked
     path (the documented guided-vs-multistep cliff)."""
-    eng = make_engine(num_scheduler_steps=4, async_decode=True)
+    eng = make_engine(num_scheduler_steps=4)
     sp = SamplingParams(max_tokens=96, temperature=0.0,
                         guided_json=SCHEMA)
     out = eng.generate(["x"], sp)[0]

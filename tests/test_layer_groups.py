@@ -58,6 +58,16 @@ def ids(n: int, seed: int = 0) -> list[int]:
             np.random.default_rng(seed).integers(1, MC.vocab_size - 4, n)]
 
 
+def settled_stats(runner) -> tuple[int, ...]:
+    """`moe_stats()` once every dispatched program's counters have
+    landed. The runner never waits for them (a scrape reads what has
+    arrived, and may lag by a round): a program's counters can still be
+    on their way when the round's tokens are already on the host, so a
+    test that counts to the last row waits here."""
+    jax.block_until_ready(list(runner._stats_pending))
+    return runner.moe_stats()
+
+
 def reference(params, tokens) -> np.ndarray:
     with jax.default_matmul_precision("highest"):
         return np.asarray(rm.mimo_v2_forward(MC, params, tokens))
@@ -141,7 +151,7 @@ def test_the_engine_generates_the_reference_greedy_tokens():
     want = [int(np.argmax(ref[len(prompt) - 1 + i]))
             for i in range(len(out.token_ids))]
     assert list(out.token_ids) == want
-    routed, local, active = e.runner.moe_stats()
+    routed, local, active = settled_stats(e.runner)
     # 3 routed layers, 4 experts a token, 45 prompt + 11 decoded rows
     assert routed == 3 * 4 * (45 + 11)
     assert 0.15 < local / routed < 0.35 and 0 < active <= 3 * 4 * 56
@@ -158,7 +168,7 @@ def test_the_counters_are_each_programs_own_and_summed_on_the_host():
     e.generate([ids(20, seed=6)], SamplingParams(
         max_tokens=5, temperature=0.0, ignore_eos=True))
     assert "stats" not in r.k_cache
-    assert r.moe_stats()[0] == 2**31 - 1 + 3 * 4 * (20 + 4)
+    assert settled_stats(r)[0] == 2**31 - 1 + 3 * 4 * (20 + 4)
     assert not r._stats_pending
 
 

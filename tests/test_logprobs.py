@@ -58,7 +58,7 @@ def test_logprobs_multi_step_matches_single_step():
     sp = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True,
                         logprobs=4)
     a = run(make_engine(num_scheduler_steps=1), sp)
-    b = run(make_engine(num_scheduler_steps=4, async_decode=False), sp)
+    b = run(make_engine(num_scheduler_steps=4), sp)
     assert a.token_ids == b.token_ids
     for ea, eb in zip(a.logprobs, b.logprobs):
         assert math.isclose(ea["logprob"], eb["logprob"], abs_tol=1e-4)
@@ -67,11 +67,15 @@ def test_logprobs_multi_step_matches_single_step():
         ]
 
 
-def test_logprobs_async_pipeline_matches_sync():
-    sp = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True,
+def test_logprobs_staged_rounds_match_unstaged():
+    """The chained program a staged round dispatches returns the same
+    logprob arrays as the round built from host tokens."""
+    sp = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True,
                         logprobs=2)
-    a = run(make_engine(num_scheduler_steps=4, async_decode=True), sp)
-    b = run(make_engine(num_scheduler_steps=4, async_decode=False), sp)
+    eng = make_engine(num_scheduler_steps=4)
+    a = run(eng, sp)
+    assert eng._staged_hits_total >= 2
+    b = run(make_engine(num_scheduler_steps=4, prefetch_decode=False), sp)
     assert a.token_ids == b.token_ids
     for ea, eb in zip(a.logprobs, b.logprobs):
         assert math.isclose(ea["logprob"], eb["logprob"], abs_tol=1e-5)
@@ -146,7 +150,7 @@ def test_logprobs_with_sampling_contains_chosen():
     full-distribution log-softmax value (may rank below top-N)."""
     sp = SamplingParams(max_tokens=6, temperature=1.0, seed=3,
                         ignore_eos=True, logprobs=3)
-    out = run(make_engine(num_scheduler_steps=4, async_decode=False), sp)
+    out = run(make_engine(num_scheduler_steps=4), sp)
     for tok, entry in zip(out.token_ids, out.logprobs):
         assert entry["token_id"] == tok
         assert np.isfinite(entry["logprob"])

@@ -133,58 +133,56 @@ def test_streaming_deltas_cover_all_tokens():
     assert "".join(deltas) == final.text
 
 
-def test_prefetch_decode_parity_and_hits():
+_PREFETCH_SAMPLING = {
+    "greedy": [dict(temperature=0.0)] * 3,
+    # the staged rounds must derive the same (seed, generated_len + i)
+    # keys as the unstaged ones
+    "sampled": [dict(temperature=0.9, top_p=0.9, seed=7)] * 3,
+    "mixed": [
+        dict(temperature=0.0),
+        dict(temperature=0.8, seed=3),
+        dict(temperature=0.8, top_p=0.9, min_p=0.05, seed=9),
+    ],
+}
+
+
+def _prefetch_engine(prefetch, **overrides):
+    kw = dict(
+        model="pst-tiny-debug", tokenizer="byte", dtype="float32",
+        cache_dtype="float32", block_size=8, num_kv_blocks=128,
+        max_num_seqs=4, max_prefill_chunk=32,
+        num_scheduler_steps=4, prefetch_decode=prefetch, seed=0,
+    )
+    kw.update(overrides)
+    return LLMEngine(EngineConfig(**kw))
+
+
+@pytest.mark.parametrize("sampling", list(_PREFETCH_SAMPLING))
+def test_prefetch_decode_parity_and_hits(sampling):
     """Speculative h2d prefetch (stage_decode_multi): streams must be
     bit-identical with prefetch on vs off, and in a steady fused run
-    the staged buffer must actually get consumed (hits > 0)."""
-    from production_stack_tpu.engine.config import EngineConfig
-    from production_stack_tpu.engine.llm_engine import LLMEngine
-    from production_stack_tpu.engine.sampling_params import SamplingParams
-
-    def eng(prefetch):
-        return LLMEngine(EngineConfig(
-            model="pst-tiny-debug", tokenizer="byte", dtype="float32",
-            cache_dtype="float32", block_size=8, num_kv_blocks=128,
-            max_num_seqs=4, max_prefill_chunk=32,
-            num_scheduler_steps=4, async_decode=False,
-            prefetch_decode=prefetch, seed=0,
-        ))
-
-    rng = __import__("numpy").random.RandomState(5)
+    the staged buffer must actually get consumed: several rounds are
+    dispatched from a stage."""
+    rng = np.random.RandomState(5)
     prompts = [rng.randint(0, 384, size=n).tolist() for n in (9, 17, 30)]
-    sps = [
-        SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True),
-        SamplingParams(max_tokens=24, temperature=0.8, seed=3,
-                       ignore_eos=True),
-        SamplingParams(max_tokens=24, temperature=0.8, top_p=0.9,
-                       min_p=0.05, seed=9, ignore_eos=True),
-    ]
-    e_on = eng(True)
+    sps = [SamplingParams(max_tokens=24, ignore_eos=True, **kw)
+           for kw in _PREFETCH_SAMPLING[sampling]]
+    e_on = _prefetch_engine(True)
     out_on = [o.token_ids for o in e_on.generate(prompts, sps)]
-    e_off = eng(False)
+    e_off = _prefetch_engine(False)
     out_off = [o.token_ids for o in e_off.generate(prompts, sps)]
     assert out_on == out_off
-    assert e_on._staged_hits_total > 0
+    assert all(len(t) == 24 for t in out_on)
+    assert e_on._staged_hits_total >= 3
     assert e_off._staged_hits_total == 0
 
 
-def test_prefetch_survives_mid_stream_admission():
+@pytest.mark.parametrize("device_stop", [True, False])
+def test_prefetch_survives_mid_stream_admission(device_stop):
     """A new arrival between rounds invalidates the staged prediction
-    (lane set changes) — the engine must fall back cleanly and stay
-    bit-identical to the unprefetched engine."""
-    from production_stack_tpu.engine.config import EngineConfig
-    from production_stack_tpu.engine.llm_engine import LLMEngine
-    from production_stack_tpu.engine.sampling_params import SamplingParams
-
-    def eng(prefetch):
-        return LLMEngine(EngineConfig(
-            model="pst-tiny-debug", tokenizer="byte", dtype="float32",
-            cache_dtype="float32", block_size=8, num_kv_blocks=128,
-            max_num_seqs=4, max_prefill_chunk=32,
-            num_scheduler_steps=4, async_decode=False,
-            prefetch_decode=prefetch, seed=0,
-        ))
-
+    (lane set changes): the stage is dropped or refused as a counted
+    miss, never dispatched, and the engine stays bit-identical to the
+    unprefetched engine."""
     sp = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
 
     def run(e):
@@ -198,11 +196,17 @@ def test_prefetch_survives_mid_stream_admission():
                     outs[o.request_id] = o.token_ids
             steps += 1
             if steps == 3:  # mid-decode admission breaks the lane set
+                assert (e._staged_decode is not None) == e._prefetch_decode
+                hits = e._staged_hits_total
                 e.add_request("b", prompt_token_ids=list(range(30, 45)),
                               sampling_params=sp)
+            if steps == 4:
+                # the round behind the admission did not take the stage
+                assert e._staged_hits_total == hits
         return outs
 
-    a, b = run(eng(True)), run(eng(False))
+    a = run(_prefetch_engine(True, device_stop=device_stop))
+    b = run(_prefetch_engine(False, device_stop=device_stop))
     assert a == b and set(a) == {"a", "b"}
 
 
@@ -211,17 +215,7 @@ def test_stage_invalidated_by_block_free_epoch():
     staged buffer (code-review r5: freed block ids can be re-handed to
     another sequence, so a same-length table could silently reference
     someone else's KV). The epoch rides the fingerprint."""
-    from production_stack_tpu.engine.config import EngineConfig
-    from production_stack_tpu.engine.llm_engine import LLMEngine
-    from production_stack_tpu.engine.sampling_params import SamplingParams
-
-    eng = LLMEngine(EngineConfig(
-        model="pst-tiny-debug", tokenizer="byte", dtype="float32",
-        cache_dtype="float32", block_size=8, num_kv_blocks=128,
-        max_num_seqs=2, max_prefill_chunk=32,
-        num_scheduler_steps=4, async_decode=False,
-        prefetch_decode=True, seed=0,
-    ))
+    eng = _prefetch_engine(True, max_num_seqs=2)
     sp = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
     eng.add_request("a", prompt_token_ids=list(range(1, 12)),
                     sampling_params=sp)

@@ -124,35 +124,6 @@ def test_engine_decode_parity_pallas_vs_xla():
     assert out_p == out_x
 
 
-@pytest.mark.parametrize("seed", [0])
-def test_tp_shard_map_parity(seed):
-    """The shard_mapped TP kernel (8-device CPU mesh, kv heads sharded)
-    must match the single-device XLA gather reference exactly — the
-    config the north-star benchmark serves (Llama-3-8B tp=8)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from production_stack_tpu.ops.pallas_attention import (
-        paged_decode_attention_tp,
-    )
-    from production_stack_tpu.parallel.sharding import make_mesh
-
-    # nkv=8 so the kv-head axis splits 1-per-chip at tp=8 (hardest case)
-    q, kc, vc, bt, ctx = make_case(seed, b=4, nkv=8, g=2, d=128)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    mesh = make_mesh(8)
-    kc_sh = jax.device_put(kc, NamedSharding(mesh, P(None, None, "tp", None)))
-    vc_sh = jax.device_put(vc, NamedSharding(mesh, P(None, None, "tp", None)))
-    q_sh = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
-    out_p = paged_decode_attention_tp(
-        q_sh, kc_sh, vc_sh, jnp.int32(1), bt, ctx,
-        mesh=mesh, block_size=8, scale=scale, interpret=True,
-    )
-    out_r = reference(q, kc, vc, 1, bt, ctx, 8, scale)
-    np.testing.assert_allclose(
-        np.asarray(out_p), np.asarray(out_r), rtol=2e-5, atol=2e-5
-    )
-
-
 # ---- ragged prefill kernel ------------------------------------------------
 
 def make_prefill_case(seed, t=16, prefix_pages=3, bs=8, nkv=2, g=2, d=128,
@@ -607,11 +578,11 @@ def test_ragged_kernel_tp_shard_map_parity():
     )
 
 
-def test_engine_single_kernel_vs_composed_and_xla():
-    """Whole-engine greedy decode is identical across the XLA path,
-    the composed kernels (--no-ragged-kernel), and the single-kernel
-    mode — chunked prompts + multi-step decode so the packed-prefill
-    rows program AND the kernel-mode decode loop both run."""
+def test_engine_single_kernel_vs_xla():
+    """Whole-engine greedy decode is identical on the XLA path and on
+    the Pallas path (the ragged kernel) — chunked prompts + multi-step
+    decode so the packed-prefill rows program AND the kernel-mode
+    decode loop both run."""
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.llm_engine import LLMEngine
     from production_stack_tpu.engine.sampling_params import SamplingParams
@@ -620,22 +591,16 @@ def test_engine_single_kernel_vs_composed_and_xla():
         model="pst-tiny-debug", tokenizer="byte", dtype="float32",
         cache_dtype="float32", block_size=8, num_kv_blocks=64,
         max_num_seqs=2, max_prefill_chunk=8, seed=0,
-        num_scheduler_steps=4, async_decode=False,
+        num_scheduler_steps=4,
     )
     sp = SamplingParams(max_tokens=10, temperature=0.0, ignore_eos=True)
     prompts = ["a chunked prompt long enough for several chunks",
                "short one"]
     out_x = [o.token_ids for o in LLMEngine(
         EngineConfig(attention_impl="xla", **kw)).generate(prompts, sp)]
-    e_c = LLMEngine(EngineConfig(
-        attention_impl="pallas", ragged_kernel=False, **kw
-    ))
-    assert not e_c.runner.ragged_kernel
-    out_c = [o.token_ids for o in e_c.generate(prompts, sp)]
     e_k = LLMEngine(EngineConfig(attention_impl="pallas", **kw))
     assert e_k.runner.ragged_kernel
     out_k = [o.token_ids for o in e_k.generate(prompts, sp)]
-    assert out_c == out_x
     assert out_k == out_x
 
 
@@ -651,7 +616,7 @@ def test_engine_multistep_pallas_path():
         model="pst-tiny-debug", tokenizer="byte", dtype="float32",
         cache_dtype="float32", block_size=8, num_kv_blocks=32,
         max_num_seqs=2, max_prefill_chunk=32,
-        num_scheduler_steps=4, async_decode=False,
+        num_scheduler_steps=4,
     )
     sp = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
     prompts = ["multi step pallas"]

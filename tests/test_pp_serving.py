@@ -62,7 +62,7 @@ def test_pp_sampled_and_multistep():
     sp0 = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
     ref0 = [o.token_ids for o in make_engine().generate(PROMPTS, sp0)]
     out0 = [o.token_ids for o in make_engine(
-        pp=2, num_scheduler_steps=4, async_decode=False,
+        pp=2, num_scheduler_steps=4,
     ).generate(PROMPTS, sp0)]
     assert out0 == ref0
 

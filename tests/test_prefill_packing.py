@@ -358,7 +358,7 @@ def test_precompile_serving_covers_all_buckets():
     the packed verify programs for every pow2 lane count."""
     eng = LLMEngine(tiny_cfg(
         max_prefill_seqs=4, num_kv_blocks=256, max_model_len=64,
-        num_scheduler_steps=2, async_decode=False,
+        num_scheduler_steps=2,
         num_speculative_tokens=2,
     ))
     r = eng.runner

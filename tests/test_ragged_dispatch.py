@@ -481,15 +481,13 @@ def test_pick_decode_k_ragged_drops_midprefill_clamp():
 
 
 def test_ragged_engine_gates():
-    """Engine-level gating: ragged is off under async decode and under
-    --no-ragged-dispatch, on otherwise; the scheduler flag follows."""
+    """Engine-level gating: ragged is off under --no-ragged-dispatch,
+    on otherwise; the scheduler flag follows."""
     e = _engine(True)
     assert e._ragged_dispatch and e.scheduler.config.ragged_dispatch
     e = _engine(False)
     assert not e._ragged_dispatch
     assert not e.scheduler.config.ragged_dispatch
-    e = _engine(True, async_decode=True)
-    assert not e._ragged_dispatch
 
 
 # -- (f) single-kernel ragged paged attention (PR 11) ------------------------
@@ -539,23 +537,6 @@ def test_single_kernel_exotic_sampling_parity():
         assert abs(x["logprob"] - y["logprob"]) < 1e-4
 
 
-def test_single_kernel_vs_composed_kernels_parity():
-    """Kernel-mode vs composed-kernel (--no-ragged-kernel) ragged
-    engines: same staggered mixed workload, identical tokens and equal
-    logical KV — the A/B the bench @norpakernel control measures."""
-    sp = SamplingParams(max_tokens=10, temperature=0.0, ignore_eos=True)
-    arrivals = [(0, "a", SHORT), (2, "b", LONG)]
-    e_k = _engine(True, attention_impl="pallas")
-    out_k = _run_staggered(e_k, arrivals, sp)
-    e_c = _engine(True, attention_impl="pallas", ragged_kernel=False)
-    out_c = _run_staggered(e_c, arrivals, sp)
-    assert e_k.runner.ragged_kernel and not e_c.runner.ragged_kernel
-    assert {r: t for r, (t, _) in out_k.items()} == {
-        r: t for r, (t, _) in out_c.items()
-    }
-    _assert_kv_close(_cached_kv_by_hash(e_k), _cached_kv_by_hash(e_c))
-
-
 def _mixed_dispatch(runner, n_pf, chunk_len, k=4, total_len=16):
     """Drive one mixed ragged_dispatch on a fresh runner: n_pf prefill
     lanes, each mid-prefill with `chunk_len` tokens of a `total_len`
@@ -595,16 +576,14 @@ def test_single_kernel_one_launch_per_lane_mix():
     """THE acceptance contract: under the single kernel, a mixed
     round's traced program contains a LANE-COUNT-INDEPENDENT number of
     ragged kernel launches (one per layer for the fused step-0
-    forward, one per layer inside the decode loop) and ZERO composed
-    prefill/decode kernel launches; the composed control's prefill
-    launches scale with the lane count."""
+    forward, one per layer inside the decode loop) and ZERO launches
+    of the lone-chunk prefill kernel."""
     from production_stack_tpu.ops import pallas_attention as pa
 
     import jax
 
-    def launches(ragged_kernel, n_pf):
-        e = _engine(True, attention_impl="pallas",
-                    ragged_kernel=ragged_kernel, num_kv_blocks=256)
+    def launches(n_pf):
+        e = _engine(True, attention_impl="pallas", num_kv_blocks=256)
         # the kernel entries are themselves jitted and jax's trace
         # cache is process-global: clear it so each program's launch
         # count is measured fresh, not deduped against a prior engine
@@ -613,36 +592,29 @@ def test_single_kernel_one_launch_per_lane_mix():
         _mixed_dispatch(e.runner, n_pf, chunk_len=4)
         return pa.launch_counts()
 
-    l1 = launches(True, 1)
-    l2 = launches(True, 4)
+    l1 = launches(1)
+    l2 = launches(4)
     # layers run under lax.scan, so the traced program holds exactly
     # TWO ragged launches — the fused step-0 forward's and the decode
     # loop body's — regardless of the lane mix
-    assert l1["ragged"] == l2["ragged"] == 2
-    assert l1["prefill"] == l1["decode"] == 0
-    assert l2["prefill"] == l2["decode"] == 0
-
-    c1 = launches(False, 1)
-    c2 = launches(False, 4)
-    assert c1["ragged"] == c2["ragged"] == 0
-    # composed control: the packed-prefill half unrolls one kernel per
-    # PADDED lane inside the layer scan — launches scale with the mix
-    assert c2["prefill"] == 4 * c1["prefill"] > 0
-    assert c1["decode"] == c2["decode"] > 0
+    assert l1 == l2 == {"ragged": 2, "prefill": 0}
 
 
 def test_single_kernel_variant_space_shrinks():
     """Precompile-variant acceptance: lane mixes that pack to the same
     row bucket share ONE program under the single kernel, so both the
     live lane-mix matrix and precompile_ragged compile strictly fewer
-    ragged variants than the PR 7 (group, chunk) grid."""
-    # live matrix: (lanes x chunk_len) mixes — composed keys
+    ragged variants than the (group, chunk) grid of the lane-mix
+    programs, which the XLA path still runs."""
+    # live matrix: (lanes x chunk_len) mixes — lane-mix keys
     # (s_pad, t_pad, ...) = 4 variants, rows keys r_pad = 3
     mixes = [(1, 4), (2, 4), (1, 12), (2, 12)]
 
-    def variants(ragged_kernel):
-        e = _engine(True, attention_impl="pallas",
-                    ragged_kernel=ragged_kernel, num_kv_blocks=256,
+    def impl(rows):
+        return "pallas" if rows else "xla"
+
+    def variants(rows):
+        e = _engine(True, attention_impl=impl(rows), num_kv_blocks=256,
                     max_prefill_chunk=16)
         for n_pf, clen in mixes:
             _mixed_dispatch(e.runner, n_pf, clen)
@@ -655,9 +627,8 @@ def test_single_kernel_variant_space_shrinks():
 
     # the split packed-prefill path collapses the same way: its
     # program keys on (r_pad, pc_pad) instead of (s_pad, t_pad, c_pad)
-    def pf_variants(ragged_kernel):
-        e = _engine(True, attention_impl="pallas",
-                    ragged_kernel=ragged_kernel, num_kv_blocks=256,
+    def pf_variants(rows):
+        e = _engine(True, attention_impl=impl(rows), num_kv_blocks=256,
                     max_prefill_chunk=16)
         r = e.runner
         nb = r.num_blocks
@@ -681,9 +652,8 @@ def test_single_kernel_variant_space_shrinks():
     # group dedupe is 1:1, so the warm pass never compiles MORE —
     # the precompile_serving group grid (multiple chunk buckets) is
     # where the row-bucket dedupe strictly shrinks, pinned above
-    def precompiled(ragged_kernel):
-        e = _engine(True, attention_impl="pallas",
-                    ragged_kernel=ragged_kernel, num_kv_blocks=256,
+    def precompiled(rows):
+        e = _engine(True, attention_impl=impl(rows), num_kv_blocks=256,
                     max_prefill_chunk=16, max_prefill_seqs=4)
         e.runner.precompile_ragged(
             [16], [4], max_groups=4, chunk_len=16,
@@ -774,7 +744,6 @@ def _ship_idle_lanes_as_before(monkeypatch):
 
 @pytest.mark.parametrize("device_stop, mode", [
     (True, "staged"), (False, "staged"), (True, "plain"),
-    (False, "chained"),
 ])
 def test_five_live_of_32_lanes_as_zero_row_segments(
         monkeypatch, device_stop, mode):
@@ -808,7 +777,6 @@ def test_five_live_of_32_lanes_as_zero_row_segments(
     kw = dict(
         attention_impl="pallas", max_num_seqs=32, num_kv_blocks=256,
         device_stop=device_stop, prefetch_decode=mode == "staged",
-        async_decode=mode == "chained",
     )
 
     def run():

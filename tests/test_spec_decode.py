@@ -148,8 +148,8 @@ def test_spec_with_multistep_config_prefers_spec_at_batch_1():
     """Spec + num_scheduler_steps>1: the lone-lane case goes through
     speculation; outputs still match the plain engine."""
     sp = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
-    a = make_engine(spec=4, num_scheduler_steps=4,
-                    async_decode=False).generate([PROMPT], sp)[0]
+    a = make_engine(spec=4, num_scheduler_steps=4).generate(
+        [PROMPT], sp)[0]
     b = make_engine(spec=0, num_scheduler_steps=1).generate(
         [PROMPT], sp)[0]
     assert a.token_ids == b.token_ids

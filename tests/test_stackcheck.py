@@ -806,7 +806,7 @@ def test_ragged_dispatch_stays_off_hot_paths():
     (plan_ragged_round) run once per engine round — zero unsuppressed
     device-sync-hot + blocking-async findings over engine/ (the one
     sanctioned fetch set lives in the UNMARKED bookkeeping helpers,
-    same split as the decode path's step/_resolve_pending)."""
+    same split as the decode path's step/_apply_multi_tokens)."""
     report = analyze_paths(
         [str(PACKAGE / "engine")],
         select=["device-sync-hot", "blocking-async"],
