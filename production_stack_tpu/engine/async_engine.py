@@ -22,6 +22,7 @@ from production_stack_tpu.engine.outputs import (
     RequestOutput,
 )
 from production_stack_tpu.engine.sampling_params import SamplingParams
+from production_stack_tpu.engine.sequence import PromptIds
 from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
 
@@ -159,6 +160,10 @@ class AsyncLLMEngine:
     ) -> AsyncIterator[RequestOutput]:
         if self.sleeping:
             raise EngineSleepingError("engine is sleeping")
+        if prompt_token_ids is not None:
+            # what grows with the prompt is done out here: the step
+            # thread wants this lock (and the GIL) for its next round
+            prompt_token_ids = PromptIds.of(prompt_token_ids)
         q: asyncio.Queue[RequestOutput] = asyncio.Queue()
         finished = False
         try:

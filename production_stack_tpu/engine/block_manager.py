@@ -25,6 +25,8 @@ in it can still fall inside a later query's window.
 
 from __future__ import annotations
 
+import sys
+from array import array
 from collections import OrderedDict
 
 import xxhash
@@ -32,19 +34,37 @@ import xxhash
 NULL_BLOCK = 0
 
 
-def hash_block(prev_hash: int, token_ids: tuple[int, ...],
-               extra: tuple = ()) -> int:
-    """Chain hash for a full block given the previous block's hash."""
-    h = xxhash.xxh64()
-    h.update(prev_hash.to_bytes(8, "little", signed=False))
-    for t in token_ids:
-        h.update(int(t).to_bytes(4, "little", signed=False))
+# array's typecode of 4 bytes: "I" wherever a C int has 32 bits
+_U32 = next(c for c in "IL" if array(c).itemsize == 4)
+
+
+def token_bytes(token_ids) -> memoryview:
+    """`token_ids` as ONE buffer of little-endian uint32s, the bytes
+    `hash_block` folds; a block is a slice of it. Refuses what does not
+    fit (an id below 0 or from 2**32 on: OverflowError; a non-integer:
+    TypeError)."""
+    ids = array(_U32, token_ids)
+    if sys.byteorder != "little":
+        ids.byteswap()
+    return memoryview(ids).cast("B")
+
+
+def hash_block(prev_hash: int, token_ids, extra: tuple = ()) -> int:
+    """Chain hash for a full block given the previous block's hash:
+    xxh64 over the previous hash's 8 bytes, then each token as 4
+    little-endian bytes. `token_ids` is the block's ids, or its slice
+    of a `token_bytes` buffer (a caller that hashes a whole prompt
+    converts it once): the same bytes, so the same digest."""
+    if not isinstance(token_ids, memoryview):
+        token_ids = token_bytes(token_ids)
+    data = prev_hash.to_bytes(8, "little", signed=False) + token_ids
     for e in extra:
-        h.update(str(e).encode())
-    return h.intdigest()
+        data += str(e).encode()
+    return xxhash.xxh64_intdigest(data)
 
 
-def iter_chain_hashes(token_ids, block_size: int, seed: int = 0):
+def iter_chain_hashes(token_ids, block_size: int, seed: int = 0,
+                      start: int = 0):
     """Chain hashes for each *full* block of token_ids, lazily.
 
     THE one token->block-hash folding, shared by the BlockManager, the
@@ -52,12 +72,17 @@ def iter_chain_hashes(token_ids, block_size: int, seed: int = 0):
     lookup hints — every copy of this loop that drifts (seed, chunk
     boundary, partial-block handling) makes cross-component prefix
     matches miss silently, so there is exactly one. Lazy so matchers
-    can stop hashing at the first miss."""
+    can stop hashing at the first miss. `start` resumes a chain at that
+    block, with `seed` the hash of the block before it."""
+    n_blocks = len(token_ids) // block_size
+    if start >= n_blocks:
+        return
+    # the ids become bytes once, whatever number of blocks follows
+    buf = token_bytes(token_ids[start * block_size:n_blocks * block_size])
+    step = 4 * block_size
     prev = seed
-    for i in range(len(token_ids) // block_size):
-        prev = hash_block(
-            prev, tuple(token_ids[i * block_size:(i + 1) * block_size])
-        )
+    for off in range(0, len(buf), step):
+        prev = hash_block(prev, buf[off:off + step])
         yield prev
 
 
@@ -98,6 +123,13 @@ class BlockManager:
         # token-level prefix-cache counters (engine /metrics contract)
         self.prefix_queries = 0
         self.prefix_hits = 0
+        # hash_block calls on a PROMPT's blocks (tpu:prefix_blocks_
+        # hashed_total): over prefix_queries / block_size, how often a
+        # queried block was hashed; at most once where every caller
+        # hands its sequence's `block_hashes` along. The blocks that
+        # generated tokens fill are hashed too (once, when registered)
+        # and are not counted: nobody queried them
+        self.blocks_hashed = 0
 
         # KV offload hooks (wired by LLMEngine when offload is configured):
         # on_admit(hashes)      -> new cached blocks live in HBM
@@ -147,29 +179,50 @@ class BlockManager:
         blk.ref_count += 1
 
     # -- sequence-level API ----------------------------------------------
-    def block_hashes_for(self, token_ids: list[int],
-                         seed: int = 0) -> list[int]:
+    def iter_hashes(self, token_ids: list[int], seed: int = 0,
+                    hashes: list[int] | None = None):
+        """Chain hashes of token_ids' full blocks, lazily. `hashes` is
+        what the caller knows of this chain already (a sequence's
+        `block_hashes`): read first, then extended by every block this
+        has to hash, so that no block of a prompt is hashed twice
+        whoever asks (restore, admission and its retries, the
+        registration of computed blocks)."""
+        n_blocks = len(token_ids) // self.block_size
+        known = hashes[:n_blocks] if hashes else []
+        yield from known
+        for h in iter_chain_hashes(token_ids, self.block_size,
+                                   known[-1] if known else seed,
+                                   start=len(known)):
+            self.blocks_hashed += 1
+            if hashes is not None:
+                hashes.append(h)
+            yield h
+
+    def block_hashes_for(self, token_ids: list[int], seed: int = 0,
+                         hashes: list[int] | None = None) -> list[int]:
         """Chain hashes for each *full* block of token_ids.
 
         `seed` starts the chain (0 = base model; LoRA requests pass a
-        per-adapter seed so adapters never share KV blocks)."""
-        return list(
-            iter_chain_hashes(token_ids, self.block_size, seed)
-        )
+        per-adapter seed so adapters never share KV blocks); `hashes`
+        as in `iter_hashes`."""
+        return list(self.iter_hashes(token_ids, seed, hashes))
 
     def contains_hash(self, h: int) -> bool:
         return h in self.cached_blocks
 
-    def match_prefix(self, token_ids: list[int],
-                     seed: int = 0) -> tuple[list[int], int]:
+    def match_prefix(self, token_ids: list[int], seed: int = 0,
+                     hashes: list[int] | None = None,
+                     ) -> tuple[list[int], int]:
         """Longest cached prefix: returns (block_ids, num_cached_tokens).
+        Hashes no block past the first miss (`hashes` as in
+        `iter_hashes`).
 
         Does NOT take references; pairs with allocate_prompt.
         """
         if not self.enable_prefix_caching:
             return [], 0
         matched: list[int] = []
-        for h in self.block_hashes_for(token_ids, seed):
+        for h in self.iter_hashes(token_ids, seed, hashes):
             bid = self.cached_blocks.get(h)
             if bid is None:
                 break
@@ -179,6 +232,7 @@ class BlockManager:
     def allocate_prompt(
         self, token_ids: list[int], seed: int = 0,
         reuse_cache: bool = True,
+        hashes: list[int] | None = None,
     ) -> tuple[list[int], int] | None:
         """Allocate the block table for a prompt, reusing cached prefix blocks.
 
@@ -188,13 +242,18 @@ class BlockManager:
 
         `reuse_cache=False` skips prefix matching (the computed blocks
         still REGISTER afterwards): prompt_logprobs needs every position
-        actually computed — a cache hit would skip its rows."""
+        actually computed — a cache hit would skip its rows. `hashes` as
+        in `iter_hashes`: the first `num_cached_tokens // block_size` of
+        them are then the adopted blocks', which are registered
+        already."""
         n = len(token_ids)
         self.prefix_queries += n
         if not reuse_cache:
             matched, cached_tokens = [], 0
         else:
-            matched, cached_tokens = self.match_prefix(token_ids, seed)
+            matched, cached_tokens = self.match_prefix(
+                token_ids, seed, hashes
+            )
         cached_tokens = min(cached_tokens, n - 1)
         num_matched_blocks = cached_tokens // self.block_size
         matched = matched[:num_matched_blocks]
@@ -241,19 +300,27 @@ class BlockManager:
         return True
 
     def register_block(
-        self, prev_hash: int, token_ids: tuple[int, ...], block_id: int
+        self, prev_hash: int, token_ids: tuple[int, ...], block_id: int,
+        of_prompt: bool = False,
     ) -> int:
-        """Incrementally content-address one full block; returns its hash."""
+        """Incrementally content-address one full block; returns its
+        hash. `of_prompt`: the block lies inside a prompt that was
+        counted in prefix_queries, so its hashing counts too."""
         h = hash_block(prev_hash, token_ids)
+        self.blocks_hashed += of_prompt
+        self.register_hash(h, block_id)
+        return h
+
+    def register_hash(self, h: int, block_id: int) -> None:
+        """Content-address a full block whose chain hash is known."""
         if not self.enable_prefix_caching:
-            return h
+            return
         blk = self.blocks[block_id]
         if blk.block_hash is None and h not in self.cached_blocks:
             blk.block_hash = h
             self.cached_blocks[h] = block_id
             if self.on_admit is not None:
                 self.on_admit([h])
-        return h
 
     def adopt_cached_block(self, h: int) -> int | None:
         """Claim a free block to hold offload-restored contents for hash h.
@@ -508,9 +575,10 @@ class WindowedBlockManager(BlockManager):
         return max(0, first_key // self.block_size)
 
     # -- sequence-level API -------------------------------------------------
-    def match_prefix(self, token_ids: list[int],
-                     seed: int = 0) -> tuple[list[int], int]:
-        matched, _ = super().match_prefix(token_ids, seed)
+    def match_prefix(self, token_ids: list[int], seed: int = 0,
+                     hashes: list[int] | None = None,
+                     ) -> tuple[list[int], int]:
+        matched, _ = super().match_prefix(token_ids, seed, hashes)
         # allocate_prompt computes at least one token: check the window
         # at the boundary it will really start from
         n = min(len(matched), (len(token_ids) - 1) // self.block_size)
@@ -521,8 +589,9 @@ class WindowedBlockManager(BlockManager):
         return matched[:n], n * self.block_size
 
     def allocate_prompt(self, token_ids, seed: int = 0,
-                        reuse_cache: bool = True):
-        alloc = super().allocate_prompt(token_ids, seed, reuse_cache)
+                        reuse_cache: bool = True, hashes=None):
+        alloc = super().allocate_prompt(token_ids, seed, reuse_cache,
+                                        hashes)
         if alloc is None:
             return None
         table, cached = alloc

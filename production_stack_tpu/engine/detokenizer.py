@@ -31,7 +31,8 @@ KEEP = 4      # uncommitted ids kept behind after an advance
 class IncrementalDetokenizer:
     """Per-sequence streaming decoder.
 
-    append(token_id) -> current full text (== decode(all ids so far)).
+    append(token_id) / extend(token_ids) -> current full text (==
+    decode(all ids so far)).
     """
 
     def __init__(self, tokenizer):
@@ -41,10 +42,20 @@ class IncrementalDetokenizer:
         self._committed = ""  # == decode(ids[:c])
 
     def append(self, token_id: int) -> str:
-        self._ids.append(token_id)
+        return self.extend((token_id,))
+
+    def extend(self, token_ids) -> str:
+        """Several ids at once -> the full text after the last of them,
+        which is what appending them one by one ends on (both equal
+        decode(all ids so far)), for one rendering instead of one an
+        id."""
+        self._ids.extend(token_ids)
         text = self._render()
-        if len(self._ids) - self._c > WINDOW:
+        while len(self._ids) - self._c > WINDOW:
+            c = self._c
             self._advance()
+            if self._c == c:
+                break  # no safe cut yet: the window grows (see _advance)
         return text
 
     def current(self) -> str:

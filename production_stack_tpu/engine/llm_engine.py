@@ -35,7 +35,11 @@ from production_stack_tpu.engine.scheduler import (
     SchedulerConfig,
     decode_precompile_variants,
 )
-from production_stack_tpu.engine.sequence import Sequence, SequenceStatus
+from production_stack_tpu.engine.sequence import (
+    PromptIds,
+    Sequence,
+    SequenceStatus,
+)
 from production_stack_tpu.engine.tokenizer import get_tokenizer
 from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
@@ -541,10 +545,12 @@ class LLMEngine:
             # (every position must COMPUTE) — a restored prefix would
             # be ignored, so fetching + deferring for it is pure waste
             return None, None
-        # ONE hashing pass per admission: the chain is computed here and
-        # reused by staging, finalize, and the PD pull (match_prefix
-        # would re-hash the whole prompt on every call)
-        hashes = bm.block_hashes_for(seq.prompt_token_ids, seq.hash_seed)
+        # ONE hashing pass per admission: the chain is computed here,
+        # reused by staging, finalize and the PD pull, and left on the
+        # sequence for admission's prefix match and the registration
+        hashes = bm.block_hashes_for(
+            seq.prompt_token_ids, seq.hash_seed, seq.block_hashes
+        )
         if not hashes:
             return None, hashes
         # cap the fetch at what could ever be adopted: the pool's usable
@@ -877,8 +883,12 @@ class LLMEngine:
         bm = self.block_manager
         if not bm.enable_prefix_caching:
             return
-        hashes = bm.block_hashes_for(seq.prompt_token_ids, seq.hash_seed)
-        matched, _ = bm.match_prefix(seq.prompt_token_ids, seq.hash_seed)
+        hashes = bm.block_hashes_for(
+            seq.prompt_token_ids, seq.hash_seed, seq.block_hashes
+        )
+        matched, _ = bm.match_prefix(
+            seq.prompt_token_ids, seq.hash_seed, seq.block_hashes
+        )
         restore: list[tuple[int, np.ndarray]] = []  # (block_id, data)
         adopted: list[int] = []
         i = len(matched)
@@ -922,7 +932,7 @@ class LLMEngine:
         bm = self.block_manager
         if hashes is None:
             hashes = bm.block_hashes_for(
-                seq.prompt_token_ids, seq.hash_seed
+                seq.prompt_token_ids, seq.hash_seed, seq.block_hashes
             )
         i = 0
         while i < len(hashes) and bm.contains_hash(hashes[i]):
@@ -1127,12 +1137,9 @@ class LLMEngine:
             prompt_token_ids = self.tokenizer.encode(prompt)
         if not prompt_token_ids:
             raise ValueError("empty prompt")
-        if not all(isinstance(t, (int, np.integer))
-                   for t in prompt_token_ids):
-            # validate BEFORE admission: a non-int reaching the runner's
-            # array build would raise inside the step-loop thread and
-            # kill the whole engine (one malformed request = DoS)
-            raise ValueError("prompt_token_ids must be integers")
+        # validated BEFORE admission (a ValueError, the server's 400);
+        # already done where the caller came through the async engine
+        prompt_token_ids = PromptIds.of(prompt_token_ids)
         sp0 = sampling_params or SamplingParams()
         if sp0.truncate_prompt_tokens is not None:
             from production_stack_tpu.engine.sampling_params import (
@@ -1294,7 +1301,7 @@ class LLMEngine:
         grow = 0
         for s in seqs:
             sp = s.sampling_params
-            remaining = sp.max_tokens - len(s.generated_token_ids) - k
+            remaining = sp.max_tokens - s.num_generated - k
             if remaining < k:
                 return False  # final rounds run synchronously
             if s.num_tokens + 2 * k >= self.scheduler.config.max_model_len:
@@ -1382,8 +1389,24 @@ class LLMEngine:
                 # every lane froze before the trip count: the device round
                 # exited early instead of paying the all-finished tail
                 self._decode_early_exit_rounds_total += 1
-            for i in range(k):
-                for j, seq in enumerate(seqs):
+            # a lane whose tokens can only end it at the last of them
+            # takes them in one call; any other lane token by token
+            by_token: list[int] = []
+            lanes = toks[:, :nb].T.tolist() if vcounts is not None else None
+            for j, seq in enumerate(seqs):
+                if (lanes is None or seq.finished
+                        or not self._applies_in_one(seq)):
+                    by_token.append(j)
+                    continue
+                tokens = lanes[j][:vcounts[j]]
+                if tokens:
+                    seq.num_computed_tokens = (
+                        seq.num_tokens + len(tokens) - 1
+                    )
+                    self._append_tokens(seq, tokens)
+            for i in range(k if by_token else 0):
+                for j in by_token:
+                    seq = seqs[j]
                     if vcounts is not None and i >= vcounts[j]:
                         continue  # device-frozen rows: pad, never sampled
                     if seq.finished:
@@ -1666,7 +1689,7 @@ class LLMEngine:
         otherwise. Returns the stepped sequences."""
         stepped: list[Sequence] = []
         with self.phases.span("pack"):
-            tokens = [s.all_token_ids[-1] for s in seqs]
+            tokens = [s.last_token_id for s in seqs]
             positions = [s.num_tokens - 1 for s in seqs]
             tables = [s.block_table for s in seqs]
             ctx_lens = [s.num_tokens for s in seqs]
@@ -1686,7 +1709,7 @@ class LLMEngine:
                 near_budget = any(
                     self._is_guided(s)
                     and (s.sampling_params.max_tokens
-                         - len(s.generated_token_ids))
+                         - s.num_generated)
                     <= k_steps + self.GUIDED_STEER_BOUND
                     for s in seqs
                 )
@@ -1874,7 +1897,7 @@ class LLMEngine:
             return True  # first token must be masked
         if sp.logit_bias:
             return True  # on-device sample knows no bias
-        return len(s.generated_token_ids) > 0 and (
+        return s.num_generated > 0 and (
             sp.presence_penalty != 0.0
             or sp.frequency_penalty != 0.0
             or sp.repetition_penalty != 1.0
@@ -1915,7 +1938,7 @@ class LLMEngine:
             near_budget = any(
                 self._is_guided(s)
                 and (s.sampling_params.max_tokens
-                     - len(s.generated_token_ids))
+                     - s.num_generated)
                 <= k_steps + self.GUIDED_STEER_BOUND
                 for s in seqs
             )
@@ -1983,7 +2006,7 @@ class LLMEngine:
             )
             bias = self._bias_arrays(seqs)
             stop = self._stop_arrays(seqs) if self._device_stop else None
-            tokens = [s.all_token_ids[-1] for s in seqs]
+            tokens = [s.last_token_id for s in seqs]
             staged_kw = {}
             st = self._staged_ragged
             self._staged_ragged = None
@@ -2291,7 +2314,7 @@ class LLMEngine:
             tuple(w.chunk_start for w in works),
             tuple(w.chunk_len for w in works),
             tuple(len(w.seq.block_table) for w in works),
-            tuple(len(w.seq.generated_token_ids) for w in works),
+            tuple(w.seq.num_generated for w in works),
             self.block_manager.free_epoch,
         )
 
@@ -2661,7 +2684,10 @@ class LLMEngine:
         context's trailing n-gram (vLLM's ngram prompt-lookup role): no
         draft model, pure host-side memory of the sequence itself —
         strongest on repetitive/structured text."""
-        context = seq.all_token_ids[-self.NGRAM_SCAN_WINDOW:]
+        end = seq.num_tokens
+        context = seq.token_ids(
+            max(0, end - self.NGRAM_SCAN_WINDOW), end
+        )
         arr = np.asarray(context, np.int32)
         cfg = self.config
         for n in range(cfg.ngram_prompt_lookup_max,
@@ -2713,8 +2739,7 @@ class LLMEngine:
             k = min(
                 k_cfg,
                 self.scheduler.config.max_model_len - n0,
-                s.sampling_params.max_tokens
-                - len(s.generated_token_ids) - 1,
+                s.sampling_params.max_tokens - s.num_generated - 1,
                 # verify feeds k+1 tokens through the prefill buckets
                 self.config.max_prefill_chunk - 1,
             )
@@ -2728,7 +2753,7 @@ class LLMEngine:
         if not any_drafts:
             return None
         chunks = [
-            [s.all_token_ids[-1]] + d
+            [s.last_token_id] + d
             for s, d in zip(seqs, drafts_by_lane)
         ]
         temps, top_ps, top_ks, min_ps, _keys, _pen = (
@@ -2742,7 +2767,7 @@ class LLMEngine:
         # stackcheck: disable=device-sync-transitive — host staging:
         # np.asarray over a python list, no device array involved
         starts = np.asarray(
-            [len(s.generated_token_ids) for s in seqs], np.int64
+            [s.num_generated for s in seqs], np.int64
         )
         self._begin_round(
             "verify", 1, len(seqs), sum(len(c) for c in chunks))
@@ -2801,7 +2826,7 @@ class LLMEngine:
                     self.timeline.finish(
                         seq.request_id, seq.finish_reason,
                         {
-                            "generated_tokens": len(seq.generated_token_ids),
+                            "generated_tokens": seq.num_generated,
                             "preemptions": seq.metrics.num_preemptions,
                         } if self._tl_enabled else None,
                     )
@@ -2839,7 +2864,7 @@ class LLMEngine:
                 needs_penalties = True
             keys[i] = (
                 np.uint32(self._seq_seed(s) & 0xFFFFFFFF),
-                np.uint32(len(s.generated_token_ids)),
+                np.uint32(s.num_generated),
             )
         return temps, top_ps, top_ks, min_ps, keys, needs_penalties
 
@@ -2868,7 +2893,7 @@ class LLMEngine:
             sp = s.sampling_params
             if not sp.ignore_eos and s.eos_token_id is not None:
                 eos[i] = int(s.eos_token_id)
-            gen = len(s.generated_token_ids)
+            gen = s.num_generated
             min_rem[i] = max(0, sp.min_tokens - gen)
             # scheduled lanes are unfinished, so both terms are >= 1
             budget[i] = max(
@@ -2987,7 +3012,7 @@ class LLMEngine:
             # ("ab+c", ("," [0-9])*) straight past max_tokens and the
             # stream ends non-conforming
             remaining = (seq.sampling_params.max_tokens
-                         - len(seq.generated_token_ids))
+                         - seq.num_generated)
             if 0 < remaining <= self.GUIDED_STEER_BOUND:
                 steered = self._steer_allowed(
                     machine, states, allowed, remaining
@@ -3357,18 +3382,9 @@ class LLMEngine:
 
     def _append_token(self, seq: Sequence, token: int,
                       logprob_entry: dict | None = None) -> None:
-        if seq.metrics.first_token_time is None:
-            seq.metrics.first_token_time = time.time()
-            if self._tl_enabled:
-                self.timeline.event(
-                    seq.request_id, "first_token",
-                    {"ttft_s": round(
-                        seq.metrics.first_token_time
-                        - seq.metrics.arrival_time, 6,
-                    ), "engine_round": self._round},
-                )
-        seq.append_token(int(token))
-        self._generation_tokens_total += 1
+        """One sampled token: what only a token alone can be given (a
+        step of the lane's guided machine, its logprob entry), then
+        `_append_tokens`."""
         machine = getattr(seq, "_guided_machine", None)
         if machine is not None and int(token) != (
             seq.eos_token_id if seq.eos_token_id is not None else -1
@@ -3400,6 +3416,37 @@ class LLMEngine:
                 pend = []
                 seq._pending_lps = pend  # type: ignore[attr-defined]
             pend.append(entries[-1])
+        self._append_tokens(seq, [int(token)])
+
+    @staticmethod
+    def _applies_in_one(seq: Sequence) -> bool:
+        """True where no token in the MIDDLE of a fused round's tokens
+        can end the lane on the host, given that the device froze the
+        lane at its own stops (eos, stop ids, min/max tokens, the
+        context limit): no stop strings to find in the text, no guided
+        machine or choices to step, no logprob entry a token. Read off
+        the request; such a lane's tokens go in together."""
+        sp = seq.sampling_params
+        return (
+            not sp.stop and sp.logprobs is None
+            and getattr(seq, "_guided_machine", None) is None
+            and getattr(seq, "_guided_choices", None) is None
+        )
+
+    def _append_tokens(self, seq: Sequence, tokens: list[int]) -> None:
+        """Append sampled tokens, of which only the LAST may end the
+        sequence (one token; or a fused round's, `_applies_in_one`): one
+        detokenizer call, one stop test."""
+        if seq.metrics.first_token_time is None:
+            seq.metrics.first_token_time = time.time()
+            if self._tl_enabled:
+                self.timeline.event(
+                    seq.request_id, "first_token",
+                    {"ttft_s": round(
+                        seq.metrics.first_token_time
+                        - seq.metrics.arrival_time, 6,
+                    ), "engine_round": self._round},
+                )
         # incremental detokenization: O(1) amortised per token instead of
         # re-decoding the whole stream (engine/detokenizer.py); output is
         # bit-identical to decode(generated_token_ids)
@@ -3410,10 +3457,13 @@ class LLMEngine:
             )
 
             detok = IncrementalDetokenizer(self.tokenizer)
-            for t in seq.generated_token_ids[:-1]:  # post-preemption replay
+            # post-preemption replay: what was generated before these
+            for t in seq.token_ids(seq.orig_prompt_len, seq.num_tokens):
                 detok.append(t)
             seq._detok = detok  # type: ignore[attr-defined]
-        new_text = detok.append(int(token))
+        seq.append_tokens(tokens)
+        self._generation_tokens_total += len(tokens)
+        new_text = detok.extend(tokens)
         seq.output_text = new_text
         # deltas ACCUMULATE until _make_output drains them: a multi-step
         # dispatch appends K tokens before one output is built, and a
@@ -3433,7 +3483,7 @@ class LLMEngine:
         )  # type: ignore[attr-defined]
         seq._emitted_chars = stable  # type: ignore[attr-defined]
         seq._pending_ids = (
-            getattr(seq, "_pending_ids", []) + [int(token)]
+            getattr(seq, "_pending_ids", []) + tokens
         )  # type: ignore[attr-defined]
         seq.check_stop(new_text)
         if (
@@ -3463,20 +3513,24 @@ class LLMEngine:
             seq.status = SequenceStatus.FINISHED_LENGTH
 
     def _register_full_blocks(self, seq: Sequence) -> None:
-        bs = self.block_manager.block_size
-        all_ids = seq.all_token_ids
-        while (len(seq.block_hashes) + 1) * bs <= seq.num_computed_tokens:
-            i = len(seq.block_hashes)
-            if i >= len(seq.block_table):
-                break
-            prev = (
-                seq.block_hashes[-1] if seq.block_hashes else seq.hash_seed
-            )
-            h = self.block_manager.register_block(
-                prev, tuple(all_ids[i * bs : (i + 1) * bs]),
-                seq.block_table[i],
-            )
-            seq.block_hashes.append(h)
+        """Content-address the blocks whose tokens are all computed
+        since the last call: past those adopted at admission, and
+        without hashing again what the prefix match hashed."""
+        bm = self.block_manager
+        bs = bm.block_size
+        n_full = min(seq.num_computed_tokens // bs, len(seq.block_table))
+        hashes = seq.block_hashes
+        for i in range(seq.num_registered_blocks, n_full):
+            if i < len(hashes):
+                bm.register_hash(hashes[i], seq.block_table[i])
+            else:
+                end = (i + 1) * bs
+                hashes.append(bm.register_block(
+                    hashes[-1] if hashes else seq.hash_seed,
+                    seq.token_ids(i * bs, end), seq.block_table[i],
+                    of_prompt=end <= seq.num_prompt_tokens,
+                ))
+            seq.num_registered_blocks = i + 1
 
     def _make_output(self, seq: Sequence) -> RequestOutput:
         new_ids = getattr(seq, "_pending_ids", [])
@@ -3506,11 +3560,27 @@ class LLMEngine:
             # vLLM shape: one entry per prompt position, None first
             # (no context scores position 0)
             plp = [None] + list(getattr(seq, "_prompt_lp_entries", []))
+        # an output carries no copy of what the sequence holds: a round
+        # makes one output a lane, and copying a lane's prompt and
+        # answer into each would cost the step thread (and, when the
+        # event loop drops them, the GIL) in proportion to the context.
+        # The prompt is the sequence's own list unless a preemption
+        # folded generated tokens into it; the cumulative ids are the
+        # sequence's own list while it runs and, like `logprobs`, a
+        # list of the output's own once it has finished.
+        n_prompt = seq.orig_prompt_len
+        folded = len(seq.prompt_token_ids) != n_prompt
         return RequestOutput(
             request_id=seq.request_id,
-            prompt_token_ids=seq.prompt_token_ids[: seq.orig_prompt_len],
-            token_ids=list(seq.generated_token_ids),
-            new_token_ids=list(new_ids),
+            prompt_token_ids=(
+                seq.prompt_token_ids[:n_prompt] if folded
+                else seq.prompt_token_ids
+            ),
+            token_ids=(
+                seq.token_ids(n_prompt, seq.num_tokens)
+                if folded or seq.finished else seq.output_token_ids
+            ),
+            new_token_ids=new_ids,
             text=seq.output_text,
             delta_text=delta,
             finished=seq.finished,
@@ -3623,6 +3693,7 @@ class LLMEngine:
             kv_usage=self.block_manager.usage,
             prefix_cache_queries=self.block_manager.prefix_queries,
             prefix_cache_hits=self.block_manager.prefix_hits,
+            prefix_blocks_hashed_total=self.block_manager.blocks_hashed,
             prompt_tokens_total=self._prompt_tokens_total,
             generation_tokens_total=self._generation_tokens_total,
             num_preemptions_total=self._preemptions_total,

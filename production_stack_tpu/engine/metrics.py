@@ -85,6 +85,16 @@ class EngineMetrics:
             "vllm:gpu_prefix_cache_queries_total",
             "Prefix-cache token queries (total)",
         )
+        self.prefix_blocks_hashed = Counter(
+            "tpu:prefix_blocks_hashed",
+            "Prompt blocks the engine's block manager hashed (one per "
+            "hash_block call on a block of a queried prompt). Over "
+            "vllm:gpu_prefix_cache_queries_total / block_size: how "
+            "often a prompt block was hashed, at most once (match, "
+            "restore and registration share one chain per sequence); "
+            "above 1 a caller hashes what another already did",
+            label, registry=reg,
+        )
         # TPU-native aliases (the Grafana dashboard panels use either)
         self.tpu_cache_usage = gauge(
             "tpu:hbm_kv_cache_usage_perc", "KV-cache usage in TPU HBM"
@@ -496,6 +506,9 @@ class EngineMetrics:
         self.prefix_hits.labels(m).set(s.prefix_cache_hits)
         self.prefix_queries.labels(m).set(s.prefix_cache_queries)
         prev = self._counter_state
+        self.prefix_blocks_hashed.labels(m).inc(max(
+            0, s.prefix_blocks_hashed_total
+            - prev.prefix_blocks_hashed_total))
         self.prompt_tokens.labels(m).inc(
             max(0, s.prompt_tokens_total - prev.prompt_tokens_total)
         )

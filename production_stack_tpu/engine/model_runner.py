@@ -318,6 +318,9 @@ class ModelRunner:
         # segments of the attention walk (tpu:decode_lane_steps,
         # tpu:decode_idle_lane_steps)
         self.decode_lane_steps = [0, 0]
+        # the decode lanes' page-table rows as the last pack left them
+        # (_page_table_rows): id(table) -> (table, row, ids copied)
+        self._kept_rows: dict[int, tuple] = {}
         # the same per attention kind of a layer-group model, tokens a
         # LAYER of that kind read (tpu:attn_context_tokens_<kind>)
         self._kind_windows = [ak.window for ak in mc.attn_kinds]
@@ -2373,6 +2376,33 @@ class ModelRunner:
             bt[:use] = np.asarray(block_table[:use], dtype=np.int32)
         return bt
 
+    def _page_table_rows(
+        self, block_tables: list[list[int]], b: int, n_pages: int
+    ) -> np.ndarray:
+        """The decode lanes' tables as (b, n_pages) rows, padded like
+        `_padded_block_table`. A lane's row is KEPT from one pack to the
+        next and extended by the ids its table gained, instead of being
+        made from a Python list of a few hundred ids every round: while
+        a sequence holds a table the block manager only appends to it (a
+        new admission, or a preemption, hands the sequence a NEW list),
+        so a kept row is good for as long as it is the same list and no
+        shorter. Rows of tables that this pack did not see are dropped."""
+        out = np.zeros((b, n_pages), dtype=np.int32)
+        kept, seen = self._kept_rows, {}
+        for i, table in enumerate(block_tables):
+            n = len(table)
+            held, row, have = kept.get(id(table), (None, (), 0))
+            if held is table and have <= n <= len(row):
+                row[have:n] = table[have:]
+            else:
+                row = np.zeros((max(64, 2 * n),), dtype=np.int32)
+                row[:n] = table
+            seen[id(table)] = (table, row, n)
+            use = min(n, n_pages)
+            out[i, :use] = row[:use]
+        self._kept_rows = seen
+        return out
+
     def _gather_slots_for_table(
         self, block_table: list[int], c_pad: int
     ) -> np.ndarray:
@@ -2997,16 +3027,8 @@ class ModelRunner:
         ctx[:b_actual] = context_lens
         put("ctx", ctx)
 
-        n_pages = c_pad // self.block_size
-        page_tables = np.stack(
-            [
-                self._padded_block_table(
-                    block_tables[i] if i < b_actual else [], n_pages
-                )
-                for i in range(b)
-            ]
-        )
-        put("page_tables", page_tables)
+        put("page_tables", self._page_table_rows(
+            block_tables, b, c_pad // self.block_size))
         if self.attention_impl != "pallas":
             gather_tables = np.zeros((b, c_pad), dtype=np.int32)
             for i in range(b_actual):

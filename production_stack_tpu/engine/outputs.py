@@ -10,8 +10,12 @@ from production_stack_tpu.engine.sequence import RequestMetrics
 @dataclass
 class RequestOutput:
     request_id: str
+    # the prompt and all output tokens so far. Read, never written: on
+    # an unfinished output both may be the lists the running sequence
+    # itself holds (token_ids then grows with it); the finished output
+    # has token_ids to itself
     prompt_token_ids: list[int]
-    token_ids: list[int]  # all output tokens so far
+    token_ids: list[int]
     new_token_ids: list[int]  # tokens produced this step
     text: str  # full output text so far
     delta_text: str  # text produced this step
@@ -38,6 +42,11 @@ class EngineStatsSnapshot:
     kv_usage: float = 0.0  # -> vllm:gpu_cache_usage_perc
     prefix_cache_queries: int = 0  # -> vllm:gpu_prefix_cache_queries_total
     prefix_cache_hits: int = 0  # -> vllm:gpu_prefix_cache_hits_total
+    # hash_block calls on the blocks of queried prompts: over
+    # prefix_cache_queries / block_size, how often a prompt block was
+    # hashed (at most once)
+    # -> tpu:prefix_blocks_hashed_total
+    prefix_blocks_hashed_total: int = 0
     prompt_tokens_total: int = 0
     generation_tokens_total: int = 0
     num_preemptions_total: int = 0

@@ -332,6 +332,7 @@ class Scheduler:
                     reuse_cache=(
                         seq.sampling_params.prompt_logprobs is None
                     ),
+                    hashes=seq.block_hashes,
                 )
                 if alloc is not None or self.kv_flush is None or \
                         not self.kv_flush():
@@ -345,6 +346,11 @@ class Scheduler:
             table, cached = alloc
             seq.block_table = table
             seq.num_computed_tokens = cached
+            # the adopted blocks are registered: theirs are the first
+            # of the hashes the match left on the sequence
+            seq.num_registered_blocks = (
+                cached // self.block_manager.block_size
+            )
             seq.metrics.num_cached_prompt_tokens = cached
             seq.status = SequenceStatus.RUNNING
             self.waiting.popleft()
@@ -616,7 +622,7 @@ class Scheduler:
         for s in seqs:
             sp = s.sampling_params
             r = min(
-                sp.max_tokens - len(s.generated_token_ids),
+                sp.max_tokens - s.num_generated,
                 mml - s.num_tokens,
             ) - advance
             rem = max(rem, r)
@@ -685,7 +691,7 @@ class Scheduler:
             # shared cached prefix blocks cost no new allocation (same
             # cap as allocate_prompt: at least one token computes)
             _, cached_tokens = self.block_manager.match_prefix(
-                cand.prompt_token_ids, cand.hash_seed
+                cand.prompt_token_ids, cand.hash_seed, cand.block_hashes
             )
             cached_tokens = min(
                 cached_tokens, cand.num_prompt_tokens - 1

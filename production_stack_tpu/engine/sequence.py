@@ -8,6 +8,7 @@ from dataclasses import dataclass, field
 
 import xxhash
 
+from production_stack_tpu.engine.block_manager import token_bytes
 from production_stack_tpu.engine.sampling_params import SamplingParams
 
 
@@ -42,6 +43,31 @@ class RequestMetrics:
     # every preemption; feeds tpu:preemption_stall_seconds
     preempt_stall_s: float = 0.0
     last_preempt_time: float | None = None
+
+
+class PromptIds(list):
+    """A prompt's token ids that have been checked: every id is an
+    integer in [0, 2**32), which is what the KV block hash folds (4
+    bytes an id). A non-integer that reached the runner's array build,
+    or an id the hash refuses, would raise inside the step-loop thread
+    and fail every request in flight (one malformed request = DoS).
+
+    Checked by the array rather than id by id, and once: `of` hands a
+    PromptIds back as it is, so the async engine checks on the event
+    loop BEFORE it takes the engine lock, and `add_request` under the
+    lock does not check again."""
+
+    @classmethod
+    def of(cls, ids) -> "PromptIds":
+        if isinstance(ids, cls):
+            return ids
+        try:
+            token_bytes(ids)
+        except (TypeError, OverflowError):
+            raise ValueError(
+                "prompt_token_ids must be integers in [0, 2**32)"
+            ) from None
+        return cls(ids)
 
 
 class Sequence:
@@ -102,9 +128,15 @@ class Sequence:
         # drives its ring chunks + KV landing outside schedule()
         self.long_prefill_active = False
 
-        # incremental prefix-cache hashing state (chain hashes of the
-        # sequence's full blocks registered so far)
+        # prefix-cache hashing state: the chain hashes of the sequence's
+        # full blocks as far as anyone has computed them (admission's
+        # prefix match hashes the prompt up to its first miss, each
+        # later block is hashed when its tokens are computed), and how
+        # many of those blocks the block manager has content-addressed
+        # (adopted on a hit, or registered once computed). Handed to
+        # every BlockManager call that hashes, so a block is hashed once
         self.block_hashes: list[int] = []
+        self.num_registered_blocks = 0
 
         # detokenization state
         self.output_text = ""
@@ -119,6 +151,11 @@ class Sequence:
     def num_tokens(self) -> int:
         return len(self.prompt_token_ids) + len(self.output_token_ids)
 
+    # The two lists below are COPIES of everything the sequence holds,
+    # for the callers that want a list (penalties, n-gram drafts, guided
+    # choices, preemption). A round's own path asks `last_token_id`,
+    # `num_generated` and `token_ids(a, b)`, whose cost does not grow
+    # with the context.
     @property
     def all_token_ids(self) -> list[int]:
         return self.prompt_token_ids + self.output_token_ids
@@ -130,6 +167,27 @@ class Sequence:
         return self.prompt_token_ids[self.orig_prompt_len :] + (
             self.output_token_ids
         )
+
+    @property
+    def last_token_id(self) -> int:
+        out = self.output_token_ids
+        return out[-1] if out else self.prompt_token_ids[-1]
+
+    @property
+    def num_generated(self) -> int:
+        """len(generated_token_ids)."""
+        return (len(self.prompt_token_ids) - self.orig_prompt_len
+                + len(self.output_token_ids))
+
+    def token_ids(self, start: int, end: int) -> list[int]:
+        """all_token_ids[start:end] for 0 <= start <= end."""
+        prompt = self.prompt_token_ids
+        n = len(prompt)
+        if end <= n:
+            return prompt[start:end]
+        if start >= n:
+            return self.output_token_ids[start - n:end - n]
+        return prompt[start:] + self.output_token_ids[:end - n]
 
     @property
     def prefill_done(self) -> bool:
@@ -156,10 +214,15 @@ class Sequence:
         (invariant during decode: num_computed_tokens == num_tokens - 1)."""
         self.output_token_ids.append(token_id)
 
+    def append_tokens(self, token_ids: list[int]) -> None:
+        """`append_token` for each, where only the last can end the
+        sequence (`check_stop` looks at the last)."""
+        self.output_token_ids.extend(token_ids)
+
     def check_stop(self, new_text: str | None = None) -> None:
         """Update status if a stop condition fired on the latest token."""
         sp = self.sampling_params
-        n_generated = len(self.generated_token_ids)
+        n_generated = self.num_generated
         if n_generated >= sp.max_tokens:
             self.status = SequenceStatus.FINISHED_LENGTH
             return
@@ -198,6 +261,7 @@ class Sequence:
         self.num_computed_tokens = 0
         self.block_table = []
         self.block_hashes = []
+        self.num_registered_blocks = 0
         self.long_prefill_active = False
         self.status = SequenceStatus.PREEMPTED
         self.metrics.num_preemptions += 1

@@ -148,3 +148,20 @@ def test_abort_flushes_withheld_tail():
     out = eng._make_output(seq)
     assert out.delta_text.endswith("�")  # flushed on the abort path
     assert out.text.endswith("�")
+
+
+@pytest.mark.parametrize("k", [1, 3, 8, 32])
+def test_extend_equals_appends_at_every_round_boundary(k):
+    """A fused round's K ids go in with one `extend`: after each round
+    the text is the full decode, as K appends would leave it, and the
+    uncommitted window stays bounded however the rounds fall against
+    multi-byte characters and invalid bytes."""
+    tok = ByteTokenizer()
+    rng = np.random.RandomState(k)
+    ids = list(tok.encode("héllo 中文 🚀 ", add_bos=False)) * 6 \
+        + rng.randint(0, 384, size=240).tolist()
+    detok = IncrementalDetokenizer(tok)
+    for i in range(0, len(ids), k):
+        assert detok.extend(ids[i:i + k]) == tok.decode(ids[:i + k])
+        assert len(detok._ids) - detok._c <= 32 + k
+    assert detok.current() == tok.decode(ids)
