@@ -3664,9 +3664,11 @@ class LLMEngine:
         mc = self.runner.model_config
         if not mc.layer_groups:
             return {}
-        names = ["window" if ak.window else "full" for ak in mc.attn_kinds]
+        names = ["window" if ak.window else
+                 "latent" if ak.latent_dim else "full"
+                 for ak in mc.attn_kinds]
         bm = self.block_manager
-        in_use = {"full": round(bm.usage * (bm.num_blocks - 1))}
+        in_use = {names[0]: round(bm.usage * (bm.num_blocks - 1))}
         out = {}
         if self.runner.num_window_blocks:
             in_use["window"] = bm.window_blocks_in_use

@@ -171,9 +171,10 @@ class EngineMetrics:
                 f"tpu:attn_context_tokens_{kind}",
                 f"Context tokens a `{kind}` attention layer of a "
                 "layer-group model read (the window kind cut to its "
-                "window): tpu:attn_context_tokens, per kind and layer",
+                "window; a latent kind's tokens are one cached row "
+                "each): tpu:attn_context_tokens, per kind and layer",
                 label, registry=reg)
-            for kind in ("full", "window")
+            for kind in ("full", "window", "latent")
         }
         self.moe_rows = {
             name: Counter(f"tpu:moe_{name}", doc, label, registry=reg)

@@ -217,7 +217,7 @@ class ModelRunner:
             self.k_cache = zeros()
             self.v_cache = zeros()
 
-        self._scale = mc.head_dim**-0.5
+        self._scale = mc.attn_scale
         # attention impl: pallas paged kernel on TPU; under TP the kernel
         # is shard_mapped over the kv-head-sharded cache (each chip's GQA
         # groups are local, so the kernel body needs no collectives)
@@ -356,28 +356,35 @@ class ModelRunner:
         """True where the paged kernels cannot take the model's head
         widths: a page slice must be whole (8, 128) tiles. A layer-group
         model stores K padded to the next 128 lanes (`_k_store_dim`), so
-        only its V width has to be aligned."""
+        only its V width has to be aligned: `v_head_dim`, or for a
+        latent kind the latent dims its rows give as the value."""
         if mc.layer_groups:
-            return bool(mc.v_dim % 128)
+            return any((ak.latent_dim or mc.v_dim) % 128
+                       for ak in mc.attn_kinds)
         return bool(mc.head_dim % 128)
 
-    def _k_store_dim(self) -> int:
-        """Lanes a K row takes in the cache. On the chip's kernel path
-        a q/k head width that is no multiple of the 128-lane tile is
-        stored with zero lanes up to the next one (192 -> 256): HBM
-        tiles the minor dim in 128s whatever the logical width, so the
-        bytes are the same as stored, and a page slice stays whole
-        tiles for the DMA (measured: PERF.md, Findings PR 28). q is
-        padded to match where the kernel is called (`_attn`)."""
+    def _k_store_dim(self, kind: int = 0) -> int:
+        """Lanes a K row of attention kind `kind` takes in the cache:
+        the q/k head width, or a latent kind's row (latent + rotary
+        dims). On the chip's kernel path a width that is no multiple of
+        the 128-lane tile is stored with zero lanes up to the next one
+        (192 -> 256, 576 -> 640): HBM tiles the minor dim in 128s
+        whatever the logical width, so the bytes are the same as
+        stored, and a page slice stays whole tiles for the DMA
+        (measured: PERF.md, Findings PR 28). q is padded to match where
+        the kernel is called (`_attn`)."""
         mc, cfg = self.model_config, self.config
+        width = mc.head_dim
+        if mc.attn_kinds and mc.attn_kinds[kind].latent_dim:
+            width = mc.attn_kinds[kind].latent_dim + mc.rope_dim
         on_kernel_path = (
             jax.default_backend() == "tpu"
             and cfg.attention_impl in ("auto", "pallas")
             and not self._unaligned_heads(mc)
         )
-        if on_kernel_path and mc.head_dim % 128:
-            return -(-mc.head_dim // 128) * 128
-        return mc.head_dim
+        if on_kernel_path and width % 128:
+            return -(-width // 128) * 128
+        return width
 
     @staticmethod
     def _refuse_for_layer_groups(config: EngineConfig, mc) -> None:
@@ -428,16 +435,20 @@ class ModelRunner:
         """One K and one V array per attention kind, (L_kind, nkv_kind,
         slots, d): kind 0's pool takes every token and is sized from
         free HBM after the windowed kind's pool, which is sized by what
-        the lanes can hold at once (`_window_blocks_needed`)."""
+        the lanes can hold at once (`_window_blocks_needed`). A latent
+        kind has ONE array a layer: its rows are keys and values, and
+        its place among the V arrays holds None."""
         mc, bs = self.model_config, self.block_size
         item = self.cache_dtype.itemsize
-        dk, dv = self._k_store_dim(), mc.v_dim
+        kinds = range(len(mc.attn_kinds))
+        dk = [self._k_store_dim(i) for i in kinds]
+        dv = [0 if ak.latent_dim else mc.v_dim for ak in mc.attn_kinds]
         mapped = layer_groups.mapped_kind(mc)
-        layers = [mc.layer_kinds.count(i) for i in range(len(mc.attn_kinds))]
+        layers = [mc.layer_kinds.count(i) for i in kinds]
 
         def block_bytes(kind):
             return (layers[kind] * mc.attn_kinds[kind].num_kv_heads
-                    * (dk + dv) * bs * item)
+                    * (dk[kind] + dv[kind]) * bs * item)
 
         self.num_window_blocks = self._window_blocks_needed()
         window_bytes = (
@@ -454,18 +465,19 @@ class ModelRunner:
         for i, ak in enumerate(mc.attn_kinds):
             n = self.num_window_blocks if i == mapped else self.num_blocks
             kg.append(jnp.zeros(
-                (layers[i], ak.num_kv_heads, n * bs, dk), self.cache_dtype))
-            vg.append(jnp.zeros(
-                (layers[i], ak.num_kv_heads, n * bs, dv), self.cache_dtype))
+                (layers[i], ak.num_kv_heads, n * bs, dk[i]),
+                self.cache_dtype))
+            vg.append(None if ak.latent_dim else jnp.zeros(
+                (layers[i], ak.num_kv_heads, n * bs, dv[i]),
+                self.cache_dtype))
         logger.info(
-            "allocating KV cache groups: %s (K stored at %d lanes, V at "
-            "%d; %.2f GiB)",
+            "allocating KV cache groups: %s (%.2f GiB)",
             ", ".join(
                 f"kind {i}: {layers[i]} layers x {ak.num_kv_heads} kv "
-                f"heads x {a.shape[2] // bs} blocks"
+                f"heads x {a.shape[2] // bs} blocks, K stored at "
+                f"{dk[i]} lanes, V at {dv[i]}"
                 for i, (ak, a) in enumerate(zip(mc.attn_kinds, kg))),
-            dk, dv,
-            sum(a.nbytes for a in kg + vg) / 2**30,
+            sum(a.nbytes for a in kg + vg if a is not None) / 2**30,
         )
         self._k_cache = {
             "g": tuple(kg),
@@ -607,7 +619,8 @@ class ModelRunner:
     def _smoke_caches(self, mc: ModelConfig):
         """(k, v, spec) of a four-block cache for each kernel variant
         serving will compile: the model's one, or one per layer kind
-        (its kv heads, window and sink; K at its stored width)."""
+        (its kv heads, window and sink; K at its stored width; no V for
+        a latent kind)."""
         bs = self.block_size
         kinds = (
             [(ak.num_kv_heads, layer_groups.AttnSpec(
@@ -616,13 +629,17 @@ class ModelRunner:
                       if ak.sink else None),
                 block_map=(jnp.zeros((4,), jnp.int32)
                            if ak.window else None),
+                latent_v=ak.latent_dim or None,
             )) for ak in mc.attn_kinds]
             if mc.layer_groups else [(mc.num_kv_heads, None)]
         )
-        for nkv, spec in kinds:
+        for i, (nkv, spec) in enumerate(kinds):
             kc = jnp.zeros(
-                (1, nkv, 4 * bs, self._k_store_dim()), self.cache_dtype)
-            vc = jnp.zeros((1, nkv, 4 * bs, mc.v_dim), self.cache_dtype)
+                (1, nkv, 4 * bs, self._k_store_dim(i)), self.cache_dtype)
+            vc = None
+            if spec is None or not spec.latent_v:
+                vc = jnp.zeros(
+                    (1, nkv, 4 * bs, mc.v_dim), self.cache_dtype)
             if self.mesh is not None:
                 # exercise the exact shard_map paths serving will take
                 cs = sharding_rules.cache_sharding(self.mesh)
@@ -630,17 +647,22 @@ class ModelRunner:
             yield kc, vc, spec
 
     def _pallas_smoke_test(self, mc: ModelConfig) -> None:
-        d = mc.head_dim
         # probe the exact kernel variants serving will compile — the
         # windowed page walk included (traced loop start + guarded
         # DMA); `_attn` routes through the shard_map TP wrappers under
-        # a mesh, exactly as the step builders do
-        qp = jnp.zeros((8, mc.num_heads, d), self.dtype)
+        # a mesh, exactly as the step builders do. q is as wide as the
+        # kind's rows (`_attn` pads a head of 192 to 256 itself)
         table1 = jnp.zeros((2,), jnp.int32)
         for kc, vc, spec in self._smoke_caches(mc):
+            qp = jnp.zeros(
+                (8, mc.num_heads, self._smoke_q_dim(kc, vc)), self.dtype)
             out = self._attn("prefill", qp, jnp.int32(0), kc, vc,
                              table1, jnp.int32(0), spec=spec)
             jax.block_until_ready(out)
+
+    def _smoke_q_dim(self, kc, vc) -> int:
+        """A smoke query's width: the head's, or a latent kind's rows'."""
+        return kc.shape[-1] if vc is None else self.model_config.head_dim
 
     def _ragged_smoke_test(self, mc: ModelConfig) -> None:
         """Compile the unified ragged kernel in the grid shape serving
@@ -649,9 +671,10 @@ class ModelRunner:
         seg_meta = jnp.asarray(
             [[0, 0, RAGGED_TQ, 0], [1, 0, 1, 0]], jnp.int32
         )
-        qr = jnp.zeros(
-            (2 * RAGGED_TQ, mc.num_heads, mc.head_dim), self.dtype)
         for kc, vc, spec in self._smoke_caches(mc):
+            qr = jnp.zeros(
+                (2 * RAGGED_TQ, mc.num_heads, self._smoke_q_dim(kc, vc)),
+                self.dtype)
             out = self._attn(
                 "ragged", qr, jnp.int32(0), kc, vc,
                 jnp.zeros((2, 2), jnp.int32), blk_seg, seg_meta,
@@ -711,7 +734,8 @@ class ModelRunner:
         def pin(c):
             if isinstance(c, dict):  # cache groups: the arrays under "g"
                 return {**c, "g": tuple(
-                    with_layout_constraint(a, fmt) for a in c["g"])}
+                    a if a is None else with_layout_constraint(a, fmt)
+                    for a in c["g"])}
             return with_layout_constraint(c, fmt)
 
         return pin(kc), pin(vc)
@@ -760,6 +784,8 @@ class ModelRunner:
                 kw["sink"] = spec.sink
             if spec.block_map is not None:
                 args = (spec.block_map[args[0]], *args[1:])
+            if spec.latent_v:
+                kw["latent_v"] = spec.latent_v
         if kc.shape[-1] > q.shape[-1]:
             # K stored wider than d_k (`_k_store_dim`): zero lanes on
             # both sides of the product
@@ -783,8 +809,13 @@ class ModelRunner:
         # head-major cache + traced `l`: [l, :, slots] has two advanced
         # indices split by a slice, so numpy hoists them to the front —
         # the result is ALREADY (..., c, nkv, d)
-        return kc[l, :, slots], vc[l, :, slots], {
-            "window": window, "sink": sink}
+        k_ctx = kc[l, :, slots]
+        if spec is not None and spec.latent_v:
+            # a latent kind's rows are keys and, in part, values
+            v_ctx = k_ctx[..., :spec.latent_v]
+        else:
+            v_ctx = vc[l, :, slots]
+        return k_ctx, v_ctx, {"window": window, "sink": sink}
 
     def _prefill_attn_closure(self):
         """The per-layer attention callback shared by the prefill and
@@ -1679,7 +1710,7 @@ class ModelRunner:
                      total_lens, spec=None):
                 # (s, c, nkv, d)
                 k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
-                qs = q.reshape(s_pad, t_pad, mc.num_heads, mc.head_dim)
+                qs = q.reshape(s_pad, t_pad, mc.num_heads, q.shape[-1])
                 out = jax.vmap(
                     functools.partial(
                         xla_attn.context_attention_prefill, **kw

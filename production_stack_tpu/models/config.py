@@ -36,6 +36,24 @@ class AttnKind:
     sink: bool = False         # a learned per-q-head logit in the
                                # softmax denominator (nothing added to
                                # the numerator)
+    # latent attention (DeepSeek-V2's MLA): the cache holds ONE row a
+    # token, `latent_dim` normed latent dims and then the rotary key
+    # dims, read by every q head as the key (all of it) and as the
+    # value (its first `latent_dim` dims): one array a layer, no V
+    # array. 0 = keys and values of their own
+    latent_dim: int = 0
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """`rope_scaling` of `type: yarn` as DeepSeek-V3's modelling code
+    reads it (ops/layers.rope_cos_sin, yarn_mscale)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -97,6 +115,23 @@ class ModelConfig:
     dense_layers: int = 0
     ep_rank: int = 0
     ep_size: int = 1
+    # beside the routed experts: `shared_experts` experts of the routed
+    # width that every row passes (one SwiGLU of that many widths), and
+    # a factor on the routed sum's weights
+    shared_experts: int = 0
+    routed_scaling: float = 1.0
+    # latent attention (a kind with `latent_dim`): q goes through a
+    # normed bottleneck of `q_lora_rank` dims; a head is `head_dim` =
+    # nope dims then `rotary_dim` rotary dims, and `v_head_dim` out
+    q_lora_rank: int = 0
+    rope_yarn: YarnScaling | None = None
+    # hyper-connections (arXiv:2512.24880): `hc_mult` residual streams a
+    # token, each sublayer reading a learned mixture of them and writing
+    # back through a Sinkhorn-projected matrix. 1 = one plain stream
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.attn_kinds:
@@ -137,6 +172,18 @@ class ModelConfig:
         return self.rotary_dim or self.head_dim
 
     @property
+    def attn_scale(self) -> float:
+        """The softmax scale: head_dim ** -0.5, times YaRN's mscale
+        squared where the config scales every dim (`mscale_all_dim`)."""
+        scale = self.head_dim ** -0.5
+        y = self.rope_yarn
+        if y is not None and y.mscale_all_dim:
+            from production_stack_tpu.ops.layers import yarn_mscale
+
+            scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+        return scale
+
+    @property
     def local_experts(self) -> int:
         """Routed experts held here: ranks hold contiguous slices."""
         return self.router_experts // self.ep_size
@@ -173,19 +220,34 @@ class ModelConfig:
             total = v * h * (1 if self.tie_word_embeddings else 2) + h
             for li, kind in enumerate(self.layer_kinds):
                 ak = self.attn_kinds[kind]
+                if ak.latent_dim:
+                    r, nope = self.q_lora_rank, self.head_dim - self.rope_dim
+                    total += (
+                        h * r + r + r * self.q_size
+                        + h * (ak.latent_dim + self.rope_dim)
+                        + ak.latent_dim
+                        + ak.latent_dim * self.num_heads
+                        * (nope + self.v_dim)
+                    )
+                else:
+                    total += (
+                        h * self.q_size
+                        + h * ak.num_kv_heads * (self.head_dim + self.v_dim)
+                    )
                 total += (
-                    h * self.q_size
-                    + h * ak.num_kv_heads * (self.head_dim + self.v_dim)
-                    + self.num_heads * self.v_dim * h
+                    self.num_heads * self.v_dim * h
                     + 2 * h
                     + (self.num_heads if ak.sink else 0)
                 )
+                if self.hc_mult > 1:
+                    n = self.hc_mult
+                    total += 2 * (n * h * (2 * n + n * n) + 2 * n + n * n + 3)
                 if self.router_experts and li >= self.dense_layers:
                     total += (
                         h * self.router_experts
                         + (self.router_experts if self.router_bias else 0)
-                        + self.local_experts * 3 * h
-                        * self.moe_intermediate_size
+                        + (self.local_experts + self.shared_experts)
+                        * 3 * h * self.moe_intermediate_size
                     )
                 else:
                     total += 3 * h * i
@@ -297,6 +359,46 @@ TINY_GROUPS_DEBUG = _register(
         dense_layers=1,
         ep_rank=0,
         ep_size=4,
+    )
+)
+
+# latent attention, hyper-connections and a shared expert at tiny
+# widths that keep every code path they add to models/layer_groups.py:
+# a 32-dim latent row + 8 rotary dims read as key and value, q through
+# a 24-dim bottleneck, YaRN past an original 64 positions (with the
+# softmax scale's mscale squared), four residual streams, one leading
+# dense layer, then 16 sigmoid-routed experts (all held here) with a
+# scaling factor of 2 beside one shared expert
+TINY_LATENT_DEBUG = _register(
+    ModelConfig(
+        name="pst-tiny-latent-debug",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=24,
+        max_model_len=256,
+        rope_theta=1e4,
+        rms_norm_eps=1e-6,
+        attn_kinds=(AttnKind(num_kv_heads=1, rope_theta=1e4,
+                             latent_dim=32),),
+        layer_kinds=(0, 0, 0, 0),
+        v_head_dim=16,
+        rotary_dim=8,
+        q_lora_rank=24,
+        rope_yarn=YarnScaling(factor=4.0, original_max_position=64,
+                              mscale=1.0, mscale_all_dim=1.0),
+        hc_mult=4,
+        router_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        router_bias=True,
+        moe_intermediate_size=32,
+        dense_layers=1,
+        shared_experts=1,
+        routed_scaling=2.0,
     )
 )
 
@@ -421,8 +523,9 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace `config.json` on local disk."""
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
-    if hf.get("model_type") == "mimo_v2":
-        return _from_mimo_v2(hf, name or os.path.basename(
+    by_type = {"mimo_v2": _from_mimo_v2, "xing4_0": _from_xing4}
+    if hf.get("model_type") in by_type:
+        return by_type[hf["model_type"]](hf, name or os.path.basename(
             os.path.normpath(path)))
     arch = (hf.get("architectures") or ["?"])[0]
     if arch not in (
@@ -500,7 +603,9 @@ def _from_mimo_v2(hf: dict, name: str) -> ModelConfig:
     sink where the config says so; qk and v head dims apart, rotary on
     the leading `partial_rotary_factor` of the qk dims, V scaled;
     leading dense layers by `moe_layer_freq`, then sigmoid-scored
-    routed experts chosen by score + bias (`noaux_tc`).
+    routed experts chosen by score + bias (`noaux_tc`); shared experts
+    and a routed scaling factor where the file has them (MiMo's own
+    leaves them at none / 1).
 
     `n_routed_experts` is the ROUTER's width. `ep_size` / `ep_rank`
     (not published keys: a deployment's) say which contiguous slice of
@@ -515,17 +620,7 @@ def _from_mimo_v2(hf: dict, name: str) -> ModelConfig:
             f"{name}: hybrid_layer_pattern and moe_layer_freq must have "
             f"num_hidden_layers={L} entries, dense layers leading"
         )
-    for key, want in (("n_shared_experts", (None, 0)),
-                      ("n_group", (None, 1)), ("topk_group", (None, 1)),
-                      ("routed_scaling_factor", (None, 1, 1.0)),
-                      ("attention_bias", (None, False)),
-                      ("hidden_act", (None, "silu"))):
-        if hf.get(key) not in want:
-            raise ValueError(
-                f"{name}: {key}={hf.get(key)!r} is not served (shared "
-                "experts, group-limited routing, a routed scaling "
-                "factor and attention biases have no code path yet)"
-            )
+    _refuse_unserved(hf, name)
     if (hf.get("swa_head_dim", hf["head_dim"]) != hf["head_dim"]
             or hf.get("swa_v_head_dim", hf["v_head_dim"])
             != hf["v_head_dim"]
@@ -576,15 +671,102 @@ def _from_mimo_v2(hf: dict, name: str) -> ModelConfig:
         v_head_dim=hf["v_head_dim"],
         rotary_dim=rotary - rotary % 2,
         v_scale=float(hf.get("attention_value_scale") or 1.0),
+        dense_layers=dense_layers,
+        **_routed_fields(hf, routed),
+    )
+
+
+def _refuse_unserved(hf: dict, name: str) -> None:
+    for key, want in (("n_group", (None, 1)), ("topk_group", (None, 1)),
+                      ("attention_bias", (None, False)),
+                      ("hidden_act", (None, "silu"))):
+        if hf.get(key) not in want:
+            raise ValueError(
+                f"{name}: {key}={hf.get(key)!r} is not served "
+                "(group-limited routing and attention biases have no "
+                "code path yet)"
+            )
+
+
+def _routed_fields(hf: dict, routed: bool) -> dict:
+    """The routed expert layer's fields from the DeepSeek-V3 style keys
+    both layer-group families publish."""
+    return dict(
         router_experts=hf["n_routed_experts"] if routed else 0,
         num_experts_per_tok=hf.get("num_experts_per_tok", 2),
         router_scoring=hf.get("scoring_func", "softmax"),
         router_bias=hf.get("topk_method") == "noaux_tc",
         router_renorm=bool(hf.get("norm_topk_prob", True)),
         moe_intermediate_size=hf.get("moe_intermediate_size", 0),
-        dense_layers=dense_layers,
         ep_rank=int(hf.get("ep_rank", 0)),
         ep_size=int(hf.get("ep_size", 1)),
+        shared_experts=int(hf.get("n_shared_experts") or 0),
+        routed_scaling=float(hf.get("routed_scaling_factor") or 1.0),
+    )
+
+
+def _from_xing4(hf: dict, name: str) -> ModelConfig:
+    """`model_type: xing4_0` (Xing4.0-29B-A4B): every layer latent
+    attention (DeepSeek-V2's MLA: `q_lora_rank`, `kv_lora_rank`,
+    `qk_nope_head_dim` + `qk_rope_head_dim` a head, `v_head_dim` out)
+    under YaRN-scaled rotary; `hc_mult` residual streams mixed by
+    Sinkhorn-projected matrices (manifold-constrained hyper-
+    connections); `first_k_dense_replace` leading dense layers, then
+    sigmoid-scored routed experts (`noaux_tc`) with a scaling factor
+    beside `n_shared_experts` shared ones. The one MTP module
+    (`num_nextn_predict_layers`) is not part of the main model's
+    logits and is not built."""
+    L = hf["num_hidden_layers"]
+    _refuse_unserved(hf, name)
+    if hf.get("moe_layer_freq", 1) != 1:
+        raise ValueError(
+            f"{name}: moe_layer_freq={hf['moe_layer_freq']!r} is not "
+            "served (every layer after the dense ones is routed)")
+    dense_layers = min(int(hf.get("first_k_dense_replace", 0)), L)
+    rs = hf.get("rope_scaling") or {}
+    kind = rs.get("type") or rs.get("rope_type") or "default"
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"{name}: rope_scaling type {kind!r} is not served")
+    yarn = None
+    if kind == "yarn":
+        yarn = YarnScaling(
+            factor=float(rs["factor"]),
+            original_max_position=int(
+                rs["original_max_position_embeddings"]),
+            beta_fast=float(rs.get("beta_fast", 32)),
+            beta_slow=float(rs.get("beta_slow", 1)),
+            mscale=float(rs.get("mscale", 1)),
+            mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+        )
+    theta = float(hf.get("rope_theta", 10000.0))
+    ak = AttnKind(num_kv_heads=1, rope_theta=theta,
+                  latent_dim=hf["kv_lora_rank"])
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=1,
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        max_model_len=hf.get("max_position_embeddings", 8192),
+        rope_theta=theta,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attn_kinds=(ak,),
+        layer_kinds=(0,) * L,
+        v_head_dim=hf["v_head_dim"],
+        rotary_dim=hf["qk_rope_head_dim"],
+        q_lora_rank=hf["q_lora_rank"],
+        rope_yarn=yarn,
+        hc_mult=int(hf.get("hc_mult", 1)),
+        hc_sinkhorn_iters=int(hf.get("hc_sinkhorn_iters", 20)),
+        hc_eps=float(hf.get("hc_eps", 1e-6)),
+        hc_res_clamp=(float(hf.get("mhc_h_res_clamp_min", -30)),
+                      float(hf.get("mhc_h_res_clamp_max", 30))),
+        dense_layers=dense_layers,
+        **_routed_fields(hf, dense_layers < L),
     )
 
 

@@ -7,8 +7,33 @@ heads, rope theta, window and (where the config says so) a learned sink
 in the softmax; q/k heads wider than v heads, rotary on the leading dims
 of a head only, V scaled before the cache; leading dense layers and then
 a routed expert layer over the experts this engine holds
-(`ops/moe.routed_experts`, told its slice by `cfg.ep_rank/ep_size`).
+(`ops/moe.routed_experts`, told its slice by `cfg.ep_rank/ep_size`),
+with a scaling factor and shared experts beside them where the config
+has them; a LATENT attention kind (`AttnKind.latent_dim`), whose cache
+holds one row a token that every head reads as key and as value; and
+HYPER-CONNECTIONS (`cfg.hc_mult` residual streams a token).
 Nothing here branches on a model's name: the fields select the code.
+
+Latent attention (DeepSeek-V2's MLA), served ABSORBED. With x a normed
+row: c_q = RMSNorm(x W_dq), a head's q = c_q W_uq = [q_nope; q_rope];
+[c_kv; k_r] = x W_dkv, c = RMSNorm(c_kv); the cache row is [c;
+rope(k_r)], one for all heads. A head's key and value would be
+[k_nope; v] = c W_ukv; instead the query is taken through the key
+up-projection, q_lat = q_nope W_uk^T, and scored against the cached row
+itself: (q_lat . c + rope(q_rope) . rope(k_r)) * scale. The output
+sum_j p_j c_j leaves `latent_dim` wide and goes through W_uv, then W_o.
+
+Hyper-connections (manifold-constrained, arXiv:2512.24880), n streams
+X (n, h) a token, each sublayer F with parameters phi (n h, 2n + n^2),
+alpha (3,), b (2n + n^2,) of its own: xt = RMSNorm(vec(X)) without a
+weight; [pre; post; res] = alpha * (xt phi) + b by parts; H_pre =
+sigmoid(pre), H_post = 2 sigmoid(post), H_res = SK(exp(clamp(res))),
+SK repeating `hc_sinkhorn_iters` times: rows over their sum + eps, then
+columns over theirs. u = H_pre X, y = F(RMSNorm_w(u)), X' = H_res X +
+H_post^T y. The streams start as n copies of the embedding and are
+summed before the final norm; the mixing runs in float32. The carry is
+streams-major, (n, rows, h), so that its last two dims tile as every
+other activation's do.
 
 Why a module beside llama.py and not llama.py grown: the layer body
 differs in every line that touches a shape (two head widths, a cache per
@@ -55,6 +80,9 @@ from production_stack_tpu.ops.layers import (
     swiglu,
 )
 from production_stack_tpu.ops.moe import routed_experts
+from production_stack_tpu.ops.sinkhorn import sinkhorn
+
+F32 = jnp.float32
 
 # kc["stats"]: pairs routed, pairs whose expert is held here, local
 # experts with at least one row — summed over routed layers and steps
@@ -67,6 +95,9 @@ class AttnSpec(NamedTuple):
     window: int | None
     sink: jax.Array | None       # (nq,) float32 logits
     block_map: jax.Array | None  # primary block id -> this group's
+    latent_v: int | None = None  # a latent kind: the cached row's
+                                 # leading lanes are the value, and
+                                 # there is no V cache (vc is None)
 
 
 def mapped_kind(cfg: ModelConfig) -> int | None:
@@ -87,13 +118,18 @@ def mapped_kind(cfg: ModelConfig) -> int | None:
 def init_params(
     cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16
 ) -> dict:
-    """Random-init parameters; sinks and the router's selection bias
-    non-zero, so that dropping either shows against the reference."""
+    """Random-init parameters; sinks, the router's selection bias and
+    the hyper-connections' alpha, b and phi non-zero, so that dropping
+    one shows against the reference."""
     h, v = cfg.hidden_size, cfg.vocab_size
     nq, dk, dv = cfg.num_heads, cfg.head_dim, cfg.v_dim
     keys = iter(jax.random.split(key, 16 * len(cfg.segments()) + 4))
+    # what PR 33 added draws from a stream of its own, so that a model
+    # without it keeps the weights its seed gave it before
+    more = iter(jax.random.split(
+        jax.random.fold_in(key, 33), 16 * len(cfg.segments())))
 
-    def w(shape, fan_in):
+    def w(shape, fan_in, keys=keys):
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 * fan_in ** -0.5).astype(dtype)
 
@@ -104,11 +140,34 @@ def init_params(
         lp = {
             "attn_norm": jnp.ones((c, h), dtype),
             "mlp_norm": jnp.ones((c, h), dtype),
-            "wq": w((c, h, nq * dk), h),
-            "wk": w((c, h, nkv * dk), h),
-            "wv": w((c, h, nkv * dv), h),
-            "wo": w((c, nq * dv, h), nq * dv),
         }
+        if ak.latent_dim:
+            r, lat = cfg.q_lora_rank, ak.latent_dim
+            lp |= {
+                "w_dq": w((c, h, r), h, more),
+                "q_norm": jnp.ones((c, r), dtype),
+                "w_uq": w((c, r, nq * dk), r, more),
+                "w_dkv": w((c, h, lat + cfg.rope_dim), h, more),
+                "kv_norm": jnp.ones((c, lat), dtype),
+                "w_ukv": w((c, lat, nq * (dk - cfg.rope_dim + dv)), lat,
+                           more),
+            }
+        else:
+            lp |= {
+                "wq": w((c, h, nq * dk), h),
+                "wk": w((c, h, nkv * dk), h),
+                "wv": w((c, h, nkv * dv), h),
+            }
+        lp["wo"] = w((c, nq * dv, h), nq * dv)
+        if cfg.hc_mult > 1:
+            n = cfg.hc_mult
+            for sub in ("attn", "mlp"):
+                lp[f"hc_{sub}_phi"] = w(
+                    (c, n * h, 2 * n + n * n), n * h, more)
+                lp[f"hc_{sub}_alpha"] = 1.0 + 0.1 * jax.random.normal(
+                    next(more), (c, 3), F32)
+                lp[f"hc_{sub}_b"] = 0.5 * jax.random.normal(
+                    next(more), (c, 2 * n + n * n), F32)
         if ak.sink:
             lp["sink"] = jax.random.normal(
                 next(keys), (c, nq), jnp.float32)
@@ -125,6 +184,11 @@ def init_params(
             lp["w_gate"] = w((c, e, h, f), h)
             lp["w_up"] = w((c, e, h, f), h)
             lp["w_down"] = w((c, e, f, h), f)
+            if cfg.shared_experts:
+                fs = f * cfg.shared_experts
+                lp["ws_gate"] = w((c, h, fs), h, more)
+                lp["ws_up"] = w((c, h, fs), h, more)
+                lp["ws_down"] = w((c, fs, h), fs, more)
         else:
             i = cfg.intermediate_size
             lp["w_gate"] = w((c, h, i), h)
@@ -146,17 +210,94 @@ def init_params(
 EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
+def hc_mix(cfg, x, phi, alpha, b):
+    """The mixing matrices of one sublayer from the streams `x` (n,
+    rows, h): (H_pre (n, rows), H_post (n, rows), H_res (n, n, rows)),
+    float32, rows on the minor axis so that the small matrices of all
+    rows fill whole vector tiles. H_res[i, j] weighs stream j in new
+    stream i and is doubly stochastic up to `hc_eps`."""
+    n, rows, h = x.shape
+    xf = x.astype(F32)
+    ms = jnp.mean(xf * xf, axis=(0, 2))                    # (rows,)
+    xt = xf * jax.lax.rsqrt(ms + cfg.rms_norm_eps)[None, :, None]
+    proj = jnp.einsum(
+        "jrh,jhk->kr", xt, phi.astype(F32).reshape(n, h, -1),
+        precision=jax.lax.Precision.HIGHEST,
+    )                                                      # (2n + n^2, rows)
+    a, b = alpha.astype(F32), b.astype(F32)[:, None]
+    pre = jax.nn.sigmoid(a[0] * proj[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * proj[n:2 * n] + b[n:2 * n])
+    res = jnp.exp(jnp.clip(a[2] * proj[2 * n:] + b[2 * n:],
+                           *cfg.hc_res_clamp)).reshape(n, n, rows)
+    res = sinkhorn(res, cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return pre, post, res
+
+
+def _sublayer(cfg, x, lp, sub, fn):
+    """x + fn(x) on one residual stream; with hyper-connections
+    (`cfg.hc_mult` > 1, x the (n, rows, h) streams) fn reads a learned
+    mixture of the streams and its result is spread back over them
+    (the module docstring has the equations). `fn` takes (rows, h) and
+    gives (its (rows, h) result in the model's dtype, whatever else it
+    has to hand on), which comes back beside the new x."""
+    if cfg.hc_mult == 1:
+        y, aux = fn(x)
+        return x + y, aux
+    with jax.named_scope("hc_mix"):
+        pre, post, res = hc_mix(
+            cfg, x, lp[f"hc_{sub}_phi"], lp[f"hc_{sub}_alpha"],
+            lp[f"hc_{sub}_b"])
+        xf = x.astype(F32)
+        u = jnp.sum(pre[:, :, None] * xf, axis=0).astype(x.dtype)
+    y, aux = fn(u)
+    with jax.named_scope("hc_mix"):
+        out = (jnp.sum(res[:, :, :, None] * xf[None], axis=1)
+               + post[:, :, None] * y.astype(F32)[None])
+        return out.astype(x.dtype), aux
+
+
+def _latent_qkv(cfg, ak, x, lp, kc, l, write_slots, cos, sin, dtype):
+    """Latent attention's cache write and absorbed query: -> (q (n, nq,
+    latent + rope) in `dtype`, kc with the rows' [c; rope(k_r)] written
+    at `write_slots`, W_uv (latent, nq, d_v))."""
+    n = x.shape[0]
+    nq, dk, dv = cfg.num_heads, cfg.head_dim, cfg.v_dim
+    lat, rot = ak.latent_dim, cfg.rope_dim
+    nope = dk - rot
+    cq = rms_norm(
+        jnp.dot(x, lp["w_dq"], preferred_element_type=F32).astype(dtype),
+        lp["q_norm"], cfg.rms_norm_eps)
+    q = jnp.dot(cq, lp["w_uq"], preferred_element_type=F32).astype(
+        dtype).reshape(n, nq, dk)
+    ckv = jnp.dot(x, lp["w_dkv"], preferred_element_type=F32).astype(dtype)
+    c = rms_norm(ckv[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
+    q_rope, k_rope = apply_rope(
+        q[..., nope:], ckv[:, None, lat:], cos, sin)
+    w_ukv = lp["w_ukv"].reshape(lat, nq, nope + dv)
+    q_lat = jnp.einsum(
+        "nhd,lhd->nhl", q[..., :nope], w_ukv[..., :nope],
+        preferred_element_type=F32).astype(dtype)
+    row = jnp.concatenate([c, k_rope[:, 0]], axis=-1).astype(kc.dtype)
+    if kc.shape[-1] > lat + rot:
+        # stored wider (zero lanes up to the kernel's 128-lane tile)
+        row = jnp.pad(row, ((0, 0), (0, kc.shape[-1] - lat - rot)))
+    kc = kc.at[l, 0, write_slots].set(row)
+    return (jnp.concatenate([q_lat, q_rope], axis=-1), kc,
+            w_ukv[..., nope:])
+
+
 def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
            write_slots, real, attn_fn, block_map, dtype, experts=None,
            stack_index=None):
     """One layer of `kind` over n rows: llama.decoder_layer's shape
     (K/V written at `write_slots` BEFORE attn_fn runs) with this
-    family's widths. `kc`/`vc` are the kind's own cache arrays and `l`
-    indexes them. `real` (n,) bool: the rows that are tokens; the rest
-    (padding, idle lanes, lanes a device stop froze) write the null
-    block's slot 0."""
+    family's widths. `kc`/`vc` are the kind's own cache arrays (`vc`
+    None for a latent kind) and `l` indexes them. `real` (n,) bool: the
+    rows that are tokens; the rest (padding, idle lanes, lanes a device
+    stop froze) write the null block's slot 0. `h` is (n, hidden), or
+    the (hc_mult, n, hidden) streams under hyper-connections."""
     ak = cfg.attn_kinds[kind]
-    n = h.shape[0]
+    n = h.shape[-2]
     nq, nkv = cfg.num_heads, ak.num_kv_heads
     dk, dv = cfg.head_dim, cfg.v_dim
 
@@ -164,50 +305,65 @@ def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
         out = jnp.dot(x, lp[name], preferred_element_type=jnp.float32)
         return out + lp[bias].astype(jnp.float32) if cfg.qkv_bias else out
 
-    x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps,
-                 cfg.norm_weight_offset)
-    q = proj(x, "wq", "bq").astype(dtype).reshape(n, nq, dk)
-    k = proj(x, "wk", "bk").astype(dtype).reshape(n, nkv, dk)
-    v = proj(x, "wv", "bv")
-    if cfg.v_scale != 1.0:
-        v = v * cfg.v_scale
-    v = v.astype(dtype).reshape(n, nkv, dv)
-    q, k = apply_rope(q, k, cos, sin)
+    def attention(u, kc=kc, vc=vc):
+        x = rms_norm(u, lp["attn_norm"], cfg.rms_norm_eps,
+                     cfg.norm_weight_offset)
+        w_uv = None
+        if ak.latent_dim:
+            q, kc, w_uv = _latent_qkv(
+                cfg, ak, x, lp, kc, l, write_slots, cos, sin, dtype)
+        else:
+            q = proj(x, "wq", "bq").astype(dtype).reshape(n, nq, dk)
+            k = proj(x, "wk", "bk").astype(dtype).reshape(n, nkv, dk)
+            v = proj(x, "wv", "bv")
+            if cfg.v_scale != 1.0:
+                v = v * cfg.v_scale
+            v = v.astype(dtype).reshape(n, nkv, dv)
+            q, k = apply_rope(q, k, cos, sin)
 
-    # per-head plane scatters (see llama.decoder_layer for why). The
-    # cache may store K wider than d_k (zero lanes up to the kernel's
-    # 128-lane tile, model_runner): the pad is written with the row
-    kh = k.astype(kc.dtype).swapaxes(0, 1)  # (nkv, n, d_k)
-    if kc.shape[-1] > dk:
-        kh = jnp.pad(kh, ((0, 0), (0, 0), (0, kc.shape[-1] - dk)))
-    vh = v.astype(vc.dtype).swapaxes(0, 1)
-    for head in range(nkv):
-        kc = kc.at[l, head, write_slots].set(kh[head])
-        vc = vc.at[l, head, write_slots].set(vh[head])
+            # per-head plane scatters (see llama.decoder_layer for why).
+            # The cache may store K wider than d_k (zero lanes up to the
+            # kernel's 128-lane tile, model_runner): the pad is written
+            # with the row
+            kh = k.astype(kc.dtype).swapaxes(0, 1)  # (nkv, n, d_k)
+            if kc.shape[-1] > dk:
+                kh = jnp.pad(kh, ((0, 0), (0, 0), (0, kc.shape[-1] - dk)))
+            vh = v.astype(vc.dtype).swapaxes(0, 1)
+            for head in range(nkv):
+                kc = kc.at[l, head, write_slots].set(kh[head])
+                vc = vc.at[l, head, write_slots].set(vh[head])
 
-    spec = AttnSpec(
-        window=ak.window,
-        sink=lp["sink"] if ak.sink else None,
-        block_map=block_map if ak.window else None,
-    )
-    attn_out = attn_fn(q, l, kc, vc, spec)  # (n, nq, d_v)
-    # the paged kernels store a segment's rows into their output tile
-    # and leave the tile's other rows as the tile held them: now and
-    # then not a number (on the chip: tokens 0 and NaN log-probabilities
-    # after rounds with padded rows, PR 28). In a stack of alike layers
-    # such a row stays its own. Here it would reach real rows: through
-    # the routed layer's row matrices (0 x NaN), and through the null
-    # block it writes, which a windowed lane reads, masked, for the
-    # pages it let go
-    attn_out = jnp.where(real[:, None, None], attn_out, 0)
-    h = h + jnp.dot(
-        attn_out.reshape(n, nq * dv).astype(dtype), lp["wo"],
-        preferred_element_type=jnp.float32,
-    ).astype(dtype)
+        spec = AttnSpec(
+            window=ak.window,
+            sink=lp["sink"] if ak.sink else None,
+            block_map=block_map if ak.window else None,
+            latent_v=ak.latent_dim or None,
+        )
+        attn_out = attn_fn(q, l, kc, vc, spec)  # (n, nq, d_v | latent)
+        # the paged kernels store a segment's rows into their output
+        # tile and leave the tile's other rows as the tile held them:
+        # now and then not a number (on the chip: tokens 0 and NaN
+        # log-probabilities after rounds with padded rows, PR 28). In a
+        # stack of alike layers such a row stays its own. Here it would
+        # reach real rows: through the routed layer's row matrices (0 x
+        # NaN), and through the null block it writes, which a windowed
+        # lane reads, masked, for the pages it let go
+        attn_out = jnp.where(real[:, None, None], attn_out, 0)
+        if w_uv is not None:
+            attn_out = jnp.einsum(
+                "nhl,lhd->nhd", attn_out.astype(dtype), w_uv,
+                preferred_element_type=F32)
+        return jnp.dot(
+            attn_out.reshape(n, nq * dv).astype(dtype), lp["wo"],
+            preferred_element_type=jnp.float32,
+        ).astype(dtype), (kc, vc)
 
-    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps,
-                 cfg.norm_weight_offset)
-    if routed:
+    def mlp(u):
+        x = rms_norm(u, lp["mlp_norm"], cfg.rms_norm_eps,
+                     cfg.norm_weight_offset)
+        if not routed:
+            return swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                          act=cfg.hidden_act), 0
         # rows that are no tokens keep no pair
         y, st = routed_experts(
             x, lp["router"], lp.get("router_bias"),
@@ -216,13 +372,19 @@ def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
             top_k=cfg.num_experts_per_tok,
             first_expert=cfg.ep_rank * cfg.local_experts,
             scoring=cfg.router_scoring, renorm=cfg.router_renorm,
-            valid=real,
+            scale=cfg.routed_scaling, valid=real,
         )
-        h = h + y.astype(dtype)
+        if cfg.shared_experts:
+            with jax.named_scope("shared_expert"):
+                y = y + swiglu(
+                    x, lp["ws_gate"], lp["ws_up"], lp["ws_down"],
+                    act=cfg.hidden_act).astype(F32)
+        return y.astype(dtype), st
+
+    h, (kc, vc) = _sublayer(cfg, h, lp, "attn", attention)
+    h, st = _sublayer(cfg, h, lp, "mlp", mlp)
+    if routed:
         stats = stats + st
-    else:
-        h = h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
-                       act=cfg.hidden_act)
     return h, kc, vc, stats
 
 
@@ -232,7 +394,8 @@ def forward(
     token_ids: jax.Array,   # (n,) int32
     positions: jax.Array,   # (n,) int32
     k_cache: dict,          # {"g": per-kind arrays, "map", "stats"}
-    v_cache: dict,          # {"g": per-kind arrays}
+    v_cache: dict,          # {"g": per-kind arrays; None for a latent
+                            # kind, whose rows are keys and values}
     write_slots: jax.Array,  # (n,) int32 slots in KIND 0's pool
     attn_fn,                # attn_fn(q, l, kc, vc, spec) -> (n, nq, d_v)
     logits_rows: jax.Array,
@@ -259,12 +422,16 @@ def forward(
             block_map[write_slots // block_size] * block_size
             + write_slots % block_size
         )
-    rope = [rope_cos_sin(positions, cfg.rope_dim, ak.rope_theta)
+    rope = [rope_cos_sin(positions, cfg.rope_dim, ak.rope_theta,
+                         cfg.rope_yarn)
             for ak in cfg.attn_kinds]
 
     h = params["embed"][token_ids].astype(dtype)
     if cfg.embed_scale != 1.0:
         h = (h.astype(jnp.float32) * cfg.embed_scale).astype(dtype)
+    if cfg.hc_mult > 1:
+        # the streams start as copies of the embedding
+        h = jnp.broadcast_to(h, (cfg.hc_mult, *h.shape))
 
     with jax.named_scope("layers"):
         for lp_stack, (kind, routed, count, l0) in zip(
@@ -299,9 +466,13 @@ def forward(
 
     k_cache = {"g": tuple(kg), "map": block_map, "stats": stats}
     v_cache = {"g": tuple(vg)}
+    if cfg.hc_mult > 1:
+        # and are summed before the final norm: the head's rows only
+        h = jnp.sum(
+            h[:, logits_rows].astype(F32), axis=0).astype(dtype)
     h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
                  cfg.norm_weight_offset)
-    h_sel = h[logits_rows]
+    h_sel = h if cfg.hc_mult > 1 else h[logits_rows]
     if return_hidden:
         return h_sel.astype(jnp.float32), k_cache, v_cache
     lm_head = (

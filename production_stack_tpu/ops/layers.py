@@ -7,6 +7,8 @@ schedule well (paged attention over a block table, see ops/paged_attention.py).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -23,22 +25,62 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float,
     return (normed * (weight.astype(jnp.float32) + offset)).astype(x.dtype)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature (DeepSeek-V3's `yarn_get_mscale`):
+    0.1 * mscale * ln(factor) + 1 past factor 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> jax.Array:
+    """YaRN's frequencies (`models.config.YarnScaling`), as DeepSeek-V3's
+    modelling code blends them: dims that turn more than `beta_fast`
+    times within the original context keep their frequency, those that
+    turn fewer than `beta_slow` times take it divided by `factor`, and a
+    linear ramp over the dim index joins the two."""
+    half = head_dim // 2
+    inv_freq = 1.0 / (
+        theta ** (jnp.arange(0, half, dtype=jnp.float32) * (2.0 / head_dim))
+    )
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(
+            yarn.original_max_position / (rotations * 2 * math.pi))
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low)
+        / max(high - low, 0.001), 0.0, 1.0)
+    return inv_freq / yarn.factor * ramp + inv_freq * (1.0 - ramp)
+
+
 def rope_cos_sin(
-    positions: jax.Array, head_dim: int, theta: float
+    positions: jax.Array, head_dim: int, theta: float, yarn=None
 ) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables for the given positions. Returns (N, head_dim) each.
 
     HF-Llama convention: frequencies over the first half of the head dim,
-    duplicated across halves (rotate-half formulation).
+    duplicated across halves (rotate-half formulation). `yarn`: the
+    frequencies blended as YaRN does, and cos/sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim).
     """
     half = head_dim // 2
-    inv_freq = 1.0 / (
-        theta
-        ** (jnp.arange(0, half, dtype=jnp.float32) * (2.0 / head_dim))
-    )
+    if yarn is not None:
+        inv_freq = yarn_inv_freq(head_dim, theta, yarn)
+    else:
+        inv_freq = 1.0 / (
+            theta
+            ** (jnp.arange(0, half, dtype=jnp.float32) * (2.0 / head_dim))
+        )
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(freqs), jnp.cos(freqs)], axis=-1)
     sin = jnp.concatenate([jnp.sin(freqs), jnp.sin(freqs)], axis=-1)
+    if yarn is not None:
+        ratio = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim)
+        if ratio != 1.0:
+            cos, sin = cos * ratio, sin * ratio
     return cos, sin
 
 

@@ -124,6 +124,7 @@ def moe_capacity(
 def route(
     x: jax.Array, router_w: jax.Array, router_b: jax.Array | None,
     top_k: int, scoring: str = "softmax", renorm: bool = True,
+    scale: float = 1.0,
 ) -> tuple[jax.Array, jax.Array]:
     """Scores over ALL experts of the router -> (idx [n,k] int32, w
     [n,k] f32): the chosen experts and their combine weights.
@@ -133,7 +134,8 @@ def route(
     the k-th and the next expert from flipping against a float32
     reference. Selection is by score + `router_b` (a learned
     load-balancing bias that never enters the weights); weights are the
-    chosen scores, divided by their sum when `renorm`."""
+    chosen scores, divided by their sum when `renorm`, times `scale` (a
+    model's routed scaling factor)."""
     logits = jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
@@ -148,6 +150,8 @@ def route(
     w = jnp.take_along_axis(scores, idx, axis=1)
     if renorm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        w = w * scale
     return idx.astype(jnp.int32), w
 
 
@@ -163,6 +167,7 @@ def routed_experts(
     first_expert: int,       # global id of local expert 0
     scoring: str = "softmax",
     renorm: bool = True,
+    scale: float = 1.0,      # on the combine weights (`route`)
     valid: jax.Array | None = None,  # [n] bool: real rows
     stack_index: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -196,7 +201,7 @@ def routed_experts(
         # whatever such a row holds (not a number, even) must not reach
         # the others through the row matrices below: 0 x NaN is NaN
         x = jnp.where(valid[:, None], x, 0)
-    idx, w = route(x, router_w, router_b, top_k, scoring, renorm)
+    idx, w = route(x, router_w, router_b, top_k, scoring, renorm, scale)
     local = idx - first_expert
     keep = (local >= 0) & (local < e_loc)
     if valid is not None:

@@ -57,6 +57,11 @@ replaces the gather-based XLA path in ops/attention.py on TPU):
   one head width and no sink lowers to what it did without them.
 - A sliding window (`window`) is masked per key and the walk starts at
   the window's first KV block, so the pages behind it never stream in.
+- A LATENT kind (`latent_v`, DeepSeek-V2's MLA served absorbed): the
+  cache holds one row a token and there is no V cache. A row is the
+  key, all of it, and its first `latent_v` lanes are the value, for
+  every q head alike (nkv = 1), so the walk copies each page once and
+  feeds the same VMEM block to both products.
 
 Numerics match ops/attention.py (f32 softmax, same masking); parity is
 enforced by tests/test_pallas_attention.py in interpret mode on CPU.
@@ -118,9 +123,10 @@ def _kv_block_pages(nkv: int, d: int, itemsize: int, block_size: int,
     all). Measured on a v5e
     (PERF.md, Findings PR 25): 8 kv heads are fastest at 128 keys, 4 at
     256, 2 (a tensor-parallel shard) at 512. `d` is K's head width and
-    `d_v` V's where it differs."""
+    `d_v` V's where it differs (0: a latent kind, which has no V
+    buffer)."""
     budget = 2 * 2**20
-    per_key = _KV_RING * nkv * (d + (d_v or d)) * itemsize
+    per_key = _KV_RING * nkv * (d + (d if d_v is None else d_v)) * itemsize
     keys = min(512, max(128, budget // per_key // 128 * 128))
     return max(1, keys // block_size)
 
@@ -160,8 +166,9 @@ def _walk(
     kv,                 # the program's cache refs and scratch:
                         # k_cache, v_cache (L, nkv, slots, d) HBM;
                         # k_buf, v_buf (_KV_RING, nkv, N*bs, d) VMEM;
-                        # DMA sems (_KV_RING, 2)
-    static,             # block_size, num_pages, scale, window
+                        # DMA sems (_KV_RING, 2). A latent kind has
+                        # neither v_cache nor v_buf (None)
+    static,             # block_size, num_pages, scale, window, latent_v
     sink=None,          # (nkv, rows, 1) float32 logits, or None
 ):
     """THE page walk: causal (and windowed) attention of `rows` fused
@@ -170,15 +177,18 @@ def _walk(
     Returns the normalised (nkv, rows, d_v) float32 output. hi <= lo
     walks nothing and starts no copy (and gives zeros)."""
     k_cache_ref, v_cache_ref, k_buf, v_buf, sem = kv
+    latent_v = static["latent_v"]
     ring, nkv, c, _ = k_buf.shape
-    d = v_buf.shape[-1]
+    d = latent_v or v_buf.shape[-1]
     rows = q.shape[1]
     bs, scale, window = static["block_size"], static["scale"], static["window"]
     n = c // bs
     b_lo = jax.lax.div(lo, c)
     b_hi = jax.lax.div(hi + c - 1, c)
     last_page = jax.lax.div(hi - 1, bs)
-    halves = ((k_cache_ref, k_buf), (v_cache_ref, v_buf))
+    halves = ((k_cache_ref, k_buf),)
+    if not latent_v:
+        halves += ((v_cache_ref, v_buf),)
 
     def start(b):
         # one strided DMA per page and cache: all heads' rows of the
@@ -237,7 +247,8 @@ def _walk(
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        return m_new, l_new, acc * corr + _pv(p, v_buf[slot])
+        return m_new, l_new, acc * corr + _pv(
+            p, k_buf[slot, :, :, :latent_v] if latent_v else v_buf[slot])
 
     if sink is None:
         m0 = jnp.full((nkv, rows, 1), MASK_VALUE, jnp.float32)
@@ -277,7 +288,7 @@ def _attend(
     tq, nq, d = q_ref.shape
     k_buf = kv[2]
     nkv = k_buf.shape[1]
-    d_v = kv[3].shape[-1]
+    d_v = static["latent_v"] or kv[3].shape[-1]
     g = nq // nkv
     # q and K meet in the MXU in their common dtype: as stored when the
     # cache has the model's dtype (the reshapes want 32-bit rows)
@@ -333,6 +344,19 @@ def _attend(
     out_ref[...] = jnp.where(keep, out.astype(out_ref.dtype), out_ref[...])
 
 
+def _refs(rest, static):
+    """(sink_ref or None, out_ref, kv) from a kernel's refs after q:
+    k_cache, v_cache, [sink,] out, k_buf, v_buf, sems — without v_cache
+    and v_buf for a latent kind, whose kv carries None in their place."""
+    if static["latent_v"]:
+        k_cache_ref, *sink_ref, out_ref, k_buf, sem = rest
+        v_cache_ref = v_buf = None
+    else:
+        k_cache_ref, v_cache_ref, *sink_ref, out_ref, k_buf, v_buf, sem = rest
+    return (sink_ref[0] if sink_ref else None, out_ref,
+            (k_cache_ref, v_cache_ref, k_buf, v_buf, sem))
+
+
 def _decode_kernel(
     # scalar prefetch
     layer_ref,          # (1,) int32
@@ -340,25 +364,26 @@ def _decode_kernel(
     context_lens_ref,   # (b,) int32
     # array inputs
     q_ref,              # (1, nq, d_k) VMEM — this program's query
-    k_cache_ref,        # (L, nkv, slots, d_k) ANY/HBM — head-major
-    v_cache_ref,        # (L, nkv, slots, d_v)
-    *rest,              # [sink_ref (nkv, g, 1) VMEM,] then
+    *rest,              # k_cache_ref (L, nkv, slots, d_k) ANY/HBM —
+                        # head-major, v_cache_ref (L, nkv, slots, d_v),
+                        # [sink_ref (nkv, g, 1) VMEM,] then
                         # out_ref (1, nq, d_v) VMEM, and the scratch:
                         # k_buf (_KV_RING, nkv, N*bs, d_k), v_buf VMEM,
-                        # DMA sems (_KV_RING, 2)
-    **static,           # block_size, num_pages, scale, window
+                        # DMA sems (_KV_RING, 2); no v_cache_ref and no
+                        # v_buf for a latent kind (`_refs`)
+    **static,           # block_size, num_pages, scale, window, latent_v
 ):
     """One grid program per sequence: its one query row at position
     ctx_len - 1 over its own pages (the sliding window, HF semantics:
     keys j > q_pos - window, starts the walk at the window's first KV
     block)."""
     i = pl.program_id(0)
-    *sink_ref, out_ref, k_buf, v_buf, sem = rest
+    sink_ref, out_ref, kv = _refs(rest, static)
     _attend(
-        q_ref, *(sink_ref or [None]), out_ref, 0, 1,
+        q_ref, sink_ref, out_ref, 0, 1,
         context_lens_ref[i] - 1,
         lambda j: block_tables_ref[i, j], layer_ref[0],
-        (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), static,
+        kv, static,
         one_row=True,
     )
 
@@ -369,9 +394,8 @@ def _prefill_kernel(
     block_table_ref,    # (P,) int32 — this sequence's pages
     # array inputs
     q_ref,              # (Tq, nq, d_k) VMEM — this program's query tile
-    k_cache_ref,
-    v_cache_ref,
-    *rest,              # [sink_ref,] out_ref (Tq, nq, d_v), scratch
+    *rest,              # the caches, [sink_ref,] out_ref (Tq, nq, d_v),
+                        # scratch (`_refs`)
     **static,
 ):
     """Ragged chunked-prefill attention for ONE sequence over the paged
@@ -387,12 +411,12 @@ def _prefill_kernel(
     never built, and later tiles see (and stream) more pages.
     """
     tq = q_ref.shape[0]
-    *sink_ref, out_ref, k_buf, v_buf, sem = rest
+    sink_ref, out_ref, kv = _refs(rest, static)
     _attend(
-        q_ref, *(sink_ref or [None]), out_ref, 0, tq,
+        q_ref, sink_ref, out_ref, 0, tq,
         meta_ref[1] + pl.program_id(0) * tq,
         lambda j: block_table_ref[j], meta_ref[0],
-        (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), static,
+        kv, static,
         one_row=False,
     )
 
@@ -407,9 +431,8 @@ def _ragged_kernel(
     block_tables_ref,   # (S, P) int32 — per-LANE page tables
     # array inputs
     q_ref,              # (TQ, nq, d_k) VMEM — this block's query rows
-    k_cache_ref,
-    v_cache_ref,
-    *rest,              # [sink_ref,] out_ref (TQ, nq, d_v), scratch
+    *rest,              # the caches, [sink_ref,] out_ref (TQ, nq, d_v),
+                        # scratch (`_refs`)
     **static,
 ):
     """Unified ragged paged attention: ONE grid over the flattened
@@ -437,14 +460,13 @@ def _ragged_kernel(
     reads masked: 0 x NaN is NaN).
     """
     i = pl.program_id(0)
-    *sink_ref, out_ref, k_buf, v_buf, sem = rest
-    kv = (k_cache_ref, v_cache_ref, k_buf, v_buf, sem)
+    sink_ref, out_ref, kv = _refs(rest, static)
 
     def seg_body(s, _):
         lane = seg_meta_ref[s, 0]
         n_rows = seg_meta_ref[s, 2]
         attend = functools.partial(
-            _attend, q_ref, *(sink_ref or [None]), out_ref,
+            _attend, q_ref, sink_ref, out_ref,
             seg_meta_ref[s, 1], n_rows,
             seg_meta_ref[s, 3], lambda j: block_tables_ref[lane, j],
             meta_ref[0], kv, static,
@@ -465,17 +487,21 @@ def _ragged_kernel(
 def _paged_call(
     kernel, name, tq, scalars, q, k_cache, v_cache, *,
     num_pages, block_size, scale, window, interpret, sink=None,
+    latent_v=None,
 ):
     """The pallas_call the three kernels share: a grid over `tq`-row
     tiles of q, the caches left in HBM, the scalars prefetched to SMEM,
     a ring of KV-block buffers as scratch. `sink` ((nq,) logits) rides
-    as one more VMEM input where the layer has one."""
+    as one more VMEM input where the layer has one. A latent kind
+    (`latent_v`, `v_cache` None) has one cache and one ring."""
     r, nq, d = q.shape
     nkv = k_cache.shape[1]
-    d_v = v_cache.shape[-1]
+    assert (v_cache is None) == bool(latent_v), (latent_v, v_cache)
+    d_v = latent_v or v_cache.shape[-1]
     assert k_cache.shape[-1] == d, (k_cache.shape, q.shape)
     keys = block_size * _kv_block_pages(
-        nkv, d, k_cache.dtype.itemsize, block_size, d_v
+        nkv, d, k_cache.dtype.itemsize, block_size,
+        0 if latent_v else d_v,
     )
 
     def tile(width):
@@ -485,7 +511,13 @@ def _paged_call(
         )
 
     cache = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs, inputs = [tile(d), cache, cache], [q, k_cache, v_cache]
+    in_specs, inputs = [tile(d), cache], [q, k_cache]
+    scratch = [pltpu.VMEM((_KV_RING, nkv, keys, d), k_cache.dtype)]
+    if not latent_v:
+        in_specs.append(cache)
+        inputs.append(v_cache)
+        scratch.append(
+            pltpu.VMEM((_KV_RING, nkv, keys, d_v), v_cache.dtype))
     if sink is not None:
         in_specs.append(pl.BlockSpec(
             (nkv, nq // nkv, 1), lambda i, *_: (0, 0, 0),
@@ -496,7 +528,7 @@ def _paged_call(
     return pl.pallas_call(
         functools.partial(
             kernel, block_size=block_size, num_pages=num_pages,
-            scale=scale, window=window,
+            scale=scale, window=window, latent_v=latent_v,
         ),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -505,10 +537,7 @@ def _paged_call(
             in_specs=in_specs,
             out_specs=tile(d_v),
             scratch_shapes=[
-                pltpu.VMEM((_KV_RING, nkv, keys, d), k_cache.dtype),
-                pltpu.VMEM((_KV_RING, nkv, keys, d_v), v_cache.dtype),
-                pltpu.SemaphoreType.DMA((_KV_RING, 2)),
-            ],
+                *scratch, pltpu.SemaphoreType.DMA((_KV_RING, 2))],
         ),
         out_shape=jax.ShapeDtypeStruct((r, nq, d_v), q.dtype),
         interpret=interpret,
@@ -523,12 +552,13 @@ def _paged_call(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "scale", "interpret", "window"),
+    static_argnames=("block_size", "scale", "interpret", "window",
+                     "latent_v"),
 )
 def ragged_paged_attention(
     q: jax.Array,             # (R, nq, d) — flattened mixed query rows
     k_cache: jax.Array,       # (L, nkv, num_slots, d) — head-major
-    v_cache: jax.Array,
+    v_cache: jax.Array | None,  # None for a latent kind (`latent_v`)
     layer: jax.Array,         # scalar int32
     block_tables: jax.Array,  # (S, P) int32 — page table per LANE
     blk_seg: jax.Array,       # (G+1,) int32 — CSR segment offsets,
@@ -541,6 +571,7 @@ def ragged_paged_attention(
     scale: float,
     interpret: bool = False,
     window: int | None = None,
+    latent_v: int | None = None,
 ) -> jax.Array:
     """One launch of ragged paged attention over any lane mix.
 
@@ -562,7 +593,7 @@ def ragged_paged_attention(
         (jnp.reshape(layer, 1), blk_seg, seg_meta, block_tables),
         q, k_cache, v_cache, num_pages=block_tables.shape[1],
         block_size=block_size, scale=scale, window=window,
-        interpret=interpret, sink=sink,
+        interpret=interpret, sink=sink, latent_v=latent_v,
     )
 
 
@@ -581,7 +612,8 @@ def _prefill_q_tile(t: int, nq: int, d: int) -> int:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "scale", "interpret", "window"),
+    static_argnames=("block_size", "scale", "interpret", "window",
+                     "latent_v"),
 )
 def paged_prefill_attention(
     q: jax.Array,            # (t, nq, d) — one chunk, contiguous positions
@@ -596,6 +628,7 @@ def paged_prefill_attention(
     scale: float,
     interpret: bool = False,
     window: int | None = None,
+    latent_v: int | None = None,
 ) -> jax.Array:
     """Chunked-prefill paged attention for one sequence. -> (t, nq, d)."""
     t, nq, d = q.shape
@@ -606,13 +639,14 @@ def paged_prefill_attention(
                     jnp.asarray(q_start, jnp.int32)]), block_table),
         q, k_cache, v_cache, num_pages=block_table.shape[0],
         block_size=block_size, scale=scale, window=window,
-        interpret=interpret, sink=sink,
+        interpret=interpret, sink=sink, latent_v=latent_v,
     )
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "scale", "interpret", "window"),
+    static_argnames=("block_size", "scale", "interpret", "window",
+                     "latent_v"),
 )
 def paged_decode_attention(
     q: jax.Array,             # (b, nq, d)
@@ -627,6 +661,7 @@ def paged_decode_attention(
     scale: float,
     interpret: bool = False,
     window: int | None = None,
+    latent_v: int | None = None,
 ) -> jax.Array:
     """One decode step of paged attention, a grid program a sequence.
     Returns (b, nq, d) in q.dtype. The runner does not call it: its
@@ -639,7 +674,7 @@ def paged_decode_attention(
         (jnp.reshape(layer, 1), block_tables, context_lens),
         q, k_cache, v_cache, num_pages=block_tables.shape[1],
         block_size=block_size, scale=scale, window=window,
-        interpret=interpret, sink=sink,
+        interpret=interpret, sink=sink, latent_v=latent_v,
     )
 
 

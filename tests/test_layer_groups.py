@@ -594,7 +594,13 @@ def test_a_mimo_v2_config_json_becomes_layer_groups(tmp_path):
     params = layer_groups.init_params(mc, jax.random.key(0), jnp.float32)
     held = sum(a.size for a in jax.tree.leaves(params))
     assert mc.num_params() == held
+    # a shared expert is a field since PR 33 (this file leaves it at
+    # none); what has no code path is still refused by name
     hf["n_shared_experts"] = 1
     (tmp_path / "config.json").write_text(json.dumps(hf))
-    with pytest.raises(ValueError, match="n_shared_experts"):
+    assert from_hf_config(str(tmp_path), name=MC.name) == (
+        dataclasses.replace(MC, shared_experts=1))
+    hf["n_group"] = 4
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    with pytest.raises(ValueError, match="n_group"):
         from_hf_config(str(tmp_path))

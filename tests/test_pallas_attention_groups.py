@@ -172,3 +172,91 @@ def test_dense_shapes_size_their_kv_block_as_before():
     # K at 256 lanes beside V at 128, bf16: 128 keys for 8 and 4 kv heads
     assert pa._kv_block_pages(8, 256, 2, 32, 128) * 32 == 128
     assert pa._kv_block_pages(4, 256, 2, 32, 128) * 32 == 128
+
+
+# -- the latent kind: one cached row a token, key and value alike ------------
+LAT, ROT, STORED = 512, 64, 640
+
+
+def latent_case(seed, *, lanes=4, pages=12, nq=8, dtype=jnp.float32):
+    """A latent cache as the runner stores it on the chip: rows of 512
+    latent + 64 rotary dims at 640 lanes (zeros behind), no V array."""
+    rng = np.random.RandomState(seed)
+    blocks = 1 + lanes * pages
+    kc = np.zeros((2, 1, blocks * BS, STORED), np.float32)
+    kc[..., :LAT + ROT] = rng.randn(2, 1, blocks * BS, LAT + ROT)
+    tables = rng.permutation(np.arange(1, blocks)).reshape(lanes, pages)
+    q = np.zeros((3 * pa.RAGGED_TQ, nq, STORED), np.float32)
+    q[..., :LAT + ROT] = rng.randn(3 * pa.RAGGED_TQ, nq, LAT + ROT)
+    return (jnp.asarray(kc, dtype), jnp.asarray(tables, jnp.int32),
+            jnp.asarray(q, dtype))
+
+
+def test_latent_rows_are_keys_and_their_first_lanes_values():
+    """Decode rows and a prefill tile of the latent kind against
+    ops/attention.py given the same rows as K and their first 512 lanes
+    as V: what `latent_v` means."""
+    kc, tables, q = latent_case(21)
+    kw = dict(block_size=BS, scale=0.07, interpret=True, latent_v=LAT)
+    lens = jnp.asarray([1, 37, 12 * BS, 100], jnp.int32)
+    out = pa.paged_decode_attention(
+        q[:4], kc, None, jnp.int32(1), tables, lens, **kw)
+    assert out.shape == (4, 8, LAT)
+    for i in range(4):
+        slots = xla_attn.block_table_slots(tables[i], BS)
+        k_ctx = kc[1][:, slots].swapaxes(0, 1)
+        ref = xla_attn.context_attention_decode(
+            q[i:i + 1], k_ctx[None], k_ctx[None, ..., :LAT],
+            lens[i:i + 1], 0.07)
+        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(ref[0]),
+                                   rtol=2e-5, atol=2e-5)
+    start = 70
+    pre = pa.paged_prefill_attention(
+        q[:16], kc, None, jnp.int32(1), tables[0], jnp.int32(start), **kw)
+    slots = xla_attn.block_table_slots(tables[0], BS)
+    k_ctx = kc[1][:, slots].swapaxes(0, 1)
+    ref = xla_attn.context_attention_prefill(
+        q[:16], k_ctx, k_ctx[..., :LAT], start + jnp.arange(16),
+        jnp.int32(start + 16), 0.07)
+    np.testing.assert_allclose(np.asarray(pre), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_latent_ragged_rows_equal_the_composed_kernels_bit_for_bit():
+    """The latent kind in the ONE launch: a two-tile prefill chunk
+    beside three decode lanes, one of which holds no sequence (a
+    zero-row segment: exact zeros, no copy started), bf16 as on the
+    chip. Each row has the bits of the composed kernel of its kind."""
+    kc, tables, q = latent_case(23, dtype=jnp.bfloat16)
+    tq = pa.RAGGED_TQ
+    start = 90
+    ctx = np.asarray([61, 0, 12 * BS], np.int32)
+    blk_seg = jnp.asarray([0, 1, 2, 5], jnp.int32)
+    seg_meta = jnp.asarray([
+        [0, 0, tq, start], [0, 0, tq, start + tq],
+        [1, 0, 1, 60], [2, 1, 0, -1], [3, 2, 1, 12 * BS - 1]], jnp.int32)
+    kw = dict(block_size=BS, scale=0.07, interpret=True, latent_v=LAT)
+    out = pa.ragged_paged_attention(
+        q, kc, None, jnp.int32(1), tables, blk_seg, seg_meta, **kw)
+    assert out.shape == (3 * tq, 8, LAT)
+    pre = pa.paged_prefill_attention(
+        q[:2 * tq], kc, None, jnp.int32(1), tables[0], jnp.int32(start),
+        **kw)
+    dec = pa.paged_decode_attention(
+        q[2 * tq:2 * tq + 3], kc, None, jnp.int32(1), tables[1:],
+        jnp.asarray(ctx), **kw)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    np.testing.assert_array_equal(f32(out[:2 * tq]), f32(pre))
+    np.testing.assert_array_equal(f32(out[2 * tq:2 * tq + 3]), f32(dec))
+    assert not f32(out[2 * tq + 1]).any()
+
+
+def test_a_latent_kind_sizes_its_kv_block_from_one_buffer():
+    # 640 lanes of bf16, one ring: 512 keys (a block of 640 KiB)
+    assert pa._kv_block_pages(1, 640, 2, 32, 0) * 32 == 512
+    with pytest.raises(AssertionError):
+        kc, tables, q = latent_case(25)
+        pa.paged_decode_attention(
+            q[:4], kc, kc, jnp.int32(1), tables,
+            jnp.ones((4,), jnp.int32), block_size=BS, scale=1.0,
+            interpret=True, latent_v=LAT)

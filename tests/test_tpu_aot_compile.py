@@ -67,10 +67,11 @@ def _compile_decode(sh, *, nq=NQ, d=D, lanes=16, pages=32):
 
 
 def _compile_ragged(sh, *, nq=NQ, nkv=NKV, d=D, rows=128, lanes=16,
-                    pages=32, window=None, d_v=None, sink=False):
+                    pages=32, window=None, d_v=None, sink=False,
+                    latent_v=None):
     fn = functools.partial(
         pa.ragged_paged_attention, block_size=BS, scale=d**-0.5,
-        window=window,
+        window=window, latent_v=latent_v,
     )
     blocks = rows // pa.RAGGED_TQ
     scalars = [
@@ -81,7 +82,7 @@ def _compile_ragged(sh, *, nq=NQ, nkv=NKV, d=D, rows=128, lanes=16,
     ]
     args = [
         _spec(sh, (rows, nq, d), jnp.bfloat16), _cache(sh, d, nkv),
-        _cache(sh, d_v or d, nkv), *scalars,
+        None if latent_v else _cache(sh, d_v or d, nkv), *scalars,
     ]
     if sink:
         args.append(_spec(sh, (nq,), jnp.float32))
@@ -124,6 +125,10 @@ def test_ragged_kernel_compiles_at_benchmark_widths(v5e, nq, nkv, window):
           window=128)),
     ("mimo-v2.5-ep16-l7.batch-doc8k, full layers",
      dict(nq=64, nkv=4, lanes=64, pages=512, d=256, d_v=128, sink=True)),
+    # the latent kind: one row of 640 stored lanes a token, read as the
+    # key and (its first 512 lanes) as the value by 32 heads, no V cache
+    ("xing4-29b-l8.chat-doc16k",
+     dict(nq=32, nkv=1, lanes=32, pages=1024, d=640, latent_v=512)),
 ])
 @pytest.mark.parametrize("prefill_rows", [0, 512])
 def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
@@ -131,12 +136,24 @@ def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
     and stores a zero row) compiles for a v5e at every cell's decode
     shape — 32 lanes x 4,096 at 8 and 4 kv heads; 64 lanes x 16,384
     with K stored at 256 lanes beside V at 128, a sink, the window of
-    128 — and what it prefetches to SMEM stays under the 1 MiB bound."""
+    128; 32 lanes x 32,768 of the latent kind — and what it prefetches
+    to SMEM stays under the 1 MiB bound."""
     lanes = shape["lanes"]
     shape = {**shape, "rows": prefill_rows + lanes,
              "lanes": lanes + (8 if prefill_rows else 0)}
     smem = _compile_ragged(v5e, **shape)
     assert smem < SMEM_BYTES, (cell, smem)
+
+
+@pytest.mark.parametrize("rows", [32, 40, 544])
+def test_sinkhorn_kernel_compiles(v5e, rows):
+    """The hyper-connections' Sinkhorn iterations as one Mosaic kernel
+    (`ops/sinkhorn.py`) over a (4, 4, rows) block: the decode lanes'
+    rows, a lane-typed round's, two prefill chunks beside the lanes."""
+    from production_stack_tpu.ops import sinkhorn as sk
+
+    fn = functools.partial(sk.sinkhorn, iters=20, eps=1e-6, interpret=False)
+    jax.jit(fn).lower(_spec(v5e, (4, 4, rows), jnp.float32)).compile()
 
 
 @pytest.mark.slow
