@@ -3286,6 +3286,7 @@ class LLMEngine:
                     if t < vocab:
                         logits[i, t] += v
         logits = self._apply_guided_mask(seqs, logits)
+        self.runner.note_sampler(1, temps)
         out = sample_tokens(logits, temps, top_ps, top_ks, keys,
                             min_p=min_ps)
         sampled = np.asarray(out)[: len(seqs)]
@@ -3705,6 +3706,7 @@ class LLMEngine:
             engine_phases=self.phases.pairs(),
             attn_context_tokens=tuple(self.runner.attn_context_tokens),
             decode_lane_steps=tuple(self.runner.decode_lane_steps),
+            sampler_steps=tuple(self.runner.sampler_steps),
             **self._layer_group_stats(),
             program_stages=phases.program_stage_pairs(),
             program_cache_hits_total=phases.PROGRAM_CACHE_HITS[0],

@@ -415,6 +415,22 @@ class EngineMetrics:
             "counted here",
             label, registry=reg,
         )
+        self.sampler_steps = Counter(
+            "tpu:sampler_steps",
+            "Evaluations of the sampler by the dispatched rounds: a "
+            "round's fused decode steps, one more where it samples "
+            "prefill rows' first tokens, one for a batch sampled on the "
+            "host path",
+            label, registry=reg,
+        )
+        self.sampler_window_steps = Counter(
+            "tpu:sampler_window_steps",
+            "Of tpu:sampler_steps, those whose rows held a temperature "
+            "> 0: the device builds the 64-candidate window (top-k "
+            "over the vocabulary) only there, a greedy evaluation "
+            "takes its argmax",
+            label, registry=reg,
+        )
         self.decode_overshoot = Counter(
             "tpu:decode_overshoot_tokens",
             "Sampled decode slots discarded by the host past a stop "
@@ -589,8 +605,10 @@ class EngineMetrics:
         self.decode_rounds.labels(m).inc(max(
             0, s.decode_rounds_total - prev.decode_rounds_total))
         for counter, now, was in zip(
-                (self.decode_lane_steps, self.decode_idle_lane_steps),
-                s.decode_lane_steps, prev.decode_lane_steps):
+                (self.decode_lane_steps, self.decode_idle_lane_steps,
+                 self.sampler_steps, self.sampler_window_steps),
+                s.decode_lane_steps + s.sampler_steps,
+                prev.decode_lane_steps + prev.sampler_steps):
             counter.labels(m).inc(max(0, now - was))
         self.decode_overshoot.labels(m).inc(max(
             0, s.decode_overshoot_tokens_total

@@ -318,6 +318,12 @@ class ModelRunner:
         # segments of the attention walk (tpu:decode_lane_steps,
         # tpu:decode_idle_lane_steps)
         self.decode_lane_steps = [0, 0]
+        # evaluations of sampler.sample_tokens by the dispatched rounds
+        # (a fused decode step is one, a round's first-token rows one
+        # more), and those of them whose rows held a temperature > 0:
+        # the only ones that build the candidate window
+        # (tpu:sampler_steps, tpu:sampler_window_steps)
+        self.sampler_steps = [0, 0]
         # the decode lanes' page-table rows as the last pack left them
         # (_page_table_rows): id(table) -> (table, row, ids copied)
         self._kept_rows: dict[int, tuple] = {}
@@ -908,6 +914,15 @@ class ModelRunner:
             by_kind[0] += sum(
                 min(c + i, cut) for c in decode_lens for i in range(k)
             ) + sum(min(c, cut) for c in prefill_lens)
+
+    def note_sampler(self, steps: int, temps) -> None:
+        """Count `steps` evaluations of `sample_tokens` over rows of
+        these temperatures (None: the greedy defaults). The device
+        reads the same vector and builds the candidate window only
+        where a row samples; this is the host's count of how often."""
+        self.sampler_steps[0] += steps
+        if temps is not None and np.greater(temps, 0.0).any():
+            self.sampler_steps[1] += steps
 
     @staticmethod
     def _layout_of(fields: list[tuple[str, tuple[int, ...]]]):
@@ -1576,6 +1591,7 @@ class ModelRunner:
         fn = self._verify_batch_fns[key]
         lora_kw = self._packed_lora_kwargs(lora_slots, n, s_pad, t_pad)
         self._note_attn_context(prefill_lens=total_lens)
+        self.note_sampler(1, l_temps)
         with self.phases.span("dispatch"), build:
             sampled, self.k_cache, self.v_cache = fn(
                 self.params,
@@ -2522,6 +2538,7 @@ class ModelRunner:
                     packed_dev = jnp.asarray(packed)
             fn, build = self._prefill_fn(t_pad, c_pad, want_plp)
             self._note_attn_context(prefill_lens=(total_len,))
+            self.note_sampler(1, sampling and sampling[0])
             with self.phases.span("dispatch"), build:
                 ys = fn(
                     self.params, self.k_cache, self.v_cache, packed_dev,
@@ -2559,6 +2576,7 @@ class ModelRunner:
             )
         fn, build = self._prefill_fn(t_pad, c_pad, want_plp)
         self._note_attn_context(prefill_lens=(total_len,))
+        self.note_sampler(1, sampling and sampling[0])
         with self.phases.span("dispatch"), build:
             ys = fn(
                 self.params,
@@ -2632,6 +2650,7 @@ class ModelRunner:
                 )
             lora_kw = self._rows_lora_kwargs(lora_slots, chunks, r_pad)
             self._note_attn_context(prefill_lens=total_lens)
+            self.note_sampler(1, sampling and sampling[0])
             with self.phases.span("dispatch"), build:
                 sampled, logits, self.k_cache, self.v_cache = (
                     self._prefill_batch_fns[key](
@@ -2663,6 +2682,7 @@ class ModelRunner:
                 lora_slots, n, s_pad, t_pad
             )
             self._note_attn_context(prefill_lens=total_lens)
+            self.note_sampler(1, sampling and sampling[0])
             with self.phases.span("dispatch"), build:
                 sampled, logits, self.k_cache, self.v_cache = fn(
                     self.params, self.k_cache, self.v_cache,
@@ -2700,6 +2720,7 @@ class ModelRunner:
             )
         fn, build = self._prefill_batch_fn(s_pad, t_pad, c_pad)
         self._note_attn_context(prefill_lens=total_lens)
+        self.note_sampler(1, sampling and sampling[0])
         with self.phases.span("dispatch"), build:
             sampled, logits, self.k_cache, self.v_cache = fn(
                 self.params,
@@ -3352,6 +3373,7 @@ class ModelRunner:
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
         self._note_attn_context(context_lens, steps)
+        self.note_sampler(steps, temps)
         with self.phases.span("dispatch"), build:
             ys, self.k_cache, self.v_cache = fn(
                 self.params,
@@ -3906,6 +3928,8 @@ class ModelRunner:
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
         self._note_attn_context(context_lens, steps, pf_total_lens)
+        self.note_sampler(steps, temps)
+        self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:
             pf_sampled, pf_logits, ys, self.k_cache, self.v_cache = fn(
                 self.params,
@@ -4017,6 +4041,8 @@ class ModelRunner:
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
         self._note_attn_context(context_lens, steps, pf_total_lens)
+        self.note_sampler(steps, temps)
+        self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:
             pf_sampled, pf_logits, ys, self.k_cache, self.v_cache = fn(
                 self.params,

@@ -70,6 +70,10 @@ class EngineStatsSnapshot:
     # the host packed as zero-row segments: lanes holding no sequence)
     # — tpu:decode_lane_steps, tpu:decode_idle_lane_steps
     decode_lane_steps: tuple = (0, 0)
+    # (evaluations of the sampler by the dispatched rounds, those whose
+    # rows held a temperature > 0: the only ones that build the
+    # candidate window) — tpu:sampler_steps, tpu:sampler_window_steps
+    sampler_steps: tuple = (0, 0)
     # -- a model of layer groups (models/layer_groups.py); all zero or
     # empty for a model of alike layers ------------------------------
     # context tokens a LAYER of each attention kind read, by the kind's

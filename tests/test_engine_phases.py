@@ -305,6 +305,32 @@ def test_decode_lane_steps_count_the_lanes_that_hold_no_sequence():
         assert f'tpu:{name}_total{{model_name="m"}} {value}' in text, name
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_sampler_steps_reach_metrics(temperature):
+    """tpu:sampler_steps / tpu:sampler_window_steps: one evaluation for a
+    prefill round's first-token rows, K for a fused decode round; in the
+    window count where a row of the round has a temperature > 0."""
+    e = LLMEngine(cfg())
+    e.add_request("a", prompt_token_ids=prompt(10),
+                  sampling_params=SamplingParams(
+                      max_tokens=9, temperature=temperature, seed=3,
+                      ignore_eos=True))
+    e.step()
+    assert e.last_step_kind == "prefill"
+    assert e.runner.sampler_steps == [1, 1 if temperature else 0]
+    e.step()                    # K=4
+    assert e.last_step_kind == "decode"
+    window = 5 if temperature else 0
+    assert e.stats().sampler_steps == (5, window)
+    reg = CollectorRegistry()
+    metrics = EngineMetrics("m", registry=reg)
+    metrics.update_from_snapshot(e.stats())
+    text = generate_latest(reg).decode()
+    for name, value in (("sampler_steps", 5.0),
+                        ("sampler_window_steps", float(window))):
+        assert f'tpu:{name}_total{{model_name="m"}} {value}' in text, name
+
+
 def test_sliding_window_bounds_the_attention_context_count():
     r = LLMEngine(cfg()).runner
     r.model_config = type("MC", (), {"sliding_window": 8})()

@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -184,6 +185,47 @@ def test_block_tables_are_bounded_by_smem(v5e):
         _compile_decode(v5e, lanes=64, pages=4096)  # 1 MiB: refused
 
 
+def _topk_branch(text):
+    """The body of the one computation of an optimised HLO module that
+    holds the `TopK` custom call, which has to be a branch computation of
+    a `conditional`: XLA:TPU kept the sampler's `lax.cond` as control
+    flow and did not make it a `select` of both sides."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    branches = {
+        n.strip().lstrip("%")
+        for listed in re.findall(
+            r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+        for n in listed.split(",")}
+    with_topk = [name for name, body in comps.items()
+                 if any('custom_call_target="TopK"' in line for line in body)]
+    assert len(with_topk) == 1 and with_topk[0] in branches, (
+        with_topk, branches)
+    return "\n".join(comps[with_topk[0]])
+
+
+def test_sampler_window_stays_a_branch_on_the_chip(v5e):
+    """`sample_tokens` at qwen2's vocabulary and the chat cells' lanes:
+    the sort of the vocabulary — the `TopK` custom call,
+    `custom-call.*_f32_32_64_` in a trace — is in a branch computation,
+    so a round of greedy rows does not run it."""
+    from production_stack_tpu.engine.sampler import TOP_CAP, sample_tokens
+
+    b, vocab = 32, 152064
+    text = sample_tokens.lower(
+        _spec(v5e, (b, vocab), jnp.float32), _spec(v5e, (b,), jnp.float32),
+        _spec(v5e, (b,), jnp.float32), _spec(v5e, (b,), jnp.int32),
+        _spec(v5e, (b, 2), jnp.uint32), min_p=_spec(v5e, (b,), jnp.float32),
+    ).compile().as_text()
+    assert f"f32[{b},{TOP_CAP}]" in _topk_branch(text)
+
+
 @pytest.mark.slow
 def test_decode_multi_step_compiles_at_full_width_and_depth(
     v5e, monkeypatch
@@ -227,3 +269,5 @@ def test_decode_multi_step_compiles_at_full_width_and_depth(
     ).compile()
     cache_bytes = runner.k_cache.size * runner.k_cache.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
+    # the sampler's window is a branch inside the scan's body too
+    _topk_branch(compiled.as_text())
