@@ -3707,6 +3707,8 @@ class LLMEngine:
             attn_context_tokens=tuple(self.runner.attn_context_tokens),
             decode_lane_steps=tuple(self.runner.decode_lane_steps),
             sampler_steps=tuple(self.runner.sampler_steps),
+            loop_passes_total=self.runner.loop_passes,
+            loop_exit_mass=self.runner.loop_exit_mass(),
             **self._layer_group_stats(),
             program_stages=phases.program_stage_pairs(),
             program_cache_hits_total=phases.PROGRAM_CACHE_HITS[0],

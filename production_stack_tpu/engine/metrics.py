@@ -146,6 +146,14 @@ class EngineMetrics:
                 "round had to read once (sum) per round (count): each "
                 "decode lane's context at each fused step, each "
                 "prefill chunk's end context",
+            "tpu:loop_exit_pass":
+                "A looped stack with an exit gate: the pass at which a "
+                "sampled row would leave under the gate (sum: pass x "
+                "tpu:loop_exit_mass over the passes, 1-based) and the "
+                "sampled rows (count: the mass of all passes); their "
+                "ratio is the mean exit pass, out of ut_steps. The "
+                "per-pass counter under ONE name for readers that sum "
+                "a sample over its label sets",
             "tpu:kv_window_blocks_per_seq":
                 "A model with a windowed cache group: window-group "
                 "blocks some sequence holds (sum) and running "
@@ -431,6 +439,25 @@ class EngineMetrics:
             "takes its argmax",
             label, registry=reg,
         )
+        self.loop_passes = Counter(
+            "tpu:loop_passes",
+            "Passes of the layer stack by the dispatched programs: "
+            "their forwards (a round's fused decode steps, a prefill "
+            "beside them riding the first) x the model's ut_steps (1 "
+            "for a stack that runs once a token); each pass reads "
+            "every layer's weights",
+            label, registry=reg,
+        )
+        self.loop_exit_mass = Counter(
+            "tpu:loop_exit_mass",
+            "A looped stack's exit distribution under its learned "
+            "gate, summed on the device over the rows that were "
+            "sampled: the probability that a row would leave after "
+            "`pass` (the last pass takes the rest). Served at a "
+            "threshold of 1 no row leaves; sum(pass x mass) / sum(mass) "
+            "is the mean pass a lower threshold could stop at",
+            ["model_name", "pass"], registry=reg,
+        )
         self.decode_overshoot = Counter(
             "tpu:decode_overshoot_tokens",
             "Sampled decode slots discarded by the host past a stop "
@@ -610,6 +637,15 @@ class EngineMetrics:
                 s.decode_lane_steps + s.sampler_steps,
                 prev.decode_lane_steps + prev.sampler_steps):
             counter.labels(m).inc(max(0, now - was))
+        self.loop_passes.labels(m).inc(max(
+            0, s.loop_passes_total - prev.loop_passes_total))
+        for t, now in enumerate(s.loop_exit_mass):
+            was = prev.loop_exit_mass[t] if prev.loop_exit_mass else 0.0
+            self.loop_exit_mass.labels(m, str(t + 1)).inc(
+                max(0.0, now - was))
+        self.pairs.set("tpu:loop_exit_pass", (
+            sum((t + 1) * x for t, x in enumerate(s.loop_exit_mass)),
+            sum(s.loop_exit_mass)))
         self.decode_overshoot.labels(m).inc(max(
             0, s.decode_overshoot_tokens_total
             - prev.decode_overshoot_tokens_total))

@@ -103,6 +103,8 @@ class ModelRunner:
         mc = self.model_config
         if mc.layer_groups:
             self._refuse_for_layer_groups(config, mc)
+        if mc.ut_steps > 1:
+            self._refuse_for_looped_stack(config, mc)
         if mc.is_moe and mc.moe_capacity_factor > 0:
             # serving steps pad decode lanes / prefill buckets, and the
             # GShard capacity path has no per-row validity inside
@@ -190,6 +192,15 @@ class ModelRunner:
         # property reads the block map when it changed
         self.block_map_source = None
         self._map_version = None
+        # counters a step program sums on the device and returns beside
+        # its K cache ("stats": the routed layers' of a layer-group
+        # model, ints; a looped stack's exit distribution, a float a
+        # pass): each program's own, on their way to the host, and the
+        # host's totals
+        self._stats_pending: collections.deque = collections.deque()
+        self._stats_total = (
+            [0] * layer_groups.N_STATS if mc.layer_groups
+            else [0.0] * mc.ut_steps)
         if mc.layer_groups:
             self._allocate_cache_groups()
         else:
@@ -200,7 +211,7 @@ class ModelRunner:
             # kernels and the MXU want (see ops/pallas_attention.py
             # docstring)
             cache_shape = (
-                mc.num_layers, mc.num_kv_heads, num_slots, mc.head_dim
+                mc.cache_layers, mc.num_kv_heads, num_slots, mc.head_dim
             )
             logger.info(
                 "allocating KV cache: %d blocks x %d slots (%.2f GiB)",
@@ -324,6 +335,11 @@ class ModelRunner:
         # the only ones that build the candidate window
         # (tpu:sampler_steps, tpu:sampler_window_steps)
         self.sampler_steps = [0, 0]
+        # passes of the layer stack by the dispatched programs: their
+        # forwards x `ut_steps` (tpu:loop_passes; a device stop may end
+        # a round before its last fused step, as for the sampler's)
+        self.loop_passes = 0
+        self._passes_a_forward = mc.ut_steps
         # the decode lanes' page-table rows as the last pack left them
         # (_page_table_rows): id(table) -> (table, row, ids copied)
         self._kept_rows: dict[int, tuple] = {}
@@ -422,6 +438,36 @@ class ModelRunner:
                 "kind), which does not serve with: " + "; ".join(on)
             )
 
+    @staticmethod
+    def _refuse_for_looped_stack(config: EngineConfig, mc) -> None:
+        """A looped stack (`ut_steps` > 1) runs on one device through
+        the chunked-prefill and fused-decode programs, and its cache
+        moves through the KV tiers and PD transfer at `cache_layers`
+        layers a block. What walks the layers by another loop than
+        llama.forward's, or sizes by `num_layers` what the passes would
+        multiply, is refused here, by name."""
+        refused = {
+            "--enable-lora (adapter stacks a weight layer, applied in "
+            "every pass: not tested)": config.enable_lora,
+            "--tensor-parallel-size > 1": config.tensor_parallel_size > 1,
+            "--pipeline-parallel-size > 1 (the phase loop of "
+            "parallel/pp_serving.py runs the stages once)":
+                config.pipeline_parallel_size > 1,
+            "multihost serving": bool(config.multihost),
+            "--num-speculative-tokens": config.num_speculative_tokens > 0,
+            "--long-prefill-threshold (the ring prefill lane runs "
+            "the layers once)":
+                config.long_prefill_threshold is not None,
+        }
+        on = [name for name, flag in refused.items() if flag]
+        if on:
+            raise ValueError(
+                f"model {mc.name} is a looped stack (ut_steps="
+                f"{mc.ut_steps}: models/llama.py runs its layers that "
+                "many times a token, each pass on cache layers of its "
+                "own), which does not serve with: " + "; ".join(on)
+            )
+
     def _window_blocks_needed(self) -> int:
         """Blocks of the windowed cache group: for every lane the
         window behind its next query and the chunk (or fused decode
@@ -490,10 +536,6 @@ class ModelRunner:
             "map": jnp.zeros((self.num_blocks,), jnp.int32),
         }
         self.v_cache = {"g": tuple(vg)}
-        # the routed layers' counters: each program's own (`"stats"` in
-        # the K side it returns), on their way to the host, and summed
-        self._stats_pending: collections.deque = collections.deque()
-        self._stats_total = [0] * layer_groups.N_STATS
 
     @property
     def k_cache(self):
@@ -526,6 +568,9 @@ class ModelRunner:
             stats.copy_to_host_async()
             self._stats_pending.append(stats)
             self._drain_stats()
+            # a looped stack's cache is one array: {"c": it} without
+            # its counters is it
+            value = value.get("c", value)
         self._k_cache = value
 
     def _drain_stats(self) -> None:
@@ -535,12 +580,22 @@ class ModelRunner:
         pending = self._stats_pending
         while pending and pending[0].is_ready():
             for i, x in enumerate(np.asarray(pending.popleft())):
-                self._stats_total[i] += int(x)
+                self._stats_total[i] += x.item()
 
     def moe_stats(self) -> tuple[int, ...]:
         """The routed layers' counters (layer_groups.N_STATS) of every
         finished program since start-up. Reads host memory: a scrape
         waits for no round. The caller holds the engine's step lock."""
+        self._drain_stats()
+        return tuple(self._stats_total)
+
+    def loop_exit_mass(self) -> tuple[float, ...]:
+        """A looped stack's exit distribution summed over the sampled
+        rows of every finished program since start-up, a float a pass
+        (empty for a model without the gate). Host memory, as
+        `moe_stats`."""
+        if not self.model_config.exit_gate:
+            return ()
         self._drain_stats()
         return tuple(self._stats_total)
 
@@ -585,7 +640,7 @@ class ModelRunner:
         if bytes_per_block is None:
             bytes_per_block = (
                 2
-                * mc.num_layers
+                * mc.cache_layers
                 * cfg.block_size
                 * mc.num_kv_heads
                 * mc.head_dim
@@ -714,12 +769,15 @@ class ModelRunner:
             next_pow2(self.config.max_prefill_chunk),
         )
 
-    def _enter_caches(self, kc, vc):
+    def _enter_caches(self, kc, vc, counters: bool = True):
         """What every step program does to the caches it was handed. A
         layer-group cache gets the program's routed-layer counters, from
         zero, where a step composed of steps has not yet given it them
-        (`k_cache`'s setter takes them off what the program returns).
-        The caches are then pinned to the row-major physical
+        (`k_cache`'s setter takes them off what the program returns);
+        the one K array of a looped stack with an exit gate becomes
+        {"c": it, "stats": the program's exit distribution}, which
+        llama.forward adds to (`counters=False`: a program that runs no
+        forward). The caches are then pinned to the row-major physical
         layout the Pallas custom calls constrain their operands to.
 
         Without the pin, XLA may pick a different layout for the scan body's
@@ -729,6 +787,10 @@ class ModelRunner:
         if isinstance(kc, dict) and "stats" not in kc:
             kc = {**kc, "stats": jnp.zeros(
                 (layer_groups.N_STATS,), jnp.int32)}
+        mc = self.model_config
+        if counters and mc.exit_gate and not isinstance(kc, dict):
+            kc = {"c": kc,
+                  "stats": jnp.zeros((mc.ut_steps,), jnp.float32)}
         if self.attention_impl != "pallas" or (
             jax.default_backend() != "tpu"
         ):
@@ -738,6 +800,8 @@ class ModelRunner:
         fmt = Layout((0, 1, 2, 3))
 
         def pin(c):
+            if isinstance(c, dict) and "c" in c:  # a looped stack's
+                return {**c, "c": with_layout_constraint(c["c"], fmt)}
             if isinstance(c, dict):  # cache groups: the arrays under "g"
                 return {**c, "g": tuple(
                     a if a is None else with_layout_constraint(a, fmt)
@@ -745,6 +809,14 @@ class ModelRunner:
             return with_layout_constraint(c, fmt)
 
         return pin(kc), pin(vc)
+
+    def _rows_valid_kw(self, valid) -> dict:
+        """`rows_valid=` for llama.forward where the model has an exit
+        gate: which of a program's logits rows hold a token (padding
+        lanes and lanes that hold no sequence are sampled and thrown
+        away, and must not count into tpu:loop_exit_mass). Nothing for
+        any other model: their forwards take no such argument."""
+        return {"rows_valid": valid} if self.model_config.exit_gate else {}
 
     # -- jitted step builders ---------------------------------------------
     # stackcheck: hot-path — the ONE dispatch seam every pallas
@@ -883,6 +955,7 @@ class ModelRunner:
     # -- pipelined prefill: fused h2d buffer --------------------------------
     def _note_attn_context(
         self, decode_lens=(), steps: int = 0, prefill_lens=(),
+        forwards: int | None = None,
     ) -> None:
         """Count one dispatched round's attention reads: each decode
         lane's context at each of its `steps` fused steps (context + i
@@ -891,8 +964,13 @@ class ModelRunner:
         device stop freezes mid-round is counted to the round's end.
         And the decode rows' lane-steps, with those of lanes that hold
         no sequence (a frozen lane is no idle one here: the host packed
-        it live)."""
+        it live). And the passes of the layer stack: `forwards` calls
+        of the model (the fused steps, a prefill beside them riding
+        step 0; one for a prefill alone) x `ut_steps`."""
         k, n = steps, len(decode_lens)
+        self.loop_passes += (
+            (max(k, 1) if forwards is None else forwards)
+            * self._passes_a_forward)
         lanes = self.config.max_num_seqs
         self.decode_lane_steps[0] += k * lanes
         self.decode_lane_steps[1] += k * (lanes - n)
@@ -1229,6 +1307,7 @@ class ModelRunner:
                 pf["write_slots"], attn_fn,
                 logits_rows=pf["last_rows"],
                 lora=lora, lora_slots=lora_slots,
+                **self._rows_valid_kw(pf["lane_rows"] > 0),
             )
             sampled = sample_tokens(
                 logits, pf["temps"], pf["top_ps"], pf["top_ks"],
@@ -2181,6 +2260,7 @@ class ModelRunner:
                     q, l, k, v, spec=spec),
                 logits_rows=lane,
                 lora=lora, lora_slots=lora_slots,
+                **self._rows_valid_kw(ctx > 0),
             )
             return logits, kc, vc
 
@@ -3729,6 +3809,8 @@ class ModelRunner:
                     [pf["last_rows"], r_pad + jnp.arange(b)]
                 ),
                 lora=lora, lora_slots=lora_cat,
+                **self._rows_valid_kw(jnp.concatenate(
+                    [pf["lane_rows"] > 0, d_ctx > 0])),
             )
             pf_logits = logits_all[:s_cap]
             dec0_logits = logits_all[s_cap:]
@@ -3927,7 +4009,8 @@ class ModelRunner:
                 "pf_lora_slots": pf_kw["lora_slots"],
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        self._note_attn_context(context_lens, steps, pf_total_lens)
+        self._note_attn_context(context_lens, steps, pf_total_lens,
+                                forwards=steps + 1)
         self.note_sampler(steps, temps)
         self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:
@@ -4218,7 +4301,7 @@ class ModelRunner:
         # c_pad + 1 rows: the last row is the trash slot padded chunk rows
         # write into (they carry position c_pad)
         kc = jnp.zeros(
-            (mc.num_layers, mc.num_kv_heads, c_pad + 1, mc.head_dim),
+            (mc.cache_layers, mc.num_kv_heads, c_pad + 1, mc.head_dim),
             self.cache_dtype,
         )
         vc = jnp.zeros_like(kc)
@@ -4280,13 +4363,13 @@ class ModelRunner:
     def materialize_export(self, handle: tuple) -> np.ndarray:
         """Blocking half of the deferred export (runs on the offload
         worker thread): fetch the staged gathers and relayout to the
-        wire format (2, num_layers, n, nkv, block_size, d) — block count
+        wire format (2, cache_layers, n, nkv, block_size, d) — block count
         stays at dim 2, so offload/transfer consumers that slice or
         count blocks (`data[:, :, i]`, `data.shape[2]`) are
         layout-agnostic."""
         n, k, v = handle
         mc = self.model_config
-        shape = (mc.num_layers, mc.num_kv_heads, n, self.block_size,
+        shape = (mc.cache_layers, mc.num_kv_heads, n, self.block_size,
                  mc.head_dim)
         return np.stack([
             np.asarray(k).reshape(shape).swapaxes(1, 2),
@@ -4306,12 +4389,12 @@ class ModelRunner:
         bs = self.block_size
 
         def step(kc, vc, bids, cols, staged):
-            kc, vc = self._enter_caches(kc, vc)
+            kc, vc = self._enter_caches(kc, vc, counters=False)
             # staged: (2, L, n_src_pad, nkv, bs, d) wire layout
             sel = staged[:, :, cols]  # (2, L, n_dst_pad, nkv, bs, d)
             hm = jnp.swapaxes(sel, 2, 3)  # head-major
             flat = hm.reshape(
-                2, mc.num_layers, mc.num_kv_heads, n_dst_pad * bs,
+                2, mc.cache_layers, mc.num_kv_heads, n_dst_pad * bs,
                 mc.head_dim,
             ).astype(self.cache_dtype)
             idx = xla_attn.block_table_slots(bids, bs)
@@ -4403,7 +4486,7 @@ class ModelRunner:
         p = 1
         while p <= next_pow2(max(1, max_blocks)):
             data = np.zeros(
-                (2, mc.num_layers, p, mc.num_kv_heads, self.block_size,
+                (2, mc.cache_layers, p, mc.num_kv_heads, self.block_size,
                  mc.head_dim), wire_dt,
             )
             handle = self.stage_import_blocks(data)

@@ -74,6 +74,13 @@ class EngineStatsSnapshot:
     # rows held a temperature > 0: the only ones that build the
     # candidate window) — tpu:sampler_steps, tpu:sampler_window_steps
     sampler_steps: tuple = (0, 0)
+    # passes of the layer stack by the dispatched programs (their
+    # forwards x ut_steps) — tpu:loop_passes; and a looped stack's exit
+    # distribution under its gate, summed on the device over the
+    # sampled rows, a float a pass (empty without a gate) —
+    # tpu:loop_exit_mass{pass}
+    loop_passes_total: int = 0
+    loop_exit_mass: tuple = ()
     # -- a model of layer groups (models/layer_groups.py); all zero or
     # empty for a model of alike layers ------------------------------
     # context tokens a LAYER of each attention kind read, by the kind's

@@ -132,8 +132,27 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
+    # a looped stack (arXiv:2510.25741): the SAME `num_layers` layers
+    # run `ut_steps` times a token, the final norm closing every pass;
+    # each pass writes and reads K/V of its own (cache layer
+    # t * num_layers + l), so the cache holds `cache_layers` layers.
+    # `sandwich_norm`: a second RMSNorm on each sublayer's OUTPUT,
+    # before the residual add. `exit_gate`: a learned scalar gate a
+    # pass (sigmoid of a linear on the normed hidden state); served at
+    # a threshold of 1, where no token leaves early, it is only read
+    # out as a counter (tpu:loop_exit_mass). 1 / False = a stack that
+    # runs once, as every other model here
+    ut_steps: int = 1
+    sandwich_norm: bool = False
+    exit_gate: bool = False
 
     def __post_init__(self):
+        if self.ut_steps < 1 or (self.ut_steps > 1 and self.attn_kinds):
+            raise ValueError(
+                f"model {self.name}: ut_steps={self.ut_steps} needs a "
+                "stack of alike layers run at least once (a looped stack "
+                "of layer groups has no code path)"
+            )
         if self.attn_kinds:
             if len(self.layer_kinds) != self.num_layers or not all(
                 0 <= k < len(self.attn_kinds) for k in self.layer_kinds
@@ -156,6 +175,13 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K/V a token holds in a cache of alike layers: one a
+        layer and pass. The ONE count that whatever sizes, allocates or
+        moves that cache reads."""
+        return self.num_layers * self.ut_steps
 
     @property
     def layer_groups(self) -> bool:
@@ -260,10 +286,12 @@ class ModelConfig:
             + 2 * h * self.kv_size
             + self.q_size * h
             + mlp
-            + 2 * h
+            + (4 if self.sandwich_norm else 2) * h
         )
         embed = v * h * (1 if self.tie_word_embeddings else 2)
-        return self.num_layers * per_layer + embed + h
+        # a looped stack's passes share the weights: counted once
+        gate = h + 1 if self.exit_gate else 0
+        return self.num_layers * per_layer + embed + h + gate
 
 
 # -- Presets ---------------------------------------------------------------
@@ -312,6 +340,21 @@ TINY_CTX64K_DEBUG = _register(
         TINY_DEBUG,
         name="pst-tiny-ctx64k-debug",
         max_model_len=65536,
+    )
+)
+
+# the tiny dims as a looped stack: two layers run three times a token
+# with K/V of its own a pass (six cache layers), the output norms and
+# the exit gate (models/llama.py's pass loop; tests/test_ouro_loop.py)
+TINY_LOOP_DEBUG = _register(
+    dataclasses.replace(
+        TINY_DEBUG,
+        name="pst-tiny-loop-debug",
+        tie_word_embeddings=False,
+        rms_norm_eps=1e-6,
+        ut_steps=3,
+        sandwich_norm=True,
+        exit_gate=True,
     )
 )
 
@@ -523,7 +566,8 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
     """Build a ModelConfig from a HuggingFace `config.json` on local disk."""
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
-    by_type = {"mimo_v2": _from_mimo_v2, "xing4_0": _from_xing4}
+    by_type = {"mimo_v2": _from_mimo_v2, "xing4_0": _from_xing4,
+               "ouro": _from_ouro}
     if hf.get("model_type") in by_type:
         return by_type[hf["model_type"]](hf, name or os.path.basename(
             os.path.normpath(path)))
@@ -767,6 +811,64 @@ def _from_xing4(hf: dict, name: str) -> ModelConfig:
                       float(hf.get("mhc_h_res_clamp_max", 30))),
         dense_layers=dense_layers,
         **_routed_fields(hf, dense_layers < L),
+    )
+
+
+def _from_ouro(hf: dict, name: str) -> ModelConfig:
+    """`model_type: ouro` (Ouro-1.4B / 2.6B, a looped language model):
+    a Llama-class stack of full multi-head attention and SwiGLU layers
+    that runs `total_ut_steps` times a token over the same weights, each
+    pass with K/V of its own, a second norm on every sublayer's output,
+    the final norm after every pass, and a scalar exit gate a pass.
+    Served at `early_exit_threshold` 1 (the published value): every
+    token runs every pass. A lower threshold lets single rows of a
+    batch leave the loop early, which no step program can do."""
+    threshold = hf.get("early_exit_threshold", 1)
+    if threshold is not None and float(threshold) < 1.0:
+        raise ValueError(
+            f"{name}: early_exit_threshold={threshold!r} is not served: "
+            "a row would leave the looped stack early while its batch "
+            "goes on (only a threshold of 1, every pass for every "
+            "token, has a code path)"
+        )
+    if hf.get("use_sliding_window"):
+        raise ValueError(
+            f"{name}: use_sliding_window=true is not served for "
+            "model_type ouro (the published model attends over the "
+            "full context in every layer)"
+        )
+    L = hf["num_hidden_layers"]
+    kinds = hf.get("layer_types") or []
+    if any(k != "full_attention" for k in kinds[:L]):
+        raise ValueError(
+            f"{name}: layer_types other than full_attention are not "
+            f"served for model_type ouro, got {sorted(set(kinds[:L]))}"
+        )
+    for key, want in (("rope_scaling", (None,)),
+                      ("attention_bias", (None, False)),
+                      ("hidden_act", (None, "silu"))):
+        if hf.get(key) not in want:
+            raise ValueError(
+                f"{name}: {key}={hf.get(key)!r} is not served for "
+                "model_type ouro"
+            )
+    num_heads = hf["num_attention_heads"]
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=L,
+        num_heads=num_heads,
+        num_kv_heads=hf.get("num_key_value_heads", num_heads),
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // num_heads,
+        max_model_len=hf.get("max_position_embeddings", 8192),
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        ut_steps=int(hf.get("total_ut_steps", 1)),
+        sandwich_norm=True,
+        exit_gate=True,
     )
 
 

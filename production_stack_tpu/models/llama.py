@@ -11,7 +11,11 @@ Design (TPU-first, not a torch translation):
   Pallas kernel can be swapped in without touching model code
 - everything is shape-static; bucketing happens in the model runner
 
-Covers Llama 2/3/3.x, Mistral, Qwen2 (qkv_bias), Mixtral, Phi-3, Gemma, TinyLlama.
+Covers Llama 2/3/3.x, Mistral, Qwen2 (qkv_bias), Mixtral, Phi-3, Gemma, TinyLlama,
+and a LOOPED stack (`cfg.ut_steps` > 1, `model_type: ouro`): the same scan
+over layers runs once a pass inside a scan over passes, pass t writing and
+reading cache layer t * num_layers + l, the final norm closing every pass.
+At `ut_steps` 1 there is no outer loop: the program is the one traced before.
 """
 
 from __future__ import annotations
@@ -70,12 +74,18 @@ def init_params(
         layers["bq"] = jnp.zeros((L, cfg.q_size), dtype)
         layers["bk"] = jnp.zeros((L, cfg.kv_size), dtype)
         layers["bv"] = jnp.zeros((L, cfg.kv_size), dtype)
+    if cfg.sandwich_norm:
+        layers["attn_out_norm"] = jnp.ones((L, h), dtype)
+        layers["mlp_out_norm"] = jnp.ones((L, h), dtype)
 
     params = {
         "embed": w(next(keys), (v, h), h),
         "layers": layers,
         "final_norm": jnp.ones((h,), dtype),
     }
+    if cfg.exit_gate:
+        params["exit_gate_w"] = w(next(keys), (h,), h)
+        params["exit_gate_b"] = jnp.zeros((), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (h, v), h)
     return params
@@ -153,23 +163,40 @@ def decoder_layer(
         kc = kc.at[l, head, write_slots].set(kh[head])
         vc = vc.at[l, head, write_slots].set(vh[head])
 
+    def out_norm(y, name):
+        # the "sandwich": a second norm on the sublayer's output
+        if not cfg.sandwich_norm:
+            return y
+        return rms_norm(y, lp[name], cfg.rms_norm_eps,
+                        cfg.norm_weight_offset)
+
     attn_out = attn_fn(q, l, kc, vc)  # (n, nq, d)
-    h = h + proj(
+    h = h + out_norm(proj(
         attn_out.reshape(n, cfg.q_size).astype(dtype), "wo", None
-    ).astype(dtype)
+    ).astype(dtype), "attn_out_norm")
 
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps,
                  cfg.norm_weight_offset)
     if cfg.is_moe:
-        h = h + moe_block(
+        h = h + out_norm(moe_block(
             x, lp["moe_gate"], lp["w_gate"], lp["w_up"],
             lp["w_down"], cfg.num_experts_per_tok,
             cfg.moe_capacity_factor,
-        ).astype(dtype)
+        ).astype(dtype), "mlp_out_norm")
     else:
-        h = h + swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
-                       act=cfg.hidden_act)
+        h = h + out_norm(
+            swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                   act=cfg.hidden_act), "mlp_out_norm")
     return h, kc, vc
+
+
+def exit_mass(lam: jax.Array) -> jax.Array:
+    """(T, r) gate values -> (T, r) exit distribution: a row leaves
+    after pass t with p_t = lam_t * prod_{s<t} (1 - lam_s), and the last
+    pass takes what is left."""
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([(lam * before)[:-1], before[-1:]])
 
 
 def forward(
@@ -186,6 +213,7 @@ def forward(
     lora: dict | None = None,  # LoraManager.buffers: (L, S, in, r)/(L, S, r, out) + scaling (S,)
     lora_slots: jax.Array | None = None,  # (n,) int32 adapter slot per token
     return_hidden: bool = False,  # final-norm hidden states instead of logits
+    rows_valid: jax.Array | None = None,  # (r,) bool: logits rows that hold a token
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the decoder over n tokens; returns (logits[r, V] fp32, k_cache, v_cache).
 
@@ -197,7 +225,16 @@ def forward(
     rows are gathered per layer and scaling * (x @ A) @ B is added to the
     wq/wk/wv/wo projections (slot 0 is all-zero = no adapter), so one
     batch can mix adapters freely (see engine/lora.py).
+
+    A looped stack with an exit gate may be handed its K side as
+    {"c": cache, "stats": f32[ut_steps]}: the exit distribution of this
+    call's `logits_rows` (those of `rows_valid`) is added to "stats" and
+    the K side goes back in the same form (the runner's step programs
+    carry it so; ModelRunner._enter_caches).
     """
+    stats = None
+    if isinstance(k_cache, dict):
+        stats, k_cache = k_cache["stats"], k_cache["c"]
     dtype = params["embed"].dtype
     cache_dtype = k_cache.dtype
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
@@ -236,20 +273,46 @@ def forward(
         )
         return (h, kc, vc), None
 
-    xs = (
-        (params["layers"], jnp.arange(cfg.num_layers), lora_layers)
-        if use_lora
-        else (params["layers"], jnp.arange(cfg.num_layers))
-    )
-    # named scopes put `layers/...` and `lm_head/...` into the operation
-    # names a profiler trace shows (metadata only: the program is the same)
-    with jax.named_scope("layers"):
-        (h, k_cache, v_cache), _ = jax.lax.scan(
-            layer, (h, k_cache, v_cache), xs
-        )
+    def stack(h, kc, vc, first=None):
+        """The layers once, on cache layers first .. first + L - 1
+        (None: 0 .. L - 1), and the final norm."""
+        idx = jnp.arange(cfg.num_layers)
+        if first is not None:
+            idx = first + idx
+        xs = ((params["layers"], idx, lora_layers) if use_lora
+              else (params["layers"], idx))
+        # named scopes put `layers/...` and `lm_head/...` into the
+        # operation names a profiler trace shows (metadata only: the
+        # program is the same)
+        with jax.named_scope("layers"):
+            (h, kc, vc), _ = jax.lax.scan(layer, (h, kc, vc), xs)
+        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
+                     cfg.norm_weight_offset)
+        return h, kc, vc
 
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
-                 cfg.norm_weight_offset)
+    if cfg.ut_steps == 1:
+        h, k_cache, v_cache = stack(h, k_cache, v_cache)
+    else:
+        def one_pass(carry, t):
+            with jax.named_scope("loop_pass"):
+                h, kc, vc = stack(*carry, t * cfg.num_layers)
+            lam = None
+            if stats is not None:
+                lam = jax.nn.sigmoid(
+                    jnp.dot(h[logits_rows], params["exit_gate_w"],
+                            preferred_element_type=jnp.float32)
+                    + params["exit_gate_b"].astype(jnp.float32))
+            return (h, kc, vc), lam
+
+        (h, k_cache, v_cache), lam = jax.lax.scan(
+            one_pass, (h, k_cache, v_cache), jnp.arange(cfg.ut_steps))
+        if stats is not None:
+            mass = exit_mass(lam)
+            if rows_valid is not None:
+                mass = jnp.where(rows_valid[None, :], mass, 0.0)
+            stats = stats + jnp.sum(mass, axis=1)
+    if stats is not None:
+        k_cache = {"c": k_cache, "stats": stats}
     h_sel = h[logits_rows]  # (r, hidden)
     if return_hidden:
         return h_sel.astype(jnp.float32), k_cache, v_cache

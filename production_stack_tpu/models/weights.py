@@ -120,12 +120,18 @@ def load_hf_weights(
         layers["bq"] = alloc((L, cfg.q_size))
         layers["bk"] = alloc((L, cfg.kv_size))
         layers["bv"] = alloc((L, cfg.kv_size))
+    if cfg.sandwich_norm:
+        layers["attn_out_norm"] = alloc((L, h))
+        layers["mlp_out_norm"] = alloc((L, h))
     top: dict[str, np.ndarray] = {}
 
     # HF key suffix -> (our key, transpose?)
     per_layer = {
         "input_layernorm.weight": ("attn_norm", False),
         "post_attention_layernorm.weight": ("mlp_norm", False),
+        # a looped stack's norms on the sublayers' outputs (`ouro`)
+        "input_layernorm_2.weight": ("attn_out_norm", False),
+        "post_attention_layernorm_2.weight": ("mlp_out_norm", False),
         "self_attn.q_proj.weight": ("wq", True),
         "self_attn.k_proj.weight": ("wk", True),
         "self_attn.v_proj.weight": ("wv", True),
@@ -137,6 +143,9 @@ def load_hf_weights(
         "mlp.up_proj.weight": ("w_up", True),
         "mlp.down_proj.weight": ("w_down", True),
     }
+    # a looped stack's nn.Linear(hidden, 1): weight (1, h), bias (1,)
+    exit_gate = {"early_exit_gate.weight": ("exit_gate_w", (h,)),
+                 "early_exit_gate.bias": ("exit_gate_b", ())}
     n_loaded = 0
     for name, tensor in _iter_tensors(model_dir):
         key = name.removeprefix("model.")
@@ -150,6 +159,11 @@ def load_hf_weights(
             continue
         if name == "lm_head.weight":
             top["lm_head"] = np.asarray(tensor, np_dtype).T
+            n_loaded += 1
+            continue
+        if cfg.exit_gate and key in exit_gate:
+            ours, shape = exit_gate[key]
+            top[ours] = np.asarray(tensor, np_dtype).reshape(shape)
             n_loaded += 1
             continue
         if not key.startswith("layers."):
@@ -222,6 +236,7 @@ def load_hf_weights(
         per_layer_count += 1 + 3 * cfg.num_experts  # router + experts
     expected = (
         L * per_layer_count + 2 + (0 if cfg.tie_word_embeddings else 1)
+        + (2 if cfg.exit_gate else 0)
     )
     if n_loaded < expected:
         raise ValueError(
@@ -233,6 +248,9 @@ def load_hf_weights(
         "layers": {k: jnp.asarray(v, dtype) for k, v in layers.items()},
         "final_norm": jnp.asarray(top["final_norm"], dtype),
     }
+    if cfg.exit_gate:
+        for gate in ("exit_gate_w", "exit_gate_b"):
+            params[gate] = jnp.asarray(top[gate], dtype)
     if not cfg.tie_word_embeddings:
         if "lm_head" in top:
             params["lm_head"] = jnp.asarray(top["lm_head"], dtype)

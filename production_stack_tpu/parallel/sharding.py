@@ -127,6 +127,9 @@ def param_shardings(mesh: Mesh, cfg: ModelConfig) -> dict:
         layers["bq"] = ns(la, TP_AXIS)
         layers["bk"] = ns(la, TP_AXIS)
         layers["bv"] = ns(la, TP_AXIS)
+    if cfg.sandwich_norm:
+        layers["attn_out_norm"] = ns(la, None)
+        layers["mlp_out_norm"] = ns(la, None)
     out = {
         "embed": ns(None, None),  # replicated (logits need full hidden)
         "layers": layers,
@@ -134,6 +137,9 @@ def param_shardings(mesh: Mesh, cfg: ModelConfig) -> dict:
     }
     if not cfg.tie_word_embeddings:
         out["lm_head"] = ns(None, TP_AXIS)  # vocab split
+    if cfg.exit_gate:
+        out["exit_gate_w"] = ns(None)
+        out["exit_gate_b"] = ns()
     return out
 
 

@@ -130,6 +130,11 @@ def test_ragged_kernel_compiles_at_benchmark_widths(v5e, nq, nkv, window):
     # key and (its first 512 lanes) as the value by 32 heads, no V cache
     ("xing4-29b-l8.chat-doc16k",
      dict(nq=32, nkv=1, lanes=32, pages=1024, d=640, latent_v=512)),
+    # plain multi-head attention: 16 kv heads with ONE query row each
+    # (group 1, padded to the 8-row tile), where `_kv_block_pages` is at
+    # its floor of 128 keys and the ring takes 3 MiB of VMEM
+    ("ouro-2.6b-l12.reason-sys2k",
+     dict(nq=16, nkv=16, lanes=16, pages=128)),
 ])
 @pytest.mark.parametrize("prefill_rows", [0, 512])
 def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
@@ -137,7 +142,8 @@ def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
     and stores a zero row) compiles for a v5e at every cell's decode
     shape — 32 lanes x 4,096 at 8 and 4 kv heads; 64 lanes x 16,384
     with K stored at 256 lanes beside V at 128, a sink, the window of
-    128; 32 lanes x 32,768 of the latent kind — and what it prefetches
+    128; 32 lanes x 32,768 of the latent kind; 16 lanes x 4,096 at 16
+    kv heads of group 1 — and what it prefetches
     to SMEM stays under the 1 MiB bound."""
     lanes = shape["lanes"]
     shape = {**shape, "rows": prefill_rows + lanes,
