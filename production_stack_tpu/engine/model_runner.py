@@ -13,10 +13,11 @@ TPU without per-step recompilation):
   to the max bucket needed this step; a lane that holds no sequence ships
   context length 0, which the step programs read as "no row this step": the
   paged kernels walk nothing for it and give it a zero row, and its writes
-  land in the reserved trash slot 0 of the null block (a lane a device stop
-  froze mid-round is handed to attention the same way);
-- KV caches are donated into every step, so XLA performs scatter updates
-  in place in HBM (no cache copies).
+  name the reserved trash slot 0 of the null block: the XLA path's scatters
+  land there, the kernel path's cache write (ops/cache_write.py) skips the
+  row (a lane a device stop froze mid-round is handed on the same way);
+- KV caches are donated into every step, so the layers' writes happen in
+  place in HBM (no cache copies).
 
 The attention inner op is chosen at construction: the XLA gather path
 (ops/attention.py) everywhere, or the Pallas kernel on TPU.
@@ -36,6 +37,7 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.models import layer_groups, llama
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as xla_attn
+from production_stack_tpu.ops import cache_write
 from production_stack_tpu.parallel import sharding as sharding_rules
 from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
@@ -151,10 +153,12 @@ class ModelRunner:
             )
         elif mc.layer_groups:
             self._forward = functools.partial(
-                layer_groups.forward, block_size=config.block_size
+                layer_groups.forward, block_size=config.block_size,
+                write_kv=self._write_kv,
             )
         else:
-            self._forward = llama.forward
+            self._forward = functools.partial(
+                llama.forward, write_kv=self._write_kv)
 
         if params is None:
             # real checkpoints load from disk (local dir or HF cache);
@@ -872,6 +876,21 @@ class ModelRunner:
         if self.mesh is not None:
             return fns[1](q, kc, vc, layer, *args, mesh=self.mesh, **kw)
         return fns[0](q, kc, vc, layer, *args, **kw)
+
+    def _write_kv(self, kc, vc, l, write_slots, k, v):
+        """A layer's cache write (ops/cache_write.py), routed as `_attn`
+        routes its attention: the tile kernel where the walk runs, the
+        per-head scatters where the XLA path does (and under pipeline
+        parallelism, whose forward never comes here)."""
+        return cache_write.write_kv(
+            kc, vc, l, write_slots, k, v,
+            # under a tensor-parallel mesh the scatters stay: GSPMD
+            # partitions them over the kv-head-sharded cache itself, a
+            # pallas_call would want `_over_heads`' shard_map (no cell
+            # runs a mesh)
+            kernel=self.attention_impl == "pallas" and self.mesh is None,
+            interpret=jax.default_backend() != "tpu",
+        )
 
     def _xla_ctx(self, kc, vc, l, slots, spec):
         """The XLA path's gathered context of one layer, and the window

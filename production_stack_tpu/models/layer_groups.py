@@ -73,6 +73,8 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.ops.cache_write import plan_rows
+from production_stack_tpu.ops.cache_write import write_kv as scatter_kv
 from production_stack_tpu.ops.layers import (
     apply_rope,
     rms_norm,
@@ -287,8 +289,8 @@ def _latent_qkv(cfg, ak, x, lp, kc, l, write_slots, cos, sin, dtype):
 
 
 def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
-           write_slots, real, attn_fn, block_map, dtype, experts=None,
-           stack_index=None):
+           write_slots, real, attn_fn, write_kv, block_map, dtype,
+           experts=None, stack_index=None):
     """One layer of `kind` over n rows: llama.decoder_layer's shape
     (K/V written at `write_slots` BEFORE attn_fn runs) with this
     family's widths. `kc`/`vc` are the kind's own cache arrays (`vc`
@@ -320,18 +322,7 @@ def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
                 v = v * cfg.v_scale
             v = v.astype(dtype).reshape(n, nkv, dv)
             q, k = apply_rope(q, k, cos, sin)
-
-            # per-head plane scatters (see llama.decoder_layer for why).
-            # The cache may store K wider than d_k (zero lanes up to the
-            # kernel's 128-lane tile, model_runner): the pad is written
-            # with the row
-            kh = k.astype(kc.dtype).swapaxes(0, 1)  # (nkv, n, d_k)
-            if kc.shape[-1] > dk:
-                kh = jnp.pad(kh, ((0, 0), (0, 0), (0, kc.shape[-1] - dk)))
-            vh = v.astype(vc.dtype).swapaxes(0, 1)
-            for head in range(nkv):
-                kc = kc.at[l, head, write_slots].set(kh[head])
-                vc = vc.at[l, head, write_slots].set(vh[head])
+            kc, vc = write_kv(kc, vc, l, write_slots, k, v)
 
         spec = AttnSpec(
             window=ak.window,
@@ -404,6 +395,7 @@ def forward(
     return_hidden: bool = False,
     *,
     block_size: int,
+    write_kv=scatter_kv,    # the layers' cache write (ops/cache_write.py)
 ):
     """llama.forward's contract over a cache group per kind; returns
     (logits[r, V] fp32, k_cache, v_cache)."""
@@ -437,8 +429,12 @@ def forward(
         for lp_stack, (kind, routed, count, l0) in zip(
             params["segments"], cfg.segments()
         ):
-            windowed = cfg.attn_kinds[kind].window is not None
+            ak = cfg.attn_kinds[kind]
             cos, sin = rope[kind]
+            slots = mapped_slots if ak.window is not None else write_slots
+            if not ak.latent_dim:
+                # once a segment, not once a layer (`write_kv` reads it)
+                slots = plan_rows(slots, kg[kind])
 
             experts = None
             if routed:
@@ -447,14 +443,14 @@ def forward(
                             if n not in EXPERT_STACKS}
 
             def body(carry, xs, kind=kind, routed=routed, cos=cos,
-                     sin=sin, windowed=windowed, experts=experts):
+                     sin=sin, slots=slots, experts=experts):
                 h, kc, vc, st = carry
                 lp, l, c = xs
                 h, kc, vc, st = _layer(
                     cfg, kind, routed, h, kc, vc, st, lp, l,
-                    cos=cos, sin=sin,
-                    write_slots=mapped_slots if windowed else write_slots,
-                    real=real, attn_fn=attn_fn, block_map=block_map, dtype=dtype,
+                    cos=cos, sin=sin, write_slots=slots,
+                    real=real, attn_fn=attn_fn, write_kv=write_kv,
+                    block_map=block_map, dtype=dtype,
                     experts=experts, stack_index=c,
                 )
                 return (h, kc, vc, st), None

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.ops.cache_write import plan_rows
+from production_stack_tpu.ops.cache_write import write_kv as scatter_kv
 from production_stack_tpu.ops.layers import (
     apply_rope,
     rms_norm,
@@ -104,13 +106,14 @@ def decoder_layer(
     write_slots: jax.Array,
     attn_fn,
     dtype,
-    cache_dtype,
     lora_ctx: tuple | None = None,  # (lz, scaling, uniform, slots)
+    write_kv=scatter_kv,
 ):
     """One decoder layer over n token rows — the shared body of
     forward()'s layer scan and the pipeline-parallel phase loop
     (parallel/pp_serving.py). Writes the rows' K/V into the cache at
-    `write_slots` BEFORE attn_fn runs, so attention sees them."""
+    `write_slots` BEFORE attn_fn runs, so attention sees them
+    (`write_kv`: ops/cache_write.py, as the runner routes it)."""
     n = h.shape[0]
 
     def proj(x, target, base):
@@ -150,18 +153,7 @@ def decoder_layer(
     v = v.astype(dtype).reshape(n, cfg.num_kv_heads, cfg.head_dim)
     q, k = apply_rope(q, k, cos, sin)
 
-    # head-major cache writes, one scatter per kv head (nkv is tiny
-    # and static). The single fused scatter [l, :, write_slots] makes
-    # XLA prefer a slot-major physical layout for the cache inside
-    # the scan while the Pallas kernels constrain it row-major — XLA
-    # then inserts a FULL-CACHE layout copy per step (2 x 3.8 GiB on
-    # the 3B model; HBM OOM). Per-head 2D-plane scatters keep the
-    # default layout: AOT-verified 7.62 GiB -> 0 temp.
-    kh = k.astype(cache_dtype).swapaxes(0, 1)  # (nkv, n, d)
-    vh = v.astype(cache_dtype).swapaxes(0, 1)
-    for head in range(cfg.num_kv_heads):
-        kc = kc.at[l, head, write_slots].set(kh[head])
-        vc = vc.at[l, head, write_slots].set(vh[head])
+    kc, vc = write_kv(kc, vc, l, write_slots, k, v)
 
     def out_norm(y, name):
         # the "sandwich": a second norm on the sublayer's output
@@ -214,6 +206,7 @@ def forward(
     lora_slots: jax.Array | None = None,  # (n,) int32 adapter slot per token
     return_hidden: bool = False,  # final-norm hidden states instead of logits
     rows_valid: jax.Array | None = None,  # (r,) bool: logits rows that hold a token
+    write_kv=scatter_kv,  # the layers' cache write (ops/cache_write.py)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Run the decoder over n tokens; returns (logits[r, V] fp32, k_cache, v_cache).
 
@@ -236,7 +229,6 @@ def forward(
     if isinstance(k_cache, dict):
         stats, k_cache = k_cache["stats"], k_cache["c"]
     dtype = params["embed"].dtype
-    cache_dtype = k_cache.dtype
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
     h = params["embed"][token_ids].astype(dtype)
@@ -244,6 +236,9 @@ def forward(
         # Gemma normalizer: hidden states enter the stack scaled by
         # sqrt(hidden_size)
         h = (h.astype(jnp.float32) * cfg.embed_scale).astype(dtype)
+
+    # once a forward, not once a layer (the layers' writes read it)
+    write_slots = plan_rows(write_slots, k_cache)
 
     use_lora = lora is not None
     if use_lora:
@@ -269,7 +264,7 @@ def forward(
         h, kc, vc = decoder_layer(
             cfg, h, kc, vc, lp, l,
             cos=cos, sin=sin, write_slots=write_slots, attn_fn=attn_fn,
-            dtype=dtype, cache_dtype=cache_dtype, lora_ctx=lora_ctx,
+            dtype=dtype, lora_ctx=lora_ctx, write_kv=write_kv,
         )
         return (h, kc, vc), None
 

@@ -96,7 +96,6 @@ def forward_pp(
         raise NotImplementedError("LoRA under pipeline parallelism")
     S = mesh.shape[PP_AXIS]
     dtype = params["embed"].dtype
-    cache_dtype = k_cache.dtype
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
     h0 = params["embed"][token_ids].astype(dtype)
@@ -127,7 +126,7 @@ def forward_pp(
                 h, kc, vc = llama.decoder_layer(
                     cfg, h, kc, vc, lp, l,
                     cos=cos_, sin=sin_, write_slots=ws, attn_fn=attn_fn,
-                    dtype=dtype, cache_dtype=cache_dtype,
+                    dtype=dtype,
                 )
                 return (h, kc, vc), None
 

@@ -152,6 +152,49 @@ def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
     assert smem < SMEM_BYTES, (cell, smem)
 
 
+@pytest.mark.parametrize("cell, shape", [
+    # kv heads, lanes, K as stored, V: the decode rows of a step program
+    ("mistral-7b-l16.chat-sys2k + .batch-fewshot2k",
+     dict(nkv=8, lanes=32)),
+    ("qwen2-7b-l14.chat-sys2k", dict(nkv=4, lanes=32)),
+    ("mimo-v2.5-ep16-l7.batch-doc8k, window layers",
+     dict(nkv=8, lanes=64, d=256, d_v=128)),
+    ("mimo-v2.5-ep16-l7.batch-doc8k, full layers",
+     dict(nkv=4, lanes=64, d=256, d_v=128)),
+    # a row block's 16 tiles of 16 heads are 1 MiB an array in VMEM
+    ("ouro-2.6b-l12.reason-sys2k", dict(nkv=16, lanes=16)),
+    # (xing4-29b-l8.chat-doc16k: a latent kind's one row a layer stays
+    # one XLA scatter, layer_groups._latent_qkv)
+])
+@pytest.mark.parametrize("prefill_rows", [0, 512])
+def test_cache_write_compiles_at_the_cells_shapes(
+    v5e, cell, shape, prefill_rows
+):
+    """The tile kernel of ops/cache_write.py compiles for a v5e at
+    every cell's decode rows and at a lane-typed round's [prefill rows |
+    decode rows] (544 and 576: more than a row block, 528 a last block
+    that is not whole), with the caches donated and nothing of their
+    size among the temps (the kernel aliases them to its outputs)."""
+    from production_stack_tpu.ops import cache_write
+
+    nkv, d = shape["nkv"], shape.get("d", D)
+    rows = prefill_rows + shape["lanes"]
+    kc, vc = _cache(v5e, d, nkv), _cache(v5e, shape.get("d_v", d), nkv)
+
+    def write(kc, vc, l, slots, k, v):
+        return cache_write.write_kv(kc, vc, l, slots, k, v, kernel=True)
+
+    compiled = jax.jit(write, donate_argnums=(0, 1)).lower(
+        kc, vc, _spec(v5e, (), jnp.int32), _spec(v5e, (rows,), jnp.int32),
+        # mimo's K comes 192 wide and is padded to the stored 256
+        _spec(v5e, (rows, nkv, 192 if d == 256 else d), jnp.bfloat16),
+        _spec(v5e, (rows, nkv, vc.shape[-1]), jnp.bfloat16),
+    ).compile()
+    assert "kv_cache_write" in compiled.as_text()
+    one_cache = math.prod(vc.shape) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_cache / 4
+
+
 @pytest.mark.parametrize("rows", [32, 40, 544])
 def test_sinkhorn_kernel_compiles(v5e, rows):
     """The hyper-connections' Sinkhorn iterations as one Mosaic kernel
@@ -233,12 +276,17 @@ def test_sampler_window_stays_a_branch_on_the_chip(v5e):
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("looped", [False, True])
 def test_decode_multi_step_compiles_at_full_width_and_depth(
-    v5e, monkeypatch
+    v5e, monkeypatch, looped
 ):
     """One whole fused-K decode program of llama-3.2-3b, as the engine
     builds it for the chip: Mosaic kernels (not interpret), pinned cache
-    layout, donated caches. No full-cache copy may appear in its temps."""
+    layout, donated caches. No full-cache copy may appear in its temps.
+    `looped`: the same for a looped stack of 16 kv heads (ouro-2.6b's
+    widths, 12 layers run 4 times a token over 48 cache layers), whose
+    layers write the cache through the tile kernel of
+    ops/cache_write.py as every model's do."""
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.model_runner import ModelRunner
     from production_stack_tpu.models import config as mcfg
@@ -247,6 +295,13 @@ def test_decode_multi_step_compiles_at_full_width_and_depth(
     mc = dataclasses.replace(
         mcfg.get_model_config("llama-3.2-3b"), name="llama-3.2-3b-aot"
     )
+    if looped:
+        mc = dataclasses.replace(
+            mc, name="looped-16-heads-aot", hidden_size=2048,
+            intermediate_size=5632, num_heads=16, num_kv_heads=16,
+            num_layers=12, vocab_size=49152, tie_word_embeddings=False,
+            ut_steps=4, sandwich_norm=True, exit_gate=True,
+        )
     monkeypatch.setitem(mcfg._PRESETS, mc.name, mc)
     abstract = jax.eval_shape(
         lambda: llama.init_params(mc, jax.random.key(0), jnp.bfloat16)
@@ -274,6 +329,13 @@ def test_decode_multi_step_compiles_at_full_width_and_depth(
         on_chip(runner.v_cache), _spec(v5e, (packed_len,), jnp.int32),
     ).compile()
     cache_bytes = runner.k_cache.size * runner.k_cache.dtype.itemsize
-    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"temp_size_in_bytes {temp} of a cache array's {cache_bytes}")
+    assert temp < cache_bytes
+    text = compiled.as_text()
+    # one write kernel a layer body, and no scatter into a cache array
+    assert "kv_cache_write" in text
+    shape = "bf16[" + ",".join(map(str, runner.k_cache.shape)) + "]"
+    assert not re.search(re.escape(shape) + r"\S* fusion\(", text)
     # the sampler's window is a branch inside the scan's body too
-    _topk_branch(compiled.as_text())
+    _topk_branch(text)
