@@ -50,8 +50,11 @@ class AsyncLLMEngine:
         # round. These spans count that wait by site (seconds, count):
         # tpu:event_loop_lock_wait_seconds (all sites) and tpu:admit_
         # lock_wait_seconds (`generate` alone, once per request), and
-        # `server.<site>` in a profiler trace.
-        self.lock_waits = phases.PhaseTimer(phases.LOCK_WAITS, "server.")
+        # `server.<site>` in a profiler trace. The same timer holds the
+        # loop's side of a round's hand-over (`_deliver` here, the
+        # stream writers of server.py); the step thread's side is
+        # `lock_wait` and `deliver` of the engine's own timer.
+        self.loop_phases = phases.PhaseTimer(phases.LOOP_PHASES, "server.")
         self._wake = threading.Event()
         self._stopped = False
         self._thread = threading.Thread(
@@ -77,15 +80,18 @@ class AsyncLLMEngine:
     # -- step loop thread --------------------------------------------------
     def _step_loop(self) -> None:
         logger.info("engine step loop started")
+        engine_phases = self.engine.phases
         while not self._stopped:
             if self.sleeping:
                 self._wake.wait(timeout=0.1)
                 self._wake.clear()
                 continue
             try:
-                with self._lock:
+                with engine_phases.span("lock_wait") as wait, self._lock:
+                    wait.stop()  # held: what follows is the round
                     busy = self.engine.has_unfinished()
                     outputs = self.engine.step() if busy else []
+                t_fetched = engine_phases.ended("fetch")
             except Exception:  # noqa: BLE001 — a step failure must fail
                 # the in-flight REQUESTS, not the serving thread: a dead
                 # step loop wedges every current and future request
@@ -94,6 +100,7 @@ class AsyncLLMEngine:
                 )
                 outputs = self._fail_inflight()
                 busy = True
+                t_fetched = 0.0  # no round stands behind these outputs
                 # if the engine state is corrupt enough that aborts
                 # also fail, has_unfinished() can stay true forever —
                 # backoff bounds the retry/log rate instead of pegging
@@ -105,10 +112,12 @@ class AsyncLLMEngine:
                 # needed — this note is the audit trail)
                 time.sleep(0.5)
             if outputs and self._loop is not None:
-                with self.engine.phases.span("deliver"):
-                    self._loop.call_soon_threadsafe(self._deliver, outputs)
+                with engine_phases.span("deliver"):
+                    self._loop.call_soon_threadsafe(
+                        self._deliver, outputs, t_fetched,
+                        time.perf_counter())
             if not busy:
-                with self.engine.phases.span("idle"):
+                with engine_phases.span("idle"):
                     self._wake.wait(timeout=0.02)
                 self._wake.clear()
 
@@ -137,15 +146,26 @@ class AsyncLLMEngine:
                 ))
         return outs
 
-    def _deliver(self, outputs: list[RequestOutput]) -> None:
-        for out in outputs:
-            # stackcheck: disable=guarded-by-lock — loop-thread dict.get
-            # is GIL-atomic and _fail_inflight snapshots via list(); taking
-            # the lock here would stall delivery behind the next
-            # engine.step (the step thread holds it for the whole step)
-            q = self._streams.get(out.request_id)
-            if q is not None:
-                q.put_nowait(out)
+    def _deliver(self, outputs: list[RequestOutput], t_fetched: float,
+                 t_handed: float) -> None:
+        """On the event loop: a round's outputs onto their requests'
+        queues, each stamped with the `perf_counter()` reading that
+        closed the round's fetch (`t_fetched`). `t_handed` is the
+        reading the step thread took as it queued this callback: how
+        long a READY callback waited is how far the loop is behind."""
+        self.loop_phases.observe(
+            "deliver_pickup", time.perf_counter() - t_handed)
+        with self.loop_phases.span("deliver"):
+            for out in outputs:
+                out.t_fetched = t_fetched
+                # stackcheck: disable=guarded-by-lock — loop-thread
+                # dict.get is GIL-atomic and _fail_inflight snapshots
+                # via list(); taking the lock here would stall delivery
+                # behind the next engine.step (the step thread holds it
+                # for the whole step)
+                q = self._streams.get(out.request_id)
+                if q is not None:
+                    q.put_nowait(out)
 
     # -- request API -------------------------------------------------------
     async def generate(
@@ -167,20 +187,23 @@ class AsyncLLMEngine:
         q: asyncio.Queue[RequestOutput] = asyncio.Queue()
         finished = False
         try:
-            with self.lock_waits.span("admit_lock_wait") as wait, \
+            with self.loop_phases.span("admit_lock_wait") as wait, \
                     self._lock:
                 wait.stop()  # the wait is over: the lock is held
-                self._streams[request_id] = q
-                self.engine.add_request(
-                    request_id,
-                    prompt=prompt,
-                    prompt_token_ids=prompt_token_ids,
-                    sampling_params=sampling_params,
-                    arrival_time=time.time(),
-                    lora_name=lora_name,
-                    priority=priority,
-                    traceparent=traceparent,
-                )
+                # in a trace, the step thread's `engine.lock_wait`
+                # stands over this: an admission holds the lock
+                with phases.annotation("server.admit"):
+                    self._streams[request_id] = q
+                    self.engine.add_request(
+                        request_id,
+                        prompt=prompt,
+                        prompt_token_ids=prompt_token_ids,
+                        sampling_params=sampling_params,
+                        arrival_time=time.time(),
+                        lora_name=lora_name,
+                        priority=priority,
+                        traceparent=traceparent,
+                    )
             self._wake.set()
             while True:
                 out = await q.get()
@@ -195,13 +218,13 @@ class AsyncLLMEngine:
             # behind the step thread's full engine.step
             self._streams.pop(request_id, None)
             if not finished:
-                with self.lock_waits.span("abort_lock_wait") as wait, \
+                with self.loop_phases.span("abort_lock_wait") as wait, \
                         self._lock:
                     wait.stop()
                     self.engine.abort_request(request_id)
 
     async def abort(self, request_id: str) -> bool:
-        with self.lock_waits.span("abort_lock_wait") as wait, self._lock:
+        with self.loop_phases.span("abort_lock_wait") as wait, self._lock:
             wait.stop()
             return self.engine.abort_request(request_id)
 
@@ -213,10 +236,11 @@ class AsyncLLMEngine:
 
     # -- introspection -----------------------------------------------------
     def stats(self) -> EngineStatsSnapshot:
-        with self.lock_waits.span("stats_lock_wait") as wait, self._lock:
+        with self.loop_phases.span("stats_lock_wait") as wait, self._lock:
             wait.stop()
             snap = self.engine.stats()
-        snap.loop_lock_waits = self.lock_waits.pairs()
+        # the loop's own pairs, read by the loop thread itself: no lock
+        snap.loop_phases = self.loop_phases.pairs()
         return snap
 
     def drain_kv_observations(self) -> tuple[list[float], list[float]]:

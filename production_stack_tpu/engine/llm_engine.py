@@ -370,7 +370,6 @@ class LLMEngine:
         self._kv_restore_blocks_total = 0
         self._kv_restore_bytes_total = 0
         self._kv_restore_fallbacks_total = 0
-        self._kv_export_sync_fallbacks_total = 0
         # wall seconds spent in SYNCHRONOUS tier exports (backlog-cap
         # degradations + --sync-kv-offload): the overflow-export slice
         # of a long prefill's TTFT attribution reads the delta of this
@@ -484,9 +483,8 @@ class LLMEngine:
                 # buffers until the worker materializes it — under
                 # eviction churn faster than tier IO, HBM must not
                 # become the overflow buffer. Materialize THIS batch on
-                # the step thread (a bounded, counted stall — the old
+                # the step thread (a bounded stall — the old
                 # synchronous behavior) instead of growing the queue.
-                self._kv_export_sync_fallbacks_total += 1
                 self._export_sync(pending)
                 return True
             handle = self.runner.stage_export_blocks(bids)
@@ -3704,6 +3702,7 @@ class LLMEngine:
             spec_draft_tokens_total=self._spec_drafts_total,
             spec_accepted_tokens_total=self._spec_accepted_total,
             engine_phases=self.phases.pairs(),
+            engine_phases_offcpu=self.phases.offcpu_pairs(),
             attn_context_tokens=tuple(self.runner.attn_context_tokens),
             decode_lane_steps=tuple(self.runner.decode_lane_steps),
             sampler_steps=tuple(self.runner.sampler_steps),
@@ -3765,9 +3764,6 @@ class LLMEngine:
             kv_restore_blocks_total=self._kv_restore_blocks_total,
             kv_restore_bytes_total=self._kv_restore_bytes_total,
             kv_restore_fallbacks_total=self._kv_restore_fallbacks_total,
-            kv_export_sync_fallbacks_total=(
-                self._kv_export_sync_fallbacks_total
-            ),
             kv_tier_counters=(
                 self.offload.counters()
                 if self.offload is not None else {}

@@ -22,7 +22,20 @@ from prometheus_client import (
 from prometheus_client.core import SummaryMetricFamily
 
 from production_stack_tpu.engine.outputs import EngineStatsSnapshot
-from production_stack_tpu.tracing import ENGINE_PHASES
+from production_stack_tpu.tracing import (
+    ENGINE_PHASES,
+    HOST_PHASES,
+    LOCK_WAITS,
+)
+
+# the event loop's side of a round's hand-over: a name of
+# tracing.LOOP_PHASES -> the sample its (seconds, count) pair is
+LOOP_HANDOVER_SAMPLES = {
+    "deliver": "tpu:server_deliver_seconds",
+    "send": "tpu:server_send_seconds",
+    "deliver_pickup": "tpu:deliver_pickup_seconds",
+    "token_delivery": "tpu:token_delivery_seconds",
+}
 
 _LATENCY_BUCKETS = (
     0.01, 0.025, 0.05, 0.1, 0.2, 0.4, 0.8, 1.5, 3.0, 6.0, 12.0, 30.0, 60.0,
@@ -95,10 +108,6 @@ class EngineMetrics:
             "above 1 a caller hashes what another already did",
             label, registry=reg,
         )
-        # TPU-native aliases (the Grafana dashboard panels use either)
-        self.tpu_cache_usage = gauge(
-            "tpu:hbm_kv_cache_usage_perc", "KV-cache usage in TPU HBM"
-        )
         self.prompt_tokens = Counter(
             "vllm:prompt_tokens", "Prefill tokens processed",
             label, registry=reg,
@@ -133,6 +142,30 @@ class EngineMetrics:
                     "trace)"
                 for p in ENGINE_PHASES
             },
+            **{
+                f"tpu:engine_phase_{p}_offcpu_seconds":
+                    f"Of tpu:engine_phase_{p}_seconds, what the step "
+                    "thread did not run (wall less time.thread_time() "
+                    "over each span): it stood in the phase while "
+                    "another thread held the interpreter or a lock"
+                for p in HOST_PHASES
+            },
+            "tpu:server_deliver_seconds":
+                "Event loop: the callback that puts a round's outputs "
+                "on their requests' queues (`server.deliver` in a "
+                "profiler trace), once a round",
+            "tpu:server_send_seconds":
+                "Event loop: an output taken off its queue -> its "
+                "content chunk serialised and written to the socket "
+                "(`server.send`), per chunk",
+            "tpu:deliver_pickup_seconds":
+                "The step thread queued a round's delivery callback "
+                "-> the event loop ran it: how far the loop is behind, "
+                "seen once a round",
+            "tpu:token_delivery_seconds":
+                "The step thread's fetch of a round returned -> a "
+                "content chunk of that round was written to its "
+                "socket, per chunk",
             "tpu:event_loop_lock_wait_seconds":
                 "Time the server's event-loop thread spent acquiring "
                 "the engine lock (admission, abort, stats): every SSE "
@@ -301,18 +334,6 @@ class EngineMetrics:
             "tpu:kv_tier_hits", "KV tier read hits",
             tier_label, registry=reg,
         )
-        self.kv_tier_misses = Counter(
-            "tpu:kv_tier_misses", "KV tier read misses (consulted tier "
-            "did not hold the block)", tier_label, registry=reg,
-        )
-        self.kv_tier_read_bytes = Counter(
-            "tpu:kv_tier_read_bytes", "Bytes served from a KV tier",
-            tier_label, registry=reg,
-        )
-        self.kv_tier_write_bytes = Counter(
-            "tpu:kv_tier_write_bytes", "Bytes admitted into a KV tier",
-            tier_label, registry=reg,
-        )
         self.kv_export_blocks = Counter(
             "tpu:kv_export_blocks", "KV blocks exported to the offload "
             "tiers", label, registry=reg,
@@ -324,12 +345,6 @@ class EngineMetrics:
         self.kv_restore_fallbacks = Counter(
             "tpu:kv_restore_fallbacks", "Staged restores that fell back "
             "to recompute (broken chain, timeout, or full HBM)",
-            label, registry=reg,
-        )
-        self.kv_export_sync_fallbacks = Counter(
-            "tpu:kv_export_sync_fallbacks",
-            "Deferred exports forced synchronous by the device-buffer "
-            "backlog cap (tier IO slower than eviction churn)",
             label, registry=reg,
         )
         # disaggregated prefill/decode transfer (PeerTier pulls):
@@ -545,7 +560,6 @@ class EngineMetrics:
         self.num_running.labels(m).set(s.num_running)
         self.num_waiting.labels(m).set(s.num_waiting)
         self.cache_usage.labels(m).set(s.kv_usage)
-        self.tpu_cache_usage.labels(m).set(s.kv_usage)
         self.prefix_hit_rate.labels(m).set(s.prefix_cache_hit_rate)
         self.prefix_hits.labels(m).set(s.prefix_cache_hits)
         self.prefix_queries.labels(m).set(s.prefix_cache_queries)
@@ -572,13 +586,17 @@ class EngineMetrics:
         )
         for name, pair in s.engine_phases.items():
             self.pairs.set(f"tpu:engine_phase_{name}_seconds", pair)
-        if s.loop_lock_waits:
-            waits = s.loop_lock_waits
-            self.pairs.set("tpu:event_loop_lock_wait_seconds", (
-                sum(p[0] for p in waits.values()),
-                sum(p[1] for p in waits.values())))
+        for name, pair in s.engine_phases_offcpu.items():
             self.pairs.set(
-                "tpu:admit_lock_wait_seconds", waits["admit_lock_wait"])
+                f"tpu:engine_phase_{name}_offcpu_seconds", pair)
+        if s.loop_phases:
+            waits = [s.loop_phases[n] for n in LOCK_WAITS]
+            self.pairs.set("tpu:event_loop_lock_wait_seconds", (
+                sum(p[0] for p in waits), sum(p[1] for p in waits)))
+            self.pairs.set("tpu:admit_lock_wait_seconds",
+                           s.loop_phases["admit_lock_wait"])
+            for name, sample in LOOP_HANDOVER_SAMPLES.items():
+                self.pairs.set(sample, s.loop_phases[name])
         self.pairs.set("tpu:attn_context_tokens", s.attn_context_tokens)
         for kind, tokens in s.attn_context_by_kind.items():
             self.attn_context_kind[kind].labels(m).inc(max(
@@ -667,9 +685,6 @@ class EngineMetrics:
         self.kv_restore_fallbacks.labels(m).inc(max(
             0, s.kv_restore_fallbacks_total
             - prev.kv_restore_fallbacks_total))
-        self.kv_export_sync_fallbacks.labels(m).inc(max(
-            0, s.kv_export_sync_fallbacks_total
-            - prev.kv_export_sync_fallbacks_total))
         self.kv_peer_hits.labels(m).inc(max(
             0, s.kv_peer_hits_total - prev.kv_peer_hits_total))
         self.kv_peer_misses.labels(m).inc(max(
@@ -700,13 +715,6 @@ class EngineMetrics:
             pc = (prev.kv_tier_counters or {}).get(tier, {})
             self.kv_tier_hits.labels(m, tier).inc(
                 max(0, c.get("hits", 0) - pc.get("hits", 0)))
-            self.kv_tier_misses.labels(m, tier).inc(
-                max(0, c.get("misses", 0) - pc.get("misses", 0)))
-            self.kv_tier_read_bytes.labels(m, tier).inc(
-                max(0, c.get("read_bytes", 0) - pc.get("read_bytes", 0)))
-            self.kv_tier_write_bytes.labels(m, tier).inc(
-                max(0, c.get("write_bytes", 0)
-                    - pc.get("write_bytes", 0)))
         self._counter_state = s
 
     def observe_kv(

@@ -322,8 +322,12 @@ class ModelRunner:
         # overlap compute but still count — they are real link work) and
         # dispatch = the jitted call's enqueue, once per step program
         # dispatched; the engine times schedule, fetch and apply on the
-        # same timer
-        self.phases = phases.PhaseTimer(phases.ENGINE_PHASES, "engine.")
+        # same timer. The phases that are host work also keep the
+        # seconds the step thread stood in them without running
+        # (tpu:engine_phase_*_offcpu_seconds): the interpreter was
+        # another thread's
+        self.phases = phases.PhaseTimer(
+            phases.ENGINE_PHASES, "engine.", offcpu=phases.HOST_PHASES)
         # context tokens the attention calls of the dispatched rounds
         # had to read once, and the rounds counted (tpu:attn_context_
         # tokens): host integers the dispatch already holds

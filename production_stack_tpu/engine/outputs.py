@@ -23,6 +23,11 @@ class RequestOutput:
     finish_reason: str | None
     metrics: RequestMetrics
     num_cached_tokens: int = 0
+    # time.perf_counter() reading that closed the step thread's fetch
+    # of the round this output came from, written by AsyncLLMEngine.
+    # _deliver; 0.0 where no round was fetched (an abort, an engine
+    # driven without the server) — tpu:token_delivery_seconds
+    t_fetched: float = 0.0
     # per-token logprob entries (only when SamplingParams.logprobs set):
     # {"token_id", "logprob", "top_logprobs": [{"token_id", "logprob"}]}
     logprobs: list[dict] | None = None  # all tokens so far
@@ -55,14 +60,19 @@ class EngineStatsSnapshot:
     spec_draft_tokens_total: int = 0
     spec_accepted_tokens_total: int = 0
     # the round seen from inside (tracing/phases.py), each a (seconds,
-    # count) pair: the step thread's phases (schedule, pack, h2d,
-    # dispatch, fetch, apply, idle, deliver) — tpu:engine_phase_*_seconds
-    # in /metrics — and the event-loop thread's waits for the engine
-    # lock by site (filled in by AsyncLLMEngine.stats) —
-    # tpu:event_loop_lock_wait_seconds,
-    # tpu:admit_lock_wait_seconds
+    # count) pair: the step thread's phases (tracing.ENGINE_PHASES) —
+    # tpu:engine_phase_*_seconds in /metrics; of the host-work ones
+    # (tracing.HOST_PHASES) the seconds the thread did not run —
+    # tpu:engine_phase_*_offcpu_seconds; and the event-loop thread's
+    # side (tracing.LOOP_PHASES, filled in by AsyncLLMEngine.stats
+    # after the engine lock is let go): its waits for that lock by site
+    # — tpu:event_loop_lock_wait_seconds, tpu:admit_lock_wait_seconds —
+    # and a round's hand-over — tpu:deliver_pickup_seconds,
+    # tpu:server_deliver_seconds, tpu:server_send_seconds,
+    # tpu:token_delivery_seconds
     engine_phases: dict = field(default_factory=dict)
-    loop_lock_waits: dict = field(default_factory=dict)
+    engine_phases_offcpu: dict = field(default_factory=dict)
+    loop_phases: dict = field(default_factory=dict)
     # (tokens, rounds): context tokens the attention calls of the
     # dispatched rounds had to read once — tpu:attn_context_tokens
     attn_context_tokens: tuple = (0, 0)
@@ -158,9 +168,6 @@ class EngineStatsSnapshot:
     kv_restore_blocks_total: int = 0
     kv_restore_bytes_total: int = 0
     kv_restore_fallbacks_total: int = 0
-    # deferred exports forced synchronous by the device-buffer backlog
-    # cap (slow tier backpressure — see LLMEngine.KV_EXPORT_BACKLOG_CAP)
-    kv_export_sync_fallbacks_total: int = 0
     # tier name -> {hits, misses, read_bytes, write_bytes}
     kv_tier_counters: dict = field(default_factory=dict)
     # disaggregated-prefill peer pulls (PeerTier): blocks served by /

@@ -224,13 +224,22 @@ class EngineServer:
                 status=400,
             )
 
-    def _observe_first_chunk(self, request: web.Request) -> None:
-        """tpu:server_ttft_seconds, once per streamed request: handler
-        entry -> its first content chunk written."""
+    def _observe_content_chunk(
+        self, request: web.Request, t_fetched: float
+    ) -> None:
+        """A content chunk was written. tpu:token_delivery_seconds,
+        per chunk: the step thread's fetch of the chunk's round
+        (`RequestOutput.t_fetched`; 0.0 = no round behind it) -> now.
+        tpu:server_ttft_seconds, once per streamed request: handler
+        entry -> now."""
+        now = time.perf_counter()
+        if t_fetched:
+            self.engine.loop_phases.observe(
+                "token_delivery", now - t_fetched)
         t_enter = request.pop("t_enter", None)
         if t_enter is not None:
             self.metrics.server_ttft.labels(self.model_name).observe(
-                time.perf_counter() - t_enter
+                now - t_enter
             )
 
     def _observe_finish(self, out, arrival: float) -> None:
@@ -823,7 +832,8 @@ class EngineServer:
                     if out.delta_text or out.new_logprobs:
                         await queue.put((
                             "delta", idx,
-                            (out.delta_text, out.new_logprobs),
+                            (out.delta_text, out.new_logprobs,
+                             out.t_fetched),
                         ))
                 await queue.put(("finish", idx, final))
             except Exception as e:  # noqa: BLE001 — surfaced as a chunk
@@ -859,13 +869,14 @@ class EngineServer:
             while remaining:
                 kind, idx, payload = await queue.get()
                 if kind == "delta":
-                    text, new_lps = payload
-                    chunk, lp_pos[idx] = self._stream_chunk(
-                        request_id, model, chat, text, new_lps, idx,
-                        lp_pos.get(idx, 0),
-                    )
-                    await send(chunk)
-                    self._observe_first_chunk(request)
+                    text, new_lps, t_fetched = payload
+                    with self.engine.loop_phases.span("send"):
+                        chunk, lp_pos[idx] = self._stream_chunk(
+                            request_id, model, chat, text, new_lps, idx,
+                            lp_pos.get(idx, 0),
+                        )
+                        await send(chunk)
+                    self._observe_content_chunk(request, t_fetched)
                 elif kind == "finish":
                     remaining -= 1
                     if payload is not None:
@@ -935,12 +946,13 @@ class EngineServer:
             ):
                 final = out
                 if out.delta_text or out.new_logprobs:
-                    chunk, lp_pos = self._stream_chunk(
-                        request_id, model, chat, out.delta_text,
-                        out.new_logprobs, 0, lp_pos,
-                    )
-                    await send(chunk)
-                    self._observe_first_chunk(request)
+                    with self.engine.loop_phases.span("send"):
+                        chunk, lp_pos = self._stream_chunk(
+                            request_id, model, chat, out.delta_text,
+                            out.new_logprobs, 0, lp_pos,
+                        )
+                        await send(chunk)
+                    self._observe_content_chunk(request, out.t_fetched)
             if final is not None:
                 self._observe_finish(final, arrival)
                 if chat:

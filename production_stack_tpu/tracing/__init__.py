@@ -44,17 +44,34 @@ from production_stack_tpu.tracing.timeline import (
     debug_requests_payload,
 )
 
-# The step thread's phases, in the order of a round; `idle` and
-# `deliver` belong to AsyncLLMEngine._step_loop, outside `engine.step`.
+# The step thread's phases, in the order of a round; `idle`, `deliver`
+# and `lock_wait` (its acquire of AsyncLLMEngine._lock) belong to
+# AsyncLLMEngine._step_loop, outside `engine.step`.
 ENGINE_PHASES = (
     "schedule", "pack", "h2d", "dispatch", "fetch", "apply", "idle",
-    "deliver",
+    "deliver", "lock_wait",
+)
+# Those that are host work: what a span of one did not RUN (wall less
+# the thread's CPU time) is the interpreter's or a lock's, not the
+# phase's. `fetch`, `idle` and `lock_wait` are waits by design.
+HOST_PHASES = ("schedule", "pack", "h2d", "dispatch", "apply", "deliver")
+# The event-loop thread's waits for AsyncLLMEngine._lock, by site, and
+# its side of a round's hand-over: spans `deliver` (the callback that
+# queues a round's outputs) and `send` (an output taken off its queue
+# -> its chunk written), and the pairs `deliver_pickup` (callback
+# queued -> run) and `token_delivery` (round fetched -> chunk written).
+LOCK_WAITS = ("admit_lock_wait", "abort_lock_wait", "stats_lock_wait")
+LOOP_PHASES = LOCK_WAITS + (
+    "deliver", "send", "deliver_pickup", "token_delivery",
 )
 
 __all__ = [
     "DECODE_EVENT_EVERY",
     "ENGINE_PHASES",
     "EXPORTERS",
+    "HOST_PHASES",
+    "LOCK_WAITS",
+    "LOOP_PHASES",
     "NULL_RECORDER",
     "OTLP_FLUSH_INTERVAL_S",
     "REQUEST_ID_HEADER",

@@ -12,10 +12,18 @@ One helper for every boundary of the round. ``with timer.span("pack"):``
   trace can name an idle gap of the device by what the host was doing.
 
 With no session active a span costs one atomic check in the profiler
-(``TraceAnnotation.is_enabled``) and two clock reads: no annotation
-object is made, no name or attribute is formatted. Callers on the
+(``TraceAnnotation.is_enabled``) and two clock reads (two more, of the
+thread's CPU clock, for a phase that keeps its off-CPU time, below): no
+annotation object is made, no name or attribute is formatted. Callers on the
 round's path hand an annotation only integers they already hold, behind
 ``if phases.profiling():``.
+
+A wall-clock span cannot tell work from waiting: where another thread
+holds the interpreter, a phase's seconds grow though the phase did
+nothing more. A timer built with ``offcpu=`` names therefore also reads
+``time.thread_time()`` at both ends of a span of those names and adds
+wall less CPU to a third number of the phase: the seconds the thread
+stood inside the phase without running.
 
 The engine's phases are leaves of one ``engine.step`` annotation per
 ``LLMEngine.step`` call and are kept to a few milliseconds each: a
@@ -33,10 +41,12 @@ import time
 from jax import monitoring
 from jax.profiler import TraceAnnotation
 
-from production_stack_tpu.tracing import ENGINE_PHASES  # noqa: F401
-
-# the event-loop thread's waits for AsyncLLMEngine._lock, by site
-LOCK_WAITS = ("admit_lock_wait", "abort_lock_wait", "stats_lock_wait")
+from production_stack_tpu.tracing import (  # noqa: F401
+    ENGINE_PHASES,
+    HOST_PHASES,
+    LOCK_WAITS,
+    LOOP_PHASES,
+)
 
 # one atomic load in the profiler's C++; True only inside a session
 profiling = TraceAnnotation.is_enabled
@@ -86,11 +96,12 @@ def annotation(name: str, **attrs):
 
 
 class _Span:
-    __slots__ = ("_cell", "_label", "_t0", "_ann")
+    __slots__ = ("_cell", "_label", "_offcpu", "_t0", "_c0", "_ann")
 
-    def __init__(self, cell: list, label: str):
+    def __init__(self, cell: list, label: str, offcpu: bool):
         self._cell = cell
         self._label = label
+        self._offcpu = offcpu
         self._ann = None
 
     def __enter__(self):
@@ -98,6 +109,12 @@ class _Span:
             self._ann = TraceAnnotation(self._label)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
+        if self._offcpu:
+            # inside the wall-clock reads: the CPU interval lies within
+            # the wall interval. They are two clocks all the same: a
+            # phase that never left the CPU reads within a few percent
+            # of its wall seconds of 0, on either side
+            self._c0 = time.thread_time()
         return self
 
     def stop(self) -> None:
@@ -107,8 +124,14 @@ class _Span:
         if self._cell is None:
             return
         cell, self._cell = self._cell, None
-        cell[0] += time.perf_counter() - self._t0
+        if self._offcpu:
+            cpu = time.thread_time() - self._c0
+        end = cell[3] = time.perf_counter()
+        wall = end - self._t0
+        cell[0] += wall
         cell[1] += 1
+        if self._offcpu:
+            cell[2] += wall - cpu
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
 
@@ -118,16 +141,35 @@ class _Span:
 
 
 class PhaseTimer:
-    """(seconds, count) per phase name, fed by `span`. Each name is
-    written by one thread; readers on other threads take the pair as it
-    stands (the pair of one name may be one observation apart)."""
+    """(seconds, count) per phase name, fed by `span` (or `observe`,
+    for a duration the caller took itself). Each name is written by one
+    thread; readers on other threads take the pair as it stands (the
+    pair of one name may be one observation apart). For the names in
+    `offcpu` a span also costs two `time.thread_time()` reads and keeps
+    the seconds its thread did not run."""
 
-    def __init__(self, names: tuple[str, ...], prefix: str):
-        self.totals: dict[str, list] = {n: [0.0, 0] for n in names}
+    def __init__(self, names: tuple[str, ...], prefix: str,
+                 offcpu: tuple[str, ...] = ()):
+        # [seconds, count, seconds off the CPU, perf_counter() reading
+        # that closed the last span]
+        self.totals: dict[str, list] = {
+            n: [0.0, 0, 0.0, 0.0] for n in names}
         self._labels = {n: prefix + n for n in names}
+        self._offcpu = frozenset(offcpu)
 
     def span(self, name: str) -> _Span:
-        return _Span(self.totals[name], self._labels[name])
+        return _Span(self.totals[name], self._labels[name],
+                     name in self._offcpu)
+
+    def observe(self, name: str, seconds: float) -> None:
+        cell = self.totals[name]
+        cell[0] += seconds
+        cell[1] += 1
+
+    def ended(self, name: str) -> float:
+        """The `time.perf_counter()` reading that closed the last span
+        of `name` (0.0 before the first)."""
+        return self.totals[name][3]
 
     def seconds(self) -> dict[str, float]:
         return {n: c[0] for n, c in self.totals.items()}
@@ -137,6 +179,11 @@ class PhaseTimer:
 
     def pairs(self) -> dict[str, tuple[float, int]]:
         return {n: (c[0], c[1]) for n, c in self.totals.items()}
+
+    def offcpu_pairs(self) -> dict[str, tuple[float, int]]:
+        """(seconds not run, spans) of the phases that measure it."""
+        return {n: (self.totals[n][2], self.totals[n][1])
+                for n in self.totals if n in self._offcpu}
 
     def delta(self, since: dict[str, float]) -> dict[str, float]:
         """Seconds spent per phase since `since` (a `seconds()` copy),

@@ -7,8 +7,10 @@ import asyncio
 import glob
 import inspect
 import os
+import json
 import re
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +24,12 @@ from production_stack_tpu.engine.llm_engine import LLMEngine
 from production_stack_tpu.engine.metrics import EngineMetrics
 from production_stack_tpu.engine.sampling_params import SamplingParams
 from production_stack_tpu.ops import pallas_attention
-from production_stack_tpu.tracing import ENGINE_PHASES, phases
+from production_stack_tpu.tracing import ENGINE_PHASES, HOST_PHASES, phases
 
 STEP_PHASES = ("schedule", "pack", "h2d", "dispatch", "fetch", "apply")
+# the hand-over's annotations: the step thread's, the event loop's
+HANDOVER_SPANS = ("engine.lock_wait", "server.admit", "server.deliver",
+                  "server.send")
 
 
 def cfg(**overrides) -> EngineConfig:
@@ -70,12 +75,12 @@ def host_events(trace_dir):
     for plane in ProfileData.from_file(path).planes:
         if not plane.name.startswith("/host:"):
             continue
-        for line in plane.lines:
+        for i, line in enumerate(plane.lines):      # a line is a thread
             for ev in line.events:
                 if ev.name.startswith(("engine.", "server.")):
                     out.append((ev.name, int(ev.start_ns),
                                 int(ev.start_ns + ev.duration_ns),
-                                dict(ev.stats)))
+                                dict(ev.stats), (plane.name, i)))
     return out
 
 
@@ -99,15 +104,16 @@ def test_phases_are_leaves_of_engine_step_in_the_profilers_trace(tmp_path):
     assert {"engine." + p for p in STEP_PHASES} <= names
     assert "engine.build" in names          # cold: every program built
     # every phase lies inside one engine.step; a build inside a dispatch
-    for name, s, t, _ in events:
+    for name, s, t, _, _ in events:
         if name in ("engine.step", "engine.idle", "engine.deliver"):
             continue
-        assert any(s0 <= s and t <= t1 for _, s0, t1, _ in steps), name
+        assert any(s0 <= s and t <= t1 for _, s0, t1, _, _ in steps), name
     dispatches = [ev for ev in events if ev[0] == "engine.dispatch"]
-    for name, s, t, stats in events:
+    for name, s, t, stats, _ in events:
         if name == "engine.build":
             assert stats["kind"] in model_runner.PROGRAM_KINDS
-            assert any(s0 <= s and t <= t1 for _, s0, t1, _ in dispatches)
+            assert any(s0 <= s and t <= t1
+                       for _, s0, t1, _, _ in dispatches)
     # engine.step carries the round's number and kind
     tagged = [st[3] for st in steps if "round" in st[3]]
     assert len(tagged) == len(steps)
@@ -162,6 +168,20 @@ def test_no_profiler_session_no_annotation_object(monkeypatch):
     assert phases.annotation("engine.build", kind="x") is phases.NO_SPAN
     counts = e.phases.counts()
     assert all(counts[p] > 0 for p in STEP_PHASES)
+
+    # nor at the sites of the hand-over: the step loop's wait for the
+    # lock, the admission under it, the loop's delivery, a chunk's send
+    async def body(srv, client):
+        chunks = await stream_chunks(client, "/v1/completions")
+        assert len(chunks) >= 2
+        step, loop = srv.engine.engine.phases, srv.engine.loop_phases
+        assert step.counts()["lock_wait"] > 0
+        assert loop.counts()["deliver"] > 0
+        assert loop.counts()["send"] == len(chunks)
+        assert loop.counts()["admit_lock_wait"] == 1
+
+    with_server(body)
+    assert made == []
 
 
 # -- (b) names -------------------------------------------------------------
@@ -363,72 +383,326 @@ def _benchmark_samples():
     return names
 
 
-def test_server_exposes_what_the_benchmark_reads_and_counts_lock_waits():
+async def scrape(client):
+    text = await (await client.get("/metrics")).text()
+    out = {}
+    for line in text.splitlines():
+        if line and line[0] != "#":
+            head, _, value = line.rpartition(" ")
+            out[head.partition("{")[0]] = float(value)
+    return out
+
+
+def with_server(body, timeout=240.0):
+    """`await body(srv, client)` against an EngineServer at the tiny
+    widths, within `timeout` seconds, compiles included."""
     from aiohttp.test_utils import TestClient, TestServer
 
     from production_stack_tpu.engine.server import EngineServer
-
-    async def scrape(client):
-        text = await (await client.get("/metrics")).text()
-        out = {}
-        for line in text.splitlines():
-            if line and line[0] != "#":
-                head, _, value = line.rpartition(" ")
-                out[head.partition("{")[0]] = float(value)
-        return out
 
     async def run():
         srv = EngineServer(cfg(num_kv_blocks=64, max_num_seqs=2))
         client = TestClient(TestServer(srv.app))
         await client.start_server()
         try:
-            before = await scrape(client)
-            assert not [n for n in before if re.match(
-                r"tpu:prefill_(prep|h2d|dispatch|fetch)_seconds", n)]
-
-            async def stream():
-                r = await client.post("/v1/completions", json={
-                    "prompt": "hello there", "max_tokens": 6,
-                    "temperature": 0, "ignore_eos": True,
-                    # a chunk per token whatever the byte tokenizer
-                    # renders of a random model's ids
-                    "logprobs": 1, "stream": True})
-                assert r.status == 200
-                return await r.text()
-
-            # hold the engine lock while a request arrives: its wait is
-            # the admission's, on the event loop
-            lock = srv.engine._lock
-            await asyncio.get_running_loop().run_in_executor(
-                None, lock.acquire)
-            # released from another thread: the loop itself will be
-            # standing in the acquire
-            threading.Timer(0.25, lock.release).start()
-            body = await stream()
-            assert "[DONE]" in body
-            after = await scrape(client)
-            wanted = _benchmark_samples()
-            assert len(wanted) >= 14
-            assert wanted <= set(after), sorted(wanted - set(after))
-            d = {k: after[k] - before.get(k, 0.0) for k in after}
-            assert d["tpu:admit_lock_wait_seconds_count"] == 1
-            assert d["tpu:admit_lock_wait_seconds_sum"] >= 0.2
-            assert (d["tpu:event_loop_lock_wait_seconds_sum"]
-                    >= d["tpu:admit_lock_wait_seconds_sum"])
-            assert d["tpu:event_loop_lock_wait_seconds_count"] >= 2
-            assert d["tpu:server_ttft_seconds_count"] == 1
-            assert d["tpu:server_ttft_seconds_sum"] >= 0.2
-            # one dispatch observation per step program dispatched, one
-            # attention-context observation per round
-            rounds = d["tpu:engine_phase_dispatch_seconds_count"]
-            assert rounds >= 2
-            assert d["tpu:attn_context_tokens_count"] == rounds
-            assert d["tpu:attn_context_tokens_sum"] > 0
-            for p in STEP_PHASES:
-                assert d[f"tpu:engine_phase_{p}_seconds_count"] > 0, p
-            assert after["tpu:program_trace_seconds_sum"] > 0
-            assert after["tpu:program_compile_seconds_count"] > 0
+            await asyncio.wait_for(body(srv, client), timeout)
         finally:
             await client.close()
 
     asyncio.run(run())
+
+
+async def stream_chunks(client, path, **extra):
+    """One streamed request of 6 tokens; its CONTENT chunks (those
+    `EngineServer._stream_chunk` made) as the client got them."""
+    chat = path.endswith("/chat/completions")
+    body = {"max_tokens": 6, "temperature": 0, "ignore_eos": True,
+            "stream": True, **extra}
+    if chat:
+        # a chunk per engine output whatever the byte tokenizer renders
+        # of a random model's ids
+        body.update(messages=[{"role": "user", "content": "hello there"}],
+                    logprobs=True, top_logprobs=1)
+    else:
+        body.update(prompt="hello there", logprobs=1)
+    r = await client.post(path, json=body)
+    assert r.status == 200
+    text = await r.text()
+    assert "[DONE]" in text
+    chunks = [json.loads(line[6:]) for line in text.splitlines()
+              if line.startswith("data: {")]
+    if chat:
+        return [c for c in chunks
+                if "content" in c["choices"][0]["delta"]]
+    return [c for c in chunks if c["choices"][0]["finish_reason"] is None]
+
+
+def test_server_exposes_what_the_benchmark_reads_and_counts_lock_waits():
+    async def run(srv, client):
+        before = await scrape(client)
+        assert not [n for n in before if re.match(
+            r"tpu:prefill_(prep|h2d|dispatch|fetch)_seconds", n)]
+
+        # hold the engine lock while a request arrives: its wait is
+        # the admission's, on the event loop
+        lock = srv.engine._lock
+        await asyncio.get_running_loop().run_in_executor(
+            None, lock.acquire)
+        # released from another thread: the loop itself will be
+        # standing in the acquire
+        threading.Timer(0.25, lock.release).start()
+        assert await stream_chunks(client, "/v1/completions")
+        after = await scrape(client)
+        wanted = _benchmark_samples()
+        assert len(wanted) >= 14
+        assert wanted <= set(after), sorted(wanted - set(after))
+        d = {k: after[k] - before.get(k, 0.0) for k in after}
+        assert d["tpu:admit_lock_wait_seconds_count"] == 1
+        assert d["tpu:admit_lock_wait_seconds_sum"] >= 0.2
+        assert (d["tpu:event_loop_lock_wait_seconds_sum"]
+                >= d["tpu:admit_lock_wait_seconds_sum"])
+        assert d["tpu:event_loop_lock_wait_seconds_count"] >= 2
+        assert d["tpu:server_ttft_seconds_count"] == 1
+        assert d["tpu:server_ttft_seconds_sum"] >= 0.2
+        # one dispatch observation per step program dispatched, one
+        # attention-context observation per round
+        rounds = d["tpu:engine_phase_dispatch_seconds_count"]
+        assert rounds >= 2
+        assert d["tpu:attn_context_tokens_count"] == rounds
+        assert d["tpu:attn_context_tokens_sum"] > 0
+        for p in STEP_PHASES:
+            assert d[f"tpu:engine_phase_{p}_seconds_count"] > 0, p
+        assert after["tpu:program_trace_seconds_sum"] > 0
+        assert after["tpu:program_compile_seconds_count"] > 0
+
+    with_server(run)
+
+
+# -- (d) the hand-over between the step thread and the event loop ------------
+def test_offcpu_tells_a_phase_that_waits_from_one_that_works():
+    """Wall less `time.thread_time()`: a phase that sleeps 20 ms stood
+    20 ms without running, one that spins 20 ms ran them; a name
+    outside `offcpu=` pays for no second clock and keeps none."""
+    def spin_offcpu():
+        timer = phases.PhaseTimer(("spins",), "t.", offcpu=("spins",))
+        with timer.span("spins"):
+            until = time.perf_counter() + 0.02
+            while time.perf_counter() < until:
+                pass
+        (wall, n), (off, m) = (timer.pairs()["spins"],
+                               timer.offcpu_pairs()["spins"])
+        # two clocks: a span that never left the CPU reads near 0,
+        # on either side
+        assert n == m == 1 and wall >= 0.02 and -0.002 <= off <= wall
+        return off
+
+    # a spin the machine's other work pre-empts reads high: best of 5
+    assert min(spin_offcpu() for _ in range(5)) < 0.005
+    timer = phases.PhaseTimer(("sleeps", "plain"), "t.", offcpu=("sleeps",))
+    with timer.span("sleeps"):
+        time.sleep(0.02)
+    with timer.span("plain"):
+        time.sleep(0.001)
+    assert set(timer.offcpu_pairs()) == {"sleeps"}
+    wall, n = timer.pairs()["sleeps"]
+    off, m = timer.offcpu_pairs()["sleeps"]
+    assert n == m == 1 and 0.018 <= off <= wall < 0.2
+    assert timer.totals["plain"][2] == 0.0
+    assert timer.ended("plain") >= timer.ended("sleeps") > 0.0
+    timer.observe("plain", 0.5)
+    assert timer.pairs()["plain"] == (pytest.approx(0.5, abs=0.1), 2)
+    # the engine's timer measures it for the host-work phases alone
+    e = LLMEngine(cfg())
+    e.add_request("a", prompt_token_ids=prompt(10),
+                  sampling_params=greedy(5))
+    drive(e)
+    snap = e.stats()
+    assert set(snap.engine_phases_offcpu) == set(HOST_PHASES)
+    assert not set(HOST_PHASES) & {"fetch", "idle", "lock_wait"}
+    for p, (off, n) in snap.engine_phases_offcpu.items():
+        wall, count = snap.engine_phases[p]
+        assert n == count and -0.1 * wall - 1e-4 <= off <= wall, p
+    reg = CollectorRegistry()
+    metrics = EngineMetrics("m", registry=reg)
+    metrics.update_from_snapshot(snap)
+    text = generate_latest(reg).decode()
+    for p in ENGINE_PHASES:
+        exported = f"tpu:engine_phase_{p}_offcpu_seconds_sum" in text
+        assert exported == (p in HOST_PHASES), p
+
+
+def test_lock_wait_counts_what_another_thread_held_the_lock():
+    """`engine.lock_wait`: the step thread's acquire of `_lock`. ~0 while
+    nobody else wants the lock; at least the 50 ms of a 100 ms hold by
+    another thread (the idle step loop is back at the lock within 20)."""
+    from production_stack_tpu.engine.async_engine import AsyncLLMEngine
+
+    async def run():
+        eng = AsyncLLMEngine(cfg())
+        eng.start(asyncio.get_running_loop())
+        try:
+            timer = eng.engine.phases
+            await asyncio.sleep(0.2)
+            s0, n0 = timer.pairs()["lock_wait"]
+            assert n0 >= 3 and s0 < 0.02
+
+            def hold():
+                with eng._lock:
+                    time.sleep(0.1)
+
+            await asyncio.wait_for(
+                asyncio.get_running_loop().run_in_executor(None, hold), 10)
+            await asyncio.sleep(0.1)
+            s1, n1 = timer.pairs()["lock_wait"]
+            assert n1 > n0 and 0.05 <= s1 - s0 <= 0.5
+            assert timer.pairs()["deliver"] == (0.0, 0)
+        finally:
+            eng.shutdown()
+        assert not eng._thread.is_alive()
+
+    asyncio.run(asyncio.wait_for(run(), 120))
+
+
+def test_a_blocked_loop_shows_as_the_delivery_callbacks_wait():
+    """`tpu:deliver_pickup_seconds`: the loop stands still for 500 ms
+    from the instant a request is admitted; its one round is fetched
+    and handed over meanwhile, and the callback waits out the rest."""
+    from production_stack_tpu.engine.async_engine import AsyncLLMEngine
+
+    async def one_token(eng, rid, seed):
+        outs = [out async for out in eng.generate(
+            rid, prompt_token_ids=prompt(9, seed=seed),
+            sampling_params=greedy(1))]
+        assert outs[-1].finished and len(outs[-1].token_ids) == 1
+        return outs
+
+    async def run():
+        eng = AsyncLLMEngine(cfg())
+        eng.start(asyncio.get_running_loop())
+        try:
+            loop_phases = eng.loop_phases
+            # the same shapes once, so that nothing compiles below
+            await asyncio.wait_for(one_token(eng, "warm", 5), 120)
+            s0, n0 = loop_phases.pairs()["deliver_pickup"]
+            assert n0 >= 1
+            task = asyncio.ensure_future(one_token(eng, "late", 7))
+            await asyncio.sleep(0)      # runs `generate` to its queue
+            assert eng.has_request("late")
+            t0 = time.perf_counter()
+            time.sleep(0.5)             # the loop, blocked
+            blocked = time.perf_counter() - t0
+            (out,) = await asyncio.wait_for(task, 30)
+            s1, n1 = loop_phases.pairs()["deliver_pickup"]
+            assert n1 - n0 == 1
+            assert 0.05 <= s1 - s0 <= blocked
+            # the round's fetch closed inside the block too: the stamp
+            # its output carries is the step thread's reading
+            assert t0 < out.t_fetched < t0 + blocked
+            assert eng.engine.phases.ended("fetch") == out.t_fetched
+            assert loop_phases.counts()["deliver"] == n1
+        finally:
+            eng.shutdown()
+
+    asyncio.run(asyncio.wait_for(run(), 240))
+
+
+@pytest.mark.parametrize("writer", ["completion", "chat", "multi-choice"])
+def test_every_content_chunk_is_a_token_delivery_and_a_send(writer):
+    """`tpu:token_delivery_seconds` / `tpu:server_send_seconds`: one
+    observation a content chunk written, on each stream writer, each
+    between 0 and the time the request took (a chunk whose round ends
+    while the loop stands behind a compiling step waits seconds)."""
+    path, extra = {
+        "completion": ("/v1/completions", {}),
+        "chat": ("/v1/chat/completions", {}),
+        "multi-choice": ("/v1/completions", {"n": 2}),
+    }[writer]
+
+    async def body(srv, client):
+        before = await scrape(client)
+        t0 = time.perf_counter()
+        chunks = await stream_chunks(client, path, **extra)
+        took = time.perf_counter() - t0
+        after = await scrape(client)
+        d = {k: after[k] - before.get(k, 0.0) for k in after}
+        if writer == "multi-choice":
+            assert {c["choices"][0]["index"] for c in chunks} == {0, 1}
+        assert len(chunks) >= 2
+        for pair in ("tpu:token_delivery_seconds",
+                     "tpu:server_send_seconds"):
+            assert d[pair + "_count"] == len(chunks), pair
+            assert 0.0 < d[pair + "_sum"] < took * len(chunks), pair
+        # a chunk is written after it was taken off its queue
+        assert (d["tpu:token_delivery_seconds_sum"]
+                > d["tpu:server_send_seconds_sum"])
+        # one pick-up and one delivery a round handed over
+        rounds = d["tpu:deliver_pickup_seconds_count"]
+        assert 1 <= rounds <= d["tpu:engine_phase_deliver_seconds_count"]
+        assert d["tpu:server_deliver_seconds_count"] == rounds
+        assert 0.0 < d["tpu:deliver_pickup_seconds_sum"] < took * rounds
+        assert d["tpu:engine_phase_lock_wait_seconds_count"] >= rounds
+        assert d["tpu:server_ttft_seconds_count"] == 1
+
+    with_server(body)
+
+
+def test_metrics_serves_the_loops_pairs_though_the_scrape_waits_for_the_lock():
+    """The loop-side pairs are the loop's own: `/metrics` reads them
+    after `stats()` has let the engine lock go, so a scrape that stood
+    250 ms behind another thread's hold serves them whole."""
+    loop_side = ("tpu:deliver_pickup_seconds", "tpu:token_delivery_seconds",
+                 "tpu:server_send_seconds", "tpu:server_deliver_seconds")
+
+    async def body(srv, client):
+        chunks = await stream_chunks(client, "/v1/chat/completions")
+        quiet = await scrape(client)
+        lock = srv.engine._lock
+        await asyncio.get_running_loop().run_in_executor(None, lock.acquire)
+        threading.Timer(0.25, lock.release).start()
+        held = await asyncio.wait_for(scrape(client), 30)
+        assert (held["tpu:event_loop_lock_wait_seconds_sum"]
+                - quiet["tpu:event_loop_lock_wait_seconds_sum"]) >= 0.2
+        for pair in loop_side:
+            assert held[pair + "_count"] == quiet[pair + "_count"] > 0
+            assert held[pair + "_sum"] == quiet[pair + "_sum"] > 0.0
+        assert held["tpu:token_delivery_seconds_count"] == len(chunks)
+        # and the snapshot's copy is made without the lock
+        assert not lock.locked()
+        assert set(srv.engine.stats().loop_phases) == set(phases.LOOP_PHASES)
+
+    with_server(body)
+
+
+def test_the_hand_over_is_in_the_profilers_trace_on_its_threads(tmp_path):
+    """Inside a profiler session: `engine.lock_wait` on the step
+    thread, a leaf OUTSIDE every `engine.step`; `server.admit`,
+    `server.deliver` and `server.send` on the event loop's thread."""
+    async def body(srv, client):
+        await stream_chunks(client, "/v1/completions")      # compiles
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            chunks = await stream_chunks(client, "/v1/completions")
+        finally:
+            jax.profiler.stop_trace()
+        assert len(chunks) >= 2
+
+    with_server(body)
+    events = host_events(str(tmp_path))
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev[0], []).append(ev)
+    assert set(HANDOVER_SPANS) <= set(by_name)
+    (step_thread,) = {ev[4] for ev in by_name["engine.step"]}
+    (loop_thread,) = {ev[4] for ev in by_name["server.admit_lock_wait"]}
+    assert step_thread != loop_thread
+    steps = by_name["engine.step"]
+    for _, s, t, _, thread in by_name["engine.lock_wait"]:
+        assert thread == step_thread
+        assert not any(s < t1 and s0 < t for _, s0, t1, _, _ in steps)
+    for name in ("server.admit", "server.deliver", "server.send"):
+        assert {ev[4] for ev in by_name[name]} == {loop_thread}, name
+    # the admission's annotation starts where its wait for the lock ends
+    (_, _, wait_end, _, _), = by_name["server.admit_lock_wait"]
+    (_, admit_start, _, _, _), = by_name["server.admit"]
+    assert 0 <= admit_start - wait_end < 50_000_000
+    assert len(by_name["server.send"]) >= 2

@@ -59,19 +59,9 @@ ORPHAN_ALLOWLIST = {
     # outcome counter behind the error-rate panels (errors/retries
     # are charted; the ok-outcome denominator is debug surface)
     "tpu_router:requests",
-    # exact alias of the charted vllm:gpu_cache_usage_perc (kept for
-    # tpu-native naming; one chart, two names)
-    "tpu:hbm_kv_cache_usage_perc",
-    # per-tier traffic detail behind the charted tier-hit panel and
-    # the bench kv_offload slot (hits by tier IS charted)
-    "tpu:kv_tier_misses",
-    "tpu:kv_tier_read_bytes",
-    "tpu:kv_tier_write_bytes",
     # restore volume rides the charted kv_restore_seconds histogram +
-    # fallback counter; export-side sync fallbacks surface in the
-    # bench kv_offload slot (backlog-cap degradation, rare by design)
+    # fallback counter
     "tpu:kv_restore_blocks",
-    "tpu:kv_export_sync_fallbacks",
     # long-prefill requests + fallbacks are charted; per-chunk counts
     # are /debug/requests-granularity detail
     "tpu:long_prefill_chunks",
