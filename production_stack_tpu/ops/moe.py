@@ -29,12 +29,17 @@ the top-k logits only, renormalized.
 - `routed_experts` — the dropless ROUTED layer over the experts HELD
   HERE (an expert-parallel rank's contiguous slice of a wider router):
   `route` scores every expert of the deployment, the (row, expert) pairs
-  whose expert is local are kept, and each local expert runs on its own
-  rows only (rows sorted by expert + `jax.lax.ragged_dot`), so FLOPs
-  follow the routed rows. What the absent experts would add is left
-  out: the partial sum is what an all-reduce over the ranks would
-  complete. One form at every row count (its docstring has the
-  measurement against a masked all-experts einsum).
+  whose expert is local are kept and sorted by expert, and each local
+  expert runs on its own rows only, so FLOPs follow the routed rows and
+  bytes the experts that have rows. The experts themselves are
+  `ops/expert_ffn.py`: on a TPU ONE Mosaic kernel, `expert_ffn`, over
+  the whole weight stacks, which visits the experts with rows and passes
+  each one's gate, up and down weights through VMEM once; elsewhere
+  three `jax.lax.ragged_dot`s (the same mathematics; CPU tests and
+  rehearsals). What the absent experts would add is left out: the
+  partial sum is what an all-reduce over the ranks would complete. One
+  form at every row count (its docstring has the measurement against
+  the experts' bytes).
 """
 
 from __future__ import annotations
@@ -42,6 +47,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from production_stack_tpu.ops.expert_ffn import MAX_ROWS, expert_ffn
 
 
 def top_k_gating(x: jax.Array, gate_w: jax.Array, k: int) -> jax.Array:
@@ -170,6 +177,7 @@ def routed_experts(
     scale: float = 1.0,      # on the combine weights (`route`)
     valid: jax.Array | None = None,  # [n] bool: real rows
     stack_index: jax.Array | None = None,
+    interpret: bool | None = None,  # `expert_ffn`'s
 ) -> tuple[jax.Array, jax.Array]:
     """The local experts' share of a routed layer -> ([n, d] f32,
     stats [3] int32 = pairs routed, pairs whose expert is here, local
@@ -177,23 +185,44 @@ def routed_experts(
 
     `stack_index` (a traced scalar): the expert weights are STACKS over
     the layers of a scanned run, [S, E_loc, ...], and this is the layer
-    to use. The grouped matmul then runs over all S * E_loc experts of
-    the stack with every other layer's groups empty: `ragged_dot` on a
-    dynamic slice of the stack would copy the slice first (805 MB a
-    layer at 16 experts of 4096 x 2048; compile-only v5e, PR 28).
+    to use. The experts get the whole stacks, flattened to S * E_loc
+    groups, and the layer's offset into them: a dynamic slice of a stack
+    would be copied first (805 MB a layer at 16 experts of 4096 x 2048;
+    compile-only v5e, PR 28).
 
     Dropless: every kept pair is computed. A padded or idle row
     (`valid` false) keeps no pair, so it takes no expert's time and
     changes no other row; its output is zero.
 
-    Against a masked einsum over all local experts (`moe_dense` on the
-    local slice), which reads every expert whatever the rows: on a v5e
-    at 16 local experts of 4096 x 2048 out of 256, top-8 (my chip run,
-    PR 28; masked / this form, ms) 64 rows 1.20 / 1.04, 128 rows 1.19 /
-    1.06, 256 rows 1.40 / 1.30, 576 rows 2.91 / 1.59, 1,088 rows 5.45 /
-    1.81, 4,160 rows 20.8 / 4.07. Under 64 rows nothing was measured,
-    and a second form kept for row counts no workload reaches would be
-    a path nothing measures: this one serves them all."""
+    The experts alone (`ops/expert_ffn.py`), on a v5e, against the least
+    their bytes allow (experts with rows x 3 d f x 2 B at 819 GB/s); us
+    a layer, and the floor's share of it (my chip run, PR 42, call 3:
+    `scripts/bench_expert_ffn.py --seed 42`; "ragged_dot" is the three
+    `jax.lax.ragged_dot`s over all S * E_loc groups that ran here until
+    PR 42, "gmm" megablox's grouped matmul three times):
+
+        xing4 (64 of 64, top-4,         experts      floor  ragged_dot       gmm    kernel
+        3584 x 1024, stack of 6)       with rows
+          decode 128 pairs, 12 real       11 of 384    296  439 .67   423 .70   347 .85
+          decode 128 pairs, 40 real       36           968 1327 .73  1261 .77  1074 .90
+          decode 128 pairs, all real      57          1533 2069 .74  1966 .78  1688 .91
+          a round of 1,152 pairs          64          1721 2670 .64  2506 .69  1985 .87
+          a round of 2,176 pairs          64          1721 3023 .57  2804 .61  2194 .78
+        mimo (16 of 256, top-8,
+        4096 x 2048; m = 256 pairs)
+          140 local, stack of 5           16 of 80     983 1654 .59  1328 .74  1102 .89
+          115 local, stack of 1           16 of 16     983 1721 .57  1368 .72  1250 .79
+          11 local, stack of 5             9 of 80     553  954 .58   739 .75   634 .87
+
+    What is left to the kernel's floor: the first expert's tiles, which
+    nothing overlaps (13 us a call at an f tile of 512; the stack of 1
+    shows it most, every call alone in its loop), the rows in and the
+    float32 result out, a third of a microsecond a grid step, and in a
+    round of more than `MAX_ROWS` pairs the expert astride two passes,
+    read in both (2,176 pairs: five passes). An f tile of 256 read 6-9%
+    slower, 1,024 the same; row tiles of 32, 64 and 256 within 1.5%; a
+    static grid of min(E_loc, m) steps with the steps past the list doing
+    nothing 5% slower at 11 experts of 64 (call 1, same seed)."""
     n, _ = x.shape
     stacked = stack_index is not None
     e_loc = w_gate.shape[1 if stacked else 0]
@@ -217,20 +246,23 @@ def routed_experts(
         n_real * top_k, jnp.sum(sizes), jnp.sum((sizes > 0).astype(jnp.int32))
     ])
 
+    base = 0
     if stacked:
         w_gate, w_up, w_down = (
             a.reshape(-1, *a.shape[2:]) for a in (w_gate, w_up, w_down))
+        base = stack_index * e_loc
 
-    # pairs sorted by local expert, the ones not here last; the grouped
-    # matmuls walk the sorted local pairs `m` at a time. m covers twice
-    # the pairs an even routing sends here (all of them where every
-    # expert is local), so one pass is the rule; a routing more skewed
-    # than that takes another pass over the next m, and nothing is
-    # dropped
+    # pairs sorted by local expert, the ones not here last; the experts
+    # walk the sorted local pairs `m` at a time. m covers twice the
+    # pairs an even routing sends here (all of them where every expert
+    # is local) up to what `expert_ffn` holds at once, so one pass is
+    # the rule of a decode step; a routing more skewed than that, or a
+    # prefill round's pairs, take another pass over the next m, and
+    # nothing is dropped
     pairs = n * top_k
     share = e_loc / router_w.shape[1]
-    m = pairs if share >= 0.5 else min(
-        pairs, -(-int(2 * pairs * share) // 128) * 128 + 128)
+    m = min(MAX_ROWS, pairs if share >= 0.5 else min(
+        pairs, -(-int(2 * pairs * share) // 128) * 128 + 128))
     order = jnp.argsort(local.reshape(-1), stable=True)
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
@@ -240,17 +272,12 @@ def routed_experts(
         p0, out = carry
         # the m sorted pairs from `lo`; where the last pass's slice was
         # pulled back to fit, its first `skip` pairs are done already:
-        # they get no group of their own and no weight
+        # they are no expert's rows and get no weight
         lo = jnp.minimum(p0, pairs - m)
         skip = p0 - lo
         sel = lax.dynamic_slice(order, (lo,), (m,))
         group = jnp.clip(ends - p0, 0, m - skip) - jnp.clip(
             starts - p0, 0, m - skip)
-        group = group.at[0].add(skip)  # rows of no interest, weight 0
-        if stacked:
-            group = lax.dynamic_update_slice(
-                jnp.zeros((w_gate.shape[0],), group.dtype), group,
-                (stack_index * e_loc,))
         rows = sel // top_k
         # rows in and results out through one-hot matrices, not a
         # gather and a scatter-add: XLA's pair is 2.4 MB of program
@@ -261,16 +288,11 @@ def routed_experts(
         pick = rows[:, None] == jnp.arange(n)[None, :]      # (m, n)
         xs = jnp.dot(pick.astype(x.dtype), x,
                      preferred_element_type=jnp.float32).astype(x.dtype)
-        g = lax.ragged_dot(xs, w_gate, group,
-                           preferred_element_type=jnp.float32)
-        u = lax.ragged_dot(xs, w_up, group,
-                           preferred_element_type=jnp.float32)
-        a = (jax.nn.silu(g) * u).astype(x.dtype)
-        y = lax.ragged_dot(a, w_down, group,
-                           preferred_element_type=jnp.float32)
+        # zero in the rows that are no local expert's
+        y = expert_ffn(xs, w_gate, w_up, w_down, group, skip, base,
+                       interpret=interpret)
         live = (jnp.arange(m) >= skip) & (lo + jnp.arange(m) < ends[-1])
         wt = jnp.where(live, w_flat[sel], 0.0)
-        y = jnp.where(wt[:, None] > 0, y, 0.0)  # rows no group computed
         combine = jnp.where(pick, wt[:, None], 0.0).T         # (n, m)
         return p0 + m - skip, out + jnp.dot(
             combine, y, precision=lax.Precision.HIGHEST)

@@ -206,6 +206,44 @@ def test_sinkhorn_kernel_compiles(v5e, rows):
     jax.jit(fn).lower(_spec(v5e, (4, 4, rows), jnp.float32)).compile()
 
 
+@pytest.mark.parametrize("cell, stack, e_loc, d, f, rows", [
+    # the scanned run's stack, experts held, widths, the pairs of a pass
+    ("xing4-29b-l8.chat-doc16k, decode", 6, 64, 3584, 1024, 128),
+    ("xing4-29b-l8.chat-doc16k, a prefill round's pass", 6, 64, 3584,
+     1024, 512),
+    ("mimo-v2.5-ep16-l7.batch-doc8k, the window run", 5, 16, 4096, 2048,
+     256),
+    ("mimo-v2.5-ep16-l7.batch-doc8k, the full layer", 1, 16, 4096, 2048,
+     256),
+    ("a few lanes", 6, 64, 3584, 1024, 32),
+])
+def test_expert_ffn_compiles_at_the_cells_shapes(
+    v5e, cell, stack, e_loc, d, f, rows
+):
+    """The routed experts' one kernel (`ops/expert_ffn.py`) compiles for
+    a v5e over the WHOLE stacks of a scanned run, nothing of an expert's
+    size among the temps (no slice of a stack is copied), and the
+    operation's text names a whole stack in its first 400 characters:
+    what `benchmarks/chip/layer_metrics/moe_expert_*.json` find it by."""
+    from production_stack_tpu.ops import expert_ffn as ef
+
+    fn = functools.partial(ef.expert_ffn, interpret=False)
+    n = stack * e_loc
+    compiled = jax.jit(fn).lower(
+        _spec(v5e, (rows, d), jnp.bfloat16),
+        _spec(v5e, (n, d, f), jnp.bfloat16),
+        _spec(v5e, (n, d, f), jnp.bfloat16),
+        _spec(v5e, (n, f, d), jnp.bfloat16),
+        _spec(v5e, (e_loc,), jnp.int32), _spec(v5e, (), jnp.int32),
+        _spec(v5e, (), jnp.int32),
+    ).compile()
+    call = [line for line in compiled.as_text().splitlines()
+            if "custom-call(" in line and "expert_ffn" in line]
+    assert len(call) == 1
+    assert re.search(rf"bf16\[{n},({d},{f}|{f},{d})\]", call[0][:400])
+    assert compiled.memory_analysis().temp_size_in_bytes < d * f * 2
+
+
 @pytest.mark.slow
 def test_prefill_kernel_compiles(v5e):
     fn = functools.partial(
