@@ -474,19 +474,39 @@ class WindowedBlockManager(BlockManager):
       behind `next query - window` (`release_behind`), or with the
       sequence (`free`);
     - at reference count 0: freed at once if the primary block is not
-      hash-registered, else kept evictable (LRU) so that a later prefix
-      hit can end there; dropped when the primary block is evicted.
+      hash-registered, else kept evictable so that a later prefix hit
+      can end there; dropped when the primary block is evicted.
 
     A prefix hit of n blocks is granted only where the window group
     still has the blocks covering the last `window` positions before
     n * block_size; otherwise it is cut back to the longest n for which
-    that holds (`match_prefix`).
+    that holds (`match_prefix`), at worst to nothing: a cut costs a
+    prefill of everything behind it.
+
+    So which evictable twins go first decides what a returning session
+    costs (measured: PERF.md, Findings PR 43). Two classes: a twin let
+    go BEHIND a window (`release_behind`) was passed over by its
+    sequence: `_wtrail`, recycled before any other, oldest first (a long
+    prefill recycles the trails of earlier ones and then its own, and
+    evicts nobody's end); a twin let go with its sequence (`free`: the
+    last `window` positions of a prompt and its answer, where the
+    session's next turn will hit) is an END: `_wevictable`, least
+    recently used first, only once no trail is left. A twin passed over
+    counts as an end where prefix hits of at least two prompts have
+    ended in front of it (`_tail_ends`: the end of a shared document,
+    where every new session's hit ends; a hit that was cut back there
+    counts twice). And a cut heals: where a recomputed block is
+    registered and the cached block of its hash has no twin, the
+    recomputed one (which has) takes the hash over (`register_hash`),
+    so the next prompt's hit ends where this one was cut; left as the
+    base class registers, the recomputed copies stay unregistered, the
+    cached ones without twins, and every later prompt is cut again.
 
     The pool is sized by the runner for every lane's window and chunk at
-    once plus as much again for cached prefixes (`ModelRunner.
-    _window_blocks_needed`), so the lanes the scheduler admits (at most
-    max_num_seqs) can always be served after evicting cached twins:
-    running out is a bug, and raises."""
+    once plus the ends of several times as many cached sequences
+    (`ModelRunner._window_blocks_needed`), so the lanes the scheduler
+    admits (at most max_num_seqs) can always be served after evicting
+    cached twins: running out is a bug, and raises."""
 
     def __init__(self, num_blocks: int, block_size: int,
                  enable_prefix_caching: bool, *, window: int,
@@ -505,23 +525,36 @@ class WindowedBlockManager(BlockManager):
         self._wfree: list[int] = list(range(num_window_blocks - 1, 0, -1))
         self._wref = [0] * num_window_blocks
         self._wowner = [0] * num_window_blocks
-        # twin id -> None, LRU: reference count 0, primary hash-registered
+        # twin id -> None: reference count 0, primary hash-registered.
+        # The ends (LRU) and the trails (oldest first), which go before
+        # any end (the class docstring has why)
         self._wevictable: OrderedDict[int, None] = OrderedDict()
+        self._wtrail: OrderedDict[int, None] = OrderedDict()
+        # primary block id -> admitted prompts whose prefix hit ended
+        # with this block in its window (a hit cut back there: twice)
+        self._tail_ends: dict[int, int] = {}
         self.window_blocks_released = 0
+        # [blocks the admitted prompts' prefix hits were shortened by
+        # because the twins at their end were gone, admitted prompts
+        # whose prefix hit at all] (tpu:prefix_window_cutback_blocks)
+        self.prefix_cutback = [0, 0]
+        # the last match's (blocks cut back, the window before the
+        # uncut hit's end); None = no hit
+        self._cutback: tuple[int, list[int]] | None = None
 
     # -- the window pool ----------------------------------------------------
     @property
     def window_blocks_in_use(self) -> int:
         """Twins some sequence references."""
         return (self.num_window_blocks - 1 - len(self._wfree)
-                - len(self._wevictable))
+                - len(self._wevictable) - len(self._wtrail))
 
     def _walloc(self, bid: int) -> None:
         assert not self.block_map[bid], f"block {bid} already has a twin"
         if self._wfree:
             w = self._wfree.pop()
-        elif self._wevictable:
-            w, _ = self._wevictable.popitem(last=False)
+        elif self._wtrail or self._wevictable:
+            w, _ = (self._wtrail or self._wevictable).popitem(last=False)
             self.block_map[self._wowner[w]] = 0
         else:
             raise RuntimeError(
@@ -537,19 +570,22 @@ class WindowedBlockManager(BlockManager):
         w = int(self.block_map[bid])
         assert w, f"block {bid} has no twin to take"
         if self._wref[w] == 0:
-            del self._wevictable[w]
+            del (self._wtrail if w in self._wtrail else self._wevictable)[w]
         self._wref[w] += 1
 
-    def _wrelease(self, bid: int) -> None:
+    def _wrelease(self, bid: int, passed_over: bool = False) -> None:
         w = int(self.block_map[bid])
         assert w and self._wref[w] > 0, f"twin of block {bid} not held"
         self._wref[w] -= 1
         if self._wref[w]:
             return
-        if self.blocks[bid].block_hash is not None:
-            self._wevictable[w] = None
-        else:
+        if self.blocks[bid].block_hash is None:
             self._drop_twin(bid)
+            return
+        if passed_over and self._tail_ends.get(bid, 0) < 2:
+            self._wtrail[w] = None
+        else:
+            self._wevictable[w] = None
 
     def _drop_twin(self, bid: int) -> None:
         w = int(self.block_map[bid])
@@ -557,6 +593,7 @@ class WindowedBlockManager(BlockManager):
             return
         assert self._wref[w] == 0, f"twin of block {bid} still referenced"
         self._wevictable.pop(w, None)
+        self._wtrail.pop(w, None)
         self._wfree.append(w)
         self.block_map[bid] = 0
         self.map_version += 1
@@ -566,7 +603,26 @@ class WindowedBlockManager(BlockManager):
         # a primary block that starts a new life (evicted from the
         # cache, or freed unregistered) takes no twin along
         self._drop_twin(bid)
+        self._tail_ends.pop(bid, None)
         return bid
+
+    def register_hash(self, h: int, block_id: int) -> None:
+        old = self.cached_blocks.get(h)
+        if (old is not None and old != block_id
+                and self.blocks[block_id].block_hash is None
+                and self.block_map[block_id] and not self.block_map[old]):
+            # the same content computed again (a hit was cut back in
+            # front of it): the cached copy has no twin and this one
+            # has, so this one becomes the cached block. Who still reads
+            # the old copy keeps it by its id; it is freed unregistered
+            self.blocks[old].block_hash = None
+            del self.cached_blocks[h]
+            if old in self.evictable:
+                del self.evictable[old]
+                self.free_blocks.append(old)
+            if old in self._tail_ends:
+                self._tail_ends[block_id] = self._tail_ends.pop(old)
+        super().register_hash(h, block_id)
 
     def _tail_start(self, n_blocks: int) -> int:
         """First block a query at position n_blocks * block_size still
@@ -581,19 +637,31 @@ class WindowedBlockManager(BlockManager):
         matched, _ = super().match_prefix(token_ids, seed, hashes)
         # allocate_prompt computes at least one token: check the window
         # at the boundary it will really start from
-        n = min(len(matched), (len(token_ids) - 1) // self.block_size)
+        hit = n = min(len(matched), (len(token_ids) - 1) // self.block_size)
         while n > 0 and not all(
             self.block_map[b] for b in matched[self._tail_start(n):n]
         ):
             n -= 1
+        self._cutback = (
+            (hit - n, matched[self._tail_start(hit):hit]) if hit else None)
         return matched[:n], n * self.block_size
 
     def allocate_prompt(self, token_ids, seed: int = 0,
                         reuse_cache: bool = True, hashes=None):
+        self._cutback = None
         alloc = super().allocate_prompt(token_ids, seed, reuse_cache,
                                         hashes)
         if alloc is None:
             return None
+        if self._cutback is not None:
+            # counted where the prompt is admitted: the scheduler asks
+            # `match_prefix` about a waiting prompt more than once
+            cut, tail = self._cutback
+            self.prefix_cutback[0] += cut
+            self.prefix_cutback[1] += 1
+            for bid in tail:
+                self._tail_ends[bid] = (
+                    self._tail_ends.get(bid, 0) + 1 + (cut > 0))
         table, cached = alloc
         n = cached // self.block_size
         table = WindowTable(table, lo=self._tail_start(n), hi=n)
@@ -631,7 +699,7 @@ class WindowedBlockManager(BlockManager):
             len(block_table),
         )
         for i in range(block_table.lo, min(new_lo, block_table.hi)):
-            self._wrelease(block_table[i])
+            self._wrelease(block_table[i], passed_over=True)
             self.window_blocks_released += 1
         if new_lo > block_table.lo:
             block_table.lo = new_lo

@@ -3676,6 +3676,7 @@ class LLMEngine:
                     bm.window_blocks_released,
                 "kv_window_blocks_per_seq":
                     tuple(self._window_blocks_per_seq),
+                "prefix_window_cutback_blocks": tuple(bm.prefix_cutback),
             }
         return {
             "attn_context_by_kind": {

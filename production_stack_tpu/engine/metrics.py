@@ -193,6 +193,13 @@ class EngineMetrics:
                 "sequences (count), both summed over the dispatched "
                 "rounds; their ratio stays near (window + chunk) / "
                 "block_size however long the contexts grow",
+            "tpu:prefix_window_cutback_blocks":
+                "A model with a windowed cache group: blocks the "
+                "admitted prompts' prefix hits were shortened by "
+                "because the window-group blocks at the hit's end were "
+                "no longer resident (sum) and admitted prompts whose "
+                "prefix hit (count); 0 = every hit ended where its "
+                "window blocks still were",
             **{
                 f"tpu:program_{st}_seconds": doc
                 for st, doc in (
@@ -612,6 +619,8 @@ class EngineMetrics:
             - prev.kv_window_blocks_released_total))
         self.pairs.set("tpu:kv_window_blocks_per_seq",
                        s.kv_window_blocks_per_seq)
+        self.pairs.set("tpu:prefix_window_cutback_blocks",
+                       s.prefix_window_cutback_blocks)
         for stage, pair in s.program_stages.items():
             self.pairs.set(f"tpu:program_{stage}_seconds", pair)
         self.program_cache_hits.labels(m).inc(max(

@@ -406,7 +406,8 @@ class ModelRunner:
         mc, cfg = self.model_config, self.config
         width = mc.head_dim
         if mc.attn_kinds and mc.attn_kinds[kind].latent_dim:
-            width = mc.attn_kinds[kind].latent_dim + mc.rope_dim
+            ak = mc.kinds[kind]
+            width = ak.latent_dim + ak.rotary_dim
         on_kernel_path = (
             jax.default_backend() == "tpu"
             and cfg.attention_impl in ("auto", "pallas")
@@ -476,11 +477,23 @@ class ModelRunner:
                 "own), which does not serve with: " + "; ".join(on)
             )
 
+    # cached sequences whose last window the windowed pool keeps for a
+    # prefix hit to end in, as a multiple of the lanes (`_window_blocks_
+    # needed`)
+    CACHED_ENDS_A_LANE = 4
+
     def _window_blocks_needed(self) -> int:
         """Blocks of the windowed cache group: for every lane the
         window behind its next query and the chunk (or fused decode
-        steps) ahead of it, and as much again as cached prefixes may
-        pin (the window before each lane's first computed token)."""
+        steps) ahead of it, and the last window of CACHED_ENDS_A_LANE
+        times as many cached sequences: where a returning session's
+        prefix hit has to end, or it is cut back to nothing
+        (`WindowedBlockManager`). The lanes are a stand-in for the
+        sessions a deployment keeps warm, which nothing here knows; one
+        end a lane (the size until PR 43) lost 48 sessions' ends over
+        four 16.5k documents to the documents' own prefill (PERF.md,
+        Findings PR 43); tpu:prefix_window_cutback_blocks says when the
+        pool is too small."""
         cfg = self.config
         kind = layer_groups.mapped_kind(self.model_config)
         if kind is None:
@@ -489,7 +502,9 @@ class ModelRunner:
         win = -(-self.model_config.attn_kinds[kind].window // bs)
         ahead = -(-max(cfg.max_prefill_chunk,
                        2 * cfg.num_scheduler_steps) // bs)
-        return max(1, cfg.max_num_seqs) * (2 * win + ahead + 2) + 1
+        lanes = max(1, cfg.max_num_seqs)
+        return (lanes * (win + ahead + 2)
+                + self.CACHED_ENDS_A_LANE * lanes * (win + 1) + 1)
 
     def _allocate_cache_groups(self) -> None:
         """One K and one V array per attention kind, (L_kind, nkv_kind,
@@ -686,23 +701,23 @@ class ModelRunner:
         return int(min(num, max(cap, 2)))
 
     def _smoke_caches(self, mc: ModelConfig):
-        """(k, v, spec) of a four-block cache for each kernel variant
-        serving will compile: the model's one, or one per layer kind
-        (its kv heads, window and sink; K at its stored width; no V for
-        a latent kind)."""
+        """(k, v, spec, query heads) of a four-block cache for each
+        kernel variant serving will compile: the model's one, or one per
+        layer kind (its kv and query heads, window and sink; K at its
+        stored width; no V for a latent kind)."""
         bs = self.block_size
         kinds = (
             [(ak.num_kv_heads, layer_groups.AttnSpec(
                 window=ak.window,
-                sink=(jnp.zeros((mc.num_heads,), jnp.float32)
+                sink=(jnp.zeros((ak.num_heads,), jnp.float32)
                       if ak.sink else None),
                 block_map=(jnp.zeros((4,), jnp.int32)
                            if ak.window else None),
                 latent_v=ak.latent_dim or None,
-            )) for ak in mc.attn_kinds]
-            if mc.layer_groups else [(mc.num_kv_heads, None)]
+            ), ak.num_heads) for ak in mc.kinds]
+            if mc.layer_groups else [(mc.num_kv_heads, None, mc.num_heads)]
         )
-        for i, (nkv, spec) in enumerate(kinds):
+        for i, (nkv, spec, nq) in enumerate(kinds):
             kc = jnp.zeros(
                 (1, nkv, 4 * bs, self._k_store_dim(i)), self.cache_dtype)
             vc = None
@@ -713,7 +728,7 @@ class ModelRunner:
                 # exercise the exact shard_map paths serving will take
                 cs = sharding_rules.cache_sharding(self.mesh)
                 kc, vc = jax.device_put(kc, cs), jax.device_put(vc, cs)
-            yield kc, vc, spec
+            yield kc, vc, spec, nq
 
     def _pallas_smoke_test(self, mc: ModelConfig) -> None:
         # probe the exact kernel variants serving will compile — the
@@ -722,9 +737,9 @@ class ModelRunner:
         # a mesh, exactly as the step builders do. q is as wide as the
         # kind's rows (`_attn` pads a head of 192 to 256 itself)
         table1 = jnp.zeros((2,), jnp.int32)
-        for kc, vc, spec in self._smoke_caches(mc):
+        for kc, vc, spec, nq in self._smoke_caches(mc):
             qp = jnp.zeros(
-                (8, mc.num_heads, self._smoke_q_dim(kc, vc)), self.dtype)
+                (8, nq, self._smoke_q_dim(kc, vc)), self.dtype)
             out = self._attn("prefill", qp, jnp.int32(0), kc, vc,
                              table1, jnp.int32(0), spec=spec)
             jax.block_until_ready(out)
@@ -740,9 +755,9 @@ class ModelRunner:
         seg_meta = jnp.asarray(
             [[0, 0, RAGGED_TQ, 0], [1, 0, 1, 0]], jnp.int32
         )
-        for kc, vc, spec in self._smoke_caches(mc):
+        for kc, vc, spec, nq in self._smoke_caches(mc):
             qr = jnp.zeros(
-                (2 * RAGGED_TQ, mc.num_heads, self._smoke_q_dim(kc, vc)),
+                (2 * RAGGED_TQ, nq, self._smoke_q_dim(kc, vc)),
                 self.dtype)
             out = self._attn(
                 "ragged", qr, jnp.int32(0), kc, vc,
@@ -1828,14 +1843,15 @@ class ModelRunner:
                      total_lens, spec=None):
                 # (s, c, nkv, d)
                 k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
-                qs = q.reshape(s_pad, t_pad, mc.num_heads, q.shape[-1])
+                # q's heads are the layer kind's own
+                qs = q.reshape(s_pad, t_pad, *q.shape[1:])
                 out = jax.vmap(
                     functools.partial(
                         xla_attn.context_attention_prefill, **kw
                     ),
                     in_axes=(0, 0, 0, 0, 0, None),
                 )(qs, k_ctx, v_ctx, positions2d, total_lens, scale)
-                return out.reshape(s_pad * t_pad, mc.num_heads, -1)
+                return out.reshape(s_pad * t_pad, q.shape[1], -1)
 
         return attn
 

@@ -106,10 +106,14 @@ class EngineStatsSnapshot:
     # tpu:kv_blocks_in_use{group}; window-group blocks let go behind a
     # window — tpu:kv_window_blocks_released; and (window-group blocks
     # in use, running sequences) summed over the dispatched rounds —
-    # tpu:kv_window_blocks_per_seq
+    # tpu:kv_window_blocks_per_seq; (blocks the admitted prompts'
+    # prefix hits were cut back by because the window-group blocks at
+    # their end were gone, admitted prompts whose prefix hit) —
+    # tpu:prefix_window_cutback_blocks
     kv_blocks_in_use: dict = field(default_factory=dict)
     kv_window_blocks_released_total: int = 0
     kv_window_blocks_per_seq: tuple = (0, 0)
+    prefix_window_cutback_blocks: tuple = (0, 0)
     # the stages of building a program, from jax's monitoring events of
     # this process: trace / lower / compile -> (seconds, count), and the
     # persistent compile cache's hits — tpu:program_*_seconds,

@@ -17,7 +17,9 @@ real checkpoints load when present on disk.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
+import math
 import os
 from dataclasses import dataclass
 
@@ -42,6 +44,20 @@ class AttnKind:
     # value (its first `latent_dim` dims): one array a layer, no V
     # array. 0 = keys and values of their own
     latent_dim: int = 0
+    # what a kind may have of its own and otherwise takes from the model
+    # (`ModelConfig.kinds` fills a None in): its query heads (wq, wo and
+    # the GQA group follow them; the kv heads are the kind's anyway),
+    # the leading dims of a head that rotate, and YaRN on those
+    num_heads: int | None = None
+    rotary_dim: int | None = None
+    rope_yarn: YarnScaling | None = None
+    # multiplies cos and sin (HF `rope_parameters.attention_factor`, as
+    # the public YaRN implementation of that key reads it: only the
+    # rotated dims of a dot product carry its square). None = YaRN's
+    # mscale / mscale_all_dim ratio, 1 without YaRN. NOT the softmax
+    # scale's mscale squared (`ModelConfig.attn_scale`, DeepSeek's
+    # reading), which is the model's and covers every dim
+    rope_factor: float | None = None
 
 
 @dataclass(frozen=True)
@@ -145,6 +161,11 @@ class ModelConfig:
     ut_steps: int = 1
     sandwich_norm: bool = False
     exit_gate: bool = False
+    # a per-head output gate in a stack of layer groups (the head-wise
+    # gate of arXiv:2505.06708): g = sigmoid(x W_g), one scalar a query
+    # head and row from the normed layer input, multiplies that head's
+    # attention output before W_o
+    head_gate: bool = False
 
     def __post_init__(self):
         if self.ut_steps < 1 or (self.ut_steps > 1 and self.attn_kinds):
@@ -162,6 +183,20 @@ class ModelConfig:
                     f"{len(self.attn_kinds)} attn_kinds for each of "
                     f"{self.num_layers} layers, got {self.layer_kinds}"
                 )
+            for ak in self.attn_kinds:
+                y = ak.rope_yarn
+                if y is not None and y.mscale_all_dim and y != self.rope_yarn:
+                    raise ValueError(
+                        f"model {self.name}: a kind's YaRN with "
+                        f"mscale_all_dim={y.mscale_all_dim} would scale "
+                        "that kind's softmax; the softmax scale is one a "
+                        "model (`attn_scale` reads the model's rope_yarn)"
+                    )
+        elif self.head_gate:
+            raise ValueError(
+                f"model {self.name}: head_gate needs a stack of layer "
+                "groups (models/llama.py has no output gate)"
+            )
         if self.router_experts and (
             self.router_experts % self.ep_size
             or not 0 <= self.ep_rank < self.ep_size
@@ -196,6 +231,21 @@ class ModelConfig:
     @property
     def rope_dim(self) -> int:
         return self.rotary_dim or self.head_dim
+
+    def kind_of(self, ak: AttnKind) -> AttnKind:
+        """A kind as the code reads it: every field it leaves to the
+        model (None) filled in with the model's."""
+        return dataclasses.replace(
+            ak,
+            num_heads=ak.num_heads or self.num_heads,
+            rotary_dim=ak.rotary_dim or self.rope_dim,
+            rope_yarn=ak.rope_yarn or self.rope_yarn,
+        )
+
+    @functools.cached_property
+    def kinds(self) -> tuple[AttnKind, ...]:
+        """`attn_kinds`, each as `kind_of` gives it."""
+        return tuple(self.kind_of(ak) for ak in self.attn_kinds)
 
     @property
     def attn_scale(self) -> float:
@@ -245,25 +295,26 @@ class ModelConfig:
             # every weight HELD here: the local experts only
             total = v * h * (1 if self.tie_word_embeddings else 2) + h
             for li, kind in enumerate(self.layer_kinds):
-                ak = self.attn_kinds[kind]
+                ak = self.kinds[kind]
+                nq, rot = ak.num_heads, ak.rotary_dim
                 if ak.latent_dim:
-                    r, nope = self.q_lora_rank, self.head_dim - self.rope_dim
+                    r, nope = self.q_lora_rank, self.head_dim - rot
                     total += (
-                        h * r + r + r * self.q_size
-                        + h * (ak.latent_dim + self.rope_dim)
+                        h * r + r + r * nq * self.head_dim
+                        + h * (ak.latent_dim + rot)
                         + ak.latent_dim
-                        + ak.latent_dim * self.num_heads
-                        * (nope + self.v_dim)
+                        + ak.latent_dim * nq * (nope + self.v_dim)
                     )
                 else:
                     total += (
-                        h * self.q_size
+                        h * nq * self.head_dim
                         + h * ak.num_kv_heads * (self.head_dim + self.v_dim)
                     )
                 total += (
-                    self.num_heads * self.v_dim * h
+                    nq * self.v_dim * h
                     + 2 * h
-                    + (self.num_heads if ak.sink else 0)
+                    + (nq if ak.sink else 0)
+                    + (h * nq if self.head_gate else 0)
                 )
                 if self.hc_mult > 1:
                     n = self.hc_mult
@@ -445,6 +496,52 @@ TINY_LATENT_DEBUG = _register(
     )
 )
 
+# a stack shaped like published layers 0-4 of a `model_type: laguna`
+# model at tiny widths that keep every code path that family adds to
+# models/layer_groups.py: a full kind of 6 query heads and a window kind
+# of 8 over the same 2 kv heads (GQA groups 3 and 4 in one program), half
+# of a head rotated on the full kind (YaRN past 64 original positions at
+# a theta of 100, so that two of its four frequencies lie on the ramp;
+# cos and sin times 0.1 ln 4 + 1) against the whole head on the window
+# kind, the per-head output gate, a leading dense layer, then 16
+# softmax-routed experts (top-4, renormalised, scaling 2.5; all held
+# here) beside one shared expert; window 12 = three blocks of 4
+TINY_LAGUNA_DEBUG = _register(
+    ModelConfig(
+        name="pst-tiny-laguna-debug",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=5,
+        num_heads=6,
+        num_kv_heads=2,
+        head_dim=16,
+        max_model_len=256,
+        rope_theta=100.0,
+        rms_norm_eps=1e-6,
+        attn_kinds=(
+            AttnKind(num_kv_heads=2, rope_theta=100.0, num_heads=6,
+                     rotary_dim=8,
+                     rope_yarn=YarnScaling(factor=4.0,
+                                           original_max_position=64,
+                                           beta_fast=8.0),
+                     rope_factor=0.1 * math.log(4.0) + 1.0),
+            AttnKind(num_kv_heads=2, rope_theta=1e4, window=12,
+                     num_heads=8, rotary_dim=16),
+        ),
+        layer_kinds=(0, 1, 1, 1, 0),
+        rotary_dim=8,
+        head_gate=True,
+        router_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="softmax",
+        moe_intermediate_size=32,
+        dense_layers=1,
+        shared_experts=1,
+        routed_scaling=2.5,
+    )
+)
+
 # CI-scale stand-in for facebook/opt-125m in the reference's test configs:
 # same order of magnitude, Llama-class architecture.
 SMALL_125M = _register(
@@ -567,7 +664,7 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
     by_type = {"mimo_v2": _from_mimo_v2, "xing4_0": _from_xing4,
-               "ouro": _from_ouro}
+               "ouro": _from_ouro, "laguna": _from_laguna}
     if hf.get("model_type") in by_type:
         return by_type[hf["model_type"]](hf, name or os.path.basename(
             os.path.normpath(path)))
@@ -811,6 +908,155 @@ def _from_xing4(hf: dict, name: str) -> ModelConfig:
                       float(hf.get("mhc_h_res_clamp_max", 30))),
         dense_layers=dense_layers,
         **_routed_fields(hf, dense_layers < L),
+    )
+
+
+def _from_laguna(hf: dict, name: str) -> ModelConfig:
+    """`model_type: laguna` (poolside's Laguna family): full and window
+    attention layers by `layer_types`, each kind with its own QUERY
+    heads (`num_attention_heads_per_layer`) over the same kv heads, and
+    its own rotary scheme (`rope_parameters[layer type]`: theta, the
+    rotated share of a head, YaRN and the factor on cos and sin); a
+    per-head output gate (`gating`); dense or routed MLP by
+    `mlp_layer_types`, the routed ones softmax-scored over `num_experts`
+    with renormalised top-k and `moe_routed_scaling_factor`, beside a
+    shared expert of `shared_expert_intermediate_size`.
+
+    The three per-layer lists are a third spelling of segments; they
+    become kinds (0 = full, 1 = window) and `layer_kinds`. What has no
+    code path is refused by name."""
+    L = hf["num_hidden_layers"]
+    types = list(hf.get("layer_types") or ["full_attention"] * L)
+    heads = list(hf.get("num_attention_heads_per_layer")
+                 or [hf["num_attention_heads"]] * L)
+    mlps = list(hf.get("mlp_layer_types") or (
+        ["sparse" if hf.get("num_experts") else "dense"] * L))
+    if not len(types) == len(heads) == len(mlps) == L:
+        raise ValueError(
+            f"{name}: layer_types, num_attention_heads_per_layer and "
+            f"mlp_layer_types must have num_hidden_layers={L} entries")
+    names = ("full_attention", "sliding_attention")
+    if set(types) - set(names):
+        raise ValueError(
+            f"{name}: layer_types {sorted(set(types) - set(names))} are "
+            "not served (full_attention and sliding_attention are)")
+    if types[0] != "full_attention":
+        raise ValueError(
+            f"{name}: a window layer first is not served: the first "
+            "layer's kind owns the block table every sequence ships, and "
+            "that has to be the kind that keeps every token")
+    window = hf.get("sliding_window")
+    if "sliding_attention" in types and not isinstance(window, int):
+        raise ValueError(
+            f"{name}: sliding_window={window!r} is not served (one "
+            "window for every sliding_attention layer; more than one "
+            "windowed kind has no code path)")
+    gating = hf.get("gating")
+    gate_types = set(hf.get("gating_types") or ())
+    if gating not in (None, False, True, "per-head") or (
+            gate_types - {"per_head"}):
+        raise ValueError(
+            f"{name}: gating={gating!r} / gating_types="
+            f"{sorted(gate_types)} is not served (the per-head output "
+            "gate, `true` or \"per-head\", is; an element-wise gate has "
+            "no code path)")
+    for key, want in (("attention_bias", (None, False)),
+                      ("hidden_act", (None, "silu")),
+                      ("use_qk_norm", (None, False)),
+                      ("qk_norm", (None, False)),
+                      ("moe_apply_router_weight_on_input", (None, False)),
+                      ("moe_router_logit_softcapping", (None, 0)),
+                      ("n_group", (None, 1)), ("topk_group", (None, 1))):
+        if hf.get(key) not in want:
+            raise ValueError(
+                f"{name}: {key}={hf.get(key)!r} is not served for "
+                "model_type laguna")
+    head_dim = hf.get("head_dim") or (
+        hf["hidden_size"] // hf["num_attention_heads"])
+    rope = hf.get("rope_parameters") or {}
+
+    def kind(type_name: str) -> AttnKind:
+        per_layer = {h for t, h in zip(types, heads) if t == type_name}
+        if len(per_layer) != 1:
+            raise ValueError(
+                f"{name}: {type_name} layers with query heads "
+                f"{sorted(per_layer)}: one count a layer type is served")
+        rp = rope.get(type_name) or {}
+        rtype = rp.get("rope_type") or rp.get("type") or "default"
+        if rtype not in ("default", "yarn"):
+            raise ValueError(
+                f"{name}: rope_type {rtype!r} of {type_name} is not "
+                "served")
+        yarn = factor = None
+        if rtype == "yarn":
+            yarn = YarnScaling(
+                factor=float(rp["factor"]),
+                original_max_position=int(
+                    rp["original_max_position_embeddings"]),
+                beta_fast=float(rp.get("beta_fast", 32)),
+                beta_slow=float(rp.get("beta_slow", 1)),
+            )
+            # HF's reading: the given factor, else 0.1 ln(factor) + 1
+            from production_stack_tpu.ops.layers import yarn_mscale
+
+            factor = float(rp.get("attention_factor")
+                           or yarn_mscale(yarn.factor, 1.0))
+        rotary = int(head_dim * float(rp.get(
+            "partial_rotary_factor", hf.get("partial_rotary_factor", 1.0))))
+        return AttnKind(
+            num_kv_heads=hf.get("num_key_value_heads",
+                                hf["num_attention_heads"]),
+            rope_theta=float(rp.get("rope_theta",
+                                    hf.get("rope_theta", 10000.0))),
+            window=window if type_name == "sliding_attention" else None,
+            num_heads=per_layer.pop(),
+            rotary_dim=rotary - rotary % 2,
+            rope_yarn=yarn,
+            rope_factor=factor,
+        )
+
+    kinds = tuple(kind(t) for t in names if t in types)
+    if set(mlps) - {"dense", "sparse"}:
+        raise ValueError(
+            f"{name}: mlp_layer_types {sorted(set(mlps))} are not served")
+    dense_layers = mlps.index("sparse") if "sparse" in mlps else L
+    if "dense" in mlps[dense_layers:]:
+        raise ValueError(
+            f"{name}: a dense MLP after a routed one is not served "
+            "(dense layers lead)")
+    routed = dense_layers < L
+    f = hf.get("moe_intermediate_size", 0)
+    shared = hf.get("shared_expert_intermediate_size") or 0
+    if routed and shared % f:
+        raise ValueError(
+            f"{name}: shared_expert_intermediate_size={shared} is no "
+            f"multiple of moe_intermediate_size={f}")
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=kinds[0].num_kv_heads,
+        head_dim=head_dim,
+        max_model_len=hf.get("max_position_embeddings", 8192),
+        rope_theta=kinds[0].rope_theta,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attn_kinds=kinds,
+        layer_kinds=tuple(names.index(t) for t in types),
+        head_gate=bool(gating),
+        dense_layers=dense_layers,
+        router_experts=hf["num_experts"] if routed else 0,
+        num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+        router_scoring="softmax",
+        router_renorm=bool(hf.get("norm_topk_prob", True)),
+        moe_intermediate_size=f,
+        ep_rank=int(hf.get("ep_rank", 0)),
+        ep_size=int(hf.get("ep_size", 1)),
+        shared_experts=shared // f if routed else 0,
+        routed_scaling=float(hf.get("moe_routed_scaling_factor") or 1.0),
     )
 
 

@@ -3,9 +3,13 @@
 `models/llama.py` serves a stack of alike layers under one `lax.scan`.
 This module serves the stacks `ModelConfig.attn_kinds` describes: full
 and window attention layers side by side, each kind with its own kv
-heads, rope theta, window and (where the config says so) a learned sink
-in the softmax; q/k heads wider than v heads, rotary on the leading dims
-of a head only, V scaled before the cache; leading dense layers and then
+heads, QUERY heads (so wq, wo and the GQA group follow the kind), rope
+theta, rotary dims, YaRN and factor on cos and sin, window and (where
+the config says so) a learned sink in the softmax; a per-head output
+gate (`cfg.head_gate`: sigmoid of a linear on the normed layer input,
+one scalar a head and row, on the attention output before W_o); q/k
+heads wider than v heads, rotary on the leading dims of a head only, V
+scaled before the cache; leading dense layers and then
 a routed expert layer over the experts this engine holds
 (`ops/moe.routed_experts`, told its slice by `cfg.ep_rank/ep_size`),
 with a scaling factor and shared experts beside them where the config
@@ -124,12 +128,15 @@ def init_params(
     the hyper-connections' alpha, b and phi non-zero, so that dropping
     one shows against the reference."""
     h, v = cfg.hidden_size, cfg.vocab_size
-    nq, dk, dv = cfg.num_heads, cfg.head_dim, cfg.v_dim
+    dk, dv = cfg.head_dim, cfg.v_dim
     keys = iter(jax.random.split(key, 16 * len(cfg.segments()) + 4))
     # what PR 33 added draws from a stream of its own, so that a model
-    # without it keeps the weights its seed gave it before
+    # without it keeps the weights its seed gave it before; PR 43's gate
+    # from a third
     more = iter(jax.random.split(
         jax.random.fold_in(key, 33), 16 * len(cfg.segments())))
+    gates = iter(jax.random.split(
+        jax.random.fold_in(key, 43), len(cfg.segments())))
 
     def w(shape, fan_in, keys=keys):
         return (jax.random.normal(next(keys), shape, jnp.float32)
@@ -137,8 +144,8 @@ def init_params(
 
     segments = []
     for kind, routed, c, _ in cfg.segments():
-        ak = cfg.attn_kinds[kind]
-        nkv = ak.num_kv_heads
+        ak = cfg.kinds[kind]
+        nq, nkv, rot = ak.num_heads, ak.num_kv_heads, ak.rotary_dim
         lp = {
             "attn_norm": jnp.ones((c, h), dtype),
             "mlp_norm": jnp.ones((c, h), dtype),
@@ -149,10 +156,9 @@ def init_params(
                 "w_dq": w((c, h, r), h, more),
                 "q_norm": jnp.ones((c, r), dtype),
                 "w_uq": w((c, r, nq * dk), r, more),
-                "w_dkv": w((c, h, lat + cfg.rope_dim), h, more),
+                "w_dkv": w((c, h, lat + rot), h, more),
                 "kv_norm": jnp.ones((c, lat), dtype),
-                "w_ukv": w((c, lat, nq * (dk - cfg.rope_dim + dv)), lat,
-                           more),
+                "w_ukv": w((c, lat, nq * (dk - rot + dv)), lat, more),
             }
         else:
             lp |= {
@@ -161,6 +167,8 @@ def init_params(
                 "wv": w((c, h, nkv * dv), h),
             }
         lp["wo"] = w((c, nq * dv, h), nq * dv)
+        if cfg.head_gate:
+            lp["w_head_gate"] = w((c, h, nq), h, gates)
         if cfg.hc_mult > 1:
             n = cfg.hc_mult
             for sub in ("attn", "mlp"):
@@ -263,8 +271,9 @@ def _latent_qkv(cfg, ak, x, lp, kc, l, write_slots, cos, sin, dtype):
     latent + rope) in `dtype`, kc with the rows' [c; rope(k_r)] written
     at `write_slots`, W_uv (latent, nq, d_v))."""
     n = x.shape[0]
-    nq, dk, dv = cfg.num_heads, cfg.head_dim, cfg.v_dim
-    lat, rot = ak.latent_dim, cfg.rope_dim
+    ak = cfg.kind_of(ak)
+    nq, dk, dv = ak.num_heads, cfg.head_dim, cfg.v_dim
+    lat, rot = ak.latent_dim, ak.rotary_dim
     nope = dk - rot
     cq = rms_norm(
         jnp.dot(x, lp["w_dq"], preferred_element_type=F32).astype(dtype),
@@ -298,9 +307,9 @@ def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
     rows that are tokens; the rest (padding, idle lanes, lanes a device
     stop froze) write the null block's slot 0. `h` is (n, hidden), or
     the (hc_mult, n, hidden) streams under hyper-connections."""
-    ak = cfg.attn_kinds[kind]
+    ak = cfg.kinds[kind]
     n = h.shape[-2]
-    nq, nkv = cfg.num_heads, ak.num_kv_heads
+    nq, nkv = ak.num_heads, ak.num_kv_heads
     dk, dv = cfg.head_dim, cfg.v_dim
 
     def proj(x, name, bias):
@@ -340,6 +349,14 @@ def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
         # NaN), and through the null block it writes, which a windowed
         # lane reads, masked, for the pages it let go
         attn_out = jnp.where(real[:, None, None], attn_out, 0)
+        if cfg.head_gate:
+            with jax.named_scope("attn_gate"):
+                # one scalar a head and row, from the normed layer
+                # input, in float32 (rows that are no tokens are zero
+                # above, and their x is finite: nothing to mask again)
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x, lp["w_head_gate"], preferred_element_type=F32))
+                attn_out = attn_out.astype(F32) * gate[:, :, None]
         if w_uv is not None:
             attn_out = jnp.einsum(
                 "nhl,lhd->nhd", attn_out.astype(dtype), w_uv,
@@ -388,7 +405,8 @@ def forward(
     v_cache: dict,          # {"g": per-kind arrays; None for a latent
                             # kind, whose rows are keys and values}
     write_slots: jax.Array,  # (n,) int32 slots in KIND 0's pool
-    attn_fn,                # attn_fn(q, l, kc, vc, spec) -> (n, nq, d_v)
+    attn_fn,                # attn_fn(q, l, kc, vc, spec) -> (n, nq, d_v),
+                            # nq the layer kind's query heads
     logits_rows: jax.Array,
     lora: dict | None = None,
     lora_slots: jax.Array | None = None,
@@ -414,9 +432,9 @@ def forward(
             block_map[write_slots // block_size] * block_size
             + write_slots % block_size
         )
-    rope = [rope_cos_sin(positions, cfg.rope_dim, ak.rope_theta,
-                         cfg.rope_yarn)
-            for ak in cfg.attn_kinds]
+    rope = [rope_cos_sin(positions, ak.rotary_dim, ak.rope_theta,
+                         ak.rope_yarn, ak.rope_factor)
+            for ak in cfg.kinds]
 
     h = params["embed"][token_ids].astype(dtype)
     if cfg.embed_scale != 1.0:

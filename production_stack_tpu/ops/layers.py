@@ -56,14 +56,17 @@ def yarn_inv_freq(head_dim: int, theta: float, yarn) -> jax.Array:
 
 
 def rope_cos_sin(
-    positions: jax.Array, head_dim: int, theta: float, yarn=None
+    positions: jax.Array, head_dim: int, theta: float, yarn=None,
+    factor: float | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """cos/sin tables for the given positions. Returns (N, head_dim) each.
+    """cos/sin tables for the given positions. Returns (N, head_dim) each,
+    `head_dim` being the dims that rotate (a layer kind's `rotary_dim`).
 
     HF-Llama convention: frequencies over the first half of the head dim,
     duplicated across halves (rotate-half formulation). `yarn`: the
-    frequencies blended as YaRN does, and cos/sin scaled by
-    mscale(factor, mscale) / mscale(factor, mscale_all_dim).
+    frequencies blended as YaRN does. `factor` multiplies cos and sin
+    (a kind's `rope_factor`, HF's `attention_factor`); None = YaRN's
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), 1 without.
     """
     half = head_dim // 2
     if yarn is not None:
@@ -76,11 +79,11 @@ def rope_cos_sin(
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(freqs), jnp.cos(freqs)], axis=-1)
     sin = jnp.concatenate([jnp.sin(freqs), jnp.sin(freqs)], axis=-1)
-    if yarn is not None:
-        ratio = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+    if factor is None and yarn is not None:
+        factor = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
             yarn.factor, yarn.mscale_all_dim)
-        if ratio != 1.0:
-            cos, sin = cos * ratio, sin * ratio
+    if factor is not None and factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     return cos, sin
 
 

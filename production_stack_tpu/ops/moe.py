@@ -213,6 +213,13 @@ def routed_experts(
           140 local, stack of 5           16 of 80     983 1654 .59  1328 .74  1102 .89
           115 local, stack of 1           16 of 16     983 1721 .57  1368 .72  1250 .79
           11 local, stack of 5             9 of 80     553  954 .58   739 .75   634 .87
+        laguna (256 of 256, top-8,
+        2048 x 512; PR 43, --seed 43)
+          decode 256 pairs, 48 real       42 of 768    323  593 .54   455 .71   385 .84
+          the same, stack of 1            43 of 256    330  636 .52   520 .64   452 .73
+          decode 256 pairs, all real     160 of 768   1229 2042 .60  1516 .81  1369 .90
+          a chunk's 2,048 pairs          256 of 768   1967 5117 .38  2586 .76  2230 .88
+          2,304 pairs (chunk + lanes)    256 of 768   1967 3383 .58  2606 .76  2251 .87
 
     What is left to the kernel's floor: the first expert's tiles, which
     nothing overlaps (13 us a call at an f tile of 512; the stack of 1

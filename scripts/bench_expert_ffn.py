@@ -1,7 +1,8 @@
 """Microbenchmark of the routed experts' SwiGLU on the chip: the three
 `jax.lax.ragged_dot`s over the whole stack (the form before PR 42, and
 `ops/expert_ffn._plain` off the TPU), megablox's `gmm` three times, and
-the `expert_ffn` kernel, at the two routed cells' shapes, against the
+the `expert_ffn` kernel, at the three routed cells' shapes (`--only
+laguna` for one model's), against the
 floor of the active experts' bytes over the chip's HBM bandwidth.
 `chiprun -- python3 scripts/bench_expert_ffn.py`; `--tiny` is the CPU
 rehearsal of its control flow (no time from it means anything).
@@ -117,6 +118,8 @@ def main():
     ap.add_argument("--row-tiles", default="",
                     help="comma list: time the kernel at each row tile")
     ap.add_argument("--seed", type=int, default=42)
+    ap.add_argument("--only", default="",
+                    help="time the shapes whose name holds this")
     args = ap.parse_args()
     shapes = [
         # name, layers, E_loc, E_all, top_k, d, f, pairs walked, live rows
@@ -130,7 +133,17 @@ def main():
         ("mimo decode, stack of 5", 5, 16, 64, 8, 4096, 2048, 256, 64),
         ("mimo decode, stack of 1", 1, 16, 64, 8, 4096, 2048, 256, 64),
         ("mimo decode, 8 live", 5, 16, 64, 8, 4096, 2048, 256, 8),
+        # every one of 256 experts held, width 512 at hidden 2,048: a
+        # decode step walks its 32 lanes' 256 pairs, a 256-row prefill
+        # chunk's 2,048 pairs in four passes (PR 43)
+        ("laguna decode, 6 live", 3, 256, 256, 8, 2048, 512, 256, 6),
+        ("laguna decode, 6 live, stack of 1", 1, 256, 256, 8, 2048, 512,
+         256, 6),
+        ("laguna decode, 32 live", 3, 256, 256, 8, 2048, 512, 256, 32),
+        ("laguna prefill 256", 3, 256, 256, 8, 2048, 512, 2048, 256),
+        ("laguna ragged 256+6", 3, 256, 256, 8, 2048, 512, 2304, 262),
     ]
+    shapes = [s for s in shapes if args.only in s[0]]
     if args.tiny:
         args.repeat = 1
         shapes = [("tiny decode", 2, 4, 4, 2, 256, 256, 32, 3),

@@ -135,6 +135,13 @@ def test_ragged_kernel_compiles_at_benchmark_widths(v5e, nq, nkv, window):
     # its floor of 128 keys and the ring takes 3 MiB of VMEM
     ("ouro-2.6b-l12.reason-sys2k",
      dict(nq=16, nkv=16, lanes=16, pages=128)),
+    # ONE program, two query widths over 8 kv heads: groups of 6 (not a
+    # power of two, padded to the 8-row tile in a one-row segment) and
+    # of 8; the window kind walks four 128-key KV blocks
+    ("laguna-xs.2-l5.chat-doc16k, full layers",
+     dict(nq=48, nkv=8, lanes=32, pages=1024)),
+    ("laguna-xs.2-l5.chat-doc16k, window layers",
+     dict(nq=64, nkv=8, lanes=32, pages=1024, window=512)),
 ])
 @pytest.mark.parametrize("prefill_rows", [0, 512])
 def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
@@ -163,6 +170,8 @@ def test_walk_compiles_at_the_cells_shapes(v5e, cell, shape, prefill_rows):
      dict(nkv=4, lanes=64, d=256, d_v=128)),
     # a row block's 16 tiles of 16 heads are 1 MiB an array in VMEM
     ("ouro-2.6b-l12.reason-sys2k", dict(nkv=16, lanes=16)),
+    # both cache groups: 8 kv heads x 128, whatever the query heads
+    ("laguna-xs.2-l5.chat-doc16k", dict(nkv=8, lanes=32)),
     # (xing4-29b-l8.chat-doc16k: a latent kind's one row a layer stays
     # one XLA scatter, layer_groups._latent_qkv)
 ])
@@ -216,6 +225,14 @@ def test_sinkhorn_kernel_compiles(v5e, rows):
     ("mimo-v2.5-ep16-l7.batch-doc8k, the full layer", 1, 16, 4096, 2048,
      256),
     ("a few lanes", 6, 64, 3584, 1024, 32),
+    # every one of 256 experts held: 768 groups in the window run's
+    # stack, a 256 x 256 packing mask, one f tile an expert
+    ("laguna-xs.2-l5.chat-doc16k, decode, the window run", 3, 256, 2048,
+     512, 256),
+    ("laguna-xs.2-l5.chat-doc16k, decode, the full layer", 1, 256, 2048,
+     512, 256),
+    ("laguna-xs.2-l5.chat-doc16k, a prefill chunk's pass", 3, 256, 2048,
+     512, 512),
 ])
 def test_expert_ffn_compiles_at_the_cells_shapes(
     v5e, cell, stack, e_loc, d, f, rows
