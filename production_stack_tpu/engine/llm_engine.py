@@ -3705,6 +3705,7 @@ class LLMEngine:
             engine_phases=self.phases.pairs(),
             engine_phases_offcpu=self.phases.offcpu_pairs(),
             attn_context_tokens=tuple(self.runner.attn_context_tokens),
+            attn_lane_tokens=tuple(self.runner.attn_lane_tokens),
             decode_lane_steps=tuple(self.runner.decode_lane_steps),
             sampler_steps=tuple(self.runner.sampler_steps),
             loop_passes_total=self.runner.loop_passes,

@@ -178,7 +178,9 @@ class EngineMetrics:
                 "Context tokens the attention calls of a dispatched "
                 "round had to read once (sum) per round (count): each "
                 "decode lane's context at each fused step, each "
-                "prefill chunk's end context",
+                "prefill chunk's end context; a run of pages that the "
+                "decode lanes of a row block share counts once for the "
+                "block (tpu:attn_lane_context_tokens counts every lane)",
             "tpu:loop_exit_pass":
                 "A looped stack with an exit gate: the pass at which a "
                 "sampled row would leave under the gate (sum: pass x "
@@ -445,6 +447,22 @@ class EngineMetrics:
             "counted here",
             label, registry=reg,
         )
+        self.attn_lane_context_tokens = Counter(
+            "tpu:attn_lane_context_tokens",
+            "Context tokens the decode lanes and prefill chunks of the "
+            "dispatched rounds attended, each lane's own count "
+            "(tpu:attn_context_tokens counts what the walk streams: a "
+            "run of pages that the lanes of a row block share, once)",
+            label, registry=reg,
+        )
+        self.attn_shared_context_tokens = Counter(
+            "tpu:attn_shared_context_tokens",
+            "Of tpu:attn_lane_context_tokens, those a shared pass "
+            "served: the leading keys that every decode lane of a row "
+            "block reads from the same pages (one cached prefix), "
+            "walked once for the block",
+            label, registry=reg,
+        )
         self.sampler_steps = Counter(
             "tpu:sampler_steps",
             "Evaluations of the sampler by the dispatched rounds: a "
@@ -660,9 +678,12 @@ class EngineMetrics:
             0, s.decode_rounds_total - prev.decode_rounds_total))
         for counter, now, was in zip(
                 (self.decode_lane_steps, self.decode_idle_lane_steps,
-                 self.sampler_steps, self.sampler_window_steps),
-                s.decode_lane_steps + s.sampler_steps,
-                prev.decode_lane_steps + prev.sampler_steps):
+                 self.sampler_steps, self.sampler_window_steps,
+                 self.attn_lane_context_tokens,
+                 self.attn_shared_context_tokens),
+                s.decode_lane_steps + s.sampler_steps + s.attn_lane_tokens,
+                prev.decode_lane_steps + prev.sampler_steps
+                + prev.attn_lane_tokens):
             counter.labels(m).inc(max(0, now - was))
         self.loop_passes.labels(m).inc(max(
             0, s.loop_passes_total - prev.loop_passes_total))

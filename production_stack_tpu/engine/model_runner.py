@@ -82,6 +82,41 @@ PROGRAM_KINDS = (
 )
 
 
+def shared_runs(tables: np.ndarray, ctx: np.ndarray, block_size: int
+                ) -> np.ndarray:
+    """(row blocks, 2) int32: per row block of RAGGED_TQ decode lanes,
+    the leading keys that EVERY lane of it with a sequence (context > 0)
+    reads from the same physical pages, and the first such lane, whose
+    table row addresses them for all. That is the longest common
+    leading run of those lanes' rows of the packed (b, P) table, cut to
+    the smallest of their contexts less one: a prefix that prefix
+    caching holds once. The walk streams it once for the block
+    (`pallas_attention._ragged_kernel`, which cuts it down to its KV
+    block) and not once a lane. 0 where fewer than two lanes hold a
+    sequence."""
+    tq = RAGGED_TQ
+    b, n_pages = tables.shape
+    n_blk = _ceil_tq(b) // tq
+    rows = np.zeros((n_blk * tq, n_pages), np.int32)
+    rows[:b] = tables
+    rows = rows.reshape(n_blk, tq, n_pages)
+    lens = np.zeros((n_blk * tq,), np.int64)
+    lens[:b] = ctx
+    lens = lens.reshape(n_blk, tq)
+    live = lens > 0
+    first = live.argmax(axis=1)
+    blk = np.arange(n_blk)
+    same = ((rows == rows[blk, first][:, None]) | ~live[:, :, None]
+            ).all(axis=1)
+    run = np.where(same.all(axis=1), n_pages, same.argmin(axis=1))
+    least = np.where(live, lens, np.iinfo(np.int32).max).min(axis=1)
+    keys = np.maximum(np.minimum(run * block_size, least - 1), 0)
+    runs = np.zeros((n_blk, 2), np.int32)
+    runs[:, 0] = np.where(live.sum(axis=1) >= 2, keys, 0)
+    runs[:, 1] = blk * tq + first
+    return runs
+
+
 def jit_program(kind: str, fn, **jit_kw):
     """`jax.jit(fn)` under the name of its kind."""
     assert kind in PROGRAM_KINDS, kind
@@ -355,6 +390,30 @@ class ModelRunner:
         # LAYER of that kind read (tpu:attn_context_tokens_<kind>)
         self._kind_windows = [ak.window for ak in mc.attn_kinds]
         self.attn_context_by_kind = [[0] for _ in mc.attn_kinds]
+        # lanes that share a prefix (a row block's shared run, see
+        # `_shared_runs`): the context tokens the lanes attended, each
+        # lane's own count, and those of them that a shared pass served
+        # (tpu:attn_lane_context_tokens, tpu:attn_shared_context_
+        # tokens). tpu:attn_context_tokens and its per-kind siblings
+        # count what the walk STREAMS: a shared run once a row block
+        self.attn_lane_tokens = [0, 0]
+        # keys of the walk's KV block, which a shared run is cut down
+        # to a multiple of: per attention kind, and the model's own (a
+        # layer-group model's: its kind 0, the pool every token takes);
+        # 0 where the walk has a window, and so no leading run
+        self._kind_run_keys = [
+            0 if ak.window else self._kv_block_keys(
+                ak.num_kv_heads, self._k_store_dim(i),
+                0 if ak.latent_dim else mc.v_dim)
+            for i, ak in enumerate(mc.attn_kinds)]
+        self._run_keys = (
+            self._kind_run_keys[0] if mc.attn_kinds
+            else 0 if mc.sliding_window else self._kv_block_keys(
+                mc.num_kv_heads, mc.head_dim, mc.head_dim))
+        # the shared runs of the decode pack filled last
+        # (`_fill_decode_pack`), for the dispatch's counters or the
+        # staged handle that carries them to it
+        self._packed_runs: np.ndarray | None = None
         phases.install_program_listeners()
 
         # jit caches keyed by bucket tuple
@@ -380,6 +439,14 @@ class ModelRunner:
         self.compile_events_total = 0
 
         self.max_ctx_bucket = self._ctx_bucket(self.max_model_len)
+
+    def _kv_block_keys(self, nkv: int, d_k: int, d_v: int) -> int:
+        from production_stack_tpu.ops.pallas_attention import (
+            _kv_block_pages,
+        )
+
+        return self.block_size * _kv_block_pages(
+            nkv, d_k, self.cache_dtype.itemsize, self.block_size, d_v)
 
     @staticmethod
     def _unaligned_heads(mc: ModelConfig) -> bool:
@@ -846,13 +913,15 @@ class ModelRunner:
     # attention call goes through (trace-time only: closed over by the
     # jitted step builders); collapses the former per-site
     # `mesh is not None -> *_tp else *` call ladders
-    def _attn(self, kind: str, q, layer, kc, vc, *args, spec=None):
+    def _attn(self, kind: str, q, layer, kc, vc, *args, spec=None,
+              shared=None):
         """Route one attention call to the pallas kernel for `kind`
         ("prefill": a lone chunk | "ragged": every batched call),
         picking the shard_map TP variant under a mesh and filling the
         static block-size/scale/interpret/window arguments from the
         runner's config. All kernel call sites dispatch through
-        here."""
+        here. `shared`: the row blocks' shared runs of a ragged call
+        (`_shared_runs`)."""
         from production_stack_tpu.ops import pallas_attention
 
         fns = {
@@ -893,7 +962,12 @@ class ModelRunner:
             q = jnp.pad(
                 q, ((0, 0), (0, 0), (0, kc.shape[-1] - q.shape[-1])))
         if self.mesh is not None:
+            # no shared run under a mesh (the pack ships zeros there,
+            # `_shared_runs`): the shard_map wrappers do not take one,
+            # and no cell runs them
             return fns[1](q, kc, vc, layer, *args, mesh=self.mesh, **kw)
+        if shared is not None:
+            kw["shared"] = shared
         return fns[0](q, kc, vc, layer, *args, **kw)
 
     def _write_kv(self, kc, vc, l, write_slots, k, v):
@@ -993,13 +1067,18 @@ class ModelRunner:
     # -- pipelined prefill: fused h2d buffer --------------------------------
     def _note_attn_context(
         self, decode_lens=(), steps: int = 0, prefill_lens=(),
-        forwards: int | None = None,
+        forwards: int | None = None, runs: np.ndarray | None = None,
     ) -> None:
         """Count one dispatched round's attention reads: each decode
         lane's context at each of its `steps` fused steps (context + i
         at step i) and each prefill chunk's END context once, both cut
         to the sliding window where the model has one. A lane that a
         device stop freezes mid-round is counted to the round's end.
+        Where the round's pack found shared runs (`runs`, the decode
+        lanes in the pack's order), a run counts ONCE a row block and
+        step into what the walk streams (tpu:attn_context_tokens, and
+        each kind's at its own KV block), every lane's into the
+        lane-tokens attended and, of those, served by a shared pass.
         And the decode rows' lane-steps, with those of lanes that hold
         no sequence (a frozen lane is no idle one here: the host packed
         it live). And the passes of the layer stack: `forwards` calls
@@ -1020,16 +1099,36 @@ class ModelRunner:
             tokens = sum(
                 min(c + i, w) for c in decode_lens for i in range(k)
             ) + sum(min(c, w) for c in prefill_lens)
+
+        def run_tokens(kv_block: int) -> tuple[int, int]:
+            """(lane-tokens a step that a shared pass serves, tokens a
+            step that it spares the walk) at a KV block of that many
+            keys: the lanes with a sequence are the first `n`."""
+            served = spared = 0
+            if runs is not None and kv_block:
+                for blk, keys in enumerate(runs[:, 0].tolist()):
+                    cut = keys // kv_block * kv_block
+                    lanes_in = min(n - blk * RAGGED_TQ, RAGGED_TQ)
+                    if cut and lanes_in > 1:
+                        served += cut * lanes_in
+                        spared += cut * (lanes_in - 1)
+            return served, spared
+
+        served, spared = run_tokens(self._run_keys)
         cell = self.attn_context_tokens
-        cell[0] += tokens
+        cell[0] += tokens - k * spared
         cell[1] += 1
-        for window, by_kind in zip(self._kind_windows,
-                                   self.attn_context_by_kind):
+        self.attn_lane_tokens[0] += tokens
+        self.attn_lane_tokens[1] += k * served
+        for window, by_kind, kv_block in zip(
+                self._kind_windows, self.attn_context_by_kind,
+                self._kind_run_keys):
             # per layer of that kind: the window kind cut to its window
             cut = window or (1 << 62)
             by_kind[0] += sum(
                 min(c + i, cut) for c in decode_lens for i in range(k)
-            ) + sum(min(c, cut) for c in prefill_lens)
+            ) + sum(min(c, cut) for c in prefill_lens) - (
+                k * run_tokens(kv_block)[1])
 
     def note_sampler(self, steps: int, temps) -> None:
         """Count `steps` evaluations of `sample_tokens` over rows of
@@ -1968,12 +2067,15 @@ class ModelRunner:
         single-row segments of the one grid — the SAME program the
         mixed rounds launch), or the XLA gather path. `tables` =
         padded per-sequence block tables (b, pages) on the pallas
-        path, per-position gather slots (b, c_pad) on the XLA path."""
+        path, per-position gather slots (b, c_pad) on the XLA path.
+        `shared`: the row blocks' shared runs as the round's pack
+        found them (`_shared_runs`), nothing to the XLA path."""
         scale = self._scale
         if self.attention_impl == "pallas":
             tq = RAGGED_TQ
 
-            def attn(q, l, kc, vc, tables, context_lens, spec=None):
+            def attn(q, l, kc, vc, tables, context_lens, spec=None,
+                     shared=None):
                 b = q.shape[0]
                 r_pad = _ceil_tq(b)
                 n_blk = r_pad // tq
@@ -1995,12 +2097,13 @@ class ModelRunner:
                 ], axis=1)
                 out = self._attn(
                     "ragged", qp, l, kc, vc, tables, blk_seg, seg_meta,
-                    spec=spec,
+                    spec=spec, shared=shared,
                 )
                 return out[:b]
         else:
 
-            def attn(q, l, kc, vc, tables, context_lens, spec=None):
+            def attn(q, l, kc, vc, tables, context_lens, spec=None,
+                     shared=None):
                 # (b, c, nkv, d)
                 k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
                 return xla_attn.context_attention_decode(
@@ -2063,6 +2166,9 @@ class ModelRunner:
             ("keys", (b, 2)),
             ("page_tables", (b, n_pages)),
         ]
+        if self.attention_impl == "pallas":
+            # a row block's [shared_keys, lane] (`_shared_runs`)
+            fields.append(("shared_run", (_ceil_tq(b) // RAGGED_TQ, 2)))
         if guided:
             # per-lane DFA state + machine row (the big tables travel
             # separately, device-cached across dispatches)
@@ -2216,6 +2322,8 @@ class ModelRunner:
                     page_tables if use_pages
                     else _seg(packed, "gather_tables")
                 ),
+                "shared_run": (
+                    _seg(packed, "shared_run") if use_pages else None),
                 "presence": presence,
                 "frequency": frequency,
                 "repetition": repetition,
@@ -2292,6 +2400,7 @@ class ModelRunner:
             tokens, positions, write_slots, ctx = fwd_args(carry, consts)
             attn_fn = functools.partial(
                 attn, tables=consts["attn_tables"], context_lens=ctx,
+                shared=consts["shared_run"],
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
@@ -2568,6 +2677,17 @@ class ModelRunner:
             out[i, :use] = row[:use]
         self._kept_rows = seen
         return out
+
+    def _shared_runs(self, tables: np.ndarray, ctx: np.ndarray
+                     ) -> np.ndarray:
+        """The decode pack's shared runs (`shared_runs`), found once a
+        round: the tables are grown for the round's steps before the
+        pack, and contexts only grow inside it. Zeros under a mesh:
+        the shard_map wrappers take no run (`_attn`), and no cell runs
+        them."""
+        if self.mesh is not None:
+            return np.zeros((_ceil_tq(len(ctx)) // RAGGED_TQ, 2), np.int32)
+        return shared_runs(tables, ctx, self.block_size)
 
     def _gather_slots_for_table(
         self, block_table: list[int], c_pad: int
@@ -3198,8 +3318,13 @@ class ModelRunner:
         ctx[:b_actual] = context_lens
         put("ctx", ctx)
 
-        put("page_tables", self._page_table_rows(
-            block_tables, b, c_pad // self.block_size))
+        tables = self._page_table_rows(
+            block_tables, b, c_pad // self.block_size)
+        put("page_tables", tables)
+        self._packed_runs = None
+        if "shared_run" in layout:
+            self._packed_runs = self._shared_runs(tables, ctx)
+            put("shared_run", self._packed_runs)
         if self.attention_impl != "pallas":
             gather_tables = np.zeros((b, c_pad), dtype=np.int32)
             for i in range(b_actual):
@@ -3264,7 +3389,8 @@ class ModelRunner:
         min_rem/budget countdowns — advanced by K on the same lanes)
         and validates the prediction before dispatching on it; a stale
         stage (ctx-bucket mismatch) is ignored by decode_multi.
-        Returns (c_pad, device_array) for decode_multi(staged=...)."""
+        Returns (c_pad, device_array, the pack's shared runs) for
+        decode_multi(staged=...)."""
         with self.phases.span("pack"):
             c_pad = self._ctx_bucket(
                 max(context_lens) + max(0, steps - 1)
@@ -3274,7 +3400,7 @@ class ModelRunner:
                 temps, top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
             )
         with self.phases.span("h2d"):
-            handle = (c_pad, jax.device_put(packed))
+            handle = (c_pad, jax.device_put(packed), self._packed_runs)
         return handle
 
     def _decode_pen_kwargs(
@@ -3446,7 +3572,7 @@ class ModelRunner:
                 b, c_pad, chained, guided=False, stop_cap=stop_cap,
             )
             if int(staged[1].shape[0]) == want_total:
-                packed_dev = staged[1]
+                packed_dev, runs = staged[1:]
         if packed_dev is None:
             with self.phases.span("pack"):
                 packed = self._fill_decode_pack(
@@ -3454,6 +3580,7 @@ class ModelRunner:
                     context_lens, temps, top_ps, top_ks, keys,
                     min_ps=min_ps, guided_lanes=guided_lanes, stop=stop,
                 )
+                runs = self._packed_runs
             with self.phases.span("h2d"):
                 packed_dev = jnp.asarray(packed)
 
@@ -3491,7 +3618,7 @@ class ModelRunner:
                 "lora_slots": jnp.asarray(slots),
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        self._note_attn_context(context_lens, steps)
+        self._note_attn_context(context_lens, steps, runs=runs)
         self.note_sampler(steps, temps)
         with self.phases.span("dispatch"), build:
             ys, self.k_cache, self.v_cache = fn(
@@ -3830,11 +3957,19 @@ class ModelRunner:
                 ),
             ])
 
+            # the decode row blocks' shared runs, their lanes counted
+            # from the decode lanes' place in the tables; a prefill
+            # block has none
+            shared = jnp.concatenate([
+                jnp.zeros((n_pf_blk, 2), jnp.int32),
+                consts["shared_run"] + jnp.asarray([0, s_cap], jnp.int32),
+            ])
+
             def attn_fn(q, l, kcc, vcc, spec=None):
                 qp = jnp.pad(q, ((0, b_pad - b), (0, 0), (0, 0)))
                 out = self._attn(
                     "ragged", qp, l, kcc, vcc, tables_cat, blk_seg,
-                    seg_meta, spec=spec,
+                    seg_meta, spec=spec, shared=shared,
                 )
                 return out[:r_pad + b]
 
@@ -3914,7 +4049,7 @@ class ModelRunner:
                 )
                 key = ("ragged", s_pad, t_pad, pc_pad, c_pad)
         with self.phases.span("h2d"):
-            handle = (key, jax.device_put(packed))
+            handle = (key, jax.device_put(packed), self._packed_runs)
         return handle
 
     # stackcheck: hot-path — ONE dispatch serves the whole lane-typed
@@ -3994,7 +4129,7 @@ class ModelRunner:
                 guided=False, stop_cap=stop_cap,
             ))
             if int(staged[1].shape[0]) == want_total:
-                packed_dev = staged[1]
+                packed_dev, runs = staged[1:]
         if packed_dev is None:
             with self.phases.span("pack"):
                 _s, _t, _pc, packed = self._fill_ragged_pack(
@@ -4005,6 +4140,7 @@ class ModelRunner:
                     guided_lanes=guided_lanes, stop=stop,
                     pf_budgets=pf_budgets, dec_budgets=dec_budgets,
                 )
+                runs = self._packed_runs
             with self.phases.span("h2d"):
                 packed_dev = jnp.asarray(packed)
 
@@ -4049,7 +4185,7 @@ class ModelRunner:
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
         self._note_attn_context(context_lens, steps, pf_total_lens,
-                                forwards=steps + 1)
+                                forwards=steps + 1, runs=runs)
         self.note_sampler(steps, temps)
         self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:
@@ -4105,7 +4241,7 @@ class ModelRunner:
                 guided=False, stop_cap=stop_cap,
             ))
             if int(staged[1].shape[0]) == want_total:
-                packed_dev = staged[1]
+                packed_dev, runs = staged[1:]
         if packed_dev is None:
             with self.phases.span("pack"):
                 _r, _pc, packed = self._fill_ragged_rows_pack(
@@ -4116,6 +4252,7 @@ class ModelRunner:
                     guided_lanes=guided_lanes, stop=stop,
                     pf_budgets=pf_budgets, dec_budgets=dec_budgets,
                 )
+                runs = self._packed_runs
             with self.phases.span("h2d"):
                 packed_dev = jnp.asarray(packed)
 
@@ -4162,7 +4299,8 @@ class ModelRunner:
                 "pf_lora_slots": jnp.asarray(pf_rows),
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        self._note_attn_context(context_lens, steps, pf_total_lens)
+        self._note_attn_context(context_lens, steps, pf_total_lens,
+                                runs=runs)
         self.note_sampler(steps, temps)
         self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:

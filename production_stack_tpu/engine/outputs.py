@@ -76,6 +76,11 @@ class EngineStatsSnapshot:
     # (tokens, rounds): context tokens the attention calls of the
     # dispatched rounds had to read once — tpu:attn_context_tokens
     attn_context_tokens: tuple = (0, 0)
+    # (context tokens the lanes attended, each lane's own count; those
+    # of them a shared pass served: a run of pages the decode lanes of
+    # a row block share, which the walk streams once) —
+    # tpu:attn_lane_context_tokens, tpu:attn_shared_context_tokens
+    attn_lane_tokens: tuple = (0, 0)
     # (lanes x fused steps of the dispatched rounds' decode rows, those
     # the host packed as zero-row segments: lanes holding no sequence)
     # — tpu:decode_lane_steps, tpu:decode_idle_lane_steps

@@ -63,6 +63,16 @@ replaces the gather-based XLA path in ops/attention.py on TPU):
   every q head alike (nkv = 1), so the walk copies each page once and
   feeds the same VMEM block to both products.
 
+- A SHARED RUN (the ragged kernel; `_attend`): decode lanes over
+  one cached prefix hold the same pages at the head of their tables.
+  Where the caller says so for a row block, those keys are walked ONCE
+  for the block's rows fused as the tall tile, the un-normalised
+  (m, l, acc) of every row is kept in VMEM, and each lane's own walk
+  starts where the run ends, from its rows of that state (as a sink's
+  start state, which then starts the shared pass). The run ends at an
+  absolute multiple of the KV block, so every row still sums the same
+  keys in the same blocks in the same order: the same bits.
+
 Numerics match ops/attention.py (f32 softmax, same masking); parity is
 enforced by tests/test_pallas_attention.py in interpret mode on CPU.
 """
@@ -169,18 +179,20 @@ def _walk(
                         # DMA sems (_KV_RING, 2). A latent kind has
                         # neither v_cache nor v_buf (None)
     static,             # block_size, num_pages, scale, window, latent_v
-    sink=None,          # (nkv, rows, 1) float32 logits, or None
+    state,              # (m, l, acc) the online softmax starts from:
+                        # `_start_state`, or what an earlier walk over
+                        # the keys before `lo` returned
 ):
     """THE page walk: causal (and windowed) attention of `rows` fused
     query rows over keys [lo, hi) of one sequence's pages, online
     softmax over KV blocks of N pages at absolute multiples of N.
-    Returns the normalised (nkv, rows, d_v) float32 output. hi <= lo
-    walks nothing and starts no copy (and gives zeros)."""
+    Returns the un-normalised state (m, l, acc), float32 (nkv, rows, 1),
+    (nkv, rows, 1) and (nkv, rows, d_v): `_normalised` makes the output
+    of it, another walk over the keys from `hi` on may start from it.
+    hi <= lo walks nothing, starts no copy and returns `state`."""
     k_cache_ref, v_cache_ref, k_buf, v_buf, sem = kv
     latent_v = static["latent_v"]
     ring, nkv, c, _ = k_buf.shape
-    d = latent_v or v_buf.shape[-1]
-    rows = q.shape[1]
     bs, scale, window = static["block_size"], static["scale"], static["window"]
     n = c // bs
     b_lo = jax.lax.div(lo, c)
@@ -250,15 +262,24 @@ def _walk(
         return m_new, l_new, acc * corr + _pv(
             p, k_buf[slot, :, :, :latent_v] if latent_v else v_buf[slot])
 
+    return jax.lax.fori_loop(b_lo, b_hi, body, state)
+
+
+def _start_state(nkv, rows, d_v, sink=None):
+    """What the online softmax of `rows` query rows a kv head starts
+    from: nothing seen, or the sink ((nkv, rows, 1) float32 logits), a
+    key whose logit is given and whose value is 0."""
     if sink is None:
         m0 = jnp.full((nkv, rows, 1), MASK_VALUE, jnp.float32)
         l0 = jnp.zeros((nkv, rows, 1), jnp.float32)
     else:
-        # the sink is a key whose logit is given and whose value is 0
         m0 = sink
         l0 = jnp.ones((nkv, rows, 1), jnp.float32)
-    acc0 = jnp.zeros((nkv, rows, d), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(b_lo, b_hi, body, (m0, l0, acc0))
+    return m0, l0, jnp.zeros((nkv, rows, d_v), jnp.float32)
+
+
+def _normalised(state):
+    _, l, acc = state
     return acc / jnp.maximum(l, 1e-30)
 
 
@@ -275,6 +296,9 @@ def _attend(
     static,
     *,
     one_row: bool,
+    run=None,           # the ragged kernel's shared run of this row
+                        # block: (its keys, the (m, l, acc) VMEM refs
+                        # of its state, whether this turn IS the run)
 ):
     """One segment — `n_rows` contiguous positions of one sequence, in
     tile rows [row0, row0 + n_rows) — attends its context and stores its
@@ -284,7 +308,19 @@ def _attend(
     8-row sublane tile; any other fuses the whole tile as
     (nkv, TQ*g, d), row t*g + j being head j of tile row t, and rows
     outside the segment compute garbage the masked store never
-    writes."""
+    writes.
+
+    A row block's SHARED RUN (`_ragged_kernel`) goes through the tall
+    tile as a segment of its own kind: every row of the tile at the
+    run's last key, `qpos0` (each key of the run lies before every
+    row's own position, so the mask passes them all, as it does in a
+    lane's walk alone), and what is kept is the un-normalised state of
+    every row, in `run`'s refs ((TQ, nkv, g padded to 8, .) VMEM each,
+    m and l over 128 lanes). A one-row segment of that block starts
+    where the run ends, from its rows of that state, picked by `row0`:
+    an index on an untiled axis, no g rows cut out of a sublane tile.
+    Tile rows of lanes that hold no sequence compute garbage that no
+    segment picks up."""
     tq, nq, d = q_ref.shape
     k_buf = kv[2]
     nkv = k_buf.shape[1]
@@ -294,8 +330,12 @@ def _attend(
     # cache has the model's dtype (the reshapes want 32-bit rows)
     q_dtype = jnp.promote_types(q_ref.dtype, k_buf.dtype)
     window = static["window"]
+    run_keys, run_refs, is_run = run or (None, None, None)
+    # a run's rows stand still at `qpos0`, a segment's go up by one
+    step = 1 if run is None or one_row else jnp.where(is_run, 0, 1)
     hi = jnp.minimum(
-        qpos0 + n_rows, static["num_pages"] * static["block_size"]
+        qpos0 + 1 + step * (n_rows - 1),
+        static["num_pages"] * static["block_size"],
     )
     lo = jnp.int32(0)
     if window is not None:
@@ -311,10 +351,16 @@ def _attend(
             q = jnp.concatenate([q, pad], axis=1)
             if sink is not None:
                 sink = jnp.concatenate([sink, pad[:, :, :1]], axis=1)
-        out = _walk(
+        state = _start_state(nkv, q.shape[1], d_v, sink)
+        if run is not None:
+            lo = run_keys
+            state = tuple(
+                jnp.where(lo > 0, ref[row0][:, :, :x.shape[-1]], x)
+                for ref, x in zip(run_refs, state))
+        out = _normalised(_walk(
             q.astype(q_dtype), qpos0, lo, hi, page_of, layer, kv, static,
-            sink,
-        )
+            state,
+        ))
         out_ref[row0] = out[:, :g].reshape(nq, d_v).astype(out_ref.dtype)
         return
 
@@ -328,33 +374,57 @@ def _attend(
     if sink is not None:
         # fused row t*g + j is head j of tile row t
         sink = jnp.concatenate([sink] * tq, axis=1)
-    out = _walk(
-        q.astype(q_dtype), qpos0 + (row_of - row0), lo, hi, page_of,
-        layer, kv, static, sink,
+    state = _walk(
+        q.astype(q_dtype), qpos0 + step * (row_of - row0), lo, hi,
+        page_of, layer, kv, static, _start_state(nkv, tq * g, d_v, sink),
     )
-    out = (
-        out.reshape(nkv, tq, g, d_v)
-        .transpose(1, 0, 2, 3)
-        .reshape(tq, nq, d_v)
-    )
-    # row-masked merge: segments of one block write disjoint row
-    # ranges sequentially (read-modify-write within the program)
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (tq, 1, 1), 0)
-    keep = (row_ids >= row0) & (row_ids < row0 + n_rows)
-    out_ref[...] = jnp.where(keep, out.astype(out_ref.dtype), out_ref[...])
+
+    def by_tile_row(x):
+        """(nkv, TQ*g, w) of the tall tile -> (TQ, nkv, g, w)."""
+        return x.reshape(nkv, tq, g, x.shape[-1]).transpose(1, 0, 2, 3)
+
+    def store_rows():
+        out = by_tile_row(_normalised(state)).reshape(tq, nq, d_v)
+        # row-masked merge: segments of one block write disjoint row
+        # ranges sequentially (read-modify-write within the program)
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (tq, 1, 1), 0)
+        keep = (row_ids >= row0) & (row_ids < row0 + n_rows)
+        out_ref[...] = jnp.where(
+            keep, out.astype(out_ref.dtype), out_ref[...])
+
+    def keep_state():
+        for ref, x in zip(run_refs, state):
+            w = ref.shape[-1]
+            x = by_tile_row(jnp.broadcast_to(x, (nkv, tq * g, w)))
+            if g % 8:
+                x = jnp.concatenate(
+                    [x, jnp.zeros((tq, nkv, 8 - g % 8, w), jnp.float32)],
+                    axis=2)
+            ref[...] = x
+
+    if run is None:
+        store_rows()
+    else:
+        pl.when(is_run)(keep_state)
+        pl.when(jnp.logical_not(is_run))(store_rows)
 
 
 def _refs(rest, static):
-    """(sink_ref or None, out_ref, kv) from a kernel's refs after q:
-    k_cache, v_cache, [sink,] out, k_buf, v_buf, sems — without v_cache
-    and v_buf for a latent kind, whose kv carries None in their place."""
+    """(sink_ref or None, out_ref, kv, run refs or None) from a kernel's
+    refs after q: k_cache, v_cache, [sink,] out, k_buf, v_buf, sems,
+    [m, l, acc of a shared run] — without v_cache and v_buf for a
+    latent kind, whose kv carries None in their place."""
+    run = None
+    if static["run"]:
+        *rest, m_ref, l_ref, acc_ref = rest
+        run = (m_ref, l_ref, acc_ref)
     if static["latent_v"]:
         k_cache_ref, *sink_ref, out_ref, k_buf, sem = rest
         v_cache_ref = v_buf = None
     else:
         k_cache_ref, v_cache_ref, *sink_ref, out_ref, k_buf, v_buf, sem = rest
     return (sink_ref[0] if sink_ref else None, out_ref,
-            (k_cache_ref, v_cache_ref, k_buf, v_buf, sem))
+            (k_cache_ref, v_cache_ref, k_buf, v_buf, sem), run)
 
 
 def _decode_kernel(
@@ -371,14 +441,15 @@ def _decode_kernel(
                         # k_buf (_KV_RING, nkv, N*bs, d_k), v_buf VMEM,
                         # DMA sems (_KV_RING, 2); no v_cache_ref and no
                         # v_buf for a latent kind (`_refs`)
-    **static,           # block_size, num_pages, scale, window, latent_v
+    **static,           # block_size, num_pages, scale, window, latent_v,
+                        # run
 ):
     """One grid program per sequence: its one query row at position
     ctx_len - 1 over its own pages (the sliding window, HF semantics:
     keys j > q_pos - window, starts the walk at the window's first KV
     block)."""
     i = pl.program_id(0)
-    sink_ref, out_ref, kv = _refs(rest, static)
+    sink_ref, out_ref, kv, _ = _refs(rest, static)
     _attend(
         q_ref, sink_ref, out_ref, 0, 1,
         context_lens_ref[i] - 1,
@@ -411,7 +482,7 @@ def _prefill_kernel(
     never built, and later tiles see (and stream) more pages.
     """
     tq = q_ref.shape[0]
-    sink_ref, out_ref, kv = _refs(rest, static)
+    sink_ref, out_ref, kv, _ = _refs(rest, static)
     _attend(
         q_ref, sink_ref, out_ref, 0, tq,
         meta_ref[1] + pl.program_id(0) * tq,
@@ -428,6 +499,8 @@ def _ragged_kernel(
                         # [blk_seg[i], blk_seg[i+1])
     seg_meta_ref,       # (SC, 4) int32 — per segment:
                         # [lane, row0_in_block, n_rows, q_pos_of_row0]
+    shared_ref,         # (G, 2) int32 — per row block: [shared_keys,
+                        # the lane whose table row addresses them]
     block_tables_ref,   # (S, P) int32 — per-LANE page tables
     # array inputs
     q_ref,              # (TQ, nq, d_k) VMEM — this block's query rows
@@ -458,42 +531,69 @@ def _ragged_kernel(
     row is then a number whatever VMEM held (it goes on through the
     layer and is written to the null block, which a windowed lane
     reads masked: 0 x NaN is NaN).
+
+    A SHARED RUN: where the caller says that every one-row segment of a
+    row block reads its first `shared_keys` keys from the same pages
+    (decode lanes over one cached prefix), those keys go through the
+    walk once, as one tall tile (a turn of the segment loop of its own,
+    before the block's first segment; `_attend`), and each segment's
+    own walk starts where the run ends, from its rows of the run's
+    state. The run is cut down to a multiple of this kernel's KV block;
+    0 (and a kind with a window, whose walk starts at its window) is
+    the walk of each segment alone, operation for operation. The
+    caller's promise: the run's pages are the first pages of every
+    one-row segment with a row, and shared_keys is below the position
+    of each.
     """
     i = pl.program_id(0)
-    sink_ref, out_ref, kv = _refs(rest, static)
+    sink_ref, out_ref, kv, refs = _refs(rest, static)
+    first = start = blk_seg_ref[i]
+    if refs is not None:
+        c = kv[2].shape[2]
+        shared = shared_ref[i, 0] // c * c
+        start = first - (shared > 0).astype(jnp.int32)
 
     def seg_body(s, _):
-        lane = seg_meta_ref[s, 0]
-        n_rows = seg_meta_ref[s, 2]
+        seg = [seg_meta_ref[jnp.maximum(s, first), col] for col in range(4)]
+        run = None
+        if refs is not None:
+            # the turn before the block's first segment is its shared
+            # run's, where it has one: a segment of the whole tile, its
+            # rows all at the run's last key, over the named lane's pages
+            is_run = s < first
+            seg = [jnp.where(is_run, of_run, of_seg) for of_run, of_seg
+                   in zip((shared_ref[i, 1], 0, q_ref.shape[0], shared - 1),
+                          seg)]
+            run = (shared, refs, is_run)
+        lane, row0, n_rows, qpos0 = seg
         attend = functools.partial(
-            _attend, q_ref, sink_ref, out_ref,
-            seg_meta_ref[s, 1], n_rows,
-            seg_meta_ref[s, 3], lambda j: block_tables_ref[lane, j],
-            meta_ref[0], kv, static,
+            _attend, q_ref, sink_ref, out_ref, row0, n_rows, qpos0,
+            lambda j: block_tables_ref[lane, j], meta_ref[0], kv, static,
+            run=run,
         )
         pl.when(n_rows == 1)(functools.partial(attend, one_row=True))
         pl.when(n_rows > 1)(functools.partial(attend, one_row=False))
 
         @pl.when(n_rows == 0)
         def _():
-            out_ref[seg_meta_ref[s, 1]] = jnp.zeros(
-                out_ref.shape[1:], out_ref.dtype)
+            out_ref[row0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
 
         return 0
 
-    jax.lax.fori_loop(blk_seg_ref[i], blk_seg_ref[i + 1], seg_body, 0)
+    jax.lax.fori_loop(start, blk_seg_ref[i + 1], seg_body, 0)
 
 
 def _paged_call(
     kernel, name, tq, scalars, q, k_cache, v_cache, *,
     num_pages, block_size, scale, window, interpret, sink=None,
-    latent_v=None,
+    latent_v=None, run=False,
 ):
     """The pallas_call the three kernels share: a grid over `tq`-row
     tiles of q, the caches left in HBM, the scalars prefetched to SMEM,
     a ring of KV-block buffers as scratch. `sink` ((nq,) logits) rides
     as one more VMEM input where the layer has one. A latent kind
-    (`latent_v`, `v_cache` None) has one cache and one ring."""
+    (`latent_v`, `v_cache` None) has one cache and one ring. `run`: the
+    ragged kernel's scratch for a shared run's state (`_attend`)."""
     r, nq, d = q.shape
     nkv = k_cache.shape[1]
     assert (v_cache is None) == bool(latent_v), (latent_v, v_cache)
@@ -525,10 +625,16 @@ def _paged_call(
         ))
         inputs.append(
             sink.astype(jnp.float32).reshape(nkv, nq // nkv, 1))
+    scratch.append(pltpu.SemaphoreType.DMA((_KV_RING, 2)))
+    if run:
+        g_pad = -(-(nq // nkv) // 8) * 8
+        scratch += [
+            pltpu.VMEM((tq, nkv, g_pad, width), jnp.float32)
+            for width in (128, 128, d_v)]
     return pl.pallas_call(
         functools.partial(
             kernel, block_size=block_size, num_pages=num_pages,
-            scale=scale, window=window, latent_v=latent_v,
+            scale=scale, window=window, latent_v=latent_v, run=run,
         ),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -536,8 +642,7 @@ def _paged_call(
             grid=(r // tq,),
             in_specs=in_specs,
             out_specs=tile(d_v),
-            scratch_shapes=[
-                *scratch, pltpu.SemaphoreType.DMA((_KV_RING, 2))],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((r, nq, d_v), q.dtype),
         interpret=interpret,
@@ -566,6 +671,9 @@ def ragged_paged_attention(
     seg_meta: jax.Array,      # (SC, 4) int32 — [lane, row0, n_rows,
                               # q_pos0] per segment
     sink: jax.Array | None = None,  # (nq,) float32 logits
+    shared: jax.Array | None = None,  # (G, 2) int32 — a row block's
+                                      # [shared_keys, lane]; None: no
+                                      # block has a shared run
     *,
     block_size: int,
     scale: float,
@@ -581,19 +689,63 @@ def ragged_paged_attention(
     metadata — see _ragged_kernel. Returns (R, nq, d) in q.dtype; a
     zero-row segment's `row0` holds zeros, rows covered by no segment
     are undefined (callers discard them, the same contract as the
-    composed kernels' padded rows)."""
+    composed kernels' padded rows).
+
+    `shared`, where the caller has found that the one-row segments of a
+    row block read their leading keys from the same pages (decode lanes
+    over one cached prefix; `model_runner.shared_runs`), has those keys
+    walked once for the block (`_ragged_kernel`).
+
+    The decode rows' walk alone, on a v5e, against the time of the bytes
+    it must read at 819 GB/s (K and V of every key a lane reads; with a
+    shared run, of the run once a row block); us a layer call at the
+    cells' decode shapes: the parent commit's kernel, this one with
+    `shared` None ("alone": every lane its own walk) and with the runs
+    the runner finds (my chip run, PR 44, call 2:
+    `scripts/bench_attention_walk.py --parent ...`; all three outputs
+    are the same bits in every shape):
+
+                                          live   kv  alone         shared
+                                         lanes  blk  bytes parent  time  bytes  time
+        mistral batch-fewshot2k, 8 kv x 4,  32  128    460  576.5 586.1    173 279.3
+          contexts 2.4-3.3k, 2,080 shared
+        mimo batch-doc8k full kind, 4 kv    64  128   2202   3391  3385    481  1420
+          x 16, K 256 / V 128, a sink,
+          contexts 8.4-9.9k, 8,288 shared
+        mistral chat-sys2k, 5 of 32 lanes    5  128     80  123.9 127.4     80 126.2
+          over 4 prompts (no run)
+        qwen2 chat-sys2k, 4 kv x 7, same     5  256     39   78.1  82.5     39  85.6
+        ouro, 16 kv x 1, 16 lanes over      16  128    455  550.9 552.7    168 245.2
+          one 2,080-key preamble
+        xing4 latent, 8 of 32 lanes over     8  512    218  421.4 435.3     39 199.2
+          one 16,512-key document
+
+    What the shared pass leaves: the lanes' own tails, each a segment
+    with its ~3 us of start-up (32 x 3 of mistral's 279), and the tall
+    tile's own arithmetic where the tile is tall: at 8 lanes x 16 heads
+    = 128 rows a kv head (mimo) a 128-key block takes 2.1 us to compute
+    against 0.48 us to copy, so that walk gains 2.4x where its bytes
+    fall 4.6x. Where no row block shares, the kernel is the parent's
+    plus 2-6% (2-4 us a call at 8 and 4 kv heads: the walk's first
+    block is a value and no longer the constant 0; 14-18 at the latent
+    kind: the start state's read and select; PERF.md, Open questions
+    9 f), in cells where the walk is 5-25% of the device's busy time."""
     r = q.shape[0]
     n_blocks = blk_seg.shape[0] - 1
     tq = r // n_blocks
     assert tq * n_blocks == r, (
         f"ragged row space {r} must tile into {n_blocks} blocks"
     )
+    if shared is None:
+        shared = jnp.zeros((n_blocks, 2), jnp.int32)
     return _paged_call(
         _ragged_kernel, "ragged_paged_attention", tq,
-        (jnp.reshape(layer, 1), blk_seg, seg_meta, block_tables),
+        (jnp.reshape(layer, 1), blk_seg, seg_meta, shared, block_tables),
         q, k_cache, v_cache, num_pages=block_tables.shape[1],
         block_size=block_size, scale=scale, window=window,
         interpret=interpret, sink=sink, latent_v=latent_v,
+        # a windowed walk starts at its window: it has no leading run
+        run=window is None,
     )
 
 
@@ -723,7 +875,9 @@ def ragged_paged_attention_tp(
     mesh: jax.sharding.Mesh, block_size: int, scale: float,
     interpret: bool = False, window: int | None = None,
 ) -> jax.Array:
-    """ragged_paged_attention with heads sharded over the mesh's tp axis."""
+    """ragged_paged_attention with heads sharded over the mesh's tp
+    axis. No shared run here (`shared` stays None: every lane walks
+    alone); no cell runs a mesh."""
     return _over_heads(
         ragged_paged_attention, mesh, q, k_cache, v_cache, layer,
         block_tables, blk_seg, seg_meta, block_size=block_size,

@@ -886,3 +886,143 @@ def test_walk_decode_rows_beside_prefill_tail(kv_block_pages, n_pages,
     np.testing.assert_allclose(
         np.asarray(out[:tail]), np.asarray(out_r), rtol=2e-5, atol=2e-5
     )
+
+
+# -- a shared run: the leading keys that every decode lane of a row block
+# reads from the same pages go through the walk once, as one tall tile, and
+# each lane's own walk starts where the run ends. The bar is the walk of
+# each lane alone (`shared` None), bit for bit.
+
+def _shared_prefix_case(seed, ctx, prefix_pages, *, bs=8, nkv=2, g=2,
+                        d=128, d_v=None, latent=False, dtype=jnp.float32):
+    """Decode lanes whose tables start with the SAME `prefix_pages`
+    pages and go on with pages of their own; a lane with context 0 holds
+    no sequence and its table row is the null page's."""
+    rng = np.random.RandomState(seed)
+    b = len(ctx)
+    own = [max(0, -(-c // bs) - prefix_pages) if c else 0 for c in ctx]
+    num_blocks = 1 + prefix_pages + sum(own)
+    pages = prefix_pages + max(own) + 1
+    order = rng.permutation(np.arange(1, num_blocks))
+    tables = np.zeros((b, pages), np.int32)
+    at = prefix_pages
+    for i, n in enumerate(own):
+        if ctx[i]:
+            tables[i, :prefix_pages] = order[:prefix_pages]
+            tables[i, prefix_pages:prefix_pages + n] = order[at:at + n]
+            at += n
+
+    def cache(width):
+        return jnp.asarray(
+            rng.randn(2, nkv, num_blocks * bs, width).astype(np.float32),
+            dtype)
+
+    kc = cache(d)
+    vc = None if latent else cache(d_v or d)
+    q = jnp.asarray(rng.randn(b, nkv * g, d).astype(np.float32), dtype)
+    return q, kc, vc, jnp.asarray(tables)
+
+
+_SHARED_CASES = {
+    # name: (kernel shape, contexts in KV blocks of c keys (float) or
+    # None for a lane that holds no sequence, shared keys handed to the
+    # kernel in KV blocks, what the kernel is told beside them)
+    "8kv-g4": (dict(nkv=8, g=4), [2.5, 3.2, 2.1, 4.0, 2.9, 3.3, 2.2, 3.9],
+               2, {}),
+    "4kv-g7": (dict(nkv=4, g=7), [2.5, 3.2, 2.1, 4.0, 2.9], 2, {}),
+    "16kv-g1": (dict(nkv=16, g=1), [2.5, 3.2, 2.1, 4.0, 2.9, 3.3], 2, {}),
+    "4kv-g16-k256-v128-sink": (
+        dict(nkv=4, g=16, d=256, d_v=128),
+        [2.5, 3.2, 2.1, 4.0, 2.9, 3.3, 2.2, 3.9], 2, dict(sink=True)),
+    "latent": (dict(nkv=1, g=8, d=640, latent=True),
+               [2.5, 3.2, 2.1, 4.0], 2, dict(latent_v=512)),
+    "bf16-cache": (dict(nkv=8, g=4, dtype=jnp.bfloat16),
+                   [2.5, 3.2, 2.1, 4.0, 2.9, 3.3, 2.2, 3.9], 2, {}),
+    # the run is addressed through the block's first LIVE lane
+    "idle-lanes-inside": (dict(nkv=8, g=4),
+                          [None, 3.2, 2.1, None, 2.9, 3.3, None, 3.9], 2,
+                          {}),
+    "contexts-apart-by-blocks": (dict(nkv=2, g=2),
+                                 [1.1, 6.5, 3.0, 9.25, 1.5], 1, {}),
+    # 2 blocks and 5 keys: cut down to 2 blocks
+    "run-no-multiple-of-the-block": (
+        dict(nkv=2, g=4), [2.5, 3.2, 2.9, 4.0], 2 + 5 / 16, {}),
+    "run-shorter-than-a-block": (dict(nkv=2, g=4), [2.5, 3.2], 0.9, {}),
+    "one-live-row": (dict(nkv=2, g=4), [None, 3.2, None], 2, {}),
+    # a windowed walk starts at its window: the kernel has no run
+    "window": (dict(nkv=2, g=4), [2.5, 3.2, 2.9, 4.0], 2,
+               dict(window=21)),
+    # two row blocks: the second shares nothing (and says so)
+    "second-block-alone": (
+        dict(nkv=2, g=2),
+        [2.5, 3.2, 2.1, 4.0, 2.9, 3.3, 2.2, 3.9, 1.5, 0.4, 2.0], 2,
+        dict(second_block=0)),
+}
+
+
+@pytest.mark.parametrize("name", list(_SHARED_CASES))
+def test_shared_run_equals_each_lane_alone(kv_block_pages, name):
+    """A row's result does not depend on who shares its block: with a
+    shared run the ragged kernel returns what it returns without one,
+    bit for bit, in every live row; zero-row segments are zeros."""
+    shape, blocks, run_blocks, told = _SHARED_CASES[name]
+    told = dict(told)
+    bs = 8
+    c = bs * kv_block_pages(2)
+    ctx = [0 if x is None else int(x * c) for x in blocks]
+    prefix_pages = int(run_blocks) * c // bs + 1
+    q, kc, vc, tables = _shared_prefix_case(
+        17, ctx, prefix_pages, bs=bs, **shape)
+    b = len(ctx)
+    r_pad, blk_seg, seg = _dec_rows_meta(ctx)
+    seg = seg.at[:, 2].set(jnp.asarray([int(x > 0) for x in ctx]))
+    qp = jnp.pad(q, ((0, r_pad - b), (0, 0), (0, 0)))
+    live = [i for i, x in enumerate(ctx) if x]
+    shared = np.zeros((r_pad // 8, 2), np.int32)
+    shared[0] = int(run_blocks * c), live[0]
+    if "second_block" in told:
+        shared[1] = told.pop("second_block"), 8
+    sink = None
+    if told.pop("sink", False):
+        sink = jnp.asarray(
+            np.random.RandomState(3).randn(q.shape[1]), jnp.float32)
+    kw = dict(block_size=bs, scale=1.0 / np.sqrt(q.shape[-1]),
+              interpret=True, **told)
+    alone = pa.ragged_paged_attention(
+        qp, kc, vc, jnp.int32(1), tables, blk_seg, seg, sink, **kw)
+    together = pa.ragged_paged_attention(
+        qp, kc, vc, jnp.int32(1), tables, blk_seg, seg, sink,
+        jnp.asarray(shared), **kw)
+    np.testing.assert_array_equal(
+        np.asarray(together[:b], np.float32),
+        np.asarray(alone[:b], np.float32))
+    assert np.isfinite(np.asarray(together[:b], np.float32)).all()
+    # and the lanes alone are the composed kernel's, as ever
+    if "latent_v" not in told:
+        composed = paged_decode_attention(
+            q, kc, vc, jnp.int32(1), tables, jnp.asarray(ctx, jnp.int32),
+            sink, **kw)
+        np.testing.assert_array_equal(
+            np.asarray(together, np.float32)[live],
+            np.asarray(composed, np.float32)[live])
+
+
+def test_shared_run_reads_the_named_lanes_pages(kv_block_pages):
+    """The run is taken, and taken from the table row the caller names:
+    over lanes whose leading pages are NOT the same, a claimed run gives
+    the named lane its own result and every other lane another one."""
+    bs = 8
+    c = bs * kv_block_pages(2)
+    ctx = [int(x * c) for x in (2.5, 3.2, 2.1)]
+    q, kc, vc, tables, _ = _lanes_case(5, ctx, pages=8, nkv=2, g=4)
+    r_pad, blk_seg, seg = _dec_rows_meta(ctx)
+    qp = jnp.pad(q, ((0, r_pad - 3), (0, 0), (0, 0)))
+    kw = dict(block_size=bs, scale=0.09, interpret=True)
+    alone = np.asarray(pa.ragged_paged_attention(
+        qp, kc, vc, jnp.int32(1), tables, blk_seg, seg, **kw))
+    claimed = np.asarray(pa.ragged_paged_attention(
+        qp, kc, vc, jnp.int32(1), tables, blk_seg, seg, None,
+        jnp.asarray([[2 * c, 1]], jnp.int32), **kw))
+    np.testing.assert_array_equal(claimed[1], alone[1])
+    assert np.abs(claimed[0] - alone[0]).max() > 1e-3
+    assert np.abs(claimed[2] - alone[2]).max() > 1e-3
