@@ -414,6 +414,10 @@ class BlockManager:
         before it. One pool keeps every block (WindowedBlockManager
         overrides)."""
 
+    def note_saved(self, block_table: list[int], block_index: int) -> None:
+        """The table's sequence registered its block `block_index`. One
+        kind of memory, nothing to note (StateBlockManager overrides)."""
+
     def free(self, block_table: list[int]) -> None:
         """Release a sequence's references; cached blocks become evictable."""
         # table-identity epoch: freed block ids may be handed to another
@@ -710,4 +714,290 @@ class WindowedBlockManager(BlockManager):
             for bid in block_table[block_table.lo:block_table.hi]:
                 self._wrelease(bid)
             block_table.lo = block_table.hi = len(block_table)
+        super().free(block_table)
+
+
+class StateTable(list):
+    """A sequence's block table that also names the sequence's STATE
+    SLOT (`slot`), the snapshot its prefix hit is restored from while
+    that is pinned (`load`, a snapshot slot), the first block it
+    computes (`first`, an index: where the restored state is read), and
+    the last snapshot it saved itself (`last_saved`)."""
+    __slots__ = ("slot", "load", "first", "last_saved")
+
+    def __init__(self, ids=(), slot: int = 0, first: int = 0):
+        super().__init__(ids)
+        self.slot, self.first = slot, first
+        self.load = self.last_saved = 0
+
+
+class StateBlockManager(BlockManager):
+    """BlockManager for a model whose layers hold RECURRENT STATE beside
+    the paged KV cache (state-space layers, ops/ssm.py): a second kind
+    of per-sequence memory that is not pages.
+
+    - A running sequence owns one STATE SLOT (1..num_state_slots) for
+      its life: taken with its table (`allocate_prompt`), freed with it
+      (`free`; a preempted sequence recomputes).
+    - A SNAPSHOT is a sequence's whole state at a token boundary that is
+      a multiple of `interval_blocks` blocks, kept in a pool of
+      `num_snapshots` further slots of the same device arrays, keyed by
+      the chain hash of the block that ends at the boundary.
+    - A prefix hit is worth only what the deepest snapshot under it
+      allows: `match_prefix` cuts the run of hashed blocks back to the
+      deepest boundary that has BOTH its blocks and a snapshot; the
+      rest is recomputed and counted (`cutback_tokens`).
+
+    Nothing here copies a state. The device finds every row's sequence
+    through `maps` (blocks, 3), which the runner uploads when
+    `map_version` moved: a block's [state slot of the sequence writing
+    it, snapshot slot to SAVE the state to when the block's last
+    position is computed, snapshot slot to LOAD it from when its first
+    is]. A block being written belongs to one sequence, so the block of
+    a row's write slot names the sequence; the step programs ship
+    nothing more (`ssm.plan_rows`).
+
+    A snapshot slot is PENDING from the allocation of its boundary block
+    until that block's hash is registered (the state was then saved by
+    the program that computed the block's last position); a chunk that
+    would run across a boundary, which would leave the state there
+    unsaved, gives the pending slot back (`prepare_chunk`). Eviction:
+    snapshots their own sequence has passed (it saved a deeper one) go
+    first, oldest first, unless prefix hits of at least two prompts
+    ended there (a shared system prompt's end); then least recently
+    used. A snapshot goes with its block when that is evicted. A
+    snapshot a waiting hit will load from is pinned until the chunk
+    that reads it has been dispatched."""
+
+    def __init__(self, num_blocks: int, block_size: int,
+                 enable_prefix_caching: bool, *, num_state_slots: int,
+                 num_snapshots: int, interval_blocks: int):
+        super().__init__(num_blocks, block_size, enable_prefix_caching)
+        import numpy as np
+
+        self.interval = max(1, interval_blocks)
+        self.num_state_slots = num_state_slots
+        self.num_snapshots = num_snapshots if enable_prefix_caching else 0
+        self.maps = np.zeros((num_blocks, 3), np.int32)
+        self.map_version = 0
+        self._free_slots = list(range(num_state_slots, 0, -1))
+        first = num_state_slots + 1
+        self._free_snaps = list(
+            range(first + self.num_snapshots - 1, first - 1, -1))
+        # snapshot slot -> the boundary block it waits for (pending)
+        self._pending: dict[int, int] = {}
+        # chain hash -> snapshot slot, and back
+        self.snapshots: dict[int, int] = {}
+        self._snap_hash: dict[int, int] = {}
+        # resident snapshot slot -> None: least recently used first, and
+        # the ones their own sequence has passed, which go before those
+        self._lru: OrderedDict[int, None] = OrderedDict()
+        self._trail: OrderedDict[int, None] = OrderedDict()
+        self._pins: dict[int, int] = {}
+        self._ends: dict[int, int] = {}   # slot -> hits that ended there
+        # counters (engine/metrics.py: tpu:ssm_*, tpu:prefix_state_*)
+        self.snapshot_saves = 0
+        self.snapshot_restores = 0
+        self.snapshot_evictions = 0
+        self.cutback_tokens = 0
+        self._cut = 0  # the last match's cut-back blocks
+
+    # -- the pools ----------------------------------------------------------
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.num_state_slots - len(self._free_slots)
+
+    @property
+    def snapshots_resident(self) -> int:
+        return len(self.snapshots)
+
+    def _set(self, bid: int, col: int, value: int) -> None:
+        if self.maps[bid, col] != value:
+            self.maps[bid, col] = value
+            self.map_version += 1
+
+    def _drop_snapshot(self, slot: int) -> None:
+        h = self._snap_hash.pop(slot)
+        del self.snapshots[h]
+        self._lru.pop(slot, None)
+        self._trail.pop(slot, None)
+        self._ends.pop(slot, None)
+        self._free_snaps.append(slot)
+
+    def _take_snapshot_slot(self) -> int:
+        """A free snapshot slot, evicting where none is; 0 where every
+        one is pending or pinned (the boundary then gets no snapshot)."""
+        if self._free_snaps:
+            return self._free_snaps.pop()
+        for pool in (self._trail, self._lru):
+            slot = next((s for s in pool if not self._pins.get(s)), None)
+            if slot is not None:
+                self._drop_snapshot(slot)
+                self.snapshot_evictions += 1
+                return self._free_snaps.pop()
+        return 0
+
+    def _unpend(self, bid: int) -> None:
+        slot = int(self.maps[bid, 1])
+        if slot and self._pending.get(slot) == bid:
+            del self._pending[slot]
+            self._free_snaps.append(slot)
+        self._set(bid, 1, 0)
+
+    def _unpin(self, table: StateTable) -> None:
+        if table.load:
+            self._pins[table.load] -= 1
+            table.load = 0
+            if table.first < len(table):
+                self._set(table[table.first], 2, 0)
+
+    def _pop_free_block(self) -> int:
+        gone = None
+        if not self.free_blocks and self.evictable:
+            # the cached block this will evict
+            gone = self.blocks[next(iter(self.evictable))].block_hash
+        bid = super()._pop_free_block()
+        # a block that starts a new life names no sequence and no
+        # snapshot, and the snapshot at its old hash goes with it
+        if gone is not None and gone in self.snapshots:
+            slot = self.snapshots[gone]
+            if not self._pins.get(slot):
+                self._drop_snapshot(slot)
+        self._unpend(bid)
+        for col in (0, 2):
+            self._set(bid, col, 0)
+        return bid
+
+    def _own(self, table: StateTable, start: int) -> None:
+        """The table's fresh blocks from index `start` on name its
+        sequence."""
+        for i in range(start, len(table)):
+            self._set(table[i], 0, table.slot)
+
+    def _expect_snapshot(self, bid: int) -> None:
+        """The dispatch that is being planned computes the last position
+        of block `bid`, which ends at a boundary: a snapshot slot for it,
+        pending. Taken this late so that a long prompt recycles the
+        boundaries it has passed itself, and evicts nobody's end."""
+        if self.num_snapshots and not self.maps[bid, 1] and (
+                self.blocks[bid].block_hash is None):
+            slot = self._take_snapshot_slot()
+            if slot:
+                self._pending[slot] = bid
+                self._set(bid, 1, slot)
+
+    # -- sequence-level API -------------------------------------------------
+    def match_prefix(self, token_ids: list[int], seed: int = 0,
+                     hashes: list[int] | None = None,
+                     ) -> tuple[list[int], int]:
+        matched, _ = super().match_prefix(token_ids, seed, hashes)
+        # allocate_prompt computes at least one token
+        hit = min(len(matched), (len(token_ids) - 1) // self.block_size)
+        n = hit - hit % self.interval
+        while n > 0 and (
+                self.blocks[matched[n - 1]].block_hash not in self.snapshots):
+            n -= self.interval
+        self._cut = hit - n
+        return matched[:n], n * self.block_size
+
+    def allocate_prompt(self, token_ids, seed: int = 0,
+                        reuse_cache: bool = True, hashes=None):
+        if not self._free_slots:
+            return None
+        self._cut = 0
+        alloc = super().allocate_prompt(token_ids, seed, reuse_cache,
+                                        hashes)
+        if alloc is None:
+            return None
+        table, cached = alloc
+        n = cached // self.block_size
+        # counted where the prompt is admitted: the scheduler asks
+        # `match_prefix` about a waiting prompt more than once
+        self.cutback_tokens += self._cut * self.block_size
+        table = StateTable(table, slot=self._free_slots.pop(), first=n)
+        self._own(table, n)
+        if n:
+            slot = self.snapshots[self.blocks[table[n - 1]].block_hash]
+            # where this sequence starts is the first boundary it will
+            # have passed: once it has saved a deeper one
+            table.load = table.last_saved = slot
+            self._pins[slot] = self._pins.get(slot, 0) + 1
+            self._ends[slot] = self._ends.get(slot, 0) + 1
+            if slot in self._trail and self._ends[slot] >= 2:
+                del self._trail[slot]
+                self._lru[slot] = None
+            elif slot in self._lru:
+                self._lru.move_to_end(slot)
+            self._set(table[n], 2, slot)
+            self.snapshot_restores += 1
+        return table, cached
+
+    def ensure_capacity(self, num_tokens: int, block_table) -> bool:
+        had = len(block_table)
+        ok = super().ensure_capacity(num_tokens, block_table)
+        if isinstance(block_table, StateTable):
+            self._own(block_table, had)
+            # a decode lane: the chunk that read the snapshot has run,
+            # and the fused steps ahead may cross a boundary: in one of
+            # the blocks they write, the table's last two
+            self._unpin(block_table)
+            for i in range(max(0, min(had, len(block_table) - 2)),
+                           len(block_table)):
+                if (i + 1) % self.interval == 0:
+                    self._expect_snapshot(block_table[i])
+        return ok
+
+    def prepare_chunk(self, block_table, start: int, end: int) -> None:
+        if not isinstance(block_table, StateTable):
+            return
+        bs = self.block_size
+        if start > block_table.first * bs:
+            self._unpin(block_table)
+        # a boundary INSIDE the chunk: the state there is never alone
+        # in the slot, so nothing is saved for it
+        step = self.interval * bs
+        for edge in range(start - start % step + step, end, step):
+            self._unpend(block_table[edge // bs - 1])
+        if end % step == 0:
+            self._expect_snapshot(block_table[end // bs - 1])
+
+    def register_hash(self, h: int, block_id: int) -> None:
+        super().register_hash(h, block_id)
+        slot = int(self.maps[block_id, 1])
+        if not slot or self._pending.get(slot) != block_id:
+            return
+        del self._pending[slot]
+        self._set(block_id, 1, 0)
+        if h in self.snapshots or h not in self.cached_blocks:
+            self._free_snaps.append(slot)  # one is there, or no block is
+            return
+        self.snapshots[h] = slot
+        self._snap_hash[slot] = h
+        self._lru[slot] = None
+        self.snapshot_saves += 1
+
+    def note_saved(self, block_table, block_index: int) -> None:
+        """The table's sequence registered its block `block_index`: the
+        snapshot it saved before, which it has now passed, goes to the
+        front of the eviction order."""
+        if not isinstance(block_table, StateTable):
+            return
+        slot = self.snapshots.get(
+            self.blocks[block_table[block_index]].block_hash or 0, 0)
+        if not slot or slot == block_table.last_saved:
+            return
+        old = block_table.last_saved
+        if old in self._lru and self._ends.get(old, 0) < 2:
+            del self._lru[old]
+            self._trail[old] = None
+        block_table.last_saved = slot
+
+    def free(self, block_table) -> None:
+        if isinstance(block_table, StateTable) and block_table.slot:
+            self._unpin(block_table)
+            for i in range(self.interval - 1, len(block_table),
+                           self.interval):
+                self._unpend(block_table[i])
+            self._free_slots.append(block_table.slot)
+            block_table.slot = 0
         super().free(block_table)

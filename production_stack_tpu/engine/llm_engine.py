@@ -16,6 +16,7 @@ import numpy as np
 
 from production_stack_tpu.engine.block_manager import (
     BlockManager,
+    StateBlockManager,
     WindowedBlockManager,
 )
 from production_stack_tpu.engine.config import EngineConfig
@@ -89,6 +90,19 @@ class LLMEngine:
                 num_window_blocks=self.runner.num_window_blocks,
             )
             self.runner.block_map_source = self.block_manager
+        elif self.runner.num_state_slots:
+            # a model with recurrent state: a state slot a sequence and
+            # a pool of snapshots beside the pages, found on the device
+            # through maps the runner uploads when they moved
+            # (block_manager.StateBlockManager)
+            self.block_manager = StateBlockManager(
+                self.runner.num_blocks, config.block_size,
+                config.enable_prefix_caching,
+                num_state_slots=self.runner.num_state_slots,
+                num_snapshots=self.runner.num_snapshots,
+                interval_blocks=self.runner.snapshot_interval_blocks,
+            )
+            self.runner.state_map_source = self.block_manager
         else:
             self.block_manager = BlockManager(
                 num_blocks=self.runner.num_blocks,
@@ -2433,9 +2447,11 @@ class LLMEngine:
         """Every prefill chunk passes here right before its dispatch,
         scheduled, chained or split alike: a block manager with a second
         cache group releases what the chunk's sequence left behind its
-        window and makes room for the chunk's positions there (a no-op
-        with one pool)."""
-        if not self.runner.num_window_blocks:
+        window and makes room for the chunk's positions there; one with
+        recurrent state readies a snapshot slot for a chunk that ends
+        at a boundary (a no-op with one pool)."""
+        if not (self.runner.num_window_blocks
+                or self.runner.num_state_slots):
             return
         with phases.annotation("engine.kv_release", chunks=len(works)):
             for w in works:
@@ -3530,6 +3546,7 @@ class LLMEngine:
                     of_prompt=end <= seq.num_prompt_tokens,
                 ))
             seq.num_registered_blocks = i + 1
+            bm.note_saved(seq.block_table, i)
 
     def _make_output(self, seq: Sequence) -> RequestOutput:
         new_ids = getattr(seq, "_pending_ids", [])
@@ -3677,6 +3694,16 @@ class LLMEngine:
                 "kv_window_blocks_per_seq":
                     tuple(self._window_blocks_per_seq),
                 "prefix_window_cutback_blocks": tuple(bm.prefix_cutback),
+            }
+        if self.runner.num_state_slots:
+            out["ssm_stats"] = {
+                "state_slots_in_use": bm.state_slots_in_use,
+                "snapshots_resident": bm.snapshots_resident,
+                "snapshot_saves": bm.snapshot_saves,
+                "snapshot_restores": bm.snapshot_restores,
+                "snapshot_evictions": bm.snapshot_evictions,
+                "prefix_state_cutback_tokens": bm.cutback_tokens,
+                "lane_layer_steps": self.runner.ssm_lane_layer_steps,
             }
         return {
             "attn_context_by_kind": {

@@ -247,6 +247,43 @@ class EngineMetrics:
             "tpu:kv_window_blocks_released",
             "Window-group KV blocks a sequence let go because every "
             "position in them lay behind its window", label, registry=reg)
+        # a model with recurrent state (ops/ssm.py, engine/block_manager.
+        # StateBlockManager); zero for any other
+        self.ssm_gauges = {
+            key: Gauge(name, doc, label, registry=reg)
+            for key, name, doc in (
+                ("state_slots_in_use", "tpu:ssm_state_slots_in_use",
+                 "State slots (the recurrent state of every state-space "
+                 "layer, one a running sequence) that a sequence holds"),
+                ("snapshots_resident", "tpu:ssm_snapshots_resident",
+                 "Snapshots of a sequence's recurrent state at a token "
+                 "boundary resident in the pool a prefix hit restores "
+                 "from"),
+            )
+        }
+        self.ssm_counters = {
+            key: Counter(name, doc, label, registry=reg)
+            for key, name, doc in (
+                ("snapshot_saves", "tpu:ssm_snapshot_saves",
+                 "Snapshots of the recurrent state saved at a boundary "
+                 "and registered under the boundary block's hash"),
+                ("snapshot_restores", "tpu:ssm_snapshot_restores",
+                 "Admitted prompts whose prefix hit starts from a "
+                 "snapshot of the recurrent state"),
+                ("snapshot_evictions", "tpu:ssm_snapshot_evictions",
+                 "Snapshots dropped from the pool to make room"),
+                ("prefix_state_cutback_tokens",
+                 "tpu:prefix_state_cutback_tokens",
+                 "Tokens of the admitted prompts' prefix hits given up "
+                 "because no snapshot of the recurrent state stood at "
+                 "the hit's end: recomputed from the deepest snapshot "
+                 "under it (or from nothing)"),
+                ("lane_layer_steps", "tpu:ssm_lane_layer_steps",
+                 "One-token updates of the recurrent state by the "
+                 "dispatched rounds: decode lanes that hold a sequence "
+                 "x fused steps x state-space layers"),
+            )
+        }
         self.program_cache_hits = Counter(
             "tpu:program_cache_hits",
             "Programs served by jax's persistent compilation cache",
@@ -639,6 +676,11 @@ class EngineMetrics:
                        s.kv_window_blocks_per_seq)
         self.pairs.set("tpu:prefix_window_cutback_blocks",
                        s.prefix_window_cutback_blocks)
+        for key, gauge in self.ssm_gauges.items():
+            gauge.labels(m).set(s.ssm_stats.get(key, 0))
+        for key, counter in self.ssm_counters.items():
+            counter.labels(m).inc(max(0, s.ssm_stats.get(key, 0)
+                                      - prev.ssm_stats.get(key, 0)))
         for stage, pair in s.program_stages.items():
             self.pairs.set(f"tpu:program_{stage}_seconds", pair)
         self.program_cache_hits.labels(m).inc(max(

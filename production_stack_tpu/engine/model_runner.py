@@ -37,7 +37,7 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.models import layer_groups, llama
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops import attention as xla_attn
-from production_stack_tpu.ops import cache_write
+from production_stack_tpu.ops import cache_write, ssm
 from production_stack_tpu.parallel import sharding as sharding_rules
 from production_stack_tpu.tracing import phases
 from production_stack_tpu.utils import init_logger
@@ -231,6 +231,13 @@ class ModelRunner:
         # property reads the block map when it changed
         self.block_map_source = None
         self._map_version = None
+        # the same for a model with recurrent state
+        # (StateBlockManager): its maps from a block to state slots,
+        # and the slots of the state group (`_allocate_cache_groups`)
+        self.state_map_source = None
+        self._state_map_version = None
+        self.num_state_slots = self.num_snapshots = 0
+        self.snapshot_interval_blocks = 0
         # counters a step program sums on the device and returns beside
         # its K cache ("stats": the routed layers' of a layer-group
         # model, ints; a looped stack's exit distribution, a float a
@@ -372,6 +379,11 @@ class ModelRunner:
         # segments of the attention walk (tpu:decode_lane_steps,
         # tpu:decode_idle_lane_steps)
         self.decode_lane_steps = [0, 0]
+        # the state-space layers' one-token updates by those rows: lanes
+        # that hold a sequence x fused steps x such layers
+        # (tpu:ssm_lane_layer_steps)
+        self.ssm_lane_layer_steps = 0
+        self._ssm_layers = mc.ssm_layers
         # evaluations of sampler.sample_tokens by the dispatched rounds
         # (a fused decode step is one, a round's first-token rows one
         # more), and those of them whose rows held a temperature > 0:
@@ -511,8 +523,15 @@ class ModelRunner:
             raise ValueError(
                 f"model {mc.name} is a stack of layer groups "
                 "(models/layer_groups.py: a KV cache per attention "
-                "kind), which does not serve with: " + "; ".join(on)
+                "kind" + (
+                    ", and recurrent state a sequence that is not pages "
+                    "and has no export path" if mc.ssm_layers else "")
+                + "), which does not serve with: " + "; ".join(on)
             )
+        if mc.ssm_layers and any(ak.window for ak in mc.attn_kinds):
+            raise ValueError(
+                f"model {mc.name}: state-space layers beside a windowed "
+                "attention kind are not served (one map a block manager)")
 
     @staticmethod
     def _refuse_for_looped_stack(config: EngineConfig, mc) -> None:
@@ -543,6 +562,18 @@ class ModelRunner:
                 "many times a token, each pass on cache layers of its "
                 "own), which does not serve with: " + "; ".join(on)
             )
+
+    # snapshots of the recurrent state the pool keeps, as a multiple of
+    # the lanes: a stand-in for the sessions a deployment keeps warm
+    # (twice the lanes) and the shared prefixes and slack beside them;
+    # tpu:prefix_state_cutback_tokens says when the pool is too small.
+    # Four a lane would lose fewer returning sessions' snapshots to the
+    # sessions that have just left (2.9% of hit tokens cut back against
+    # 7.6% in a replay of a chat cell's plan at 4.7 req/s), but at 161
+    # slots of 20 MiB beside 9.3 GB of weights XLA rematerialised the
+    # convolution's pool in every layer step, 11.5% of the device's busy
+    # time (my chip run, PR 45, call 4; PERF.md, Findings PR 45)
+    SNAPSHOTS_A_LANE = 3
 
     # cached sequences whose last window the windowed pool keeps for a
     # prefix hit to end in, as a multiple of the lanes (`_window_blocks_
@@ -597,11 +628,24 @@ class ModelRunner:
             self.num_window_blocks * block_bytes(mapped)
             if mapped is not None else 0
         )
+        state_bytes = 0
+        if mc.ssm_layers:
+            # a slot a lane, and SNAPSHOTS_A_LANE times as many
+            # snapshots where prefixes are cached; slot 0 is nobody's
+            self.num_state_slots = max(1, self.config.max_num_seqs)
+            self.num_snapshots = (
+                self.SNAPSHOTS_A_LANE * self.num_state_slots
+                if self.config.enable_prefix_caching else 0)
+            self.snapshot_interval_blocks = max(
+                1, self.config.max_prefill_chunk // bs)
+            state_bytes = (
+                1 + self.num_state_slots + self.num_snapshots
+            ) * mc.state_bytes_per_seq(self.dtype.itemsize)
         self.num_blocks = self._resolve_num_blocks(
             bytes_per_block=sum(
                 block_bytes(i) for i in range(len(mc.attn_kinds))
                 if i != mapped),
-            reserve=window_bytes,
+            reserve=window_bytes + state_bytes,
         )
         kg, vg = [], []
         for i, ak in enumerate(mc.attn_kinds):
@@ -625,6 +669,26 @@ class ModelRunner:
             "g": tuple(kg),
             "map": jnp.zeros((self.num_blocks,), jnp.int32),
         }
+        if mc.ssm_layers:
+            slots = 1 + self.num_state_slots + self.num_snapshots
+            logger.info(
+                "allocating recurrent state: %d layers x (%d lanes + %d "
+                "snapshots + 1) slots of %.2f MiB a sequence (%.2f GiB)",
+                mc.ssm_layers, self.num_state_slots, self.num_snapshots,
+                mc.state_bytes_per_seq(self.dtype.itemsize) / 2**20,
+                state_bytes / 2**30,
+            )
+            self._k_cache |= {
+                "ssm": {
+                    "s": jnp.zeros(
+                        (mc.ssm_layers, slots, *ssm.packed_shape(mc)),
+                        jnp.float32),
+                    "conv": jnp.zeros(
+                        (mc.ssm_layers, slots, mc.ssm_conv - 1,
+                         mc.ssm_conv_dim), self.dtype),
+                },
+                "smap": jnp.zeros((self.num_blocks, 3), jnp.int32),
+            }
         self.v_cache = {"g": tuple(vg)}
 
     @property
@@ -643,6 +707,11 @@ class ModelRunner:
                 # while the scheduler plans the next round
                 "map": jnp.asarray(src.block_map.copy()),
             }
+        src = self.state_map_source
+        if src is not None and src.map_version != self._state_map_version:
+            self._state_map_version = src.map_version
+            self._k_cache = {
+                **self._k_cache, "smap": jnp.asarray(src.maps.copy())}
         return self._k_cache
 
     @k_cache.setter
@@ -908,6 +977,15 @@ class ModelRunner:
         any other model: their forwards take no such argument."""
         return {"rows_valid": valid} if self.model_config.exit_gate else {}
 
+    def _state_kw(self, lanes: int, lane_rows: int, tail: int) -> dict:
+        """`state_rows=` for layer_groups.forward where the model has
+        recurrent state: the program's rows as `ssm.plan_rows` takes
+        them (prefill lanes, rows a lane at most, trailing one-token
+        rows). Nothing for any other model."""
+        if not self.model_config.ssm_layers:
+            return {}
+        return {"state_rows": (lanes, lane_rows, tail)}
+
     # -- jitted step builders ---------------------------------------------
     # stackcheck: hot-path — the ONE dispatch seam every pallas
     # attention call goes through (trace-time only: closed over by the
@@ -1091,6 +1169,7 @@ class ModelRunner:
         lanes = self.config.max_num_seqs
         self.decode_lane_steps[0] += k * lanes
         self.decode_lane_steps[1] += k * (lanes - n)
+        self.ssm_lane_layer_steps += k * n * self._ssm_layers
         w = self.model_config.sliding_window
         if w is None:
             tokens = (k * sum(decode_lens) + n * (k * (k - 1) // 2)
@@ -1210,6 +1289,12 @@ class ModelRunner:
         """Static prefill-lane capacity of the ragged-rows programs
         (config-derived, NOT part of the program key)."""
         return next_pow2(max(self.config.max_prefill_seqs, 1))
+
+    def _rows_lanes(self, r_pad: int) -> tuple[int, int]:
+        """(prefill lanes, rows a lane at most) of a ragged-rows
+        program of `r_pad` rows."""
+        return self._rows_lane_cap(), min(
+            r_pad, self._prefill_bucket(self.config.max_prefill_chunk))
 
     def _rows_bucket(self, n_rows: int) -> int:
         return next_pow2(max(n_rows, RAGGED_TQ))
@@ -1445,6 +1530,7 @@ class ModelRunner:
                 logits_rows=pf["last_rows"],
                 lora=lora, lora_slots=lora_slots,
                 **self._rows_valid_kw(pf["lane_rows"] > 0),
+                **self._state_kw(*self._rows_lanes(r_pad), 0),
             )
             sampled = sample_tokens(
                 logits, pf["temps"], pf["top_ps"], pf["top_ks"],
@@ -1639,6 +1725,7 @@ class ModelRunner:
                     else last_row[None]
                 ),
                 lora=lora, lora_slots=lora_slots,
+                **self._state_kw(1, t_pad, 0),
             )
             last_logits = logits[last_row] if want_prompt_lp else logits[0]
             # sample the first generated token ON DEVICE: the host then
@@ -1995,6 +2082,7 @@ class ModelRunner:
                     q, l, k, v, spec=spec),
                 logits_rows=last_rows,
                 lora=lora, lora_slots=lora_slots,
+                **self._state_kw(s_pad, t_pad, 0),
             )
             # on-device first-token sampling (see _build_prefill): the
             # host fetches (s_pad,) int32, not (s_pad, vocab) f32
@@ -2128,6 +2216,7 @@ class ModelRunner:
                     q, l, k, v, spec=spec),
                 logits_rows=jnp.arange(b),
                 lora=lora, lora_slots=lora_slots,
+                **self._state_kw(0, 0, b),
             )
             return logits, kc, vc
 
@@ -2409,6 +2498,7 @@ class ModelRunner:
                 logits_rows=lane,
                 lora=lora, lora_slots=lora_slots,
                 **self._rows_valid_kw(ctx > 0),
+                **self._state_kw(0, 0, b),
             )
             return logits, kc, vc
 
@@ -3985,6 +4075,7 @@ class ModelRunner:
                 lora=lora, lora_slots=lora_cat,
                 **self._rows_valid_kw(jnp.concatenate(
                     [pf["lane_rows"] > 0, d_ctx > 0])),
+                **self._state_kw(*self._rows_lanes(r_pad), b),
             )
             pf_logits = logits_all[:s_cap]
             dec0_logits = logits_all[s_cap:]

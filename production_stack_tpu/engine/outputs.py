@@ -119,6 +119,16 @@ class EngineStatsSnapshot:
     kv_window_blocks_released_total: int = 0
     kv_window_blocks_per_seq: tuple = (0, 0)
     prefix_window_cutback_blocks: tuple = (0, 0)
+    # a model with recurrent state (state-space layers): state slots
+    # that running sequences hold and snapshots resident in the pool
+    # (gauges: tpu:ssm_state_slots_in_use, tpu:ssm_snapshots_resident);
+    # snapshots saved, prefix hits restored from one, snapshots evicted,
+    # hit tokens given up because no snapshot stood at or under the
+    # hit's end, and one-token state updates of decode lanes x
+    # state-space layers (counters: tpu:ssm_snapshot_saves, _restores,
+    # _evictions, tpu:prefix_state_cutback_tokens,
+    # tpu:ssm_lane_layer_steps). Empty for any other model
+    ssm_stats: dict = field(default_factory=dict)
     # the stages of building a program, from jax's monitoring events of
     # this process: trace / lower / compile -> (seconds, count), and the
     # persistent compile cache's hits — tpu:program_*_seconds,

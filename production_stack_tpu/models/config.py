@@ -166,6 +166,33 @@ class ModelConfig:
     # head and row from the normed layer input, multiplies that head's
     # attention output before W_o
     head_gate: bool = False
+    # -- a stack of SINGLE-sublayer blocks (models/layer_groups.py,
+    # `forward_blocks`): each layer is x + F(RMSNorm(x)) with ONE F, by
+    # its letter in `block_pattern`: "M" a state-space (Mamba-2) mixer,
+    # "*" attention (kind 0 of `attn_kinds`; `layer_kinds` then lists
+    # the attention blocks only), "E" routed experts. "" = every layer
+    # is attention followed by an MLP, as everywhere else
+    block_pattern: str = ""
+    rope: bool = True   # False: attention without positional encoding
+    # the state-space mixer: `ssm_heads` heads of `ssm_head_dim`, B and
+    # C in `ssm_groups` groups of `ssm_state` dims, a causal depthwise
+    # convolution of `ssm_conv` taps over [x | B | C], the scan computed
+    # in chunks of `ssm_chunk` rows. A sequence carries, a layer, the
+    # (heads, head_dim, state) float32 state and the convolution's last
+    # `ssm_conv - 1` rows: a STATE SLOT, not pages
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # routed experts at a LATENT width: rows go hidden -> latent before
+    # the experts and back after their weighted sum (the router and the
+    # shared expert read the hidden state). 0 = experts at hidden width
+    moe_latent_size: int = 0
+    # False: an MLP (dense, expert, shared) is up -> act -> down with no
+    # gate matrix; `hidden_act` "relu2" is relu squared
+    mlp_gated: bool = True
 
     def __post_init__(self):
         if self.ut_steps < 1 or (self.ut_steps > 1 and self.attn_kinds):
@@ -174,8 +201,25 @@ class ModelConfig:
                 "stack of alike layers run at least once (a looped stack "
                 "of layer groups has no code path)"
             )
+        pattern = self.block_pattern
+        if pattern and (
+            len(pattern) != self.num_layers or set(pattern) - set("M*E")
+            or not self.attn_kinds
+            or ("M" in pattern and not (self.ssm_heads and self.ssm_state))
+            or ("E" in pattern and not self.router_experts)
+            or self.hc_mult > 1 or self.head_gate or self.dense_layers
+        ):
+            raise ValueError(
+                f"model {self.name}: block_pattern {pattern!r} must give "
+                f"one of M, *, E for each of {self.num_layers} layers of "
+                "a stack of layer groups, with the state-space sizes for "
+                "an M and routed experts for an E (no residual streams, "
+                "head gate or leading dense layers there)"
+            )
         if self.attn_kinds:
-            if len(self.layer_kinds) != self.num_layers or not all(
+            if len(self.layer_kinds) != (
+                pattern.count("*") if pattern else self.num_layers
+            ) or not all(
                 0 <= k < len(self.attn_kinds) for k in self.layer_kinds
             ):
                 raise ValueError(
@@ -280,6 +324,60 @@ class ModelConfig:
             seen[kind] += 1
         return tuple(tuple(r) for r in runs)
 
+    def units(self) -> tuple[tuple[str, int, int, int], ...]:
+        """A stack of single-sublayer blocks as runs of a repeating
+        UNIT of unlike blocks: (unit, count, index of the run's first
+        "*" within the attention cache group, the same for its first
+        "M" within the state group). One `lax.scan` walks a run, so a
+        program traces each unit once: the cover with the fewest traced
+        bodies (EMEMEMEMEM* = "EM" x 5 and "*": two units, three
+        bodies)."""
+        p, n = self.block_pattern, len(self.block_pattern)
+        best: list[tuple[int, tuple]] = [(0, ())] * (n + 1)
+        for i in range(n - 1, -1, -1):
+            cands = []
+            for size in range(1, n - i + 1):
+                unit, reps = p[i:i + size], 1
+                while p[i + reps * size:i + (reps + 1) * size] == unit:
+                    reps += 1
+                for r in range(1, reps + 1):
+                    cost, rest = best[i + r * size]
+                    cands.append((size + cost, -r, ((unit, r),) + rest))
+            cost, _, cover = min(cands)
+            best[i] = (cost, cover)
+        runs, n_attn, n_ssm = [], 0, 0
+        for unit, count in best[0][1]:
+            runs.append((unit, count, n_attn, n_ssm))
+            n_attn += count * unit.count("*")
+            n_ssm += count * unit.count("M")
+        return tuple(runs)
+
+    @property
+    def ssm_layers(self) -> int:
+        return self.block_pattern.count("M")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Lanes the convolution runs over: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def state_bytes_per_seq(self, conv_itemsize: int = 2) -> int:
+        """Bytes of recurrent state a sequence holds in all its
+        state-space layers: the float32 state and the convolution's
+        tail (what a state slot, and a snapshot, takes)."""
+        return self.ssm_layers * (
+            self.ssm_heads * self.ssm_head_dim * self.ssm_state * 4
+            + (self.ssm_conv - 1) * self.ssm_conv_dim * conv_itemsize)
+
+    @property
+    def expert_width(self) -> int:
+        """The width the routed experts read and write."""
+        return self.moe_latent_size or self.hidden_size
+
     @property
     def q_size(self) -> int:
         return self.num_heads * self.head_dim
@@ -288,9 +386,36 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    def block_params(self, letter: str) -> int:
+        """Every weight HELD here of one single-sublayer block (its norm
+        counted): exact, the budget of blocks, slots and snapshots
+        rests on it."""
+        h = self.hidden_size
+        if letter == "M":
+            d, heads = self.ssm_inner, self.ssm_heads
+            return (h + h * (d + self.ssm_conv_dim + heads)
+                    + (self.ssm_conv + 1) * self.ssm_conv_dim
+                    + 3 * heads + d + d * h)
+        if letter == "*":
+            ak = self.kinds[0]
+            return (h + h * ak.num_heads * self.head_dim
+                    + h * ak.num_kv_heads * (self.head_dim + self.v_dim)
+                    + ak.num_heads * self.v_dim * h)
+        mats = 3 if self.mlp_gated else 2
+        w, f = self.expert_width, self.moe_intermediate_size
+        return (h + h * self.router_experts
+                + (self.router_experts if self.router_bias else 0)
+                + (2 * h * w if self.moe_latent_size else 0)
+                + self.local_experts * mats * w * f
+                + self.shared_experts * mats * h * f)
+
     def num_params(self) -> int:
-        """Approximate parameter count (for memory budgeting)."""
+        """Parameter count (for memory budgeting): exact for a stack of
+        layer groups, approximate for a stack of alike layers."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        if self.block_pattern:
+            return (v * h * (1 if self.tie_word_embeddings else 2) + h
+                    + sum(self.block_params(c) for c in self.block_pattern))
         if self.layer_groups:
             # every weight HELD here: the local experts only
             total = v * h * (1 if self.tie_word_embeddings else 2) + h
@@ -542,6 +667,52 @@ TINY_LAGUNA_DEBUG = _register(
     )
 )
 
+# a stack of single-sublayer blocks at tiny widths that keep every code
+# path of `layer_groups.forward_blocks`: the pattern EMEMEM* (one unit
+# "EM" three times and an attention block), a state-space mixer of 8
+# heads of 4 in 2 groups (4 heads a group) with a state of 8, a
+# convolution of 4 taps and chunks of 8 rows (shorter than the prompts),
+# attention without positional encoding, 16 sigmoid-routed experts
+# (top-4 of score + bias, renormalised, times 2.5; rank 0 of 2 holds 8)
+# of width 24 at a latent width of 16 under a hidden width of 32, relu
+# squared with no gate matrix, a shared expert of two widths
+TINY_NEMOTRON_DEBUG = _register(
+    ModelConfig(
+        name="pst-tiny-nemotron-debug",
+        vocab_size=384,
+        hidden_size=32,
+        intermediate_size=0,
+        num_layers=7,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_model_len=256,
+        rope_theta=1e4,
+        hidden_act="relu2",
+        mlp_gated=False,
+        attn_kinds=(AttnKind(num_kv_heads=2, rope_theta=1e4),),
+        layer_kinds=(0,),
+        block_pattern="EMEMEM*",
+        rope=False,
+        ssm_heads=8,
+        ssm_head_dim=8,
+        ssm_groups=2,
+        ssm_state=8,
+        ssm_conv=4,
+        ssm_chunk=8,
+        moe_latent_size=16,
+        router_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        router_bias=True,
+        moe_intermediate_size=24,
+        ep_rank=0,
+        ep_size=2,
+        shared_experts=2,
+        routed_scaling=2.5,
+    )
+)
+
 # CI-scale stand-in for facebook/opt-125m in the reference's test configs:
 # same order of magnitude, Llama-class architecture.
 SMALL_125M = _register(
@@ -664,7 +835,8 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
     by_type = {"mimo_v2": _from_mimo_v2, "xing4_0": _from_xing4,
-               "ouro": _from_ouro, "laguna": _from_laguna}
+               "ouro": _from_ouro, "laguna": _from_laguna,
+               "nemotron_h": _from_nemotron_h}
     if hf.get("model_type") in by_type:
         return by_type[hf["model_type"]](hf, name or os.path.basename(
             os.path.normpath(path)))
@@ -1057,6 +1229,105 @@ def _from_laguna(hf: dict, name: str) -> ModelConfig:
         ep_size=int(hf.get("ep_size", 1)),
         shared_experts=shared // f if routed else 0,
         routed_scaling=float(hf.get("moe_routed_scaling_factor") or 1.0),
+    )
+
+
+def _from_nemotron_h(hf: dict, name: str) -> ModelConfig:
+    """`model_type: nemotron_h` (the Nemotron-H / Nemotron-3 hybrids):
+    `hybrid_override_pattern` gives each layer ONE sublayer, "M" a
+    Mamba-2 mixer (`mamba_num_heads` x `mamba_head_dim`, `n_groups`,
+    `ssm_state_size`, `conv_kernel`, `chunk_size`), "*" GQA attention
+    without positional encoding, "E" sigmoid-scored routed experts
+    chosen by score + bias (`n_routed_experts`, `num_experts_per_tok`,
+    renormalised, times `routed_scaling_factor`) at `moe_latent_size`
+    beside a shared expert of `moe_shared_expert_intermediate_size` on
+    the hidden state; every MLP is up -> relu squared -> down
+    (`mlp_hidden_act: relu2`, no gate matrix). The MTP module
+    (`num_nextn_predict_layers`) is not part of the main model's logits
+    and is not built. `ep_size` / `ep_rank` (a deployment's keys) say
+    which contiguous slice of the experts this engine holds. What has
+    no code path is refused by name."""
+    pattern = str(hf["hybrid_override_pattern"])
+    L = hf["num_hidden_layers"]
+    if len(pattern) != L:
+        raise ValueError(
+            f"{name}: hybrid_override_pattern has {len(pattern)} letters "
+            f"for num_hidden_layers={L}")
+    if set(pattern) - set("M*E"):
+        raise ValueError(
+            f"{name}: hybrid_override_pattern letters "
+            f"{sorted(set(pattern) - set('M*E'))} are not served (M, * "
+            "and E are; a plain-MLP block, \"-\", has no code path)")
+    for key, want in (("n_group", (None, 1)), ("topk_group", (None, 1)),
+                      ("attention_bias", (None, False)),
+                      ("mamba_proj_bias", (None, False)),
+                      ("use_bias", (None, False)),
+                      ("mlp_bias", (None, False)),
+                      ("moe_shared_expert_overlap", (None, False)),
+                      ("use_conv_bias", (None, True)),
+                      ("mamba_hidden_act", (None, "silu")),
+                      ("mlp_hidden_act", ("relu2",)),
+                      ("norm_topk_prob", (None, True)),
+                      ("sliding_window", (None,))):
+        if hf.get(key) not in want:
+            raise ValueError(
+                f"{name}: {key}={hf.get(key)!r} is not served for "
+                "model_type nemotron_h")
+    heads, p_dim = hf["mamba_num_heads"], hf["mamba_head_dim"]
+    if heads * p_dim != hf.get("expand", 2) * hf["hidden_size"] or (
+            heads % hf["n_groups"]):
+        raise ValueError(
+            f"{name}: mamba_num_heads={heads} x mamba_head_dim={p_dim} "
+            f"must be expand={hf.get('expand', 2)} x hidden_size and a "
+            f"multiple of n_groups={hf['n_groups']}")
+    routed = "E" in pattern
+    f = hf.get("moe_intermediate_size", 0)
+    shared = (hf.get("moe_shared_expert_intermediate_size") or 0) * int(
+        hf.get("n_shared_experts") or 0)
+    if routed and shared % f:
+        raise ValueError(
+            f"{name}: moe_shared_expert_intermediate_size={shared} is no "
+            f"multiple of moe_intermediate_size={f}")
+    num_heads = hf["num_attention_heads"]
+    theta = float(hf.get("rope_theta", 10000.0))
+    kv = hf.get("num_key_value_heads", num_heads)
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf.get("intermediate_size", 0),
+        num_layers=L,
+        num_heads=num_heads,
+        num_kv_heads=kv,
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // num_heads,
+        max_model_len=hf.get("max_position_embeddings", 8192),
+        rope_theta=theta,
+        rms_norm_eps=hf.get("layer_norm_epsilon",
+                            hf.get("norm_eps", 1e-5)),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        hidden_act="relu2",
+        mlp_gated=False,
+        attn_kinds=(AttnKind(num_kv_heads=kv, rope_theta=theta),),
+        layer_kinds=(0,) * pattern.count("*"),
+        block_pattern=pattern,
+        rope=False,
+        ssm_heads=heads,
+        ssm_head_dim=p_dim,
+        ssm_groups=hf["n_groups"],
+        ssm_state=hf["ssm_state_size"],
+        ssm_conv=hf.get("conv_kernel", 4),
+        ssm_chunk=hf.get("chunk_size", 128),
+        moe_latent_size=int(hf.get("moe_latent_size") or 0),
+        router_experts=hf["n_routed_experts"] if routed else 0,
+        num_experts_per_tok=hf.get("num_experts_per_tok", 2),
+        router_scoring="sigmoid",
+        router_bias=True,
+        router_renorm=True,
+        moe_intermediate_size=f,
+        ep_rank=int(hf.get("ep_rank", 0)),
+        ep_size=int(hf.get("ep_size", 1)),
+        shared_experts=shared // f if routed else 0,
+        routed_scaling=float(hf.get("routed_scaling_factor") or 1.0),
     )
 
 

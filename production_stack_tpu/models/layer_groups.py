@@ -18,6 +18,18 @@ holds one row a token that every head reads as key and as value; and
 HYPER-CONNECTIONS (`cfg.hc_mult` residual streams a token).
 Nothing here branches on a model's name: the fields select the code.
 
+And stacks of SINGLE-sublayer blocks (`cfg.block_pattern`,
+`forward_blocks`): a layer is x + F(RMSNorm(x)) with ONE F, a
+state-space mixer (`ops/ssm.py`), attention, or routed experts at a
+latent width with no gate matrix beside a shared expert. Runs of a
+repeating UNIT of unlike blocks ("EM" x 5) go under one `lax.scan`
+(`cfg.units()`), so a program traces a unit once. The recurrent state of
+the mixers is a third member of the K-side cache pytree: "ssm": {"s",
+"conv"}, a slot a sequence, and "smap", the block manager's maps from a
+block to state slots (`engine/block_manager.StateBlockManager`), from
+which `ssm.plan_rows` finds every row's sequence: no program ships
+anything for them.
+
 Latent attention (DeepSeek-V2's MLA), served ABSORBED. With x a normed
 row: c_q = RMSNorm(x W_dq), a head's q = c_q W_uq = [q_nope; q_rope];
 [c_kv; k_r] = x W_dkv, c = RMSNorm(c_kv); the cache row is [c;
@@ -77,8 +89,10 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.ops import ssm
 from production_stack_tpu.ops.cache_write import plan_rows
 from production_stack_tpu.ops.cache_write import write_kv as scatter_kv
+from production_stack_tpu.ops.expert_ffn import activation
 from production_stack_tpu.ops.layers import (
     apply_rope,
     rms_norm,
@@ -127,6 +141,8 @@ def init_params(
     """Random-init parameters; sinks, the router's selection bias and
     the hyper-connections' alpha, b and phi non-zero, so that dropping
     one shows against the reference."""
+    if cfg.block_pattern:
+        return _init_blocks(cfg, key, dtype)
     h, v = cfg.hidden_size, cfg.vocab_size
     dk, dv = cfg.head_dim, cfg.v_dim
     keys = iter(jax.random.split(key, 16 * len(cfg.segments()) + 4))
@@ -208,6 +224,78 @@ def init_params(
     params = {
         "embed": w((v, h), h),
         "segments": segments,
+        "final_norm": jnp.ones((h,), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((h, v), h)
+    return params
+
+
+def _init_blocks(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
+    """`init_params` for a stack of single-sublayer blocks: per unit of
+    `cfg.units()` a list with one stacked tree a letter. The mixer's
+    own parameters as Mamba-2 initialises them (A_log = log U(1, 16),
+    dt_bias the inverse softplus of a log-uniform step in [1e-3, 1e-1],
+    D ones); the convolution's bias and the router's selection bias
+    non-zero, so that dropping one shows against the reference."""
+    h, v = cfg.hidden_size, cfg.vocab_size
+    keys = iter(jax.random.split(
+        key, 16 * sum(len(u[0]) for u in cfg.units()) + 4))
+
+    def w(shape, fan_in):
+        return (jax.random.normal(next(keys), shape, F32)
+                * fan_in ** -0.5).astype(dtype)
+
+    def block(letter, c):
+        lp = {"norm": jnp.ones((c, h), dtype)}
+        if letter == "M":
+            d, cd, nh = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
+            step = jnp.exp(jax.random.uniform(
+                next(keys), (c, nh), F32, jnp.log(1e-3), jnp.log(1e-1)))
+            return lp | {
+                "w_in": w((c, h, d + cd + nh), h),
+                "conv_w": w((c, cfg.ssm_conv, cd), cfg.ssm_conv),
+                "conv_b": w((c, cd), 100),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "A_log": jnp.log(jax.random.uniform(
+                    next(keys), (c, nh), F32, 1.0, 16.0)),
+                "D": jnp.ones((c, nh), F32),
+                "ssm_norm": jnp.ones((c, d), dtype),
+                "w_out": w((c, d, h), d),
+            }
+        if letter == "*":
+            ak = cfg.kinds[0]
+            nq, nkv = ak.num_heads, ak.num_kv_heads
+            return lp | {
+                "wq": w((c, h, nq * cfg.head_dim), h),
+                "wk": w((c, h, nkv * cfg.head_dim), h),
+                "wv": w((c, h, nkv * cfg.v_dim), h),
+                "wo": w((c, nq * cfg.v_dim, h), nq * cfg.v_dim),
+            }
+        e, f, lat = (cfg.local_experts, cfg.moe_intermediate_size,
+                     cfg.expert_width)
+        lp |= {"router": w((c, h, cfg.router_experts), h),
+               "w_up": w((c, e, lat, f), lat),
+               "w_down": w((c, e, f, lat), f)}
+        if cfg.router_bias:
+            lp["router_bias"] = 0.1 * jax.random.normal(
+                next(keys), (c, cfg.router_experts), F32)
+        if cfg.mlp_gated:
+            lp["w_gate"] = w((c, e, lat, f), lat)
+        if cfg.moe_latent_size:
+            lp["w_lat_in"] = w((c, h, lat), h)
+            lp["w_lat_out"] = w((c, lat, h), lat)
+        if cfg.shared_experts:
+            fs = f * cfg.shared_experts
+            lp |= {"ws_up": w((c, h, fs), h), "ws_down": w((c, fs, h), fs)}
+            if cfg.mlp_gated:
+                lp["ws_gate"] = w((c, h, fs), h)
+        return lp
+
+    params = {
+        "embed": w((v, h), h),
+        "segments": [[block(letter, c) for letter in unit]
+                     for unit, c, _, _ in cfg.units()],
         "final_norm": jnp.ones((h,), dtype),
     }
     if not cfg.tie_word_embeddings:
@@ -414,10 +502,19 @@ def forward(
     *,
     block_size: int,
     write_kv=scatter_kv,    # the layers' cache write (ops/cache_write.py)
+    state_rows: tuple[int, int, int] | None = None,  # a model with
+    # recurrent state: the program's shape as `ssm.plan_rows` takes it
+    # (lanes, rows a lane at most, trailing one-token rows)
 ):
     """llama.forward's contract over a cache group per kind; returns
     (logits[r, V] fp32, k_cache, v_cache)."""
     assert lora is None, "LoRA is refused at start-up for layer groups"
+    if cfg.block_pattern:
+        return forward_blocks(
+            cfg, params, token_ids, positions, k_cache, v_cache,
+            write_slots, attn_fn, logits_rows, return_hidden,
+            block_size=block_size, write_kv=write_kv,
+            state_rows=state_rows)
     dtype = params["embed"].dtype
     block_map = k_cache["map"]
     kg, vg = list(k_cache["g"]), list(v_cache["g"])
@@ -480,6 +577,13 @@ def forward(
 
     k_cache = {"g": tuple(kg), "map": block_map, "stats": stats}
     v_cache = {"g": tuple(vg)}
+    return _head(cfg, params, h, logits_rows, return_hidden, k_cache,
+                 v_cache)
+
+
+def _head(cfg, params, h, logits_rows, return_hidden, k_cache, v_cache):
+    """The final norm and the head over the asked rows."""
+    dtype = params["embed"].dtype
     if cfg.hc_mult > 1:
         # and are summed before the final norm: the head's rows only
         h = jnp.sum(
@@ -496,3 +600,126 @@ def forward(
     with jax.named_scope("lm_head"):
         logits = jnp.dot(h_sel, lm_head, preferred_element_type=jnp.float32)
     return logits, k_cache, v_cache
+
+
+def _mlp_ungated(x, w_up, w_down, act: str):
+    """act(x W_up) W_down, float32 out."""
+    a = activation(act, jnp.dot(x, w_up, preferred_element_type=F32))
+    return jnp.dot(a.astype(x.dtype), w_down, preferred_element_type=F32)
+
+
+def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
+                   write_slots, attn_fn, logits_rows, return_hidden, *,
+                   block_size, write_kv, state_rows):
+    """`forward` for a stack of single-sublayer blocks
+    (`cfg.block_pattern`): one attention cache group, the state group
+    `k_cache["ssm"]` and the maps `k_cache["smap"]` beside it."""
+    assert state_rows is not None or not cfg.ssm_layers, (
+        "a program that reaches a state-space layer says its rows' shape")
+    dtype = params["embed"].dtype
+    ak = cfg.kinds[0]
+    nq, nkv, dk, dv = ak.num_heads, ak.num_kv_heads, cfg.head_dim, cfg.v_dim
+    kc, vc = k_cache["g"][0], v_cache["g"][0]
+    stats, state = k_cache["stats"], k_cache.get("ssm")
+    real = write_slots > 0
+    n = token_ids.shape[0]
+    plan = None
+    if cfg.ssm_layers:
+        plan = ssm.plan_rows(write_slots, positions, k_cache["smap"],
+                             block_size, *state_rows)
+    slots = plan_rows(write_slots, kc)
+    cos = sin = None
+    if cfg.rope:
+        cos, sin = rope_cos_sin(positions, ak.rotary_dim, ak.rope_theta,
+                                ak.rope_yarn, ak.rope_factor)
+    spec = AttnSpec(window=None, sink=None, block_map=None)
+    act = cfg.hidden_act
+
+    def attention(x, lp, l, kc, vc):
+        q = jnp.dot(x, lp["wq"], preferred_element_type=F32).astype(
+            dtype).reshape(n, nq, dk)
+        k = jnp.dot(x, lp["wk"], preferred_element_type=F32).astype(
+            dtype).reshape(n, nkv, dk)
+        v = jnp.dot(x, lp["wv"], preferred_element_type=F32).astype(
+            dtype).reshape(n, nkv, dv)
+        if cfg.rope:
+            q, k = apply_rope(q, k, cos, sin)
+        kc, vc = write_kv(kc, vc, l, slots, k, v)
+        # rows that are no tokens come back as the tile held them
+        # (`_layer` has the story): zero before they meet a matrix
+        out = jnp.where(real[:, None, None],
+                        attn_fn(q, l, kc, vc, spec), 0)
+        return jnp.dot(out.reshape(n, nq * dv).astype(dtype), lp["wo"],
+                       preferred_element_type=F32).astype(dtype), kc, vc
+
+    def experts(x, lp, stacks, i):
+        lat = x
+        if cfg.moe_latent_size:
+            lat = jnp.dot(x, lp["w_lat_in"],
+                          preferred_element_type=F32).astype(dtype)
+        y, st = routed_experts(
+            x, lp["router"], lp.get("router_bias"),
+            stacks.get("w_gate"), stacks["w_up"], stacks["w_down"],
+            stack_index=i, top_k=cfg.num_experts_per_tok,
+            first_expert=cfg.ep_rank * cfg.local_experts,
+            scoring=cfg.router_scoring, renorm=cfg.router_renorm,
+            scale=cfg.routed_scaling, valid=real, act=act,
+            expert_x=lat if cfg.moe_latent_size else None,
+        )
+        if cfg.moe_latent_size:
+            y = jnp.dot(y.astype(dtype), lp["w_lat_out"],
+                        preferred_element_type=F32)
+        if cfg.shared_experts:
+            with jax.named_scope("shared_expert"):
+                y = y + (swiglu(x, lp["ws_gate"], lp["ws_up"],
+                                lp["ws_down"], act=act).astype(F32)
+                         if cfg.mlp_gated else _mlp_ungated(
+                             x, lp["ws_up"], lp["ws_down"], act))
+        return y.astype(dtype), st
+
+    h = params["embed"][token_ids].astype(dtype)
+    if cfg.embed_scale != 1.0:
+        h = (h.astype(F32) * cfg.embed_scale).astype(dtype)
+    with jax.named_scope("layers"):
+        for blocks, (unit, count, a0, m0) in zip(
+                params["segments"], cfg.units()):
+            n_attn, n_ssm = unit.count("*"), unit.count("M")
+            # the experts' stacks stay whole, outside the scan's slices
+            stacks = [{k: lp[k] for k in EXPERT_STACKS if k in lp}
+                      if letter == "E" else None
+                      for letter, lp in zip(unit, blocks)]
+            sliced = [{k: a for k, a in lp.items()
+                       if letter != "E" or k not in EXPERT_STACKS}
+                      for letter, lp in zip(unit, blocks)]
+
+            def body(carry, xs, unit=unit, stacks=stacks, a0=a0, m0=m0,
+                     n_attn=n_attn, n_ssm=n_ssm):
+                h, kc, vc, st, state = carry
+                lps, i = xs
+                seen = {"*": 0, "M": 0}
+                for letter, lp, stack in zip(unit, lps, stacks):
+                    x = rms_norm(h, lp["norm"], cfg.rms_norm_eps,
+                                 cfg.norm_weight_offset)
+                    if letter == "M":
+                        f, state = ssm.mixer(
+                            cfg, x, lp, state,
+                            m0 + i * n_ssm + seen["M"], plan)
+                    elif letter == "*":
+                        f, kc, vc = attention(
+                            x, lp, a0 + i * n_attn + seen["*"], kc, vc)
+                    else:
+                        f, s = experts(x, lp, stack, i)
+                        st = st + s
+                    seen[letter] = seen.get(letter, 0) + 1
+                    h = h + f
+                return (h, kc, vc, st, state), None
+
+            (h, kc, vc, stats, state), _ = jax.lax.scan(
+                body, (h, kc, vc, stats, state),
+                (sliced, jnp.arange(count)))
+
+    k_cache = {**k_cache, "g": (kc,), "stats": stats}
+    if state is not None:
+        k_cache["ssm"] = state
+    return _head(cfg, params, h, logits_rows, return_hidden, k_cache,
+                 {"g": (vc,)})

@@ -26,6 +26,12 @@ the group, not m. The rows and the result stay whole in VMEM (m <=
 Off the TPU (CPU tests, rehearsals) the same mathematics runs as three
 `jax.lax.ragged_dot`s over all N groups with the other layers' groups
 empty (`_plain`).
+
+`w_gate=None`: experts WITHOUT a gate matrix, `act(x W_up) W_down` with
+`act` relu squared ("relu2"): two weight tiles a step through a kernel
+body of its own (`_kernel_ungated`), so the SwiGLU kernel above stays
+what it compiled to; an `f` that no tile of `_F_TILES` divides takes its
+largest divisor of whole 128-lane tiles up to `_F_MAX` (2,688 -> 896).
 """
 
 from __future__ import annotations
@@ -42,18 +48,31 @@ from jax.experimental.pallas import tpu as pltpu
 MAX_ROWS = 512      # rows and float32 result whole in VMEM: 24 MiB at d 4096
 _ROW_TILE = 128     # the MXU's height
 _F_TILES = (512, 256, 128)
+_F_MAX = 1024       # an ungated expert's f tile: two weight tiles a step
 
 
-def _plain(xs, w_gate, w_up, w_down, sizes, skip, base):
+def activation(name: str, v):
+    """An MLP's activation by its config name ("silu", "relu2")."""
+    if name == "relu2":
+        return jnp.square(jax.nn.relu(v))
+    assert name == "silu", name
+    return jax.nn.silu(v)
+
+
+def _plain(xs, w_gate, w_up, w_down, sizes, skip, base, act="silu"):
     # ragged_dot's groups start at row 0: the skipped rows ride with the
     # first expert and are zeroed below
     group = lax.dynamic_update_slice(
-        jnp.zeros((w_gate.shape[0],), jnp.int32),
+        jnp.zeros((w_up.shape[0],), jnp.int32),
         sizes.at[0].add(skip).astype(jnp.int32), (base,))
     kw = dict(preferred_element_type=jnp.float32)
-    g = lax.ragged_dot(xs, w_gate, group, **kw)
-    u = lax.ragged_dot(xs, w_up, group, **kw)
-    a = (jax.nn.silu(g) * u).astype(xs.dtype)
+    if w_gate is None:
+        a = activation(act, lax.ragged_dot(xs, w_up, group, **kw))
+    else:
+        g = lax.ragged_dot(xs, w_gate, group, **kw)
+        u = lax.ragged_dot(xs, w_up, group, **kw)
+        a = activation(act, g) * u
+    a = a.astype(xs.dtype)
     y = lax.ragged_dot(a, w_down, group, **kw)
     row = jnp.arange(xs.shape[0])[:, None]
     return jnp.where((row >= skip) & (row < skip + jnp.sum(sizes)), y, 0.0)
@@ -91,20 +110,57 @@ def _kernel(meta_ref, wg_ref, wu_ref, wd_ref, x_ref, o_ref, *, steps, ts):
             lax.fori_loop(first // ts, (first + size - 1) // ts + 1, tile, 0)
 
 
-def expert_ffn(xs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+def _kernel_ungated(meta_ref, wu_ref, wd_ref, x_ref, o_ref, *, steps, ts,
+                    act):
+    """`_kernel` for experts without a gate matrix."""
+    w, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((w == 0) & (t == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    first = meta_ref[steps + w]
+    size = meta_ref[2 * steps + w]
+
+    def rows(r0):
+        x = x_ref[pl.ds(r0, ts), :]
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        row = r0 + lax.broadcasted_iota(jnp.int32, (ts, 1), 0)
+        a = jnp.where((row >= first) & (row < first + size),
+                      activation(act, u), 0.0).astype(x.dtype)
+        o_ref[pl.ds(r0, ts), :] += jnp.dot(
+            a, wd_ref[0], preferred_element_type=jnp.float32)
+
+    @pl.when(size > 0)
+    def _():
+        if ts == x_ref.shape[0]:
+            rows(0)
+        else:
+            def tile(r, carry):
+                rows(pl.multiple_of(r * ts, ts))
+                return carry
+
+            lax.fori_loop(first // ts, (first + size - 1) // ts + 1, tile, 0)
+
+
+def expert_ffn(xs: jax.Array, w_gate: jax.Array | None, w_up: jax.Array,
                w_down: jax.Array, sizes: jax.Array,
                skip: jax.Array | int = 0, base: jax.Array | int = 0,
-               interpret: bool | None = None) -> jax.Array:
+               interpret: bool | None = None,
+               act: str = "silu") -> jax.Array:
     """`interpret`: None = the kernel on a TPU and `_plain` elsewhere;
     True = the kernel in interpret mode (tests)."""
     if interpret is None and jax.default_backend() != "tpu":
-        return _plain(xs, w_gate, w_up, w_down, sizes, skip, base)
+        return _plain(xs, w_gate, w_up, w_down, sizes, skip, base, act)
     m, d = xs.shape
-    f = w_gate.shape[2]
+    f = w_up.shape[2]
     e_loc = sizes.shape[0]
     if m > MAX_ROWS:
         raise ValueError(f"{m} rows: expert_ffn holds at most {MAX_ROWS}")
     tf = next((t for t in _F_TILES if f % t == 0), f)
+    if w_gate is None and f % 128 == 0:
+        tf = max(t for t in range(128, min(f, _F_MAX) + 1, 128)
+                 if f % t == 0)
     ts = math.gcd(m, _ROW_TILE)
     if ts % 16:
         ts = m  # no aligned tile divides the rows: one tile of them all
@@ -136,6 +192,24 @@ def expert_ffn(xs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                       memory_space=pltpu.VMEM)
     down = pl.BlockSpec((1, tf, d), lambda w, t, meta: (meta[w], t, 0),
                         memory_space=pltpu.VMEM)
+    if w_gate is None:
+        return pl.pallas_call(
+            functools.partial(_kernel_ungated, steps=steps, ts=ts, act=act),
+            name="expert_ffn",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(count, f // tf),
+                in_specs=[up, down, whole],
+                out_specs=whole,
+            ),
+            out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
+            interpret=bool(interpret),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=64 * 2**20,
+            ),
+        )(meta, w_up, w_down, xs)
+    assert act == "silu", act
     return pl.pallas_call(
         functools.partial(_kernel, steps=steps, ts=ts),
         name="expert_ffn",

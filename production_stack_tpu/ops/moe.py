@@ -166,7 +166,8 @@ def routed_experts(
     x: jax.Array,            # [n, d]
     router_w: jax.Array,     # [d, E_all]
     router_b: jax.Array | None,  # [E_all]
-    w_gate: jax.Array,       # [E_loc, d, f] — the experts held here
+    w_gate: jax.Array | None,  # [E_loc, d, f] — the experts held here;
+                               # None: experts without a gate matrix
     w_up: jax.Array,
     w_down: jax.Array,       # [E_loc, f, d]
     *,
@@ -178,6 +179,10 @@ def routed_experts(
     valid: jax.Array | None = None,  # [n] bool: real rows
     stack_index: jax.Array | None = None,
     interpret: bool | None = None,  # `expert_ffn`'s
+    act: str = "silu",       # the experts' activation (`expert_ffn`)
+    expert_x: jax.Array | None = None,  # [n, d_e]: what the experts
+    # read where that is not the router's input (a latent width d_e;
+    # the result is then [n, d_e] too)
 ) -> tuple[jax.Array, jax.Array]:
     """The local experts' share of a routed layer -> ([n, d] f32,
     stats [3] int32 = pairs routed, pairs whose expert is here, local
@@ -232,12 +237,15 @@ def routed_experts(
     nothing 5% slower at 11 experts of 64 (call 1, same seed)."""
     n, _ = x.shape
     stacked = stack_index is not None
-    e_loc = w_gate.shape[1 if stacked else 0]
+    e_loc = w_up.shape[1 if stacked else 0]
     if valid is not None:
         # whatever such a row holds (not a number, even) must not reach
         # the others through the row matrices below: 0 x NaN is NaN
         x = jnp.where(valid[:, None], x, 0)
     idx, w = route(x, router_w, router_b, top_k, scoring, renorm, scale)
+    if expert_x is not None:
+        x = expert_x if valid is None else jnp.where(
+            valid[:, None], expert_x, 0)
     local = idx - first_expert
     keep = (local >= 0) & (local < e_loc)
     if valid is not None:
@@ -256,7 +264,8 @@ def routed_experts(
     base = 0
     if stacked:
         w_gate, w_up, w_down = (
-            a.reshape(-1, *a.shape[2:]) for a in (w_gate, w_up, w_down))
+            a if a is None else a.reshape(-1, *a.shape[2:])
+            for a in (w_gate, w_up, w_down))
         base = stack_index * e_loc
 
     # pairs sorted by local expert, the ones not here last; the experts
@@ -297,7 +306,7 @@ def routed_experts(
                      preferred_element_type=jnp.float32).astype(x.dtype)
         # zero in the rows that are no local expert's
         y = expert_ffn(xs, w_gate, w_up, w_down, group, skip, base,
-                       interpret=interpret)
+                       interpret=interpret, act=act)
         live = (jnp.arange(m) >= skip) & (lo + jnp.arange(m) < ends[-1])
         wt = jnp.where(live, w_flat[sel], 0.0)
         combine = jnp.where(pick, wt[:, None], 0.0).T         # (n, m)

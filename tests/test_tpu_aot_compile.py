@@ -261,6 +261,45 @@ def test_expert_ffn_compiles_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < d * f * 2
 
 
+def test_the_ungated_expert_kernel_and_the_state_update_compile(v5e):
+    """What `nemotron3-super-ep4-l11.chat-sys2k` adds to the chip's
+    kernels, at its shapes: `expert_ffn` without a gate matrix over the
+    whole stacks of the "EM" run (640 groups of 1,024 x 2,688, an f tile
+    of 896), and the decode lanes' state update in place
+    (`ops/ssm.state_update`: 32 lanes of 128 x 64 x 128 float32, packed
+    two heads a row, in a pool of 129 slots a layer, nothing of a
+    state's size among the temps)."""
+    from production_stack_tpu.ops import expert_ffn as ef
+    from production_stack_tpu.ops import ssm
+
+    n, d, f, rows = 5 * 128, 1024, 2688, 512
+    compiled = jax.jit(functools.partial(
+        ef.expert_ffn, interpret=False, act="relu2")).lower(
+        _spec(v5e, (rows, d), jnp.bfloat16), None,
+        _spec(v5e, (n, d, f), jnp.bfloat16),
+        _spec(v5e, (n, f, d), jnp.bfloat16),
+        _spec(v5e, (128,), jnp.int32), _spec(v5e, (), jnp.int32),
+        _spec(v5e, (), jnp.int32),
+    ).compile()
+    call = [line for line in compiled.as_text().splitlines()
+            if "custom-call(" in line and "expert_ffn" in line]
+    assert len(call) == 1
+    assert re.search(rf"bf16\[{n},({d},{f}|{f},{d})\]", call[0][:400])
+    assert compiled.memory_analysis().temp_size_in_bytes < d * f * 2
+
+    layers, slots, r, h, p, ns, g = 5, 129, 32, 128, 64, 128, 8
+    compiled = jax.jit(ssm.state_update, donate_argnums=(0,)).lower(
+        _spec(v5e, (layers, slots, h // 2, ns, 2 * p), jnp.float32),
+        _spec(v5e, (), jnp.int32), _spec(v5e, (r,), jnp.int32),
+        _spec(v5e, (r,), jnp.int32), _spec(v5e, (r,), jnp.bool_),
+        _spec(v5e, (r, h, p), jnp.bfloat16), _spec(v5e, (r, h), jnp.float32),
+        _spec(v5e, (h,), jnp.float32), _spec(v5e, (r, g, ns), jnp.bfloat16),
+        _spec(v5e, (r, g, ns), jnp.bfloat16),
+    ).compile()
+    assert "ssm_state_update" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < h * p * ns * 4
+
+
 @pytest.mark.slow
 def test_prefill_kernel_compiles(v5e):
     fn = functools.partial(
