@@ -1368,7 +1368,16 @@ class LLMEngine:
         making a same-length table reference someone else's KV. At
         stage time `advance` is the CURRENT round's K (its tokens are
         not yet applied) while `k` is the STAGED round's predicted K —
-        under adaptive K the two can differ."""
+        under adaptive K the two can differ.
+
+        The lanes need no entry of their own: a sequence's lane
+        (`ModelRunner.decode_lanes`) is a function of the first pages
+        of the round's tables in order, and the same sequences in the
+        same order with no table freed hold the same first pages. So
+        the stage was laid out over the lanes this round takes, and
+        the chained `toks_dev[-1]` of the round before sits in them
+        already. The dispatch still compares the two maps, and counts
+        a stage laid out otherwise as a miss."""
         return (
             tuple(s.request_id for s in seqs),
             tuple(s.num_tokens + advance for s in seqs),
@@ -1384,8 +1393,11 @@ class LLMEngine:
         lps: tuple | None = None,
         valid: np.ndarray | None = None,
         round_attrs: dict | None = None,
+        lanes: np.ndarray | None = None,
     ) -> None:
-        """Apply a fused-K round's (k, b) sampled tokens.
+        """Apply a fused-K round's (k, b) sampled tokens: sequence j's
+        are lane `lanes[j]`'s (`ModelRunner.decode_lanes`; None: lane
+        j), in `toks`, `lps` and `valid` alike.
         `lps` = (chosen (k,b), top_vals (k,b,CAP), top_ids (k,b,CAP))
         host arrays when any lane requested logprobs. `valid` = the
         device-stop per-lane valid counts ((b,) int32, full-lane
@@ -1394,9 +1406,14 @@ class LLMEngine:
         touching the overshoot counter — the host takes exactly the
         generated tokens."""
         with self.phases.span("apply"):
-            nb = len(seqs)
+            if lanes is None:
+                lanes = slice(len(seqs))
+            # from here on column j is sequence j's lane
+            toks = toks[:, lanes]
+            if lps is not None:
+                lps = tuple(a[:, lanes] for a in lps)
             # one numpy->python conversion per lane, not one per k*b slot
-            vcounts = valid[:nb].tolist() if valid is not None else None
+            vcounts = valid[lanes].tolist() if valid is not None else None
             if vcounts and max(vcounts) < k:
                 # every lane froze before the trip count: the device round
                 # exited early instead of paying the all-finished tail
@@ -1404,13 +1421,13 @@ class LLMEngine:
             # a lane whose tokens can only end it at the last of them
             # takes them in one call; any other lane token by token
             by_token: list[int] = []
-            lanes = toks[:, :nb].T.tolist() if vcounts is not None else None
+            rows = toks.T.tolist() if vcounts is not None else None
             for j, seq in enumerate(seqs):
-                if (lanes is None or seq.finished
+                if (rows is None or seq.finished
                         or not self._applies_in_one(seq)):
                     by_token.append(j)
                     continue
-                tokens = lanes[j][:vcounts[j]]
+                tokens = rows[j][:vcounts[j]]
                 if tokens:
                     seq.num_computed_tokens = (
                         seq.num_tokens + len(tokens) - 1
@@ -1743,6 +1760,10 @@ class LLMEngine:
                 stop = (
                     self._stop_arrays(seqs) if self._device_stop else None
                 )
+                # where each sequence sits among the round's lanes:
+                # everything the pack ships a lane goes there, and
+                # everything that comes back a lane is read there
+                lanes = self.runner.decode_lanes(tables)
                 staged_kw = {}
                 st = self._staged_decode
                 self._staged_decode = None
@@ -1750,7 +1771,8 @@ class LLMEngine:
                     if (penalties is None and bias is None
                             and guided_tables is None
                             and st["fp"] == self._stage_fingerprint(
-                                seqs, k_steps)):
+                                seqs, k_steps)
+                            and np.array_equal(st["lanes"], lanes)):
                         # the prediction held: dispatch chained on the
                         # previous round's on-device tokens with the
                         # pre-uploaded packed buffer — zero serial h2d
@@ -1774,6 +1796,7 @@ class LLMEngine:
                 want_logprobs=want_lp,
                 guided=guided_tables,
                 logit_bias=bias,
+                lanes=lanes,
                 **stop_kw,
                 **staged_kw,
             )  # (k, b) on device [+ logprob arrays] [+ valid]
@@ -1822,8 +1845,9 @@ class LLMEngine:
                         [s.block_table for s in seqs],
                         [s.num_tokens + k_steps for s in seqs],
                         k_next, temps, top_ps, top_ks, nk,
-                        min_ps=min_ps, stop=stage_stop,
+                        min_ps=min_ps, stop=stage_stop, lanes=lanes,
                     ),
+                    "lanes": lanes,
                     "chain_tokens": toks_dev[-1],
                 }
             # materialize the round's results in one place so the d2h
@@ -1849,6 +1873,7 @@ class LLMEngine:
                 )
             self._apply_multi_tokens(
                 seqs, toks_np, k_steps, lps=lps_np, valid=valid_np,
+                lanes=lanes,
             )
             stepped.extend(seqs)
         else:
@@ -2019,6 +2044,10 @@ class LLMEngine:
             bias = self._bias_arrays(seqs)
             stop = self._stop_arrays(seqs) if self._device_stop else None
             tokens = [s.last_token_id for s in seqs]
+            # the decode sequences' lanes, as in `_run_decode_round`;
+            # the prefill lanes stay in the order of `works`
+            lanes = self.runner.decode_lanes(
+                [s.block_table for s in seqs])
             staged_kw = {}
             st = self._staged_ragged
             self._staged_ragged = None
@@ -2026,7 +2055,8 @@ class LLMEngine:
                 if (penalties is None and bias is None
                         and guided_tables is None
                         and st["fp"] == self._ragged_fingerprint(
-                            works, seqs, k_steps)):
+                            works, seqs, k_steps)
+                        and np.array_equal(st["lanes"], lanes)):
                     # the prediction held: chain the decode lanes on the
                     # previous round's on-device tokens with the
                     # pre-uploaded lane-typed buffer — zero serial h2d
@@ -2061,6 +2091,7 @@ class LLMEngine:
             guided=guided_tables,
             logit_bias=bias,
             pf_budgets=pf_budgets,
+            lanes=lanes,
             **stop_kw,
             **staged_kw,
         )
@@ -2077,7 +2108,7 @@ class LLMEngine:
         # so its upload overlaps this round's execution + fetch
         self._maybe_stage_ragged(
             works, seqs, k_steps, temps, top_ps, top_ks, keys, min_ps,
-            stop, penalties, bias, guided_tables, toks_dev,
+            stop, penalties, bias, guided_tables, toks_dev, lanes,
         )
         stepped: list[Sequence] = []
         for w in works:
@@ -2164,6 +2195,7 @@ class LLMEngine:
                 "prefill_lanes": len(works),
                 "decode_lanes": len(seqs),
             },
+            lanes=lanes,
         )
         stepped.extend(seqs)
         self._note_ragged_round(len(works), len(seqs))
@@ -2215,13 +2247,14 @@ class LLMEngine:
 
     def _maybe_stage_ragged(
         self, works, seqs, k_steps, temps, top_ps, top_ks, keys,
-        min_ps, stop, penalties, bias, guided_tables, toks_dev,
+        min_ps, stop, penalties, bias, guided_tables, toks_dev, lanes,
     ) -> None:
         """Stage the PREDICTED next lane-typed round (h2d prefetch —
         the PR 1/PR 5 staging pattern applied to the unified round):
         prefill lanes advance by their chunk, decode lanes chain on
-        this round's on-device tokens advanced by K. Validated by
-        fingerprint + the runner's total-layout check before use."""
+        this round's on-device tokens advanced by K, in this round's
+        `lanes` (`_stage_fingerprint`). Validated by fingerprint + the
+        runner's total-layout check before use."""
         if not (self._prefetch_decode and self._prefill_pipeline):
             return
         if (penalties is not None or bias is not None
@@ -2275,6 +2308,7 @@ class LLMEngine:
                 - (w.chunk_start + w.chunk_len)
                 for w in nxt
             ],
+            lanes=lanes,
         )
         self._staged_ragged = {
             "fp": (
@@ -2286,6 +2320,7 @@ class LLMEngine:
                 k_next,
             ),
             "handle": handle,
+            "lanes": lanes,
             "chain_tokens": toks_dev[-1],
         }
 

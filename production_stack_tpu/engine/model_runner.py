@@ -117,6 +117,75 @@ def shared_runs(tables: np.ndarray, ctx: np.ndarray, block_size: int
     return runs
 
 
+def seat_least(latent: bool) -> int:
+    """The fewest sequences on one leading page that `place_lanes`
+    seats in row blocks of their own. Two, where a shared pass streams
+    once what two walks stream twice. Three where the walk is a latent
+    kind's: its shared pass, a tall tile of RAGGED_TQ x 32 query rows
+    over a 512-key block, is bound by its arithmetic and costs 2.9
+    lanes' walks of the same keys, so a pair seated together LOSES 12%
+    of the walk (290.8 -> 325.6 us a layer for 5 live lanes over 4
+    documents of 16.5k keys) where three gain 5% (346.5 -> 327.8, 6
+    live over 2 documents) and eight on one document 53% (441.5 ->
+    205.3): scripts/bench_attention_walk.py on the chip, PERF.md
+    Findings PR 47."""
+    return 3 if latent else 2
+
+
+def place_lanes(first_pages, b: int, least: int = 2) -> np.ndarray:
+    """(n,) int32: the lane, of a round's `b`, that each of its n decode
+    sequences takes, from the physical id of the first page of its
+    table. `shared_runs` finds a run only where EVERY lane of a row
+    block holds the same leading pages, so the sequences on one first
+    page sit together: the groups of `least` or more, largest first
+    (ties by first appearance), take whole row blocks of RAGGED_TQ
+    lanes from the lowest that is free, `ceil(n / RAGGED_TQ)` each, and
+    everybody else fills the blocks that are left in the order given. A
+    group whose blocks would leave the others too few lanes stays with
+    the others. The lanes between hold no sequence (context 0: a zero-row
+    segment of the walk, frozen from iteration 0). One group of
+    everybody, or no group, is the identity: lane i for sequence i.
+    A pure function of its arguments: the same sequences in the same
+    order take the same lanes in every round, which is what lets a
+    staged round chain on the tokens the last one left on the device
+    (`LLMEngine._stage_fingerprint`)."""
+    n, tq = len(first_pages), RAGGED_TQ
+    lanes = np.arange(n, dtype=np.int32)
+    groups: dict[int, list[int]] = {}
+    for i, page in enumerate(first_pages):
+        groups.setdefault(page, []).append(i)
+    shared = sorted((g for g in groups.values() if len(g) >= least),
+                    key=lambda g: -len(g))
+    if not shared or len(shared[0]) == n:
+        return lanes
+    free = list(range(-(-b // tq)))
+    rest = set(range(n))
+
+    def room(blocks: list[int]) -> int:
+        return sum(min(tq, b - blk * tq) for blk in blocks)
+
+    for g in shared:
+        need = -(-len(g) // tq)
+        take, left = free[:need], free[need:]
+        if room(take) < len(g) or room(left) < len(rest) - len(g):
+            continue
+        for j, i in enumerate(g):
+            lanes[i] = take[j // tq] * tq + j % tq
+        free = left
+        rest.difference_update(g)
+    spare = (blk * tq + r for blk in free
+             for r in range(min(tq, b - blk * tq)))
+    for lane, i in zip(spare, sorted(rest)):
+        lanes[i] = lane
+    return lanes
+
+
+def _seats(lanes: np.ndarray | None, n: int) -> np.ndarray:
+    """A round's map from sequence to lane (`place_lanes`), or lane i
+    for sequence i where the caller gave none."""
+    return np.arange(n, dtype=np.int32) if lanes is None else lanes
+
+
 def jit_program(kind: str, fn, **jit_kw):
     """`jax.jit(fn)` under the name of its kind."""
     assert kind in PROGRAM_KINDS, kind
@@ -422,6 +491,10 @@ class ModelRunner:
             self._kind_run_keys[0] if mc.attn_kinds
             else 0 if mc.sliding_window else self._kv_block_keys(
                 mc.num_kv_heads, mc.head_dim, mc.head_dim))
+        # how many sequences on one leading page `decode_lanes` seats
+        # together: by the kind whose table `shared_runs` reads
+        self._seat_least = seat_least(
+            bool(mc.attn_kinds and mc.attn_kinds[0].latent_dim))
         # the shared runs of the decode pack filled last
         # (`_fill_decode_pack`), for the dispatch's counters or the
         # staged handle that carries them to it
@@ -1146,14 +1219,16 @@ class ModelRunner:
     def _note_attn_context(
         self, decode_lens=(), steps: int = 0, prefill_lens=(),
         forwards: int | None = None, runs: np.ndarray | None = None,
+        lanes: np.ndarray | None = None,
     ) -> None:
         """Count one dispatched round's attention reads: each decode
         lane's context at each of its `steps` fused steps (context + i
         at step i) and each prefill chunk's END context once, both cut
         to the sliding window where the model has one. A lane that a
         device stop freezes mid-round is counted to the round's end.
-        Where the round's pack found shared runs (`runs`, the decode
-        lanes in the pack's order), a run counts ONCE a row block and
+        Where the round's pack found shared runs (`runs`, with the
+        lanes its sequences took, `lanes`: lane i for sequence i where
+        None), a run counts ONCE a row block and
         step into what the walk streams (tpu:attn_context_tokens, and
         each kind's at its own KV block), every lane's into the
         lane-tokens attended and, of those, served by a shared pass.
@@ -1166,9 +1241,9 @@ class ModelRunner:
         self.loop_passes += (
             (max(k, 1) if forwards is None else forwards)
             * self._passes_a_forward)
-        lanes = self.config.max_num_seqs
-        self.decode_lane_steps[0] += k * lanes
-        self.decode_lane_steps[1] += k * (lanes - n)
+        b = self.config.max_num_seqs
+        self.decode_lane_steps[0] += k * b
+        self.decode_lane_steps[1] += k * (b - n)
         self.ssm_lane_layer_steps += k * n * self._ssm_layers
         w = self.model_config.sliding_window
         if w is None:
@@ -1179,15 +1254,19 @@ class ModelRunner:
                 min(c + i, w) for c in decode_lens for i in range(k)
             ) + sum(min(c, w) for c in prefill_lens)
 
+        live: list[int] = []
+        if runs is not None:
+            live = np.bincount(_seats(lanes, n) // RAGGED_TQ,
+                               minlength=len(runs)).tolist()
+
         def run_tokens(kv_block: int) -> tuple[int, int]:
             """(lane-tokens a step that a shared pass serves, tokens a
             step that it spares the walk) at a KV block of that many
-            keys: the lanes with a sequence are the first `n`."""
+            keys: a row block's run, times its lanes with a sequence."""
             served = spared = 0
             if runs is not None and kv_block:
-                for blk, keys in enumerate(runs[:, 0].tolist()):
+                for keys, lanes_in in zip(runs[:, 0].tolist(), live):
                     cut = keys // kv_block * kv_block
-                    lanes_in = min(n - blk * RAGGED_TQ, RAGGED_TQ)
                     if cut and lanes_in > 1:
                         served += cut * lanes_in
                         spared += cut * (lanes_in - 1)
@@ -2742,10 +2821,12 @@ class ModelRunner:
         return bt
 
     def _page_table_rows(
-        self, block_tables: list[list[int]], b: int, n_pages: int
+        self, block_tables: list[list[int]], b: int, n_pages: int,
+        lanes: np.ndarray | None = None,
     ) -> np.ndarray:
-        """The decode lanes' tables as (b, n_pages) rows, padded like
-        `_padded_block_table`. A lane's row is KEPT from one pack to the
+        """The decode lanes' tables as (b, n_pages) rows, table i at
+        lane `lanes[i]` (None: i), padded like `_padded_block_table`. A
+        sequence's row is KEPT from one pack to the
         next and extended by the ids its table gained, instead of being
         made from a Python list of a few hundred ids every round: while
         a sequence holds a table the block manager only appends to it (a
@@ -2754,7 +2835,8 @@ class ModelRunner:
         shorter. Rows of tables that this pack did not see are dropped."""
         out = np.zeros((b, n_pages), dtype=np.int32)
         kept, seen = self._kept_rows, {}
-        for i, table in enumerate(block_tables):
+        seats = _seats(lanes, len(block_tables)).tolist()
+        for lane, table in zip(seats, block_tables):
             n = len(table)
             held, row, have = kept.get(id(table), (None, (), 0))
             if held is table and have <= n <= len(row):
@@ -2764,7 +2846,7 @@ class ModelRunner:
                 row[:n] = table
             seen[id(table)] = (table, row, n)
             use = min(n, n_pages)
-            out[i, :use] = row[:use]
+            out[lane, :use] = row[:use]
         self._kept_rows = seen
         return out
 
@@ -2778,6 +2860,16 @@ class ModelRunner:
         if self.mesh is not None:
             return np.zeros((_ceil_tq(len(ctx)) // RAGGED_TQ, 2), np.int32)
         return shared_runs(tables, ctx, self.block_size)
+
+    def decode_lanes(self, block_tables: list[list[int]]) -> np.ndarray:
+        """The lanes a round's decode sequences take (`place_lanes`),
+        from the first page of each one's table: a layer-group model's
+        is its kind 0's, the table `shared_runs` reads. The engine asks
+        once a round, hands the map to the dispatch (and to the stage
+        of the next round) as `lanes=`, and reads what comes back a
+        lane through it."""
+        return place_lanes([t[0] for t in block_tables],
+                           self.config.max_num_seqs, self._seat_least)
 
     def _gather_slots_for_table(
         self, block_table: list[int], c_pad: int
@@ -3291,7 +3383,11 @@ class ModelRunner:
         lora_slots: list[int] | None = None,
     ) -> jax.Array:
         """One decode step for a batch; returns fp32 logits (b, vocab) where
-        rows beyond len(token_ids) are padded lanes."""
+        rows beyond len(token_ids) are padded lanes. Sequence i is lane
+        i here: this step ships no shared run (`_decode_pack_layout`
+        has the field, this program's arguments do not), so where a
+        sequence sits changes nothing, and the host samples from
+        `logits[:len(token_ids)]`."""
         b_actual = len(token_ids)
         b = self.config.max_num_seqs
         c_pad = self._ctx_bucket(max(context_lens))
@@ -3371,6 +3467,7 @@ class ModelRunner:
         min_ps=None,
         guided_lanes: tuple | None = None,
         stop: tuple | None = None,
+        lanes: np.ndarray | None = None,
     ) -> np.ndarray:
         """Build the ONE packed int32 host buffer a fused decode
         dispatch ships (layout: _decode_pack_layout). Shared by the
@@ -3378,9 +3475,13 @@ class ModelRunner:
         (stage_decode_multi). `stop` = (eos, min_rem, budget,
         stop_ids|None) per-lane device-stop arrays (see decode_multi);
         padded lanes ship eos -1 and budget 0 (frozen from iteration
-        0, so all-real-lanes-done rounds early-exit)."""
+        0, so all-real-lanes-done rounds early-exit). Every argument
+        holds one entry a SEQUENCE; sequence i's go to lane `lanes[i]`
+        (`decode_lanes`; None: lane i), and the lanes that nobody takes
+        are the padded ones, wherever they lie."""
         b = self.config.max_num_seqs
         b_actual = len(positions)
+        lanes = _seats(lanes, b_actual)
         stop_cap = None
         if stop is not None:
             stop_cap = 0 if stop[3] is None else int(stop[3].shape[1])
@@ -3397,19 +3498,19 @@ class ModelRunner:
 
         if not chained:
             tokens = np.zeros((b,), dtype=np.int32)
-            tokens[:b_actual] = token_ids
+            tokens[lanes] = token_ids
             put("tokens", tokens)
         pos = np.zeros((b,), dtype=np.int32)
-        pos[:b_actual] = positions
+        pos[lanes] = positions
         put("positions", pos)
         # a lane that holds no sequence ships context 0: the step
         # programs make it a zero-row segment of the attention walk
         ctx = np.zeros((b,), dtype=np.int32)
-        ctx[:b_actual] = context_lens
+        ctx[lanes] = context_lens
         put("ctx", ctx)
 
         tables = self._page_table_rows(
-            block_tables, b, c_pad // self.block_size)
+            block_tables, b, c_pad // self.block_size, lanes)
         put("page_tables", tables)
         self._packed_runs = None
         if "shared_run" in layout:
@@ -3417,50 +3518,50 @@ class ModelRunner:
             put("shared_run", self._packed_runs)
         if self.attention_impl != "pallas":
             gather_tables = np.zeros((b, c_pad), dtype=np.int32)
-            for i in range(b_actual):
-                gather_tables[i] = self._gather_slots_for_table(
-                    block_tables[i], c_pad
+            for lane, table in zip(lanes.tolist(), block_tables):
+                gather_tables[lane] = self._gather_slots_for_table(
+                    table, c_pad
                 )
             put("gather_tables", gather_tables)
 
         t_full = np.zeros((b,), np.float32)
-        t_full[:b_actual] = temps
+        t_full[lanes] = temps
         put("temps", t_full)
         p_full = np.ones((b,), np.float32)
-        p_full[:b_actual] = top_ps
+        p_full[lanes] = top_ps
         put("top_ps", p_full)
         k_full = np.full((b,), -1, np.int32)
-        k_full[:b_actual] = top_ks
+        k_full[lanes] = top_ks
         put("top_ks", k_full)
         m_full = np.zeros((b,), np.float32)
         if min_ps is not None:
-            m_full[:b_actual] = min_ps
+            m_full[lanes] = min_ps
         put("min_ps", m_full)
         key_full = np.zeros((b, 2), np.uint32)
-        key_full[:b_actual] = keys
+        key_full[lanes] = keys
         put("keys", key_full)
         if guided_lanes is not None:
             init_states, lane_map = guided_lanes
             g_state = np.zeros((b,), np.int32)
-            g_state[:b_actual] = init_states[:b_actual]
+            g_state[lanes] = init_states[:b_actual]
             put("g_state", g_state)
             g_lane = np.zeros((b,), np.int32)
-            g_lane[:b_actual] = lane_map[:b_actual]
+            g_lane[lanes] = lane_map[:b_actual]
             put("g_lane", g_lane)
         if stop is not None:
             eos, min_rem, budget, stop_ids = stop
             eos_full = np.full((b,), -1, np.int32)
-            eos_full[:b_actual] = eos
+            eos_full[lanes] = eos
             put("stop_eos", eos_full)
             min_full = np.zeros((b,), np.int32)
-            min_full[:b_actual] = min_rem
+            min_full[lanes] = min_rem
             put("stop_min", min_full)
             bud_full = np.zeros((b,), np.int32)  # padded lanes: done
-            bud_full[:b_actual] = budget
+            bud_full[lanes] = budget
             put("stop_budget", bud_full)
             if stop_cap:
                 sid_full = np.full((b, stop_cap), -1, np.int32)
-                sid_full[:b_actual] = stop_ids
+                sid_full[lanes] = stop_ids
                 put("stop_ids", sid_full)
         return packed
 
@@ -3468,6 +3569,7 @@ class ModelRunner:
     def stage_decode_multi(
         self, positions, block_tables, context_lens, steps,
         temps, top_ps, top_ks, keys, min_ps=None, stop=None,
+        lanes=None,
     ):
         """Speculative h2d prefetch for the NEXT chained fused round:
         build the packed buffer and START its async host->device
@@ -3488,16 +3590,19 @@ class ModelRunner:
             packed = self._fill_decode_pack(
                 c_pad, True, None, positions, block_tables, context_lens,
                 temps, top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
+                lanes=lanes,
             )
         with self.phases.span("h2d"):
             handle = (c_pad, jax.device_put(packed), self._packed_runs)
         return handle
 
     def _decode_pen_kwargs(
-        self, penalties: tuple | None, b: int, c_pad: int, b_actual: int
+        self, penalties: tuple | None, b: int, c_pad: int,
+        lanes: np.ndarray,
     ) -> dict:
         """Device penalty-state args for the fused decode scan, shared
-        by decode_multi and ragged_dispatch."""
+        by decode_multi and ragged_dispatch: sequence i's at lane
+        `lanes[i]`."""
         if penalties is None:
             return {}
         gen_lists, presence, frequency, repetition = penalties
@@ -3506,14 +3611,14 @@ class ModelRunner:
         # varies only with the existing ctx bucket — a separate pow2
         # gen bucket would multiply the compile space mid-serving
         gen_full = np.full((b, c_pad), -1, np.int32)
-        for i, g in enumerate(gen_lists):
-            gen_full[i, : len(g)] = g
+        for lane, g in zip(lanes.tolist(), gen_lists):
+            gen_full[lane, : len(g)] = g
         pres_full = np.zeros((b,), np.float32)
-        pres_full[:b_actual] = presence
+        pres_full[lanes] = presence
         freq_full = np.zeros((b,), np.float32)
-        freq_full[:b_actual] = frequency
+        freq_full[lanes] = frequency
         rep_full = np.ones((b,), np.float32)
-        rep_full[:b_actual] = repetition
+        rep_full[lanes] = repetition
         return {
             "gen_ids": jnp.asarray(gen_full),
             "presence": jnp.asarray(pres_full),
@@ -3554,18 +3659,19 @@ class ModelRunner:
         return guided_kw, guided_shapes
 
     def _decode_bias_kwargs(
-        self, logit_bias: tuple | None, b: int, b_actual: int
+        self, logit_bias: tuple | None, b: int, lanes: np.ndarray
     ) -> tuple[dict, int]:
         """Dense logit-bias args (+ cap) for the fused decode scan,
-        shared by decode_multi and ragged_dispatch."""
+        shared by decode_multi and ragged_dispatch: sequence i's at
+        lane `lanes[i]`."""
         if logit_bias is None:
             return {}, 0
         lb_ids, lb_vals = logit_bias  # (b_actual, cap) ndarrays
         bias_cap = int(lb_ids.shape[1])
         ids_full = np.zeros((b, bias_cap), np.int32)
         vals_full = np.zeros((b, bias_cap), np.float32)
-        ids_full[:b_actual] = lb_ids
-        vals_full[:b_actual] = lb_vals
+        ids_full[lanes] = lb_ids
+        vals_full[lanes] = lb_vals
         return {
             "lb_ids": jnp.asarray(ids_full),
             "lb_vals": jnp.asarray(vals_full),
@@ -3599,6 +3705,9 @@ class ModelRunner:
                                     # budget (b_actual,) i32,
                                     # stop_ids (b_actual, cap) i32
                                     # padded -1, or None)
+        lanes: np.ndarray | None = None,  # (b_actual,) the lane each
+                                          # sequence takes (decode_lanes);
+                                          # None: lane i for sequence i
     ):
         """`steps` fused decode+sample iterations (one dispatch, one
         fetch); returns (steps, b) int32 sampled tokens on device — or,
@@ -3628,7 +3737,14 @@ class ModelRunner:
         TokenDFA tables (engine/structured.py) evaluated INSIDE the
         fused scan so constrained lanes keep the K-step fetch
         amortization. The three big tables are uploaded once per
-        `cache_token` and reused across dispatches."""
+        `cache_token` and reused across dispatches.
+
+        Every per-sequence argument above is in the caller's order;
+        `lanes` says where each sits among the b lanes, and everything
+        returned per lane ((k, b) tokens, valid, the logprob arrays) is
+        read at `[..., lanes]`. A staged buffer was laid out by
+        `stage_decode_multi(lanes=)`: the caller passes the same map
+        with it, or does not pass the stage."""
         if steps > self.block_size:
             raise ValueError(
                 f"num_scheduler_steps={steps} > block_size="
@@ -3637,7 +3753,7 @@ class ModelRunner:
             )
         b = self.config.max_num_seqs
         chained = isinstance(token_ids, jax.Array)
-        b_actual = len(positions) if chained else len(token_ids)
+        lanes = _seats(lanes, len(positions))
         c_pad = self._ctx_bucket(max(context_lens) + steps - 1)
 
         # ONE packed i32 host->device buffer per dispatch (layout shared
@@ -3669,15 +3785,16 @@ class ModelRunner:
                     c_pad, chained, token_ids, positions, block_tables,
                     context_lens, temps, top_ps, top_ks, keys,
                     min_ps=min_ps, guided_lanes=guided_lanes, stop=stop,
+                    lanes=lanes,
                 )
                 runs = self._packed_runs
             with self.phases.span("h2d"):
                 packed_dev = jnp.asarray(packed)
 
-        pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, b_actual)
+        pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, lanes)
         guided_kw, guided_shapes = self._decode_guided_kwargs(guided)
         bias_kw, bias_cap = self._decode_bias_kwargs(
-            logit_bias, b, b_actual
+            logit_bias, b, lanes
         )
         cache_key = (b, c_pad, steps, penalties is not None,
                      want_logprobs, chained, guided_shapes, bias_cap,
@@ -3702,13 +3819,14 @@ class ModelRunner:
         if self.lora_manager is not None:
             slots = np.zeros((b,), dtype=np.int32)
             if lora_slots is not None:
-                slots[:b_actual] = lora_slots
+                slots[lanes] = lora_slots
             lora_kw = {
                 "lora": self.lora_manager.buffers,
                 "lora_slots": jnp.asarray(slots),
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
-        self._note_attn_context(context_lens, steps, runs=runs)
+        self._note_attn_context(context_lens, steps, runs=runs,
+                                lanes=lanes)
         self.note_sampler(steps, temps)
         with self.phases.span("dispatch"), build:
             ys, self.k_cache, self.v_cache = fn(
@@ -3776,10 +3894,12 @@ class ModelRunner:
         stop: tuple | None = None,
         pf_budgets: list[int] | None = None,
         dec_budgets: list[int] | None = None,
+        lanes: np.ndarray | None = None,
     ) -> tuple[int, int, int, np.ndarray]:
         """Concatenate lane-meta + prefill pack + decode pack; returns
         (s_pad, t_pad, pc_pad, packed). Lane order: prefill lanes 0..n_pf
-        (padded to s_pad), then the b decode lanes. `lane_budgets` carry
+        (padded to s_pad), then the b decode lanes, decode sequence i at
+        `lanes[i]` of them (`_fill_decode_pack`). `lane_budgets` carry
         remaining prompt tokens (prefill lanes) / remaining token budget
         (decode lanes) — self-describing for debugging, and lane_types
         gates the device-side idle-lane token pinning."""
@@ -3791,27 +3911,38 @@ class ModelRunner:
         dec_packed = self._fill_decode_pack(
             c_pad, chained, token_ids, positions, block_tables,
             context_lens, temps, top_ps, top_ks, keys, min_ps=min_ps,
-            guided_lanes=guided_lanes, stop=stop,
+            guided_lanes=guided_lanes, stop=stop, lanes=lanes,
         )
+        meta = self._ragged_lane_meta(
+            s_pad, pf_chunks, len(positions), steps, stop, pf_budgets,
+            dec_budgets, lanes)
+        packed = np.concatenate([meta, pf_packed, dec_packed])
+        return s_pad, t_pad, pc_pad, packed
+
+    def _ragged_lane_meta(
+        self, s_cap: int, pf_chunks, n_dec: int, steps: int, stop,
+        pf_budgets, dec_budgets, lanes: np.ndarray | None,
+    ) -> np.ndarray:
+        """The lane-meta header of a lane-typed round's buffer (types,
+        lengths, budgets): `s_cap` prefill lanes, then the b decode
+        lanes, decode sequence i at `lanes[i]` of them."""
         n_pf = len(pf_chunks)
-        n_dec = len(positions)
-        n_lanes = s_pad + b
+        dec = s_cap + _seats(lanes, n_dec)
+        n_lanes = s_cap + self.config.max_num_seqs
         types = np.zeros((n_lanes,), np.int32)
         types[:n_pf] = RAGGED_LANE_PREFILL
-        types[s_pad:s_pad + n_dec] = RAGGED_LANE_DECODE
+        types[dec] = RAGGED_LANE_DECODE
         lens = np.zeros((n_lanes,), np.int32)
         lens[:n_pf] = [len(c) for c in pf_chunks]
-        lens[s_pad:s_pad + n_dec] = steps
+        lens[dec] = steps
         budgets = np.zeros((n_lanes,), np.int32)
         if pf_budgets is not None:
             budgets[:n_pf] = pf_budgets
         if dec_budgets is not None:
-            budgets[s_pad:s_pad + n_dec] = dec_budgets
+            budgets[dec] = dec_budgets
         elif stop is not None:
-            budgets[s_pad:s_pad + n_dec] = stop[2]
-        packed = np.concatenate([types, lens, budgets, pf_packed,
-                                 dec_packed])
-        return s_pad, t_pad, pc_pad, packed
+            budgets[dec] = stop[2]
+        return np.concatenate([types, lens, budgets])
 
     def _build_ragged(self, s_pad: int, t_pad: int, pc_pad: int,
                       b: int, c_pad: int, k_steps: int,
@@ -3902,7 +4033,7 @@ class ModelRunner:
         pf_sampling, c_pad, chained, token_ids, positions,
         block_tables, context_lens, steps, temps, top_ps, top_ks,
         keys, min_ps=None, guided_lanes=None, stop=None,
-        pf_budgets=None, dec_budgets=None,
+        pf_budgets=None, dec_budgets=None, lanes=None,
     ) -> tuple[int, int, np.ndarray]:
         """Kernel-mode mirror of _fill_ragged_pack: lane-meta header
         (lane cap + b lanes) + the ragged-ROWS prefill pack + the
@@ -3916,26 +4047,12 @@ class ModelRunner:
         dec_packed = self._fill_decode_pack(
             c_pad, chained, token_ids, positions, block_tables,
             context_lens, temps, top_ps, top_ks, keys, min_ps=min_ps,
-            guided_lanes=guided_lanes, stop=stop,
+            guided_lanes=guided_lanes, stop=stop, lanes=lanes,
         )
-        n_pf = len(pf_chunks)
-        n_dec = len(positions)
-        n_lanes = s_cap + b
-        types = np.zeros((n_lanes,), np.int32)
-        types[:n_pf] = RAGGED_LANE_PREFILL
-        types[s_cap:s_cap + n_dec] = RAGGED_LANE_DECODE
-        lens = np.zeros((n_lanes,), np.int32)
-        lens[:n_pf] = [len(c) for c in pf_chunks]
-        lens[s_cap:s_cap + n_dec] = steps
-        budgets = np.zeros((n_lanes,), np.int32)
-        if pf_budgets is not None:
-            budgets[:n_pf] = pf_budgets
-        if dec_budgets is not None:
-            budgets[s_cap:s_cap + n_dec] = dec_budgets
-        elif stop is not None:
-            budgets[s_cap:s_cap + n_dec] = stop[2]
-        packed = np.concatenate([types, lens, budgets, pf_packed,
-                                 dec_packed])
+        meta = self._ragged_lane_meta(
+            s_cap, pf_chunks, len(positions), steps, stop, pf_budgets,
+            dec_budgets, lanes)
+        packed = np.concatenate([meta, pf_packed, dec_packed])
         return r_pad, pc_pad, packed
 
     def _build_ragged_rows(self, r_pad: int, pc_pad: int, b: int,
@@ -4109,7 +4226,7 @@ class ModelRunner:
         positions, block_tables, context_lens, steps,
         temps, top_ps, top_ks, keys,
         min_ps=None, stop=None,
-        pf_budgets=None, dec_budgets=None,
+        pf_budgets=None, dec_budgets=None, lanes=None,
     ) -> tuple:
         """Build + START uploading the predicted next ragged round's
         packed buffer (decode half chained: its tokens ride on device
@@ -4128,6 +4245,7 @@ class ModelRunner:
                     positions, block_tables, context_lens, steps, temps,
                     top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
                     pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                    lanes=lanes,
                 )
                 key = ("rows", r_pad, pc_pad, c_pad)
             else:
@@ -4137,6 +4255,7 @@ class ModelRunner:
                     positions, block_tables, context_lens, steps, temps,
                     top_ps, top_ks, keys, min_ps=min_ps, stop=stop,
                     pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                    lanes=lanes,
                 )
                 key = ("ragged", s_pad, t_pad, pc_pad, c_pad)
         with self.phases.span("h2d"):
@@ -4170,6 +4289,7 @@ class ModelRunner:
         stop: tuple | None = None,
         pf_budgets: list[int] | None = None,
         dec_budgets: list[int] | None = None,
+        lanes: np.ndarray | None = None,
     ) -> tuple:
         """One lane-typed engine round: prefill chunk lanes + fused
         decode lanes in a single program. Returns (pf_sampled (s_pad,)
@@ -4179,7 +4299,9 @@ class ModelRunner:
         stage_ragged handle; used only when its bucket key AND total
         layout length match (a lane-mix or stop-cap drift between stage
         and dispatch rebuilds serially — a counted staging miss, never
-        a dispatch error)."""
+        a dispatch error). `lanes`: where each decode sequence sits
+        among the b decode lanes, as for decode_multi; the prefill
+        lanes are in the caller's order."""
         if steps > self.block_size:
             raise ValueError(
                 f"num_scheduler_steps={steps} > block_size="
@@ -4196,11 +4318,11 @@ class ModelRunner:
                 penalties=penalties, want_logprobs=want_logprobs,
                 guided=guided, logit_bias=logit_bias, staged=staged,
                 stop=stop, pf_budgets=pf_budgets,
-                dec_budgets=dec_budgets,
+                dec_budgets=dec_budgets, lanes=lanes,
             )
         b = self.config.max_num_seqs
         chained = isinstance(token_ids, jax.Array)
-        b_actual = len(positions)
+        lanes = _seats(lanes, len(positions))
         c_pad = self._ctx_bucket(max(context_lens) + steps - 1)
         s_pad = next_pow2(max(len(pf_chunks), 1))
         t_pad = self._prefill_bucket(max(len(c) for c in pf_chunks))
@@ -4230,15 +4352,16 @@ class ModelRunner:
                     top_ps, top_ks, keys, min_ps=min_ps,
                     guided_lanes=guided_lanes, stop=stop,
                     pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                    lanes=lanes,
                 )
                 runs = self._packed_runs
             with self.phases.span("h2d"):
                 packed_dev = jnp.asarray(packed)
 
-        pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, b_actual)
+        pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, lanes)
         guided_kw, guided_shapes = self._decode_guided_kwargs(guided)
         bias_kw, bias_cap = self._decode_bias_kwargs(
-            logit_bias, b, b_actual
+            logit_bias, b, lanes
         )
         cache_key = (s_pad, t_pad, pc_pad, b, c_pad, steps,
                      penalties is not None, want_logprobs, chained,
@@ -4265,7 +4388,7 @@ class ModelRunner:
         if self.lora_manager is not None:
             slots = np.zeros((b,), dtype=np.int32)
             if lora_slots is not None:
-                slots[:b_actual] = lora_slots
+                slots[lanes] = lora_slots
             pf_kw = self._packed_lora_kwargs(
                 pf_lora_slots, len(pf_chunks), s_pad, t_pad
             )
@@ -4276,7 +4399,8 @@ class ModelRunner:
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
         self._note_attn_context(context_lens, steps, pf_total_lens,
-                                forwards=steps + 1, runs=runs)
+                                forwards=steps + 1, runs=runs,
+                                lanes=lanes)
         self.note_sampler(steps, temps)
         self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:
@@ -4304,6 +4428,7 @@ class ModelRunner:
         pf_lora_slots=None, lora_slots=None, penalties=None,
         want_logprobs=False, guided=None, logit_bias=None,
         staged=None, stop=None, pf_budgets=None, dec_budgets=None,
+        lanes=None,
     ) -> tuple:
         """Kernel-mode body of ragged_dispatch (same contract): the
         program keys on the padded ROW bucket + ctx buckets —
@@ -4312,7 +4437,7 @@ class ModelRunner:
         attention of the whole mix is one kernel launch."""
         b = self.config.max_num_seqs
         chained = isinstance(token_ids, jax.Array)
-        b_actual = len(positions)
+        lanes = _seats(lanes, len(positions))
         c_pad = self._ctx_bucket(max(context_lens) + steps - 1)
         r_pad, pc_pad = self._rows_dims(pf_chunks, pf_total_lens)
         guided_lanes = None
@@ -4342,15 +4467,16 @@ class ModelRunner:
                     top_ps, top_ks, keys, min_ps=min_ps,
                     guided_lanes=guided_lanes, stop=stop,
                     pf_budgets=pf_budgets, dec_budgets=dec_budgets,
+                    lanes=lanes,
                 )
                 runs = self._packed_runs
             with self.phases.span("h2d"):
                 packed_dev = jnp.asarray(packed)
 
-        pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, b_actual)
+        pen_kw = self._decode_pen_kwargs(penalties, b, c_pad, lanes)
         guided_kw, guided_shapes = self._decode_guided_kwargs(guided)
         bias_kw, bias_cap = self._decode_bias_kwargs(
-            logit_bias, b, b_actual
+            logit_bias, b, lanes
         )
         cache_key = ("rows", r_pad, pc_pad, b, c_pad, steps,
                      penalties is not None, want_logprobs, chained,
@@ -4378,7 +4504,7 @@ class ModelRunner:
         if self.lora_manager is not None:
             slots = np.zeros((b,), dtype=np.int32)
             if lora_slots is not None:
-                slots[:b_actual] = lora_slots
+                slots[lanes] = lora_slots
             # the fused step-0 forward concatenates prefill + decode
             # slot vectors, so the prefill side always ships per-row
             pf_rows = self._rows_slot_vector(
@@ -4391,7 +4517,7 @@ class ModelRunner:
             }
         chained_kw = {"chained_tokens": token_ids} if chained else {}
         self._note_attn_context(context_lens, steps, pf_total_lens,
-                                runs=runs)
+                                runs=runs, lanes=lanes)
         self.note_sampler(steps, temps)
         self.note_sampler(1, pf_sampling and pf_sampling[0])
         with self.phases.span("dispatch"), build:

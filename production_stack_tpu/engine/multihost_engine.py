@@ -161,7 +161,8 @@ class BroadcastingRunner:
     def decode_multi(self, token_ids, positions, block_tables,
                      context_lens, steps, temps, top_ps, top_ks, keys,
                      min_ps=None, lora_slots=None, penalties=None,
-                     want_logprobs=False, guided=None, logit_bias=None):
+                     want_logprobs=False, guided=None, logit_bias=None,
+                     lanes=None):
         msg = {
             "kind": "decode_multi",
             "token_ids": [int(t) for t in token_ids],
@@ -183,6 +184,9 @@ class BroadcastingRunner:
         }
         if lora_slots is not None:
             msg["lora_slots"] = [int(s) for s in lora_slots]
+        if lanes is not None:
+            # every host packs the sequences into the same lanes
+            msg["lanes"] = np.asarray(lanes).tolist()
         if penalties is not None:
             gen, pres, freq, rep = penalties
             msg["penalties"] = {
@@ -231,7 +235,7 @@ class BroadcastingRunner:
             temps, top_ps, top_ks, keys, min_ps=min_ps,
             lora_slots=lora_slots, penalties=penalties,
             want_logprobs=want_logprobs, guided=guided,
-            logit_bias=logit_bias,
+            logit_bias=logit_bias, lanes=lanes,
         )
 
     def verify_batch(self, chunks, start_positions, block_tables,
@@ -337,6 +341,8 @@ def follower_loop(runner, timeout_s: float = 600.0) -> None:
             if msg.get("min_ps") is not None:
                 msg["min_ps"] = np.asarray(msg["min_ps"], np.float32)
             msg["keys"] = np.asarray(msg["keys"], np.uint32)
+            if msg.get("lanes") is not None:
+                msg["lanes"] = np.asarray(msg["lanes"], np.int32)
             lb = msg.pop("logit_bias", None)
             if lb is not None:
                 msg["logit_bias"] = (

@@ -15,7 +15,13 @@ of five runs over LAYERS x REPEAT. Prints one JSON line a shape:
 `alone_us` (every lane's walk of its own, `shared` None), `shared_us`
 (the runs the runner would find in these tables), `parent_us`, the time
 of the bytes at the chip's HBM rate for each (`*_bytes_us`), and whether
-the two outputs are the same bits (`equal`).
+the two outputs are the same bits (`equal`). Where the lanes hold more
+than one prefix, lane i holds prefix i % groups: the order of arrival,
+in which no row block shares anything; `placed_us` is the same
+sequences seated as the engine seats them (`model_runner.place_lanes`:
+a prefix's lanes in row blocks of their own, from `seat_least` lanes
+on), with the runs found there, and `placed_equal` says whether each
+SEQUENCE got the bits its walk alone gives.
 """
 
 from __future__ import annotations
@@ -32,7 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, ".")
-from production_stack_tpu.engine.model_runner import shared_runs  # noqa: E402
+from production_stack_tpu.engine.model_runner import (  # noqa: E402
+    place_lanes, seat_least, shared_runs,
+)
 from production_stack_tpu.ops import pallas_attention as pa  # noqa: E402
 
 BS = 32
@@ -106,13 +114,26 @@ def main():
          False, (2200, 3400), 2080, 1, 128),
         ("xing4 latent, 8 lanes one document", 32, range(8), 32, 1, 640,
          0, False, (16600, 18500), 16512, 1, 1024),
+        ("ouro reason-sys2k, 4 live, 2 preambles", 16, range(4), 16, 16,
+         128, 128, False, (2300, 2900), 2080, 2, 128),
+        ("ouro reason-sys2k, 3 live, 2 preambles", 16, range(3), 16, 16,
+         128, 128, False, (2300, 2900), 2080, 2, 128),
+        ("laguna full kind, 5 live, 4 documents", 32, range(5), 48, 8,
+         128, 128, False, (16600, 18500), 16512, 4, 1024),
+        ("xing4 latent, 5 live, 4 documents", 32, range(5), 32, 1, 640,
+         0, False, (16600, 18500), 16512, 4, 1024),
+        ("xing4 latent, 6 live, 2 documents", 32, range(6), 32, 1, 640,
+         0, False, (16600, 18500), 16512, 2, 1024),
+        ("xing4 latent, 4 live, 2 documents", 32, range(4), 32, 1, 640,
+         0, False, (16600, 18500), 16512, 2, 1024),
     ]
     if args.tiny:
         args.layers, args.repeat = 2, 1
-        shapes = [(n, 8, [j for j in live if j < 8], nq // 4 or 1,
+        shapes = [(n, 16 if g > 1 else 8, [j for j in live if j < 8],
+                   nq // 4 or 1,
                    max(1, nkv // 4), dk, dv, sink, (600, 800), 512, g, 32)
                   for n, _, live, nq, nkv, dk, dv, sink, _, _, g, _
-                  in shapes[:3]]
+                  in shapes[:3] + shapes[6:7]]
     kernels = {"alone": (pa.ragged_paged_attention, False),
                "shared": (pa.ragged_paged_attention, True)}
     for i, path in enumerate(args.parent):
@@ -144,40 +165,61 @@ def main():
             keys[2], (args.layers, nkv, blocks * BS, dv), jnp.bfloat16)
         sk = jax.random.normal(keys[3], (nq,)) if sink else None
         n_blk = lanes // tq
-        lane_ids = np.arange(lanes, dtype=np.int32)
-        seg = np.stack([lane_ids, lane_ids % tq, (ctx > 0).astype(np.int32),
-                        ctx - 1], axis=1)
-        blk_seg = np.arange(n_blk + 1, dtype=np.int32) * tq
-        runs = shared_runs(tables, ctx, BS)  # as the round's pack does
         c = BS * pa._kv_block_pages(nkv, dk, 2, BS, 0 if latent else dv)
-        cut = runs[:, 0] // c * c
-        n_live = (ctx.reshape(n_blk, tq) > 0).sum(axis=1)
         token_bytes = nkv * (dk + dv) * 2
         lane_tokens = int(ctx.sum())
-        streamed = lane_tokens - int((cut * (n_live - 1)).sum())
-        row = {"shape": name, "lanes": lanes, "live": int((ctx > 0).sum()),
+
+        def walk(q, tables, ctx):
+            """The kernel's arguments for these lanes, and the tokens
+            the walk streams with the runs the round's pack finds."""
+            lane_ids = np.arange(lanes, dtype=np.int32)
+            seg = np.stack([lane_ids, lane_ids % tq,
+                            (ctx > 0).astype(np.int32), ctx - 1], axis=1)
+            blk_seg = np.arange(n_blk + 1, dtype=np.int32) * tq
+            runs = shared_runs(tables, ctx, BS)
+            cut = runs[:, 0] // c * c
+            n_live = (ctx.reshape(n_blk, tq) > 0).sum(axis=1)
+            streamed = lane_tokens - int((cut * (n_live - 1)).sum())
+            return (q, kc, vc, jnp.asarray(tables), jnp.asarray(blk_seg),
+                    jnp.asarray(seg), sk, jnp.asarray(runs)), streamed
+
+        def bytes_us(tokens):
+            return round(tokens * token_bytes / HBM_BYTES_PER_S * 1e6, 1)
+
+        at = np.flatnonzero(ctx > 0)     # the sequences, as they arrived
+        ins, streamed = walk(q, tables, ctx)
+        row = {"shape": name, "lanes": lanes, "live": len(at),
                "nkv": nkv, "g": nq // nkv, "kv_block": c,
                "lane_tokens": lane_tokens, "streamed_tokens": streamed,
-               "alone_bytes_us": round(
-                   lane_tokens * token_bytes / HBM_BYTES_PER_S * 1e6, 1),
-               "shared_bytes_us": round(
-                   streamed * token_bytes / HBM_BYTES_PER_S * 1e6, 1)}
-        ins = (q, kc, vc, jnp.asarray(tables), jnp.asarray(blk_seg),
-               jnp.asarray(seg), sk, jnp.asarray(runs))
+               "alone_bytes_us": bytes_us(lane_tokens),
+               "shared_bytes_us": bytes_us(streamed)}
+        # variant: (kernel, takes a run, its arguments, its sequences' lanes)
+        variants = {v: (*k, ins, at) for v, k in kernels.items()}
+        if groups > 1:
+            seat = place_lanes(tables[at, 0].tolist(), lanes,
+                               seat_least(bool(latent)))
+            tables_p, ctx_p = np.zeros_like(tables), np.zeros_like(ctx)
+            tables_p[seat], ctx_p[seat] = tables[at], ctx[at]
+            q_p = jnp.zeros_like(q).at[seat].set(q[at])
+            ins_p, streamed_p = walk(q_p, tables_p, ctx_p)
+            row["placed_streamed_tokens"] = streamed_p
+            row["placed_bytes_us"] = bytes_us(streamed_p)
+            variants["placed"] = (pa.ragged_paged_attention, True, ins_p,
+                                  seat)
         got = {}
-        for vname, (kernel, with_shared) in kernels.items():
+        for vname, (kernel, with_shared, v_ins, v_at) in variants.items():
             try:
                 fn = program(kernel, args.layers, args.repeat, static,
-                             with_shared).lower(*ins).compile()
-                out = jax.block_until_ready(fn(*ins))
+                             with_shared).lower(*v_ins).compile()
+                out = jax.block_until_ready(fn(*v_ins))
                 best = float("inf")
                 for _ in range(5):
                     t0 = time.perf_counter()
-                    out = jax.block_until_ready(fn(*ins))
+                    out = jax.block_until_ready(fn(*v_ins))
                     best = min(best, time.perf_counter() - t0)
                 row[f"{vname}_us"] = round(
                     best / (args.layers * args.repeat) * 1e6, 1)
-                got[vname] = np.asarray(out)[ctx > 0]
+                got[vname] = np.asarray(out)[v_at]
             except Exception as e:  # noqa: BLE001 — report, go on
                 row[f"{vname}_error"] = repr(e)[:300]
         for vname in got:

@@ -128,6 +128,21 @@ def test_the_cache_group_is_one_array_a_layer_and_no_v(eng):
     assert set(stats["kv_blocks_in_use"]) == {"latent"}
 
 
+def test_a_pair_on_one_page_keeps_its_seats_under_the_latent_walk(
+        kernel_eng):
+    """The latent kind's shared pass costs more than two lanes' walks
+    (`model_runner.seat_least`): the runner seats three on one leading
+    page together and leaves a pair where it arrived."""
+    r = kernel_eng.runner
+    assert r._seat_least == 3
+    b = r.config.max_num_seqs
+    pair = [[7, 20], [9, 21], [7, 22], [11, 23]][:b]
+    assert r.decode_lanes(pair).tolist() == list(range(len(pair)))
+    r16 = engine(max_num_seqs=16).runner
+    three = [[7, 20], [9, 21], [7, 22], [9, 23], [7, 24]]
+    assert r16.decode_lanes(three).tolist() == [0, 8, 1, 9, 2]
+
+
 def test_a_prefix_hit_on_the_kernel_path_serves_the_same_logits(kernel_eng):
     e = kernel_eng
     first = ids(40, seed=7)

@@ -894,21 +894,26 @@ def test_walk_decode_rows_beside_prefill_tail(kv_block_pages, n_pages,
 # each lane alone (`shared` None), bit for bit.
 
 def _shared_prefix_case(seed, ctx, prefix_pages, *, bs=8, nkv=2, g=2,
-                        d=128, d_v=None, latent=False, dtype=jnp.float32):
+                        d=128, d_v=None, latent=False, dtype=jnp.float32,
+                        prefix_a_block=False):
     """Decode lanes whose tables start with the SAME `prefix_pages`
-    pages and go on with pages of their own; a lane with context 0 holds
-    no sequence and its table row is the null page's."""
+    pages (`prefix_a_block`: the lanes of a row block of 8 with the
+    same, each block with other pages, as `place_lanes` seats them) and
+    go on with pages of their own; a lane with context 0 holds no
+    sequence and its table row is the null page's."""
     rng = np.random.RandomState(seed)
     b = len(ctx)
     own = [max(0, -(-c // bs) - prefix_pages) if c else 0 for c in ctx]
-    num_blocks = 1 + prefix_pages + sum(own)
+    n_prefixes = -(-b // 8) if prefix_a_block else 1
+    num_blocks = 1 + n_prefixes * prefix_pages + sum(own)
     pages = prefix_pages + max(own) + 1
     order = rng.permutation(np.arange(1, num_blocks))
     tables = np.zeros((b, pages), np.int32)
-    at = prefix_pages
+    at = n_prefixes * prefix_pages
     for i, n in enumerate(own):
         if ctx[i]:
-            tables[i, :prefix_pages] = order[:prefix_pages]
+            first = (i // 8 if prefix_a_block else 0) * prefix_pages
+            tables[i, :prefix_pages] = order[first:first + prefix_pages]
             tables[i, prefix_pages:prefix_pages + n] = order[at:at + n]
             at += n
 
@@ -957,6 +962,13 @@ _SHARED_CASES = {
         dict(nkv=2, g=2),
         [2.5, 3.2, 2.1, 4.0, 2.9, 3.3, 2.2, 3.9, 1.5, 0.4, 2.0], 2,
         dict(second_block=0)),
+    # lanes seated by the prefix they hold (`model_runner.place_lanes`):
+    # another prefix in each row block, a run in each, and the lanes
+    # that nobody took at the end of each block
+    "a-prefix-a-block-idle-tails": (
+        dict(nkv=2, g=2, prefix_a_block=True),
+        [2.5, 3.2, 2.1] + [None] * 5 + [2.9, 4.0] + [None] * 6, 2,
+        dict(second_block="a run of its own")),
 }
 
 
@@ -980,6 +992,8 @@ def test_shared_run_equals_each_lane_alone(kv_block_pages, name):
     live = [i for i, x in enumerate(ctx) if x]
     shared = np.zeros((r_pad // 8, 2), np.int32)
     shared[0] = int(run_blocks * c), live[0]
+    if told.get("second_block") == "a run of its own":
+        told["second_block"] = int(run_blocks * c)
     if "second_block" in told:
         shared[1] = told.pop("second_block"), 8
     sink = None
