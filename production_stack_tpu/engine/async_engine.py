@@ -11,9 +11,10 @@ compute finishes), so one runner thread saturates the chip.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import threading
 import time
-from collections.abc import AsyncIterator
+from collections.abc import AsyncIterator, Iterator
 
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.llm_engine import LLMEngine
@@ -89,7 +90,10 @@ class AsyncLLMEngine:
             try:
                 with engine_phases.span("lock_wait") as wait, self._lock:
                     wait.stop()  # held: what follows is the round
-                    busy = self.engine.has_unfinished()
+                    # `sleep` drains the round in flight under this
+                    # lock: a step that waited it out starts nothing
+                    busy = (not self.sleeping
+                            and self.engine.has_unfinished())
                     outputs = self.engine.step() if busy else []
                 t_fetched = engine_phases.ended("fetch")
             except Exception:  # noqa: BLE001 — a step failure must fail
@@ -128,6 +132,12 @@ class AsyncLLMEngine:
 
         outs: list[RequestOutput] = []
         with self._lock:
+            try:
+                # a round in flight goes first: an abort under it would
+                # only be deferred to its fetch
+                self.engine.drain_round()
+            except Exception:  # noqa: BLE001 — state may be corrupt
+                logger.exception("draining the round in flight failed")
             for request_id in list(self._streams):
                 try:
                     self.engine.abort_request(request_id)
@@ -167,6 +177,20 @@ class AsyncLLMEngine:
                 if q is not None:
                     q.put_nowait(out)
 
+    @contextlib.contextmanager
+    def _at_the_lock(self) -> Iterator[None]:
+        """Around the wait for `_lock` of a caller that changes what
+        the next round holds (an arrival, an abort): while it stands
+        there the engine can see it (`LLMEngine.callers_waiting`; the
+        loop thread is the count's one writer), so the step that holds
+        the lock dispatches no round past it, and it gets in at that
+        step's end, before the next round is chosen."""
+        self.engine.callers_waiting += 1
+        try:
+            yield
+        finally:
+            self.engine.callers_waiting -= 1
+
     # -- request API -------------------------------------------------------
     async def generate(
         self,
@@ -187,7 +211,8 @@ class AsyncLLMEngine:
         q: asyncio.Queue[RequestOutput] = asyncio.Queue()
         finished = False
         try:
-            with self.loop_phases.span("admit_lock_wait") as wait, \
+            with self._at_the_lock(), \
+                    self.loop_phases.span("admit_lock_wait") as wait, \
                     self._lock:
                 wait.stop()  # the wait is over: the lock is held
                 # in a trace, the step thread's `engine.lock_wait`
@@ -218,13 +243,15 @@ class AsyncLLMEngine:
             # behind the step thread's full engine.step
             self._streams.pop(request_id, None)
             if not finished:
-                with self.loop_phases.span("abort_lock_wait") as wait, \
+                with self._at_the_lock(), \
+                        self.loop_phases.span("abort_lock_wait") as wait, \
                         self._lock:
                     wait.stop()
                     self.engine.abort_request(request_id)
 
     async def abort(self, request_id: str) -> bool:
-        with self.loop_phases.span("abort_lock_wait") as wait, self._lock:
+        with self._at_the_lock(), \
+                self.loop_phases.span("abort_lock_wait") as wait, self._lock:
             wait.stop()
             return self.engine.abort_request(request_id)
 
@@ -285,6 +312,14 @@ class AsyncLLMEngine:
         (the KV cache is dropped either way once in-flight work drains)."""
         self.sleeping = True
         self.sleep_level = level
+        with self._lock:
+            # a round that started at the last fetch's return is
+            # fetched and applied before the pause; the step loop
+            # dispatches nothing behind it
+            outputs = self.engine.drain_round()
+        if outputs and self._loop is not None:
+            self._loop.call_soon_threadsafe(
+                self._deliver, outputs, 0.0, time.perf_counter())
         logger.info("engine going to sleep (level %d)", level)
 
     def wake_up(self) -> None:

@@ -218,6 +218,19 @@ class LLMEngine:
         self._staged_decode: dict | None = None
         self._staged_hits_total = 0
         self._staged_misses_total = 0
+        # a staged round starts when the fetch returns: where the
+        # fetched arrays show that the stage's prediction holds, round
+        # N+1 is dispatched BEFORE round N's tokens are applied
+        # (`_starts_at_fetch`), and is in flight when step() returns.
+        # The next step() finds it here and goes to its fetch. At most
+        # one round is on the device at any time, as without a stage
+        self._inflight: dict | None = None
+        self._early_dispatch_total = 0
+        # callers that stand at the lock around step() with a request
+        # or an abort (AsyncLLMEngine counts them): they get in before
+        # the next round is chosen, so a round never starts early
+        # past one of them
+        self.callers_waiting = 0
         # pipelined prefill (RTT-amortisation extended to the prefill
         # path): chunk N+1's packed h2d buffer uploads while chunk N
         # computes, cold multi-chunk prompts chain their chunks
@@ -1279,7 +1292,17 @@ class LLMEngine:
             self._drop_kv_restore(request_id)
         if self.long_prefill is not None:
             self._cancel_long_prefill(seq)
-        aborted = self.scheduler.abort(request_id)
+        rnd = self._inflight
+        if rnd is not None and any(s is seq for s in rnd["seqs"]):
+            # the round on the device still writes this sequence's
+            # blocks (and its state slot): they are its own until that
+            # round's fetch (`_finish_inflight`), where its tokens of
+            # the round are dropped and counted as overshoot
+            seq.status = SequenceStatus.FINISHED_ABORTED
+            rnd["aborted"].append(seq)
+            aborted = True
+        else:
+            aborted = self.scheduler.abort(request_id)
         self.timeline.finish(request_id, "abort")
         return aborted
 
@@ -1297,7 +1320,9 @@ class LLMEngine:
         return any(k.startswith(pref) for k in list(self._seqs))
 
     def has_unfinished(self) -> bool:
-        return self.scheduler.has_unfinished()
+        # a round that started at the last fetch's return is on the
+        # device between two step() calls (`drain_round`)
+        return self.scheduler.has_unfinished() or self._inflight is not None
 
     # -- the staged next round (h2d prefetch) ------------------------------
     def _reserve_next_round(self, seqs: list[Sequence], k: int) -> bool:
@@ -1314,8 +1339,12 @@ class LLMEngine:
         for s in seqs:
             sp = s.sampling_params
             remaining = sp.max_tokens - s.num_generated - k
-            if remaining < k:
-                return False  # final rounds run synchronously
+            if remaining < (1 if self._device_stop else k):
+                # the lane ends in this round. One that ends INSIDE the
+                # staged round rides it where the device stops it (the
+                # stage ships its budget less K); without device stops
+                # final rounds run synchronously
+                return False
             if s.num_tokens + 2 * k >= self.scheduler.config.max_model_len:
                 return False
             # blocks needed to cover this round + the next one
@@ -1342,15 +1371,17 @@ class LLMEngine:
             # transfer; under a mesh jit would have to reshard it
         if self.scheduler.waiting:
             return False  # admission will change the lane set
-        if self._ragged_dispatch and any(
+        if any(
             not s.prefill_done and not s.long_prefill_active
             for s in self.scheduler.running
         ):
-            return False  # the next round is lane-typed (ragged): the
-            # ragged stage covers it; a pure-decode stage would only
-            # be dropped at the next schedule(). A long-lane runner is
-            # NOT a ragged lane — its ring runs outside the round, so
-            # pure-decode staging stays live under it
+            return False  # the next round carries prefill rows (a
+            # lane-typed round, which the ragged stage covers, or the
+            # split path's prefill round, which comes before any
+            # decode): a pure-decode stage would only be dropped at
+            # the next schedule(). A long-lane runner is NOT such a
+            # lane — its ring runs outside the round, so pure-decode
+            # staging stays live under it
         if any(self._is_guided(s) for s in seqs):
             # the chained dispatch carries no DFA tables; guided lanes
             # resolve each round so their device states re-initialize
@@ -1529,16 +1560,26 @@ class LLMEngine:
             held = self._window_blocks_per_seq
             held[0] += self.block_manager.window_blocks_in_use
             held[1] += self.scheduler.num_running
+        self._tag_step(self._round, kind, k, lanes, rows)
+
+    def _tag_step(self, number: int, kind: str, k: int, lanes: int,
+                  rows: int) -> None:
+        """Inside a profiler session, tag this step's `engine.step`
+        with a round, unless it carries one already."""
         ann = self._step_ann
         if ann is not None and not self._step_tagged:
             self._step_tagged = True
-            ann.set_metadata(round=self._round, kind=kind, k=k,
+            ann.set_metadata(round=number, kind=kind, k=k,
                              lanes=lanes, rows=rows)
 
     # stackcheck: hot-path — a round is dispatched and fetched inside
     # the step that scheduled it; the fetches are the metered
     # `phases.span("fetch")` seams of the round runners below
     def _step_impl(self) -> list[RequestOutput]:
+        if self._inflight is not None:
+            # the round this step would choose was chosen and started
+            # at the last fetch's return
+            return self._finish_inflight()
         with self.phases.span("schedule"):
             if self._kv_restores:
                 # start h2d uploads for restores whose tier fetch landed
@@ -1764,7 +1805,7 @@ class LLMEngine:
                 # everything the pack ships a lane goes there, and
                 # everything that comes back a lane is read there
                 lanes = self.runner.decode_lanes(tables)
-                staged_kw = {}
+                staged = None
                 st = self._staged_decode
                 self._staged_decode = None
                 if st is not None:
@@ -1776,106 +1817,18 @@ class LLMEngine:
                         # the prediction held: dispatch chained on the
                         # previous round's on-device tokens with the
                         # pre-uploaded packed buffer — zero serial h2d
-                        staged_kw = {"staged": st["handle"]}
+                        staged = st["handle"]
                         tokens = st["chain_tokens"]
                         self._staged_hits_total += 1
                     else:
                         self._staged_misses_total += 1
-            # fused on-device decode+sample loop: K tokens per
-            # dispatch, ONE device->host fetch
-            # stop rides a conditional kwarg: the multihost runner
-            # wrapper replays host token lists and knows no stop
-            # masks (and _device_stop is already off there)
-            stop_kw = {"stop": stop} if stop is not None else {}
-            self._begin_round("decode", k_steps, len(seqs), 0)
-            ys = self.runner.decode_multi(
-                tokens, positions, tables, ctx_lens, k_steps,
-                temps, top_ps, top_ks, keys, min_ps=min_ps,
-                lora_slots=[self._lora_slot(s) for s in seqs],
-                penalties=penalties,
-                want_logprobs=want_lp,
-                guided=guided_tables,
-                logit_bias=bias,
-                lanes=lanes,
-                **stop_kw,
-                **staged_kw,
-            )  # (k, b) on device [+ logprob arrays] [+ valid]
-            valid_dev = None
-            if stop is not None:
-                toks_dev = ys[0]
-                valid_dev = ys[-1]
-                lps_dev = ys[1:-1] if want_lp else None
-            else:
-                toks_dev, lps_dev = (
-                    (ys[0], ys[1:]) if want_lp else (ys, None)
-                )
-            if (self._prefetch_decode and penalties is None
-                    and guided_tables is None and bias is None
-                    and self._can_stage(seqs, k_steps)):
-                # upload round N+1's predicted inputs NOW — the
-                # transfer rides out the fetch below; validated by
-                # fingerprint before the next dispatch uses it
-                nk = keys.copy()
-                nk[:, 1] += k_steps
-                # predict the NEXT round's adaptive K; capped at
-                # this round's K because _reserve_next_round only
-                # grew the block tables to cover 2*k positions
-                k_next = min(
-                    self.scheduler.pick_decode_k(
-                        seqs, advance=k_steps),
-                    k_steps,
-                )
-                stage_stop = None
-                if stop is not None:
-                    # the countdowns advance with the k tokens this
-                    # round will apply (a lane that freezes earlier
-                    # breaks the fingerprint, so the stale stage is
-                    # never dispatched)
-                    stage_stop = (
-                        stop[0],
-                        np.maximum(stop[1] - k_steps, 0),
-                        stop[2] - k_steps,
-                        stop[3],
-                    )
-                self._staged_decode = {
-                    "fp": self._stage_fingerprint(
-                        seqs, k_next, advance=k_steps),
-                    "handle": self.runner.stage_decode_multi(
-                        [s.num_tokens - 1 + k_steps for s in seqs],
-                        [s.block_table for s in seqs],
-                        [s.num_tokens + k_steps for s in seqs],
-                        k_next, temps, top_ps, top_ks, nk,
-                        min_ps=min_ps, stop=stage_stop, lanes=lanes,
-                    ),
-                    "lanes": lanes,
-                    "chain_tokens": toks_dev[-1],
-                }
-            # materialize the round's results in one place so the d2h
-            # cost lands in the fetch phase like other fetches
-            with self.phases.span("fetch"):
-                # stackcheck: disable=device-sync-transitive — the ONE
-                # metered multi-token fetch for this decode round
-                toks_np = np.asarray(toks_dev)
-                lps_np = (
-                    # stackcheck: disable=device-sync-transitive —
-                    # logprob arrays exist only when lanes requested
-                    # them; they ride this round's metered fetch with
-                    # the tokens
-                    tuple(np.asarray(a) for a in lps_dev)
-                    if lps_dev else None
-                )
-                valid_np = (
-                    # stackcheck: disable=device-sync-transitive —
-                    # validity mask rides the same metered fetch as the
-                    # tokens it gates
-                    np.asarray(valid_dev)
-                    if valid_dev is not None else None
-                )
-            self._apply_multi_tokens(
-                seqs, toks_np, k_steps, lps=lps_np, valid=valid_np,
-                lanes=lanes,
+            rnd = self._dispatch_decode(
+                seqs, k_steps, tokens, positions, ctx_lens,
+                (temps, top_ps, top_ks, min_ps, keys), stop, lanes,
+                staged=staged, penalties=penalties,
+                guided=guided_tables, bias=bias,
             )
-            stepped.extend(seqs)
+            stepped.extend(self._finish_decode_round(rnd))
         else:
             self._begin_round("decode", 1, len(seqs), 0)
             logits = self.runner.decode(
@@ -1907,6 +1860,254 @@ class LLMEngine:
                 # tpu:decode_k histogram too
                 self._note_decode_round(seqs, 1)
         return stepped
+
+    # -- a fused decode round: dispatch, and what follows its fetch ---------
+    def _dispatch_decode(
+        self, seqs: list[Sequence], k: int, tokens, positions: list[int],
+        ctx_lens: list[int], sampling: tuple, stop: tuple | None,
+        lanes: np.ndarray, *, staged: tuple | None = None,
+        penalties: tuple | None = None, guided: tuple | None = None,
+        bias: tuple | None = None,
+    ) -> dict:
+        """Number and dispatch ONE fused K-step decode round and return
+        its record: what `_finish_decode_round` fetches and applies,
+        and what the round after it is staged from. `sampling` =
+        (temps, top_ps, top_ks, min_ps, keys) and `stop` (the device
+        stop arrays or None) AS THIS ROUND SHIPS THEM. `tokens` is the
+        host list of last tokens, or the device array a staged round
+        chains on."""
+        temps, top_ps, top_ks, min_ps, keys = sampling
+        want_lp = any(
+            s.sampling_params.logprobs is not None for s in seqs
+        )
+        # fused on-device decode+sample loop: K tokens per
+        # dispatch, ONE device->host fetch
+        # stop and staged ride conditional kwargs: the multihost
+        # runner wrapper replays host token lists and knows neither
+        # stop masks nor a staged buffer (both are off there)
+        stop_kw = {"stop": stop} if stop is not None else {}
+        staged_kw = {"staged": staged} if staged is not None else {}
+        self._begin_round("decode", k, len(seqs), 0)
+        ys = self.runner.decode_multi(
+            tokens, positions, [s.block_table for s in seqs], ctx_lens, k,
+            temps, top_ps, top_ks, keys, min_ps=min_ps,
+            lora_slots=[self._lora_slot(s) for s in seqs],
+            penalties=penalties,
+            want_logprobs=want_lp,
+            guided=guided,
+            logit_bias=bias,
+            lanes=lanes,
+            **stop_kw,
+            **staged_kw,
+        )  # (k, b) on device [+ logprob arrays] [+ valid]
+        valid_dev = None
+        if stop is not None:
+            toks_dev = ys[0]
+            valid_dev = ys[-1]
+            lps_dev = ys[1:-1] if want_lp else None
+        else:
+            toks_dev, lps_dev = (
+                (ys[0], ys[1:]) if want_lp else (ys, None)
+            )
+        for out in (toks_dev, valid_dev, *(lps_dev or ())):
+            # the results start for the host when the program ends, not
+            # when the fetch asks: the fetch's tail lies in the gap
+            # before a round that starts at that fetch's return
+            if out is not None:
+                out.copy_to_host_async()
+        return {
+            "seqs": seqs, "k": k, "round": self._round, "lanes": lanes,
+            "sampling": sampling, "stop": stop,
+            "toks": toks_dev, "lps": lps_dev, "valid": valid_dev,
+            # per-round host state (penalty counts, DFA tables, bias)
+            # does not chain: such a round stages no successor
+            "chains": penalties is None and guided is None and bias is None,
+            # sequences aborted while this round was in flight across
+            # two step() calls: freed at its fetch (`abort_request`)
+            "aborted": [],
+        }
+
+    def _stage_next_decode(self, rnd: dict) -> None:
+        """Upload the PREDICTED inputs of the round after `rnd` now —
+        the transfer rides out `rnd`'s fetch; validated by fingerprint
+        before any dispatch uses it. `rnd` is dispatched and its tokens
+        are not applied: every lane is taken to advance by its K."""
+        seqs, k_steps, lanes = rnd["seqs"], rnd["k"], rnd["lanes"]
+        temps, top_ps, top_ks, min_ps, keys = rnd["sampling"]
+        stop = rnd["stop"]
+        nk = keys.copy()
+        nk[:, 1] += k_steps
+        # predict the NEXT round's adaptive K; capped at
+        # this round's K because _reserve_next_round only
+        # grew the block tables to cover 2*k positions
+        k_next = min(
+            self.scheduler.pick_decode_k(seqs, advance=k_steps),
+            k_steps,
+        )
+        stage_stop = None
+        if stop is not None:
+            # the countdowns advance with the k tokens this
+            # round will apply (a lane that freezes earlier
+            # breaks the fingerprint, so the stale stage is
+            # never dispatched)
+            stage_stop = (
+                stop[0],
+                np.maximum(stop[1] - k_steps, 0),
+                stop[2] - k_steps,
+                stop[3],
+            )
+        positions = [s.num_tokens - 1 + k_steps for s in seqs]
+        ctx_lens = [s.num_tokens + k_steps for s in seqs]
+        self._staged_decode = {
+            "fp": self._stage_fingerprint(seqs, k_next, advance=k_steps),
+            "handle": self.runner.stage_decode_multi(
+                positions, [s.block_table for s in seqs], ctx_lens,
+                k_next, temps, top_ps, top_ks, nk,
+                min_ps=min_ps, stop=stage_stop, lanes=lanes,
+            ),
+            "lanes": lanes,
+            "chain_tokens": rnd["toks"][-1],
+            # what the stage was packed from: a round that starts at
+            # the fetch's return is dispatched with exactly these
+            "k": k_next, "positions": positions, "ctx_lens": ctx_lens,
+            "sampling": (temps, top_ps, top_ks, min_ps, nk),
+            "stop": stage_stop,
+        }
+
+    def _starts_at_fetch(
+        self, rnd: dict, st: dict, toks: np.ndarray,
+        valid: np.ndarray | None,
+    ) -> bool:
+        """Decided on round `rnd`'s FETCHED arrays, before they are
+        applied: will the staged round `st` be the one the next step()
+        would dispatch? Then it can start now. The stage itself was
+        made a fetch ago under this hold of the caller's lock, so what
+        `_can_stage` refused (a mesh, multihost, penalties, logit bias,
+        guided lanes, a waiting request, a prefill lane, a lane at its
+        bounds) never gets here; a KV restore belongs to a waiting
+        request, and deferred KV exports are of blocks that stay
+        pinned until their snapshot is enqueued, whichever side of a
+        dispatch that falls. What is left to observe:"""
+        seqs, k = rnd["seqs"], rnd["k"]
+        if self.callers_waiting:
+            return False  # an arrival or an abort stands at the lock:
+            # it gets in at this step's end, before the next round is
+            # chosen, as it always did
+        if self._spec_enabled or (
+            self.long_prefill is not None and self.long_prefill.active
+        ):
+            return False  # the next step() may choose a verify round,
+            # and advances the long lane's ring by one enqueue: both
+            # happen in schedule order, which a round in flight skips
+        if any(s.sampling_params.stop for s in seqs):
+            return False  # stop strings: a rule only the host
+            # evaluates, after rendering, may end a lane in this round
+            # (the other such rule, a guided lane, stages nothing)
+        if st["fp"] != self._stage_fingerprint(seqs, st["k"], advance=k):
+            return False  # a free since the stage (the epoch)
+        lanes = rnd["lanes"]
+        if valid is not None and (valid[lanes] != k).any():
+            return False  # a lane froze on the device: it ended
+        # and no token of the round ends its sequence at the last step
+        # (or anywhere, without device stops). An EOS under min_tokens
+        # would not: refusing it too costs one early start
+        eos, _, _, stop_ids = rnd["stop"] or self._stop_arrays(seqs)
+        mine = toks[:, lanes]
+        if (mine == eos).any():
+            return False
+        return stop_ids is None or not (
+            mine[:, :, None] == stop_ids[None]).any()
+
+    def _finish_decode_round(self, rnd: dict) -> list[Sequence]:
+        """What follows a fused decode round's dispatch, in the step
+        that dispatched it or (a round that started at the fetch before
+        it, `_inflight`) in the next: stage its successor, fetch it,
+        START THE SUCCESSOR where the fetched arrays show the stage
+        holds, apply it. Returns the stepped sequences."""
+        seqs, k_steps = rnd["seqs"], rnd["k"]
+        if (self._prefetch_decode and rnd["chains"]
+                and not rnd["aborted"] and self._can_stage(seqs, k_steps)):
+            self._stage_next_decode(rnd)
+        # materialize the round's results in one place so the d2h
+        # cost lands in the fetch phase like other fetches
+        with self.phases.span("fetch"):
+            # stackcheck: disable=device-sync-transitive — the ONE
+            # metered multi-token fetch for this decode round
+            toks_np = np.asarray(rnd["toks"])
+            lps_np = (
+                # stackcheck: disable=device-sync-transitive —
+                # logprob arrays exist only when lanes requested
+                # them; they ride this round's metered fetch with
+                # the tokens
+                tuple(np.asarray(a) for a in rnd["lps"])
+                if rnd["lps"] else None
+            )
+            valid_np = (
+                # stackcheck: disable=device-sync-transitive —
+                # validity mask rides the same metered fetch as the
+                # tokens it gates
+                np.asarray(rnd["valid"])
+                if rnd["valid"] is not None else None
+            )
+        st = self._staged_decode
+        if st is not None and self._starts_at_fetch(
+                rnd, st, toks_np, valid_np):
+            # the device has every input of the next round: it starts
+            # now, and this round's tokens are applied under it
+            self._staged_decode = None
+            self._staged_hits_total += 1
+            self._early_dispatch_total += 1
+            self._inflight = self._dispatch_decode(
+                seqs, st["k"], st["chain_tokens"], st["positions"],
+                st["ctx_lens"], st["sampling"], st["stop"], st["lanes"],
+                staged=st["handle"],
+            )
+        self._apply_multi_tokens(
+            seqs, toks_np, k_steps, lps=lps_np, valid=valid_np,
+            round_attrs={"engine_round": rnd["round"]},
+            lanes=rnd["lanes"],
+        )
+        return seqs
+
+    def _finish_inflight(self) -> list[RequestOutput]:
+        """The step of a round that was dispatched at the fetch before
+        it: nothing to schedule, pack or dispatch; its fetch, and what
+        `_finish_decode_round` hangs on a fetch."""
+        rnd, self._inflight = self._inflight, None
+        # the step carries the number of the round it fetches, not of
+        # one it may start at that fetch
+        self._tag_step(rnd["round"], "decode", rnd["k"], len(rnd["seqs"]), 0)
+        self.last_step_kind = "decode"
+        for seq in rnd["seqs"]:
+            # what the schedule this round did without does for a lane
+            # it has chosen: a second cache group lets go of what lies
+            # behind the window of the lane's first query of THIS round
+            # (no-op with one pool, and for a lane aborted under it)
+            self.block_manager.release_behind(
+                seq.block_table, seq.num_computed_tokens)
+        try:
+            stepped = self._finish_decode_round(rnd)
+        finally:
+            # the round is off the device: what was aborted under it
+            # lets its blocks and its state slot go
+            for seq in rnd["aborted"]:
+                self.scheduler.free_finished(seq)
+        gone = {id(s) for s in rnd["aborted"]}
+        return self._finalize_stepped(
+            [s for s in stepped if id(s) not in gone])
+
+    def drain_round(self) -> list[RequestOutput]:
+        """Fetch and apply the round in flight, if there is one, and
+        dispatch nothing: before a sleep, a shutdown, or the abort of
+        everything after a failed step."""
+        if self._inflight is None:
+            return []
+        # no stage behind the round, so nothing to start at its fetch
+        staging, self._prefetch_decode = self._prefetch_decode, False
+        try:
+            return self._finish_inflight()
+        finally:
+            self._prefetch_decode = staging
 
     # -- unified ragged prefill+decode rounds -------------------------------
     def _penalty_args(self, seqs: list[Sequence]) -> tuple:
@@ -3676,6 +3877,7 @@ class LLMEngine:
             return 0
 
     def shutdown(self) -> None:
+        self.drain_round()
         if hasattr(self.runner, "shutdown_followers"):
             self.runner.shutdown_followers()
         if self.long_prefill is not None:
@@ -3807,6 +4009,7 @@ class LLMEngine:
                 if self.long_prefill is not None else 0.0
             ),
             decode_rounds_total=self._decode_rounds_total,
+            decode_early_dispatch_total=self._early_dispatch_total,
             decode_overshoot_tokens_total=(
                 self._decode_overshoot_tokens_total
             ),

@@ -468,6 +468,14 @@ class EngineMetrics:
             "tpu:decode_rounds", "Decode rounds dispatched",
             label, registry=reg,
         )
+        self.decode_early_dispatch = Counter(
+            "tpu:decode_early_dispatch",
+            "Decode rounds whose program was dispatched when the fetch "
+            "of the round before them returned, before that round's "
+            "tokens were applied (over tpu:decode_rounds: the share of "
+            "rounds between which the device waited for one dispatch)",
+            label, registry=reg,
+        )
         self.decode_lane_steps = Counter(
             "tpu:decode_lane_steps",
             "Lanes x fused steps of every dispatched round's decode "
@@ -718,6 +726,9 @@ class EngineMetrics:
             - prev.long_prefill_overflow_seconds_total))
         self.decode_rounds.labels(m).inc(max(
             0, s.decode_rounds_total - prev.decode_rounds_total))
+        self.decode_early_dispatch.labels(m).inc(max(
+            0, s.decode_early_dispatch_total
+            - prev.decode_early_dispatch_total))
         for counter, now, was in zip(
                 (self.decode_lane_steps, self.decode_idle_lane_steps,
                  self.sampler_steps, self.sampler_window_steps,

@@ -155,9 +155,11 @@ class EngineStatsSnapshot:
     long_prefill_overflow_seconds_total: float = 0.0
     # elastic fused decode: rounds dispatched, sampled-then-discarded
     # overshoot tokens (~0 with device stops, except host-resolved stop
-    # strings), and whole-round device early exits — tpu:decode_* in
-    # /metrics
+    # strings), whole-round device early exits, and rounds dispatched
+    # at the fetch's return, before the round before them was applied
+    # — tpu:decode_* in /metrics
     decode_rounds_total: int = 0
+    decode_early_dispatch_total: int = 0
     decode_overshoot_tokens_total: int = 0
     decode_early_exit_rounds_total: int = 0
     # unified ragged dispatch: fused lane-typed rounds, rounds a mixed

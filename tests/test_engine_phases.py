@@ -286,10 +286,14 @@ def test_attn_context_tokens_equals_the_hand_count_and_phases_count():
     dispatch0 = e.phases.counts()["dispatch"]
     e.step()                    # two lanes, K=4: contexts 11.. and 7..
     assert e.last_step_kind == "decode"
-    hand = sum(11 + i for i in range(4)) + sum(7 + i for i in range(4))
+    # the step carries TWO dispatched rounds: the one it fetched, and
+    # its staged successor (contexts 15.. and 11..), which started at
+    # that fetch's return and is in flight when the step returns
+    assert e._inflight is not None and e._early_dispatch_total == 1
+    hand = sum(11 + i for i in range(8)) + sum(7 + i for i in range(8))
     assert e.runner.attn_context_tokens[0] - tokens0 == hand
-    assert e.runner.attn_context_tokens[1] - rounds0 == 1
-    assert e.phases.counts()["dispatch"] - dispatch0 == 1
+    assert e.runner.attn_context_tokens[1] - rounds0 == 2
+    assert e.phases.counts()["dispatch"] - dispatch0 == 2
     snap = e.stats()
     assert snap.attn_context_tokens == tuple(e.runner.attn_context_tokens)
     assert set(snap.engine_phases) == set(ENGINE_PHASES)
@@ -312,16 +316,18 @@ def test_decode_lane_steps_count_the_lanes_that_hold_no_sequence():
     e.step()
     assert e.last_step_kind == "prefill"
     assert e.runner.decode_lane_steps == [0, 0]
-    e.step()                    # two live lanes, K=4
+    e.step()                    # two live lanes, K=4, and the round
+    # after it, dispatched at this one's fetch: two rounds' worth
     assert e.last_step_kind == "decode"
-    assert e.runner.decode_lane_steps == [4 * lanes, 4 * (lanes - 2)]
-    assert e.stats().decode_lane_steps == (4 * lanes, 4 * (lanes - 2))
+    assert e.stats().decode_early_dispatch_total == 1
+    assert e.runner.decode_lane_steps == [8 * lanes, 8 * (lanes - 2)]
+    assert e.stats().decode_lane_steps == (8 * lanes, 8 * (lanes - 2))
     reg = CollectorRegistry()
     metrics = EngineMetrics("m", registry=reg)
     metrics.update_from_snapshot(e.stats())
     text = generate_latest(reg).decode()
-    for name, value in (("decode_lane_steps", 4.0 * lanes),
-                        ("decode_idle_lane_steps", 4.0 * (lanes - 2))):
+    for name, value in (("decode_lane_steps", 8.0 * lanes),
+                        ("decode_idle_lane_steps", 8.0 * (lanes - 2))):
         assert f'tpu:{name}_total{{model_name="m"}} {value}' in text, name
 
 
@@ -338,15 +344,15 @@ def test_sampler_steps_reach_metrics(temperature):
     e.step()
     assert e.last_step_kind == "prefill"
     assert e.runner.sampler_steps == [1, 1 if temperature else 0]
-    e.step()                    # K=4
+    e.step()                    # K=4, and the round started at its fetch
     assert e.last_step_kind == "decode"
-    window = 5 if temperature else 0
-    assert e.stats().sampler_steps == (5, window)
+    window = 9 if temperature else 0
+    assert e.stats().sampler_steps == (9, window)
     reg = CollectorRegistry()
     metrics = EngineMetrics("m", registry=reg)
     metrics.update_from_snapshot(e.stats())
     text = generate_latest(reg).decode()
-    for name, value in (("sampler_steps", 5.0),
+    for name, value in (("sampler_steps", 9.0),
                         ("sampler_window_steps", float(window))):
         assert f'tpu:{name}_total{{model_name="m"}} {value}' in text, name
 
@@ -623,8 +629,16 @@ def test_every_content_chunk_is_a_token_delivery_and_a_send(writer):
         t0 = time.perf_counter()
         chunks = await stream_chunks(client, path, **extra)
         took = time.perf_counter() - t0
-        after = await scrape(client)
-        d = {k: after[k] - before.get(k, 0.0) for k in after}
+        for _ in range(40):
+            after = await scrape(client)
+            d = {k: after[k] - before.get(k, 0.0) for k in after}
+            # the step thread closes its `deliver` span AFTER it has
+            # queued the callback: this loop may have run the callback
+            # and the scrape before that thread ran again
+            if (d["tpu:deliver_pickup_seconds_count"]
+                    <= d["tpu:engine_phase_deliver_seconds_count"]):
+                break
+            await asyncio.sleep(0.025)
         if writer == "multi-choice":
             assert {c["choices"][0]["index"] for c in chunks} == {0, 1}
         assert len(chunks) >= 2

@@ -190,16 +190,22 @@ def test_a_staged_decode_round_over_placed_lanes_is_a_hit(monkeypatch):
     # a stage laid out over other lanes than the round's is no hit:
     # the next round packs and uploads for itself
     e.add_request("late", prompt_token_ids=prompts[0], sampling_params=sp)
+    e.callers_waiting = 1  # somebody at the lock: the staged round
+    # waits for the next step and does not start at this one's fetch
+    early = e._early_dispatch_total
+    assert early >= 4
     for _ in range(20):
         e.step()
         if e._staged_decode is not None:
             break
-    assert e._staged_decode is not None
+    assert e._staged_decode is not None and e._inflight is None
+    assert e._early_dispatch_total == early
     hits, misses = e._staged_hits_total, e._staged_misses_total
     e._staged_decode["lanes"] = e._staged_decode["lanes"] + 1
     e.step()
     assert (e._staged_hits_total, e._staged_misses_total) == (
         hits, misses + 1)
+    e.callers_waiting = 0
     while e.has_unfinished():
         e.step()
 

@@ -104,12 +104,17 @@ def test_rejects_k_above_block_size():
 
 
 def test_tp_multistep_parity():
-    """Multi-step under tensor parallelism matches tp=1."""
-    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
-    base = [o.token_ids for o in _engine(4).generate(PROMPTS[:2], sp)]
-    tp = [o.token_ids for o in
-          _engine(4, tensor_parallel_size=2).generate(PROMPTS[:2], sp)]
+    """Multi-step under tensor parallelism matches tp=1. Under a mesh
+    nothing is staged, so no round starts at a fetch either: what
+    refuses a stage refuses the early start, with no list of its own
+    (tests/test_early_dispatch.py)."""
+    sp = SamplingParams(max_tokens=14, temperature=0.0, ignore_eos=True)
+    one, mesh = _engine(4), _engine(4, tensor_parallel_size=2)
+    base = [o.token_ids for o in one.generate(PROMPTS[:2], sp)]
+    tp = [o.token_ids for o in mesh.generate(PROMPTS[:2], sp)]
     assert tp == base
+    assert one._early_dispatch_total >= 2
+    assert (mesh._staged_hits_total, mesh._early_dispatch_total) == (0, 0)
 
 
 def test_streaming_deltas_cover_all_tokens():
@@ -196,7 +201,10 @@ def test_prefetch_survives_mid_stream_admission(device_stop):
                     outs[o.request_id] = o.token_ids
             steps += 1
             if steps == 3:  # mid-decode admission breaks the lane set
-                assert (e._staged_decode is not None) == e._prefetch_decode
+                # the stage of the next round is outstanding: uploaded,
+                # or dispatched already at this step's fetch
+                assert (e._staged_decode is not None
+                        or e._inflight is not None) == e._prefetch_decode
                 hits = e._staged_hits_total
                 e.add_request("b", prompt_token_ids=list(range(30, 45)),
                               sampling_params=sp)
@@ -219,13 +227,17 @@ def test_stage_invalidated_by_block_free_epoch():
     sp = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
     eng.add_request("a", prompt_token_ids=list(range(1, 12)),
                     sampling_params=sp)
+    stage = eng.runner.stage_decode_multi
+
+    def stage_then_free(*a, **kw):
+        # simulate a table free (abort/preempt of some other sequence)
+        # after the stage took its fingerprint
+        eng.block_manager.free_epoch += 1
+        return stage(*a, **kw)
+
+    eng.runner.stage_decode_multi = stage_then_free
     outs = []
     while eng.has_unfinished():
-        before = eng._staged_decode is not None
-        if before:
-            # simulate a concurrent table free (abort/preempt of some
-            # other sequence) between rounds
-            eng.block_manager.free_epoch += 1
         for o in eng.step():
             if o.finished:
                 outs.append(o.token_ids)

@@ -280,7 +280,9 @@ def test_lora_lanes_ride_ragged_rounds():
 
         sp = SamplingParams(max_tokens=8, temperature=0.0,
                             ignore_eos=True)
-        arrivals = [(0, "a", SHORT), (2, "b", LONG)]
+        # "b" comes while "a" has rounds left (a round that starts at
+        # its predecessor's fetch is not joined by a later arrival)
+        arrivals = [(0, "a", SHORT), (1, "b", LONG)]
 
         def run(ragged):
             e = eng(ragged)
@@ -690,7 +692,7 @@ def test_compile_events_counted_and_in_stats():
     events_total), and distinguishes kernel-mode builder kinds."""
     sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
     e = _engine(True, attention_impl="pallas")
-    _run_staggered(e, [(0, "a", SHORT), (2, "b", LONG)], sp)
+    _run_staggered(e, [(0, "a", SHORT), (1, "b", LONG)], sp)
     assert e.runner.compile_events_total > 0
     assert "ragged_rows" in e.runner.compile_events
     s = e.stats()

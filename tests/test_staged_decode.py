@@ -58,9 +58,12 @@ def _count_dispatches(eng):
 
 
 def _step_until_staged(eng) -> None:
+    """Step until a stage is outstanding: uploaded and waiting for the
+    next step, or (where its prediction held at the fetch's return)
+    dispatched already and in flight (tests/test_early_dispatch.py)."""
     for _ in range(20):
         eng.step()
-        if eng._staged_decode is not None:
+        if eng._staged_decode is not None or eng._inflight is not None:
             return
     raise AssertionError("no round was staged in 20 steps")
 
@@ -202,8 +205,11 @@ def test_abort_all_with_a_stage_outstanding_drains():
                     sampling_params=sp)
     _step_until_staged(eng)
     eng.abort_request("only")
+    # a round that holds the aborted lane may be on the device: it is
+    # fetched (and its tokens dropped) before anything else happens
+    assert eng.has_unfinished() == (eng._inflight is not None)
+    assert [o.request_id for o in eng.step()] == []
     assert not eng.has_unfinished()
-    assert [o.request_id for o in eng.step() if o.finished] == []
     hits = eng._staged_hits_total
     eng.add_request("next", prompt_token_ids=prompts[2],
                     sampling_params=sp)
@@ -225,3 +231,7 @@ def test_staging_respects_max_model_len():
     assert out_on == run(make_engine(False, max_model_len=48), prompts, sp)
     assert len(out_on[0]) == 48 - len(prompts[0])
     assert eng._staged_hits_total >= 1
+    # and no round starts at a fetch past it either: the early starts
+    # are the stages, and none is left in flight at the limit
+    assert 1 <= eng._early_dispatch_total <= eng._staged_hits_total
+    assert eng._inflight is None
