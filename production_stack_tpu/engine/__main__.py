@@ -56,8 +56,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "wait (0 = prefill always wins)")
     p.add_argument("--num-scheduler-steps", type=int, default=1,
                    help="fused decode+sample iterations per dispatch "
-                        "(on-device sampling; amortises host RTT); the "
-                        "CAP under --adaptive-decode-k")
+                        "(on-device sampling; amortises host RTT); "
+                        "every round has this size")
     p.add_argument("--device-stop", action="store_true", default=True,
                    help="evaluate EOS/stop-token/max-token stops INSIDE "
                         "the fused decode scan: finished lanes freeze "
@@ -67,16 +67,11 @@ def build_parser() -> argparse.ArgumentParser:
                    action="store_false",
                    help="fixed-trip fused scan; overshoot discarded on "
                         "the host (the tests' reference)")
-    p.add_argument("--adaptive-decode-k", action="store_true",
-                   default=True,
-                   help="size each fused round from pow2 buckets up to "
-                        "--num-scheduler-steps: clamped low while "
-                        "prefill work waits, bounded by the batch's "
-                        "remaining-token budget")
-    p.add_argument("--no-adaptive-decode-k", dest="adaptive_decode_k",
-                   action="store_false",
-                   help="every round dispatches the full "
-                        "--num-scheduler-steps (fixed K)")
+    p.add_argument("--no-adaptive-decode-k", action="store_true",
+                   help="parsed and ignored: a fused round is always "
+                        "--num-scheduler-steps long. Kept only while "
+                        "the benchmark's configurations pass it "
+                        "(ROADMAP Queue 3)")
     p.add_argument("--num-speculative-tokens", type=int, default=0,
                    help="ngram prompt-lookup speculative decoding: "
                         "draft up to this many tokens and verify them "
@@ -240,7 +235,6 @@ def config_from_args(args: argparse.Namespace) -> EngineConfig:
         decode_interleave=args.decode_interleave,
         num_scheduler_steps=args.num_scheduler_steps,
         device_stop=args.device_stop,
-        adaptive_decode_k=args.adaptive_decode_k,
         precompile_serving=args.precompile_serving,
         prefetch_decode=args.prefetch_decode,
         prefill_pipeline=args.prefill_pipeline,

@@ -276,11 +276,6 @@ class AsyncLLMEngine:
         the step/worker threads' appends."""
         return self.engine.drain_kv_observations()
 
-    def drain_decode_k_observations(self) -> list[int]:
-        """Chosen-K observations (tpu:decode_k) since the last drain.
-        Lock-free: same GIL-atomic deque contract as the KV drain."""
-        return self.engine.drain_decode_k_observations()
-
     def drain_ragged_observations(self) -> list[int]:
         """Ragged lane-mix observations (tpu:ragged_lane_mix) since the
         last drain. Lock-free: same GIL-atomic deque contract."""

@@ -46,10 +46,12 @@ class EngineConfig:
     # sampling (incl. presence/frequency/repetition penalties, whose
     # token counts ride on device through the scan) runs on device and K
     # tokens come back in ONE host fetch, amortising the dispatch/fetch
-    # RTT. Must be <= block_size. With adaptive_decode_k this is the
-    # CAP: the scheduler sizes each round from pow2 buckets up to it.
+    # RTT. Must be <= block_size. Every round has this one size (one
+    # decode program and one lane-typed program a context bucket): a
+    # lane out of budget freezes and a round whose lanes all ended
+    # exits early (device_stop), so a short tail costs what it runs.
     num_scheduler_steps: int = 1
-    # elastic fused decode, part 1 — device-side stop masks: EOS, the
+    # device-side stop masks: EOS, the
     # request's stop_token_ids, and a remaining-max_tokens countdown
     # are evaluated INSIDE the fused K-step scan. A lane that finishes
     # mid-round freezes (sampled slot pinned to the pad token, KV-slot
@@ -65,35 +67,29 @@ class EngineConfig:
     # Multihost engines ignore this (the broadcast wire ships host
     # token lists, not stop matrices).
     device_stop: bool = True
-    # elastic fused decode, part 2 — admission-aware adaptive K: the
-    # scheduler picks each round's K from pow2 buckets (precompiled by
-    # --precompile-serving) instead of always dispatching the full
-    # num_scheduler_steps. A queued/cold prefill clamps K low so a
-    # long fused round never starves admission, and the batch's max
-    # remaining-token budget bounds K so the last rounds of short
-    # answers stop dispatching full-K programs (the K=32 waste mode).
-    # False (--no-adaptive-decode-k) keeps the fixed-K behavior.
-    adaptive_decode_k: bool = True
     # speculative h2d prefetch: while a fused decode round executes,
     # upload the NEXT round's packed host inputs (positions/ctx/keys
     # advanced by K on the same lanes) and dispatch it chained on the
     # on-device sampled tokens when the prediction holds. Removes the
-    # serial host->device transfer (cost on an attached chip: not
-    # measured) from the steady-state round critical path with fully
-    # synchronous admission (at most ONE round is in flight).
-    # Requires num_scheduler_steps > 1; single-device; off multihost.
+    # pack and the host->device transfer from the steady-state round's
+    # critical path, and lets the staged round start when the fetch
+    # returns, before the fetched tokens are applied
+    # (LLMEngine._starts_at_fetch): on one v5e 59-61% of the dense
+    # chat cells' decode rounds start so, and laguna's tpot_mean_ms
+    # went 5.03 -> 4.77 (ledger, PR 48). At most ONE round is in
+    # flight. Requires num_scheduler_steps > 1; single-device; off
+    # multihost.
     prefetch_decode: bool = True
     # pipelined prefill: (1) every prefill dispatch ships ONE packed i32
     # host->device buffer (tokens/positions/write slots/tables/sampling
     # args fused, mirroring the decode pack) instead of ~8 small
     # transfers (cost of either on an attached chip: not measured);
-    # (2) while chunk N computes on device, chunk N+1's buffer is built
-    # and uploaded so the h2d overlaps compute; (3) cold multi-chunk
-    # prompts chain their chunks back-to-back without a host round-trip
-    # in between (only the final chunk's sampled token is fetched), and
-    # a staged-and-ready chunk is admitted as zero-cost by the
-    # scheduler's decode interleave. Outputs are bit-identical to the
-    # serial path (tests/test_prefill_pipeline.py). False = the
+    # (2) cold multi-chunk prompts chain their chunks back-to-back
+    # without a host round-trip in between while nothing is
+    # decode-ready or waiting (only the final chunk's sampled token is
+    # fetched; chunk N+1's buffer is built and uploaded while chunk N
+    # computes). Outputs are bit-identical to the serial path
+    # (tests/test_prefill_pipeline.py). False = the
     # per-array upload path (--no-prefill-pipeline): what a multihost
     # engine's staging takes, and the tests' reference.
     prefill_pipeline: bool = True
@@ -103,11 +99,11 @@ class EngineConfig:
     # (scheduler.plan_ragged_round) and the engine dispatches ONE
     # device program (model_runner.ragged_dispatch) whose packed h2d
     # buffer carries prefill-chunk lanes and fused decode lanes
-    # together — the prefill/decode interleave throttle and the
-    # admission-K clamp for in-round prefill work dissolve, a waiting
-    # prompt's chunk runs in the very next round, and the decode half
-    # keeps the device stop masks + staged h2d prefetch. Tokens are
-    # bit-identical to the split path (tests/test_ragged_dispatch.py).
+    # together — the prefill/decode interleave throttle dissolves, a
+    # waiting prompt's chunk runs in the very next round, and the
+    # decode half keeps the device stop masks + staged h2d prefetch.
+    # Tokens are bit-identical to the split path
+    # (tests/test_ragged_dispatch.py).
     # False (--no-ragged-dispatch) keeps the split alternating rounds:
     # the path multihost and meshed (tp/pp) engines always take, and
     # the tests' reference.

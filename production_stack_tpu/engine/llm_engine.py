@@ -34,7 +34,7 @@ from production_stack_tpu.engine.scheduler import (
     PrefillWork,
     Scheduler,
     SchedulerConfig,
-    decode_precompile_variants,
+    decode_precompile_variant,
 )
 from production_stack_tpu.engine.sequence import (
     PromptIds,
@@ -119,11 +119,6 @@ class LLMEngine:
                 scheduling_policy=config.scheduling_policy,
                 decode_interleave=config.decode_interleave,
                 decode_lookahead=max(0, config.num_scheduler_steps - 1),
-                decode_k_cap=config.num_scheduler_steps,
-                adaptive_decode_k=(
-                    config.adaptive_decode_k
-                    and config.num_scheduler_steps > 1
-                ),
             ),
             self.block_manager,
         )
@@ -195,21 +190,19 @@ class LLMEngine:
             and config.num_scheduler_steps > 1
             and not config.multihost
         )
-        # elastic decode accounting: chosen-K histogram observations
-        # (drained by the server's stats loop into tpu:decode_k),
-        # host-discarded overshoot tokens (~0 under device stops except
-        # for host-resolved stop STRINGS), and whole-round early exits
+        # decode round accounting: rounds, host-discarded overshoot
+        # tokens (~0 under device stops except for host-resolved stop
+        # STRINGS), and whole-round early exits
         self._decode_rounds_total = 0
-        self._decode_k_hist: dict[int, int] = {}
         self._decode_overshoot_tokens_total = 0
         self._decode_early_exit_rounds_total = 0
         # speculative h2d prefetch (stage_decode_multi): upload the NEXT
         # fused round's packed host inputs while the current round is
         # still executing, then dispatch it chained on the on-device
-        # tokens — the ~116 ms serial h2d leaves the round's critical
-        # path while admission behavior stays fully synchronous (one
-        # round in flight). Multihost is out: the broadcast wire ships
-        # host token lists, not device arrays.
+        # tokens — the pack and its h2d leave the round's critical
+        # path, and a staged round can start at the fetch's return
+        # (`_starts_at_fetch`). Multihost is out: the broadcast wire
+        # ships host token lists, not device arrays.
         self._prefetch_decode = (
             config.prefetch_decode
             and config.num_scheduler_steps > 1
@@ -231,20 +224,17 @@ class LLMEngine:
         # the next round is chosen, so a round never starts early
         # past one of them
         self.callers_waiting = 0
-        # pipelined prefill (RTT-amortisation extended to the prefill
-        # path): chunk N+1's packed h2d buffer uploads while chunk N
-        # computes, cold multi-chunk prompts chain their chunks
-        # back-to-back in one engine round when nothing is decode-ready,
-        # and a staged-and-ready chunk is admitted as zero cost by the
-        # scheduler's interleave. Multihost is out for the staging part
-        # (the broadcast wire ships host argument lists, not device
-        # buffers) — the fused-buffer dispatch itself works everywhere.
+        # pipelined prefill: a dispatch ships ONE packed buffer, and
+        # cold multi-chunk prompts chain their chunks back-to-back in
+        # one engine round when nothing is decode-ready or waiting
+        # (`_chain_next_prefill`: each chunk's upload overlaps the
+        # chunk before it on the device). Multihost is out for the
+        # chaining and the ragged stage (the broadcast wire ships host
+        # argument lists, not device buffers) — the fused-buffer
+        # dispatch itself works everywhere.
         self._prefill_pipeline = (
             config.prefill_pipeline and not config.multihost
         )
-        self._staged_prefill: dict | None = None
-        self._pf_staged_hits_total = 0
-        self._pf_staged_misses_total = 0
         self._pf_chained_chunks_total = 0
         # unified ragged prefill+decode dispatch: mixed rounds run as
         # ONE lane-typed device program (model_runner.ragged_dispatch);
@@ -261,7 +251,7 @@ class LLMEngine:
         )
         self.scheduler.config.ragged_dispatch = self._ragged_dispatch
         # staged NEXT ragged round (h2d prefetch): fingerprint-validated
-        # like _staged_decode/_staged_prefill; a lane-mix change between
+        # like _staged_decode; a lane-mix change between
         # stage and dispatch is a counted miss, never a dispatch error
         self._staged_ragged: dict | None = None
         self._ragged_staged_hits_total = 0
@@ -384,9 +374,6 @@ class LLMEngine:
 
         self._kv_export_obs: _deque = _deque(maxlen=1024)
         self._kv_restore_obs: _deque = _deque(maxlen=1024)
-        # chosen-K per decode round, drained into the tpu:decode_k
-        # histogram by the server's stats loop (appends/pops GIL-atomic)
-        self._decode_k_obs: _deque = _deque(maxlen=4096)
         # prefill-lane count per fused ragged round, drained into the
         # tpu:ragged_lane_mix histogram (appends/pops GIL-atomic)
         self._ragged_obs: _deque = _deque(maxlen=4096)
@@ -1389,7 +1376,7 @@ class LLMEngine:
         return self._reserve_next_round(seqs, k)
 
     def _stage_fingerprint(
-        self, seqs: list[Sequence], k: int, advance: int = 0
+        self, seqs: list[Sequence], advance: int = 0
     ) -> tuple:
         """State the staged buffer was built for, as observed at the
         NEXT dispatch: same lanes in the same order, every lane exactly
@@ -1397,9 +1384,8 @@ class LLMEngine:
         stage's growth, and NO free() anywhere in between (the free
         epoch) — freed block ids can be re-handed to another sequence,
         making a same-length table reference someone else's KV. At
-        stage time `advance` is the CURRENT round's K (its tokens are
-        not yet applied) while `k` is the STAGED round's predicted K —
-        under adaptive K the two can differ.
+        stage time `advance` is the round's K (the tokens of the round
+        on the device are not yet applied).
 
         The lanes need no entry of their own: a sequence's lane
         (`ModelRunner.decode_lanes`) is a function of the first pages
@@ -1414,7 +1400,6 @@ class LLMEngine:
             tuple(s.num_tokens + advance for s in seqs),
             tuple(len(s.block_table) for s in seqs),
             self.block_manager.free_epoch,
-            k,
         )
 
     # stackcheck: not-hot — host-side token bookkeeping over numpy
@@ -1497,16 +1482,13 @@ class LLMEngine:
         self, seqs: list[Sequence], k: int,
         extra_attrs: dict | None = None,
     ) -> None:
-        """Per-round elastic-decode accounting — the ONE copy shared
-        by the fused path (_apply_multi_tokens) and the single-step
-        branch (adaptive K sizes rounds down to 1): tpu:decode_rounds /
-        tpu:decode_k chosen-K histogram, and one SAMPLED timeline tick
-        per request per round (tracing.DECODE_EVENT_EVERY), not per
-        token — the elastic k_chosen/lanes_done fields ride the same
+        """Per-round decode accounting — the ONE copy shared by the
+        fused path (_apply_multi_tokens) and the host-sampled
+        single-step branch: tpu:decode_rounds, and one SAMPLED timeline
+        tick per request per round (tracing.DECODE_EVENT_EVERY), not
+        per token — the k_chosen/lanes_done fields ride the same
         append-only event."""
         self._decode_rounds_total += 1
-        self._decode_k_hist[k] = self._decode_k_hist.get(k, 0) + 1
-        self._decode_k_obs.append(k)
         if self._tl_enabled:
             lanes_done = sum(1 for s in seqs if s.finished)
             # lane-mix attribution: a split-path decode round carries
@@ -1624,20 +1606,6 @@ class LLMEngine:
             for seq in sched_out.preempted:
                 if not seq.long_prefill_active:
                     self.long_prefill.cancel(seq.request_id)
-        if sched_out.preempted:
-            # same rule for the staged PREFILL buffer: preemption frees
-            # tables that can be re-handed. (Admission ABORTS don't
-            # invalidate — rejected prompts never held tables, and
-            # aborts of running requests bump free_epoch, which the
-            # fingerprint already catches.) If this very schedule()
-            # admitted a prefill as a zero-cost bypass, that dispatch
-            # now pays the full serial h2d — convert the bypass back
-            # into a charged one so the ITL accounting holds
-            if self._staged_prefill is not None:
-                self._pf_staged_misses_total += 1
-                self.scheduler.note_staged_prefill_miss()
-            self._staged_prefill = None
-            self.scheduler.staged_prefill_ready = False
         self._preemptions_total += len(sched_out.preempted)
         self.last_step_kind = (
             "ragged"
@@ -1693,38 +1661,18 @@ class LLMEngine:
                 self._step_ragged(sched_out.prefills, sched_out.decode)
             )
         elif sched_out.prefills:
-            # pipelined prefill: a buffer staged in an earlier round may
-            # cover this dispatch (validated by fingerprint inside
-            # _run_prefill_works); afterwards, a cold group's remaining
-            # chunks chain back-to-back in THIS engine round while
-            # nothing is decode-ready, and otherwise the next chunk is
-            # staged so its upload overlaps the interleaved decode round
-            staged = self._staged_prefill
-            self._staged_prefill = None
-            self.scheduler.staged_prefill_ready = False
+            # pipelined prefill: a cold group's remaining chunks chain
+            # back-to-back in THIS engine round while nothing is
+            # decode-ready or waiting (`_chain_next_prefill`)
             works = sched_out.prefills
-            # chain cap: one engine.step() holds the server's step lock,
-            # so an unbounded chain would freeze add_request/abort (and
-            # with them the whole HTTP loop) for a very long prompt's
-            # entire prefill. Bounded, the remaining chunks keep
-            # draining via staged zero-cost admission on later rounds.
-            chain_budget = self.scheduler.config.max_staged_prefill_run
-            chained = False
-            while True:
+            stepped.extend(self._run_prefill_works(works))
+            for _ in range(self.MAX_CHAINED_PREFILLS):
+                works = self._chain_next_prefill(works)
+                if works is None:
+                    break
+                self._pf_chained_chunks_total += len(works)
                 stepped.extend(
-                    self._run_prefill_works(works, staged, chained=chained)
-                )
-                staged = None
-                if chain_budget <= 0:
-                    break
-                nxt = self._chain_next_prefill(works)
-                if nxt is None:
-                    break
-                chain_budget -= 1
-                self._pf_chained_chunks_total += len(nxt)
-                chained = True
-                works = nxt
-            self._maybe_stage_prefill(works)
+                    self._run_prefill_works(works, chained=True))
         elif sched_out.decode is not None:
             seqs = sched_out.decode.seqs
             if self._spec_enabled:
@@ -1733,9 +1681,7 @@ class LLMEngine:
                     stepped.extend(spec)
                     outputs.extend(self._finalize_stepped(stepped))
                     return outputs
-            stepped.extend(
-                self._run_decode_round(seqs, sched_out.decode.k)
-            )
+            stepped.extend(self._run_decode_round(seqs))
 
         if long_stepped and len(stepped) > len(long_stepped):
             # a just-finalized long prefill may ALSO have ridden this
@@ -1749,15 +1695,14 @@ class LLMEngine:
         outputs.extend(self._finalize_stepped(stepped))
         return outputs
 
-    def _run_decode_round(
-        self, seqs: list[Sequence], k_steps: int
-    ) -> list[Sequence]:
+    def _run_decode_round(self, seqs: list[Sequence]) -> list[Sequence]:
         """Dispatch one decode round over `seqs` (the body of the
         decode step, shared by the split path and the ragged round's
         split-execution fallback): the fused K-step on-device path when
         the batch supports it, the host-sampled single-step path
         otherwise. Returns the stepped sequences."""
         stepped: list[Sequence] = []
+        k_steps = self.config.num_scheduler_steps
         with self.phases.span("pack"):
             tokens = [s.last_token_id for s in seqs]
             positions = [s.num_tokens - 1 for s in seqs]
@@ -1811,8 +1756,7 @@ class LLMEngine:
                 if st is not None:
                     if (penalties is None and bias is None
                             and guided_tables is None
-                            and st["fp"] == self._stage_fingerprint(
-                                seqs, k_steps)
+                            and st["fp"] == self._stage_fingerprint(seqs)
                             and np.array_equal(st["lanes"], lanes)):
                         # the prediction held: dispatch chained on the
                         # previous round's on-device tokens with the
@@ -1855,9 +1799,6 @@ class LLMEngine:
                         )
                     self._append_token(seq, int(token), entry)
                     stepped.append(seq)
-                # adaptive K can size a round down to 1 (single token
-                # left / admission pressure): those rounds belong in the
-                # tpu:decode_k histogram too
                 self._note_decode_round(seqs, 1)
         return stepped
 
@@ -1937,13 +1878,6 @@ class LLMEngine:
         stop = rnd["stop"]
         nk = keys.copy()
         nk[:, 1] += k_steps
-        # predict the NEXT round's adaptive K; capped at
-        # this round's K because _reserve_next_round only
-        # grew the block tables to cover 2*k positions
-        k_next = min(
-            self.scheduler.pick_decode_k(seqs, advance=k_steps),
-            k_steps,
-        )
         stage_stop = None
         if stop is not None:
             # the countdowns advance with the k tokens this
@@ -1959,17 +1893,17 @@ class LLMEngine:
         positions = [s.num_tokens - 1 + k_steps for s in seqs]
         ctx_lens = [s.num_tokens + k_steps for s in seqs]
         self._staged_decode = {
-            "fp": self._stage_fingerprint(seqs, k_next, advance=k_steps),
+            "fp": self._stage_fingerprint(seqs, advance=k_steps),
             "handle": self.runner.stage_decode_multi(
                 positions, [s.block_table for s in seqs], ctx_lens,
-                k_next, temps, top_ps, top_ks, nk,
+                k_steps, temps, top_ps, top_ks, nk,
                 min_ps=min_ps, stop=stage_stop, lanes=lanes,
             ),
             "lanes": lanes,
             "chain_tokens": rnd["toks"][-1],
             # what the stage was packed from: a round that starts at
             # the fetch's return is dispatched with exactly these
-            "k": k_next, "positions": positions, "ctx_lens": ctx_lens,
+            "positions": positions, "ctx_lens": ctx_lens,
             "sampling": (temps, top_ps, top_ks, min_ps, nk),
             "stop": stage_stop,
         }
@@ -2003,7 +1937,7 @@ class LLMEngine:
             return False  # stop strings: a rule only the host
             # evaluates, after rendering, may end a lane in this round
             # (the other such rule, a guided lane, stages nothing)
-        if st["fp"] != self._stage_fingerprint(seqs, st["k"], advance=k):
+        if st["fp"] != self._stage_fingerprint(seqs, advance=k):
             return False  # a free since the stage (the epoch)
         lanes = rnd["lanes"]
         if valid is not None and (valid[lanes] != k).any():
@@ -2058,7 +1992,7 @@ class LLMEngine:
             self._staged_hits_total += 1
             self._early_dispatch_total += 1
             self._inflight = self._dispatch_decode(
-                seqs, st["k"], st["chain_tokens"], st["positions"],
+                seqs, k_steps, st["chain_tokens"], st["positions"],
                 st["ctx_lens"], st["sampling"], st["stop"], st["lanes"],
                 staged=st["handle"],
             )
@@ -2164,7 +2098,7 @@ class LLMEngine:
         scheduling contract holds either way)."""
         self._prepare_chunks(works)
         seqs = dwork.seqs
-        k_steps = dwork.k
+        k_steps = self.config.num_scheduler_steps
         # decode-half gates mirror _run_decode_round's fused path; the
         # ragged program additionally fuses k=1 rounds (host sampling
         # is only needed for near-budget guided steering and
@@ -2195,7 +2129,7 @@ class LLMEngine:
                 self._ragged_staged_misses_total += 1
                 self._staged_ragged = None
             stepped = self._run_prefill_works(works)
-            stepped.extend(self._run_decode_round(seqs, k_steps))
+            stepped.extend(self._run_decode_round(seqs))
             return stepped
         return self._dispatch_ragged(works, seqs, k_steps, guided_tables)
 
@@ -2211,13 +2145,6 @@ class LLMEngine:
         bookkeeping afterwards. The h2d-prefetch stage for the NEXT
         round starts before any fetch so its upload overlaps."""
         now = time.time()
-        if self._staged_prefill is not None:
-            # a pure-prefill round staged ahead but the round went
-            # lane-typed instead: the prefill stage cannot be consumed
-            # here — counted miss, fingerprint would refuse it later
-            self._pf_staged_misses_total += 1
-            self._staged_prefill = None
-            self.scheduler.staged_prefill_ready = False
         for w in works:
             if w.seq.metrics.first_scheduled_time is None:
                 w.seq.metrics.first_scheduled_time = now
@@ -2256,7 +2183,7 @@ class LLMEngine:
                 if (penalties is None and bias is None
                         and guided_tables is None
                         and st["fp"] == self._ragged_fingerprint(
-                            works, seqs, k_steps)
+                            works, seqs)
                         and np.array_equal(st["lanes"], lanes)):
                     # the prediction held: chain the decode lanes on the
                     # previous round's on-device tokens with the
@@ -2429,21 +2356,29 @@ class LLMEngine:
         return nxt
 
     def _ragged_fingerprint(
-        self, works: list[PrefillWork], seqs: list[Sequence], k: int
+        self, works: list[PrefillWork], seqs: list[Sequence],
+        advance: int = 0,
     ) -> tuple:
         """State a staged ragged buffer was built for, as observed at
-        dispatch: the prefill lanes' fingerprint (chunk offsets, table
-        lengths, free epoch) + the decode lanes in order at exact token
-        counts + the round's K. Any lane-mix change — a prefill lane
-        finishing, a new admission, a different adaptive K — breaks
-        it, converting the stage into a counted miss."""
+        dispatch: the prefill lanes in order at the same chunk offsets,
+        block tables untouched (length + the allocator's free epoch —
+        freed ids can be re-handed to another sequence) and no tokens
+        appended since the stage (the sampling keys depend on
+        generated_len), + the decode lanes in order at exact token
+        counts (`advance` further at stage time: the K tokens of the
+        round on the device are not yet applied). Any lane-mix change
+        — a prefill lane finishing, a new admission — breaks it,
+        converting the stage into a counted miss."""
         return (
-            self._prefill_fingerprint(works),
+            tuple(w.seq.request_id for w in works),
+            tuple(w.chunk_start for w in works),
+            tuple(w.chunk_len for w in works),
+            tuple(len(w.seq.block_table) for w in works),
+            tuple(w.seq.num_generated for w in works),
             tuple(s.request_id for s in seqs),
-            tuple(s.num_tokens for s in seqs),
+            tuple(s.num_tokens + advance for s in seqs),
             tuple(len(s.block_table) for s in seqs),
             self.block_manager.free_epoch,
-            k,
         )
 
     def _maybe_stage_ragged(
@@ -2472,10 +2407,6 @@ class LLMEngine:
             return
         if not self._reserve_next_round(seqs, k_steps):
             return
-        k_next = min(
-            self.scheduler.pick_decode_k(seqs, advance=k_steps),
-            k_steps,
-        )
         nk = keys.copy()
         nk[:, 1] += k_steps
         stage_stop = None
@@ -2502,7 +2433,7 @@ class LLMEngine:
             [s.num_tokens - 1 + k_steps for s in seqs],
             [s.block_table for s in seqs],
             [s.num_tokens + k_steps for s in seqs],
-            k_next, temps, top_ps, top_ks, nk,
+            k_steps, temps, top_ps, top_ks, nk,
             min_ps=min_ps, stop=stage_stop,
             pf_budgets=[
                 w.seq.num_prompt_tokens
@@ -2512,14 +2443,7 @@ class LLMEngine:
             lanes=lanes,
         )
         self._staged_ragged = {
-            "fp": (
-                self._prefill_fingerprint(nxt),
-                tuple(s.request_id for s in seqs),
-                tuple(s.num_tokens + k_steps for s in seqs),
-                tuple(len(s.block_table) for s in seqs),
-                self.block_manager.free_epoch,
-                k_next,
-            ),
+            "fp": self._ragged_fingerprint(nxt, seqs, advance=k_steps),
             "handle": handle,
             "lanes": lanes,
             "chain_tokens": toks_dev[-1],
@@ -2550,22 +2474,6 @@ class LLMEngine:
         return out
 
     # -- pipelined prefill --------------------------------------------------
-    def _prefill_fingerprint(self, works: list[PrefillWork]) -> tuple:
-        """State a staged prefill buffer was built for, as observed at
-        dispatch: same sequences in the same order at the same chunk
-        offsets, block tables untouched (length + the allocator's free
-        epoch — freed ids can be re-handed to another sequence), and no
-        tokens appended since the stage (the sampling keys depend on
-        generated_len)."""
-        return (
-            tuple(w.seq.request_id for w in works),
-            tuple(w.chunk_start for w in works),
-            tuple(w.chunk_len for w in works),
-            tuple(len(w.seq.block_table) for w in works),
-            tuple(w.seq.num_generated for w in works),
-            self.block_manager.free_epoch,
-        )
-
     def _next_prefill_works(
         self, works: list[PrefillWork]
     ) -> list[PrefillWork]:
@@ -2592,6 +2500,13 @@ class LLMEngine:
                 seq=s, chunk_start=s.num_computed_tokens, chunk_len=clen,
             ))
         return nxt
+
+    # chain cap: one engine.step() holds the server's step lock, so an
+    # unbounded chain would freeze add_request/abort (and with them the
+    # whole HTTP loop) for a very long prompt's entire prefill. Past
+    # the cap the next step() schedules the following chunk and chains
+    # again.
+    MAX_CHAINED_PREFILLS = 8
 
     def _chain_next_prefill(
         self, works: list[PrefillWork]
@@ -2623,62 +2538,6 @@ class LLMEngine:
         nxt = self._next_prefill_works(works)
         return nxt or None
 
-    def _maybe_stage_prefill(self, works: list[PrefillWork]) -> None:
-        """Stage the predicted next chunk group's packed buffer so its
-        h2d transfer rides out the interleaved decode round instead of
-        sitting serially before the next prefill dispatch. Validated by
-        fingerprint before use; single-device only (a mesh would have to
-        reshard the committed transfer)."""
-        if not self._prefill_pipeline or self.runner.mesh is not None:
-            return
-        if self.scheduler.waiting:
-            return  # the next group will include new admissions: miss
-        if self._ragged_dispatch and any(
-            s.prefill_done and not s.finished
-            for s in self.scheduler.running
-        ):
-            # a decode-ready lane exists (possibly made ready by THIS
-            # round's final chunk): the next round is lane-typed and
-            # consumes the RAGGED stage, never the prefill stage
-            return
-        nxt = self._next_prefill_works(works)
-        if not nxt:
-            return
-        seqs = [w.seq for w in nxt]
-        temps, top_ps, top_ks, min_ps, keys, _ = (
-            self._sampling_arrays(seqs)
-        )
-        sampling = (temps, top_ps, top_ks, min_ps, keys)
-        if len(nxt) == 1:
-            w = nxt[0]
-            handle = self.runner.stage_prefill(
-                w.seq.prompt_token_ids[
-                    w.chunk_start : w.chunk_start + w.chunk_len
-                ],
-                w.chunk_start,
-                w.seq.block_table,
-                w.chunk_start + w.chunk_len,
-                sampling=sampling,
-            )
-        else:
-            handle = self.runner.stage_prefill_batch(
-                [
-                    w.seq.prompt_token_ids[
-                        w.chunk_start : w.chunk_start + w.chunk_len
-                    ]
-                    for w in nxt
-                ],
-                start_positions=[w.chunk_start for w in nxt],
-                block_tables=[w.seq.block_table for w in nxt],
-                total_lens=[w.chunk_start + w.chunk_len for w in nxt],
-                sampling=sampling,
-            )
-        self._staged_prefill = {
-            "fp": self._prefill_fingerprint(nxt),
-            "handle": handle,
-        }
-        self.scheduler.staged_prefill_ready = True
-
     def _prepare_chunks(self, works: list[PrefillWork]) -> None:
         """Every prefill chunk passes here right before its dispatch,
         scheduled, chained or split alike: a block manager with a second
@@ -2701,15 +2560,13 @@ class LLMEngine:
                 )
 
     def _run_prefill_works(
-        self, works: list[PrefillWork], staged: dict | None = None,
-        chained: bool = False,
+        self, works: list[PrefillWork], chained: bool = False,
     ) -> list[Sequence]:
         """Dispatch one scheduled prefill chunk group (the body of the
         prefill step): prompt_logprobs sequences on the single-sequence
         program variant, everything else in one packed dispatch, first
         tokens appended for final chunks. Returns the stepped sequences.
-        `staged` = a _maybe_stage_prefill record; used when its
-        fingerprint matches this exact group. `chained` marks groups
+        `chained` marks groups
         dispatched by cold-prompt chaining (no host round-trip since the
         previous group) for the timeline."""
         self._prepare_chunks(works)
@@ -2718,21 +2575,9 @@ class LLMEngine:
         for w in works:
             if w.seq.metrics.first_scheduled_time is None:
                 w.seq.metrics.first_scheduled_time = now
-        staged_hit = False
         phase_snap = self.phases.seconds() if self._tl_enabled else None
         self._begin_round(
             "prefill", 0, 0, sum(w.chunk_len for w in works))
-        staged_kw = {}
-        if staged is not None:
-            if staged["fp"] == self._prefill_fingerprint(works):
-                # the prediction held: the packed buffer is already on
-                # device — zero serial h2d for this dispatch
-                staged_kw = {"staged": staged["handle"]}
-                self._pf_staged_hits_total += 1
-                staged_hit = True
-            else:
-                self._pf_staged_misses_total += 1
-                self.scheduler.note_staged_prefill_miss()
         # prompt_logprobs requests take the single-sequence program
         # variant (every row's distribution scored on device); they
         # never pack — their per-row outputs are per-sequence
@@ -2801,7 +2646,6 @@ class LLMEngine:
                     total_len=w.chunk_start + w.chunk_len,
                     lora_slot=self._lora_slot(seq),
                     sampling=sampling,
-                    **staged_kw,
                 )
                 tokens_dev = token_dev[None]
                 last_logits[std_works[0][0]] = logits
@@ -2824,7 +2668,6 @@ class LLMEngine:
                         self._lora_slot(w.seq) for w in sworks
                     ],
                     sampling=sampling,
-                    **staged_kw,
                 )
                 for j, (i, _) in enumerate(std_works):
                     last_logits[i] = logits[j]
@@ -2853,7 +2696,6 @@ class LLMEngine:
                         "chunk_start": w.chunk_start,
                         "chunk_len": w.chunk_len,
                         "last": w.is_last_chunk,
-                        "staged_hit": staged_hit,
                         "chained": chained,
                         "group_size": len(works),
                         "engine_round": self._round,
@@ -3161,17 +3003,6 @@ class LLMEngine:
                 if ids:
                     stop_ids[i, : len(ids)] = ids
         return eos, min_rem, budget, stop_ids
-
-    def drain_decode_k_observations(self) -> list[int]:
-        """Chosen-K observations since the last drain — feeds the
-        server's tpu:decode_k histogram (deque pops GIL-atomic)."""
-        out: list[int] = []
-        while True:
-            try:
-                out.append(self._decode_k_obs.popleft())
-            except IndexError:
-                break
-        return out
 
     @staticmethod
     def _bias_arrays(
@@ -3977,8 +3808,6 @@ class LLMEngine:
             **self._layer_group_stats(),
             program_stages=phases.program_stage_pairs(),
             program_cache_hits_total=phases.PROGRAM_CACHE_HITS[0],
-            prefill_staged_hits_total=self._pf_staged_hits_total,
-            prefill_staged_misses_total=self._pf_staged_misses_total,
             prefill_chained_chunks_total=self._pf_chained_chunks_total,
             long_prefill_requests_total=(
                 self.long_prefill.requests_total
@@ -4170,37 +3999,27 @@ class LLMEngine:
         # decode: pick context lens that land IN each bucket after the
         # +K-1 lookahead shift (passing the bucket boundary itself would
         # shift every program one bucket up and leave the smallest
-        # bucket cold). Adaptive K dispatches any pow2 bucket below the
-        # cap, so warm each bucket's program (fixed K = just the cap);
-        # device stops select a distinct program variant.
-        for kk, chained, stop in decode_precompile_variants(
+        # bucket cold). Every round is K steps; device stops select a
+        # distinct program variant.
+        kk, chained, stop = decode_precompile_variant(
             cfg.num_scheduler_steps,
-            self.scheduler.config.adaptive_decode_k,
             overlap=self._prefetch_decode,
             device_stop=self._device_stop,
-        ):
-            n += rnr.precompile_decode(
-                [max(1, c - kk + 1) for c in ctxs], kk,
-                chained=chained, stop=stop,
-            )
+        )
+        n += rnr.precompile_decode(
+            [max(1, c - kk + 1) for c in ctxs], kk,
+            chained=chained, stop=stop,
+        )
         if self._ragged_dispatch:
             # unified ragged rounds: warm the pow2 lane-mix buckets —
-            # every prefill-lane group size x each fused-K bucket x
-            # each ctx bucket, prefill context matched to the decode
-            # bucket (sessions in one workload share a length regime;
+            # every prefill-lane group size x each ctx bucket, prefill
+            # context matched to the decode bucket (sessions in one workload share a length regime;
             # off-diagonal prefill/decode context pairs are
             # request-dependent and compile on first use, cached by
             # JAX_COMPILATION_CACHE_DIR across restarts)
-            from production_stack_tpu.engine.scheduler import (
-                decode_k_buckets,
-            )
-
             n += rnr.precompile_ragged(
-                [max(1, c - cfg.num_scheduler_steps + 1) for c in ctxs],
-                decode_k_buckets(
-                    cfg.num_scheduler_steps,
-                    self.scheduler.config.adaptive_decode_k,
-                ),
+                [max(1, c - kk + 1) for c in ctxs],
+                [kk],
                 cfg.max_prefill_seqs,
                 cfg.max_prefill_chunk,
                 stop=self._device_stop,

@@ -300,16 +300,6 @@ class EngineMetrics:
             "written", label, buckets=_LATENCY_BUCKETS, registry=reg,
         )
         self.server_ttft.labels(model_name)  # exported from boot
-        self.prefill_staged_hits = Counter(
-            "tpu:prefill_staged_hits",
-            "Prefill dispatches served from a pre-uploaded staged "
-            "buffer", label, registry=reg,
-        )
-        self.prefill_staged_misses = Counter(
-            "tpu:prefill_staged_misses",
-            "Staged prefill buffers invalidated before dispatch",
-            label, registry=reg,
-        )
         self.prefill_chained_chunks = Counter(
             "tpu:prefill_chained_chunks",
             "Prefill chunks dispatched via cold-prompt chaining "
@@ -454,16 +444,8 @@ class EngineMetrics:
             "frame) that degraded without stalling the engine",
             label, registry=reg,
         )
-        # elastic fused decode: per-round chosen K (adaptive sizing in
-        # pow2 buckets up to num_scheduler_steps), host-discarded
-        # overshoot tokens (the K=32 waste mode — ~0 under device
-        # stops), and whole-round device early exits
-        self.decode_k = Histogram(
-            "tpu:decode_k",
-            "Fused decode iterations dispatched per round (adaptive K "
-            "buckets; the cap with --no-adaptive-decode-k)",
-            label, buckets=(1, 2, 4, 8, 16, 32), registry=reg,
-        )
+        # fused decode: rounds, host-discarded overshoot tokens (~0
+        # under device stops), and whole-round device early exits
         self.decode_rounds = Counter(
             "tpu:decode_rounds", "Decode rounds dispatched",
             label, registry=reg,
@@ -694,12 +676,6 @@ class EngineMetrics:
         self.program_cache_hits.labels(m).inc(max(
             0, s.program_cache_hits_total
             - prev.program_cache_hits_total))
-        self.prefill_staged_hits.labels(m).inc(max(
-            0, s.prefill_staged_hits_total
-            - prev.prefill_staged_hits_total))
-        self.prefill_staged_misses.labels(m).inc(max(
-            0, s.prefill_staged_misses_total
-            - prev.prefill_staged_misses_total))
         self.prefill_chained_chunks.labels(m).inc(max(
             0, s.prefill_chained_chunks_total
             - prev.prefill_chained_chunks_total))
@@ -812,13 +788,6 @@ class EngineMetrics:
             self.kv_export_s.labels(m).observe(max(0.0, s))
         for s in restore_seconds:
             self.kv_restore_s.labels(m).observe(max(0.0, s))
-
-    def observe_decode_k(self, ks: list[int]) -> None:
-        """Feed drained chosen-K observations (LLMEngine.
-        drain_decode_k_observations) into the tpu:decode_k histogram."""
-        m = self.model_name
-        for k in ks:
-            self.decode_k.labels(m).observe(k)
 
     def observe_ragged(self, lane_counts: list[int]) -> None:
         """Feed drained ragged lane-mix observations (LLMEngine.

@@ -1723,54 +1723,6 @@ class ModelRunner:
         put("keys", keys)
         return s_pad, t_pad, c_pad, packed
 
-    # stackcheck: hot-path — staging must overlap the in-flight dispatch;
-    # any hidden host-device sync here serializes the prefill pipeline
-    def stage_prefill(
-        self, token_ids: list[int], start_pos: int,
-        block_table: list[int], total_len: int, sampling=None,
-    ) -> tuple:
-        """Speculative h2d prefetch for a FUTURE prefill chunk: build
-        the packed buffer and START its async host->device transfer now
-        so the upload overlaps the in-flight dispatch's compute instead
-        of sitting serially before the next one (prefill mirror of
-        stage_decode_multi). Returns a handle for prefill(staged=...);
-        the caller (engine) validates its fingerprint before use."""
-        with self.phases.span("pack"):
-            t_pad, c_pad, packed = self._fill_prefill_pack(
-                token_ids, start_pos, block_table, total_len,
-                sampling=sampling,
-            )
-        with self.phases.span("h2d"):
-            handle = (("single", t_pad, c_pad), jax.device_put(packed))
-        return handle
-
-    # stackcheck: hot-path
-    def stage_prefill_batch(
-        self,
-        chunks: list[list[int]],
-        start_positions: list[int],
-        block_tables: list[list[int]],
-        total_lens: list[int],
-        sampling=None,
-    ) -> tuple:
-        """Packed-group variant of stage_prefill."""
-        with self.phases.span("pack"):
-            if self.ragged_kernel and self.prefill_pipeline:
-                r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
-                    chunks, start_positions, block_tables, total_lens,
-                    sampling=sampling,
-                )
-                key = ("rows", r_pad, pc_pad)
-            else:
-                s_pad, t_pad, c_pad, packed = self._fill_packed_prefill_pack(
-                    chunks, start_positions, block_tables, total_lens,
-                    sampling=sampling,
-                )
-                key = ("packed", s_pad, t_pad, c_pad)
-        with self.phases.span("h2d"):
-            handle = (key, jax.device_put(packed))
-        return handle
-
     def _build_prefill(self, t_pad: int, c_pad: int,
                        want_prompt_lp: bool = False):
         mc = self.model_config
@@ -2913,7 +2865,6 @@ class ModelRunner:
         lora_slot: int = 0,
         sampling=None,
         prompt_lp_targets: list[int] | None = None,
-        staged: tuple | None = None,
     ) -> tuple:
         """Run one prefill chunk; returns (token, logits) ON DEVICE where
         `token` is the first generated token sampled from the chunk's last
@@ -2926,12 +2877,7 @@ class ModelRunner:
         prompt token ids (-1 = no target); selects a program variant
         that additionally returns (chosen (t_pad,) f32, top_vals
         (t_pad, CAP) f32, top_ids (t_pad, CAP) i32) device arrays —
-        row i scores targets[i] under the model's distribution.
-
-        `staged` = a stage_prefill handle whose packed buffer was
-        uploaded ahead of time (chunk pipelining); used only when its
-        bucket key matches — the CALLER guarantees the staged content
-        equals what these arguments would build."""
+        row i scores targets[i] under the model's distribution."""
         want_plp = prompt_lp_targets is not None
         lora_kw = {}
         if self.lora_manager is not None:
@@ -2942,21 +2888,14 @@ class ModelRunner:
                 "lora_slots": jnp.int32(lora_slot),
             }
         if self.prefill_pipeline:
-            t_pad = self._prefill_bucket(len(token_ids))
-            c_pad = self._ctx_bucket(total_len)
-            packed_dev = None
-            if (staged is not None and not want_plp
-                    and staged[0] == ("single", t_pad, c_pad)):
-                packed_dev = staged[1]  # upload already overlapped
-            if packed_dev is None:
-                with self.phases.span("pack"):
-                    t_pad, c_pad, packed = self._fill_prefill_pack(
-                        token_ids, start_pos, block_table, total_len,
-                        sampling=sampling,
-                        prompt_lp_targets=prompt_lp_targets,
-                    )
-                with self.phases.span("h2d"):
-                    packed_dev = jnp.asarray(packed)
+            with self.phases.span("pack"):
+                t_pad, c_pad, packed = self._fill_prefill_pack(
+                    token_ids, start_pos, block_table, total_len,
+                    sampling=sampling,
+                    prompt_lp_targets=prompt_lp_targets,
+                )
+            with self.phases.span("h2d"):
+                packed_dev = jnp.asarray(packed)
             fn, build = self._prefill_fn(t_pad, c_pad, want_plp)
             self._note_attn_context(prefill_lens=(total_len,))
             self.note_sampler(1, sampling and sampling[0])
@@ -3031,33 +2970,24 @@ class ModelRunner:
         total_lens: list[int],
         lora_slots: list[int] | None = None,
         sampling=None,
-        staged: tuple | None = None,
     ) -> tuple[jax.Array, jax.Array]:
         """Run one prompt chunk for EACH of n sequences in a single packed
         dispatch; returns (tokens, logits) ON DEVICE — tokens (s_pad,)
         sampled from each chunk's last *actual* row with `sampling` =
         per-sequence (temps, top_ps, top_ks, keys), logits (s_pad, vocab)
         for penalty/debug paths (rows >= n are padding). K/V for every
-        chunk is written into the cache.
-
-        `staged` = a stage_prefill_batch handle (see prefill)."""
+        chunk is written into the cache."""
         n = len(chunks)
         if self.prefill_pipeline and self.ragged_kernel:
             # ragged-rows path: program keys on the padded ROW bucket
             # (r_pad, pc_pad), one kernel launch for any group
-            r_pad, pc_pad = self._rows_dims(chunks, total_lens)
-            packed_dev = None
-            if (staged is not None
-                    and staged[0] == ("rows", r_pad, pc_pad)):
-                packed_dev = staged[1]  # upload already overlapped
-            if packed_dev is None:
-                with self.phases.span("pack"):
-                    r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
-                        chunks, start_positions, block_tables, total_lens,
-                        sampling=sampling,
-                    )
-                with self.phases.span("h2d"):
-                    packed_dev = jnp.asarray(packed)
+            with self.phases.span("pack"):
+                r_pad, pc_pad, packed = self._fill_rows_prefill_pack(
+                    chunks, start_positions, block_tables, total_lens,
+                    sampling=sampling,
+                )
+            with self.phases.span("h2d"):
+                packed_dev = jnp.asarray(packed)
             key = ("rows", r_pad, pc_pad)
             build = phases.NO_SPAN
             if key not in self._prefill_batch_fns:
@@ -3081,23 +3011,15 @@ class ModelRunner:
                 )
             return sampled, logits
         if self.prefill_pipeline:
-            s_pad = next_pow2(max(n, 1))
-            t_pad = self._prefill_bucket(max(len(c) for c in chunks))
-            c_pad = max(self._ctx_bucket(tl) for tl in total_lens)
-            packed_dev = None
-            if (staged is not None
-                    and staged[0] == ("packed", s_pad, t_pad, c_pad)):
-                packed_dev = staged[1]  # upload already overlapped
-            if packed_dev is None:
-                with self.phases.span("pack"):
-                    s_pad, t_pad, c_pad, packed = (
-                        self._fill_packed_prefill_pack(
-                            chunks, start_positions, block_tables,
-                            total_lens, sampling=sampling,
-                        )
+            with self.phases.span("pack"):
+                s_pad, t_pad, c_pad, packed = (
+                    self._fill_packed_prefill_pack(
+                        chunks, start_positions, block_tables,
+                        total_lens, sampling=sampling,
                     )
-                with self.phases.span("h2d"):
-                    packed_dev = jnp.asarray(packed)
+                )
+            with self.phases.span("h2d"):
+                packed_dev = jnp.asarray(packed)
             fn, build = self._prefill_batch_fn(s_pad, t_pad, c_pad)
             lora_kw = self._packed_lora_kwargs(
                 lora_slots, n, s_pad, t_pad
@@ -4214,8 +4136,8 @@ class ModelRunner:
 
     # stackcheck: hot-path — speculative h2d prefetch of the NEXT ragged
     # round's packed buffer: the upload overlaps the in-flight round's
-    # execution and fetch (prefill mirror: stage_prefill_batch; decode
-    # mirror: stage_decode_multi). Enqueue-only, no device fetch.
+    # execution and fetch (decode mirror: stage_decode_multi).
+    # Enqueue-only, no device fetch.
     def stage_ragged(
         self,
         pf_chunks: list[list[int]],

@@ -135,9 +135,8 @@ class EngineStatsSnapshot:
     # tpu:program_cache_hits
     program_stages: dict = field(default_factory=dict)
     program_cache_hits_total: int = 0
-    # prefill staging effectiveness — tpu:prefill_staged_* in /metrics
-    prefill_staged_hits_total: int = 0
-    prefill_staged_misses_total: int = 0
+    # chunks a cold prompt's chain dispatched after a step's first —
+    # tpu:prefill_chained_chunks in /metrics
     prefill_chained_chunks_total: int = 0
     # long-prefill lane (context-parallel ring prefill, engine/
     # long_prefill.py): requests served by the ring, ring chunks

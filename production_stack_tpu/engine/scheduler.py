@@ -30,11 +30,11 @@ disappears entirely. `plan_ragged_round` packs every mid-prefill
 runner's next chunk AND the decode-ready batch into ONE lane-typed
 round (the engine dispatches both halves in a single device program —
 model_runner.ragged_dispatch), so a waiting prefill claims a lane in
-the very next round instead of queueing behind the interleave streak,
-and the admission-K clamp no longer applies to in-round prefill work
-(pick_decode_k's ragged branch). The streak counter, staged-bypass
-accounting, and clamp stay in place for the split path
-(`--no-ragged-dispatch`, multihost, meshed engines).
+the very next round instead of queueing behind the interleave streak.
+The streak counter stays in place for the split path
+(`--no-ragged-dispatch`, multihost, meshed engines). A fused decode
+round has one size everywhere, the operator's `--num-scheduler-steps`:
+the scheduler only reserves its lookahead (`decode_lookahead`).
 """
 
 from __future__ import annotations
@@ -66,11 +66,6 @@ class PrefillWork:
 @dataclass
 class DecodeWork:
     seqs: list[Sequence]
-    # fused decode iterations this round (elastic fused decode): the
-    # scheduler sizes each round from pow2 buckets up to decode_k_cap —
-    # clamped low under admission pressure, bounded by the batch's
-    # remaining-token budget; the cap itself with adaptive K off
-    k: int = 1
 
 
 @dataclass
@@ -126,28 +121,13 @@ class SchedulerConfig:
     decode_interleave: int = 1
     # extra decode positions to reserve per scheduled sequence so a
     # multi-step dispatch (num_scheduler_steps - 1 lookahead) never runs
-    # off the end of its block table mid-scan (always the CAP, so a
-    # round sized below the cap is trivially covered)
+    # off the end of its block table mid-scan
     decode_lookahead: int = 0
-    # fused decode iterations per dispatch, ceiling (engine
-    # num_scheduler_steps); pick_decode_k sizes each round up to it
-    decode_k_cap: int = 1
-    # admission-aware adaptive K (EngineConfig.adaptive_decode_k):
-    # False = every round dispatches the full cap
-    adaptive_decode_k: bool = False
-    # pipelined prefill: a chunk whose packed h2d buffer is already
-    # uploaded (engine sets `staged_prefill_ready`) is admitted as
-    # zero cost against the decode interleave — cold multi-chunk
-    # prefills then drain in consecutive rounds instead of one chunk
-    # per decode round. This caps how many consecutive staged
-    # dispatches may bypass starvation before decode gets its turn
-    # (bounds worst-case ITL for very long prompts).
-    max_staged_prefill_run: int = 8
     # unified ragged dispatch (EngineConfig.ragged_dispatch, gated by
     # the engine for multihost/async/mesh): plan ONE lane-typed round
     # carrying prefill-chunk lanes AND the decode batch together —
-    # dissolves the interleave streak and the admission-K clamp for
-    # in-round prefill work (plan_ragged_round / pick_decode_k)
+    # dissolves the interleave streak for in-round prefill work
+    # (plan_ragged_round)
     ragged_dispatch: bool = False
     # long-prefill lane (EngineConfig.long_prefill_threshold, set by
     # the engine only when its ring manager actually built): an
@@ -159,36 +139,17 @@ class SchedulerConfig:
     long_prefill_threshold: int = 0
 
 
-def decode_k_buckets(cap: int, adaptive: bool) -> list[int]:
-    """The fused-decode K program variants a serving config can
-    dispatch: just the cap with adaptive K off, plus every pow2 below
-    it with adaptive K on (pick_decode_k rounds remaining budgets UP
-    to the next pow2, so these are exactly the reachable Ks). Kept
-    beside pick_decode_k so the set LLMEngine.precompile_serving warms
-    can never drift from the scheduler's rounding."""
-    cap = max(1, cap)
-    ks = {cap}
-    if adaptive and cap > 1:
-        p = 1
-        while p < cap:
-            ks.add(p)
-            p *= 2
-    return sorted(ks)
-
-
-def decode_precompile_variants(
-    cap: int, adaptive: bool, *, overlap: bool, device_stop: bool,
-) -> list[tuple[int, bool, bool]]:
-    """(k, chained, stop) decode program variants a serving config
-    dispatches — the variant-selection policy LLMEngine.precompile_serving
-    warms by, kept beside the scheduler's rounding so it cannot silently
-    warm a different set than the runtime selects (a missed variant = a
-    mid-request XLA compile). `overlap` = the h2d prefetch, whose staged
-    round dispatches the chained program."""
-    return [
-        (k, overlap and k > 1, device_stop and k > 1)
-        for k in decode_k_buckets(cap, adaptive)
-    ]
+def decode_precompile_variant(
+    k: int, *, overlap: bool, device_stop: bool,
+) -> tuple[int, bool, bool]:
+    """(k, chained, stop): the decode program a serving config
+    dispatches — what LLMEngine.precompile_serving warms, kept beside
+    the scheduler so it cannot silently warm another program than the
+    runtime selects (a missed variant = a mid-request XLA compile).
+    `overlap` = the h2d prefetch, whose staged round dispatches the
+    chained program; K=1 is the single step, which has neither."""
+    k = max(1, k)
+    return k, overlap and k > 1, device_stop and k > 1
 
 
 class Scheduler:
@@ -226,12 +187,6 @@ class Scheduler:
         # per-request timeline; None/disabled costs one check
         self.timeline = None
         self._prefill_streak = 0  # consecutive prefill steps scheduled
-        # engine-maintained hint (pipelined prefill): the next prefill
-        # dispatch's packed buffer is already on device, so admitting it
-        # costs ~no link time; it bypasses the interleave's starvation
-        # gate, bounded by max_staged_prefill_run consecutive bypasses
-        self.staged_prefill_ready = False
-        self._staged_run = 0
 
     # -- queue introspection (feeds the vllm:num_requests_* gauges) -------
     @property
@@ -420,14 +375,9 @@ class Scheduler:
         has_decode_ready = any(
             s.prefill_done and not s.finished for s in self.running
         )
-        staged_bypass = (
-            self.staged_prefill_ready
-            and self._staged_run < self.config.max_staged_prefill_run
-        )
         decode_starved = (
             self.config.decode_interleave > 0
             and has_decode_ready
-            and not staged_bypass
             and self._prefill_streak >= self.config.decode_interleave
         )
         if not decode_starved:
@@ -459,28 +409,15 @@ class Scheduler:
                 # throttled admission to ONE UNPACKED chunk per decode
                 # round under load (cost of either on an attached chip:
                 # not measured).
-                if (staged_bypass and has_decode_ready
-                        and self._prefill_streak
-                        >= self.config.decode_interleave):
-                    # zero-cost admission: this dispatch's h2d already
-                    # overlapped earlier compute (pipelined prefill);
-                    # decode's extra wait is bounded by the staged-run
-                    # cap, and a stale stage is converted back into a
-                    # charged dispatch via note_staged_prefill_miss
-                    self._staged_run += 1
-                else:
-                    self._prefill_streak += 1
+                self._prefill_streak += 1
                 return out
         self._prefill_streak = 0
-        self._staged_run = 0
 
         # 3) otherwise decode every decode-ready running sequence (mid-
         # prefill sequences sit out the interleaved decode steps)
         decode_seqs = self._collect_decode_ready(out)
         if decode_seqs:
-            out.decode = DecodeWork(
-                seqs=decode_seqs, k=self.pick_decode_k(decode_seqs)
-            )
+            out.decode = DecodeWork(seqs=decode_seqs)
         return out
 
     def _collect_decode_ready(
@@ -543,9 +480,7 @@ class Scheduler:
         with no interleave-streak wait, which is the scheduling contract
         tests/test_ragged_dispatch.py pins. The decode-capacity pass
         (with its preemption) runs FIRST so a victim never also claims a
-        prefill lane; pick_decode_k's ragged branch drops the
-        admission-K clamp for in-round prefill work (only a
-        capacity-starved waiting queue still clamps)."""
+        prefill lane."""
         decode_seqs = self._collect_decode_ready(out)
         group_cap = (
             self.config.max_prefill_seqs
@@ -568,70 +503,8 @@ class Scheduler:
                 chunk_len=chunk_len,
             ))
         if decode_seqs:
-            out.decode = DecodeWork(
-                seqs=decode_seqs, k=self.pick_decode_k(decode_seqs)
-            )
+            out.decode = DecodeWork(seqs=decode_seqs)
         return out
-
-    # K clamp while admission work exists: a fused round never keeps a
-    # cold prompt waiting for more than ~this many steps (a K=16
-    # round is 16 uninterruptible steps while prefill chunks queue)
-    ADMISSION_K_CLAMP = 2
-
-    # stackcheck: hot-path — pure host arithmetic on the scheduling
-    # path; one pass over the decode batch, no allocation beyond ints
-    def pick_decode_k(
-        self, seqs: list[Sequence], advance: int = 0
-    ) -> int:
-        """Size this round's fused decode K (elastic fused decode):
-        pow2 buckets up to decode_k_cap, clamped to ADMISSION_K_CLAMP
-        while any prefill work is pending (waiting queue or a running
-        mid-prefill sequence — admission must never be starved by a
-        long uninterruptible round), and bounded by the batch's MAX
-        remaining-token budget (when every lane has <=4 tokens left, a
-        K=16 dispatch wastes 3/4 of its slots — the K=32 overshoot
-        mode; under device stops the shorter lanes freeze mid-round
-        anyway, so the max is the right bound). `advance` predicts the
-        pick `advance` tokens ahead (h2d prefetch stages the NEXT
-        round before this one's tokens are applied). Returns the cap
-        unchanged with adaptive K off."""
-        cap = max(1, self.config.decode_k_cap)
-        if not self.config.adaptive_decode_k or cap == 1 or not seqs:
-            return cap
-        k = cap
-        if self.config.ragged_dispatch:
-            # ragged audit: a mid-prefill runner rides THIS round as a
-            # prefill lane, so it must not clamp K — that was exactly
-            # the interleave-era starvation the unified round dissolves.
-            # Only a capacity-starved waiting queue (admission loop left
-            # it non-empty) still clamps: a shorter round reaches the
-            # next admission/preemption decision sooner.
-            if self.waiting:
-                k = min(k, self.ADMISSION_K_CLAMP)
-        elif self.waiting or any(
-            not s.prefill_done and not s.long_prefill_active
-            for s in self.running
-        ):
-            # a long-lane runner is mid-prefill for SECONDS (the whole
-            # ring) and advances one enqueue per step regardless of K —
-            # clamping every decode round under it was exactly the
-            # starvation the lane exists to avoid
-            k = min(k, self.ADMISSION_K_CLAMP)
-        rem = 0
-        mml = self.config.max_model_len
-        for s in seqs:
-            sp = s.sampling_params
-            r = min(
-                sp.max_tokens - s.num_generated,
-                mml - s.num_tokens,
-            ) - advance
-            rem = max(rem, r)
-        rem = max(1, rem)
-        if rem < k:
-            # round UP to the pow2 bucket so the variant space stays
-            # O(log cap) (precompiled by --precompile-serving)
-            k = 1 << (rem - 1).bit_length()
-        return max(1, min(k, cap))
 
     def _note_admitted(self, seq: Sequence) -> None:
         """Queue-wait/stall bookkeeping + timeline event on each
@@ -659,15 +532,6 @@ class Scheduler:
                     ),
                 },
             )
-
-    def note_staged_prefill_miss(self) -> None:
-        """The engine found the staged prefill buffer stale at dispatch
-        time (fingerprint mismatch): the dispatch paid the full serial
-        h2d after all, so convert the zero-cost admission back into a
-        normally charged one."""
-        if self._staged_run > 0:
-            self._staged_run -= 1
-            self._prefill_streak += 1
 
     def schedule_admit_retry(self, out: SchedulerOutput) -> SchedulerOutput:
         """Re-run schedule() after a priority claim, merging the

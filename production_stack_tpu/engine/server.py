@@ -167,9 +167,6 @@ class EngineServer:
                 self.metrics.observe_kv(
                     *self.engine.drain_kv_observations()
                 )
-                self.metrics.observe_decode_k(
-                    self.engine.drain_decode_k_observations()
-                )
                 self.metrics.observe_ragged(
                     self.engine.drain_ragged_observations()
                 )
@@ -1240,9 +1237,6 @@ class EngineServer:
     async def handle_metrics(self, request: web.Request) -> web.Response:
         self.metrics.update_from_snapshot(self.engine.stats())
         self.metrics.observe_kv(*self.engine.drain_kv_observations())
-        self.metrics.observe_decode_k(
-            self.engine.drain_decode_k_observations()
-        )
         self.metrics.observe_ragged(
             self.engine.drain_ragged_observations()
         )

@@ -26,7 +26,8 @@ in between two steps as the server's lock lets them in; a prompt is
 one of the cell's documents and a fresh question, an answer is of the
 traffic's lengths; every program warmed first as the benchmark warms
 them), and prints what no exporter reads: decode-bearing rounds,
-lane-typed rounds among them, staged hits and misses, early starts,
+lane-typed rounds among them, staged hits and misses (the decode
+stage's, and the lane-typed round's own), early starts,
 and WHY a stage was refused, by a wrapper of `_reserve_next_round`
 that lives here. An engine without the decision (the parent commit:
 copy this file beside it) is counted the same way, its early starts 0.
@@ -54,6 +55,8 @@ def counters(engine) -> dict:
         "ragged_rounds": engine._ragged_rounds_total,
         "staged_hits": engine._staged_hits_total,
         "staged_misses": engine._staged_misses_total,
+        "ragged_staged_hits": engine._ragged_staged_hits_total,
+        "ragged_staged_misses": engine._ragged_staged_misses_total,
         "early": getattr(engine, "_early_dispatch_total", 0),
         "phases": engine.phases.pairs(),
     }

@@ -1,15 +1,13 @@
-"""Elastic fused decode (device-side stop masks + admission-aware
-adaptive K): tokens must stay BIT-IDENTICAL to the serial single-step
-path while lanes finish MID-ROUND on device — EOS, stop_token_ids, and
+"""Fused decode with device-side stop masks: tokens must stay
+BIT-IDENTICAL to the serial single-step path while lanes finish MID-ROUND on device — EOS, stop_token_ids, and
 max_tokens freeze the lane inside the fused scan (pinned pad slot,
 KV writes to the trash slot, penalty/DFA state frozen) and the host
 applies exactly the per-lane valid counts instead of discarding
 overshoot after the fetch.
 
-Role: a fixed-trip K round samples slots past a lane's stop and keeps
-waiting prompts out for K uninterruptible steps; device stops remove
-the waste, adaptive K removes the admission starvation, and this suite
-pins the parity bar every prior perf PR met."""
+Role: a fixed-trip K round samples slots past a lane's stop; device
+stops remove the waste (a round whose lanes all ended exits early),
+and this suite pins the parity bar every prior perf PR met."""
 
 from __future__ import annotations
 
@@ -157,81 +155,7 @@ def test_guided_lanes_with_device_stops():
     assert multi == single
 
 
-# -- (f) adaptive K round sizing ---------------------------------------------
-def test_adaptive_k_shrinks_under_cold_prefill_and_grows_back():
-    """A queued cold prefill clamps the round size (admission is never
-    starved by a long fused round — the K=16 TTFT failure mode); once
-    the backlog drains, rounds grow back to the cap. Outputs stay
-    bit-identical to the fixed-K engine (the per-iteration sampling
-    keys depend only on generated_len)."""
-    sp = SamplingParams(max_tokens=40, temperature=0.0, ignore_eos=True)
-    long_prompt = list(range(1, 30))  # 4 chunks at max_prefill_chunk=8
-
-    def run(adaptive):
-        eng = _engine(
-            8, max_num_seqs=2, num_kv_blocks=128, max_prefill_chunk=8,
-            adaptive_decode_k=adaptive,
-            # chunk-by-chunk decode interleave: with the prefill
-            # pipeline's staged bypass on, a cold prompt's chunks drain
-            # back-to-back BEFORE any decode round runs, so no round
-            # ever observes the backlog (that path is its own fix for
-            # admission starvation — the clamp covers the interleaved
-            # rounds this config forces)
-            prefill_pipeline=False,
-            # the clamp is SPLIT-path behavior: unified ragged rounds
-            # run the cold prompt's chunks in-lane, so no round needs
-            # to shrink for it (tests/test_ragged_dispatch.py pins
-            # that no-clamp contract)
-            ragged_dispatch=False,
-        )
-        outs = {}
-        eng.add_request("a", prompt_token_ids=PROMPTS[0],
-                        sampling_params=sp)
-        steps = 0
-        while eng.has_unfinished():
-            for o in eng.step():
-                if o.finished:
-                    outs[o.request_id] = o.token_ids
-            steps += 1
-            if steps == 3:
-                # cold multi-chunk arrival mid-decode: rounds must
-                # shrink while its chunks drain
-                eng.add_request("b", prompt_token_ids=long_prompt,
-                                sampling_params=sp)
-        return eng, outs
-
-    eng, outs = run(True)
-    ks = list(eng._decode_k_obs)
-    from production_stack_tpu.engine.scheduler import Scheduler
-
-    assert 8 in ks  # full-cap rounds with no admission pressure
-    assert Scheduler.ADMISSION_K_CLAMP in ks  # clamped under backlog
-    # rounds GROW BACK once the cold prefill drains: a full-cap round
-    # happens after the last clamped one
-    last_clamped = max(
-        i for i, k in enumerate(ks) if k == Scheduler.ADMISSION_K_CLAMP
-    )
-    assert any(k == 8 for k in ks[last_clamped + 1:])
-
-    _, fixed_outs = run(False)
-    assert outs == fixed_outs and set(outs) == {"a", "b"}
-
-
-def test_adaptive_k_bounded_by_remaining_budget():
-    """When every lane has <= a few tokens left, the round shrinks to
-    the pow2 bucket of the MAX remaining budget instead of dispatching
-    the full cap (the K=32 waste mode)."""
-    sp = SamplingParams(max_tokens=11, temperature=0.0, ignore_eos=True)
-    eng = _engine(8)
-    outs = [o.token_ids for o in eng.generate(PROMPTS, sp)]
-    assert all(len(t) == 11 for t in outs)
-    ks = list(eng._decode_k_obs)
-    # 10 decode tokens after the prefill token: 8 then a 2-round — never
-    # a second full-8 dispatch for a 2-token tail
-    assert ks.count(8) == 1 and 2 in ks
-    assert [o.token_ids for o in _engine(1).generate(PROMPTS, sp)] == outs
-
-
+# -- (f) staging and accounting under device stops --------------------------
 def test_prefetch_staging_hits_with_device_stops():
     """The h2d-prefetch stage carries the advanced stop countdowns; in
     a steady fused run the staged buffer must actually be consumed
@@ -252,18 +176,14 @@ def test_prefetch_staging_hits_with_device_stops():
     assert e_on._staged_hits_total > 0
 
 
-def test_decode_k_observations_drain():
-    """The chosen-K deque drains into the server's tpu:decode_k
-    histogram feed and the stats snapshot carries the elastic
-    counters."""
+def test_decode_round_counters_at_the_one_round_size():
+    """Every round is `num_scheduler_steps` long: 8 decode tokens after
+    the prefill's first are two rounds of 4, nothing discarded."""
     eng = _engine(4)
     sp = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
     eng.generate(PROMPTS[:1], sp)
-    ks = eng.drain_decode_k_observations()
-    assert ks and all(1 <= k <= 4 for k in ks)
-    assert eng.drain_decode_k_observations() == []
     s = eng.stats()
-    assert s.decode_rounds_total == len(ks)
+    assert s.decode_rounds_total == 2
     assert s.decode_overshoot_tokens_total == 0
 
 

@@ -21,7 +21,7 @@ def cfg(**overrides) -> EngineConfig:
         model="pst-tiny-debug", tokenizer="byte", dtype="float32",
         cache_dtype="float32", block_size=4, num_kv_blocks=128,
         max_num_seqs=4, max_prefill_chunk=16, num_scheduler_steps=4,
-        adaptive_decode_k=False, seed=0,
+        seed=0,
     )
     kwargs.update(overrides)
     return EngineConfig(**kwargs)

@@ -1,15 +1,15 @@
-"""Pipelined prefill (fused h2d buffer + staged chunk uploads +
-cold-prompt chunk chaining) vs the serial per-array upload path.
+"""Pipelined prefill (fused h2d buffer + cold-prompt chunk chaining)
+vs the serial per-array upload path.
 
 The pipeline is a pure transport/scheduling optimisation: sampled
 tokens and KV cache CONTENTS must be bit-identical to the serial path
 (`prefill_pipeline=False`, the `--no-prefill-pipeline` escape hatch) on
 every prefill shape — single-sequence, packed cross-sequence groups,
 multi-chunk prompts, prefix-cache resume tails, and LoRA-slotted
-requests. Because the scheduler's zero-cost staged admission may
-legitimately reorder decode/prefill rounds, physical block ids can
-differ between the two engines under load; the cache comparison is
-therefore per-CONTENT (cached-block hash -> slot data), which pins the
+requests. Chaining and lane-typed rounds may legitimately order
+decode/prefill rounds otherwise than the serial engine, so physical
+block ids can differ between the two engines under load; the cache
+comparison is therefore per-CONTENT (cached-block hash -> slot data), which pins the
 logical KV while staying layout-agnostic. Single-sequence runs have a
 deterministic layout and compare the raw caches whole."""
 
@@ -93,38 +93,6 @@ def test_runner_packed_buffer_matches_serial():
                                   np.asarray(r_old.v_cache))
 
 
-def test_runner_staged_dispatch_matches_unstaged():
-    """A dispatch consuming a stage_prefill handle equals one that
-    builds + uploads at dispatch time."""
-    r_a = ModelRunner(cfg(prefill_pipeline=True))
-    r_b = ModelRunner(cfg(prefill_pipeline=True))
-    rng = np.random.RandomState(5)
-    ids = rng.randint(0, 384, size=9).tolist()
-    h = r_a.stage_prefill(ids, 0, [2, 3, 4], len(ids))
-    tok_a, lg_a = r_a.prefill(ids, 0, [2, 3, 4], len(ids), staged=h)
-    tok_b, lg_b = r_b.prefill(ids, 0, [2, 3, 4], len(ids))
-    assert int(np.asarray(tok_a)) == int(np.asarray(tok_b))
-    np.testing.assert_array_equal(np.asarray(lg_a), np.asarray(lg_b))
-    np.testing.assert_array_equal(np.asarray(r_a.k_cache),
-                                  np.asarray(r_b.k_cache))
-
-
-def test_runner_stale_staged_key_is_ignored():
-    """A staged handle whose bucket key does not match the dispatch
-    arguments is rebuilt from the arguments, never trusted."""
-    r = ModelRunner(cfg(prefill_pipeline=True))
-    r_ref = ModelRunner(cfg(prefill_pipeline=True))
-    rng = np.random.RandomState(6)
-    ids9 = rng.randint(0, 384, size=9).tolist()
-    ids3 = rng.randint(0, 384, size=3).tolist()
-    # staged for a 9-token chunk (t_pad 16); dispatched with 3 tokens
-    # (t_pad 8) -> key mismatch -> fresh build
-    h = r.stage_prefill(ids9, 0, [2, 3, 4], len(ids9))
-    tok, _ = r.prefill(ids3, 0, [2], len(ids3), staged=h)
-    tok_ref, _ = r_ref.prefill(ids3, 0, [2], len(ids3))
-    assert int(np.asarray(tok)) == int(np.asarray(tok_ref))
-
-
 # -- engine level -----------------------------------------------------------
 
 def _prompts(seed=7, sizes=(5, 23, 45, 12)):
@@ -133,8 +101,8 @@ def _prompts(seed=7, sizes=(5, 23, 45, 12)):
 
 
 def test_engine_parity_mixed_batch():
-    """Packed groups + multi-chunk prompts + interleaved decode under
-    staged admission: tokens and logical KV bit-identical."""
+    """Packed groups + multi-chunk prompts + interleaved decode:
+    tokens and logical KV bit-identical."""
     e_new, e_old = engine_pair()
     out_n = [o.token_ids for o in e_new.generate(_prompts(), greedy(6))]
     out_o = [o.token_ids for o in e_old.generate(_prompts(), greedy(6))]
@@ -169,6 +137,41 @@ def test_engine_cold_multi_chunk_chains():
                                   np.asarray(e_old.runner.k_cache))
     np.testing.assert_array_equal(np.asarray(e_new.runner.v_cache),
                                   np.asarray(e_old.runner.v_cache))
+
+
+@pytest.fixture(scope="module")
+def long_cold_prompt():
+    """A prompt of 11 chunks, more than one step's chain holds, and
+    the serial engine's tokens for it."""
+    prompt = np.random.RandomState(10).randint(0, 384, size=163).tolist()
+    serial = LLMEngine(cfg(prefill_pipeline=False))
+    return prompt, serial.generate([prompt], greedy(5))[0].token_ids
+
+
+@pytest.mark.parametrize("ragged", [True, False])
+def test_chain_past_its_cap_continues_next_step(long_cold_prompt, ragged):
+    """Chaining is the one way a cold prompt's chunks follow each
+    other: past the cap the next step schedules the following chunk and
+    chains again, lane-typed rounds or not. Every chunk but the steps'
+    first is a chained one, and the tokens are the serial path's."""
+    prompt, want = long_cold_prompt
+    e = LLMEngine(cfg(ragged_dispatch=ragged))
+    cap = e.MAX_CHAINED_PREFILLS
+    chunks = -(-len(prompt) // e.config.max_prefill_chunk)
+    assert chunks > cap + 1
+    e.add_request("cold", prompt_token_ids=prompt,
+                  sampling_params=greedy(5))
+    e.step()
+    assert e._pf_chained_chunks_total == cap
+    assert e._seqs["cold"].num_computed_tokens == (
+        (cap + 1) * e.config.max_prefill_chunk)
+    got = None
+    while e.has_unfinished():
+        for o in e.step():
+            if o.finished:
+                got = o.token_ids
+    assert got == want
+    assert e.stats().prefill_chained_chunks_total == chunks - 2
 
 
 def test_engine_prefix_cache_resume_tail():
@@ -233,12 +236,12 @@ def test_engine_parity_lora_slot():
 
 def test_phase_timing_and_staging_counters_populate():
     """The /metrics + bench attribution surface: per-phase prefill
-    timings accumulate and the staging counters move. Split-path
-    engine: unified ragged rounds route mixed prefill+decode work
-    through their OWN staging counters (tests/test_ragged_dispatch.py)
-    and legitimately leave the prefill-stage ones untouched."""
-    e, _ = engine_pair(ragged_dispatch=False)
+    timings accumulate, and a cold prompt served alone moves the
+    chain's counter (three chunks after the step's first)."""
+    e = LLMEngine(cfg(ragged_dispatch=False))
     e.generate(_prompts(), greedy(4))
+    assert e.stats().prefill_chained_chunks_total == 0  # finals, streams
+    e.generate(_prompts(seed=9, sizes=(61,)), greedy(4))
     s = e.stats()
     # (seconds, count) per phase of the round (tracing/phases.py) —
     # tpu:engine_phase_*_seconds in /metrics
@@ -247,9 +250,7 @@ def test_phase_timing_and_staging_counters_populate():
         assert seconds > 0 and count > 0, phase
     assert s.engine_phases["h2d"][0] >= 0
     assert s.engine_phases["h2d"][1] > 0
-    assert (s.prefill_staged_hits_total
-            + s.prefill_staged_misses_total
-            + s.prefill_chained_chunks_total) > 0
+    assert s.prefill_chained_chunks_total == 3
 
 
 def test_no_pipeline_flag_selects_serial_path():

@@ -448,40 +448,6 @@ def test_waiting_prefill_joins_next_ragged_round_no_interleave_wait():
     assert "d" in kinds and "p" in kinds  # the alternation ragged removes
 
 
-def test_pick_decode_k_ragged_drops_midprefill_clamp():
-    """Fix audit: a mid-prefill RUNNER must not clamp K under ragged
-    dispatch (its chunk rides the same round); a capacity-starved
-    waiting queue still clamps. The split path keeps both clamps."""
-    for ragged in (True, False):
-        sched = _sched(ragged, decode_k_cap=8, adaptive_decode_k=True)
-        a = _mkseq("a", 4, max_tokens=64, ignore_eos=True)
-        sched.add_seq(a)
-        sched.schedule()
-        a.num_computed_tokens = 4
-        a.append_token(7)
-        # a mid-prefill runner exists
-        b = _mkseq("b", 24, max_tokens=64, ignore_eos=True)
-        sched.add_seq(b)
-        out = sched.schedule()
-        assert out.decode is not None
-        if ragged:
-            assert out.decode.k == 8, "ragged round must not clamp"
-        else:
-            assert out.decode.k == Scheduler.ADMISSION_K_CLAMP
-    # capacity-starved waiting queue clamps in BOTH modes
-    sched = _sched(True, max_num_seqs=1, decode_k_cap=8,
-                   adaptive_decode_k=True)
-    a = _mkseq("a", 4, max_tokens=64, ignore_eos=True)
-    sched.add_seq(a)
-    sched.schedule()
-    a.num_computed_tokens = 4
-    a.append_token(7)
-    sched.add_seq(_mkseq("c", 4, max_tokens=8))  # cannot admit: no lane
-    out = sched.schedule()
-    assert out.decode is not None and not out.prefills
-    assert out.decode.k == Scheduler.ADMISSION_K_CLAMP
-
-
 def test_ragged_engine_gates():
     """Engine-level gating: ragged is off under --no-ragged-dispatch,
     on otherwise; the scheduler flag follows."""

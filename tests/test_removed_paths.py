@@ -2,11 +2,20 @@
 (`async_decode`), the switch for a third attention path
 (`ragged_kernel`) and `bench.py`, the tool that flipped them. A change
 is attributed by the driver's pairs on the chip and the ledger, not by
-a flag beside every mechanism."""
+a flag beside every mechanism.
+
+And what PR 50 took out: the prefill stage with the scheduler's
+zero-cost bypass (`stage_prefill`, `staged_prefill_ready`,
+`max_staged_prefill_run`) and adaptive K with the K axis of the
+program space (`adaptive_decode_k`, `pick_decode_k`). A decode round
+has one size, `--num-scheduler-steps`; `--no-adaptive-decode-k` still
+parses, and changes nothing, while the benchmark's configurations pass
+it."""
 
 from __future__ import annotations
 
 import dataclasses
+import json
 import re
 import subprocess
 from pathlib import Path
@@ -18,10 +27,14 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.llm_engine import LLMEngine
 from production_stack_tpu.engine.model_runner import ModelRunner
 from production_stack_tpu.engine.scheduler import (
-    decode_precompile_variants,
+    Scheduler,
+    SchedulerConfig,
+    decode_precompile_variant,
 )
 
 REPO = Path(__file__).resolve().parent.parent
+CHIP_CONFIGS = sorted(
+    p.name for p in (REPO / "benchmarks" / "chip" / "configs").glob("*.json"))
 
 
 @pytest.mark.parametrize("flag", [
@@ -64,21 +77,76 @@ def test_ragged_kernel_is_the_attention_impl(impl):
 
 
 @pytest.mark.parametrize("overlap,device_stop,want", [
-    (True, True, [(8, True, True)]),
-    (False, True, [(8, False, True)]),
-    (True, False, [(8, True, False)]),
+    (True, True, (8, True, True)),
+    (False, True, (8, False, True)),
+    (True, False, (8, True, False)),
 ])
 def test_decode_variants_follow_the_two_switches_that_remain(
         overlap, device_stop, want):
     """A staged round dispatches the chained program WITH its stop
     masks: no variant is warmed without them where device stops are on
     (the chained rounds of the protocol that went carried none)."""
-    assert decode_precompile_variants(
-        8, False, overlap=overlap, device_stop=device_stop) == want
-    adaptive = decode_precompile_variants(
-        8, True, overlap=overlap, device_stop=device_stop)
-    assert [k for k, _, _ in adaptive] == [1, 2, 4, 8]
-    assert adaptive[0] == (1, False, False)  # K=1 is the single step
+    assert decode_precompile_variant(
+        8, overlap=overlap, device_stop=device_stop) == want
+
+
+def test_the_single_step_has_no_variant():
+    assert decode_precompile_variant(
+        1, overlap=True, device_stop=True) == (1, False, False)
+
+
+# -- PR 50: the prefill stage and adaptive K -------------------------------
+@pytest.mark.parametrize("build,error", [
+    (lambda: EngineConfig(
+        model="pst-tiny-debug", adaptive_decode_k=False), TypeError),
+    (lambda: SchedulerConfig(adaptive_decode_k=False), TypeError),
+    (lambda: SchedulerConfig(max_staged_prefill_run=8), TypeError),
+    (lambda: ModelRunner.stage_prefill, AttributeError),
+    (lambda: ModelRunner.stage_prefill_batch, AttributeError),
+    (lambda: ModelRunner.prefill(
+        None, [1], 0, [0], 1, staged=((), None)), TypeError),
+    (lambda: Scheduler.pick_decode_k, AttributeError),
+], ids=["engine.adaptive_decode_k", "scheduler.adaptive_decode_k",
+        "max_staged_prefill_run", "stage_prefill", "stage_prefill_batch",
+        "prefill(staged=)", "pick_decode_k"])
+def test_the_names_that_went_are_gone(build, error):
+    with pytest.raises(error):
+        build()
+
+
+def test_the_inert_flag_parses_and_changes_nothing():
+    parser = engine_main.build_parser()
+    base = ["--model", "pst-tiny-debug", "--num-scheduler-steps", "8"]
+    assert engine_main.config_from_args(
+        parser.parse_args(base + ["--no-adaptive-decode-k"])
+    ) == engine_main.config_from_args(parser.parse_args(base))
+
+
+def test_the_parser_refuses_the_switch_that_went(capsys):
+    with pytest.raises(SystemExit) as exc:
+        engine_main.build_parser().parse_args(
+            ["--model", "pst-tiny-debug", "--adaptive-decode-k"])
+    assert exc.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("name", CHIP_CONFIGS)
+def test_a_cell_resolves_to_one_round_size(name):
+    """Every configuration of the benchmark still parses (each passes
+    the inert flag), and its rounds have the one size it names: this
+    PR cannot strand a cell."""
+    config = json.loads(
+        (REPO / "benchmarks" / "chip" / "configs" / name).read_text())
+    args = list(config["engine_args"])
+    assert "--no-adaptive-decode-k" in args
+    steps = int(args[args.index("--num-scheduler-steps") + 1])
+    ecfg = engine_main.config_from_args(
+        engine_main.build_parser().parse_args(
+            ["--model", Path(name).stem, *args]))
+    assert ecfg.num_scheduler_steps == steps > 1
+    assert decode_precompile_variant(
+        ecfg.num_scheduler_steps, overlap=ecfg.prefetch_decode,
+        device_stop=ecfg.device_stop) == (steps, True, True)
 
 
 # history lines may name what went; nothing else may send a reader there
@@ -96,10 +164,9 @@ def _tracked_files() -> list[str]:
             if p.is_file() and "__pycache__" not in p.parts]
 
 
-def test_no_tracked_file_sends_a_reader_to_bench_py():
-    assert not (REPO / "bench.py").exists()
-    pat = re.compile(r"\bbench\.py\b|PST_BENCH_")
-    named = []
+def _files_naming(pat: re.Pattern) -> dict[str, int]:
+    """Tracked file outside the history -> lines of it that match."""
+    named = {}
     for rel in _tracked_files():
         path = REPO / rel
         if rel in _HISTORY or not path.is_file():
@@ -108,6 +175,28 @@ def test_no_tracked_file_sends_a_reader_to_bench_py():
             text = path.read_text()
         except UnicodeDecodeError:
             continue
-        if pat.search(text):
-            named.append(rel)
-    assert named == []
+        hits = sum(1 for line in text.splitlines() if pat.search(line))
+        if hits:
+            named[rel] = hits
+    return named
+
+
+def test_no_tracked_file_sends_a_reader_to_bench_py():
+    assert not (REPO / "bench.py").exists()
+    assert _files_naming(re.compile(r"\bbench\.py\b|PST_BENCH_")) == {}
+
+
+def test_no_tracked_file_names_the_stage_or_adaptive_k():
+    assert _files_naming(re.compile(
+        "adaptive_decode_k|pick_decode_k|ADMISSION_K_CLAMP"
+        "|decode_k_buckets|_staged_prefill|staged_prefill_ready"
+        "|stage_prefill|max_staged_prefill_run|note_staged_prefill_miss"
+        "|prefill_staged_|observe_decode_k|drain_decode_k")) == {}
+
+
+def test_the_flag_is_named_by_the_configurations_and_one_parser_line():
+    named = _files_naming(re.compile("adaptive-decode-k"))
+    parser = "production_stack_tpu/engine/__main__.py"
+    assert named.pop(parser) == 1
+    assert sorted(named) == [
+        f"benchmarks/chip/configs/{name}" for name in CHIP_CONFIGS]

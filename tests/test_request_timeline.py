@@ -109,7 +109,7 @@ def test_async_engine_timeline_chunked_preempted_shared_trace():
             for marker in ("admit", "prefill_chunk", "first_token"):
                 assert marker in names, f"missing {marker}: {names}"
             # 17-token prompt at chunk 8 -> 3 prefill chunks, the last
-            # flagged; chunk events carry the staged/chained flags
+            # flagged; chunk events carry the chained flag
             chunks = [e for e in tl_a["events"]
                       if e["name"] == "prefill_chunk"]
             assert len(chunks) == 3
@@ -118,7 +118,6 @@ def test_async_engine_timeline_chunked_preempted_shared_trace():
             assert [c["attributes"]["last"] for c in chunks] == \
                 [False, False, True]
             for c in chunks:
-                assert "staged_hit" in c["attributes"]
                 assert "chained" in c["attributes"]
             # strict event order (enqueue -> ... -> finish) on the
             # monotonic clock

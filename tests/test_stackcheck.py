@@ -760,9 +760,8 @@ def test_kv_tiering_hot_marks_present():
 
 
 def test_elastic_decode_stays_off_hot_paths():
-    """Elastic fused decode (device-side stop masks + adaptive K): the
-    stop-array build (LLMEngine._stop_arrays), the round sizing
-    (Scheduler.pick_decode_k), and the dispatch/staging path they feed
+    """Fused decode with device-side stop masks: the stop-array build
+    (LLMEngine._stop_arrays) and the dispatch/staging path it feeds
     (decode_multi / stage_decode_multi) must keep device syncs and
     event-loop stalls off the marked hot paths — zero unsuppressed
     device-sync-hot + blocking-async over the touched engine files."""
@@ -786,7 +785,6 @@ def test_elastic_decode_hot_marks_present():
 
     want = {
         "llm_engine.py": {"_stop_arrays", "_step_impl"},
-        "scheduler.py": {"pick_decode_k"},
         "model_runner.py": {"decode_multi", "stage_decode_multi"},
     }
     for fname, funcs in want.items():
