@@ -749,9 +749,10 @@ class StateBlockManager(BlockManager):
       rest is recomputed and counted (`cutback_tokens`).
 
     Nothing here copies a state. The device finds every row's sequence
-    through `maps` (blocks, 3), which the runner uploads when
-    `map_version` moved: a block's [state slot of the sequence writing
-    it, snapshot slot to SAVE the state to when the block's last
+    through `maps` (3, blocks: a row a map, so that the device's tiles
+    pad 3 to 8 and not to 128, and an upload is the maps' own size),
+    which the runner uploads when `map_version` moved: a block's [state
+    slot of the sequence writing it, snapshot slot to SAVE the state to when the block's last
     position is computed, snapshot slot to LOAD it from when its first
     is]. A block being written belongs to one sequence, so the block of
     a row's write slot names the sequence; the step programs ship
@@ -778,7 +779,7 @@ class StateBlockManager(BlockManager):
         self.interval = max(1, interval_blocks)
         self.num_state_slots = num_state_slots
         self.num_snapshots = num_snapshots if enable_prefix_caching else 0
-        self.maps = np.zeros((num_blocks, 3), np.int32)
+        self.maps = np.zeros((3, num_blocks), np.int32)
         self.map_version = 0
         self._free_slots = list(range(num_state_slots, 0, -1))
         first = num_state_slots + 1
@@ -812,8 +813,8 @@ class StateBlockManager(BlockManager):
         return len(self.snapshots)
 
     def _set(self, bid: int, col: int, value: int) -> None:
-        if self.maps[bid, col] != value:
-            self.maps[bid, col] = value
+        if self.maps[col, bid] != value:
+            self.maps[col, bid] = value
             self.map_version += 1
 
     def _drop_snapshot(self, slot: int) -> None:
@@ -838,7 +839,7 @@ class StateBlockManager(BlockManager):
         return 0
 
     def _unpend(self, bid: int) -> None:
-        slot = int(self.maps[bid, 1])
+        slot = int(self.maps[1, bid])
         if slot and self._pending.get(slot) == bid:
             del self._pending[slot]
             self._free_snaps.append(slot)
@@ -879,7 +880,7 @@ class StateBlockManager(BlockManager):
         of block `bid`, which ends at a boundary: a snapshot slot for it,
         pending. Taken this late so that a long prompt recycles the
         boundaries it has passed itself, and evicts nobody's end."""
-        if self.num_snapshots and not self.maps[bid, 1] and (
+        if self.num_snapshots and not self.maps[1, bid] and (
                 self.blocks[bid].block_hash is None):
             slot = self._take_snapshot_slot()
             if slot:
@@ -963,7 +964,7 @@ class StateBlockManager(BlockManager):
 
     def register_hash(self, h: int, block_id: int) -> None:
         super().register_hash(h, block_id)
-        slot = int(self.maps[block_id, 1])
+        slot = int(self.maps[1, block_id])
         if not slot or self._pending.get(slot) != block_id:
             return
         del self._pending[slot]

@@ -3772,6 +3772,7 @@ class LLMEngine:
                 "snapshot_evictions": bm.snapshot_evictions,
                 "prefix_state_cutback_tokens": bm.cutback_tokens,
                 "lane_layer_steps": self.runner.ssm_lane_layer_steps,
+                "update_calls": self.runner.state_update_calls,
             }
         return {
             "attn_context_by_kind": {

@@ -247,8 +247,10 @@ class EngineMetrics:
             "tpu:kv_window_blocks_released",
             "Window-group KV blocks a sequence let go because every "
             "position in them lay behind its window", label, registry=reg)
-        # a model with recurrent state (ops/ssm.py, engine/block_manager.
-        # StateBlockManager); zero for any other
+        # a model with recurrent state (ops/ssm.py or ops/kda.py fills
+        # the state group, engine/block_manager.StateBlockManager keeps
+        # it: the tpu:ssm_* names are the GROUP's, whichever recurrence);
+        # zero for any other
         self.ssm_gauges = {
             key: Gauge(name, doc, label, registry=reg)
             for key, name, doc in (
@@ -281,7 +283,12 @@ class EngineMetrics:
                 ("lane_layer_steps", "tpu:ssm_lane_layer_steps",
                  "One-token updates of the recurrent state by the "
                  "dispatched rounds: decode lanes that hold a sequence "
-                 "x fused steps x state-space layers"),
+                 "x fused steps x state layers"),
+                ("update_calls", "tpu:state_update_calls",
+                 "Calls of the decode lanes' state-update kernel "
+                 "dispatched: fused steps x state layers, whatever the "
+                 "lanes hold (lane-layer steps over calls = live lanes "
+                 "a call)"),
             )
         }
         self.program_cache_hits = Counter(

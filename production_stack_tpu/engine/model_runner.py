@@ -448,10 +448,13 @@ class ModelRunner:
         # segments of the attention walk (tpu:decode_lane_steps,
         # tpu:decode_idle_lane_steps)
         self.decode_lane_steps = [0, 0]
-        # the state-space layers' one-token updates by those rows: lanes
+        # the state layers' one-token updates by those rows: lanes
         # that hold a sequence x fused steps x such layers
-        # (tpu:ssm_lane_layer_steps)
+        # (tpu:ssm_lane_layer_steps), and the calls of the update
+        # kernel that made them: fused steps x such layers, whatever
+        # the lanes hold (tpu:state_update_calls)
         self.ssm_lane_layer_steps = 0
+        self.state_update_calls = 0
         self._ssm_layers = mc.ssm_layers
         # evaluations of sampler.sample_tokens by the dispatched rounds
         # (a fused decode step is one, a round's first-token rows one
@@ -760,7 +763,7 @@ class ModelRunner:
                         (mc.ssm_layers, slots, mc.ssm_conv - 1,
                          mc.ssm_conv_dim), self.dtype),
                 },
-                "smap": jnp.zeros((self.num_blocks, 3), jnp.int32),
+                "smap": jnp.zeros((3, self.num_blocks), jnp.int32),
             }
         self.v_cache = {"g": tuple(vg)}
 
@@ -1245,6 +1248,7 @@ class ModelRunner:
         self.decode_lane_steps[0] += k * b
         self.decode_lane_steps[1] += k * (b - n)
         self.ssm_lane_layer_steps += k * n * self._ssm_layers
+        self.state_update_calls += k * self._ssm_layers
         w = self.model_config.sliding_window
         if w is None:
             tokens = (k * sum(decode_lens) + n * (k * (k - 1) // 2)

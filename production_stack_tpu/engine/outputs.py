@@ -127,7 +127,8 @@ class EngineStatsSnapshot:
     # hit's end, and one-token state updates of decode lanes x
     # state-space layers (counters: tpu:ssm_snapshot_saves, _restores,
     # _evictions, tpu:prefix_state_cutback_tokens,
-    # tpu:ssm_lane_layer_steps). Empty for any other model
+    # tpu:ssm_lane_layer_steps), and the update kernel's calls
+    # (tpu:state_update_calls). Empty for any other model
     ssm_stats: dict = field(default_factory=dict)
     # the stages of building a program, from jax's monitoring events of
     # this process: trace / lower / compile -> (seconds, count), and the

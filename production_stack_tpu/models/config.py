@@ -137,8 +137,9 @@ class ModelConfig:
     shared_experts: int = 0
     routed_scaling: float = 1.0
     # latent attention (a kind with `latent_dim`): q goes through a
-    # normed bottleneck of `q_lora_rank` dims; a head is `head_dim` =
-    # nope dims then `rotary_dim` rotary dims, and `v_head_dim` out
+    # normed bottleneck of `q_lora_rank` dims (0: projected directly);
+    # a head is `head_dim` = nope dims then `rotary_dim` rotary dims,
+    # and `v_head_dim` out
     q_lora_rank: int = 0
     rope_yarn: YarnScaling | None = None
     # hyper-connections (arXiv:2512.24880): `hc_mult` residual streams a
@@ -169,9 +170,13 @@ class ModelConfig:
     # -- a stack of SINGLE-sublayer blocks (models/layer_groups.py,
     # `forward_blocks`): each layer is x + F(RMSNorm(x)) with ONE F, by
     # its letter in `block_pattern`: "M" a state-space (Mamba-2) mixer,
-    # "*" attention (kind 0 of `attn_kinds`; `layer_kinds` then lists
-    # the attention blocks only), "E" routed experts. "" = every layer
-    # is attention followed by an MLP, as everywhere else
+    # "K" a gated delta-rule linear-attention (KDA) mixer, "*" attention
+    # (kind 0 of `attn_kinds`, latent where the kind says so;
+    # `layer_kinds` then lists the attention blocks only), "E" routed
+    # experts, "-" a dense MLP of `intermediate_size`. A letter a BLOCK:
+    # `num_layers` letters where a published layer is one block, twice
+    # as many where it is a mixer and a feed-forward part. "" = every
+    # layer is attention followed by an MLP, as everywhere else
     block_pattern: str = ""
     rope: bool = True   # False: attention without positional encoding
     # the state-space mixer: `ssm_heads` heads of `ssm_head_dim`, B and
@@ -179,7 +184,11 @@ class ModelConfig:
     # convolution of `ssm_conv` taps over [x | B | C], the scan computed
     # in chunks of `ssm_chunk` rows. A sequence carries, a layer, the
     # (heads, head_dim, state) float32 state and the convolution's last
-    # `ssm_conv - 1` rows: a STATE SLOT, not pages
+    # `ssm_conv - 1` rows: a STATE SLOT, not pages. The KDA mixer
+    # (ops/kda.py) fills the same slot and is sized by the same fields:
+    # `ssm_heads` heads with a (`ssm_state` key dims, `ssm_head_dim`
+    # value dims) matrix state each, `ssm_groups` = `ssm_heads`, the
+    # convolutions over [v | k | q] where Mamba-2 has [x | B | C]
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_groups: int = 1
@@ -203,19 +212,31 @@ class ModelConfig:
             )
         pattern = self.block_pattern
         if pattern and (
-            len(pattern) != self.num_layers or set(pattern) - set("M*E")
+            len(pattern) % self.num_layers or set(pattern) - set("MK*E-")
             or not self.attn_kinds
-            or ("M" in pattern and not (self.ssm_heads and self.ssm_state))
+            or (self.ssm_layers and not (self.ssm_heads and self.ssm_state))
+            or ("K" in pattern and self.ssm_groups != self.ssm_heads)
             or ("E" in pattern and not self.router_experts)
+            or ("-" in pattern and not self.intermediate_size)
             or self.hc_mult > 1 or self.head_gate or self.dense_layers
         ):
             raise ValueError(
                 f"model {self.name}: block_pattern {pattern!r} must give "
-                f"one of M, *, E for each of {self.num_layers} layers of "
-                "a stack of layer groups, with the state-space sizes for "
-                "an M and routed experts for an E (no residual streams, "
-                "head gate or leading dense layers there)"
+                f"one of M, K, *, E, - for each block of {self.num_layers} "
+                "layers (as many blocks a layer throughout) of a stack of "
+                "layer groups, with the state sizes "
+                "for an M or a K (a K head has keys of its own: "
+                "ssm_groups = ssm_heads), routed experts for an E and "
+                "intermediate_size for a - (no residual streams, head "
+                "gate or `dense_layers` there: a - says where a dense "
+                "MLP stands)"
             )
+        if "M" in pattern and "K" in pattern:
+            raise ValueError(
+                f"model {self.name}: block_pattern {pattern!r} has "
+                "state-space (M) and delta-rule (K) mixers in one stack, "
+                "which is not served: the state group holds one "
+                "recurrence's slots")
         if self.attn_kinds:
             if len(self.layer_kinds) != (
                 pattern.count("*") if pattern else self.num_layers
@@ -328,7 +349,7 @@ class ModelConfig:
         """A stack of single-sublayer blocks as runs of a repeating
         UNIT of unlike blocks: (unit, count, index of the run's first
         "*" within the attention cache group, the same for its first
-        "M" within the state group). One `lax.scan` walks a run, so a
+        "M" or "K" within the state group). One `lax.scan` walks a run, so a
         program traces each unit once: the cover with the fewest traced
         bodies (EMEMEMEMEM* = "EM" x 5 and "*": two units, three
         bodies)."""
@@ -349,12 +370,34 @@ class ModelConfig:
         for unit, count in best[0][1]:
             runs.append((unit, count, n_attn, n_ssm))
             n_attn += count * unit.count("*")
-            n_ssm += count * unit.count("M")
+            n_ssm += count * (unit.count("M") + unit.count("K"))
         return tuple(runs)
+
+    @functools.cached_property
+    def switched(self) -> bool:
+        """True where the pattern has fewer kinds of block than its best
+        cover has bodies (K-KEKE*EKE: 4 against 8). The blocks then run
+        under ONE scan that runs the body of the block's letter, each
+        from its letter's stack at its own index, and a program traces
+        each kind once: size, trace time and compile time follow the kinds,
+        not the order the pattern puts them in."""
+        return len(set(self.block_pattern)) < sum(
+            len(u) for u, _, _, _ in self.units())
+
+    def tree_units(self) -> tuple[tuple[str, int, int, int], ...]:
+        """How the parameter tree stacks the blocks: `units()`, or where
+        `switched` one single-letter unit a letter, in the order of
+        first occurrence, all its blocks stacked."""
+        if not self.switched:
+            return self.units()
+        p = self.block_pattern
+        return tuple((c, p.count(c), 0, 0) for c in dict.fromkeys(p))
 
     @property
     def ssm_layers(self) -> int:
-        return self.block_pattern.count("M")
+        """Layers that carry a recurrent state: a slot of the state
+        group a sequence, whichever recurrence fills it."""
+        return self.block_pattern.count("M") + self.block_pattern.count("K")
 
     @property
     def ssm_inner(self) -> int:
@@ -396,12 +439,30 @@ class ModelConfig:
             return (h + h * (d + self.ssm_conv_dim + heads)
                     + (self.ssm_conv + 1) * self.ssm_conv_dim
                     + 3 * heads + d + d * h)
+        if letter == "K":
+            d, kd = self.ssm_inner, self.ssm_heads * self.ssm_state
+            rank = self.ssm_state
+            return (h + h * self.ssm_conv_dim
+                    + self.ssm_conv * self.ssm_conv_dim
+                    + h * rank + rank * kd + kd + self.ssm_heads
+                    + h * self.ssm_heads
+                    + h * rank + rank * d + self.ssm_head_dim + d * h)
         if letter == "*":
             ak = self.kinds[0]
-            return (h + h * ak.num_heads * self.head_dim
+            nq, out = ak.num_heads, ak.num_heads * self.v_dim * h
+            if ak.latent_dim:
+                lat, r = ak.latent_dim, self.q_lora_rank
+                nope = self.head_dim - ak.rotary_dim
+                q = (h * r + r + r * nq * self.head_dim if r
+                     else h * nq * self.head_dim)
+                return (h + q + h * (lat + ak.rotary_dim) + lat
+                        + lat * nq * (nope + self.v_dim) + out)
+            return (h + h * nq * self.head_dim
                     + h * ak.num_kv_heads * (self.head_dim + self.v_dim)
-                    + ak.num_heads * self.v_dim * h)
+                    + out)
         mats = 3 if self.mlp_gated else 2
+        if letter == "-":
+            return h + mats * h * self.intermediate_size
         w, f = self.expert_width, self.moe_intermediate_size
         return (h + h * self.router_experts
                 + (self.router_experts if self.router_bias else 0)
@@ -425,7 +486,8 @@ class ModelConfig:
                 if ak.latent_dim:
                     r, nope = self.q_lora_rank, self.head_dim - rot
                     total += (
-                        h * r + r + r * nq * self.head_dim
+                        (h * r + r + r * nq * self.head_dim if r
+                         else h * nq * self.head_dim)
                         + h * (ak.latent_dim + rot)
                         + ak.latent_dim
                         + ak.latent_dim * nq * (nope + self.v_dim)
@@ -713,6 +775,53 @@ TINY_NEMOTRON_DEBUG = _register(
     )
 )
 
+# published layers 1-5 of a `model_type: kimi_linear` model as ten
+# single-sublayer blocks (K-KEKE*EKE: KDA + dense MLP, then KDA, KDA,
+# latent attention, KDA each with routed experts) at tiny widths that
+# keep every code path that family adds: KDA mixers of 4 heads with 8
+# key dims and 16 value dims (K != V), 4 taps, chunks of 8 rows (shorter
+# than the prompts); latent attention without positional encoding, a
+# 32-dim latent row + 8 shared key dims that are not rotated, the query
+# projected directly; a gated dense MLP; 16 sigmoid-routed gated experts
+# (top-4 of score + bias, renormalised, times 2.446; rank 0 of 2 holds
+# 8) beside one shared expert
+TINY_KIMI_DEBUG = _register(
+    ModelConfig(
+        name="pst-tiny-kimi-debug",
+        vocab_size=384,
+        hidden_size=32,
+        intermediate_size=64,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=24,
+        max_model_len=256,
+        rope_theta=1e4,
+        attn_kinds=(AttnKind(num_kv_heads=1, rope_theta=1e4,
+                             latent_dim=32),),
+        layer_kinds=(0,),
+        v_head_dim=16,
+        rotary_dim=8,
+        block_pattern="K-KEKE*EKE",
+        rope=False,
+        ssm_heads=4,
+        ssm_head_dim=16,
+        ssm_groups=4,
+        ssm_state=8,
+        ssm_conv=4,
+        ssm_chunk=8,
+        router_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        router_bias=True,
+        moe_intermediate_size=24,
+        ep_rank=0,
+        ep_size=2,
+        shared_experts=1,
+        routed_scaling=2.446,
+    )
+)
+
 # CI-scale stand-in for facebook/opt-125m in the reference's test configs:
 # same order of magnitude, Llama-class architecture.
 SMALL_125M = _register(
@@ -836,7 +945,8 @@ def from_hf_config(path: str, name: str | None = None) -> ModelConfig:
         hf = json.load(f)
     by_type = {"mimo_v2": _from_mimo_v2, "xing4_0": _from_xing4,
                "ouro": _from_ouro, "laguna": _from_laguna,
-               "nemotron_h": _from_nemotron_h}
+               "nemotron_h": _from_nemotron_h,
+               "kimi_linear": _from_kimi_linear}
     if hf.get("model_type") in by_type:
         return by_type[hf["model_type"]](hf, name or os.path.basename(
             os.path.normpath(path)))
@@ -1327,6 +1437,110 @@ def _from_nemotron_h(hf: dict, name: str) -> ModelConfig:
         ep_rank=int(hf.get("ep_rank", 0)),
         ep_size=int(hf.get("ep_size", 1)),
         shared_experts=shared // f if routed else 0,
+        routed_scaling=float(hf.get("routed_scaling_factor") or 1.0),
+    )
+
+
+def _from_kimi_linear(hf: dict, name: str) -> ModelConfig:
+    """`model_type: kimi_linear` (Kimi-Linear, arXiv:2510.26692): every
+    published layer is a mixer and a feed-forward part, each a block of
+    its own here. `linear_attn_config` numbers the layers from 1:
+    `kda_layers` take a KDA mixer ("K": `num_heads` heads of `head_dim`
+    keys and values, convolutions of `short_conv_kernel_size` taps),
+    `full_attn_layers` latent attention ("*": `kv_lora_rank`,
+    `qk_nope_head_dim` + `qk_rope_head_dim` a head, `v_head_dim` out, the
+    query projected directly where `q_lora_rank` is null, the shared key
+    dims NOT rotated under `mla_use_nope`). The first
+    `first_k_dense_replace` layers keep a dense SwiGLU ("-"), the rest
+    sigmoid-scored routed experts ("E": `num_experts`,
+    `num_experts_per_token`, `moe_renormalize`, `routed_scaling_factor`,
+    selection by score + bias as the family's gate carries it) beside
+    `num_shared_experts` shared ones. `head_dim` and
+    `num_key_value_heads` at the file's top level are read by nothing.
+    `ep_size` / `ep_rank` (a deployment's keys) say which contiguous
+    slice of the experts this engine holds. What has no code path is
+    refused by name."""
+    L = hf["num_hidden_layers"]
+    lin = hf["linear_attn_config"]
+    kda, full = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+    if kda & full or kda | full != set(range(1, L + 1)):
+        raise ValueError(
+            f"{name}: linear_attn_config's kda_layers and "
+            f"full_attn_layers must share out layers 1..{L} (numbered "
+            "from 1)")
+    if not full:
+        raise ValueError(
+            f"{name}: a stack without a full_attn_layers entry is not "
+            "served (the block table every sequence ships is the "
+            "attention cache group's)")
+    for key, want in (("num_expert_group", (None, 1)),
+                      ("topk_group", (None, 1)),
+                      ("num_nextn_predict_layers", (None, 0)),
+                      ("moe_layer_freq", (None, 1)),
+                      ("hidden_act", (None, "silu")),
+                      ("moe_router_activation_func", ("sigmoid",)),
+                      ("q_lora_rank", (None, 0)),
+                      ("attention_bias", (None, False))):
+        if hf.get(key) not in want:
+            raise ValueError(
+                f"{name}: {key}={hf.get(key)!r} is not served for "
+                "model_type kimi_linear (group-limited routing, an MTP "
+                "module, dense layers among routed ones and a query "
+                "bottleneck have no tested code path there)")
+    if not hf.get("mla_use_nope", False) or hf.get("rope_scaling"):
+        raise ValueError(
+            f"{name}: mla_use_nope={hf.get('mla_use_nope')!r} with "
+            f"rope_scaling={hf.get('rope_scaling')!r} is not served for "
+            "model_type kimi_linear (latent attention in a stack of "
+            "single-sublayer blocks runs without positional encoding)")
+    dense = min(int(hf.get("first_k_dense_replace", 0)), L)
+    routed = dense < L
+    pattern = "".join(
+        ("K" if i in kda else "*") + ("-" if i <= dense else "E")
+        for i in range(1, L + 1))
+    theta = float(hf.get("rope_theta", 10000.0))
+    heads, dim = lin["num_heads"], lin["head_dim"]
+    return ModelConfig(
+        name=name,
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=1,
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        max_model_len=hf.get("model_max_length",
+                             hf.get("max_position_embeddings", 8192)),
+        rope_theta=theta,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=hf.get("tie_word_embeddings", False),
+        attn_kinds=(AttnKind(num_kv_heads=1, rope_theta=theta,
+                             latent_dim=hf["kv_lora_rank"]),),
+        layer_kinds=(0,) * len(full),
+        v_head_dim=hf["v_head_dim"],
+        rotary_dim=hf["qk_rope_head_dim"],
+        block_pattern=pattern,
+        rope=False,
+        ssm_heads=heads,
+        ssm_head_dim=dim,
+        ssm_groups=heads,
+        ssm_state=dim,
+        ssm_conv=lin.get("short_conv_kernel_size", 4),
+        # the chunked form's chunk: not a key of the config. 16 by the
+        # chip (two lanes of 256 rows at 32 heads of 128 x 128: 1.57 ms
+        # a layer against 1.80 at 32 and 2.21 at 64; PERF.md, Findings
+        # PR 51): the (chunk, chunk, keys) decays are elementwise work
+        ssm_chunk=16,
+        router_experts=hf["num_experts"] if routed else 0,
+        num_experts_per_tok=hf.get("num_experts_per_token", 2),
+        router_scoring="sigmoid",
+        router_bias=True,
+        router_renorm=bool(hf.get("moe_renormalize", True)),
+        moe_intermediate_size=hf.get("moe_intermediate_size", 0),
+        ep_rank=int(hf.get("ep_rank", 0)),
+        ep_size=int(hf.get("ep_size", 1)),
+        shared_experts=int(hf.get("num_shared_experts") or 0) if routed
+        else 0,
         routed_scaling=float(hf.get("routed_scaling_factor") or 1.0),
     )
 

@@ -20,10 +20,18 @@ Nothing here branches on a model's name: the fields select the code.
 
 And stacks of SINGLE-sublayer blocks (`cfg.block_pattern`,
 `forward_blocks`): a layer is x + F(RMSNorm(x)) with ONE F, a
-state-space mixer (`ops/ssm.py`), attention, or routed experts at a
-latent width with no gate matrix beside a shared expert. Runs of a
+state-space mixer (`ops/ssm.py`), a gated delta-rule linear-attention
+mixer (`ops/kda.py`), attention (plain, or latent where kind 0 is:
+`_latent_qkv`, shared with `forward`), routed experts (at a latent
+width with no gate matrix, or gated at the model's own) beside a shared
+expert, or a dense MLP. Runs of a
 repeating UNIT of unlike blocks ("EM" x 5) go under one `lax.scan`
-(`cfg.units()`), so a program traces a unit once. The recurrent state of
+(`cfg.units()`), so a program traces a unit once; where a pattern has
+fewer KINDS of block than its best cover has bodies (K-KEKE*EKE: four
+against eight) ONE scan walks the blocks in the pattern's order and
+runs the body of each block's letter, every block taking its parameters from
+its letter's stack (`cfg.switched`, `cfg.tree_units()`), so a program
+traces each kind once. The recurrent state of
 the mixers is a third member of the K-side cache pytree: "ssm": {"s",
 "conv"}, a slot a sequence, and "smap", the block manager's maps from a
 block to state slots (`engine/block_manager.StateBlockManager`), from
@@ -31,7 +39,9 @@ which `ssm.plan_rows` finds every row's sequence: no program ships
 anything for them.
 
 Latent attention (DeepSeek-V2's MLA), served ABSORBED. With x a normed
-row: c_q = RMSNorm(x W_dq), a head's q = c_q W_uq = [q_nope; q_rope];
+row: c_q = RMSNorm(x W_dq), a head's q = c_q W_uq = [q_nope; q_rope]
+(q = x W_q directly where `q_lora_rank` is 0; rope the identity where
+the model has no positional encoding);
 [c_kv; k_r] = x W_dkv, c = RMSNorm(c_kv); the cache row is [c;
 rope(k_r)], one for all heads. A head's key and value would be
 [k_nope; v] = c W_ukv; instead the query is taken through the key
@@ -89,7 +99,7 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops import ssm
+from production_stack_tpu.ops import kda, ssm
 from production_stack_tpu.ops.cache_write import plan_rows
 from production_stack_tpu.ops.cache_write import write_kv as scatter_kv
 from production_stack_tpu.ops.expert_ffn import activation
@@ -172,6 +182,8 @@ def init_params(
                 "w_dq": w((c, h, r), h, more),
                 "q_norm": jnp.ones((c, r), dtype),
                 "w_uq": w((c, r, nq * dk), r, more),
+            } if r else {"wq": w((c, h, nq * dk), h, more)}
+            lp |= {
                 "w_dkv": w((c, h, lat + rot), h, more),
                 "kv_norm": jnp.ones((c, lat), dtype),
                 "w_ukv": w((c, lat, nq * (dk - rot + dv)), lat, more),
@@ -233,14 +245,14 @@ def init_params(
 
 def _init_blocks(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     """`init_params` for a stack of single-sublayer blocks: per unit of
-    `cfg.units()` a list with one stacked tree a letter. The mixer's
+    `cfg.tree_units()` a list with one stacked tree a letter. The mixer's
     own parameters as Mamba-2 initialises them (A_log = log U(1, 16),
     dt_bias the inverse softplus of a log-uniform step in [1e-3, 1e-1],
     D ones); the convolution's bias and the router's selection bias
     non-zero, so that dropping one shows against the reference."""
     h, v = cfg.hidden_size, cfg.vocab_size
     keys = iter(jax.random.split(
-        key, 16 * sum(len(u[0]) for u in cfg.units()) + 4))
+        key, 16 * sum(len(u[0]) for u in cfg.tree_units()) + 4))
 
     def w(shape, fan_in):
         return (jax.random.normal(next(keys), shape, F32)
@@ -262,6 +274,44 @@ def _init_blocks(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
                 "D": jnp.ones((c, nh), F32),
                 "ssm_norm": jnp.ones((c, d), dtype),
                 "w_out": w((c, d, h), d),
+            }
+        if letter == "K":
+            # the gated delta rule's published initialisation
+            nh, vd, kd = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+            step = jnp.exp(jax.random.uniform(
+                next(keys), (c, nh * kd), F32, jnp.log(1e-3),
+                jnp.log(1e-1)))
+            return lp | {
+                # [v | k | q | f_a | g_a | beta]: one product a row
+                "w_in": w((c, h, cfg.ssm_conv_dim + 2 * kd + nh), h),
+                "conv_w": w((c, cfg.ssm_conv, cfg.ssm_conv_dim),
+                            cfg.ssm_conv),
+                "w_fb": w((c, kd, nh * kd), kd),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "A_log": jnp.log(jax.random.uniform(
+                    next(keys), (c, nh), F32, 1.0, 16.0)),
+                "w_gb": w((c, kd, nh * vd), kd),
+                "o_norm": jnp.ones((c, vd), dtype),
+                "w_o": w((c, nh * vd, h), nh * vd),
+            }
+        if letter == "-":
+            i = cfg.intermediate_size
+            lp |= {"w_up": w((c, h, i), h), "w_down": w((c, i, h), i)}
+            if cfg.mlp_gated:
+                lp["w_gate"] = w((c, h, i), h)
+            return lp
+        if letter == "*" and cfg.kinds[0].latent_dim:
+            ak = cfg.kinds[0]
+            nq, lat, rot = ak.num_heads, ak.latent_dim, ak.rotary_dim
+            r, dk = cfg.q_lora_rank, cfg.head_dim
+            q = {"w_dq": w((c, h, r), h), "q_norm": jnp.ones((c, r), dtype),
+                 "w_uq": w((c, r, nq * dk), r)} if r else {
+                     "wq": w((c, h, nq * dk), h)}
+            return lp | q | {
+                "w_dkv": w((c, h, lat + rot), h),
+                "kv_norm": jnp.ones((c, lat), dtype),
+                "w_ukv": w((c, lat, nq * (dk - rot + cfg.v_dim)), lat),
+                "wo": w((c, nq * cfg.v_dim, h), nq * cfg.v_dim),
             }
         if letter == "*":
             ak = cfg.kinds[0]
@@ -295,7 +345,7 @@ def _init_blocks(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
     params = {
         "embed": w((v, h), h),
         "segments": [[block(letter, c) for letter in unit]
-                     for unit, c, _, _ in cfg.units()],
+                     for unit, c, _, _ in cfg.tree_units()],
         "final_norm": jnp.ones((h,), dtype),
     }
     if not cfg.tie_word_embeddings:
@@ -357,21 +407,26 @@ def _sublayer(cfg, x, lp, sub, fn):
 def _latent_qkv(cfg, ak, x, lp, kc, l, write_slots, cos, sin, dtype):
     """Latent attention's cache write and absorbed query: -> (q (n, nq,
     latent + rope) in `dtype`, kc with the rows' [c; rope(k_r)] written
-    at `write_slots`, W_uv (latent, nq, d_v))."""
+    at `write_slots`, W_uv (latent, nq, d_v)). `cos` None: no positional
+    encoding, the shared key dims are cached as projected."""
     n = x.shape[0]
     ak = cfg.kind_of(ak)
     nq, dk, dv = ak.num_heads, cfg.head_dim, cfg.v_dim
     lat, rot = ak.latent_dim, ak.rotary_dim
     nope = dk - rot
-    cq = rms_norm(
-        jnp.dot(x, lp["w_dq"], preferred_element_type=F32).astype(dtype),
-        lp["q_norm"], cfg.rms_norm_eps)
-    q = jnp.dot(cq, lp["w_uq"], preferred_element_type=F32).astype(
-        dtype).reshape(n, nq, dk)
+    if cfg.q_lora_rank:
+        cq = rms_norm(
+            jnp.dot(x, lp["w_dq"], preferred_element_type=F32).astype(dtype),
+            lp["q_norm"], cfg.rms_norm_eps)
+        q = jnp.dot(cq, lp["w_uq"], preferred_element_type=F32)
+    else:
+        q = jnp.dot(x, lp["wq"], preferred_element_type=F32)
+    q = q.astype(dtype).reshape(n, nq, dk)
     ckv = jnp.dot(x, lp["w_dkv"], preferred_element_type=F32).astype(dtype)
     c = rms_norm(ckv[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
-    q_rope, k_rope = apply_rope(
-        q[..., nope:], ckv[:, None, lat:], cos, sin)
+    q_rope, k_rope = q[..., nope:], ckv[:, None, lat:]
+    if cos is not None:
+        q_rope, k_rope = apply_rope(q_rope, k_rope, cos, sin)
     w_ukv = lp["w_ukv"].reshape(lat, nq, nope + dv)
     q_lat = jnp.einsum(
         "nhd,lhd->nhl", q[..., :nope], w_ukv[..., :nope],
@@ -612,8 +667,9 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
                    write_slots, attn_fn, logits_rows, return_hidden, *,
                    block_size, write_kv, state_rows):
     """`forward` for a stack of single-sublayer blocks
-    (`cfg.block_pattern`): one attention cache group, the state group
-    `k_cache["ssm"]` and the maps `k_cache["smap"]` beside it."""
+    (`cfg.block_pattern`): one attention cache group (plain or
+    latent), the state group `k_cache["ssm"]` and the maps
+    `k_cache["smap"]` beside it."""
     assert state_rows is not None or not cfg.ssm_layers, (
         "a program that reaches a state-space layer says its rows' shape")
     dtype = params["embed"].dtype
@@ -627,13 +683,24 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
     if cfg.ssm_layers:
         plan = ssm.plan_rows(write_slots, positions, k_cache["smap"],
                              block_size, *state_rows)
-    slots = plan_rows(write_slots, kc)
+    slots = write_slots if ak.latent_dim else plan_rows(write_slots, kc)
     cos = sin = None
     if cfg.rope:
         cos, sin = rope_cos_sin(positions, ak.rotary_dim, ak.rope_theta,
                                 ak.rope_yarn, ak.rope_factor)
-    spec = AttnSpec(window=None, sink=None, block_map=None)
+    spec = AttnSpec(window=None, sink=None, block_map=None,
+                    latent_v=ak.latent_dim or None)
     act = cfg.hidden_act
+
+    def latent_attention(x, lp, l, kc, vc):
+        q, kc, w_uv = _latent_qkv(
+            cfg, ak, x, lp, kc, l, slots, cos, sin, dtype)
+        out = jnp.where(real[:, None, None],
+                        attn_fn(q, l, kc, vc, spec), 0)
+        out = jnp.einsum("nhl,lhd->nhd", out.astype(dtype), w_uv,
+                         preferred_element_type=F32)
+        return jnp.dot(out.reshape(n, nq * dv).astype(dtype), lp["wo"],
+                       preferred_element_type=F32).astype(dtype), kc, vc
 
     def attention(x, lp, l, kc, vc):
         q = jnp.dot(x, lp["wq"], preferred_element_type=F32).astype(
@@ -651,6 +718,14 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
                         attn_fn(q, l, kc, vc, spec), 0)
         return jnp.dot(out.reshape(n, nq * dv).astype(dtype), lp["wo"],
                        preferred_element_type=F32).astype(dtype), kc, vc
+
+    def mlp(x, lp, pre):
+        """A dense MLP (`pre` "w_") or the shared expert ("ws_"), gated
+        or not, float32 out."""
+        if cfg.mlp_gated:
+            return swiglu(x, lp[pre + "gate"], lp[pre + "up"],
+                          lp[pre + "down"], act=act).astype(F32)
+        return _mlp_ungated(x, lp[pre + "up"], lp[pre + "down"], act)
 
     def experts(x, lp, stacks, i):
         lat = x
@@ -671,53 +746,151 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
                         preferred_element_type=F32)
         if cfg.shared_experts:
             with jax.named_scope("shared_expert"):
-                y = y + (swiglu(x, lp["ws_gate"], lp["ws_up"],
-                                lp["ws_down"], act=act).astype(F32)
-                         if cfg.mlp_gated else _mlp_ungated(
-                             x, lp["ws_up"], lp["ws_down"], act))
+                y = y + mlp(x, lp, "ws_")
         return y.astype(dtype), st
+
+    def block(letter, lp, stack, i, l, carry):
+        """One block: `lp` its own parameters, `stack` its experts'
+        whole stacks and `i` its index in them, `l` its layer in the
+        attention cache group or the state group."""
+        h, kc, vc, st, state = carry
+        x = rms_norm(h, lp["norm"], cfg.rms_norm_eps,
+                     cfg.norm_weight_offset)
+        if letter in "MK":
+            # one state group, whichever recurrence
+            f, state = (ssm if letter == "M" else kda).mixer(
+                cfg, x, lp, state, l, plan)
+        elif letter == "*":
+            f, kc, vc = (latent_attention if ak.latent_dim
+                         else attention)(x, lp, l, kc, vc)
+        elif letter == "-":
+            f = mlp(x, lp, "w_").astype(dtype)
+        else:
+            f, s = experts(x, lp, stack, i)
+            st = st + s
+        return h + f, kc, vc, st, state
+
+    def split(letter, lp):
+        """(what a scan or an index slices, the experts' stacks, which
+        stay whole)."""
+        if letter != "E":
+            return lp, None
+        return ({k: a for k, a in lp.items() if k not in EXPERT_STACKS},
+                {k: lp[k] for k in EXPERT_STACKS if k in lp})
 
     h = params["embed"][token_ids].astype(dtype)
     if cfg.embed_scale != 1.0:
         h = (h.astype(F32) * cfg.embed_scale).astype(dtype)
+    if cfg.switched:
+        # ONE scan over the blocks in the pattern's order; each runs
+        # its letter's body with its index in that letter's stack,
+        # which is also its layer in its cache or state group, and a
+        # program traces each kind ONCE
+        letters = [u for u, _, _, _ in cfg.tree_units()]
+        parts = [split(c, seg[0]) for c, seg in zip(
+            letters, params["segments"])]
+        seen: dict[str, int] = {}
+        at = []
+        for c in cfg.block_pattern:
+            at.append((letters.index(c), seen.get(c, 0)))
+            seen[c] = seen.get(c, 0) + 1
+
+        # what of the carry (h, kc, vc, stats, state) a kind touches
+        touches = {"M": (0, 4), "K": (0, 4), "*": (0, 1, 2), "E": (0, 3),
+                   "-": (0,)}
+
+        def branch(n, c, sliced, stack):
+            """`run(carry, kind, i)`: block kind `n` (letter `c`) at
+            index `i` of its stack where `kind` is `n`, else nothing."""
+            idx = touches[c]
+
+            def take(carry):
+                return tuple(carry[j] for j in idx)
+
+            def put(carry, part):
+                full = list(carry)
+                for j, p in zip(idx, part):
+                    full[j] = p
+                return tuple(full)
+
+            def on_part(lp, i, carry, part):
+                return take(block(c, lp, stack, i, i, put(carry, part)))
+
+            if cfg.block_pattern.count(c) == 1:
+                # a kind that stands once: its weights are sliced here,
+                # statically, and it runs under a `cond` on what it
+                # touches. (As a loop its weights, which do not vary in
+                # it, were prefetched in the pattern's scan: copied at
+                # every block, ten times a step; my chip run, PR 51.)
+                lp = jax.tree.map(lambda a: a[0], sliced)
+
+                def run(carry, kind, i):
+                    return put(carry, jax.lax.cond(
+                        kind == n,
+                        lambda p: on_part(lp, jnp.int32(0), carry, p),
+                        lambda p: p, take(carry)))
+                return run
+
+            def run(carry, kind, i):
+                # a loop of one turn or none carries the state pool in
+                # place, where a `cond` copied it (1.1 GB a block: a
+                # step 27 ms for 3). The index rides the turn's counter:
+                # one that does not vary in the loop is hoisted out of
+                # it with the slices of the weights
+                return put(carry, jax.lax.fori_loop(
+                    0, (kind == n).astype(jnp.int32),
+                    lambda t, p: on_part(
+                        jax.tree.map(lambda a: a[i + t], sliced), i + t,
+                        carry, p),
+                    take(carry)))
+            return run
+
+        branches = [branch(n, c, *part)
+                    for n, (c, part) in enumerate(zip(letters, parts))]
+
+        def body(carry, xs):
+            for run in branches:
+                carry = run(carry, xs[0], xs[1])
+            return carry, None
+
+        with jax.named_scope("layers"):
+            (h, kc, vc, stats, state), _ = jax.lax.scan(
+                body, (h, kc, vc, stats, state),
+                jnp.asarray(at, jnp.int32))
+        return _tail(cfg, params, h, logits_rows, return_hidden, k_cache,
+                     kc, vc, stats, state)
     with jax.named_scope("layers"):
         for blocks, (unit, count, a0, m0) in zip(
                 params["segments"], cfg.units()):
-            n_attn, n_ssm = unit.count("*"), unit.count("M")
-            # the experts' stacks stay whole, outside the scan's slices
-            stacks = [{k: lp[k] for k in EXPERT_STACKS if k in lp}
-                      if letter == "E" else None
-                      for letter, lp in zip(unit, blocks)]
-            sliced = [{k: a for k, a in lp.items()
-                       if letter != "E" or k not in EXPERT_STACKS}
-                      for letter, lp in zip(unit, blocks)]
+            n_attn = unit.count("*")
+            n_ssm = unit.count("M") + unit.count("K")
+            sliced, stacks = zip(*(split(c, lp)
+                                   for c, lp in zip(unit, blocks)))
 
             def body(carry, xs, unit=unit, stacks=stacks, a0=a0, m0=m0,
                      n_attn=n_attn, n_ssm=n_ssm):
-                h, kc, vc, st, state = carry
                 lps, i = xs
                 seen = {"*": 0, "M": 0}
                 for letter, lp, stack in zip(unit, lps, stacks):
-                    x = rms_norm(h, lp["norm"], cfg.rms_norm_eps,
-                                 cfg.norm_weight_offset)
-                    if letter == "M":
-                        f, state = ssm.mixer(
-                            cfg, x, lp, state,
-                            m0 + i * n_ssm + seen["M"], plan)
-                    elif letter == "*":
-                        f, kc, vc = attention(
-                            x, lp, a0 + i * n_attn + seen["*"], kc, vc)
-                    else:
-                        f, s = experts(x, lp, stack, i)
-                        st = st + s
-                    seen[letter] = seen.get(letter, 0) + 1
-                    h = h + f
-                return (h, kc, vc, st, state), None
+                    kind = "M" if letter in "MK" else letter
+                    l = None
+                    if kind in seen:
+                        l = (a0 + i * n_attn if kind == "*"
+                             else m0 + i * n_ssm) + seen[kind]
+                        seen[kind] += 1
+                    carry = block(letter, lp, stack, i, l, carry)
+                return carry, None
 
             (h, kc, vc, stats, state), _ = jax.lax.scan(
                 body, (h, kc, vc, stats, state),
-                (sliced, jnp.arange(count)))
+                (list(sliced), jnp.arange(count)))
+    return _tail(cfg, params, h, logits_rows, return_hidden, k_cache, kc,
+                 vc, stats, state)
 
+
+def _tail(cfg, params, h, logits_rows, return_hidden, k_cache, kc, vc,
+          stats, state):
+    """`forward_blocks`' way out: the caches put back, then the head."""
     k_cache = {**k_cache, "g": (kc,), "stats": stats}
     if state is not None:
         k_cache["ssm"] = state

@@ -77,18 +77,18 @@ class RowPlan(NamedTuple):
 
 def plan_rows(write_slots, positions, maps, block_size: int,
               lanes: int, lane_rows: int, tail: int) -> RowPlan:
-    """`maps` (blocks, 3) int32: a block's state slot, save slot, load
+    """`maps` (3, blocks) int32: a block's state slot, save slot, load
     slot. `lanes`, `lane_rows`, `tail`: the program's shape (static)."""
     lead = write_slots.shape[0] - tail
     blk, off = write_slots // block_size, write_slots % block_size
-    slot = maps[blk, 0]
+    slot = maps[0, blk]
 
     def edges(first, last):
         """(src, zero, save) from a sequence's first and last row."""
-        load = jnp.where(off[first] == 0, maps[blk[first], 2], 0)
+        load = jnp.where(off[first] == 0, maps[2, blk[first]], 0)
         src = jnp.where(load > 0, load, slot[first])
         save = jnp.where(off[last] == block_size - 1,
-                         maps[blk[last], 1], 0)
+                         maps[1, blk[last]], 0)
         return src, positions[first] == 0, save
 
     if lead and lanes:
@@ -369,15 +369,35 @@ def state_update(s_all, l, src, dst, zero, x, dt, a, b, c,
     0) is skipped: its y is zero, and slot 0 holds whatever the
     result's buffer held, which no lane with a sequence reads."""
     r, h, p = x.shape
-    g, n = b.shape[1:]
+    g = b.shape[1]
     rows, _, width = s_all.shape[2:]
+    f32 = jnp.float32
+    # a head's decay, and dt x, along the state's minor axis
+    da = jnp.repeat(jnp.exp(dt * a), p, axis=-1).reshape(r, rows, width)
+    dx = (dt[..., None] * x.astype(f32)).reshape(r, rows, width)
+    y, s_all = lane_update_call(
+        _update_kernel, "ssm_state_update", s_all, l, src, dst, zero,
+        [da, dx, jnp.swapaxes(b.astype(f32), 1, 2),
+         jnp.swapaxes(c.astype(f32), 1, 2)],
+        (rows, width), interpret, lanes=r, groups=g,
+        rows_a_group=rows // g)
+    return y.reshape(r, h, p), s_all
+
+
+def lane_update_call(kernel, name: str, s_all, l, src, dst, zero,
+                     lane_ins, y_block, interpret: bool, **kernel_kw):
+    """A one-token update of the state group in place, a lane a grid
+    step: `kernel(meta, s, *lane_ins, s_out, y, **kernel_kw)` sees lane
+    i's slot [l, src[i]] of `s_all` (L, slots, a, b, c) as `s`, row i of
+    each (r, ., .) array of `lane_ins`, and writes the slot [l, dst[i]]
+    and row i of y (r, *y_block) float32; meta = [l | src | dst | zero]
+    rides scalar-prefetch SMEM. The pool is aliased to the result, so a
+    lane's state passes HBM once each way -> (y, s_all)."""
+    r = src.shape[0]
     f32 = jnp.float32
     meta = jnp.concatenate([
         jnp.reshape(l, (1,)).astype(jnp.int32), src.astype(jnp.int32),
         dst.astype(jnp.int32), zero.astype(jnp.int32)])
-    # a head's decay, and dt x, along the state's minor axis
-    da = jnp.repeat(jnp.exp(dt * a), p, axis=-1).reshape(r, rows, width)
-    dx = (dt[..., None] * x.astype(f32)).reshape(r, rows, width)
 
     def lane(block):
         return pl.BlockSpec((None, *block), lambda i, m: (i, 0, 0),
@@ -385,31 +405,29 @@ def state_update(s_all, l, src, dst, zero, x, dt, a, b, c,
 
     def state(col):
         return pl.BlockSpec(
-            (None, None, rows, n, width),
+            (None, None, *s_all.shape[2:]),
             lambda i, m: (m[0], m[1 + col * r + i], 0, 0, 0),
             memory_space=pltpu.VMEM)
 
     s_all, y = pl.pallas_call(
-        functools.partial(_update_kernel, lanes=r, groups=g,
-                          rows_a_group=rows // g),
-        name="ssm_state_update",
+        functools.partial(kernel, **kernel_kw),
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(r,),
-            in_specs=[state(0), lane((rows, width)), lane((rows, width)),
-                      lane((n, g)), lane((n, g))],
-            out_specs=[state(1), lane((rows, width))],
+            in_specs=[state(0), *(lane(x.shape[1:]) for x in lane_ins)],
+            out_specs=[state(1), lane(y_block)],
         ),
         out_shape=[jax.ShapeDtypeStruct(s_all.shape, f32),
-                   jax.ShapeDtypeStruct((r, rows, width), f32)],
+                   jax.ShapeDtypeStruct((r, *y_block), f32)],
         # the pool is the first operand after the scalars
         input_output_aliases={1: 0},
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # a lane's state in and out, twice over each
-            vmem_limit_bytes=max(32 * 2**20, 5 * rows * n * width * 4),
+            vmem_limit_bytes=max(
+                32 * 2**20, 5 * math.prod(s_all.shape[2:]) * 4),
         ),
-    )(meta, s_all, da, dx, jnp.swapaxes(b.astype(f32), 1, 2),
-      jnp.swapaxes(c.astype(f32), 1, 2))
-    return y.reshape(r, h, p), s_all
+    )(meta, s_all, *lane_ins)
+    return y, s_all

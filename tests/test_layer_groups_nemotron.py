@@ -198,11 +198,11 @@ def test_the_plan_finds_lanes_and_tail_rows_from_the_maps():
     decode rows, one of them idle: every row's sequence, where a lane
     starts from zero, from its slot or from a snapshot, and where one
     saves."""
-    maps = np.zeros((16, 3), np.int32)
-    maps[3] = (1, 0, 0)          # lane A writes block 3, position 0 on
-    maps[5] = (2, 9, 7)          # lane B: loads snapshot 7, saves to 9
-    maps[6] = (3, 0, 0)          # a decode lane
-    maps[8] = (4, 11, 0)         # a decode lane at a block's last slot
+    maps = np.zeros((3, 16), np.int32)
+    maps[:, 3] = (1, 0, 0)       # lane A writes block 3, position 0 on
+    maps[:, 5] = (2, 9, 7)       # lane B: loads snapshot 7, saves to 9
+    maps[:, 6] = (3, 0, 0)       # a decode lane
+    maps[:, 8] = (4, 11, 0)      # a decode lane at a block's last slot
     ws = np.zeros((19,), np.int32)
     pos = np.zeros((19,), np.int32)
     ws[0:3], pos[0:3] = 3 * BS + np.arange(3), np.arange(3)
@@ -437,7 +437,7 @@ def test_a_sequence_owns_a_slot_and_admission_waits_for_one():
     bm = manager(slots=1)
     t1, _ = bm.allocate_prompt(ids(10, seed=1))
     assert t1.slot == 1 and bm.state_slots_in_use == 1
-    assert all(bm.maps[b, 0] == 1 for b in t1)
+    assert all(bm.maps[0, b] == 1 for b in t1)
     assert bm.allocate_prompt(ids(10, seed=2)) is None
     bm.free(t1)
     t2, _ = bm.allocate_prompt(ids(10, seed=2))
@@ -470,11 +470,11 @@ def test_a_snapshot_goes_with_its_block_and_pins_hold():
     assert bm.snapshots_resident == 2
     table, cached = bm.allocate_prompt(first[:34] + ids(6, seed=10))
     assert cached == 32 and table.load and bm._pins[table.load] == 1
-    assert bm.maps[table[8], 2] == table.load
+    assert bm.maps[2, table[8]] == table.load
     bm.prepare_chunk(table, 32, 40)
     bm.free(table)
     assert not any(bm._pins.values())
-    assert bm.maps[table[8], 2] == 0
+    assert bm.maps[2, table[8]] == 0
     # another prompt takes every block: the cached ones are evicted and
     # their snapshots go with them
     play(bm, ids(50, seed=11))

@@ -300,6 +300,37 @@ def test_the_ungated_expert_kernel_and_the_state_update_compile(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < h * p * ns * 4
 
 
+def test_the_delta_rule_update_and_its_chunked_form_compile(v5e):
+    """What `kimi-linear-ep2-l5.chat-doc16k` adds to the chip's kernels,
+    at its shapes: the decode lanes' delta-rule update in place
+    (`ops/kda.state_update`: 32 lanes of 32 x 128 x 128 float32, keys on
+    the sublanes, in a pool of 129 slots a layer) and the chunked form
+    over two prefill lanes of 256 rows at the configuration's chunk."""
+    from production_stack_tpu.ops import kda
+
+    layers, slots, r, h, kd, vd, rows = 4, 129, 32, 32, 128, 128, 256
+    f32 = jnp.float32
+    compiled = jax.jit(kda.state_update, donate_argnums=(0,)).lower(
+        _spec(v5e, (layers, slots, h, kd, vd), f32),
+        _spec(v5e, (), jnp.int32), _spec(v5e, (r,), jnp.int32),
+        _spec(v5e, (r,), jnp.int32), _spec(v5e, (r,), jnp.bool_),
+        _spec(v5e, (r, h, kd), f32), _spec(v5e, (r, h, kd), f32),
+        _spec(v5e, (r, h, vd), jnp.bfloat16), _spec(v5e, (r, h, kd), f32),
+        _spec(v5e, (r, h), f32),
+    ).compile()
+    call = [line for line in compiled.as_text().splitlines()
+            if "custom-call(" in line and "kda_state_update" in line]
+    assert len(call) == 1
+    assert f"f32[{layers},{slots},{h},{kd},{vd}]" in call[0][:400]
+    chunked = jax.vmap(functools.partial(kda.scan_chunked, chunk=16))
+    jax.jit(chunked).lower(
+        _spec(v5e, (2, rows, h, kd), f32), _spec(v5e, (2, rows, h, kd), f32),
+        _spec(v5e, (2, rows, h, vd), jnp.bfloat16),
+        _spec(v5e, (2, rows, h, kd), f32), _spec(v5e, (2, rows, h), f32),
+        _spec(v5e, (2, h, kd, vd), f32),
+    ).compile()
+
+
 @pytest.mark.slow
 def test_prefill_kernel_compiles(v5e):
     fn = functools.partial(
