@@ -31,7 +31,11 @@ fewer KINDS of block than its best cover has bodies (K-KEKE*EKE: four
 against eight) ONE scan walks the blocks in the pattern's order and
 runs the body of each block's letter, every block taking its parameters from
 its letter's stack (`cfg.switched`, `cfg.tree_units()`), so a program
-traces each kind once. The recurrent state of
+traces each kind once. In a turn of that scan the kinds that stand
+once come first and a stack of matrices goes in as the chip holds it
+(`_stored_turned`): neither changes what is computed, and each took a
+copy off the device that the turn or the program made for nothing
+(PERF.md, Findings PR 52). The recurrent state of
 the mixers is a third member of the K-side cache pytree: "ssm": {"s",
 "conv"}, a slot a sequence, and "smap", the block manager's maps from a
 block to state slots (`engine/block_manager.StateBlockManager`), from
@@ -663,6 +667,21 @@ def _mlp_ungated(x, w_up, w_down, act: str):
     return jnp.dot(a.astype(x.dtype), w_down, preferred_element_type=F32)
 
 
+LANES = 128  # the minor tile of the chip's memory
+
+
+def _stored_turned(a) -> bool:
+    """Whether the chip holds the stack of matrices `a` (L, m, n) with m
+    and not n as its minor dimension: it does where n would be padded to
+    the tile and m would not (the KDA in-projection, 2304 x 12576: 12576
+    is 98.25 tiles). A loop carries its operands in the default order,
+    so handed such a stack as it is written the compiler copies the
+    whole of it into that order once a program (232 MB: 0.75 ms a decode
+    round, and as much memory; my chip runs, PRs 51 and 52)."""
+    return a.ndim == 3 and a.shape[2] % LANES != 0 and (
+        a.shape[1] % LANES == 0)
+
+
 def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
                    write_slots, attn_fn, logits_rows, return_hidden, *,
                    block_size, write_kv, state_rows):
@@ -831,6 +850,15 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
                         lambda p: p, take(carry)))
                 return run
 
+            # a stack of matrices goes into the loop as the chip holds
+            # it: a transpose that is a bitcast there. The block's own
+            # slice is turned back, and the matrix product takes that
+            # as its dimension numbers
+            turned = jax.tree.map(_stored_turned, sliced)
+            stored = jax.tree.map(
+                lambda a, tr: jnp.swapaxes(a, 1, 2) if tr else a,
+                sliced, turned)
+
             def run(carry, kind, i):
                 # a loop of one turn or none carries the state pool in
                 # place, where a `cond` copied it (1.1 GB a block: a
@@ -840,13 +868,23 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
                 return put(carry, jax.lax.fori_loop(
                     0, (kind == n).astype(jnp.int32),
                     lambda t, p: on_part(
-                        jax.tree.map(lambda a: a[i + t], sliced), i + t,
+                        jax.tree.map(
+                            lambda a, tr: a[i + t].T if tr else a[i + t],
+                            stored, turned), i + t,
                         carry, p),
                     take(carry)))
             return run
 
-        branches = [branch(n, c, *part)
-                    for n, (c, part) in enumerate(zip(letters, parts))]
+        # the kinds that stand once come first in a turn. Behind the
+        # other kinds' loops the compiler had the time to fetch their
+        # largest matrix (the latent block's `wo`, 19 MB) into VMEM
+        # ahead of the `cond`: in every turn, ten times a step for the
+        # one that uses it (0.15 ms of a 3.7 ms decode step). First in
+        # the turn there is nothing to hide a fetch behind, and it is
+        # left to the branch that is taken
+        branches = [branch(n, c, *part) for n, (c, part) in sorted(
+            enumerate(zip(letters, parts)),
+            key=lambda e: cfg.block_pattern.count(e[1][0]) != 1)]
 
         def body(carry, xs):
             for run in branches:

@@ -494,7 +494,196 @@ def test_latent_attention_without_positions_is_the_unabsorbed_form(
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
-# -- (e) the reader and its refusals --------------------------------------------
+# -- (e) the one scan over a switched pattern -----------------------------------
+def _one_step(r):
+    """One decode step of the module's runner over its `max_num_seqs`
+    lanes: what `_build_decode` jits, nothing donated, traced anew."""
+    attn = r._decode_attn_closure()
+    b = r.config.max_num_seqs
+
+    def step(params, kc, vc, tokens, positions, write_slots, tables, ctx):
+        kc, vc = r._enter_caches(kc, vc)
+        return r._forward(
+            MC, params, tokens, positions, kc, vc, write_slots,
+            lambda q, l, k, v, spec=None: attn(
+                q, l, k, v, tables=tables, context_lens=ctx, spec=spec),
+            logits_rows=jnp.arange(b), **r._state_kw(0, 0, b))
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("shape,turned", [
+    ((4, 2304, 12576), True),    # the KDA in-projections: 98.25 tiles
+    ((1, 2304, 576), True),      # the latent down-projection: 4.5
+    ((4, 4096, 2304), False),    # whole tiles both ways
+    ((4, 2304, 256), False),
+    ((4, 100, 12576), False),    # neither way whole: as written
+    ((4, 2304), False),          # a stack of vectors
+])
+def test_a_stack_is_turned_where_the_chip_holds_it_turned(shape, turned):
+    assert layer_groups._stored_turned(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16)) == turned
+
+
+@pytest.mark.parametrize("case,prompts,lanes", [
+    ("two_lanes", (37, 22), (0, 1)),
+    # a lane BETWEEN two sequences holds none: slot 0, context 0
+    ("an_empty_lane_between", (37, 22), (0, 2)),
+    # positions 47 and 31 end blocks that end at a boundary (16 tokens):
+    # the step saves both states to snapshot slots
+    ("across_a_snapshot_boundary", (47, 31), (1, 3)),
+])
+def test_a_decode_step_is_the_same_with_a_stack_turned_and_as_written(
+        eng, monkeypatch, case, prompts, lanes):
+    """`forward_blocks` hands a loop of the scan a stack of matrices as
+    the chip holds it and turns the block's slice back: the same
+    product. At a tile of 8 the tiny preset's in-projections (32 x 148)
+    are turned as the cell's are at 128; logits, the latent cache, the
+    state pool, the convolution's rows and the routed layers' counters
+    are what the stacks as written give."""
+    r, bm = eng.runner, eng.block_manager
+    b, pages = r.config.max_num_seqs, 16
+    tokens = np.zeros(b, np.int32)
+    positions, slots, ctx = (np.zeros(b, np.int32) for _ in range(3))
+    tables = np.zeros((b, pages), np.int32)
+    held = []
+    for n, lane in zip(prompts, lanes):
+        toks = ids(n + 1, seed=100 + n)
+        _, _, table = serve(eng, toks[:n], n, CHUNK, reuse=False)
+        assert bm.ensure_capacity(n + 1, table)
+        held.append(table)
+        tokens[lane], positions[lane], ctx[lane] = toks[n], n, n + 1
+        slots[lane] = table[n // BS] * BS + n % BS
+        tables[lane, :len(table)] = table
+    saves = [int(bm.maps[1, t[n // BS]]) for t, n in zip(held, prompts)]
+    assert all(saves) == (case == "across_a_snapshot_boundary")
+    args = (r.params, r.k_cache, r.v_cache, *map(jnp.asarray, (
+        tokens, positions, slots, tables, ctx)))
+    stacks = r.params["segments"][0][0]          # the K kind's
+    out = []
+    for tile, which in ((1 << 30, []), (8, ["w_in"])):
+        monkeypatch.setattr(layer_groups, "LANES", tile)
+        assert [k for k, a in sorted(stacks.items())
+                if layer_groups._stored_turned(a)] == which
+        out.append(_one_step(r)(*args))
+    written, turned = out
+    for got, want in zip(jax.tree.leaves(turned), jax.tree.leaves(written)):
+        # the CPU sums a product against a turned matrix in another
+        # order: float32 rounding, no more
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    logits, kc, _ = turned
+    # and the step did something: the rows' pairs were routed, each
+    # sequence's state moved, a boundary's snapshot was written
+    assert int(kc["stats"][0]) == 4 * 4 * len(prompts)
+    before = r.k_cache["ssm"]["s"]
+    for table, slot in zip(held, saves):
+        assert np.any(np.asarray(kc["ssm"]["s"][:, table.slot])
+                      != np.asarray(before[:, table.slot]))
+        if slot:
+            np.testing.assert_array_equal(
+                kc["ssm"]["s"][:, slot], kc["ssm"]["s"][:, table.slot])
+    assert np.all(np.isfinite(np.asarray(logits)[list(lanes)]))
+    for table in held:
+        bm.free(table)
+    assert bm.state_slots_in_use == 0
+
+
+def _under(jaxpr, path=()):
+    """{a block kind's mark: the loops and branches it sits under, the
+    shortest such path}: K by the scope `kda_step`, E by
+    `shared_expert`, * by the paged attention kernel."""
+    found: dict[str, tuple] = {}
+
+    def note(mark, path):
+        if mark not in found or len(path) < len(found[mark]):
+            found[mark] = path
+
+    for eqn in jaxpr.eqns:
+        name, scopes = eqn.primitive.name, str(eqn.source_info.name_stack)
+        if name == "pallas_call":
+            note("*", path)
+        for mark, scope in (("K", "kda_step"), ("E", "shared_expert")):
+            if scope in scopes:
+                note(mark, path)
+        here = path + ((name,) if name in ("scan", "while", "cond") else ())
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            for mark, p in _under(sub, here).items():
+                note(mark, p)
+    return found
+
+
+def _traced(r, monkeypatch, build, n_packed):
+    """The jaxpr of a fresh step program and what the model's code was
+    asked for while it was traced, in order: a letter a block body
+    (K a delta-rule mixer, - a dense MLP, E routed experts with their
+    shared expert, * latent attention) and the number of chunked KDA
+    bodies."""
+    calls = []
+
+    def noting(mod, name, mark):
+        real = getattr(mod, name)
+
+        def wrapper(*a, **kw):
+            calls.append(mark)
+            return real(*a, **kw)
+
+        monkeypatch.setattr(mod, name, wrapper)
+
+    noting(kda, "mixer", "K")
+    noting(kda, "scan_chunked", "c")
+    noting(layer_groups, "routed_experts", "E")
+    noting(layer_groups, "swiglu", "s")
+    noting(layer_groups, "_latent_qkv", "*")
+
+    def spec(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    jaxpr = build().trace(
+        spec(r.params), spec(r.k_cache), spec(r.v_cache),
+        jax.ShapeDtypeStruct((n_packed,), jnp.int32)).jaxpr
+    monkeypatch.undo()
+    order = "".join(calls)
+    return jaxpr, order.replace("c", "").replace("Es", "E").replace(
+        "s", "-"), order.count("c")
+
+
+def test_every_program_walks_the_blocks_under_the_one_scan(eng, monkeypatch):
+    """Each kind of block is traced ONCE a forward in every kind of
+    program (what set-up pays follows the kinds, not the pattern), under
+    the one scan over the pattern: a loop of one turn or none around a
+    kind that stands more than once, a branch around one that stands
+    once, and in a turn the kinds that stand once FIRST (behind the
+    others' loops the compiler fetched the latent block's `wo` ahead of
+    its branch in every turn). A program with prefill rows holds ONE
+    chunked KDA body."""
+    r = eng.runner
+    b, k = r.config.max_num_seqs, 4
+    n_dec = r._decode_pack_layout(b, 64, False, stop_cap=0)[1]
+    turn = ("while", "scan", "while")
+    scanned = {"K": turn, "E": turn, "*": ("while", "scan", "cond")}
+    for kw in ({}, {"want_logprobs": True}):
+        jaxpr, order, chunked = _traced(
+            r, monkeypatch,
+            lambda: r._build_decode_multi(b, 64, k, stop_cap=0, **kw), n_dec)
+        assert (order, chunked) == ("-*KE", 0)
+        assert _under(jaxpr) == scanned
+    jaxpr, order, chunked = _traced(
+        r, monkeypatch,
+        lambda: r._build_ragged_rows(32, 64, b, 64, k, stop_cap=0),
+        sum(r._ragged_rows_pack_sizes(32, 64, b, 64, False, stop_cap=0)))
+    # the step with the prefill rows, then the loop's step
+    assert (order, chunked) == ("-*KE-*KE", 1)
+    assert _under(jaxpr) == {c: p[1:] for c, p in scanned.items()}
+    jaxpr, order, chunked = _traced(
+        r, monkeypatch, lambda: r._build_prefill_rows(32, 64),
+        r._rows_prefill_pack_layout(32, 64)[1])
+    assert (order, chunked) == ("-*KE", 1)
+    # no one-token rows behind the chunks: no `kda_step`
+    assert _under(jaxpr) == {c: scanned[c][1:] for c in "E*"}
+
+
+# -- (f) the reader and its refusals --------------------------------------------
 HF = {
     "model_type": "kimi_linear", "vocab_size": 384, "hidden_size": 32,
     "intermediate_size": 64, "num_hidden_layers": 5,

@@ -400,6 +400,46 @@ def test_sampler_window_stays_a_branch_on_the_chip(v5e):
     assert f"f32[{b},{TOP_CAP}]" in _topk_branch(text)
 
 
+def test_the_decode_round_of_the_kimi_cell_compiles_clean(
+        v5e, monkeypatch):
+    """The fused decode round's program of `kimi-linear-ep2-l5.chat-
+    doc16k` as the engine builds it for the chip (32 lanes, 8 steps,
+    the 32k context bucket, the ten blocks under the one scan), through
+    `scripts/bench_kda.py --step --describe`'s own functions: the text
+    holds no prefetch of the latent block's `wo` in the scan's turn and
+    no copy of the KDA in-projections' stack (PR 52 took both off: the
+    kinds that stand once first in a turn, a stack handed over as the
+    chip holds it), no copy of the state pool or of the latent cache
+    (each of which one of PR 51's forms cost a chip run to find), each
+    kind's body once, and the update kernel writes the pool in place."""
+    import importlib.util
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_kda_for_aot", os.path.join(root, "scripts", "bench_kda.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    runner, _, _ = bench.cell_runner(
+        one_chip=v5e, as_chip=lambda: monkeypatch.setattr(
+            jax, "default_backend", lambda: "tpu"))
+    compiled, _ = bench.decode_program(runner, 32768, v5e)
+    counts = bench.text_counts(compiled.as_text(), runner)
+    headers = {**counts.pop("whiles"), **counts.pop("conditionals")}
+    # the scan over the pattern; in its turn a loop of one turn or none
+    # for each of the two kinds that stand four times, a branch for
+    # each of the two that stand once
+    assert headers["layers/while"] == 1
+    assert headers["layers/while/body/closed_call/while"] == 2
+    assert headers["layers/while/body/closed_call/cond"] == 2
+    assert counts == {
+        "wo_copy_start": 0, "copy_of_in_proj_stack": 0,
+        "copy_of_state_pool": 0, "copy_of_latent_cache": 0,
+        "kda_state_update_calls_aliased": [1, 1], "expert_ffn_calls": 1}
+    # the in-projections' 232 MB are not among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("looped", [False, True])
 def test_decode_multi_step_compiles_at_full_width_and_depth(
