@@ -923,8 +923,7 @@ class ModelRunner:
                 window=ak.window,
                 sink=(jnp.zeros((ak.num_heads,), jnp.float32)
                       if ak.sink else None),
-                block_map=(jnp.zeros((4,), jnp.int32)
-                           if ak.window else None),
+                mapped=bool(ak.window),
                 latent_v=ak.latent_dim or None,
             ), ak.num_heads) for ak in mc.kinds]
             if mc.layer_groups else [(mc.num_kv_heads, None, mc.num_heads)]
@@ -953,7 +952,8 @@ class ModelRunner:
             qp = jnp.zeros(
                 (8, nq, self._smoke_q_dim(kc, vc)), self.dtype)
             out = self._attn("prefill", qp, jnp.int32(0), kc, vc,
-                             table1, jnp.int32(0), spec=spec)
+                             table1, jnp.int32(0), spec=spec,
+                             mapped=table1)
             jax.block_until_ready(out)
 
     def _smoke_q_dim(self, kc, vc) -> int:
@@ -967,14 +967,14 @@ class ModelRunner:
         seg_meta = jnp.asarray(
             [[0, 0, RAGGED_TQ, 0], [1, 0, 1, 0]], jnp.int32
         )
+        tables = jnp.zeros((2, 2), jnp.int32)
         for kc, vc, spec, nq in self._smoke_caches(mc):
             qr = jnp.zeros(
                 (2 * RAGGED_TQ, nq, self._smoke_q_dim(kc, vc)),
                 self.dtype)
             out = self._attn(
-                "ragged", qr, jnp.int32(0), kc, vc,
-                jnp.zeros((2, 2), jnp.int32), blk_seg, seg_meta,
-                spec=spec,
+                "ragged", qr, jnp.int32(0), kc, vc, tables, blk_seg,
+                seg_meta, spec=spec, mapped=tables,
             )
             jax.block_until_ready(out)
 
@@ -1068,14 +1068,15 @@ class ModelRunner:
     # jitted step builders); collapses the former per-site
     # `mesh is not None -> *_tp else *` call ladders
     def _attn(self, kind: str, q, layer, kc, vc, *args, spec=None,
-              shared=None):
+              shared=None, mapped=None):
         """Route one attention call to the pallas kernel for `kind`
         ("prefill": a lone chunk | "ragged": every batched call),
         picking the shard_map TP variant under a mesh and filling the
         static block-size/scale/interpret/window arguments from the
         runner's config. All kernel call sites dispatch through
         here. `shared`: the row blocks' shared runs of a ragged call
-        (`_shared_runs`)."""
+        (`_shared_runs`). `mapped`: the program's tables as the windowed
+        cache group holds them (`_map_tables`)."""
         from production_stack_tpu.ops import pallas_attention
 
         fns = {
@@ -1101,13 +1102,13 @@ class ModelRunner:
         if spec is not None:
             # a layer kind of a layer-group model (layer_groups.AttnSpec):
             # its own window and sink, and, for the windowed cache group,
-            # the lanes' tables (args[0] for every kernel) mapped into
-            # that group's pool
+            # the lanes' tables (args[0] for every kernel) as the program
+            # mapped them into that group's pool
             kw["window"] = spec.window
             if spec.sink is not None:
                 kw["sink"] = spec.sink
-            if spec.block_map is not None:
-                args = (spec.block_map[args[0]], *args[1:])
+            if spec.mapped:
+                args = (mapped, *args[1:])
             if spec.latent_v:
                 kw["latent_v"] = spec.latent_v
         if kc.shape[-1] > q.shape[-1]:
@@ -1139,17 +1140,36 @@ class ModelRunner:
             interpret=jax.default_backend() != "tpu",
         )
 
-    def _xla_ctx(self, kc, vc, l, slots, spec):
+    def _map_tables(self, kc, tables):
+        """A program's lane tables as its windowed kind walks them: the
+        primary pool's block ids (the XLA path: gather slots) mapped
+        into the windowed cache group's pool through the block map the
+        caches carry; None for a model without such a group. A
+        program's tables and map are fixed at its dispatch, so its
+        builder calls this ONCE, where it unpacks its constants, before
+        the layers and before a fused round's loop (inside the attention
+        call it was a gather of lanes x pages scalars a windowed layer
+        and step: 4.3% of the laguna cell's busy time; ledger, PR 52),
+        and hands the result to its attention closure as `mapped`."""
+        if not isinstance(kc, dict) or "map" not in kc or not any(
+                ak.window for ak in self.model_config.attn_kinds):
+            return None
+        block_map = kc["map"]
+        if self.attention_impl == "pallas":
+            return block_map[tables]
+        bs = self.block_size
+        return block_map[tables // bs] * bs + tables % bs
+
+    def _xla_ctx(self, kc, vc, l, slots, spec, mapped):
         """The XLA path's gathered context of one layer, and the window
         and sink its attention call takes: the model's one window, or
         what the layer kind's `spec` says (the windowed cache group's
-        slots are the primary pool's mapped block by block)."""
+        slots are `mapped`: the primary pool's, block by block)."""
         window, sink = self.model_config.sliding_window, None
         if spec is not None:
             window, sink = spec.window, spec.sink
-            if spec.block_map is not None:
-                bs = self.block_size
-                slots = spec.block_map[slots // bs] * bs + slots % bs
+            if spec.mapped:
+                slots = mapped
         # head-major cache + traced `l`: [l, :, slots] has two advanced
         # indices split by a slice, so numpy hoists them to the front —
         # the result is ALREADY (..., c, nkv, d)
@@ -1170,22 +1190,23 @@ class ModelRunner:
         chunk — the per-layer (ctx, nkv, d) gathered copy is never
         built; q row 0 is always a real token, so positions[0] is the
         chunk's absolute start position), or the flat slot gather on the
-        XLA path."""
+        XLA path; `mapped` = the same as the windowed cache group holds
+        it (`_map_tables`)."""
         scale = self._scale
         if self.attention_impl == "pallas":
 
             def attn(q, l, kc, vc, gather_slots, q_positions, total_len,
-                     spec=None):
+                     spec=None, mapped=None):
                 return self._attn(
                     "prefill", q, l, kc, vc, gather_slots,
-                    q_positions[0], spec=spec,
+                    q_positions[0], spec=spec, mapped=mapped,
                 )
         else:
 
             def attn(q, l, kc, vc, gather_slots, q_positions, total_len,
-                     spec=None):
+                     spec=None, mapped=None):
                 k_ctx, v_ctx, kw = self._xla_ctx(
-                    kc, vc, l, gather_slots, spec)
+                    kc, vc, l, gather_slots, spec, mapped)
                 return xla_attn.context_attention_prefill(
                     q, k_ctx, v_ctx, q_positions, total_len, scale, **kw
                 )
@@ -1601,10 +1622,12 @@ class ModelRunner:
                 r_pad // RAGGED_TQ + 1, dtype=jnp.int32
             )
 
+            mapped = self._map_tables(kc, pf["tables"])
+
             def attn_fn(q, l, kcc, vcc, spec=None):
                 return self._attn(
                     "ragged", q, l, kcc, vcc, pf["tables"], blk_seg,
-                    seg_meta, spec=spec,
+                    seg_meta, spec=spec, mapped=mapped,
                 )
 
             logits, kc, vc = self._forward(
@@ -1747,6 +1770,7 @@ class ModelRunner:
                 gather_slots=gather_slots,
                 q_positions=positions,
                 total_len=total_len,
+                mapped=self._map_tables(kc, gather_slots),
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
@@ -1858,6 +1882,7 @@ class ModelRunner:
                 q_starts=q_starts,
                 positions2d=positions.reshape(s_pad, t_pad),
                 total_lens=total_lens,
+                mapped=self._map_tables(kc, tables),
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
@@ -2045,7 +2070,7 @@ class ModelRunner:
             off_in = (np.arange(n_blk, dtype=np.int32) * tq) % t_pad
 
             def attn(q, l, kc, vc, tables, q_starts, positions2d,
-                     total_lens, spec=None):
+                     total_lens, spec=None, mapped=None):
                 blk_seg = jnp.arange(n_blk + 1, dtype=jnp.int32)
                 seg_meta = jnp.stack([
                     jnp.asarray(lane_of),
@@ -2055,15 +2080,16 @@ class ModelRunner:
                 ], axis=1)
                 return self._attn(
                     "ragged", q, l, kc, vc, tables, blk_seg, seg_meta,
-                    spec=spec,
+                    spec=spec, mapped=mapped,
                 )
         else:
 
             # tables: (s_pad, c_pad) per-sequence gather slots
             def attn(q, l, kc, vc, tables, q_starts, positions2d,
-                     total_lens, spec=None):
+                     total_lens, spec=None, mapped=None):
                 # (s, c, nkv, d)
-                k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
+                k_ctx, v_ctx, kw = self._xla_ctx(
+                    kc, vc, l, tables, spec, mapped)
                 # q's heads are the layer kind's own
                 qs = q.reshape(s_pad, t_pad, *q.shape[1:])
                 out = jax.vmap(
@@ -2110,6 +2136,7 @@ class ModelRunner:
                 q_starts=q_starts,
                 positions2d=positions.reshape(s_pad, t_pad),
                 total_lens=total_lens,
+                mapped=self._map_tables(kc, tables),
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
@@ -2190,7 +2217,9 @@ class ModelRunner:
         single-row segments of the one grid — the SAME program the
         mixed rounds launch), or the XLA gather path. `tables` =
         padded per-sequence block tables (b, pages) on the pallas
-        path, per-position gather slots (b, c_pad) on the XLA path.
+        path, per-position gather slots (b, c_pad) on the XLA path;
+        `mapped` = the same as the windowed cache group holds them
+        (`_map_tables`).
         `shared`: the row blocks' shared runs as the round's pack
         found them (`_shared_runs`), nothing to the XLA path."""
         scale = self._scale
@@ -2198,7 +2227,7 @@ class ModelRunner:
             tq = RAGGED_TQ
 
             def attn(q, l, kc, vc, tables, context_lens, spec=None,
-                     shared=None):
+                     shared=None, mapped=None):
                 b = q.shape[0]
                 r_pad = _ceil_tq(b)
                 n_blk = r_pad // tq
@@ -2220,15 +2249,16 @@ class ModelRunner:
                 ], axis=1)
                 out = self._attn(
                     "ragged", qp, l, kc, vc, tables, blk_seg, seg_meta,
-                    spec=spec, shared=shared,
+                    spec=spec, shared=shared, mapped=mapped,
                 )
                 return out[:b]
         else:
 
             def attn(q, l, kc, vc, tables, context_lens, spec=None,
-                     shared=None):
+                     shared=None, mapped=None):
                 # (b, c, nkv, d)
-                k_ctx, v_ctx, kw = self._xla_ctx(kc, vc, l, tables, spec)
+                k_ctx, v_ctx, kw = self._xla_ctx(
+                    kc, vc, l, tables, spec, mapped)
                 return xla_attn.context_attention_decode(
                     q, k_ctx, v_ctx, context_lens, scale, **kw
                 )
@@ -2243,7 +2273,8 @@ class ModelRunner:
                  tables, context_lens, lora=None, lora_slots=None):
             kc, vc = self._enter_caches(kc, vc)
             attn_fn = functools.partial(
-                attn, tables=tables, context_lens=context_lens
+                attn, tables=tables, context_lens=context_lens,
+                mapped=self._map_tables(kc, tables),
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
@@ -2365,7 +2396,7 @@ class ModelRunner:
                  lora=None, lora_slots=None):
             kc, vc = self._enter_caches(kc, vc)
             consts, carry0 = core["unpack"](
-                packed, chained_tokens=chained_tokens,
+                packed, kc, chained_tokens=chained_tokens,
                 g_token_class=g_token_class, g_class_mask=g_class_mask,
                 g_class_trans=g_class_trans, gen_ids=gen_ids,
                 presence=presence, frequency=frequency,
@@ -2416,17 +2447,22 @@ class ModelRunner:
 
         lane = jnp.arange(b)
 
-        def unpack(packed, chained_tokens=None, g_token_class=None,
+        def unpack(packed, kc, chained_tokens=None, g_token_class=None,
                    g_class_mask=None, g_class_trans=None, gen_ids=None,
                    presence=None, frequency=None, repetition=None,
                    lb_ids=None, lb_vals=None):
-            """Decode-pack fields -> (consts dict, initial carry)."""
+            """Decode-pack fields -> (consts dict, initial carry). `kc`:
+            the K side as the round was handed it (its block map: the
+            windowed kind's tables are mapped here, once a round)."""
             tokens = (
                 chained_tokens if chained else _seg(packed, "tokens")
             )
             positions = _seg(packed, "positions")
             context_lens = _seg(packed, "ctx")
             page_tables = _seg(packed, "page_tables")
+            attn_tables = (
+                page_tables if use_pages
+                else _seg(packed, "gather_tables"))
             consts = {
                 "temps": jax.lax.bitcast_convert_type(
                     _seg(packed, "temps"), jnp.float32
@@ -2442,10 +2478,8 @@ class ModelRunner:
                     _seg(packed, "keys"), jnp.uint32
                 ),
                 "page_tables": page_tables,
-                "attn_tables": (
-                    page_tables if use_pages
-                    else _seg(packed, "gather_tables")
-                ),
+                "attn_tables": attn_tables,
+                "mapped_tables": self._map_tables(kc, attn_tables),
                 "shared_run": (
                     _seg(packed, "shared_run") if use_pages else None),
                 "presence": presence,
@@ -2525,6 +2559,7 @@ class ModelRunner:
             attn_fn = functools.partial(
                 attn, tables=consts["attn_tables"], context_lens=ctx,
                 shared=consts["shared_run"],
+                mapped=consts["mapped_tables"],
             )
             logits, kc, vc = self._forward(
                 mc, params, tokens, positions, kc, vc, write_slots,
@@ -4037,7 +4072,7 @@ class ModelRunner:
             dec_packed = packed[meta_n + pf_n:]
             pf = pf_unpack(pf_packed)
             consts, carry0 = core["unpack"](
-                dec_packed, chained_tokens=chained_tokens,
+                dec_packed, kc, chained_tokens=chained_tokens,
                 g_token_class=g_token_class, g_class_mask=g_class_mask,
                 g_class_trans=g_class_trans, gen_ids=gen_ids,
                 presence=presence, frequency=frequency,
@@ -4057,15 +4092,19 @@ class ModelRunner:
             # lane tables: prefill lanes then decode lanes, padded to
             # the wider page count (pad pages point at the null block
             # and sit beyond every segment's page walk)
-            pf_tab = pf["tables"]
-            dec_tab = consts["page_tables"]
-            pf_tab = jnp.pad(
-                pf_tab, ((0, 0), (0, n_pages - pf_tab.shape[1]))
-            )
-            dec_tab = jnp.pad(
-                dec_tab, ((0, 0), (0, n_pages - dec_tab.shape[1]))
-            )
-            tables_cat = jnp.concatenate([pf_tab, dec_tab], axis=0)
+            def widen(tab):
+                return jnp.pad(tab, ((0, 0), (0, n_pages - tab.shape[1])))
+
+            pf_tab = widen(pf["tables"])
+            tables_cat = jnp.concatenate(
+                [pf_tab, widen(consts["page_tables"])], axis=0)
+            # as the windowed kind walks them: the decode lanes' rows
+            # are the round's, mapped once in the core's unpack (a pad
+            # page is the null block, which maps to itself)
+            mapped_cat = self._map_tables(kc, pf_tab)
+            if mapped_cat is not None:
+                mapped_cat = jnp.concatenate(
+                    [mapped_cat, widen(consts["mapped_tables"])], axis=0)
             # block map: prefill blocks carry one chunk segment each;
             # decode lanes are single-row segments sharing the tail
             # blocks (q_pos = ctx-1 makes decode the degenerate causal
@@ -4103,6 +4142,7 @@ class ModelRunner:
                 out = self._attn(
                     "ragged", qp, l, kcc, vcc, tables_cat, blk_seg,
                     seg_meta, spec=spec, shared=shared,
+                    mapped=mapped_cat,
                 )
                 return out[:r_pad + b]
 

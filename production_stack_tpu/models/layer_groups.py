@@ -88,7 +88,12 @@ Design:
   (primary block id -> this pool's block id, 0 = the null block: not
   resident), on the device, so no program ships a second table or a
   second set of write slots (engine/block_manager.WindowedBlockManager
-  keeps the map; the runner uploads it when it changed).
+  keeps the map; the runner uploads it when it changed). The runner
+  maps a program's tables ONCE, where the program unpacks its constants
+  (`ModelRunner._map_tables`: a round's tables and map are fixed at its
+  dispatch), and an attention call says only whether its kind walks the
+  mapped ones (`AttnSpec.mapped`); this module maps the rows' write
+  slots, once a forward.
 - "stats" accumulates the routed layers' counters on the device through
   every layer and fused step of ONE program, which starts it at zero
   (`ModelRunner._enter_caches`); the runner takes it off what the
@@ -128,7 +133,8 @@ class AttnSpec(NamedTuple):
     caches; the runner's attention callbacks take it as `spec`."""
     window: int | None
     sink: jax.Array | None       # (nq,) float32 logits
-    block_map: jax.Array | None  # primary block id -> this group's
+    mapped: bool                 # the windowed cache group's kind: it
+                                 # walks the program's MAPPED tables
     latent_v: int | None = None  # a latent kind: the cached row's
                                  # leading lanes are the value, and
                                  # there is no V cache (vc is None)
@@ -445,7 +451,7 @@ def _latent_qkv(cfg, ak, x, lp, kc, l, write_slots, cos, sin, dtype):
 
 
 def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
-           write_slots, real, attn_fn, write_kv, block_map, dtype,
+           write_slots, real, attn_fn, write_kv, dtype,
            experts=None, stack_index=None):
     """One layer of `kind` over n rows: llama.decoder_layer's shape
     (K/V written at `write_slots` BEFORE attn_fn runs) with this
@@ -483,7 +489,7 @@ def _layer(cfg, kind, routed, h, kc, vc, stats, lp, l, *, cos, sin,
         spec = AttnSpec(
             window=ak.window,
             sink=lp["sink"] if ak.sink else None,
-            block_map=block_map if ak.window else None,
+            mapped=bool(ak.window),
             latent_v=ak.latent_dim or None,
         )
         attn_out = attn_fn(q, l, kc, vc, spec)  # (n, nq, d_v | latent)
@@ -624,8 +630,7 @@ def forward(
                     cfg, kind, routed, h, kc, vc, st, lp, l,
                     cos=cos, sin=sin, write_slots=slots,
                     real=real, attn_fn=attn_fn, write_kv=write_kv,
-                    block_map=block_map, dtype=dtype,
-                    experts=experts, stack_index=c,
+                    dtype=dtype, experts=experts, stack_index=c,
                 )
                 return (h, kc, vc, st), None
 
@@ -707,7 +712,7 @@ def forward_blocks(cfg, params, token_ids, positions, k_cache, v_cache,
     if cfg.rope:
         cos, sin = rope_cos_sin(positions, ak.rotary_dim, ak.rope_theta,
                                 ak.rope_yarn, ak.rope_factor)
-    spec = AttnSpec(window=None, sink=None, block_map=None,
+    spec = AttnSpec(window=None, sink=None, mapped=False,
                     latent_v=ak.latent_dim or None)
     act = cfg.hidden_act
 

@@ -176,7 +176,7 @@ def big_copies(text: str, floor: int = 1 << 20) -> list[str]:
     return found
 
 
-def cell_runner(*, one_chip=None, as_chip=None):
+def cell_runner(*, one_chip=None, as_chip=None, cell: str = CELL):
     """The cell's `ModelRunner` on its seeded weights -> (runner, block
     manager, the cell's traffic). With `one_chip`, a sharding on a chip
     that is described and not attached: the parameters abstract, the
@@ -196,7 +196,7 @@ def cell_runner(*, one_chip=None, as_chip=None):
     )
     from production_stack_tpu.engine.llm_engine import LLMEngine
 
-    cell = manifest.load_cell(CELL)
+    cell = manifest.load_cell(cell)
     family = manifest.load_family(cell.family_file)
     engine_args = list(cell.config["engine_args"]) + [
         "--num-kv-blocks", "1024" if one_chip else "20000"]

@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 import reference_model as rm
+from moved_block_map import fused_rounds_across_a_moved_map
 from production_stack_tpu.engine.block_manager import (
     WindowedBlockManager,
     WindowTable,
@@ -199,6 +200,15 @@ def test_the_kernel_path_serves_mixed_rounds_like_the_reference():
         want = [int(np.argmax(ref[len(prompt) - 1 + i]))
                 for i in range(len(got))]
         assert got == want, rid
+
+
+def test_fused_rounds_across_a_moved_block_map_are_the_single_steps():
+    """Fused rounds map their tables into the window group's pool once,
+    each with the map of its own dispatch: the map moves between them
+    (pages let go, pages taken, a returning session's twinned anew) and
+    the tokens are the single-step path's (tests/moved_block_map.py)."""
+    fused_rounds_across_a_moved_map(
+        engine(attention_impl="pallas", num_scheduler_steps=4), serve, ids)
 
 
 # -- (b) after a prefix-cache hit ---------------------------------------------

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from moved_block_map import fused_rounds_across_a_moved_map
 from production_stack_tpu.engine.block_manager import WindowedBlockManager
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.llm_engine import LLMEngine
@@ -188,6 +189,18 @@ def test_a_returning_sequence_hits_and_one_whose_twins_are_gone_is_cut_back(
     assert bm.prefix_cutback == [before[0] + 2, before[1] + 2]
     assert_rows(rows, reference(e.runner.params, third))
     bm.free(table3)
+
+
+def test_fused_rounds_across_a_moved_block_map_are_the_single_steps(
+        kernel_eng):
+    """Three fused rounds of four steps over lanes 0, 2 and (from the
+    second) 3, GQA groups of 3 and 4 in each program: between two
+    rounds the map lets pages go and takes new ones, a returning
+    session's among them; each round maps its tables once, with the map
+    of its own dispatch, and the tokens are the single-step path's
+    (tests/moved_block_map.py). A lane that holds no sequence maps to
+    the null block only."""
+    fused_rounds_across_a_moved_map(kernel_eng, serve, ids)
 
 
 def test_the_engine_serves_mixed_rounds_with_a_prefix_hit(kernel_eng):

@@ -359,11 +359,8 @@ def test_block_tables_are_bounded_by_smem(v5e):
         _compile_decode(v5e, lanes=64, pages=4096)  # 1 MiB: refused
 
 
-def _topk_branch(text):
-    """The body of the one computation of an optimised HLO module that
-    holds the `TopK` custom call, which has to be a branch computation of
-    a `conditional`: XLA:TPU kept the sampler's `lax.cond` as control
-    flow and did not make it a `select` of both sides."""
+def _computations(text):
+    """{name: lines} of an optimised HLO module's computations."""
     comps, name = {}, None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
@@ -372,6 +369,15 @@ def _topk_branch(text):
             comps[name] = []
         elif name is not None:
             comps[name].append(line)
+    return comps
+
+
+def _topk_branch(text):
+    """The body of the one computation of an optimised HLO module that
+    holds the `TopK` custom call, which has to be a branch computation of
+    a `conditional`: XLA:TPU kept the sampler's `lax.cond` as control
+    flow and did not make it a `select` of both sides."""
+    comps = _computations(text)
     branches = {
         n.strip().lstrip("%")
         for listed in re.findall(
@@ -400,6 +406,20 @@ def test_sampler_window_stays_a_branch_on_the_chip(v5e):
     assert f"f32[{b},{TOP_CAP}]" in _topk_branch(text)
 
 
+def _bench_kda():
+    """`scripts/bench_kda.py` as a module: a cell's runner for a
+    described chip and its fused decode round's program."""
+    import importlib.util
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_kda_for_aot", os.path.join(root, "scripts", "bench_kda.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    return bench
+
+
 def test_the_decode_round_of_the_kimi_cell_compiles_clean(
         v5e, monkeypatch):
     """The fused decode round's program of `kimi-linear-ep2-l5.chat-
@@ -412,14 +432,7 @@ def test_the_decode_round_of_the_kimi_cell_compiles_clean(
     chip holds it), no copy of the state pool or of the latent cache
     (each of which one of PR 51's forms cost a chip run to find), each
     kind's body once, and the update kernel writes the pool in place."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_kda_for_aot", os.path.join(root, "scripts", "bench_kda.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+    bench = _bench_kda()
     runner, _, _ = bench.cell_runner(
         one_chip=v5e, as_chip=lambda: monkeypatch.setattr(
             jax, "default_backend", lambda: "tpu"))
@@ -438,6 +451,55 @@ def test_the_decode_round_of_the_kimi_cell_compiles_clean(
         "kda_state_update_calls_aliased": [1, 1], "expert_ffn_calls": 1}
     # the in-projections' 232 MB are not among the temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
+
+
+def _under_a_loop(text):
+    """The computations of an optimised HLO module that run inside some
+    `while`: the loops' bodies and whatever those call."""
+    comps = _computations(text)
+    called = {
+        name: set(re.findall(
+            r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+            "\n".join(body))) | {
+            n.strip().lstrip("%")
+            for listed in re.findall(
+                r"branch_computations=\{([^}]*)\}", "\n".join(body))
+            for n in listed.split(",")}
+        for name, body in comps.items()}
+    inside, todo = set(), re.findall(r" while\(.*body=%([\w.\-]+)", text)
+    assert todo, "the fused round is a loop"
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo += called.get(name, ())
+    return {name: comps[name] for name in inside}
+
+
+@pytest.mark.parametrize("cell,lanes,pages", [
+    ("laguna-xs.2-l5.chat-doc16k", 32, 1024),
+    ("mimo-v2.5-ep16-l7.batch-doc8k", 64, 512),
+])
+def test_a_windowed_kinds_tables_are_mapped_once_a_decode_round(
+        v5e, monkeypatch, cell, lanes, pages):
+    """The fused decode round's program of a cell with a windowed cache
+    group as the engine builds it for the chip (its lanes, its context
+    bucket's pages, 8 steps): the lanes' whole page tables go through
+    the block map in ONE gather, where the round unpacks its constants,
+    and no loop's body holds one. Inside the attention call it stood
+    in the round's loop, once a step: laguna's `fusion.600 s32[32768]`,
+    4.3% of the device's busy time (ledger, PR 52)."""
+    bench = _bench_kda()
+    runner, _, _ = bench.cell_runner(
+        cell=cell, one_chip=v5e, as_chip=lambda: monkeypatch.setattr(
+            jax, "default_backend", lambda: "tpu"))
+    assert runner.config.max_num_seqs == lanes
+    compiled, _ = bench.decode_program(runner, pages * BS, v5e)
+    text = compiled.as_text()
+    mapped = re.compile(rf"= s32\[{lanes},{pages}\]\S* gather\(")
+    assert len(mapped.findall(text)) == 1
+    assert not [name for name, body in _under_a_loop(text).items()
+                if any(mapped.search(line) for line in body)]
 
 
 @pytest.mark.slow
